@@ -8,9 +8,9 @@ attention, bench.py) picks the tuned widths up automatically.  Only
 entries that beat the built-in 512/1024 heuristic by >3% are written
 (the heuristic stays the fallback for everything unswept).
 
-Timing uses the relay-safe recipe: warm, then chain the grad through q
-so the whole sequence must execute, fetch one scalar, subtract the
-measured null round-trip (see benchmarks/_common.py).
+Timing: warm, then chain the grad through q so the whole sequence must
+execute, fetch one scalar, subtract the measured null round-trip (see
+benchmarks/_common.py).
 
 Off-TPU this prints a note and exits 0: interpret-mode timing would
 tune for the interpreter, not the chip.
@@ -26,10 +26,6 @@ import sys
 import time
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-  jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp
 import numpy as np
 

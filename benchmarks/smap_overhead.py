@@ -29,14 +29,17 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=8")
 import jax  # noqa: E402
-jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 import easyparallellibrary_tpu as epl  # noqa: E402
+from easyparallellibrary_tpu.parallel.planner import (  # noqa: E402
+    MODEL_DEVICE_KIND)
+from easyparallellibrary_tpu.profiler.flops import PEAK_FLOPS  # noqa: E402
 from easyparallellibrary_tpu.models import GPT, GPTConfig  # noqa: E402
 from easyparallellibrary_tpu.models.gpt import (  # noqa: E402
     make_gpt_1f1b_grad_fn, make_gpt_smap_grad_fn)
@@ -115,7 +118,7 @@ def main():
 
   bw = float(os.environ.get("EPL_SMAP_BW_GBS", "45")) * 1e9
   mfu = float(os.environ.get("EPL_SMAP_MFU", "0.4"))
-  peak = 197e12
+  peak = PEAK_FLOPS[MODEL_DEVICE_KIND]
   t_coll = per_step_boundary / bw
   t_flop = smap["flops"] / (mfu * peak)
   share = t_coll / max(t_coll + t_flop, 1e-30)

@@ -1,0 +1,632 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process, the only one that touches JAX, drives the main path once at
+the full width of GPT-350M (24 layers, d 1024, 16 heads, d_ff 4096, vocab
+32768, S 1024, bf16; random weights from a seed) through the entry points
+a user calls, and checks what comes out by the repo's own references:
+
+  kernels         flash fwd + dk/dv + dq (resident and streaming) against
+                  ``_dense_causal_attention``; paged attention (f32, bf16)
+                  against ``paged_attention_reference``; all compiled
+  train           ``bench.py``'s GPT-350M trainer: 2 warm-up + 5 steps
+  serve           ``ContinuousBatchingEngine`` on the same model, 8
+                  requests, contiguous then paged; greedy streams against
+                  ``generate(use_cache=True)``
+  four chips      (when the machine has four) the trainer as ``data:4``
+                  and as ``data:2,model:2``
+
+Any failed check raises; nothing catches it, so the exit code is non-zero
+and no result line is printed.  Finding no TPU is a failure.  The last
+line of standard output of a passing run is
+
+  {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+``--rehearse-cpu`` runs the same control flow at toy sizes with the
+kernels interpreted, to debug the script without a chip.  It says so, it
+prints no result line, and it always exits non-zero: it is not a pass.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import functools
+import json
+import re
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+import bench
+import easyparallellibrary_tpu as epl
+from easyparallellibrary_tpu.kernels import (
+    flash_attention, paged_attention_pallas, paged_attention_reference)
+from easyparallellibrary_tpu.models import GPT, GPTConfig
+from easyparallellibrary_tpu.models.gpt import (
+    _dense_causal_attention, generate, gpt_loss)
+from easyparallellibrary_tpu.observability.device import specs_of
+from easyparallellibrary_tpu.serving import (
+    ContinuousBatchingEngine, Request)
+from easyparallellibrary_tpu.testing import chaos
+from easyparallellibrary_tpu.utils import compile_cache
+from easyparallellibrary_tpu.utils.pytree import tree_bytes
+
+MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
+REHEARSAL_EXIT = 2
+
+
+class SmokeFailure(Exception):
+  """A check did not hold."""
+
+
+def check(ok, what: str) -> None:
+  if not ok:
+    raise SmokeFailure(what)
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+  """What one run drives.  ``real()`` is the contract; ``toy()`` exists
+  for the CPU rehearsal only."""
+  rehearsal: bool
+  train_cfg: GPTConfig
+  batch_candidates: tuple
+  serve_cfg: GPTConfig            # the 24-layer bf16 server
+  cut_cfg: GPTConfig              # float32 2-layer cut, same width
+  prompt_lens: tuple
+  new_tokens: int
+  flash_shapes: tuple             # (B, H, S, D, dtype)
+  paged_shape: tuple              # (T, H, hd, block, table width)
+
+  @staticmethod
+  def real() -> "Sizes":
+    serve = bench.gpt350m_config(remat=False, attn_impl="xla",
+                                 remat_policy="nothing", loss_chunk=0)
+    return Sizes(
+        rehearsal=False,
+        train_cfg=bench.gpt350m_config(),
+        batch_candidates=bench.BATCH_CANDIDATES,
+        serve_cfg=serve,
+        cut_cfg=dataclasses.replace(serve, num_layers=2,
+                                    dtype=jnp.float32),
+        prompt_lens=(64, 160, 320, 512),
+        new_tokens=32,
+        # Resident at the trainer's shape; streaming past
+        # _RESIDENT_MAX_BYTES (two heads keep the dense reference's
+        # [S, S] scores inside HBM).
+        flash_shapes=((2, 16, 1024, 64, jnp.bfloat16),
+                      (1, 2, 16384, 64, jnp.bfloat16)),
+        paged_shape=(16, 16, 64, 16, 8))
+
+  @staticmethod
+  def toy() -> "Sizes":
+    train = GPTConfig(vocab_size=512, num_layers=2, num_heads=4,
+                      d_model=128, d_ff=256, max_seq_len=128,
+                      dtype=jnp.float32, remat=True,
+                      attn_impl="pallas_flash", remat_policy="dots_flash",
+                      loss_chunk=32)
+    serve = dataclasses.replace(train, remat=False, attn_impl="xla",
+                                remat_policy="nothing", loss_chunk=0)
+    return Sizes(
+        rehearsal=True, train_cfg=train, batch_candidates=(4,),
+        serve_cfg=serve, cut_cfg=serve, prompt_lens=(8, 20, 40, 64),
+        new_tokens=8,
+        flash_shapes=((1, 2, 128, 32, jnp.float32),
+                      (1, 1, 256, 32, jnp.float32)),
+        paged_shape=(6, 4, 32, 8, 4))
+
+
+def say(msg: str) -> None:
+  print(msg, flush=True)
+
+
+def rel_err(got, ref) -> float:
+  """Largest deviation as a share of the reference's largest value."""
+  got = np.asarray(got, np.float32)
+  ref = np.asarray(ref, np.float32)
+  return float(np.abs(got - ref).max() / max(np.abs(ref).max(), 1e-30))
+
+
+def compile_here(fn, *args, mosaic_calls: int, rehearsal: bool):
+  """AOT-compile ``fn`` for these arguments and prove the kernel did not
+  run interpreted: the optimized program holds exactly ``mosaic_calls``
+  Mosaic custom calls."""
+  compiled = jax.jit(fn).lower(*args).compile()
+  if not rehearsal:
+    found = compiled.as_text().count(MOSAIC_CALL)
+    check(found == mosaic_calls,
+          f"expected {mosaic_calls} Mosaic custom calls in the compiled "
+          f"program, found {found}: the kernel ran interpreted or was "
+          "not reached")
+  return compiled
+
+
+# ---------------------------------------------------------------- kernels --
+
+
+def check_flash(B, H, S, D, dtype, rehearsal: bool) -> None:
+  r = np.random.RandomState(S)
+  q, k, v, dout = (jnp.asarray(r.randn(B, S, H, D), dtype)
+                   for _ in range(4))
+
+  def fwd_bwd(attend, q, k, v, dout):
+    out, vjp = jax.vjp(attend, q, k, v)
+    return (out,) + vjp(dout.astype(out.dtype))
+
+  kernel = compile_here(
+      functools.partial(fwd_bwd, functools.partial(flash_attention,
+                                                   causal=True)),
+      q, k, v, dout, mosaic_calls=3, rehearsal=rehearsal)
+  got = kernel(q, k, v, dout)
+  # The reference sees the same (storage-dtype) values in float32, so
+  # the difference is the kernel's own error, not the reference's.
+  f32 = [x.astype(jnp.float32) for x in (q, k, v, dout)]
+  with jax.default_matmul_precision("highest"):
+    ref = jax.jit(functools.partial(
+        fwd_bwd, lambda q, k, v: _dense_causal_attention(
+            q, k, v, jnp.float32)))(*f32)
+  tol = 2e-2 if dtype == jnp.bfloat16 else 5e-4
+  errs = {}
+  for name, g, w in zip(("out", "dq", "dk", "dv"), got, ref):
+    check(bool(jnp.isfinite(g.astype(jnp.float32)).all()),
+          f"flash {name} not finite at {(B, H, S, D)}")
+    errs[name] = rel_err(g, w)
+    check(errs[name] <= tol,
+          f"flash {name} at {(B, H, S, D)} {jnp.dtype(dtype).name}: "
+          f"error {errs[name]:.3g} of the reference's max, tol {tol}")
+  say(f"  flash B{B} H{H} S{S} D{D} {jnp.dtype(dtype).name}: "
+      + " ".join(f"{n} {e:.2e}" for n, e in errs.items())
+      + f" (tol {tol})")
+
+
+def check_paged(T, H, hd, bs, MB, dtype, rehearsal: bool) -> None:
+  r = np.random.RandomState(1)
+  NB = 2 * MB + 1
+  q = jnp.asarray(r.randn(T, H, hd), dtype)
+  kp = jnp.asarray(r.randn(NB, bs, H, hd), dtype)
+  vp = jnp.asarray(r.randn(NB, bs, H, hd), dtype)
+  tables = jnp.asarray(r.randint(0, NB, (T, MB)), jnp.int32)
+  positions = jnp.asarray(r.randint(0, MB * bs, (T,)), jnp.int32)
+  args = (q, kp, vp, tables, positions)
+  kernel = compile_here(
+      functools.partial(paged_attention_pallas, interpret=rehearsal),
+      *args, mosaic_calls=1, rehearsal=rehearsal)
+  got = kernel(*args)
+  with jax.default_matmul_precision("highest"):
+    ref = jax.jit(paged_attention_reference)(*args)
+  rtol, atol = (2e-2, 2e-2) if dtype == jnp.bfloat16 else (2e-5, 2e-6)
+  got32, ref32 = (np.asarray(x, np.float32) for x in (got, ref))
+  check(np.isfinite(got32).all(), "paged attention output not finite")
+  check(np.allclose(got32, ref32, rtol=rtol, atol=atol),
+        f"paged attention {jnp.dtype(dtype).name}: max abs error "
+        f"{np.abs(got32 - ref32).max():.3g} (rtol {rtol}, atol {atol})")
+  say(f"  paged T{T} H{H} hd{hd} block{bs} {jnp.dtype(dtype).name}: "
+      f"max abs error {np.abs(got32 - ref32).max():.2e}")
+
+
+def phase_kernels(sizes: Sizes) -> None:
+  for shape in sizes.flash_shapes:
+    check_flash(*shape, rehearsal=sizes.rehearsal)
+  for dtype in (jnp.float32, jnp.bfloat16):
+    check_paged(*sizes.paged_shape, dtype, rehearsal=sizes.rehearsal)
+  say("PASS kernels: flash fwd/bwd "
+      + ("at toy shapes, paged f32 + bf16, all INTERPRETED"
+         if sizes.rehearsal else
+         "resident + streaming, paged f32 + bf16, all compiled")
+      + ", within tolerance")
+
+
+# ------------------------------------------------------------------ train --
+
+
+def flash_call_operands(hlo: str):
+  """Operand shapes of every Mosaic custom call in an optimized HLO
+  module, as ``{(shape, ...): count}``."""
+  seen = {}
+  for line in hlo.splitlines():
+    if MOSAIC_CALL not in line:
+      continue
+    ops = line.split("operand_layout_constraints={")[1].split("}, ")[0]
+    key = tuple(re.findall(r"\w+\[[\d,]*\]", ops))
+    seen[key] = seen.get(key, 0) + 1
+  return seen
+
+
+def take_steps(step, state, batch, n: int):
+  """``n`` steps on the fixed batch, each timed both ways: host clock
+  to ``block_until_ready`` on everything the step returns, then on to a
+  scalar fetch that depends on the step.  If ``block_until_ready``
+  waits for the device the second adds next to nothing."""
+  rng = jax.random.PRNGKey(0)
+  losses, ready_ms, fetch_ms = [], [], []
+  for _ in range(n):
+    t0 = time.perf_counter()
+    state, metrics = step(state, batch, rng)
+    jax.block_until_ready((state, metrics))
+    t1 = time.perf_counter()
+    losses.append(float(jax.device_get(metrics["loss"])))
+    t2 = time.perf_counter()
+    ready_ms.append(1e3 * (t1 - t0))
+    fetch_ms.append(1e3 * (t2 - t0))
+  return state, losses, ready_ms, fetch_ms
+
+
+def run_trainer(sizes: Sizes, devices, tensor_parallel: bool = False):
+  """The trainer through its normal entry points on ``devices``: 2
+  warm-up + 5 steps on a fixed seeded batch, checked.  Returns
+  ``(state, batch, losses, hlo)`` for the checks a layout adds."""
+  epl.init(devices=devices)
+  cfg = dataclasses.replace(sizes.train_cfg,
+                            tensor_parallel=tensor_parallel)
+  with epl.replicate(1):
+    model = GPT(cfg)
+  if tensor_parallel:
+    with epl.split(2):
+      pass
+  mesh = epl.current_plan().build_mesh()
+  shape = {a: s for a, s in zip(mesh.axis_names, mesh.devices.shape)
+           if s > 1}
+  say(f"  mesh {shape or '{single chip}'} over devices "
+      f"{[d.id for d in mesh.devices.reshape(-1)]}")
+
+  t0 = time.perf_counter()
+  state, step, batch, first = bench.largest_batch_trainer(
+      model, mesh, candidates=sizes.batch_candidates,
+      per_replica=len(devices))
+  losses = [float(first["loss"])]
+  say(f"  set-up (init + compile + first step): "
+      f"{time.perf_counter() - t0:.1f} s, global batch "
+      f"{batch['ids'].shape[0]}")
+  state, later, ready_ms, fetch_ms = take_steps(step, state, batch, 6)
+  losses += later
+  say("  loss per step: " + " ".join(f"{l:.4f}" for l in losses))
+  say("  step ms to block_until_ready: "      # the 5 after warm-up
+      + " ".join(f"{t:.1f}" for t in ready_ms[1:]))
+  say("  step ms to dependent scalar fetch: "
+      + " ".join(f"{t:.1f}" for t in fetch_ms[1:]))
+  check(all(np.isfinite(losses)), f"non-finite loss: {losses}")
+  check(losses[-1] < losses[0],
+        f"loss did not fall: {losses[0]} -> {losses[-1]}")
+  check(step.jitted._cache_size() == 1,
+        f"train step compiled {step.jitted._cache_size()} times")
+  compiled = step.jitted.lower(state, batch,
+                               jax.random.PRNGKey(0)).compile()
+  hlo = compiled.as_text()
+  plan = compiled.memory_analysis()
+  say(f"  compiled step, per chip: arguments "
+      f"{plan.argument_size_in_bytes / 2 ** 30:.2f} GiB + temporaries "
+      f"{plan.temp_size_in_bytes / 2 ** 30:.2f} GiB (XLA's plan)")
+  if not sizes.rehearsal:
+    calls = hlo.count(MOSAIC_CALL)
+    check(calls == 3 * cfg.num_layers,
+          f"{calls} Mosaic custom calls in the train step, expected "
+          f"{3 * cfg.num_layers} (fwd, dk/dv, dq per layer)")
+  return state, batch, losses, hlo
+
+
+def peak_hbm(dev, at_least: int) -> str:
+  """Device memory high-water marks since the process started, as the
+  backend reports them, in words: resident buffers
+  (``peak_bytes_in_use`` — a real number, at least what the state
+  takes) and what it reserved to run programs (``peak_bytes_reserved``:
+  the largest step's temporaries).  Within one phase they add up to the
+  peak; across phases each mark may come from a different program."""
+  stats = dev.memory_stats()
+  check(stats is not None, f"memory_stats() is None on {dev}")
+  used = stats["peak_bytes_in_use"]
+  check(used >= at_least,
+        f"peak_bytes_in_use {used} on {dev} is below the {at_least} "
+        "bytes the state alone takes")
+  gib = 2 ** 30
+  return (f"{used / gib:.2f} in use, "
+          f"{stats['peak_bytes_reserved'] / gib:.2f} reserved, limit "
+          f"{stats['bytes_limit'] / gib:.2f}")
+
+
+def phase_train(sizes: Sizes, dev) -> None:
+  state, batch, _, _ = run_trainer(sizes, [dev])
+  if not sizes.rehearsal:
+    say(f"  peak HBM GiB at batch {batch['ids'].shape[0]}: "
+        f"{peak_hbm(dev, tree_bytes(state))} (memory_stats)")
+  say("PASS train: loss finite and falling, zero recompiles after "
+      "warm-up"
+      + ("" if sizes.rehearsal else ", Mosaic calls present, real peak "
+                                    "HBM"))
+
+
+# ------------------------------------------------------------------ serve --
+
+
+def seeded_requests(sizes: Sizes, cfg: GPTConfig, n: int = 8):
+  r = np.random.RandomState(0)
+  lens = [sizes.prompt_lens[i % len(sizes.prompt_lens)] for i in range(n)]
+  return [r.randint(0, cfg.vocab_size, (n_tok,)).astype(np.int32)
+          for n_tok in r.permutation(lens)]
+
+
+class _StepSpecs(chaos._StepFnWrapper):
+  """Remembers the fused step's argument specs, so the program the
+  engine compiled can be lowered again and read."""
+  specs = None
+
+  def __call__(self, *args):
+    if self.specs is None:
+      self.specs = specs_of(args)
+    return self.inner(*args)
+
+
+def serve(model, params, prompts, new_tokens: int, paged: bool,
+          rehearsal: bool):
+  """All requests through one engine at the default ``serving.*``
+  config, to completion.  Returns ``{uid: prompt + generated}``."""
+  eng = ContinuousBatchingEngine(model, params, paged=paged)
+  spy = _StepSpecs(eng)
+  for uid, p in enumerate(prompts):
+    check(eng.submit(Request(uid=uid, prompt=p,
+                             max_new_tokens=new_tokens)),
+          f"request {uid} refused at admission")
+  out = eng.run()
+  for uid, p in enumerate(prompts):
+    check(uid in out, f"request {uid} never finished")
+    check(eng.finished[uid].finish_reason == "length"
+          and len(out[uid]) == len(p) + new_tokens,
+          f"request {uid}: {eng.finished[uid].finish_reason}, "
+          f"{len(out[uid])} tokens for a {len(p)}-token prompt")
+    check((out[uid][:len(p)] == p).all(), f"request {uid}: prompt changed")
+  check(spy._cache_size() == 1,
+        f"fused step compiled {spy._cache_size()} times")
+  if paged and not rehearsal:
+    check(eng._paged_impl == "pallas",
+          f"paged attend resolved to {eng._paged_impl!r}, not the kernel")
+    calls = spy.inner.lower(*spy.specs).compile().as_text().count(
+        MOSAIC_CALL)
+    check(calls == model.cfg.num_layers,
+          f"{calls} Mosaic custom calls in the fused paged step, "
+          f"expected one per layer ({model.cfg.num_layers})")
+  return out
+
+
+def reference_streams(model, params, prompts, new_tokens: int):
+  gen = jax.jit(lambda p, ids: generate(model, p, ids, new_tokens))
+  return [np.asarray(gen(params, jnp.asarray(p)[None]))[0]
+          for p in prompts]
+
+
+def first_difference(a, b):
+  diff = np.nonzero(np.asarray(a) != np.asarray(b))[0]
+  return int(diff[0]) if diff.size else None
+
+
+def padded_forward(model, params, streams):
+  """Teacher-forced logits of every stream in one [n, max_seq_len]
+  forward (causal: the padding cannot reach a real position).  Returns
+  per-position ``(argmax, all-finite, top-2 gap)``."""
+  ids = np.zeros((len(streams), model.cfg.max_seq_len), np.int32)
+  for i, s in enumerate(streams):
+    ids[i, :len(s)] = s
+
+  @jax.jit
+  def fwd(p, ids):
+    logits = model.apply({"params": p}, ids).astype(jnp.float32)
+    top2 = jax.lax.top_k(logits, 2)[0]
+    return (jnp.argmax(logits, -1), jnp.isfinite(logits).all(-1),
+            top2[..., 0] - top2[..., 1], jnp.abs(logits).max(-1))
+
+  return [np.asarray(x) for x in fwd(params, jnp.asarray(ids))]
+
+
+def phase_serve(sizes: Sizes) -> None:
+  epl.init(devices=jax.devices()[:1])
+  init = lambda model: jax.jit(lambda k: model.init(
+      k, jnp.zeros((1, 8), jnp.int32))["params"])(jax.random.PRNGKey(0))
+  new = sizes.new_tokens
+
+  # Gate: a float32 2-layer cut of the same width must reproduce
+  # generate(use_cache=True) token for token, contiguous and paged.  A
+  # stream may differ only where the reference's own top-2 logits tie
+  # within float32 rounding.
+  with jax.default_matmul_precision("highest"):
+    model = GPT(sizes.cut_cfg)
+    params = init(model)
+    prompts = seeded_requests(sizes, sizes.cut_cfg)
+    want = reference_streams(model, params, prompts, new)
+    gap = scale = None
+    for paged in (False, True):
+      got = serve(model, params, prompts, new, paged, sizes.rehearsal)
+      for uid, ref in enumerate(want):
+        at = first_difference(got[uid], ref)
+        if at is None:
+          continue
+        if gap is None:
+          _, _, gap, scale = padded_forward(model, params, want)
+        tie = 1e-5 * max(1.0, float(scale[uid, at - 1]))
+        say(f"  float32 cut, {'paged' if paged else 'contiguous'}, "
+            f"request {uid}: differs at position {at}; reference top-2 "
+            f"gap there {gap[uid, at - 1]:.3g} (tie below {tie:.3g})")
+        check(gap[uid, at - 1] <= tie,
+              f"request {uid} emitted a wrong token at position {at}")
+      say(f"  float32 {sizes.cut_cfg.num_layers}-layer cut, "
+          f"{'paged' if paged else 'contiguous'}: equal to "
+          "generate(use_cache=True) up to float32 ties")
+
+  # The full-depth bf16 server.  Bit-equality across batch shapes on the
+  # MXU is reported, not gated.
+  model = GPT(sizes.serve_cfg)
+  params = init(model)
+  prompts = seeded_requests(sizes, sizes.serve_cfg)
+  want = reference_streams(model, params, prompts, new)
+  for paged in (False, True):
+    name = "paged" if paged else "contiguous"
+    got = serve(model, params, prompts, new, paged, sizes.rehearsal)
+    streams = [got[uid] for uid in range(len(prompts))]
+    argmax, finite, _, _ = padded_forward(model, params, streams)
+    same = agree = 0
+    for uid, (p, s) in enumerate(zip(prompts, streams)):
+      rows = slice(len(p) - 1, len(s) - 1)      # logits that chose s[len(p):]
+      check(finite[uid, rows].all(),
+            f"{name} request {uid}: non-finite logits on its stream")
+      agree += int((argmax[uid, rows] == s[len(p):]).sum())
+      same += first_difference(s, want[uid]) is None
+    total = len(prompts) * new
+    say(f"  {model.cfg.num_layers}-layer "
+        f"{jnp.dtype(model.cfg.dtype).name} {name}: {same}/{len(prompts)} "
+        "streams equal generate(use_cache=True); teacher-forced argmax "
+        f"agrees on {agree}/{total} generated tokens (not gated)")
+    say(f"PASS serve {name}: {len(prompts)} requests finished at the "
+        "right length with finite logits, fused step compiled once"
+        + (", paged attend is the compiled kernel"
+           if paged and not sizes.rehearsal else ""))
+
+
+# ------------------------------------------------------------- four chips --
+
+
+def one_chip_loss(sizes: Sizes, dev, batch) -> float:
+  """The loss of the freshly initialised model on ``batch``, computed on
+  one chip in slices that fit it."""
+  epl.init(devices=[dev])
+  model = GPT(sizes.train_cfg)
+  ids = np.asarray(batch["ids"])
+  per = sizes.batch_candidates[-1]
+  params = jax.jit(lambda k: model.init(
+      k, jnp.zeros((per, model.cfg.max_seq_len), jnp.int32))["params"])(
+          jax.random.PRNGKey(0))
+  loss = jax.jit(lambda p, b: gpt_loss(model, p, b,
+                                       jax.random.PRNGKey(0))[0])
+  parts = [float(loss(params, {"ids": jnp.asarray(ids[i:i + per])}))
+           for i in range(0, len(ids), per)]
+  return float(np.mean(parts))
+
+
+def check_row_overlap(devices) -> None:
+  """The row-parallel Dense's ring (communication.overlap) on a pure-TP
+  mesh at the model's width, against the fused program."""
+  from easyparallellibrary_tpu import ops
+  from flax import linen as nn
+
+  class Net(nn.Module):
+    @nn.compact
+    def __call__(self, x):
+      with epl.split():
+        h = ops.Dense(4096, parallel="column")(x)
+        return ops.Dense(1024, parallel="row")(nn.relu(h))
+
+  x = jnp.asarray(np.random.RandomState(0).randn(512, 1024), jnp.float32)
+  outs = {}
+  for mode in ("off", "on", "auto"):
+    epl.init(epl.Config({"communication.overlap": mode}), devices=devices)
+    with epl.split():
+      pass
+    epl.current_plan().build_mesh()
+    model = Net()
+    params = nn.meta.unbox(model.init(jax.random.PRNGKey(0), x)["params"])
+    with jax.default_matmul_precision("highest"):
+      outs[mode] = jax.jit(
+          lambda p, x: model.apply({"params": p}, x))(params, x)
+  for mode in ("on", "auto"):
+    err = rel_err(outs[mode], outs["off"])
+    check(err <= 1e-5, f"overlap={mode} differs from fused by {err:.3g}")
+  say(f"  row-parallel Dense on model:{len(devices)}: overlap on/auto "
+      "equal the fused program")
+
+
+def phase_four_chips(sizes: Sizes) -> None:
+  devices = jax.devices()[:4]
+  cfg = sizes.train_cfg
+  refs = {}
+  for name, tp in (("data:4", False), ("data:2,model:2", True)):
+    say(f"  -- {name}")
+    state, batch, losses, hlo = run_trainer(sizes, devices, tp)
+    for path, leaf in jax.tree_util.tree_leaves_with_path(state):
+      placed = {s.device for s in leaf.addressable_shards}
+      check(placed == set(devices),
+            f"{jax.tree_util.keystr(path)} sits on {len(placed)} devices")
+    if not sizes.rehearsal:
+      for d in devices:
+        say(f"  peak HBM GiB on chip {d.id}: "
+            f"{peak_hbm(d, tree_bytes(state) // 4)}")
+      B, dp, mp = batch["ids"].shape[0], (2 if tp else 4), (2 if tp else 1)
+      want = (f"bf16[{B // dp},{cfg.num_heads // mp},"
+              f"{cfg.max_seq_len},{cfg.d_model // cfg.num_heads}]")
+      operands = flash_call_operands(hlo)
+      say(f"  flash custom-call operands: {operands}")
+      check(all(op == want for ops in operands for op in ops
+                if op.startswith("bf16")),
+            f"flash operands are not the per-chip shard {want}")
+      gathers = sorted(set(re.findall(
+          r"= (\S+?)\{[^ ]* all-gather(?:-start)?\(", hlo)))
+      say(f"  all-gather results in the step: {gathers or 'none'}")
+    B = batch["ids"].shape[0]
+    if B not in refs:
+      refs[B] = one_chip_loss(sizes, devices[0], batch)
+    tol = 1e-2 if cfg.dtype == jnp.bfloat16 else 1e-4
+    say(f"  first-step loss {losses[0]:.5f}, one chip on the same "
+        f"global batch {refs[B]:.5f} (tol {tol})")
+    check(abs(losses[0] - refs[B]) <= tol,
+          f"{name}: first-step loss {losses[0]} vs one chip {refs[B]}")
+    del state
+  check_row_overlap(devices)
+  say("PASS four chips: data:4 and data:2,model:2 took their steps, "
+      "state on four devices, losses agree with one chip, flash "
+      "operands are per-chip shards, overlap on/auto does not raise")
+
+
+# ------------------------------------------------------------------- main --
+
+
+def main(argv=None) -> int:
+  parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+  parser.add_argument(
+      "--rehearse-cpu", action="store_true",
+      help="toy sizes, interpreted kernels, on the CPU; always exits "
+           f"{REHEARSAL_EXIT}; not a pass")
+  args = parser.parse_args(argv)
+  t_start = time.perf_counter()
+  cache_dir = compile_cache.configure()
+
+  dev = jax.devices()[0]
+  count = len(jax.devices())
+  say(f"jax {jax.__version__}")
+  say(f"platform {dev.platform}")
+  say(f"device_kind {dev.device_kind}")
+  say(f"device_count {count}")
+  say(f"compile cache {cache_dir}")
+  if args.rehearse_cpu:
+    say("REHEARSAL: toy sizes, interpreted kernels, whatever platform "
+        "this is.  Nothing below is a pass or a measurement.")
+    sizes = Sizes.toy()
+  else:
+    bench.require_tpu()
+    sizes = Sizes.real()
+
+  for name, phase in (("kernels", lambda: phase_kernels(sizes)),
+                      ("train", lambda: phase_train(sizes, dev)),
+                      ("serve", lambda: phase_serve(sizes))):
+    t0 = time.perf_counter()
+    say(f"== {name}")
+    phase()
+    say(f"   ({name}: {time.perf_counter() - t0:.1f} s)")
+  if count >= 4:
+    t0 = time.perf_counter()
+    say("== four chips")
+    phase_four_chips(sizes)
+    say(f"   (four chips: {time.perf_counter() - t0:.1f} s)")
+  else:
+    say(f"== four chips: skipped, the machine has {count} device(s)")
+  say(f"total {time.perf_counter() - t_start:.1f} s")
+
+  if args.rehearse_cpu:
+    say("REHEARSAL complete: every phase ran.  This is not a pass; "
+        f"exiting {REHEARSAL_EXIT}.")
+    return REHEARSAL_EXIT
+  print(json.dumps({"ok": True, "device": {
+      "platform": dev.platform, "kind": dev.device_kind, "count": count}}),
+        flush=True)
+  return 0
+
+
+if __name__ == "__main__":
+  sys.exit(main())

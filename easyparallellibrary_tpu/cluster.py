@@ -62,10 +62,10 @@ def _build_device_array(devices: List[jax.Device],
 
   On TPU, delegate to ``mesh_utils.create_device_mesh`` for ICI-topology-aware
   placement (the reference's AwareRowLayout host-reordering role,
-  epl/cluster.py:193-241).  On CPU/virtual platforms fall back to row-major
-  reshape; with ``prefer_intra_node`` the innermost axes vary fastest within
-  a process, mirroring ``device_place_prefer_intra_node``
-  (epl/cluster.py:137).
+  epl/cluster.py:193-241); a shape it cannot place raises.  On CPU/virtual
+  platforms the layout is a row-major reshape; with ``prefer_intra_node``
+  the innermost axes vary fastest within a process, mirroring
+  ``device_place_prefer_intra_node`` (epl/cluster.py:137).
   """
   shape = tuple(shape)
   n = math.prod(shape)
@@ -74,11 +74,8 @@ def _build_device_array(devices: List[jax.Device],
                      f"have {len(devices)}")
   platform = devices[0].platform if devices else "cpu"
   if platform == "tpu" and n > 1:
-    try:
-      from jax.experimental import mesh_utils
-      return mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:  # pragma: no cover - topology helpers can be picky
-      pass
+    from jax.experimental import mesh_utils
+    return mesh_utils.create_device_mesh(shape, devices=devices)
   order = sorted(devices, key=lambda d: (d.process_index, d.id)) \
       if prefer_intra_node else list(devices)
   return np.array(order, dtype=object).reshape(shape)

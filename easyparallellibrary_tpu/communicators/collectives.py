@@ -48,8 +48,7 @@ def axis_index(axis_name: str):
 
 
 def axis_size(axis_name: str) -> int:
-  from easyparallellibrary_tpu.utils.compat import axis_size as _axis_size
-  return _axis_size(axis_name)
+  return lax.axis_size(axis_name)
 
 
 def all_reduce(x, axis_name: str, op: str = SUM):

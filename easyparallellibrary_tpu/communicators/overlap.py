@@ -50,8 +50,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from easyparallellibrary_tpu.utils.compat import axis_size as _axis_size
-
 
 def ring_step(x, axis_name: str, n: Optional[int] = None):
   """One ring hop: device d's value moves to d+1 (so after t hops the
@@ -60,7 +58,7 @@ def ring_step(x, axis_name: str, n: Optional[int] = None):
   collective-matmuls here and the seq-manual ring-attention rotation
   (sequence/ring_attention.py) walk the same ring."""
   if n is None:
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
   return lax.ppermute(x, axis_name, [(i, (i + 1) % n) for i in range(n)])
 
 
@@ -97,7 +95,7 @@ def all_gather_matmul(x, w, axis_name: str, num_chunks: int = 0):
   if x.ndim != 2 or w.ndim != 2:
     raise ValueError(f"all_gather_matmul wants rank-2 operands; got "
                      f"{x.shape} @ {w.shape}")
-  n = _axis_size(axis_name)
+  n = lax.axis_size(axis_name)
   K = normalize_chunks(num_chunks, n)
   if K <= 1:
     return jnp.matmul(lax.all_gather(x, axis_name, axis=0, tiled=True), w)
@@ -167,7 +165,7 @@ def matmul_reduce_scatter(x, w, axis_name: str, num_chunks: int = 0):
   if x.ndim != 2 or w.ndim != 2:
     raise ValueError(f"matmul_reduce_scatter wants rank-2 operands; got "
                      f"{x.shape} @ {w.shape}")
-  n = _axis_size(axis_name)
+  n = lax.axis_size(axis_name)
   K = normalize_chunks(num_chunks, n)
   if K <= 1:
     return lax.psum_scatter(jnp.matmul(x, w), axis_name,
@@ -222,7 +220,7 @@ def reduce_scatter(x, axis_name: str, axis: int = 0, num_chunks: int = 0):
   <= 1 emits the fused ``psum_scatter``.  Chunk-count policy still flows
   through so call sites read uniformly, but only its sign matters.
   """
-  n = _axis_size(axis_name)
+  n = lax.axis_size(axis_name)
   K = normalize_chunks(num_chunks, n)
   if K <= 1:
     return lax.psum_scatter(x, axis_name, scatter_dimension=axis,

@@ -39,6 +39,7 @@ tests exercise identical code paths on CPU.
 from __future__ import annotations
 
 import functools
+import json
 import os
 from typing import Optional
 
@@ -48,6 +49,12 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from easyparallellibrary_tpu import constants
+from easyparallellibrary_tpu.env import Env
+from easyparallellibrary_tpu.utils.compat import (
+    ambient_manual_axes, shard_map)
 
 NEG_INF = -1e30
 
@@ -672,17 +679,15 @@ def _ensure_block_table() -> dict:
   _BLOCK_TABLE = {}
   try:
     with open(_BLOCK_TABLE_PATH) as f:
-      raw = __import__("json").load(f)
-    entries = raw.get("entries") if isinstance(raw, dict) else None
-    device = raw.get("device") if isinstance(raw, dict) else None
-    if isinstance(entries, dict) and device == jax.devices()[0].device_kind:
-      for key, want in entries.items():
-        s_, d_, it_ = (int(x) for x in key.split(":"))
-        _BLOCK_TABLE[(s_, d_, it_)] = int(want)
-  except Exception:
-    # Any malformed/foreign table falls back to the heuristic silently —
-    # the table is an optimization, never a correctness dependency.
-    _BLOCK_TABLE = {}
+      raw = json.load(f)
+  except FileNotFoundError:
+    # No table shipped: the heuristic stands.  A table that IS there
+    # but malformed raises — it was written to be used.
+    return _BLOCK_TABLE
+  if raw["device"] == jax.devices()[0].device_kind:
+    for key, want in raw["entries"].items():
+      s_, d_, it_ = (int(x) for x in key.split(":"))
+      _BLOCK_TABLE[(s_, d_, it_)] = int(want)
   return _BLOCK_TABLE
 
 
@@ -732,6 +737,29 @@ def flash_blockable(S: int, *, d: int, itemsize: int = 2) -> bool:
   return _default_block(S, d=d, itemsize=itemsize) > 0
 
 
+def _mesh_shard_spec(B: int, H: int):
+  """``(mesh, spec)`` for running the kernel per chip, or None.
+
+  The SPMD partitioner cannot split a Mosaic custom call (jax refuses to
+  lower one outside a manual region on a multi-device mesh), so on a
+  built mesh of more than one device the kernel entry runs inside a
+  ``shard_map``: batch over ``data`` and heads over ``model`` — the
+  layout the models constrain q/k/v to — and replicated over every
+  other axis.  A dim its axis does not divide stays whole on each chip.
+  Inside an ambient manual region (ring / Ulysses / the smap engines)
+  the caller already holds per-shard values: no wrap."""
+  cluster = Env.get().cluster
+  mesh = cluster.built_mesh if cluster is not None else None
+  if mesh is None or mesh.size == 1 or ambient_manual_axes():
+    return None
+  sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+  b_axis = (constants.DATA_AXIS
+            if B % sizes[constants.DATA_AXIS] == 0 else None)
+  h_axis = (constants.MODEL_AXIS
+            if H % sizes[constants.MODEL_AXIS] == 0 else None)
+  return mesh, P(b_axis, None, h_axis, None)
+
+
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None):
@@ -745,6 +773,9 @@ def flash_attention(q, k, v, causal: bool = True,
   (fewer grid invocations amortize per-call overhead and the [512, 512]
   score tile keeps the MXU busy); still comfortably within VMEM (score
   tile 1 MB fp32 + K/V blocks 128 KB).
+
+  On a multi-device mesh each chip runs the kernel on its own
+  batch/head shard (:func:`_mesh_shard_spec`).
   """
   B, S, H, D = q.shape
   bq = (min(block_q, S) if block_q else
@@ -753,9 +784,16 @@ def flash_attention(q, k, v, causal: bool = True,
         _default_block(S, d=D, itemsize=q.dtype.itemsize))
   if not bq or not bk or S % bq or S % bk:
     raise ValueError(f"block sizes ({bq}, {bk}) must divide seq len {S}")
-  # Kernels use [B, H, S, D] layout.
-  qt = q.transpose(0, 2, 1, 3)
-  kt = k.transpose(0, 2, 1, 3)
-  vt = v.transpose(0, 2, 1, 3)
-  out = _flash(qt, kt, vt, causal, bq, bk)
-  return out.transpose(0, 2, 1, 3)
+
+  def per_shard(q, k, v):
+    # Kernels use [B, H, S, D] layout.
+    out = _flash(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                 v.transpose(0, 2, 1, 3), causal, bq, bk)
+    return out.transpose(0, 2, 1, 3)
+
+  sharded = _mesh_shard_spec(B, H)
+  if sharded is None:
+    return per_shard(q, k, v)
+  mesh, spec = sharded
+  return shard_map(per_shard, mesh, in_specs=(spec, spec, spec),
+                   out_specs=spec)(q, k, v)

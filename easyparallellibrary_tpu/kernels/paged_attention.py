@@ -14,10 +14,10 @@ implementations of that gather-attend, behind one dispatcher:
   ``generate(use_cache=True)`` is carried by this implementation, and
   the TPU kernel is tested against it (tests/test_serving_paged.py).
 * **pallas** — a streaming TPU kernel in the flash-attention house
-  style (kernels/flash_attention.py): grid ``(T, H, MB)``, the block
-  table scalar-prefetched so each KV block's DMA is issued straight from
-  the table entry, online softmax carried across the MB grid steps in
-  VMEM scratch.  Under the per-token causal bound the block index map
+  style (kernels/flash_attention.py): grid ``(T, MB)`` with every head
+  of a KV block in one program, the block table scalar-prefetched so
+  each KV block's DMA is issued straight from the table entry, online
+  softmax carried across the MB grid steps in VMEM scratch.  Under the per-token causal bound the block index map
   clamps to the last live block (Mosaic elides the repeated DMA) and
   ``pl.when`` skips the dead compute — so a token's attend costs its own
   context length, not the table width.
@@ -119,11 +119,17 @@ def paged_attention_reference(q, k_pages, v_pages, tables_tok, positions):
 def _paged_kernel(tab_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, bs: int, num_blocks_grid: int,
                   scale: float):
-  """One (token, head, table-slot) grid step: score this KV block
-  against the token's query row, fold into the online softmax carried in
-  VMEM scratch, emit on the last table slot."""
+  """One (token, table-slot) grid step: score this KV block against the
+  token's query rows for ALL heads, fold into the online softmax carried
+  in VMEM scratch, emit on the last table slot.
+
+  Every value keeps heads on the sublane dim and head_dim on the lane
+  dim (``[bs, H, hd]`` tiles, ``[.., H, 1]`` row statistics), so the
+  body is elementwise VPU work plus lane/major-dim reductions — no
+  per-head slicing and no layout change.  Decode attention is bandwidth-bound
+  (one query row per head); the MXU has nothing to amortize here."""
   t = pl.program_id(0)
-  i = pl.program_id(2)
+  i = pl.program_id(1)
 
   @pl.when(i == 0)
   def _init():
@@ -138,28 +144,25 @@ def _paged_kernel(tab_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
   @pl.when(live)
   def _compute():
-    q = q_ref[0]                                    # [1, hd]
-    k = k_ref[0, :, 0, :]                           # [bs, hd]
-    v = v_ref[0, :, 0, :]
-    s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    row = i * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
-    s = jnp.where(row <= pos, s, NEG_INF)           # [bs, 1]
-    m_prev = m_ref[0:1, 0:1]                        # [1, 1]
-    l_prev = l_ref[0:1, 0:1]
-    new_m = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-    p = jnp.exp(s - new_m)                          # [bs, 1]
-    corr = jnp.exp(m_prev - new_m)                  # [1, 1]
-    new_l = l_prev * corr + jnp.sum(p, axis=0, keepdims=True)
+    q = q_ref[...].astype(jnp.float32)              # [1, H, hd]
+    k = k_ref[0].astype(jnp.float32)                # [bs, H, hd]
+    v = v_ref[0].astype(jnp.float32)
+    s = jnp.sum(q * k, axis=-1, keepdims=True) * scale      # [bs, H, 1]
+    row = i * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    s = jnp.where(row <= pos, s, NEG_INF)
+    m_prev = m_ref[...][:, :1]                      # [H, 1]
+    l_prev = l_ref[...][:, :1]
+    new_m = jnp.maximum(m_prev, jnp.max(s, axis=0))
+    p = jnp.exp(s - new_m[None])                    # [bs, H, 1]
+    corr = jnp.exp(m_prev - new_m)                  # [H, 1]
+    new_l = l_prev * corr + jnp.sum(p, axis=0)
     m_ref[...] = jnp.broadcast_to(new_m, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(new_l, l_ref.shape)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * corr + jnp.sum(p * v, axis=0)
 
   @pl.when(i == num_blocks_grid - 1)
   def _finalize():
-    l_safe = jnp.maximum(l_ref[0:1, 0:1], 1e-30)
+    l_safe = jnp.maximum(l_ref[...][:, :1], 1e-30)
     o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
@@ -177,36 +180,40 @@ def paged_attention_pallas(q, k_pages, v_pages, tables_tok, positions,
   # The index maps receive the scalar-prefetch refs after the grid
   # coordinates; dead blocks clamp to the token's last live table slot
   # so Mosaic elides the repeated DMA.
-  def kv_idx(t, h, i, tab, pos):
+  def kv_idx(t, i, tab, pos):
     i = jnp.minimum(i, pos[t] // bs)
-    return (tab[t, i], 0, h, 0)
+    return (tab[t, i], 0, 0, 0)
 
+  # Mosaic wants the last two block dims tile-aligned or FULL: blocks
+  # span every head (H, hd are the arrays' own trailing dims) and the
+  # head loop lives inside the program.
   grid_spec = pltpu.PrefetchScalarGridSpec(
       num_scalar_prefetch=2,
-      grid=(T, H, MB),
+      grid=(T, MB),
       in_specs=[
-          pl.BlockSpec((1, 1, hd), lambda t, h, i, tab, pos: (t, h, 0)),
-          pl.BlockSpec((1, bs, 1, hd), kv_idx),
-          pl.BlockSpec((1, bs, 1, hd), kv_idx),
+          pl.BlockSpec((1, H, hd), lambda t, i, tab, pos: (t, 0, 0)),
+          pl.BlockSpec((1, bs, H, hd), kv_idx),
+          pl.BlockSpec((1, bs, H, hd), kv_idx),
       ],
-      out_specs=pl.BlockSpec((1, 1, hd),
-                             lambda t, h, i, tab, pos: (t, h, 0)),
+      out_specs=pl.BlockSpec((1, H, hd),
+                             lambda t, i, tab, pos: (t, 0, 0)),
       scratch_shapes=[
-          pltpu.VMEM((8, 128), jnp.float32),      # running max
-          pltpu.VMEM((8, 128), jnp.float32),      # running denom
-          pltpu.VMEM((1, hd), jnp.float32),       # output accumulator
+          pltpu.VMEM((H, 128), jnp.float32),      # running max
+          pltpu.VMEM((H, 128), jnp.float32),      # running denom
+          pltpu.VMEM((H, hd), jnp.float32),       # output accumulator
       ],
   )
   kwargs = {}
   if not interpret:
     kwargs["compiler_params"] = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel", "arbitrary"))
   return pl.pallas_call(
       functools.partial(_paged_kernel, bs=bs, num_blocks_grid=MB,
                         scale=scale),
       grid_spec=grid_spec,
       out_shape=jax.ShapeDtypeStruct((T, H, hd), q.dtype),
       interpret=interpret,
+      name="paged_attention",
       **kwargs,
   )(tables_tok.astype(jnp.int32), positions.astype(jnp.int32),
     q, k_pages, v_pages)

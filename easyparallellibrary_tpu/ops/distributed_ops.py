@@ -53,8 +53,7 @@ def gather_matmul(x, w, axis_name: str = constants.SEQ_AXIS,
   overlap policy; bit-exact vs the fused gather+matmul."""
   from easyparallellibrary_tpu.communicators import overlap
   from easyparallellibrary_tpu.parallel.planner import SITE_GATHER_MATMUL
-  from easyparallellibrary_tpu.utils.compat import axis_size
-  n = axis_size(axis_name)
+  n = jax.lax.axis_size(axis_name)
   if num_chunks is None:
     num_chunks = overlap.resolve_num_chunks(
         "all_gather_matmul", n, m=x.shape[0], k=x.shape[1],
@@ -70,8 +69,7 @@ def matmul_scatter(x, w, axis_name: str = constants.SEQ_AXIS,
   accumulation-order tolerance vs the fused matmul+psum_scatter."""
   from easyparallellibrary_tpu.communicators import overlap
   from easyparallellibrary_tpu.parallel.planner import SITE_MATMUL_SCATTER
-  from easyparallellibrary_tpu.utils.compat import axis_size
-  n = axis_size(axis_name)
+  n = jax.lax.axis_size(axis_name)
   if num_chunks is None:
     num_chunks = overlap.resolve_num_chunks(
         "matmul_reduce_scatter", n, m=x.shape[0], k=x.shape[1],

@@ -64,17 +64,15 @@ def _row_overlap_chunks(x, padded_in: int, out_features: int) -> int:
   """Ring chunk count for a row-parallel Dense matmul under the
   ``communication.overlap`` policy; 1 = keep the fused GSPMD program.
 
-  The ring runs as an explicit (partial-manual) shard_map over the model
-  axis, so it engages only where that region is well-defined:
+  The ring runs as an explicit shard_map over the model axis, so it
+  engages only where that region is well-defined:
 
     * not already inside a manual region (the smap engines own their
       schedule; a nested ring's whole-mesh permute channels would
       deadlock against their gated ticks);
-    * every mesh axis except ``model`` has size 1 (a collective-permute
-      inside a region with live auto axes trips the older XLA SPMD
-      partitioner — the same constraint the smap engines' stage
-      ppermutes live under; pure-TP meshes are exactly the shape the
-      explicit ``split`` library targets);
+    * every mesh axis except ``model`` has size 1 (the region is
+      full-manual; pure-TP meshes are exactly the shape the explicit
+      ``split`` library targets);
     * the flattened activation rows divide the model axis (the scatter
       grain).
   """
@@ -126,13 +124,16 @@ def _row_overlap_matmul(x, kernel, dtype, num_chunks: int):
     y = jax.lax.all_gather(y, constants.MODEL_AXIS, axis=0, tiled=True)
     return y.reshape(lead + (n_out,))
 
+  # Full-manual region: _row_overlap_chunks engages only where every
+  # other mesh axis has size 1, so leaving them out of the specs is the
+  # same program — and a partial-manual region evaluated eagerly (flax
+  # `init` outside jit) is refused by jax.shard_map's out-spec check.
   nd = len(lead)
   f = shard_map(
       body, mesh,
       in_specs=(P(*([None] * nd), constants.MODEL_AXIS),
                 P(constants.MODEL_AXIS, None)),
-      out_specs=P(*([None] * nd), None),
-      manual_axes=frozenset({constants.MODEL_AXIS}))
+      out_specs=P(*([None] * nd), None))
   return f(x, kernel)
 
 

@@ -128,6 +128,21 @@ DEFAULT_STEP_LATENCY_US = 2.0
 # A chunked matmul loses MXU efficiency once chunks get skinny; modeled
 # as a fixed per-chunk re-issue cost.
 DEFAULT_CHUNK_OVERHEAD_US = 1.0
+# Off-TPU (the CPU test mesh) the crossover is still computed, as a
+# model of the chip the program is written for.
+MODEL_DEVICE_KIND = "TPU v5e"
+
+
+def _model_peak_flops() -> float:
+  """Peak FLOP/s the analytic crossover divides by: the running chip's
+  own table entry on TPU (an unknown kind raises there), the named
+  model device's everywhere else."""
+  import jax
+  from easyparallellibrary_tpu.profiler.flops import (
+      PEAK_FLOPS, peak_flops_per_chip)
+  if jax.default_backend() == "tpu":
+    return peak_flops_per_chip()
+  return PEAK_FLOPS[MODEL_DEVICE_KIND]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -198,11 +213,7 @@ def plan_collective_matmul(kind: str, *, m: int, k: int, n_out: int,
   if n <= 1:
     return OverlapDecision(False, 1, 0.0, 0.0, 0.0, 0.0)
   if peak_flops is None:
-    from easyparallellibrary_tpu.profiler.flops import peak_flops_per_chip
-    try:
-      peak_flops = peak_flops_per_chip()
-    except Exception:
-      peak_flops = 197e12
+    peak_flops = _model_peak_flops()
 
   if kind == "all_gather_matmul":
     # Ring moves (n-1) local shards past each device; the matmul is the
@@ -252,12 +263,11 @@ def plan_collective_matmul_from_cost(fn: Callable, *sample_args,
   T_fused / T_overlap(K) model.  This is the profiled-cost twin of
   :func:`plan_collective_matmul`, the same relationship
   ``search_from_cost_model`` has to ``search``."""
-  from easyparallellibrary_tpu.profiler.flops import (
-      compiled_cost, peak_flops_per_chip)
+  from easyparallellibrary_tpu.profiler.flops import compiled_cost
   cost = compiled_cost(fn, *sample_args)
   flops = float(cost.get("flops", 0.0)) or 1.0
   bytes_out = float(cost.get("bytes accessed", 0.0))
-  peak = model_kwargs.pop("peak_flops", None) or peak_flops_per_chip()
+  peak = model_kwargs.pop("peak_flops", None) or _model_peak_flops()
   # Back out effective dims for the analytic model: treat the measured
   # flops as one [m, k] @ [k, n_out] with the caller's k/n_out hints, or
   # fall back to a square split.

@@ -17,7 +17,8 @@ import jax
 
 from easyparallellibrary_tpu.utils.logging import get_logger
 
-# Peak bf16 FLOP/s per chip by device kind (public TPU specs).
+# Peak bf16 FLOP/s per chip by ``device_kind`` prefix (public TPU specs;
+# a v5e chip reports "TPU v5 lite").
 PEAK_FLOPS = {
     "TPU v2": 46e12,
     "TPU v3": 123e12,
@@ -32,19 +33,20 @@ PEAK_FLOPS = {
 
 
 def peak_flops_info(device: Optional[jax.Device] = None
-                    ) -> "tuple[float, bool]":
-  """(peak bf16 FLOP/s, recognized?) for the device kind.  The single
+                    ) -> "tuple[float, str]":
+  """(peak bf16 FLOP/s, table key) for the device kind.  The single
   source of truth for every MFU denominator in the repo (bench.py imports
-  this — the tables must not fork and drift)."""
+  this — the tables must not fork and drift).  A device kind the table
+  does not know raises: an MFU against a guessed peak is not an MFU."""
   device = device or jax.devices()[0]
   kind = device.device_kind
   for name, flops in sorted(PEAK_FLOPS.items(), key=lambda kv: -len(kv[0])):
     if kind.startswith(name):
-      return flops, True
-  get_logger().warning("unknown device kind %r; assuming 197 TFLOP/s — "
-                       "MFU numbers against this denominator are guesses",
-                       kind)
-  return 197e12, False
+      return flops, name
+  raise ValueError(
+      f"no peak FLOP/s on record for device kind {kind!r} (platform "
+      f"{device.platform!r}); add it to profiler.flops.PEAK_FLOPS with "
+      f"its source before reporting utilization on it")
 
 
 def peak_flops_per_chip(device: Optional[jax.Device] = None) -> float:
@@ -134,6 +136,9 @@ def collective_bytes(fn: Callable, *args, **kwargs) -> float:
 
 def estimate_mfu(flops_per_step: float, step_time_s: float,
                  n_chips: Optional[int] = None) -> float:
+  """Achieved over peak FLOP/s; raises on a device without a peak on
+  record (:func:`peak_flops_info`) — the profilers report ``mfu`` only
+  on TPU, a CPU run has none."""
   n_chips = n_chips or len(jax.devices())
   achieved = flops_per_step / max(step_time_s, 1e-12)
   return achieved / (peak_flops_per_chip() * n_chips)
@@ -210,7 +215,8 @@ class FlopsProfiler:
     stats = {"step_time_s": dt, "steps_per_sec": 1.0 / dt}
     if self.flops_per_step:
       stats["gflops_per_step"] = self.flops_per_step / 1e9
-      stats["mfu"] = estimate_mfu(self.flops_per_step, dt)
+      if jax.default_backend() == "tpu":
+        stats["mfu"] = estimate_mfu(self.flops_per_step, dt)
     if self.comm_bytes_per_step:
       stats["comm_gb_per_step"] = self.comm_bytes_per_step / 1e9
       # Wire-time share of the step at the modeled link bandwidth; the

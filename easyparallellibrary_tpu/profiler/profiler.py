@@ -13,6 +13,8 @@ from __future__ import annotations
 import time
 from typing import Callable, Dict, Optional
 
+import jax
+
 from easyparallellibrary_tpu.parallel.pipeline import bubble_fraction
 from easyparallellibrary_tpu.profiler.flops import (
     compiled_cost, estimate_mfu)
@@ -83,7 +85,7 @@ class StepProfiler:
     out = {"step_time_s": dt, "steps_per_sec": 1.0 / dt}
     if self.tokens_per_step:
       out["tokens_per_sec"] = self.tokens_per_step / dt
-    if self.flops_per_step:
+    if self.flops_per_step and jax.default_backend() == "tpu":
       out["mfu"] = estimate_mfu(self.flops_per_step, dt)
     if self.bad_steps:
       out["bad_steps"] = float(self.bad_steps)

@@ -479,7 +479,7 @@ class ContinuousBatchingEngine:
                 f"{self._paged_impl} attend, "
                 f"{kv_lib.paged_cache_bytes(cfg, self.num_blocks, self.block_size) / 1e6:.1f} MB")
     else:
-      layout = (f"contiguous slots, "
+      layout = (f"contiguous slots, xla attend, "
                 f"{kv_lib.cache_bytes(cfg, self.num_slots, self.chunk) / 1e6:.1f} MB")
     get_logger().info(
         "serving engine: %d slots x chunk %d (%s, %s), "

@@ -87,6 +87,8 @@ from easyparallellibrary_tpu.observability import trace as trace_lib
 from easyparallellibrary_tpu.serving.replica import EngineReplica
 from easyparallellibrary_tpu.serving.scheduler import (
     FinishedRequest, Request)
+from easyparallellibrary_tpu.utils.chip import (
+    ChipOwnershipError, reaches_for_tpu, this_process_holds_tpu)
 from easyparallellibrary_tpu.utils.logging import get_logger
 from easyparallellibrary_tpu.utils.retry import retry_call
 
@@ -492,10 +494,27 @@ class ProcessTransport(ReplicaTransport):
     against cold spawn from evidence."""
     if self.alive:
       return
+    env = dict(os.environ)
+    if reaches_for_tpu(env):
+      # One process per chip (utils/chip.py): refuse here, by name,
+      # what the child would otherwise meet as a failed or hung backend
+      # start-up deep inside its factory.
+      if this_process_holds_tpu():
+        raise ChipOwnershipError(
+            "this process has initialised JAX on the TPU and owns the "
+            "chip; a replica subprocess cannot reach it.  Serve with "
+            "serving.router.transport='inproc' (one process, one device "
+            "per replica), or keep the parent off JAX until the "
+            "replicas are up")
+      if _LIVE_CHILDREN:
+        raise ChipOwnershipError(
+            f"replica {self.index}: another replica process of this "
+            "host already reaches for its TPU chips, and a chip belongs "
+            "to one process.  Serve with serving.router.transport="
+            "'inproc', or set JAX_PLATFORMS=cpu for a CPU fleet")
     t_spawn = time.monotonic()
     parent_sock, child_sock = socket.socketpair()
     try:
-      env = dict(os.environ)
       # The child resolves the package the same way the parent did,
       # even when running from a source checkout that is not installed.
       pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(

@@ -1,18 +1,10 @@
-"""JAX API compatibility layer for the manual-sharding surface.
+"""The manual-sharding surface, spelled once.
 
-The framework targets the modern manual-sharding API (``jax.shard_map``
-with ``axis_names=``/``check_vma=``, ``jax.sharding.get_abstract_mesh``,
-``lax.axis_size``), but must also run on older jax releases where the
-same machinery lives under ``jax.experimental.shard_map.shard_map`` with
-``auto=``/``check_rep=`` and no abstract-mesh introspection.  Every
-module that enters a manual region goes through these wrappers instead
-of touching the jax surface directly, so the old/new split lives in
-exactly one file.
-
-No behavior differences are papered over: both APIs lower to the same
-manual-mesh partitioning; only spelling differs.  ``check`` maps to
-``check_vma`` (new) / ``check_rep`` (old) — the engines disable it for
-the same reason either way (per-device branch divergence is intentional).
+Every module that enters a manual region calls :func:`shard_map` here
+instead of ``jax.shard_map`` directly, so the framework's vocabulary
+(``manual_axes``, ``check``) maps onto jax's (``axis_names``,
+``check_vma``) in exactly one file.  The engines disable the check
+because per-device branch divergence is intentional.
 """
 
 from __future__ import annotations
@@ -20,9 +12,6 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax import lax
-
-_NEW_SHARD_MAP = hasattr(jax, "shard_map")
 
 
 def shard_map(f, mesh, in_specs, out_specs,
@@ -30,64 +19,18 @@ def shard_map(f, mesh, in_specs, out_specs,
               check: bool = False):
   """Manual-map ``f`` over ``mesh``.
 
-  ``manual_axes``: axes the body is manual over (None = all mesh axes —
-  the full-manual default both APIs share).  Partial-manual regions pass
-  a subset; the remaining axes stay auto (GSPMD) inside the body.
+  ``manual_axes``: axes the body is manual over (None = all mesh axes).
+  Partial-manual regions pass a subset; the remaining axes stay auto
+  (GSPMD) inside the body.
   """
-  if _NEW_SHARD_MAP:
-    kwargs = {}
-    if manual_axes is not None:
-      kwargs["axis_names"] = frozenset(manual_axes)
-    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=check, **kwargs)
-  from jax.experimental.shard_map import shard_map as _shard_map
   kwargs = {}
   if manual_axes is not None:
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    # Size-1 auto axes are promoted to manual: semantically identical
-    # (nothing shards over them, unmentioned in_specs dims stay
-    # replicated) and it keeps the region FULL-manual whenever possible,
-    # which the old SPMD partitioner handles robustly.  Genuinely live
-    # auto axes are a hard stop here: the old partitioner either rejects
-    # the region's axis_index (PartitionId: Unimplemented) or CHECK-
-    # aborts the process on its collective-permute/all-to-all — a clean
-    # error beats both.
-    live_auto = sorted(a for a in mesh.axis_names
-                       if a not in manual_axes and sizes.get(a, 1) > 1)
-    if live_auto:
-      raise NotImplementedError(
-          f"partial-manual shard_map with live auto axes {live_auto} "
-          f"(manual over {sorted(manual_axes)}) is not supported by this "
-          "jax/XLA version's SPMD partitioner; upgrade jax, or lay the "
-          "mesh out so the non-manual axes have size 1")
-  return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=check, **kwargs)
-
-
-def axis_size(axis_name: str) -> int:
-  """Size of a named mesh axis from inside a manual region."""
-  if hasattr(lax, "axis_size"):
-    return lax.axis_size(axis_name)
-  # Old-jax spelling: psum of the literal 1 is special-cased to the
-  # concrete axis size (no collective is lowered).
-  return lax.psum(1, axis_name)
+    kwargs["axis_names"] = frozenset(manual_axes)
+  return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=check, **kwargs)
 
 
 def ambient_manual_axes() -> frozenset:
   """Mesh axes that are Manual in the ambient shard_map region (empty
-  outside one).  On old jax there is no abstract-mesh introspection;
-  the bound-axis environment is the equivalent signal (vmap-bound axis
-  names are included, which is the conservative answer for every caller:
-  a named axis that cannot take a global sharding constraint or a nested
-  manual region either way)."""
-  get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-  if get_abstract_mesh is not None:
-    return frozenset(
-        getattr(get_abstract_mesh(), "manual_axes", ()) or ())
-  try:
-    if not jax.core.nonempty_axis_env_DO_NOT_USE():
-      return frozenset()
-    names = jax.core.unsafe_get_axis_names_DO_NOT_USE()
-    return frozenset(n for n in names if isinstance(n, str))
-  except Exception:
-    return frozenset()
+  outside one)."""
+  return frozenset(jax.sharding.get_abstract_mesh().manual_axes)

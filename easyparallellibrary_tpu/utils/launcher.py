@@ -10,7 +10,9 @@ retries once (:125-188).  The TPU-native equivalents:
     with env-var fallbacks (the launcher exports them per process);
   * local multi-process testing (the reference's 2-worker launcher test,
     tests/Makefile:12-13) spawns N processes on CPU with a shared
-    coordinator;
+    coordinator.  On a TPU host one process drives all local chips:
+    several local workers that would each reach for them are refused
+    (utils/chip.py);
   * straggler kill + single retry semantics are preserved.
 
 Console entry: ``epl-tpu-launch --num_workers 2 -- python train.py``.
@@ -26,6 +28,8 @@ import sys
 import time
 from typing import List, Optional
 
+from easyparallellibrary_tpu.utils.chip import (
+    ChipOwnershipError, reaches_for_tpu)
 from easyparallellibrary_tpu.utils.logging import get_logger
 
 
@@ -66,7 +70,18 @@ def launch_local(num_workers: int, command: List[str],
   Returns the exit code (0 = all workers succeeded).  On any worker
   failure, the remaining workers are killed and the whole job is retried
   up to `retries` times (reference launcher.py:168-188).
+
+  Every worker gets the same environment, so on a TPU host each would
+  reach for every local chip: more than one worker is refused unless
+  the environment keeps them off the TPU (``JAX_PLATFORMS=cpu``).
   """
+  if num_workers > 1 and reaches_for_tpu({**os.environ,
+                                          **(extra_env or {})}):
+    raise ChipOwnershipError(
+        f"{num_workers} local workers would each reach for all of this "
+        "host's TPU chips, and a chip belongs to one process: run ONE "
+        "worker per TPU host (it drives every local chip), or set "
+        "JAX_PLATFORMS=cpu for local multi-process runs on the CPU")
   for attempt in range(retries + 1):
     port = _free_port()
     procs = []

@@ -44,7 +44,7 @@ def _batches(files, batch_size=8, seq=16, use_native=True):
   return gen
 
 
-def test_native_reader_feeds_training(tmp_path):
+def test_native_reader_feeds_training(tmp_path, native_io):
   assert native_io_available()
   env = epl.init()
   mesh = epl.current_plan().build_mesh()

@@ -34,8 +34,8 @@ def _make_files(tmp_path, n_files=4, recs_per_file=5):
   return files
 
 
-def test_native_io_built():
-  assert native_io_available(), "run `make build` to compile csrc/"
+def test_native_io_built(native_io):
+  assert native_io_available(), "csrc/ built but the library did not load"
 
 
 @pytest.mark.parametrize("use_native", [True, False])
@@ -159,7 +159,8 @@ def test_step_profiler_summary():
   s = prof.summary()
   assert s["step_time_s"] > 0
   assert s["tokens_per_sec"] > 0
-  assert 0 <= s["mfu"] < 10
+  # MFU needs a device with a peak on record: a CPU run reports none.
+  assert "mfu" not in s
 
 
 def test_flops_profiler_measure():
@@ -169,7 +170,8 @@ def test_flops_profiler_measure():
   assert prof.step() is None  # first call only arms the timer
   assert prof.step() is None
   stats = prof.step()
-  assert stats is not None and "mfu" in stats
+  assert stats is not None and stats["gflops_per_step"] > 0
+  assert "mfu" not in stats       # CPU: no peak on record, no MFU
 
 
 # ------------------------------------------------------------- metrics --

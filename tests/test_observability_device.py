@@ -236,7 +236,7 @@ def test_capture_twin_attributes_real_lowered_collective():
   psum_scatter's StableHLO reduce_scatter op is attributed back to the
   registered site, and the wire-byte figure lands in the store the
   overlap policy reads."""
-  from jax.experimental.shard_map import shard_map
+  from jax import shard_map
   from jax.sharding import Mesh, PartitionSpec as P
   intro = device_lib.install(DeviceIntrospector())
   # Site expecting a [4, 8] f32 fused reduce_scatter result (128 B).

@@ -7,10 +7,11 @@ engine (itself quick-pinned to generate), no matter when a request was
 admitted, which blocks its K/V landed in, who owned those blocks
 before, or whether the block pool ran dry and preempted it mid-flight.
 Compile count stays 1 as requests join/leave and block tables reshuffle.
-Heavyweight shape sweeps are ``slow``-marked so tier-1 keeps its window;
-the Pallas kernel parity test is TPU-gated (skip-not-fail on CPU — the
-CPU engine runs the bit-exact jnp reference path, which these tests
-exercise throughout).
+Heavyweight shape sweeps are ``slow``-marked so tier-1 keeps its window.
+The CPU engine runs the bit-exact jnp reference path, which these tests
+exercise throughout; the Pallas kernel is checked here interpreted,
+lowered for the TPU in tests/test_tpu_lowering.py, and compiled against
+the reference on the chip by chip_smoke.py.
 """
 
 import jax
@@ -387,24 +388,6 @@ def test_paged_kernel_parity_interpret_mode():
   ker = paged_attention_pallas(*args, interpret=True)
   np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                              rtol=2e-5, atol=2e-6)
-
-
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="Pallas paged-attention kernel needs a TPU "
-                           "(CPU runs the bit-exact jnp reference path)")
-def test_paged_kernel_parity_tpu():
-  """On real hardware the compiled kernel matches the reference within
-  flash-kernel tolerance (rides the benchmarks/flash_vs_xla.py harness
-  pattern: same tolerances, bf16 and fp32 both)."""
-  for dtype, rtol, atol in ((jnp.float32, 2e-5, 2e-6),
-                            (jnp.bfloat16, 2e-2, 2e-2)):
-    args = _parity_case(seed=1, T=16, H=8, hd=64, NB=17, bs=16, MB=8,
-                        dtype=dtype)
-    ref = paged_attention_reference(*args)
-    ker = paged_attention_pallas(*args, interpret=False)
-    np.testing.assert_allclose(
-        np.asarray(ker, np.float32), np.asarray(ref, np.float32),
-        rtol=rtol, atol=atol)
 
 
 # ------------------------------------------------------------- slow sweeps

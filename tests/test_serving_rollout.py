@@ -397,6 +397,12 @@ def test_full_rollout_zero_loss_single_version_attribution(tmp_path):
     admitted_version[uid] = router._replica_version(
         router.placement[uid])
 
+  def feed(upto):
+    """Live traffic for one phase, leaving ``prompts[upto:]`` to the
+    phases after it — however many sweeps the green spawner (a real
+    thread, real time) lets this one last."""
+    return lambda: submit_one() if uid_ctr[0] < upto else None
+
   for _ in range(4):
     submit_one()
   router.step()
@@ -404,17 +410,17 @@ def test_full_rollout_zero_loss_single_version_attribution(tmp_path):
   assert green_version == 1 and router.rollout.state == "spawning"
   _pump(router, clock,
         until=lambda: router.rollout.state == "canary",
-        submit=submit_one)
+        submit=feed(12))
   assert len(router.replicas) == 4          # 2 blue + 2 green
   assert router._version_weights == {0: 0.5, 1: 0.5}
   # Canary traffic flows to BOTH versions while the hold elapses.
   _pump(router, clock,
         until=lambda: router.rollout.state != "canary",
-        submit=submit_one)
+        submit=feed(18))
   assert router.rollout.state in ("draining_blue", "idle")
   _pump(router, clock,
         until=lambda: router.rollout.state == "idle",
-        submit=submit_one)
+        submit=feed(20))
   while uid_ctr[0] < len(prompts):          # post-cutover traffic
     submit_one()
   router.run()
@@ -482,6 +488,12 @@ def test_canary_breach_rolls_back_blue_bit_exact(tmp_path):
       admitted_version[uid] = router._replica_version(
           router.placement[uid])
 
+    def keep_blue_busy():
+      # While green spawns (a real thread, real time) — but the last
+      # four prompts are the canary's, however long the spawn takes.
+      if uid_ctr[0] < len(prompts) - 4:
+        submit_one()
+
     for _ in range(4):
       submit_one()
     router.step()
@@ -489,7 +501,7 @@ def test_canary_breach_rolls_back_blue_bit_exact(tmp_path):
       router.rollout.begin(ckpt_dir)
       _pump(router, clock,
             until=lambda: router.rollout.state == "canary",
-            submit=submit_one)
+            submit=keep_blue_busy)
       for _ in range(4):
         submit_one()              # canary traffic on both versions
       router.step()

@@ -1,0 +1,76 @@
+"""The Pallas entry points must LOWER for the TPU, checked without one.
+
+``lower(lowering_platforms=("tpu",))`` runs the Pallas TPU lowering —
+block-shape rules included — on any host.  The CPU tests run the kernels
+interpreted, where those rules do not apply, so a kernel Mosaic would
+refuse stayed green here until it met a chip (the paged kernel did).
+Shapes are the serving engine's and bench.py's; only tracing happens, so
+these take seconds.
+"""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+import easyparallellibrary_tpu as epl
+from easyparallellibrary_tpu.kernels import (
+    flash_attention, paged_attention_pallas)
+
+fa = importlib.import_module(
+    "easyparallellibrary_tpu.kernels.flash_attention")
+
+
+def _lowers_for_tpu(fn, *args) -> str:
+  return jax.jit(fn).trace(*args).lower(
+      lowering_platforms=("tpu",)).as_text()
+
+
+def _flash_loss(q, k, v):
+  return jnp.sum(flash_attention(q, k, v, causal=True)
+                 .astype(jnp.float32) ** 2)
+
+
+@pytest.mark.parametrize("B,S,H,D,dtype", [
+    (2, 1024, 16, 64, jnp.bfloat16),      # bench.py's shape: resident
+    (1, 16384, 2, 64, jnp.bfloat16),      # past _RESIDENT_MAX_BYTES
+    (1, 1024, 16, 64, jnp.float32),
+])
+def test_flash_fwd_and_grad_lower_for_tpu(monkeypatch, B, S, H, D, dtype):
+  monkeypatch.setattr(fa, "_interpret", lambda: False)
+  x = jax.ShapeDtypeStruct((B, S, H, D), dtype)
+  text = _lowers_for_tpu(jax.value_and_grad(_flash_loss, (0, 1, 2)),
+                         x, x, x)
+  assert text.count("tpu_custom_call") == 3     # fwd, dk/dv, dq
+
+
+def test_flash_on_a_mesh_lowers_per_shard(monkeypatch):
+  """On a multi-device mesh jax refuses to lower a Mosaic call outside a
+  manual region; the kernel entry wraps itself in one, over batch and
+  heads."""
+  monkeypatch.setattr(fa, "_interpret", lambda: False)
+  epl.init(epl.Config({"cluster.mesh_shape": "data:4,model:2"}))
+  mesh = epl.Env.get().cluster.build_mesh()
+  x = jax.ShapeDtypeStruct(
+      (8, 1024, 16, 64), jnp.bfloat16,
+      sharding=NamedSharding(mesh, P("data", None, "model", None)))
+  text = _lowers_for_tpu(jax.value_and_grad(_flash_loss, (0, 1, 2)),
+                         x, x, x)
+  assert text.count("tpu_custom_call") == 3
+  # Each call sees its chip's shard: batch 8/4, heads 16/2.
+  assert "tensor<2x8x1024x64xbf16>" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_lowers_for_tpu(dtype):
+  T, H, hd, bs, NB, MB = 16, 16, 64, 16, 65, 64   # the engine's shapes
+  text = _lowers_for_tpu(
+      lambda *a: paged_attention_pallas(*a, interpret=False),
+      jax.ShapeDtypeStruct((T, H, hd), dtype),
+      jax.ShapeDtypeStruct((NB, bs, H, hd), dtype),
+      jax.ShapeDtypeStruct((NB, bs, H, hd), dtype),
+      jax.ShapeDtypeStruct((T, MB), jnp.int32),
+      jax.ShapeDtypeStruct((T,), jnp.int32))
+  assert text.count("tpu_custom_call") == 1
