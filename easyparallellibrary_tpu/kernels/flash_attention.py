@@ -58,6 +58,16 @@ from easyparallellibrary_tpu.utils.compat import (
 
 NEG_INF = -1e30
 
+# The kernels' names in a device trace: each ``pallas_call`` below passes
+# one as ``name=``, which makes it the innermost scope of the Mosaic custom
+# call and so the name of its instruction on the trace's ``XLA Ops`` line.
+# The resident and the streaming variant of a kernel do the same required
+# work and share a name.  The benchmark reads these (PERF.md section 3):
+# renaming one takes a ``benchmark`` issue.
+FLASH_FWD = "flash_fwd"
+FLASH_DKV = "flash_dkv"
+FLASH_DQ = "flash_dq"
+
 
 def _interpret() -> bool:
   return jax.default_backend() != "tpu"
@@ -329,6 +339,7 @@ def _fwd(q, k, v, causal: bool, block_q: int, block_k: int):
             jax.ShapeDtypeStruct((B, H, 8, S), jnp.float32),
         ],
         interpret=_interpret(),
+        name=FLASH_FWD,
     )(q, k, v)
     return out, lse
 
@@ -361,6 +372,7 @@ def _fwd(q, k, v, causal: bool, block_q: int, block_k: int):
       ],
       compiler_params=_compiler_params(3),
       interpret=_interpret(),
+      name=FLASH_FWD,
   )(q, k, v)
   return out, lse
 
@@ -484,6 +496,7 @@ def _bwd_kernels(q, k, v, dout, lse8, delta8, causal, block_q, block_k):
             jax.ShapeDtypeStruct((B, H, Skv, D), q.dtype),
         ],
         interpret=_interpret(),
+        name=FLASH_DKV,
     )(q, k, v, dout, lse8, delta8)
 
     dq = pl.pallas_call(
@@ -502,6 +515,7 @@ def _bwd_kernels(q, k, v, dout, lse8, delta8, causal, block_q, block_k):
                                lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
         interpret=_interpret(),
+        name=FLASH_DQ,
     )(q, k, v, dout, lse8, delta8)
     return dq, dk, dv
 
@@ -538,6 +552,7 @@ def _bwd_kernels(q, k, v, dout, lse8, delta8, causal, block_q, block_k):
       ],
       compiler_params=_compiler_params(3),
       interpret=_interpret(),
+      name=FLASH_DKV,
   )(q, k, v, dout, lse8, delta8)
 
   # dq: grid streams KV blocks innermost (same layout as the forward).
@@ -561,6 +576,7 @@ def _bwd_kernels(q, k, v, dout, lse8, delta8, causal, block_q, block_k):
       scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
       compiler_params=_compiler_params(3),
       interpret=_interpret(),
+      name=FLASH_DQ,
   )(q, k, v, dout, lse8, delta8)
   return dq, dk, dv
 

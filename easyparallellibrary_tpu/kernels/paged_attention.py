@@ -57,6 +57,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# The kernel's name in a device trace (see ``flash_attention.FLASH_FWD``).
+PAGED_ATTN = "paged_attn"
 
 IMPLS = ("pallas", "reference", "interpret")
 
@@ -213,7 +215,7 @@ def paged_attention_pallas(q, k_pages, v_pages, tables_tok, positions,
       grid_spec=grid_spec,
       out_shape=jax.ShapeDtypeStruct((T, H, hd), q.dtype),
       interpret=interpret,
-      name="paged_attention",
+      name=PAGED_ATTN,
       **kwargs,
   )(tables_tok.astype(jnp.int32), positions.astype(jnp.int32),
     q, k_pages, v_pages)

@@ -247,19 +247,30 @@ class Tracer:
 
   def span_at(self, name: str, t0_us: float, t1_us: float, cat: str = "",
               track: Optional[str] = None,
-              args: Optional[Dict[str, Any]] = None):
+              args: Optional[Dict[str, Any]] = None,
+              children: Tuple[Tuple[str, float, float], ...] = ()):
     """Record a completed span with explicit timestamps — for work whose
     duration is known only after the fact (one fused device step covers
-    every serving slot; each slot's span shares its bounds)."""
+    every serving slot; each slot's span shares its bounds).
+
+    ``children`` are ``(name, t0_us, t1_us)`` sub-spans on the same
+    track and category, in time order, disjoint and inside the parent's
+    bounds.  They are buffered BETWEEN the parent's B and E, so the
+    export's stable sort keeps them nested even where a child shares a
+    bound with the parent or a sibling (the engine's dispatch and fetch
+    tile its device step) — separate ``span_at`` calls could not."""
     if not self.enabled:
       return
     tid = self.track(track) if track else 0
+    t1_us = t1_us if t1_us >= t0_us else t0_us
     with self._lock:
       append = self._events.append
       append(("B", name, cat, t0_us, tid, args))
-      append(("E", name, cat, t1_us if t1_us >= t0_us else t0_us, tid,
-              None))
-      self._n_appended += 2
+      for child, c0_us, c1_us in children:
+        append(("B", child, cat, c0_us, tid, None))
+        append(("E", child, cat, c1_us, tid, None))
+      append(("E", name, cat, t1_us, tid, None))
+      self._n_appended += 2 + 2 * len(children)
 
   def begin(self, name: str, cat: str = "", track: Optional[str] = None,
             args: Optional[Dict[str, Any]] = None):

@@ -860,44 +860,48 @@ class ContinuousBatchingEngine:
     never admitted — the client learns at submit time, not after a
     hopeless queue wait.  Malformed requests raise regardless of load
     (validation must not depend on instantaneous queue depth)."""
-    prompt = self.scheduler.validate(request)
-    if self._admission is not None and not self.scheduler.has_work:
-      # The ladder normally de-escalates inside step(), but an idle
-      # engine never steps: if the queue drained without stepping
-      # (every queued request cancelled or expired after a shed-level
-      # observation), a stale shed level would otherwise reject 100%
-      # of traffic forever.  Re-observe with the idle signals first.
-      self._apply_degradation()
-    if (self._admission is not None
-        and self._admission.should_shed(self.scheduler.queue_depth)):
-      self._admission.note_shed()
-      fin = FinishedRequest(uid=request.uid, tokens=prompt,
-                            new_tokens=0, finish_reason="shed")
-      self._record_finished(fin)
+    tracer = trace_lib.get_tracer()
+    # Host work of the engine BETWEEN steps: the benchmark reads this
+    # span by name (PERF.md section 3).  ``serving/submit`` is the
+    # scheduler's instant, one per ACCEPTED request.
+    with tracer.span("serving/enqueue", cat="serving", track="serving"):
+      prompt = self.scheduler.validate(request)
+      if self._admission is not None and not self.scheduler.has_work:
+        # The ladder normally de-escalates inside step(), but an idle
+        # engine never steps: if the queue drained without stepping
+        # (every queued request cancelled or expired after a shed-level
+        # observation), a stale shed level would otherwise reject 100%
+        # of traffic forever.  Re-observe with the idle signals first.
+        self._apply_degradation()
+      if (self._admission is not None
+          and self._admission.should_shed(self.scheduler.queue_depth)):
+        self._admission.note_shed()
+        fin = FinishedRequest(uid=request.uid, tokens=prompt,
+                              new_tokens=0, finish_reason="shed")
+        self._record_finished(fin)
+        if self.stats is not None:
+          self.stats.note_shed(request.uid)
+        if tracer.enabled:
+          tracer.instant(
+              "serving/shed", cat="serving", track="serving/requests",
+              args={"uid": str(request.uid),
+                    "queue_depth": int(self.scheduler.queue_depth),
+                    "level": DEGRADE_LEVELS[self._admission.level]})
+          if request.flow_id is not None:
+            # A router-minted flow must terminate even on a shed — the
+            # rejection IS this request's resolution.
+            tracer.flow("f", request.flow_id, track="serving/requests",
+                        args={"uid": str(request.uid), "reason": "shed"})
+        get_logger().warning(
+            "shedding request %r at submit (queue %d/%d, level %s)",
+            request.uid, self.scheduler.queue_depth,
+            self._admission.queue_limit,
+            DEGRADE_LEVELS[self._admission.level])
+        return False
       if self.stats is not None:
-        self.stats.note_shed(request.uid)
-      tracer = trace_lib.get_tracer()
-      if tracer.enabled:
-        tracer.instant(
-            "serving/shed", cat="serving", track="serving/requests",
-            args={"uid": str(request.uid),
-                  "queue_depth": int(self.scheduler.queue_depth),
-                  "level": DEGRADE_LEVELS[self._admission.level]})
-        if request.flow_id is not None:
-          # A router-minted flow must terminate even on a shed — the
-          # rejection IS this request's resolution.
-          tracer.flow("f", request.flow_id, track="serving/requests",
-                      args={"uid": str(request.uid), "reason": "shed"})
-      get_logger().warning(
-          "shedding request %r at submit (queue %d/%d, level %s)",
-          request.uid, self.scheduler.queue_depth,
-          self._admission.queue_limit,
-          DEGRADE_LEVELS[self._admission.level])
-      return False
-    if self.stats is not None:
-      self.stats.note_submitted(request.uid)
-    self.scheduler.submit(request, _prompt=prompt)
-    return True
+        self.stats.note_submitted(request.uid)
+      self.scheduler.submit(request, _prompt=prompt)
+      return True
 
   def cancel(self, uid: Any) -> bool:
     """Client cancellation: retire `uid` wherever it is; the record (and
@@ -1174,6 +1178,64 @@ class ContinuousBatchingEngine:
         int(b) for b in np.nonzero(mask)[0] if b != kv_lib.NULL_BLOCK)
     self._kv = self._sanitize_fn(self._kv, mask, start)
 
+  def _step_args(self, plan, num_draft):
+    """The fused step's arguments for this plan, in the order its twin
+    takes them: speculative (``num_draft`` given) or plain, paged or
+    contiguous."""
+    sampling = (plan.keys, plan.tok_index, plan.temperature, plan.top_k,
+                plan.top_p)
+    if self.paged:
+      last_idx = (plan.base_idx + plan.num_valid - 1).astype(np.int32)
+      drafts = () if num_draft is None else (plan.draft_base, num_draft)
+      return (self.params, self._kv, plan.tokens, plan.slot_ids,
+              plan.positions, plan.valid, plan.block_tables, last_idx,
+              *drafts, plan.num_valid > 0, *sampling)
+    if num_draft is None:
+      return (self.params, self._kv, self._cursors, plan.tokens,
+              plan.num_valid, plan.reset, *sampling)
+    return (self.params, self._kv, self._cursors, plan.tokens,
+            plan.num_valid + num_draft, num_draft, plan.reset, *sampling)
+
+  def _dispatch_and_fetch(self, tracer, t0_us: float, step_args,
+                          n_fetch: int):
+    """Launch the fused step and fetch what the host commits from: the
+    one place where all four twins cross to the device and back.  Every
+    twin returns ``(*tokens, [ok,] kv[, cursors])`` with ``n_fetch``
+    token arrays in front.  Returns ``(fetched, slot_ok, t1_us)``.
+
+    Spans, on the ``serving`` track: ``serving/device_step`` from
+    ``t0_us`` (stamped by the caller before it built ``step_args``) to
+    the return of the last fetch, tiled by ``serving/dispatch`` (host:
+    argument transfer and launch; the device has nothing to run while
+    it lasts) and ``serving/fetch`` (the device's run plus the way
+    back).  One clock read more than the device step alone; the
+    benchmark reads all three by name (PERF.md section 3)."""
+    self._note_step_specs(step_args)
+    out = self._step_fn(*step_args)
+    t_launched_us = tracer.now_us()
+    tokens, state = out[:n_fetch], out[n_fetch:]
+    ok_dev = None
+    if self._resilient:
+      ok_dev, state = state[0], state[1:]
+    if self.paged:
+      (self._kv,) = state
+    else:
+      self._kv, self._cursors = state
+    slot_ok = None if ok_dev is None else jax.device_get(ok_dev)
+    # The step's ONE designated token fetch: explicit (device_get),
+    # so it stays visible — and legal — under
+    # jax.transfer_guard_device_to_host("disallow"); any OTHER
+    # device->host crossing in this loop is a bug the guard (and
+    # epl-lint's host-sync rule) catches.
+    fetched = [jax.device_get(t) for t in tokens]
+    t1_us = tracer.now_us()
+    tracer.span_at(
+        "serving/device_step", t0_us, t1_us, cat="serving",
+        track="serving",
+        children=(("serving/dispatch", t0_us, t_launched_us),
+                  ("serving/fetch", t_launched_us, t1_us)))
+    return fetched, slot_ok, t1_us
+
   def step(self) -> List[FinishedRequest]:
     """One engine iteration: [degrade ->] plan -> [draft ->] fused
     device step -> commit [-> bad-step policy].  Returns the requests
@@ -1211,56 +1273,26 @@ class ContinuousBatchingEngine:
       xla_ctx.__enter__()
     drafted = accepted = 0
     slot_ok = None
+    num_draft = None
     try:
       if self.drafter is not None:
         # Propose BEFORE the token block gains drafts: the draft
         # model's mirror call needs the same plan the target sees.
         num_draft = self._propose_drafts(tracer, plan)
-        t0_us = tracer.now_us()
-        if self.paged:
-          base_last = (plan.base_idx + plan.num_valid - 1).astype(np.int32)
-          step_args = (
-              self.params, self._kv, plan.tokens, plan.slot_ids,
-              plan.positions, plan.valid, plan.block_tables, base_last,
-              plan.draft_base, num_draft, plan.num_valid > 0, plan.keys,
-              plan.tok_index, plan.temperature, plan.top_k, plan.top_p)
-          self._note_step_specs(step_args)
-          out = self._step_fn(*step_args)
-          if self._resilient:
-            committed, n_committed, ok_dev, self._kv = out
-            slot_ok = jax.device_get(ok_dev)
-          else:
-            committed, n_committed, self._kv = out
-        else:
-          step_args = (
-              self.params, self._kv, self._cursors, plan.tokens,
-              plan.num_valid + num_draft, num_draft, plan.reset,
-              plan.keys, plan.tok_index, plan.temperature, plan.top_k,
-              plan.top_p)
-          self._note_step_specs(step_args)
-          out = self._step_fn(*step_args)
-          if self._resilient:
-            committed, n_committed, ok_dev, self._kv, self._cursors = out
-            slot_ok = jax.device_get(ok_dev)
-          else:
-            committed, n_committed, self._kv, self._cursors = out
-        # The step's ONE designated token fetch: explicit (device_get),
-        # so it stays visible — and legal — under
-        # jax.transfer_guard_device_to_host("disallow"); any OTHER
-        # device->host crossing in this loop is a bug the guard (and
-        # epl-lint's host-sync rule) catches.
-        committed = jax.device_get(committed)
-        n_committed = jax.device_get(n_committed)
-        t1_us = tracer.now_us()
-        tracer.span_at("serving/device_step", t0_us, t1_us,
-                       cat="serving", track="serving")
-        self._trace_slot_spans(tracer, plan, t0_us, t1_us,
-                               num_draft, n_committed)
-        with tracer.span("serving/commit", cat="serving",
-                         track="serving"):
-          finished = self.scheduler.commit(committed, n_committed,
-                                           slot_ok=slot_ok)
+      t0_us = tracer.now_us()
+      fetched, slot_ok, t1_us = self._dispatch_and_fetch(
+          tracer, t0_us, self._step_args(plan, num_draft),
+          n_fetch=1 if num_draft is None else 2)
+      # ``fetched`` is (next_tokens,) or, speculative, (committed,
+      # n_committed): commit()'s own positional arguments.
+      n_committed = fetched[1] if num_draft is not None else None
+      self._trace_slot_spans(tracer, plan, t0_us, t1_us,
+                             num_draft, n_committed)
+      with tracer.span("serving/commit", cat="serving", track="serving"):
+        finished = self.scheduler.commit(*fetched, slot_ok=slot_ok)
+        if self.drafter is not None:
           self.drafter.observe_commit(self._cursors)
+      if num_draft is not None:
         # Stats count only slots whose verdict committed: a bad slot's
         # n_committed is NaN-logit garbage and its drafts are re-spent
         # on the retry — counting them would double/poison the
@@ -1269,43 +1301,6 @@ class ContinuousBatchingEngine:
         speculated = (num_draft > 0) & ok
         drafted = int(num_draft[ok].sum())
         accepted = int((n_committed[speculated] - 1).sum())
-      else:
-        t0_us = tracer.now_us()
-        if self.paged:
-          last_idx = (plan.base_idx + plan.num_valid - 1).astype(np.int32)
-          step_args = (
-              self.params, self._kv, plan.tokens, plan.slot_ids,
-              plan.positions, plan.valid, plan.block_tables, last_idx,
-              plan.num_valid > 0, plan.keys, plan.tok_index,
-              plan.temperature, plan.top_k, plan.top_p)
-          self._note_step_specs(step_args)
-          out = self._step_fn(*step_args)
-          if self._resilient:
-            nxt, ok_dev, self._kv = out
-            slot_ok = jax.device_get(ok_dev)
-          else:
-            nxt, self._kv = out
-        else:
-          step_args = (
-              self.params, self._kv, self._cursors, plan.tokens,
-              plan.num_valid, plan.reset, plan.keys, plan.tok_index,
-              plan.temperature, plan.top_k, plan.top_p)
-          self._note_step_specs(step_args)
-          out = self._step_fn(*step_args)
-          if self._resilient:
-            nxt, ok_dev, self._kv, self._cursors = out
-            slot_ok = jax.device_get(ok_dev)
-          else:
-            nxt, self._kv, self._cursors = out
-        # Designated fetch (see the speculative branch above).
-        nxt = jax.device_get(nxt)
-        t1_us = tracer.now_us()
-        tracer.span_at("serving/device_step", t0_us, t1_us,
-                       cat="serving", track="serving")
-        self._trace_slot_spans(tracer, plan, t0_us, t1_us)
-        with tracer.span("serving/commit", cat="serving",
-                         track="serving"):
-          finished = self.scheduler.commit(nxt, slot_ok=slot_ok)
     finally:
       if self._watchdog is not None:
         self._watchdog.disarm()

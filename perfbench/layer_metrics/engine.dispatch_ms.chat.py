@@ -1,0 +1,4 @@
+"""Host share of the fused step (``serving/dispatch``) where it moves
+``itl_p95_ms``; the arithmetic is ``harness/loop_spans.py``'s."""
+
+from perfbench.harness.loop_spans import dispatch_ms as read  # noqa: F401
