@@ -43,7 +43,8 @@ import numpy as np
 import bench
 import easyparallellibrary_tpu as epl
 from easyparallellibrary_tpu.kernels import (
-    flash_attention, paged_attention_pallas, paged_attention_reference)
+    flash_attention, kv_write_pallas, kv_write_reference,
+    paged_attention_pallas, paged_attention_reference)
 from easyparallellibrary_tpu.models import GPT, GPTConfig
 from easyparallellibrary_tpu.models.gpt import (
     _dense_causal_attention, generate, gpt_loss)
@@ -80,6 +81,7 @@ class Sizes:
   new_tokens: int
   flash_shapes: tuple             # (B, H, S, D, dtype)
   paged_shape: tuple              # (T, H, hd, block, table width)
+  kv_write_shape: tuple           # (slots, Lc, H, hd, chunk)
 
   @staticmethod
   def real() -> "Sizes":
@@ -99,7 +101,9 @@ class Sizes:
         # [S, S] scores inside HBM).
         flash_shapes=((2, 16, 1024, 64, jnp.bfloat16),
                       (1, 2, 16384, 64, jnp.bfloat16)),
-        paged_shape=(16, 16, 64, 16, 8))
+        paged_shape=(16, 16, 64, 16, 8),
+        # The serving cache's leaf: 1024 + one chunk of slack.
+        kv_write_shape=(8, 1040, 16, 64, 16))
 
   @staticmethod
   def toy() -> "Sizes":
@@ -116,7 +120,8 @@ class Sizes:
         new_tokens=8,
         flash_shapes=((1, 2, 128, 32, jnp.float32),
                       (1, 1, 256, 32, jnp.float32)),
-        paged_shape=(6, 4, 32, 8, 4))
+        paged_shape=(6, 4, 32, 8, 4),
+        kv_write_shape=(4, 136, 4, 32, 8))
 
 
 def say(msg: str) -> None:
@@ -207,15 +212,42 @@ def check_paged(T, H, hd, bs, MB, dtype, rehearsal: bool) -> None:
       f"max abs error {np.abs(got32 - ref32).max():.2e}")
 
 
+def check_kv_write(B, Lc, H, hd, C, dtype, rehearsal: bool) -> None:
+  """The in-place window write against ``vmap(dynamic_update_slice)``,
+  bit for bit over both whole leaves: cursors at a leaf's start, across
+  a 128-position boundary, and at the last legal window."""
+  r = np.random.RandomState(2)
+  ck, cv = (jnp.asarray(r.randn(B, Lc, H, hd), dtype) for _ in range(2))
+  k, v = (jnp.asarray(r.randn(B, C, H, hd), dtype) for _ in range(2))
+  cursors = jnp.asarray(
+      ([0, 128 - C // 2, Lc - C, 127] + list(r.randint(0, Lc - C, B)))[:B],
+      jnp.int32)
+  args = (ck, cv, k, v, cursors)
+  kernel = compile_here(
+      functools.partial(kv_write_pallas, interpret=rehearsal),
+      *args, mosaic_calls=1, rehearsal=rehearsal)
+  bits = lambda x: np.asarray(x).view(
+      {2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+  for name, g, w in zip("KV", kernel(*args),
+                        jax.jit(kv_write_reference)(*args)):
+    check((bits(g) == bits(w)).all(),
+          f"kv_write {name} leaf {jnp.dtype(dtype).name} differs from the "
+          f"reference in {(bits(g) != bits(w)).sum()} elements")
+  say(f"  kv_write slots{B} Lc{Lc} H{H} hd{hd} chunk{C} "
+      f"{jnp.dtype(dtype).name}: K and V leaves bit-identical")
+
+
 def phase_kernels(sizes: Sizes) -> None:
   for shape in sizes.flash_shapes:
     check_flash(*shape, rehearsal=sizes.rehearsal)
   for dtype in (jnp.float32, jnp.bfloat16):
     check_paged(*sizes.paged_shape, dtype, rehearsal=sizes.rehearsal)
+    check_kv_write(*sizes.kv_write_shape, dtype, rehearsal=sizes.rehearsal)
   say("PASS kernels: flash fwd/bwd "
-      + ("at toy shapes, paged f32 + bf16, all INTERPRETED"
+      + ("at toy shapes, paged and kv_write f32 + bf16, all INTERPRETED"
          if sizes.rehearsal else
-         "resident + streaming, paged f32 + bf16, all compiled")
+         "resident + streaming, paged and kv_write f32 + bf16, all "
+         "compiled")
       + ", within tolerance")
 
 
@@ -378,14 +410,17 @@ def serve(model, params, prompts, new_tokens: int, paged: bool,
     check((out[uid][:len(p)] == p).all(), f"request {uid}: prompt changed")
   check(spy._cache_size() == 1,
         f"fused step compiled {spy._cache_size()} times")
-  if paged and not rehearsal:
-    check(eng._paged_impl == "pallas",
-          f"paged attend resolved to {eng._paged_impl!r}, not the kernel")
+  if not rehearsal:
+    impl = eng._paged_impl if paged else eng.kv_write_impl
+    check(impl == "pallas",
+          f"{'paged attend' if paged else 'cache write'} resolved to "
+          f"{impl!r}, not the kernel")
     calls = spy.inner.lower(*spy.specs).compile().as_text().count(
         MOSAIC_CALL)
     check(calls == model.cfg.num_layers,
-          f"{calls} Mosaic custom calls in the fused paged step, "
-          f"expected one per layer ({model.cfg.num_layers})")
+          f"{calls} Mosaic custom calls in the fused "
+          f"{'paged' if paged else 'contiguous'} step, expected one per "
+          f"layer ({model.cfg.num_layers})")
   return out
 
 

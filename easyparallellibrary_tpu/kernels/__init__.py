@@ -1,4 +1,6 @@
 from easyparallellibrary_tpu.kernels.flash_attention import flash_attention
+from easyparallellibrary_tpu.kernels.kv_write import (
+    kv_write_pallas, kv_write_reference)
 from easyparallellibrary_tpu.kernels.paged_attention import (
     paged_attention, paged_attention_pallas, paged_attention_reference,
     set_paged_attention_impl,
@@ -6,6 +8,7 @@ from easyparallellibrary_tpu.kernels.paged_attention import (
 
 __all__ = [
     "flash_attention",
+    "kv_write_pallas", "kv_write_reference",
     "paged_attention", "paged_attention_pallas",
     "paged_attention_reference", "set_paged_attention_impl",
 ]

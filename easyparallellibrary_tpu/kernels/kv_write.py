@@ -1,0 +1,238 @@
+"""In-place K/V window write — Pallas TPU kernel + the XLA reference.
+
+The serving engine's fused step appends a ``[slots, chunk, H, hd]`` K/V
+window to every layer's contiguous cache at each slot's own cursor
+(``models.gpt.slot_cache_attend``; the layout note in
+``serving/kv_cache.py`` is the contract: the full ``chunk``-wide window
+lands at ``cursor``, never clamped, never shifted).  One algorithm, two
+lowerings, behind one dispatcher:
+
+* **reference** — ``jax.vmap(dynamic_update_slice)``, one cursor per
+  slot.  XLA makes it a ``scatter``, which the TPU compiler expands into
+  a serial loop of one trip per slot and leaf (bounds check, slice one
+  slot's update, write it into the whole leaf in place).  Correct
+  everywhere, and cheap wherever the leaf is small.
+* **pallas** — one launch per layer, K and V as two operands aliased to
+  their outputs, grid over the slots with the cursors scalar-prefetched.
+  The TPU keeps a cache leaf position-minor (``[slot, H, hd, position]``
+  in memory; ``hd`` minor would pad 64 lanes to 128), so the kernel
+  addresses the leaf in that order — the transposes around the call are
+  bitcasts — and a window is a run of lanes inside one 128-position
+  tile, or two when it straddles a boundary.  Each grid step reads the
+  tile, rotates the chunk to the window's lane offset, selects it in
+  under a lane mask and writes the tile back; no other byte of the
+  donated leaf moves.  The contents afterwards are bit-identical to the
+  reference's (data movement only: no arithmetic touches a value).
+
+Dispatch rule (docs/serving.md): the kernel runs when the backend is TPU,
+the leaf is not spread over a multi-device mesh and the shapes fit its
+tiles (:func:`kv_write_fits`); the reference runs everywhere else.  The
+rule reads nothing but what it is handed and the backend — no
+configuration field, no environment variable, no setter.  A third impl,
+``interpret``, runs the kernel in Pallas interpreter mode: the parity
+tests' CPU vehicle, reached by naming it or by patching
+:func:`_backend_impl`.  The engine resolves
+the lowering ONCE when it builds its step and records it
+(``engine.kv_write_impl``, trace metadata ``serving/kv_write_impl``).
+
+Shapes: ``cached_k/cached_v`` ``[B, Lc, H, hd]``; ``k/v`` ``[B, C, H,
+hd]``; ``cursors`` int32 ``[B]``.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from easyparallellibrary_tpu.env import Env
+
+# The kernel's name in a device trace (see ``flash_attention.FLASH_FWD``).
+# The benchmark reads it (PERF.md section 3).
+KV_WRITE = "kv_write"
+
+IMPLS = ("pallas", "reference", "interpret")
+
+# Positions per tile: the lane width of the position-minor leaf.
+LANES = 128
+# VMEM the kernel may ask for.  It holds 13 tile blocks: K and V tiles in
+# and out, double-buffered (8), the two chunks, lane-padded to a tile and
+# double-buffered (4), and the staging tile.  v5e's scoped default is 16
+# MiB; GPT-2 medium's bf16 leaf takes 3.3 MiB.
+_VMEM_BUDGET = 12 * 1024 * 1024
+_VMEM_TILES = 13
+
+
+def _backend_impl() -> str:
+  """The lowering this backend takes when the shapes allow it.  The CPU
+  parity tests patch it to ``interpret``."""
+  return "pallas" if jax.default_backend() == "tpu" else "reference"
+
+
+def kv_write_fits(cache_shape, dtype, chunk: int) -> bool:
+  """Whether the kernel can tile a ``[B, Lc, H, hd]`` leaf of ``dtype``
+  for ``chunk``-wide windows: at least one whole 128-position tile, a
+  window of at most one tile's width (so it touches two at most), a
+  32-bit or 16-bit float leaf whose ``hd`` fills whole sublane tiles,
+  and tile blocks within the VMEM budget."""
+  _, Lc, H, hd = cache_shape
+  dtype = jnp.dtype(dtype)
+  if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
+    return False
+  if Lc < LANES or not 1 <= chunk <= LANES:
+    return False
+  if hd % (8 * 4 // dtype.itemsize):
+    return False
+  return _VMEM_TILES * H * hd * LANES * dtype.itemsize <= _VMEM_BUDGET
+
+
+def resolve_kv_write_impl(cache_shape, dtype, chunk: int,
+                          sharded: bool = False) -> str:
+  """The dispatch rule: the backend's lowering (``pallas`` on a TPU,
+  ``reference`` elsewhere), and ``reference`` whenever the leaf lives on
+  a multi-device mesh (``sharded``: the SPMD partitioner cannot split a
+  Mosaic call) or the shapes do not fit (:func:`kv_write_fits`)."""
+  impl = _backend_impl()
+  if impl != "reference" and (
+      sharded or not kv_write_fits(cache_shape, dtype, chunk)):
+    return "reference"
+  return impl
+
+
+# -------------------------------------------------------------- reference --
+
+
+def kv_write_reference(cached_k, cached_v, k, v, cursors):
+  """One ``dynamic_update_slice`` per slot at its own cursor."""
+  def write(cache, new):
+    return jax.vmap(
+        lambda row, chunk, cur: jax.lax.dynamic_update_slice(
+            row, chunk, (cur, 0, 0)))(cache, new.astype(cache.dtype),
+                                      cursors)
+  return write(cached_k, k), write(cached_v, v)
+
+
+# ----------------------------------------------------------------- pallas --
+
+
+def _window_tile(cur, j, chunk: int):
+  """Tile index of the window's first (``j == 0``) or last (``j == 1``)
+  position; the same tile twice when the window does not straddle."""
+  return (cur + j * (chunk - 1)) // LANES
+
+
+def _kv_write_kernel(cur_ref, k_new_ref, v_new_ref, k_in_ref, v_in_ref,
+                     k_out_ref, v_out_ref, stage_ref, *, chunk: int):
+  """One (slot, tile) grid step: lay the slot's chunk over the lanes
+  ``[cursor, cursor + chunk)`` of this 128-position tile, for K and V.
+
+  Values keep ``[H, hd, position]`` — ``hd`` on sublanes, positions on
+  lanes.  The chunk is staged into lanes ``[0, chunk)`` of a scratch
+  tile, rotated to the window's offset and selected in under the lane
+  mask; lanes the rotation wraps around fall outside the mask.  A
+  window inside one tile visits it twice and writes the same values
+  twice: the block stays resident between the two steps, so nothing
+  moves, and skipping the second pass saved no time on the chip (the
+  tile's DMA bounds a step, not its arithmetic)."""
+  b = pl.program_id(0)
+  j = pl.program_id(1)
+  cur = cur_ref[b]
+  # Where the window starts relative to this tile: negative in the second
+  # tile of a straddling window.
+  off = cur - _window_tile(cur, j, chunk) * LANES
+  shift = jnp.where(off < 0, off + LANES, off)
+  lane = jax.lax.broadcasted_iota(jnp.int32, stage_ref.shape, 2)
+  window = (lane >= off) & (lane < off + chunk)
+  # Mosaic rotates 32-bit lanes only: a 16-bit leaf goes through as the
+  # uint32 words its sublane pairs already are in a register.
+  packed = k_in_ref.dtype.itemsize < 4
+  as32 = (lambda x: pltpu.bitcast(x, jnp.uint32)) if packed else (lambda x: x)
+  for new_ref, in_ref, out_ref in ((k_new_ref, k_in_ref, k_out_ref),
+                                   (v_new_ref, v_in_ref, v_out_ref)):
+    stage_ref[:, :, :chunk] = as32(new_ref[0])
+    moved = pltpu.roll(stage_ref[...], shift, 2)
+    merged = jnp.where(window, moved, as32(in_ref[0]))
+    out_ref[0] = pltpu.bitcast(merged, out_ref.dtype) if packed else merged
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kv_write_pallas(cached_k, cached_v, k, v, cursors,
+                    interpret: bool = False):
+  """The in-place window write; ``interpret`` runs the kernel in Pallas
+  interpreter mode (any backend).  Jitted, so that the layers of one
+  step share one trace and one Mosaic lowering of the kernel (a
+  ``pallas_call`` per layer, lowered apart, cost the 24-layer serving
+  step seconds of set-up); XLA inlines the calls."""
+  B, Lc, H, hd = cached_k.shape
+  C = k.shape[1]
+  dtype = cached_k.dtype
+  # ``dynamic_update_slice`` clamps a start that would run the window
+  # off the leaf; the contract keeps cursors inside (kv_cache.py), and
+  # the clamp keeps the two lowerings equal outside it too.
+  cursors = jnp.clip(cursors.astype(jnp.int32), 0, Lc - C)
+  # Position-minor views: bitcasts on the TPU, whose layout of the leaf
+  # is already this.
+  to_minor = lambda x: jnp.transpose(x.astype(dtype), (0, 2, 3, 1))
+  n_tiles = 1 if C == 1 else 2
+
+  def tile_idx(b, j, cur):
+    return (b, 0, 0, _window_tile(cur[b], j, C))
+
+  chunk_spec = pl.BlockSpec((1, H, hd, C), lambda b, j, cur: (b, 0, 0, 0))
+  tile_spec = pl.BlockSpec((1, H, hd, LANES), tile_idx)
+  grid_spec = pltpu.PrefetchScalarGridSpec(
+      num_scalar_prefetch=1,
+      grid=(B, n_tiles),
+      in_specs=[chunk_spec, chunk_spec, tile_spec, tile_spec],
+      out_specs=[tile_spec, tile_spec],
+      # 32-bit words: a 16-bit leaf packs two ``hd`` rows into each.
+      scratch_shapes=[pltpu.VMEM((H, hd * dtype.itemsize // 4, LANES),
+                                 jnp.float32 if dtype.itemsize == 4
+                                 else jnp.uint32)],
+  )
+  kwargs = {}
+  if not interpret:
+    kwargs["compiler_params"] = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"))
+  leaf = jax.ShapeDtypeStruct((B, H, hd, Lc), dtype)
+  new_k, new_v = pl.pallas_call(
+      functools.partial(_kv_write_kernel, chunk=C),
+      grid_spec=grid_spec,
+      out_shape=[leaf, leaf],
+      # Operands count the scalar-prefetch cursors: 3, 4 are the leaves.
+      input_output_aliases={3: 0, 4: 1},
+      interpret=interpret,
+      name=KV_WRITE,
+      **kwargs,
+  )(cursors, to_minor(k), to_minor(v), to_minor(cached_k),
+    to_minor(cached_v))
+  to_major = lambda x: jnp.transpose(x, (0, 3, 1, 2))
+  return to_major(new_k), to_major(new_v)
+
+
+# --------------------------------------------------------------- dispatch --
+
+
+def kv_write(cached_k, cached_v, k, v, cursors, impl: Optional[str] = None):
+  """Write each slot's K/V chunk at its cursor (module docstring);
+  returns ``(new_cached_k, new_cached_v)``.  ``impl=None`` applies the
+  dispatch rule to the shapes at hand, and takes the leaf as spread
+  over chips whenever a multi-device mesh has been built (the legacy
+  ``generate()`` decode); the serving engine resolves the impl from its
+  own mesh and passes it."""
+  if impl is None:
+    cluster = Env.get().cluster
+    mesh = cluster.built_mesh if cluster is not None else None
+    impl = resolve_kv_write_impl(
+        cached_k.shape, cached_k.dtype, k.shape[1],
+        sharded=mesh is not None and mesh.size > 1)
+  if impl not in IMPLS:
+    raise ValueError(f"impl must be one of {IMPLS} or None; got {impl!r}")
+  if impl == "reference":
+    return kv_write_reference(cached_k, cached_v, k, v, cursors)
+  return kv_write_pallas(cached_k, cached_v, k, v, cursors,
+                         interpret=impl == "interpret")

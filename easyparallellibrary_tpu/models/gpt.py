@@ -102,7 +102,8 @@ def _act_spec(cfg: GPTConfig, ndim: int = 3) -> P:
 from easyparallellibrary_tpu.utils.sharding import constrain as _constrain  # noqa: E402
 
 
-def slot_cache_attend(q, k, v, cached_k, cached_v, cursors, dtype):
+def slot_cache_attend(q, k, v, cached_k, cached_v, cursors, dtype,
+                      write_impl=None):
   """Slot-indexed KV-cache attention — the shared core of the legacy
   single-request decode step and the serving engine's fused
   prefill+decode step (serving/engine.py).
@@ -138,20 +139,20 @@ def slot_cache_attend(q, k, v, cached_k, cached_v, cursors, dtype):
   (engine._sanitize_slots) — so the invariant holds without taxing
   this hot path.
 
+  The window write has two lowerings with bit-identical results
+  (kernels/kv_write.py): ``write_impl`` names one, ``None`` applies the
+  dispatch rule to the shapes at hand (the serving engine resolves it
+  once when it builds its step and passes it down).
+
   Returns ``(out [B, C, H, hd], new_cached_k, new_cached_v)``.
   """
+  from easyparallellibrary_tpu.kernels.kv_write import kv_write
   B, C, H, hd = q.shape
   Lc = cached_k.shape[1]
   scale = 1.0 / jnp.sqrt(hd).astype(dtype)
 
-  def write(cache, new):
-    return jax.vmap(
-        lambda row, chunk, cur: jax.lax.dynamic_update_slice(
-            row, chunk, (cur, 0, 0)))(cache, new.astype(cache.dtype),
-                                      cursors)
-
-  cached_k = write(cached_k, k)
-  cached_v = write(cached_v, v)
+  cached_k, cached_v = kv_write(cached_k, cached_v, k, v, cursors,
+                                impl=write_impl)
   logits = jnp.einsum("bqhd,bkhd->bhqk", q, cached_k) * scale
   # Key position j is visible to query i (absolute position cursor+i)
   # iff j <= cursor + i: the query's own causal prefix, nothing newer,
@@ -257,7 +258,8 @@ def paged_step_logits(model, params, kv, tokens, slot_ids, positions,
   return logits[:, 0], mut["cache"]
 
 
-def slot_step_logits(model, params, kv, tokens, cursors):
+def slot_step_logits(model, params, kv, tokens, cursors,
+                     kv_write_impl=None):
   """Multi-token scoring on the shared slot-cache core — THE device entry
   every serving component steps through.
 
@@ -275,13 +277,17 @@ def slot_step_logits(model, params, kv, tokens, cursors):
     distributions come back in the same call
     (serving/speculative/verify.py).
 
+  ``kv_write_impl`` is the resolved lowering of the cache write
+  (kernels/kv_write.py; ``None`` resolves it from the shapes).
+
   Returns ``(logits [num_slots, C, vocab], new_kv)``; the caller owns
   cursor advancement (and, for speculation, rollback to the last
   accepted position).
   """
   logits, mut = model.apply(
       {"params": params, "cache": kv}, tokens, decode=True,
-      slot_cursors=cursors, mutable=["cache"])
+      slot_cursors=cursors, kv_write_impl=kv_write_impl,
+      mutable=["cache"])
   return logits, mut["cache"]
 
 
@@ -309,6 +315,9 @@ def _dense_causal_attention(q, k, v, dtype):
 class CausalSelfAttention(nn.Module):
   cfg: GPTConfig
   decode: bool = False
+  # Resolved lowering of the slot cache's window write (kernels/
+  # kv_write.py); None = resolve from the shapes when traced.
+  kv_write_impl: Optional[str] = None
 
   @nn.compact
   def __call__(self, x, slot_cursors=None, paged_info=None):
@@ -392,7 +401,8 @@ class CausalSelfAttention(nn.Module):
       ck = self.variable("cache", "cached_key", _missing_slot_cache)
       cv = self.variable("cache", "cached_value", _missing_slot_cache)
       out, ck.value, cv.value = slot_cache_attend(
-          q, k, v, ck.value, cv.value, slot_cursors, cfg.dtype)
+          q, k, v, ck.value, cv.value, slot_cursors, cfg.dtype,
+          write_impl=self.kv_write_impl)
       return out
 
     ck = self.variable("cache", "cached_key",
@@ -413,7 +423,8 @@ class CausalSelfAttention(nn.Module):
     # One-token step == slot attention with a batch-uniform cursor.
     cursors = jnp.broadcast_to(ci.value, (B,))
     out, ck.value, cv.value = slot_cache_attend(
-        q, k, v, ck.value, cv.value, cursors, cfg.dtype)
+        q, k, v, ck.value, cv.value, cursors, cfg.dtype,
+        write_impl=self.kv_write_impl)
     ci.value = ci.value + 1
     return out
 
@@ -439,6 +450,7 @@ class Block(nn.Module):
   use_moe: bool = False
   deterministic: bool = True
   decode: bool = False
+  kv_write_impl: Optional[str] = None
 
   @nn.compact
   def __call__(self, x, slot_cursors=None, paged_info=None):
@@ -448,6 +460,7 @@ class Block(nn.Module):
                       or cfg.dropout_rate == 0.0)
     y = LayerNorm(dtype=cfg.dtype, name="ln1")(x)
     x = x + drop(CausalSelfAttention(cfg, decode=self.decode,
+                                     kv_write_impl=self.kv_write_impl,
                                      name="attn")(y, slot_cursors,
                                                   paged_info))
     y = LayerNorm(dtype=cfg.dtype, name="ln2")(x)
@@ -599,7 +612,7 @@ class GPT(nn.Module):
   @nn.compact
   def __call__(self, ids, deterministic: bool = True,
                decode: bool = False, return_hidden: bool = False,
-               slot_cursors=None, paged_info=None):
+               slot_cursors=None, paged_info=None, kv_write_impl=None):
     from easyparallellibrary_tpu.runtime.amp import resolve_model_dtypes
     cfg = resolve_model_dtypes(self.cfg)
     B, S = ids.shape
@@ -697,8 +710,8 @@ class GPT(nn.Module):
         use_moe = cfg.num_experts > 0 and \
           (i % cfg.moe_every == cfg.moe_every - 1)
         x = block_cls(cfg, use_moe=use_moe, deterministic=deterministic,
-                      decode=decode, name=f"block_{i}")(x, slot_cursors,
-                                                        paged_info)
+                      decode=decode, kv_write_impl=kv_write_impl,
+                      name=f"block_{i}")(x, slot_cursors, paged_info)
 
     x = LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
     if return_hidden:

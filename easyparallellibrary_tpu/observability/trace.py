@@ -149,6 +149,9 @@ class Tracer:
     self.trace_path = trace_path
     self._events: "deque[_Event]" = deque(maxlen=ring_capacity)
     self._tracks: Dict[str, int] = {"main": 0}
+    # Facts decided once (metadata()): exported ahead of the timeline,
+    # outside the ring, so neither eviction nor clear() loses them.
+    self._metadata: Dict[str, Dict[str, Any]] = {}
     # The watchdog monitor thread records instants while the main
     # thread records spans, so track registration (two unsynchronized
     # first-uses could claim the same tid) and the append/eviction
@@ -290,6 +293,15 @@ class Tracer:
               args: Optional[Dict[str, Any]] = None):
     if self.enabled:
       self._append("i", name, cat, self.now_us(), self.track(track), args)
+
+  def metadata(self, name: str, args: Dict[str, Any]):
+    """A fact of the run that is decided once (which lowering a step
+    was built with): exported as a metadata event ahead of the timeline.
+    Kept outside the ring like the track names, so eviction and
+    :meth:`clear` leave it; recording a name again replaces it."""
+    if self.enabled:
+      with self._lock:
+        self._metadata[name] = dict(args)
 
   def counter(self, name: str, value: Union[int, float], cat: str = ""):
     """One sample of a numeric counter track (Perfetto draws a graph)."""
@@ -466,6 +478,7 @@ class Tracer:
     with self._lock:  # a concurrent append must not mutate mid-snapshot
       events = list(self._events)
       tracks = sorted(self._tracks.items(), key=lambda kv: kv[1])
+      facts = sorted(self._metadata.items())
       remote = [(rpid, s["label"],
                  sorted(s["tracks"].items(), key=lambda kv: kv[1]),
                  list(s["events"]))
@@ -476,6 +489,9 @@ class Tracer:
                   "args": {"name": name}})
       out.append({"ph": "M", "name": "thread_sort_index", "pid": pid,
                   "tid": tid, "args": {"sort_index": tid}})
+    for name, args in facts:
+      out.append({"ph": "M", "name": name, "pid": pid, "tid": 0,
+                  "args": args})
     for rpid, label, rtracks, _revents in remote:
       out.append({"ph": "M", "name": "process_name", "pid": rpid,
                   "tid": 0, "args": {"name": label}})

@@ -288,6 +288,18 @@ class ContinuousBatchingEngine:
     else:
       self.block_size = self.num_blocks = self.token_budget = 0
       self._paged_impl = None
+    # The contiguous cache's window write (kernels/kv_write.py): which
+    # lowering the fused step is built with, resolved ONCE here from
+    # the backend, the leaf's shape and dtype and the mesh (None on a
+    # paged engine, whose pools take a scatter of flat rows).
+    self.kv_write_impl = None if self.paged else kv_lib.kv_write_impl(
+        cfg, self.num_slots, self.chunk, self.mesh)
+    if self.kv_write_impl is not None:
+      # A run says which write it timed: a step that fell back shows
+      # "reference".  Metadata, so no ring eviction loses it.
+      trace_lib.get_tracer().metadata(
+          f"{self._track_prefix}/kv_write_impl",
+          {"impl": self.kv_write_impl})
     # Copy-on-write prefix caching (serving.prefix_cache.*;
     # docs/serving.md "Prefix caching"): radix-tree block reuse over
     # the paged pool — the scheduler rejects it without paged mode.
@@ -480,6 +492,7 @@ class ContinuousBatchingEngine:
                 f"{kv_lib.paged_cache_bytes(cfg, self.num_blocks, self.block_size) / 1e6:.1f} MB")
     else:
       layout = (f"contiguous slots, xla attend, "
+                f"{self.kv_write_impl} kv write, "
                 f"{kv_lib.cache_bytes(cfg, self.num_slots, self.chunk) / 1e6:.1f} MB")
     get_logger().info(
         "serving engine: %d slots x chunk %d (%s, %s), "
@@ -582,6 +595,7 @@ class ContinuousBatchingEngine:
         "num_active": sched.num_active,
         "num_slots": self.num_slots,
         "paged": self.paged,
+        "kv_write_impl": self.kv_write_impl,
         "recompiles": self._compile_sentinel.recompiles,
         "active_uids": [str(s.req.uid)
                         for s in sched.active.values()][:32],
@@ -678,11 +692,13 @@ class ContinuousBatchingEngine:
     from easyparallellibrary_tpu.models.gpt import slot_step_logits
     model = self.model
     C = self.chunk
+    write_impl = self.kv_write_impl
 
     def step(params, kv, cursors, tokens, num_valid, reset, keys,
              tok_index, temperature, top_k, top_p):
       cursors = jnp.where(reset, 0, cursors)
-      logits, kv = slot_step_logits(model, params, kv, tokens, cursors)
+      logits, kv = slot_step_logits(model, params, kv, tokens, cursors,
+                                    kv_write_impl=write_impl)
       # Each slot's next-token logits sit at its LAST live chunk
       # position; idle slots (num_valid=0) read position 0 — garbage the
       # scheduler never consumes.
@@ -720,11 +736,13 @@ class ContinuousBatchingEngine:
     model = self.model
     C = self.chunk
     K = self.drafter.k
+    write_impl = self.kv_write_impl
 
     def step(params, kv, cursors, tokens, num_valid, num_draft, reset,
              keys, tok_index, temperature, top_k, top_p):
       cursors = jnp.where(reset, 0, cursors)
-      logits, kv = slot_step_logits(model, params, kv, tokens, cursors)
+      logits, kv = slot_step_logits(model, params, kv, tokens, cursors,
+                                    kv_write_impl=write_impl)
       # base = non-draft tokens fed (prefill grant, or 1 for decode);
       # position base-1+j's logits are the target distribution for
       # draft j, and base-1+num_draft's feed the bonus token.  With

@@ -90,6 +90,23 @@ def kv_cache_shardings(cfg, mesh: Optional[Mesh]):
   return kv, NamedSharding(mesh, P())
 
 
+def kv_write_impl(cfg, num_slots: int, chunk: int,
+                  mesh: Optional[Mesh] = None) -> str:
+  """The lowering of the fused step's window write into the cache
+  :func:`allocate_kv_cache` builds for the same arguments — the
+  dispatch rule of kernels/kv_write.py applied to its leaf: the Pallas
+  kernel on a TPU when the leaf sits whole on one chip and fits the
+  kernel's tiles, ``vmap(dynamic_update_slice)`` everywhere else.
+  Resolved once by whoever builds a step over the cache."""
+  from easyparallellibrary_tpu.kernels.kv_write import (
+      resolve_kv_write_impl)
+  shape = (num_slots, cache_length(cfg, chunk), cfg.num_heads,
+           cfg.d_model // cfg.num_heads)
+  return resolve_kv_write_impl(
+      shape, cfg.dtype, chunk,
+      sharded=mesh is not None and mesh.size > 1)
+
+
 def allocate_kv_cache(cfg, num_slots: int, chunk: int,
                       mesh: Optional[Mesh] = None
                       ) -> Tuple[Dict[str, Any], jax.Array]:

@@ -26,6 +26,7 @@ that case (accept with prob ``p(d)``, residual excludes ``d``).
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -241,32 +242,37 @@ class DraftModelDrafter(Drafter):
     else:
       self._kv, self._cursors = kv_lib.allocate_kv_cache(
           self.model.cfg, engine.num_slots, engine.chunk, mesh)
-      self._fn = self._build_draft_fn(engine.chunk)
+      self._fn = self._build_draft_fn(
+          engine.chunk, kv_lib.kv_write_impl(
+              self.model.cfg, engine.num_slots, engine.chunk, mesh))
 
-  def _build_draft_fn(self, chunk: int):
+  def _build_draft_fn(self, chunk: int, kv_write_impl: str):
     from easyparallellibrary_tpu.models.gpt import slot_step_logits
     model, K, C = self.model, self.k, chunk
+    # One resolved cache-write lowering for the chunk-wide and the
+    # one-token calls alike (what fits a chunk fits one token).
+    score = functools.partial(slot_step_logits,
+                              kv_write_impl=kv_write_impl)
 
     def draft(params, kv, cursors, tokens, num_valid, reset):
       cursors = jnp.where(reset, 0, cursors)
       # Mirror the engine's chunk: writes the same prefill K/V the
       # target wrote, and scores decode slots' last committed token.
-      logits, kv = slot_step_logits(model, params, kv, tokens, cursors)
+      logits, kv = score(model, params, kv, tokens, cursors)
       last = jnp.take_along_axis(
           logits, jnp.clip(num_valid - 1, 0, C - 1)[:, None, None],
           axis=1)[:, 0]
       toks = [jnp.argmax(last, axis=-1).astype(jnp.int32)]
       cur = cursors + num_valid
       for _ in range(1, K):
-        lg, kv = slot_step_logits(model, params, kv, toks[-1][:, None],
-                                  cur)
+        lg, kv = score(model, params, kv, toks[-1][:, None], cur)
         toks.append(jnp.argmax(lg[:, 0], axis=-1).astype(jnp.int32))
         cur = cur + 1
       # Write-only feed of the final draft: its K/V must be cache-
       # resident too — if every draft is accepted the rolled-back cursor
       # covers its position, and a later step would attend garbage
       # there (the logits of this call are dead code XLA prunes).
-      _, kv = slot_step_logits(model, params, kv, toks[-1][:, None], cur)
+      _, kv = score(model, params, kv, toks[-1][:, None], cur)
       return jnp.stack(toks, axis=1), kv
 
     return jax.jit(draft, donate_argnums=(1,))

@@ -1,0 +1,4 @@
+"""Device time a step of the ``kv_write`` kernel where it moves
+``itl_p95_ms``; the arithmetic is ``harness/kernel_time.py``'s."""
+
+from perfbench.harness.kernel_time import kv_write_ms as read  # noqa: F401

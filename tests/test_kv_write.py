@@ -1,0 +1,300 @@
+"""The serving step's in-place K/V window write (kernels/kv_write.py).
+
+One algorithm — write each slot's ``chunk``-wide window at its cursor —
+with two lowerings.  The contract under test: the Pallas kernel leaves
+the WHOLE cache leaf bit-identical to ``vmap(dynamic_update_slice)``
+(K and V, every cursor a tile can see), the dispatch rule declines what
+the kernel cannot tile, the engine commits the same tokens and ends on
+the same cursors under either lowering, and the program compiled for a
+described v5e holds the kernel in place of the scatter loop with no copy
+of a cache leaf.
+"""
+
+import importlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import easyparallellibrary_tpu as epl
+from easyparallellibrary_tpu.models import GPT, GPTConfig
+from easyparallellibrary_tpu.models.gpt import generate, slot_cache_attend
+from easyparallellibrary_tpu.observability import trace as trace_lib
+from easyparallellibrary_tpu.serving import (
+    ContinuousBatchingEngine, Request, kv_cache as kv_lib)
+from easyparallellibrary_tpu.serving.speculative import NgramDrafter
+
+kvw = importlib.import_module("easyparallellibrary_tpu.kernels.kv_write")
+
+MAX_SEQ, CHUNK = 1024, 16
+LC = MAX_SEQ + CHUNK           # 1040: the cell's leaf, 8 tiles and 16 rows
+
+
+def _backend_takes(monkeypatch, impl):
+  """What a test steers: the lowering the backend would take."""
+  monkeypatch.setattr(kvw, "_backend_impl", lambda: impl)
+
+
+def _bits(x):
+  x = np.asarray(x)
+  return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+def _operands(B, Lc, H, hd, C, dtype, seed=0):
+  """Random leaves and chunks, salted with the values arithmetic would
+  not carry through unchanged."""
+  r = np.random.RandomState(seed)
+
+  def salted(shape):
+    x = r.standard_normal(shape).astype(np.float32)
+    flat = x.reshape(-1)
+    flat[r.randint(0, flat.size, 24)] = np.tile(
+        [-0.0, np.nan, np.inf, -np.inf], 6)
+    return jnp.asarray(x, dtype)
+
+  return (salted((B, Lc, H, hd)), salted((B, Lc, H, hd)),
+          salted((B, C, H, hd)), salted((B, C, H, hd)))
+
+
+CURSORS = {
+    "zeros": [0] * 16,
+    "mixed": [0, 1, 15, 16, 17, 100, 111, 128, 129, 255, 256, 300, 511,
+              640, 900, 1007],
+    # Every offset at which a 16-wide window crosses a 128 boundary (and
+    # 112, the last that does not), over several tiles.
+    "straddle": [128 * (i % 7) + 112 + i for i in range(16)],
+    # max_seq_len: the last legal window, rows 1024..1039 of 1040.
+    "last_window": [MAX_SEQ] * 8 + [MAX_SEQ - i for i in range(1, 9)],
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", sorted(CURSORS))
+def test_kernel_leaves_the_whole_leaf_bit_identical(case, dtype):
+  cur = jnp.asarray(CURSORS[case], jnp.int32)
+  ck, cv, k, v = _operands(len(CURSORS[case]), LC, 2, 16, CHUNK, dtype)
+  assert kvw.kv_write_fits(ck.shape, dtype, CHUNK)
+  want_k, want_v = kvw.kv_write_reference(ck, cv, k, v, cur)
+  got_k, got_v = jax.jit(
+      lambda *a: kvw.kv_write(*a, impl="interpret"))(ck, cv, k, v, cur)
+  np.testing.assert_array_equal(_bits(got_k), _bits(want_k))
+  np.testing.assert_array_equal(_bits(got_v), _bits(want_v))
+  # The window did move: the reference is not the leaf it started from.
+  assert (_bits(want_k) != _bits(ck)).any()
+
+
+@pytest.mark.parametrize("C,Lc,cursors", [
+    (1, 256, [0, 127, 128, 255]),       # generate()'s decode: no slack row
+    (32, 288, [0, 97, 127, 200, 256]),  # a wider chunk straddles earlier
+    (128, 384, [0, 1, 127, 128, 256]),  # a window as wide as a tile
+], ids=["decode_1", "chunk_32", "chunk_128"])
+def test_other_chunk_widths_are_bit_identical(C, Lc, cursors):
+  cur = jnp.asarray(cursors, jnp.int32)
+  ck, cv, k, v = _operands(len(cursors), Lc, 2, 16, C, jnp.bfloat16, seed=1)
+  assert kvw.kv_write_fits(ck.shape, ck.dtype, C)
+  want = kvw.kv_write_reference(ck, cv, k, v, cur)
+  got = kvw.kv_write(ck, cv, k, v, cur, impl="interpret")
+  for g, w in zip(got, want):
+    np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+@pytest.mark.parametrize("shape,dtype,chunk,sharded", [
+    ((4, 36, 2, 16), jnp.float32, 4, False),       # under one tile
+    ((4, 1200, 2, 16), jnp.float32, 160, False),   # window over two tiles
+    ((4, 272, 2, 12), jnp.float32, 16, False),     # hd not whole sublanes
+    ((4, 272, 2, 16), jnp.float16, 16, False),     # a dtype it was not
+                                                   # proven on
+    ((4, 272, 64, 128), jnp.float32, 16, False),   # tiles over the VMEM
+    ((4, 272, 2, 16), jnp.float32, 16, True),      # leaf spread over chips
+], ids=["short_leaf", "wide_chunk", "odd_hd", "f16", "vmem", "sharded"])
+def test_what_the_kernel_declines_takes_the_reference(
+    monkeypatch, shape, dtype, chunk, sharded):
+  for impl in ("interpret", "pallas"):
+    _backend_takes(monkeypatch, impl)
+    assert kvw.resolve_kv_write_impl(shape, dtype, chunk, sharded) == \
+        "reference"
+
+
+def test_rule_follows_the_backend(monkeypatch):
+  shape = (4, LC, 2, 16)
+  assert kvw.resolve_kv_write_impl(shape, jnp.bfloat16, CHUNK) == \
+      "reference"                      # this backend is the CPU
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  assert kvw.resolve_kv_write_impl(shape, jnp.bfloat16, CHUNK) == "pallas"
+  assert kvw.resolve_kv_write_impl(shape, jnp.bfloat16, CHUNK,
+                                   sharded=True) == "reference"
+  # A typo'd impl must not fall through to the kernel.
+  ck, cv, k, v = _operands(*shape, CHUNK, jnp.bfloat16)
+  with pytest.raises(ValueError, match="impl must be one of"):
+    kvw.kv_write(ck, cv, k, v, jnp.zeros((4,), jnp.int32), impl="mosaic")
+
+
+def test_declined_shape_runs_the_reference_through_the_dispatcher(
+    monkeypatch):
+  """A backend that takes the kernel, a leaf shorter than a tile:
+  ``slot_cache_attend`` (impl unresolved, as ``generate()`` calls it)
+  must come out equal to the reference write, not fail in the kernel."""
+  _backend_takes(monkeypatch, "interpret")
+  ck, cv, k, v = _operands(3, 36, 2, 16, 4, jnp.float32)
+  cur = jnp.asarray([0, 7, 32], jnp.int32)
+  _, got_k, got_v = slot_cache_attend(k, k, v, ck, cv, cur, jnp.float32)
+  want_k, want_v = kvw.kv_write_reference(ck, cv, k, v, cur)
+  np.testing.assert_array_equal(_bits(got_k), _bits(want_k))
+  np.testing.assert_array_equal(_bits(got_v), _bits(want_v))
+
+
+# ------------------------------------------------------------------ engine
+
+SERVE = GPTConfig(vocab_size=64, num_layers=2, num_heads=2, d_model=32,
+                  d_ff=64, max_seq_len=256, dtype=jnp.float32)
+
+
+def _serve(monkeypatch, impl, drafter=None):
+  """Five greedy requests over three slots, prompts long enough that
+  prefill chunks and decode tokens share steps and that decode cursors
+  walk through a tile boundary one row at a time."""
+  _backend_takes(monkeypatch, impl)
+  tracer = trace_lib.install(trace_lib.Tracer(enabled=True))
+  try:
+    model = GPT(SERVE)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 4), jnp.int32))["params"]
+    eng = ContinuousBatchingEngine(model, params, num_slots=3,
+                                   prefill_chunk=8, drafter=drafter)
+    r = np.random.RandomState(0)
+    prompts = [r.randint(0, 64, (n,)).astype(np.int32)
+               for n in (118, 3, 121, 40, 126)]
+    for i, p in enumerate(prompts):
+      eng.submit(Request(uid=i, prompt=p, max_new_tokens=14))
+    out = eng.run()
+    facts = [e for e in tracer.events()
+             if e["ph"] == "M" and e["name"] == "serving/kv_write_impl"]
+    return (eng, {u: np.asarray(t) for u, t in out.items()},
+            np.asarray(jax.device_get(eng._cursors)), facts, model,
+            params, prompts)
+  finally:
+    trace_lib.install(None)
+
+
+@pytest.mark.parametrize("drafter", [None, "ngram"],
+                         ids=["fused_step", "speculative_step"])
+def test_engine_commits_the_same_under_either_lowering(monkeypatch,
+                                                       drafter):
+  epl.init()
+  mk = lambda: NgramDrafter(k=3, ngram_max=3) if drafter else None
+  eng_k, out_k, cur_k, facts_k, model, params, prompts = _serve(
+      monkeypatch, "interpret", mk())
+  eng_r, out_r, cur_r, facts_r, *_ = _serve(monkeypatch, "reference", mk())
+  # Which write each run timed is on record, not inferred.
+  assert eng_k.kv_write_impl == "interpret"
+  assert eng_r.kv_write_impl == "reference"
+  assert [f["args"] for f in facts_k] == [{"impl": "interpret"}]
+  assert [f["args"] for f in facts_r] == [{"impl": "reference"}]
+  assert sorted(out_k) == sorted(out_r) == list(range(len(prompts)))
+  for uid in out_k:
+    np.testing.assert_array_equal(out_k[uid], out_r[uid])
+  np.testing.assert_array_equal(cur_k, cur_r)
+  # Each step compiled once under its lowering.
+  assert eng_k._step_fn._cache_size() == eng_r._step_fn._cache_size() == 1
+  # And the kernel-written engine still equals the one-request oracle.
+  want = np.asarray(generate(model, params,
+                             jnp.asarray(prompts[2])[None], 14))[0]
+  np.testing.assert_array_equal(out_k[2], want)
+
+
+def test_trace_metadata_outlives_the_ring_and_clear():
+  """What is decided once must still be in the export after the ring has
+  turned over and after the benchmark clears it at its window's start."""
+  tr = trace_lib.Tracer(enabled=True, ring_capacity=4)
+  tr.metadata("serving/kv_write_impl", {"impl": "pallas"})
+  for i in range(10):
+    tr.instant(f"tick{i}")
+  tr.clear()
+  tr.instant("after")
+  events = tr.events()
+  facts = [e for e in events if e["name"] == "serving/kv_write_impl"]
+  assert facts == [{"ph": "M", "name": "serving/kv_write_impl", "pid": 0,
+                    "tid": 0, "args": {"impl": "pallas"}}]
+  assert trace_lib.validate_trace({"traceEvents": events}) == events
+  # Recorded again, it replaces; a disabled tracer records nothing.
+  tr.metadata("serving/kv_write_impl", {"impl": "reference"})
+  assert [e["args"] for e in tr.events()
+          if e["name"] == "serving/kv_write_impl"] == [{"impl": "reference"}]
+  off = trace_lib.Tracer(enabled=False)
+  off.metadata("serving/kv_write_impl", {"impl": "pallas"})
+  assert off.events() == [e for e in off.events() if e["ph"] == "M"
+                          and e["name"] != "serving/kv_write_impl"]
+
+
+def test_engine_on_a_mesh_of_chips_takes_the_reference(monkeypatch):
+  _backend_takes(monkeypatch, "interpret")
+  epl.init(epl.Config({"cluster.mesh_shape": "data:4,model:2"}))
+  mesh = epl.Env.get().cluster.build_mesh()
+  assert kv_lib.kv_write_impl(SERVE, 3, 8, mesh) == "reference"
+  assert kv_lib.kv_write_impl(SERVE, 3, 8, None) == "interpret"
+
+
+# --------------------------------------------------- compiled for the chip
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+  from jax.experimental import topologies
+  try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+  except Exception as e:  # no libtpu here, or another process holds it
+    pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+  return SingleDeviceSharding(topo.devices[0])
+
+
+def test_compiled_for_v5e_holds_the_kernel_and_no_copy_of_a_leaf(
+    one_chip):
+  """``slot_cache_attend`` at the serving cells' shapes, compiled for a
+  described v5e: one ``kv_write`` custom call whose two leaf operands
+  alias its outputs, no ``while`` left of the scatter loop, and nothing
+  but the two attention fusions reads a whole leaf (no copy, no
+  relayout: the transposes around the call are bitcasts)."""
+  B, H, hd = 96, 16, 64
+  dt = jnp.bfloat16
+  spec = lambda shape, d=dt: jax.ShapeDtypeStruct(shape, d,
+                                                  sharding=one_chip)
+  new, leaf = spec((B, CHUNK, H, hd)), spec((B, LC, H, hd))
+  fn = lambda q, k, v, ck, cv, cur: slot_cache_attend(
+      q, k, v, ck, cv, cur, dt, write_impl="pallas")
+  from jax.experimental.compilation_cache import compilation_cache
+  cache_was = jax.config.jax_enable_compilation_cache
+  jax.config.update("jax_enable_compilation_cache", False)
+  compilation_cache.reset_cache()
+  try:
+    text = jax.jit(fn, donate_argnums=(3, 4)).lower(
+        new, new, new, leaf, leaf, spec((B,), jnp.int32)).compile().as_text()
+  finally:
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+  entry = text[text.index("\nENTRY "):]
+  calls = [l for l in entry.splitlines() if " custom-call(" in l
+           and "kv_write" in l.split("=")[0]]
+  assert len(calls) == 1, entry
+  aliasing = re.search(r"output_to_operand_aliasing=\{(.*?)\}, ", calls[0])
+  assert aliasing and "{0}: (3, {})" in aliasing.group(1) \
+      and "{1}: (4, {})" in aliasing.group(1), calls[0]
+  assert " while(" not in text
+  # Instructions of the entry computation whose result is a whole leaf
+  # (in either order of its dimensions): parameters, bitcasts and the
+  # kernel's own results only.
+  leaf_shapes = (f"bf16[{B},{LC},{H},{hd}]", f"bf16[{B},{H},{hd},{LC}]")
+  for line in entry.splitlines():
+    m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) (\S+?)\(", line)
+    if not m or not m.group(2).startswith(leaf_shapes):
+      continue
+    assert m.group(3) in ("parameter", "bitcast", "get-tuple-element"), line
+  # The two attention fusions take the written leaves straight from the
+  # kernel, through a bitcast.
+  readers = [l for l in entry.splitlines() if " fusion(" in l
+             and "kind=kOutput" in l]
+  assert len(readers) == 2, entry
