@@ -12,6 +12,12 @@ a user calls, and checks what comes out by the repo's own references:
   serve           ``ContinuousBatchingEngine`` on the same model, 8
                   requests, contiguous then paged; greedy streams against
                   ``generate(use_cache=True)``
+  hybrid          the selective scan (f32, bf16) against
+                  ``ssm_scan_reference``; a four-layer cut of the hybrid
+                  decoder (models/jamba.py: 3 Mamba + 1 attention layer at
+                  AI21-Jamba2-3B's widths) through the engine: both
+                  kernels resolved, one ``ssm_scan`` call a Mamba layer,
+                  the fused step's logits against the reference lowering
   four chips      (when the machine has four) the trainer as ``data:4``
                   and as ``data:2,model:2``
 
@@ -45,7 +51,10 @@ import easyparallellibrary_tpu as epl
 from easyparallellibrary_tpu.kernels import (
     flash_attention, kv_write_pallas, kv_write_reference,
     paged_attention_pallas, paged_attention_reference)
+from easyparallellibrary_tpu.kernels.ssm_scan import (
+    SSM_SCAN, ssm_scan_pallas, ssm_scan_reference)
 from easyparallellibrary_tpu.models import GPT, GPTConfig
+from easyparallellibrary_tpu.models.jamba import MAMBA, Jamba, JambaConfig
 from easyparallellibrary_tpu.models.gpt import (
     _dense_causal_attention, generate, gpt_loss)
 from easyparallellibrary_tpu.observability.device import specs_of
@@ -82,6 +91,8 @@ class Sizes:
   flash_shapes: tuple             # (B, H, S, D, dtype)
   paged_shape: tuple              # (T, H, hd, block, table width)
   kv_write_shape: tuple           # (slots, Lc, H, hd, chunk)
+  hybrid_cfg: JambaConfig         # four layers of the hybrid decoder
+  ssm_scan_shape: tuple           # (slots, d_state, d_inner, chunk)
 
   @staticmethod
   def real() -> "Sizes":
@@ -103,7 +114,12 @@ class Sizes:
                       (1, 2, 16384, 64, jnp.bfloat16)),
         paged_shape=(16, 16, 64, 16, 8),
         # The serving cache's leaf: 1024 + one chunk of slack.
-        kv_write_shape=(8, 1040, 16, 64, 16))
+        kv_write_shape=(8, 1040, 16, 64, 16),
+        # AI21-Jamba2-3B's widths, one attention layer among three Mamba
+        # layers instead of two among 26; a cache for 1024 positions.
+        hybrid_cfg=JambaConfig(num_layers=4, attn_layer_period=4,
+                               attn_layer_offset=1, max_seq_len=1024),
+        ssm_scan_shape=(16, 16, 5120, 8))
 
   @staticmethod
   def toy() -> "Sizes":
@@ -121,7 +137,13 @@ class Sizes:
         flash_shapes=((1, 2, 128, 32, jnp.float32),
                       (1, 1, 256, 32, jnp.float32)),
         paged_shape=(6, 4, 32, 8, 4),
-        kv_write_shape=(4, 136, 4, 32, 8))
+        kv_write_shape=(4, 136, 4, 32, 8),
+        hybrid_cfg=JambaConfig(
+            vocab_size=512, num_layers=4, d_model=64, d_ff=128, num_heads=4,
+            num_kv_heads=1, attn_layer_period=4, attn_layer_offset=1,
+            mamba_dt_rank=4, max_seq_len=128, dtype=jnp.float32,
+            param_dtype=jnp.float32),
+        ssm_scan_shape=(4, 16, 128, 4))
 
 
 def say(msg: str) -> None:
@@ -609,6 +631,108 @@ def phase_four_chips(sizes: Sizes) -> None:
       "operands are per-chip shards, overlap on/auto does not raise")
 
 
+# ----------------------------------------------------------------- hybrid --
+
+
+def check_ssm_scan(B, N, Di, C, dtype, rehearsal: bool) -> None:
+  """The selective-scan kernel against ``lax.scan``: ragged ``num_valid``
+  (idle slots among them), some slots reset.  State and outputs to
+  float32 rounding (a 16-bit output to its own rounding); an idle slot's
+  state bit for bit."""
+  r = np.random.RandomState(3)
+  f32 = jnp.float32
+  state = jnp.asarray(r.randn(B, N, Di), f32)
+  u, z = (jnp.asarray(r.randn(B, C, Di), dtype) for _ in range(2))
+  delta = jax.nn.softplus(jnp.asarray(r.randn(B, C, Di) - 3.0, f32))
+  Bm, Cm = (jnp.asarray(r.randn(B, C, N), f32) for _ in range(2))
+  A = -jnp.broadcast_to(jnp.arange(1, N + 1, dtype=f32)[:, None], (N, Di))
+  D = jnp.ones((Di,), f32)
+  num_valid = jnp.asarray(([0, C, 1] + list(r.randint(0, C + 1, B)))[:B],
+                          jnp.int32)
+  reset = jnp.asarray(([False, True, False] + list(r.rand(B) < 0.3))[:B])
+  args = (state, u, delta, Bm, Cm, z, A, D, num_valid, reset)
+  kernel = compile_here(
+      functools.partial(ssm_scan_pallas, interpret=rehearsal),
+      *args, mosaic_calls=1, rehearsal=rehearsal)
+  out, new = kernel(*args)
+  ref_out, ref_new = jax.jit(ssm_scan_reference)(*args)
+  e_state, e_out = rel_err(new, ref_new), rel_err(out, ref_out)
+  tol_out = 1e-5 if jnp.dtype(dtype).itemsize == 4 else 1e-2
+  check(e_state <= 1e-5 and e_out <= tol_out,
+        f"ssm_scan {jnp.dtype(dtype).name}: state error {e_state:.3g}, "
+        f"output error {e_out:.3g} against the reference")
+  check((np.asarray(new)[0] == np.asarray(state)[0]).all(),
+        "ssm_scan moved the state of an idle slot")
+  say(f"  ssm_scan slots{B} N{N} Di{Di} chunk{C} {jnp.dtype(dtype).name}: "
+      f"state error {e_state:.2e}, output error {e_out:.2e}")
+
+
+def phase_hybrid(sizes: Sizes) -> None:
+  for dtype in (jnp.float32, jnp.bfloat16):
+    check_ssm_scan(*sizes.ssm_scan_shape, dtype, rehearsal=sizes.rehearsal)
+  cfg = sizes.hybrid_cfg
+  model = Jamba(cfg)
+  params = jax.jit(lambda: model.init(
+      jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32))["params"])()
+  prompts = seeded_requests(sizes, cfg)
+  eng = ContinuousBatchingEngine(model, params)
+  spy = _StepSpecs(eng)
+  for uid, p in enumerate(prompts):
+    check(eng.submit(Request(uid=uid, prompt=p,
+                             max_new_tokens=sizes.new_tokens)),
+          f"request {uid} refused at admission")
+  out = eng.run()
+  for uid, p in enumerate(prompts):
+    check(uid in out and len(out[uid]) == len(p) + sizes.new_tokens,
+          f"hybrid request {uid} did not run to its length")
+  check(spy._cache_size() == 1,
+        f"hybrid fused step compiled {spy._cache_size()} times")
+  n_mamba = cfg.layer_kinds().count(MAMBA)
+  say(f"  hybrid engine: {len(prompts)} requests, kv write "
+      f"{eng.kv_write_impl}, ssm scan {eng.ssm_scan_impl}, cache "
+      f"{eng.cache_layout}")
+  if not sizes.rehearsal:
+    check(eng.kv_write_impl == "pallas" and eng.ssm_scan_impl == "pallas",
+          f"hybrid engine resolved kv write {eng.kv_write_impl!r}, ssm "
+          f"scan {eng.ssm_scan_impl!r}: not the kernels")
+    hlo = spy.inner.lower(*spy.specs).compile().as_text()
+    scans = len(re.findall(rf"%{SSM_SCAN}[.\d]* = ", hlo))
+    check(scans == n_mamba and hlo.count(MOSAIC_CALL) == cfg.num_layers,
+          f"{scans} ssm_scan calls and {hlo.count(MOSAIC_CALL)} Mosaic "
+          f"calls in the hybrid step, expected {n_mamba} and "
+          f"{cfg.num_layers}")
+  # One fused call, the kernel against the reference lowering, on the
+  # same inputs: prefill chunks, decodes and idle slots side by side.
+  from easyparallellibrary_tpu.models.gpt import slot_step_logits
+  from easyparallellibrary_tpu.serving import kv_cache as kv_lib
+  N, C = 8, eng.chunk
+  r = np.random.RandomState(4)
+  tokens = jnp.asarray(r.randint(0, cfg.vocab_size, (N, C)), jnp.int32)
+  num_valid = jnp.asarray([C, 1, 0, C // 2, 1, C, 0, 1], jnp.int32)
+  first = jnp.asarray([True, False, False, True] * 2)
+  kernel_impl = "interpret" if sizes.rehearsal else "pallas"
+  logits = {}
+  for impl in (kernel_impl, "reference"):
+    kv, cursors = kv_lib.allocate_kv_cache(cfg, N, C)
+    step = jax.jit(functools.partial(
+        slot_step_logits, model, ssm_scan_impl=impl))
+    # the second call runs on the state the first carried over
+    for reset in (first, jnp.zeros_like(first)):
+      got, kv = step(params, kv, tokens, cursors, num_valid=num_valid,
+                     reset=reset)
+      cursors = cursors + num_valid
+    logits[impl] = got[np.asarray(num_valid) > 0]
+  err = rel_err(logits[kernel_impl], logits["reference"])
+  tol = 1e-4 if jnp.dtype(cfg.dtype).itemsize == 4 else 3e-2
+  check(err <= tol, f"hybrid step logits, kernel against the reference "
+        f"lowering: {err:.3g} of the largest logit (limit {tol})")
+  say(f"PASS hybrid: ssm_scan f32 + bf16 "
+      + ("INTERPRETED" if sizes.rehearsal else "compiled")
+      + f" within float32 rounding of the reference; {n_mamba} Mamba + "
+      f"{cfg.num_layers - n_mamba} attention layers served; step logits "
+      f"kernel against reference lowering {err:.2e}")
+
+
 # ------------------------------------------------------------------- main --
 
 
@@ -639,7 +763,8 @@ def main(argv=None) -> int:
 
   for name, phase in (("kernels", lambda: phase_kernels(sizes)),
                       ("train", lambda: phase_train(sizes, dev)),
-                      ("serve", lambda: phase_serve(sizes))):
+                      ("serve", lambda: phase_serve(sizes)),
+                      ("hybrid", lambda: phase_hybrid(sizes))):
     t0 = time.perf_counter()
     say(f"== {name}")
     phase()

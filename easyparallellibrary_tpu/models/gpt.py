@@ -112,7 +112,9 @@ def slot_cache_attend(q, k, v, cached_k, cached_v, cursors, dtype,
   new tokens per slot (C == 1 for pure decode), ``cached_k``/``cached_v``
   are ``[B, Lc, H, hd]`` per-slot caches, and ``cursors`` is an int32
   ``[B]`` vector of write offsets — how many tokens each slot already
-  holds.  Token ``i`` of slot ``b`` lands at cache position
+  holds.  Grouped K/V heads (models/jamba.py): ``k``/``v`` and the cache
+  leaves may carry ``H_kv < H`` heads, each shared by ``H / H_kv`` query
+  heads; with ``H_kv == H`` the program is the one it always was.  Token ``i`` of slot ``b`` lands at cache position
   ``cursors[b] + i`` and attends causally over positions
   ``<= cursors[b] + i``, so a chunk replays exactly the dense causal
   prefill for its token range.  ``Lc`` must be at least
@@ -153,16 +155,25 @@ def slot_cache_attend(q, k, v, cached_k, cached_v, cursors, dtype,
 
   cached_k, cached_v = kv_write(cached_k, cached_v, k, v, cursors,
                                 impl=write_impl)
-  logits = jnp.einsum("bqhd,bkhd->bhqk", q, cached_k) * scale
+  Hkv = cached_k.shape[2]
+  # Grouped heads: query head h reads K/V head h // (H / H_kv); the
+  # group is one more axis of the same two contractions.
+  if Hkv != H:
+    q = q.reshape(B, C, Hkv, H // Hkv, hd)
+  qk, pv = (("bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd") if Hkv == H else
+            ("bqhgd,bkhd->bhgqk", "bhgqk,bkhd->bqhgd"))
+  logits = jnp.einsum(qk, q, cached_k) * scale
   # Key position j is visible to query i (absolute position cursor+i)
   # iff j <= cursor + i: the query's own causal prefix, nothing newer,
   # nothing stale.
   pos = cursors[:, None, None, None] + jnp.arange(C)[None, None, :, None]
   valid = jnp.arange(Lc)[None, None, None, :] <= pos
+  if Hkv != H:
+    valid = valid[:, :, None]
   logits = jnp.where(valid, logits, jnp.asarray(-1e9, logits.dtype))
   probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-  out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(dtype), cached_v)
-  return out, cached_k, cached_v
+  out = jnp.einsum(pv, probs.astype(dtype), cached_v)
+  return out.reshape(B, C, H, hd), cached_k, cached_v
 
 
 @dataclasses.dataclass
@@ -259,7 +270,7 @@ def paged_step_logits(model, params, kv, tokens, slot_ids, positions,
 
 
 def slot_step_logits(model, params, kv, tokens, cursors,
-                     kv_write_impl=None):
+                     kv_write_impl=None, **state_args):
   """Multi-token scoring on the shared slot-cache core — THE device entry
   every serving component steps through.
 
@@ -279,6 +290,10 @@ def slot_step_logits(model, params, kv, tokens, cursors,
 
   ``kv_write_impl`` is the resolved lowering of the cache write
   (kernels/kv_write.py; ``None`` resolves it from the shapes).
+  ``state_args`` go to a model whose layers keep recurrent state beside
+  K/V (models/jamba.py: ``num_valid``, ``reset``, ``ssm_scan_impl``) — a
+  recurrence, unlike a cache under a cursor, must be told how many of the
+  chunk's positions are real; a GPT takes none.
 
   Returns ``(logits [num_slots, C, vocab], new_kv)``; the caller owns
   cursor advancement (and, for speculation, rollback to the last
@@ -287,7 +302,7 @@ def slot_step_logits(model, params, kv, tokens, cursors,
   logits, mut = model.apply(
       {"params": params, "cache": kv}, tokens, decode=True,
       slot_cursors=cursors, kv_write_impl=kv_write_impl,
-      mutable=["cache"])
+      mutable=["cache"], **state_args)
   return logits, mut["cache"]
 
 

@@ -1,0 +1,326 @@
+"""Jamba — the hybrid decoder: Mamba layers with one attention layer a
+period, a gated MLP after every mixer.
+
+Lieber et al. 2024 as ``transformers`` runs ``model_type: jamba`` with one
+expert: pre-RMSNorm residual layers; layer ``i`` mixes by attention where
+``i % attn_layer_period == attn_layer_offset`` (grouped K/V heads, NO
+positional encoding of any kind) and by a Mamba-1 block elsewhere (causal
+depthwise convolution, input-dependent ``dt``/``B``/``C`` each through an
+RMSNorm of its own, selective scan, gate); a final RMSNorm; the head tied
+to the embedding.  ``perfbench/reference/jamba.py`` holds the same
+equations in plain float32 and the tests compare the two.
+
+Serving only.  The model exposes the surface the continuous-batching
+engine steps through (``model.cfg``, ``model.apply(..., decode=True,
+slot_cursors=..., mutable=["cache"])``, ``models.gpt.slot_step_logits``)
+and keeps TWO kinds of per-slot state in the ``cache`` collection, which
+``serving/kv_cache.py`` allocates from :meth:`JambaConfig.layer_kinds`:
+
+* attention layers: ``cached_key`` / ``cached_value`` ``[slots, Lc, H_kv,
+  hd]`` under a cursor, through ``models.gpt.slot_cache_attend``;
+* Mamba layers: ``conv_state`` ``[slots, d_conv - 1, d_inner]`` (the last
+  inputs of the convolution) and ``ssm_state`` float32 ``[slots, d_state,
+  d_inner]`` (state-major: the channels ride the lanes).
+
+A K/V cache tolerates a partly valid chunk — garbage beyond the cursor is
+masked and later overwritten.  A recurrence does not: both Mamba states
+advance by exactly ``num_valid`` tokens a slot (0 leaves them bit for
+bit), and a slot that starts a request (``reset``) starts from zero state.
+Rolling a request back by moving a cursor cannot roll a recurrence back:
+the paged layout, prefix caching, speculation and the guarded retry refuse
+this model (``serving/_capabilities.py``).  Training (the scan's backward)
+is not built.
+
+Precision: the residual stream and the matmuls in ``cfg.dtype``; norms,
+the convolution, ``softplus``, the scan and the states in float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from flax import linen as nn
+
+from easyparallellibrary_tpu.models.gpt import (
+    _missing_slot_cache, slot_cache_attend)
+from easyparallellibrary_tpu.ops import Dense, Embedding
+
+# What a layer keeps per slot: the cache manager's vocabulary
+# (serving/kv_cache.py reads ``cfg.layer_kinds()``).
+ATTENTION, MAMBA = "attention", "mamba"
+
+
+@dataclasses.dataclass(frozen=True)
+class JambaConfig:
+  vocab_size: int = 65536
+  num_layers: int = 28
+  d_model: int = 2560
+  d_ff: int = 8192
+  num_heads: int = 20
+  num_kv_heads: int = 1
+  attn_layer_period: int = 14
+  attn_layer_offset: int = 7
+  mamba_d_state: int = 16
+  mamba_d_conv: int = 4
+  mamba_expand: int = 2
+  mamba_dt_rank: int = 160
+  rms_norm_eps: float = 1e-6
+  max_seq_len: int = 8192            # served context; the cache's length
+  dtype: Any = jnp.bfloat16
+  param_dtype: Any = jnp.bfloat16
+
+  @property
+  def head_dim(self) -> int:
+    return self.d_model // self.num_heads
+
+  @property
+  def d_inner(self) -> int:
+    return self.mamba_expand * self.d_model
+
+  def layer_kinds(self) -> tuple:
+    """Per layer, which state it keeps: :data:`ATTENTION` where ``i %
+    attn_layer_period == attn_layer_offset``, :data:`MAMBA` elsewhere."""
+    return tuple(
+        ATTENTION if i % self.attn_layer_period == self.attn_layer_offset
+        else MAMBA for i in range(self.num_layers))
+
+
+def _boxed(init, ndim: int):
+  return nn.with_partitioning(init, (None,) * ndim)
+
+
+class RMSNorm(nn.Module):
+  """``x * rsqrt(mean(x^2) + eps) * g`` in float32; the gain is a float32
+  parameter whatever the weights' dtype."""
+  eps: float
+  dtype: Any
+
+  @nn.compact
+  def __call__(self, x):
+    g = self.param("scale", _boxed(nn.initializers.ones_init(), 1),
+                   (x.shape[-1],), jnp.float32)
+    x = x.astype(jnp.float32)
+    y = x * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * g
+    return y.astype(self.dtype)
+
+
+def _dense(cfg: JambaConfig, features: int, name: str):
+  return Dense(features, use_bias=False, parallel="none", dtype=cfg.dtype,
+               param_dtype=cfg.param_dtype,
+               kernel_init=nn.initializers.normal(stddev=0.02), name=name)
+
+
+def gqa_causal_attention(q, k, v, dtype):
+  """Dense causal attention of ``q`` [B, S, H, hd] over ``k``/``v`` [B, S,
+  H_kv, hd], each K/V head shared by H / H_kv query heads: the full
+  forward's attention (float32 softmax, as ``_dense_causal_attention``)."""
+  B, S, H, hd = q.shape
+  Hkv = k.shape[2]
+  q = q.reshape(B, S, Hkv, H // Hkv, hd)
+  scale = 1.0 / jnp.sqrt(hd).astype(dtype)
+  logits = jnp.einsum("bqhgd,bkhd->bhgqk", q, k) * scale
+  mask = jnp.tril(jnp.ones((S, S), jnp.bool_))
+  logits = jnp.where(mask, logits, jnp.asarray(-1e9, logits.dtype))
+  probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+  out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(dtype), v)
+  return out.reshape(B, S, H, hd)
+
+
+class AttentionMixer(nn.Module):
+  cfg: JambaConfig
+  decode: bool = False
+  kv_write_impl: Optional[str] = None
+
+  @nn.compact
+  def __call__(self, h, slot_cursors=None):
+    cfg = self.cfg
+    B, S, _ = h.shape
+    H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    q = _dense(cfg, H * hd, "q")(h).reshape(B, S, H, hd)
+    k = _dense(cfg, Hkv * hd, "k")(h).reshape(B, S, Hkv, hd)
+    v = _dense(cfg, Hkv * hd, "v")(h).reshape(B, S, Hkv, hd)
+    if self.decode:
+      ck = self.variable("cache", "cached_key", _missing_slot_cache)
+      cv = self.variable("cache", "cached_value", _missing_slot_cache)
+      out, ck.value, cv.value = slot_cache_attend(
+          q, k, v, ck.value, cv.value, slot_cursors, cfg.dtype,
+          write_impl=self.kv_write_impl)
+    else:
+      out = gqa_causal_attention(q, k, v, cfg.dtype)
+    return _dense(cfg, cfg.d_model, "o")(out.reshape(B, S, H * hd))
+
+
+def advance_window(full, num_valid, keep: int):
+  """The convolution's carried inputs after a chunk: rows ``[num_valid,
+  num_valid + keep)`` of ``full`` [B, keep + C, Di] (the old window
+  followed by the chunk's inputs), per slot.  A select and a sum over the
+  few rows, not a gather: exact, and no serial loop over the slots on a
+  TPU.  ``num_valid = 0`` returns the old window bit for bit."""
+  if num_valid is None:
+    return full[:, full.shape[1] - keep:]
+  rows = num_valid[:, None] + jnp.arange(keep)[None]          # [B, keep]
+  pick = rows[:, :, None] == jnp.arange(full.shape[1])[None, None]
+  return jnp.sum(jnp.where(pick[..., None], full[:, None],
+                           jnp.zeros((), full.dtype)), axis=2)
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+  """Mamba's own: the inverse softplus of a step drawn log-uniform in
+  [1e-3, 1e-1]."""
+  dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
+               * (np.log(1e-1) - np.log(1e-3)) + np.log(1e-3))
+  dt = jnp.maximum(dt, 1e-4)
+  return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+  """``log(1..N)`` down the state axis of ``[N, Di]``."""
+  n = jnp.arange(1, shape[0] + 1, dtype=jnp.float32)
+  return jnp.broadcast_to(jnp.log(n)[:, None], shape).astype(dtype)
+
+
+def _uniform(bound: float):
+  def init(key, shape, dtype=jnp.float32):
+    return jax.random.uniform(key, shape, jnp.float32, -bound,
+                              bound).astype(dtype)
+  return init
+
+
+class MambaMixer(nn.Module):
+  """The Mamba-1 mixer with Jamba's three inner norms.  Parameters the
+  recurrence depends on are float32 (``A_log`` and ``D`` state-major
+  ``[d_state, d_inner]`` / ``[d_inner]``, the ``dt`` bias); the
+  convolution's taps are ``[d_conv, d_inner]``, tap ``d_conv - 1`` on the
+  current token."""
+  cfg: JambaConfig
+  decode: bool = False
+  ssm_scan_impl: Optional[str] = None
+
+  @nn.compact
+  def __call__(self, h, num_valid=None, reset=None):
+    from easyparallellibrary_tpu.kernels.ssm_scan import ssm_scan
+    cfg = self.cfg
+    B, C, _ = h.shape
+    Di, N, K, R = (cfg.d_inner, cfg.mamba_d_state, cfg.mamba_d_conv,
+                   cfg.mamba_dt_rank)
+    f32 = jnp.float32
+    uz = _dense(cfg, 2 * Di, "in_proj")(h)
+    u, z = uz[..., :Di], uz[..., Di:]
+    conv_w = self.param("conv_w", _boxed(_uniform(K ** -0.5), 2), (K, Di),
+                        cfg.param_dtype)
+    conv_b = self.param("conv_b", _boxed(_uniform(K ** -0.5), 1), (Di,),
+                        cfg.param_dtype)
+    if self.decode:
+      conv_var = self.variable("cache", "conv_state", _missing_slot_cache)
+      ssm_var = self.variable("cache", "ssm_state", _missing_slot_cache)
+      window, state = conv_var.value, ssm_var.value
+      if reset is not None:
+        window = jnp.where(reset[:, None, None],
+                           jnp.zeros((), window.dtype), window)
+    else:
+      window = jnp.zeros((B, K - 1, Di), u.dtype)
+      state = jnp.zeros((B, N, Di), f32)
+    full = jnp.concatenate([window.astype(u.dtype), u], axis=1)
+    w32 = jnp.asarray(conv_w, f32)
+    conv = sum(full[:, j:j + C].astype(f32) * w32[j] for j in range(K)) \
+        + jnp.asarray(conv_b, f32)
+    u = jax.nn.silu(conv).astype(cfg.dtype)
+    if self.decode:
+      conv_var.value = advance_window(full, num_valid, K - 1)
+
+    dbc = _dense(cfg, R + 2 * N, "x_proj")(u)
+    norm = lambda name: RMSNorm(cfg.rms_norm_eps, f32, name=name)
+    dt = norm("dt_norm")(dbc[..., :R])
+    Bm = norm("b_norm")(dbc[..., R:R + N])
+    Cm = norm("c_norm")(dbc[..., R + N:])
+    dt_w = self.param("dt_proj", _boxed(_uniform(R ** -0.5), 2), (R, Di),
+                      cfg.param_dtype)
+    dt_b = self.param("dt_bias", _boxed(_dt_bias_init, 1), (Di,), f32)
+    delta = jax.nn.softplus(
+        jnp.matmul(dt.astype(cfg.dtype), jnp.asarray(dt_w, cfg.dtype),
+                   preferred_element_type=f32) + dt_b)
+    a_log = self.param("A_log", _boxed(_a_log_init, 2), (N, Di), f32)
+    d_skip = self.param("D", _boxed(nn.initializers.ones_init(), 1), (Di,),
+                        f32)
+    y, state = ssm_scan(state, u, delta, Bm, Cm, z, -jnp.exp(a_log), d_skip,
+                        num_valid=num_valid, reset=reset,
+                        impl=self.ssm_scan_impl)
+    if self.decode:
+      ssm_var.value = state
+    return _dense(cfg, cfg.d_model, "out_proj")(y)
+
+
+class GatedMLP(nn.Module):
+  cfg: JambaConfig
+
+  @nn.compact
+  def __call__(self, h):
+    cfg = self.cfg
+    gate = _dense(cfg, cfg.d_ff, "gate")(h)
+    up = _dense(cfg, cfg.d_ff, "up")(h)
+    return _dense(cfg, cfg.d_model, "down")(jax.nn.silu(gate) * up)
+
+
+class JambaBlock(nn.Module):
+  cfg: JambaConfig
+  kind: str
+  decode: bool = False
+  kv_write_impl: Optional[str] = None
+  ssm_scan_impl: Optional[str] = None
+
+  @nn.compact
+  def __call__(self, x, slot_cursors=None, num_valid=None, reset=None):
+    cfg = self.cfg
+    norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
+    h = norm("norm_in")(x)
+    if self.kind == ATTENTION:
+      mixed = AttentionMixer(cfg, decode=self.decode,
+                             kv_write_impl=self.kv_write_impl,
+                             name="attn")(h, slot_cursors)
+    else:
+      mixed = MambaMixer(cfg, decode=self.decode,
+                         ssm_scan_impl=self.ssm_scan_impl,
+                         name="mamba")(h, num_valid, reset)
+    x = x + mixed
+    return x + GatedMLP(cfg, name="mlp")(norm("norm_ff")(x))
+
+
+class Jamba(nn.Module):
+  """Decoder-only hybrid LM.  ``__call__(ids) -> logits`` is the full
+  forward from zero state; ``decode=True`` with ``slot_cursors`` is the
+  serving engine's slot mode (module docstring): ``num_valid`` int32
+  ``[slots]`` says how many of the chunk's positions each slot's
+  recurrent state takes (``None``: all), ``reset`` bool ``[slots]`` which
+  slots start from zero state."""
+
+  cfg: JambaConfig
+
+  @nn.compact
+  def __call__(self, ids, decode: bool = False, return_hidden: bool = False,
+               slot_cursors=None, num_valid=None, reset=None,
+               kv_write_impl=None, ssm_scan_impl=None):
+    cfg = self.cfg
+    if decode and slot_cursors is None:
+      raise ValueError(
+          "Jamba decodes in slot mode only: pass slot_cursors= and a slot "
+          "cache from serving.kv_cache.allocate_kv_cache (the serving "
+          "engine does)")
+    if slot_cursors is not None and not decode:
+      raise ValueError("slot_cursors is a decode-mode argument (serving "
+                       "engine); pass decode=True")
+    tok = Embedding(cfg.vocab_size, cfg.d_model, parallel="none",
+                    param_dtype=cfg.param_dtype, name="embed")
+    x = tok(ids).astype(cfg.dtype)
+    for i, kind in enumerate(cfg.layer_kinds()):
+      x = JambaBlock(cfg, kind, decode=decode, kv_write_impl=kv_write_impl,
+                     ssm_scan_impl=ssm_scan_impl, name=f"block_{i}")(
+                         x, slot_cursors, num_valid, reset)
+    x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm_f")(x)
+    if return_hidden:
+      return x
+    return tok.attend(x)

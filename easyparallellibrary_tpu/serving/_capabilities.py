@@ -14,14 +14,21 @@ work, safe to call before any allocation.
 from __future__ import annotations
 
 ROADMAP_PP_SERVING = (
-    "pipeline-parallel serving is a ROADMAP open item ('Pipeline-parallel "
-    "serving'; docs/serving.md 'Current limits')")
+    "pipeline-parallel serving is ROADMAP item R9 ('Multi-chip serving'; "
+    "docs/serving.md 'Current limits')")
 ROADMAP_MOE_SERVING = (
-    "MoE serving (expert-parallel decode) is a ROADMAP open item "
-    "('MoE serving'; docs/serving.md 'Current limits')")
+    "MoE serving (expert decode inside the fused step) is ROADMAP item R4 "
+    "('Sparse experts that serve'; docs/serving.md 'Current limits')")
 ROADMAP_DRAFT_DISTILL = (
-    "training a matched drafter is a ROADMAP follow-up ('draft-model "
-    "distillation'; docs/serving.md 'Speculative decoding')")
+    "a drafter trained on the target's tokenizer is not queued; ROADMAP "
+    "item R2's speculative cell decides whether the draft-model path "
+    "stays (docs/serving.md 'Speculative decoding')")
+ROADMAP_RECURRENT_STATE = (
+    "a recurrence cannot be rolled back by moving a cursor: snapshots of "
+    "recurrent state (prefix caching, paged and speculative rollback, the "
+    "guarded retry) are ROADMAP item R7 ('Recurrent state beside KV'; "
+    "docs/serving.md 'Current limits'); serve it through the contiguous "
+    "cache with those features off")
 ROADMAP_PREEMPTION = (
     "priority reorders ADMISSION, and on the paged engine "
     "(serving.paged.enabled) a RUNNING throughput-class slot is "
@@ -75,22 +82,41 @@ def check_request_fields(req) -> None:
 def check_servable(cfg, role: str = "the serving engine") -> None:
   """Reject model configs the serving stack cannot run.
 
-  ``cfg`` is a :class:`models.gpt.GPTConfig` (or anything exposing
-  ``pipeline_stages`` / ``num_experts``); ``role`` names the component
-  doing the rejecting so a draft-model failure reads differently from a
+  ``cfg`` is a :class:`models.gpt.GPTConfig` or a
+  :class:`models.jamba.JambaConfig` (a config without ``pipeline_stages``
+  / ``num_experts`` has neither); ``role`` names the component doing the
+  rejecting so a draft-model failure reads differently from a
   target-model one.
   """
-  if cfg.pipeline_stages > 1:
+  stages = getattr(cfg, "pipeline_stages", 1)
+  if stages > 1:
     raise ValueError(
         f"{role} is single-program (pipeline_stages=1) but got "
-        f"pipeline_stages={cfg.pipeline_stages}; restore the checkpoint "
+        f"pipeline_stages={stages}; restore the checkpoint "
         f"into a non-pipelined config (runtime.saver.restore_params) — "
         f"{ROADMAP_PP_SERVING}")
-  if cfg.num_experts > 0:
+  experts = getattr(cfg, "num_experts", 0)
+  if experts > 0:
     raise ValueError(
         f"{role} does not support MoE checkpoints yet "
-        f"(num_experts={cfg.num_experts}); restore a dense checkpoint — "
+        f"(num_experts={experts}); restore a dense checkpoint — "
         f"{ROADMAP_MOE_SERVING}")
+
+
+def check_recurrent_state(cfg, feature: str) -> None:
+  """Reject ``feature`` (the paged cache, prefix caching, speculative
+  decoding, the guarded retry, a draft model) for a model some of whose
+  layers keep recurrent state (``cfg.layer_kinds()``, models/jamba.py):
+  each of them takes a request back to an earlier position by moving a
+  cursor or dropping blocks, and stale recurrent state is masked by
+  nothing.  ONE message for every such composition.  Preemption by
+  replay (scheduler.requeue_slot) is not among them: a replay starts
+  from ``reset``."""
+  from easyparallellibrary_tpu.serving.kv_cache import has_recurrent_state
+  if has_recurrent_state(cfg):
+    raise ValueError(
+        f"{feature} is not available for a model with recurrent-state "
+        f"layers ({type(cfg).__name__}) — {ROADMAP_RECURRENT_STATE}")
 
 
 def check_draft_compatible(target_cfg, draft_cfg) -> None:
@@ -103,6 +129,8 @@ def check_draft_compatible(target_cfg, draft_cfg) -> None:
   that asymmetry is the whole point of a drafter.
   """
   check_servable(draft_cfg, role="a draft model")
+  check_recurrent_state(draft_cfg, "a draft model (its rejected drafts "
+                        "roll back)")
   if draft_cfg.vocab_size != target_cfg.vocab_size:
     raise ValueError(
         f"draft model vocab_size {draft_cfg.vocab_size} != target "

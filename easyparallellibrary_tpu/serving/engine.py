@@ -75,7 +75,7 @@ from easyparallellibrary_tpu.observability.registry import (
     SERVING_NAMESPACE, MetricRegistry)
 from easyparallellibrary_tpu.serving import kv_cache as kv_lib
 from easyparallellibrary_tpu.serving._capabilities import (
-    check_draft_fits_chunk, check_servable)
+    check_draft_fits_chunk, check_recurrent_state, check_servable)
 from easyparallellibrary_tpu.serving.resilience import (
     AdmissionController, BadStepPolicy, DEGRADE_LEVELS)
 from easyparallellibrary_tpu.serving.scheduler import (
@@ -263,6 +263,8 @@ class ContinuousBatchingEngine:
     # num_slots * max_seq_len.
     pconf = conf.paged
     self.paged = paged if paged is not None else pconf.enabled
+    if self.paged:
+      check_recurrent_state(cfg, "the paged cache (serving.paged)")
     eff_batch = max_batch if max_batch is not None else conf.max_batch
     if self.paged:
       self.block_size = (block_size if block_size is not None
@@ -300,14 +302,36 @@ class ContinuousBatchingEngine:
       trace_lib.get_tracer().metadata(
           f"{self._track_prefix}/kv_write_impl",
           {"impl": self.kv_write_impl})
+    # Recurrent state beside K/V (models/jamba.py): the scan's lowering
+    # (kernels/ssm_scan.py), resolved once by the same kind of rule, and
+    # what the cache holds of each kind of state.  None / absent for a
+    # model whose every layer is attention.
+    self._recurrent = kv_lib.has_recurrent_state(cfg)
+    self.ssm_scan_impl = kv_lib.ssm_scan_impl(
+        cfg, self.num_slots, self.chunk, self.mesh)
+    self.cache_layout = None
+    if self._recurrent:
+      self.cache_layout = kv_lib.cache_layout(cfg, self.num_slots,
+                                              self.chunk)
+      trace_lib.get_tracer().metadata(
+          f"{self._track_prefix}/ssm_scan_impl",
+          {"impl": self.ssm_scan_impl})
+      trace_lib.get_tracer().metadata(
+          f"{self._track_prefix}/cache_layout", dict(self.cache_layout))
     # Copy-on-write prefix caching (serving.prefix_cache.*;
     # docs/serving.md "Prefix caching"): radix-tree block reuse over
     # the paged pool — the scheduler rejects it without paged mode.
     pc_conf = conf.prefix_cache
     self.prefix_caching = (prefix_cache if prefix_cache is not None
                            else pc_conf.enabled)
+    if self.prefix_caching:
+      check_recurrent_state(cfg, "prefix caching (serving.prefix_cache)")
     self.drafter = self._resolve_drafter(conf, drafter, speculative,
                                          draft_model, draft_params)
+    if self.drafter is not None:
+      check_recurrent_state(
+          cfg, "speculative decoding (serving.speculative: rejected "
+          "drafts roll back)")
     self.scheduler = FCFSScheduler(
         num_slots=self.num_slots, prefill_chunk=self.chunk,
         max_seq_len=cfg.max_seq_len, prefill_token_budget=budget,
@@ -325,6 +349,10 @@ class ContinuousBatchingEngine:
     res_conf = conf.resilience
     self._resilient = (resilience if resilience is not None
                        else res_conf.enabled)
+    if self._resilient:
+      check_recurrent_state(
+          cfg, "the guarded step (serving.resilience: a retried step "
+          "needs the state it started from)")
     self.stats = stats
     if self._resilient and self.stats is None:
       # The degradation ladder reads measured ITL from ServingStats;
@@ -412,7 +440,9 @@ class ContinuousBatchingEngine:
     # guaranteed to rewrite its OWN grant window, which can be smaller
     # than the bad step's (speculation degraded off, drafter fault,
     # prefill budget tightened between steps).  Separate tiny program;
-    # dispatched only on bad-step events, compiles once.  The SAME
+    # dispatched only on bad-step events, compiles once.  Every leaf it
+    # sees is K/V with a position axis: a model with recurrent state (no
+    # such axis) is refused the guarded step above.  The SAME
     # program serves both layouts: dim 0 is slots (contiguous) or pool
     # blocks (paged), dim 1 rows within — the paged host side maps slot
     # block lists to (block mask, per-block start row) and always
@@ -494,6 +524,12 @@ class ContinuousBatchingEngine:
       layout = (f"contiguous slots, xla attend, "
                 f"{self.kv_write_impl} kv write, "
                 f"{kv_lib.cache_bytes(cfg, self.num_slots, self.chunk) / 1e6:.1f} MB")
+      if self._recurrent:
+        lay = self.cache_layout
+        layout += (f": {lay['kv_leaves']} K/V leaves "
+                   f"{lay['kv_bytes'] / 1e6:.1f} MB + {lay['state_leaves']} "
+                   f"recurrent-state leaves {lay['state_bytes'] / 1e6:.1f} "
+                   f"MB, {self.ssm_scan_impl} ssm scan")
     get_logger().info(
         "serving engine: %d slots x chunk %d (%s, %s), "
         "prefill budget %s, max batch %d, speculation %s, resilience %s",
@@ -596,6 +632,7 @@ class ContinuousBatchingEngine:
         "num_slots": self.num_slots,
         "paged": self.paged,
         "kv_write_impl": self.kv_write_impl,
+        "ssm_scan_impl": self.ssm_scan_impl,
         "recompiles": self._compile_sentinel.recompiles,
         "active_uids": [str(s.req.uid)
                         for s in sched.active.values()][:32],
@@ -693,12 +730,19 @@ class ContinuousBatchingEngine:
     model = self.model
     C = self.chunk
     write_impl = self.kv_write_impl
+    scan_impl = self.ssm_scan_impl
+    recurrent = self._recurrent
 
     def step(params, kv, cursors, tokens, num_valid, reset, keys,
              tok_index, temperature, top_k, top_p):
       cursors = jnp.where(reset, 0, cursors)
+      # A cache under a cursor masks what lies beyond it; a recurrence
+      # must be told how far each slot really advances and which slots
+      # start a request (stale state is masked by nothing).
+      state_args = dict(num_valid=num_valid, reset=reset,
+                        ssm_scan_impl=scan_impl) if recurrent else {}
       logits, kv = slot_step_logits(model, params, kv, tokens, cursors,
-                                    kv_write_impl=write_impl)
+                                    kv_write_impl=write_impl, **state_args)
       # Each slot's next-token logits sit at its LAST live chunk
       # position; idle slots (num_valid=0) read position 0 — garbage the
       # scheduler never consumes.
@@ -1371,6 +1415,10 @@ class ContinuousBatchingEngine:
       dc_tokens = int((ok & ~plan.prefilling).sum())
     if tracer.enabled:
       tracer.counter("serving/active_slots", plan.active_slots)
+      if self._recurrent:
+        # Slots whose recurrent state this step zeroed: requests that
+        # started (or restarted, after a requeue) here.
+        tracer.counter("serving/state_resets", int(plan.reset.sum()))
       if self.paged:
         # Block-pool occupancy rides the counter tracks next to
         # active_slots, so Perfetto shows pool pressure against load.

@@ -20,6 +20,13 @@ never attendable because the mask only exposes positions the current
 request's own tokens have written (see slot_cache_attend's docstring;
 tests/test_serving.py asserts the no-leakage property).
 
+Two kinds of per-slot state live in the contiguous cache, chosen per
+layer from the model's own layer kinds (:func:`cache_leaves`): K/V rows
+under the slot's cursor for attention layers, and for recurrent layers
+(models/jamba.py's Mamba mixer) the convolution's last inputs and the
+scan's float32 state, which have no position axis and which no cursor can
+roll back (serving/_capabilities.py ``check_recurrent_state``).
+
 Placement: the cache is materialized directly into its sharded layout on
 the mesh (same jit-with-out-shardings trick as
 ``create_sharded_train_state``), heads sharded over the tensor-parallel
@@ -43,9 +50,11 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from easyparallellibrary_tpu import constants
+from easyparallellibrary_tpu.models.jamba import ATTENTION, MAMBA
 
 # Pool index of the reserved null/trash block: block tables default-fill
 # with it (unallocated table slots resolve there), and the fused step's
@@ -65,8 +74,63 @@ def cache_length(cfg, chunk: int) -> int:
   return cfg.max_seq_len + int(chunk)
 
 
+def layer_kinds(cfg) -> Tuple[str, ...]:
+  """Per layer, which state it keeps in a slot: what the model's config
+  says (``cfg.layer_kinds()``, models/jamba.py), attention everywhere for
+  a model that says nothing (GPT)."""
+  kinds = getattr(cfg, "layer_kinds", None)
+  return tuple(kinds()) if kinds is not None else (
+      (ATTENTION,) * cfg.num_layers)
+
+
+def has_recurrent_state(cfg) -> bool:
+  """Whether some layer keeps a recurrence's state, which no cursor can
+  roll back (serving/_capabilities.py refuses what would need to)."""
+  return MAMBA in layer_kinds(cfg)
+
+
+def kv_heads(cfg) -> Tuple[int, int]:
+  """``(H_kv, hd)`` of one K/V row: the model's K/V head count (its query
+  heads when it has no fewer) and the head size."""
+  if cfg.d_model % cfg.num_heads:
+    raise ValueError(f"d_model {cfg.d_model} must divide into "
+                     f"{cfg.num_heads} heads")
+  return (getattr(cfg, "num_kv_heads", None) or cfg.num_heads,
+          cfg.d_model // cfg.num_heads)
+
+
+def cache_leaves(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
+  """The slot cache as shapes: the pytree :func:`allocate_kv_cache`
+  fills, one entry a layer BY ITS KIND — one manager, two kinds of state:
+
+  * attention: ``{"attn": {"cached_key", "cached_value"}}``, each
+    ``[num_slots, Lc, H_kv, hd]`` in the compute dtype, read under the
+    slot's cursor;
+  * Mamba: ``{"mamba": {"conv_state": [num_slots, d_conv - 1, d_inner]``
+    in the compute dtype (the convolution's last inputs, which are
+    produced in it), ``"ssm_state": [num_slots, d_state, d_inner]``
+    float32}}``, no position axis: the whole state is the request's.
+  """
+  H, hd = kv_heads(cfg)
+  kv = jax.ShapeDtypeStruct((num_slots, cache_length(cfg, chunk), H, hd),
+                            cfg.dtype)
+  out = {}
+  for i, kind in enumerate(layer_kinds(cfg)):
+    if kind == ATTENTION:
+      out[f"block_{i}"] = {"attn": {"cached_key": kv, "cached_value": kv}}
+    elif kind == MAMBA:
+      out[f"block_{i}"] = {"mamba": {
+          "conv_state": jax.ShapeDtypeStruct(
+              (num_slots, cfg.mamba_d_conv - 1, cfg.d_inner), cfg.dtype),
+          "ssm_state": jax.ShapeDtypeStruct(
+              (num_slots, cfg.mamba_d_state, cfg.d_inner), jnp.float32)}}
+    else:
+      raise ValueError(f"layer {i}: no cache for layer kind {kind!r}")
+  return out
+
+
 def kv_spec() -> P:
-  """PartitionSpec of one cache leaf ``[num_slots, Lc, H, hd]``: heads
+  """PartitionSpec of one K/V leaf ``[num_slots, Lc, H, hd]``: heads
   over the TP axis, slots/positions replicated."""
   return P(None, None, constants.MODEL_AXIS, None)
 
@@ -75,68 +139,82 @@ def kv_cache_shardings(cfg, mesh: Optional[Mesh]):
   """(kv_shardings_pytree, cursor_sharding) matching
   :func:`allocate_kv_cache`'s structure, or (None, None) without a mesh.
 
-  Heads shard over ``model`` only when the cache's head count actually
-  divides the axis; otherwise the cache is replicated (a 1-sized or
-  absent model axis degrades to replication anyway).
+  K/V heads shard over ``model`` only when the cache's head count
+  actually divides the axis; otherwise the leaf is replicated (a 1-sized
+  or absent model axis degrades to replication anyway).  Recurrent state
+  is replicated: a sharded state is not built.
   """
   if mesh is None:
     return None, None
   sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
   tp = sizes.get(constants.MODEL_AXIS, 1)
-  spec = kv_spec() if tp > 1 and cfg.num_heads % tp == 0 else P()
-  leaf = NamedSharding(mesh, spec)
-  kv = {f"block_{i}": {"attn": {"cached_key": leaf, "cached_value": leaf}}
-        for i in range(cfg.num_layers)}
-  return kv, NamedSharding(mesh, P())
+  heads = NamedSharding(
+      mesh, kv_spec() if tp > 1 and kv_heads(cfg)[0] % tp == 0 else P())
+  rep = NamedSharding(mesh, P())
+  kv = jax.tree_util.tree_map(
+      lambda leaf: heads if len(leaf.shape) == 4 else rep,
+      cache_leaves(cfg, 1, 1))
+  return kv, rep
 
 
 def kv_write_impl(cfg, num_slots: int, chunk: int,
-                  mesh: Optional[Mesh] = None) -> str:
+                  mesh: Optional[Mesh] = None) -> Optional[str]:
   """The lowering of the fused step's window write into the cache
   :func:`allocate_kv_cache` builds for the same arguments — the
-  dispatch rule of kernels/kv_write.py applied to its leaf: the Pallas
-  kernel on a TPU when the leaf sits whole on one chip and fits the
-  kernel's tiles, ``vmap(dynamic_update_slice)`` everywhere else.
-  Resolved once by whoever builds a step over the cache."""
+  dispatch rule of kernels/kv_write.py applied to its K/V leaf: the
+  Pallas kernel on a TPU when the leaf sits whole on one chip and fits
+  the kernel's tiles, ``vmap(dynamic_update_slice)`` everywhere else.
+  Resolved once by whoever builds a step over the cache; ``None`` for a
+  model without an attention layer."""
   from easyparallellibrary_tpu.kernels.kv_write import (
       resolve_kv_write_impl)
-  shape = (num_slots, cache_length(cfg, chunk), cfg.num_heads,
-           cfg.d_model // cfg.num_heads)
+  if ATTENTION not in layer_kinds(cfg):
+    return None
+  shape = (num_slots, cache_length(cfg, chunk)) + kv_heads(cfg)
   return resolve_kv_write_impl(
       shape, cfg.dtype, chunk,
+      sharded=mesh is not None and mesh.size > 1)
+
+
+def ssm_scan_impl(cfg, num_slots: int, chunk: int,
+                  mesh: Optional[Mesh] = None) -> Optional[str]:
+  """The lowering of the fused step's selective scan over the recurrent
+  state :func:`allocate_kv_cache` builds — the dispatch rule of
+  kernels/ssm_scan.py applied to its ``ssm_state`` leaf, resolved once
+  like :func:`kv_write_impl`; ``None`` for a model without recurrent
+  state."""
+  if not has_recurrent_state(cfg):
+    return None
+  from easyparallellibrary_tpu.kernels.ssm_scan import (
+      resolve_ssm_scan_impl)
+  return resolve_ssm_scan_impl(
+      (num_slots, cfg.mamba_d_state, cfg.d_inner), cfg.dtype, chunk,
       sharded=mesh is not None and mesh.size > 1)
 
 
 def allocate_kv_cache(cfg, num_slots: int, chunk: int,
                       mesh: Optional[Mesh] = None
                       ) -> Tuple[Dict[str, Any], jax.Array]:
-  """Preallocate the slot cache for a GPT config.
+  """Preallocate the slot cache of a model config.
 
   Returns ``(kv, cursors)``: ``kv`` is a pytree shaped exactly like the
-  ``"cache"`` collection GPT's slot-mode decode reads/writes
-  (``{"block_i": {"attn": {"cached_key"/"cached_value":
-  [num_slots, Lc, H, hd]}}}``), ``cursors`` the int32 ``[num_slots]``
-  write-offset vector (all zero).  With a mesh, every leaf materializes
-  already sharded (jit + out_shardings — no host-memory spike, no
-  transfer).
+  ``"cache"`` collection the model's slot-mode decode reads/writes
+  (:func:`cache_leaves`: K/V leaves for attention layers, convolution and
+  scan state for Mamba layers), all zero; ``cursors`` the int32
+  ``[num_slots]`` write-offset vector (all zero).  With a mesh, every
+  leaf materializes already sharded (jit + out_shardings — no
+  host-memory spike, no transfer).
   """
   if num_slots < 1:
     raise ValueError(f"num_slots must be >= 1: {num_slots}")
   if chunk < 1:
     raise ValueError(f"prefill chunk must be >= 1: {chunk}")
-  if cfg.d_model % cfg.num_heads:
-    raise ValueError(f"d_model {cfg.d_model} must divide into "
-                     f"{cfg.num_heads} heads")
-  H, hd = cfg.num_heads, cfg.d_model // cfg.num_heads
-  Lc = cache_length(cfg, chunk)
-  shape = (num_slots, Lc, H, hd)
+  leaves = cache_leaves(cfg, num_slots, chunk)
   kv_shardings, cur_sharding = kv_cache_shardings(cfg, mesh)
 
   def build():
-    leaf = lambda: jnp.zeros(shape, cfg.dtype)
-    kv = {f"block_{i}": {"attn": {"cached_key": leaf(),
-                                  "cached_value": leaf()}}
-          for i in range(cfg.num_layers)}
+    kv = jax.tree_util.tree_map(
+        lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), leaves)
     return kv, jnp.zeros((num_slots,), jnp.int32)
 
   if kv_shardings is None:
@@ -149,12 +227,25 @@ def allocate_kv_cache(cfg, num_slots: int, chunk: int,
   return jax.jit(build, out_shardings=(kv_shardings, cur_sharding))()
 
 
+def cache_layout(cfg, num_slots: int, chunk: int) -> Dict[str, int]:
+  """What the slot cache holds, by kind of state: bytes and leaves of
+  K/V (under a cursor) and of recurrent state (no position axis).  The
+  engine records it (trace metadata ``serving/cache_layout``)."""
+  out = {"kv_bytes": 0, "kv_leaves": 0, "state_bytes": 0, "state_leaves": 0}
+  for leaf in jax.tree_util.tree_leaves(cache_leaves(cfg, num_slots, chunk)):
+    kind = "kv" if len(leaf.shape) == 4 else "state"
+    out[f"{kind}_bytes"] += (int(np.prod(leaf.shape))
+                             * jnp.dtype(leaf.dtype).itemsize)
+    out[f"{kind}_leaves"] += 1
+  return out
+
+
 def cache_bytes(cfg, num_slots: int, chunk: int) -> int:
-  """Total cache footprint in bytes (both K and V, all layers) — the
-  number the admission knobs trade against HBM."""
-  H, hd = cfg.num_heads, cfg.d_model // cfg.num_heads
-  per_leaf = num_slots * cache_length(cfg, chunk) * H * hd
-  return 2 * cfg.num_layers * per_leaf * jnp.dtype(cfg.dtype).itemsize
+  """Total cache footprint in bytes (every leaf of every layer: K and V,
+  convolution and scan state) — the number the admission knobs trade
+  against HBM."""
+  layout = cache_layout(cfg, num_slots, chunk)
+  return layout["kv_bytes"] + layout["state_bytes"]
 
 
 # ------------------------------------------------------------ paged cache --

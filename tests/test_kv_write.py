@@ -298,3 +298,44 @@ def test_compiled_for_v5e_holds_the_kernel_and_no_copy_of_a_leaf(
   readers = [l for l in entry.splitlines() if " fusion(" in l
              and "kind=kOutput" in l]
   assert len(readers) == 2, entry
+
+
+def test_ssm_scan_compiled_for_v5e_moves_the_state_once(one_chip):
+  """The selective-scan kernel (kernels/ssm_scan.py) at the hybrid cell's
+  shapes, compiled for a described v5e (here beside the other compile of
+  this file: one process describes the chip): one Mosaic custom call
+  named ``ssm_scan`` whose state operand aliases its output, no ``while``
+  (the reference lowering is a scan), and no copy of the ``[128, 16,
+  5120]`` state anywhere in the program."""
+  from easyparallellibrary_tpu.kernels.ssm_scan import ssm_scan_pallas
+  B, C, N, Di = 128, 8, 16, 5120
+  f32, bf16 = jnp.float32, jnp.bfloat16
+  spec = lambda shape, d: jax.ShapeDtypeStruct(shape, d, sharding=one_chip)
+  args = (spec((B, N, Di), f32), spec((B, C, Di), bf16),
+          spec((B, C, Di), f32), spec((B, C, N), f32), spec((B, C, N), f32),
+          spec((B, C, Di), bf16), spec((N, Di), f32), spec((Di,), f32),
+          spec((B,), jnp.int32), spec((B,), jnp.bool_))
+  from jax.experimental.compilation_cache import compilation_cache
+  cache_was = jax.config.jax_enable_compilation_cache
+  jax.config.update("jax_enable_compilation_cache", False)
+  compilation_cache.reset_cache()
+  try:
+    text = jax.jit(ssm_scan_pallas, donate_argnums=0).lower(
+        *args).compile().as_text()
+  finally:
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+  entry = text[text.index("\nENTRY "):]
+  calls = [l for l in entry.splitlines() if " custom-call(" in l
+           and "tpu_custom_call" in l]
+  assert len(calls) == 1 and "ssm_scan" in calls[0].split("=")[0], entry
+  # operand 2 (after the two scalar-prefetch vectors) is the state,
+  # output 1 the new state
+  assert "{1}: (2, {})" in calls[0], calls[0]
+  assert " while(" not in text
+  state = f"f32[{B},{N},{Di}]"
+  for line in entry.splitlines():
+    m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) (\S+?)\(", line)
+    if m and m.group(2).startswith(state):
+      assert m.group(3) in ("parameter", "bitcast",
+                            "get-tuple-element"), line
