@@ -11,7 +11,8 @@ a user calls, and checks what comes out by the repo's own references:
   train           ``bench.py``'s GPT-350M trainer: 2 warm-up + 5 steps
   serve           ``ContinuousBatchingEngine`` on the same model, 8
                   requests, contiguous then paged; greedy streams against
-                  ``generate(use_cache=True)``
+                  ``generate(use_cache=True)``, alone and beside one slot
+                  that samples; where the compiled step keeps its sort
   hybrid          the selective scan (f32, bf16) against
                   ``ssm_scan_reference``; a four-layer cut of the hybrid
                   decoder (models/jamba.py: 3 Mamba + 1 attention layer at
@@ -61,6 +62,7 @@ from easyparallellibrary_tpu.observability.device import specs_of
 from easyparallellibrary_tpu.serving import (
     ContinuousBatchingEngine, Request)
 from easyparallellibrary_tpu.testing import chaos
+from easyparallellibrary_tpu.testing.hlo import op_sites
 from easyparallellibrary_tpu.utils import compile_cache
 from easyparallellibrary_tpu.utils.pytree import tree_bytes
 
@@ -413,16 +415,27 @@ class _StepSpecs(chaos._StepFnWrapper):
 
 
 def serve(model, params, prompts, new_tokens: int, paged: bool,
-          rehearsal: bool):
+          rehearsal: bool, sampled: bool = False):
   """All requests through one engine at the default ``serving.*``
-  config, to completion.  Returns ``{uid: prompt + generated}``."""
+  config, to completion; with ``sampled``, one more request rides along
+  that samples (temperature, top-k and top-p on), so the greedy ones
+  share their steps with the sampling branch.  Returns
+  ``{uid: prompt + generated}`` of the greedy requests."""
   eng = ContinuousBatchingEngine(model, params, paged=paged)
   spy = _StepSpecs(eng)
   for uid, p in enumerate(prompts):
     check(eng.submit(Request(uid=uid, prompt=p,
                              max_new_tokens=new_tokens)),
           f"request {uid} refused at admission")
+  if sampled:
+    check(eng.submit(Request(uid="sampled", prompt=prompts[0],
+                             max_new_tokens=new_tokens, temperature=0.8,
+                             top_k=40, top_p=0.95, seed=7)),
+          "the sampled request was refused at admission")
   out = eng.run()
+  if sampled:
+    check(len(out.pop("sampled")) == len(prompts[0]) + new_tokens,
+          "the sampled request did not run to its length")
   for uid, p in enumerate(prompts):
     check(uid in out, f"request {uid} never finished")
     check(eng.finished[uid].finish_reason == "length"
@@ -432,13 +445,23 @@ def serve(model, params, prompts, new_tokens: int, paged: bool,
     check((out[uid][:len(p)] == p).all(), f"request {uid}: prompt changed")
   check(spy._cache_size() == 1,
         f"fused step compiled {spy._cache_size()} times")
+  # The step sorts the vocabulary only for slots that ask for it: the
+  # program this backend built keeps its one sort in a branch.
+  hlo = spy.inner.lower(*spy.specs).compile().as_text()
+  always, in_branch = op_sites(hlo, "sort")
+  check(not always and len(in_branch) == 1,
+        f"fused step: sorts that every step runs {always}, sorts inside a "
+        f"conditional {in_branch}; expected none and one")
+  say(f"  fused {'paged' if paged else 'contiguous'} step, "
+      f"{model.cfg.num_layers} layers: its one sort sits in branch "
+      f"computation {in_branch[0]} of a conditional, none on the path "
+      "every step takes")
   if not rehearsal:
     impl = eng._paged_impl if paged else eng.kv_write_impl
     check(impl == "pallas",
           f"{'paged attend' if paged else 'cache write'} resolved to "
           f"{impl!r}, not the kernel")
-    calls = spy.inner.lower(*spy.specs).compile().as_text().count(
-        MOSAIC_CALL)
+    calls = hlo.count(MOSAIC_CALL)
     check(calls == model.cfg.num_layers,
           f"{calls} Mosaic custom calls in the fused "
           f"{'paged' if paged else 'contiguous'} step, expected one per "
@@ -508,6 +531,17 @@ def phase_serve(sizes: Sizes) -> None:
       say(f"  float32 {sizes.cut_cfg.num_layers}-layer cut, "
           f"{'paged' if paged else 'contiguous'}: equal to "
           "generate(use_cache=True) up to float32 ties")
+      # The same requests beside one that samples: the step takes its
+      # sampling branch, and the greedy streams must not notice.
+      mixed = serve(model, params, prompts, new, paged, sizes.rehearsal,
+                    sampled=True)
+      for uid in range(len(prompts)):
+        at = first_difference(mixed[uid], got[uid])
+        check(at is None,
+              f"request {uid}: its greedy stream beside a sampled slot "
+              f"differs from the greedy-only run at position {at}")
+      say(f"  float32 cut, {'paged' if paged else 'contiguous'}: greedy "
+          "streams beside one sampled slot equal the greedy-only run's")
 
   # The full-depth bf16 server.  Bit-equality across batch shapes on the
   # MXU is reported, not gated.

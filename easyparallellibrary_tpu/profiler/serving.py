@@ -146,6 +146,7 @@ class ServingStats:
     # pop-then-insert refreshes a reused uid's position in O(1).
     self._finished_order: Dict[Any, None] = {}
     self.steps = 0
+    self.sampling_steps = 0     # steps with a slot at temperature > 0
     self.busy_time_s = 0.0
     self.prefill_tokens = 0
     self.decode_tokens = 0
@@ -305,8 +306,12 @@ class ServingStats:
   def note_step(self, active_slots: int, num_slots: int,
                 prefill_tokens: int, decode_tokens: int,
                 step_time_s: float, drafted_tokens: int = 0,
-                accepted_tokens: int = 0):
+                accepted_tokens: int = 0, sampled_slots: int = 0):
     self.steps += 1
+    if sampled_slots > 0:
+      # The fused step sorts and draws only on these steps
+      # (serving/engine.py sample_token_slots); a greedy step does not.
+      self.sampling_steps += 1
     self.busy_time_s += step_time_s
     self.prefill_tokens += prefill_tokens
     self.decode_tokens += decode_tokens
@@ -362,9 +367,10 @@ class ServingStats:
   # ----------------------------------------------------- wire round trip
 
   _STATE_SCALARS = (
-      "steps", "busy_time_s", "prefill_tokens", "decode_tokens",
-      "finished_requests", "generated_tokens", "drafted_tokens",
-      "accepted_tokens", "shed_requests", "requeues", "bad_steps",
+      "steps", "sampling_steps", "busy_time_s", "prefill_tokens",
+      "decode_tokens", "finished_requests", "generated_tokens",
+      "drafted_tokens", "accepted_tokens", "shed_requests", "requeues",
+      "bad_steps",
       "step_retries", "degraded_transitions", "degraded_level",
       "watchdog_timeouts", "recompiles", "kv_blocks_free",
       "kv_blocks_used", "kv_fragmentation", "preemptions",
@@ -424,6 +430,8 @@ class ServingStats:
         "itl_p50_s": percentile(itls, 50),
         "itl_p99_s": percentile(itls, 99),
         "slot_occupancy_mean": (self._occupancy_sum / self.steps
+                                if self.steps else 0.0),
+        "sampling_step_share": (self.sampling_steps / self.steps
                                 if self.steps else 0.0),
         # Speculation (all 0.0 on a non-speculative engine): drafted vs
         # accepted totals, overall acceptance rate, and accepted-per-
@@ -517,6 +525,8 @@ def fleet_summary(replica_stats: List["ServingStats"],
       "itl_p50_s": percentile(itls, 50),
       "itl_p99_s": percentile(itls, 99),
       "slot_occupancy_mean": occ,
+      "sampling_step_share": (
+          sum(s.sampling_steps for s in stats) / steps if steps else 0.0),
       "drafted_tokens": float(drafted),
       "accepted_tokens": float(accepted),
       "acceptance_rate": (accepted / drafted) if drafted else 0.0,

@@ -102,11 +102,27 @@ def filtered_logits(logits, temperature, top_k, top_p):
   [M] (0 disables).  Returns the scaled, filtered logits [M, V]
   (filtered entries at -1e30); their softmax is the sampling
   distribution at ``temperature > 0``.
+
+  The vocabulary is sorted only when the parameters ask for it, decided
+  on the device by ``lax.cond`` (the caller stays one compiled program):
+  with no filter on in any row (``0 < top_k < V`` or ``top_p < 1``) the
+  scaled logits are the answer and nothing is sorted; otherwise ONE
+  sort serves both filters for the whole batch.
   """
   V = logits.shape[-1]
-  neg = jnp.asarray(-1e30, logits.dtype)
   t = jnp.where(temperature > 0, temperature, 1.0)[:, None]
   scaled = logits / t.astype(logits.dtype)
+  filter_on = ((top_k > 0) & (top_k < V)) | (top_p < 1.0)
+  return jax.lax.cond(jnp.any(filter_on),
+                      lambda: _filter_sorted(scaled, top_k, top_p),
+                      lambda: scaled)
+
+
+def _filter_sorted(scaled, top_k, top_p):
+  """The filtering branch of :func:`filtered_logits`: top-k, then top-p
+  over the survivors, from one descending sort of ``scaled``."""
+  V = scaled.shape[-1]
+  neg = jnp.asarray(-1e30, scaled.dtype)
   # top-k with a traced k: threshold at the k-th largest value (ties at
   # the threshold survive, exactly like sample_logits' `logits < kth`).
   sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
@@ -114,9 +130,12 @@ def filtered_logits(logits, temperature, top_k, top_p):
       sorted_desc, jnp.clip(top_k - 1, 0, V - 1)[:, None], axis=-1)
   k_off = (top_k[:, None] <= 0) | (top_k[:, None] >= V)
   scaled = jnp.where((scaled >= kth) | k_off, scaled, neg)
+  # The survivors sorted again ARE the first sort with its tail masked:
+  # in descending order the entries >= kth are a prefix, and what top-k
+  # drops becomes -1e30, which sorts last (logits lie above -1e30).
+  sorted_desc = jnp.where((sorted_desc >= kth) | k_off, sorted_desc, neg)
   # top-p over the survivors: keep entries whose PRECEDING mass is < p
   # (the crossing token survives; the top token always survives).
-  sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
   probs = jax.nn.softmax(sorted_desc.astype(jnp.float32), axis=-1)
   cum = jnp.cumsum(probs, axis=-1)
   keep_sorted = (cum - probs) < top_p[:, None]
@@ -137,11 +156,23 @@ def sample_token_slots(logits, keys, temperature, top_k, top_p):
   ``logits`` [N, V]; ``keys`` uint32 [N, 2] per-slot PRNG keys;
   ``temperature``/``top_p`` f32 [N]; ``top_k`` int32 [N] (0 disables).
   Returns int32 [N] token ids.
+
+  The work follows the parameters, chosen on the device by ``lax.cond``
+  so the step stays one compiled program: (1) no slot samples (every
+  ``temperature <= 0``; idle slots carry 0): the argmax alone, with no
+  scaling, sort, softmax or noise; (2) some slot samples and no filter
+  is on: scaling and ``categorical``, no sort; (3) a filter is on in
+  some row: one sort (:func:`filtered_logits`).  A mixed batch pays for
+  the whole batch; the tokens are the same in every case.
   """
-  greedy = jnp.argmax(logits, axis=-1)
-  scaled = filtered_logits(logits, temperature, top_k, top_p)
-  sampled = jax.vmap(jax.random.categorical)(keys, scaled)
-  return jnp.where(temperature <= 0, greedy, sampled).astype(jnp.int32)
+  greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+  def sample():
+    scaled = filtered_logits(logits, temperature, top_k, top_p)
+    sampled = jax.vmap(jax.random.categorical)(keys, scaled)
+    return jnp.where(temperature <= 0, greedy, sampled).astype(jnp.int32)
+
+  return jax.lax.cond(jnp.any(temperature > 0), sample, lambda: greedy)
 
 
 def _resolve_mesh(mesh):
@@ -1413,8 +1444,13 @@ class ContinuousBatchingEngine:
       ok = (plan.num_valid > 0) & slot_ok
       pf_tokens = int(plan.num_valid[ok & plan.prefilling].sum())
       dc_tokens = int((ok & ~plan.prefilling).sum())
+    # Slots the step was handed a temperature > 0 for: exactly what the
+    # plain step's own predicate reads (sample_token_slots), so 0 means
+    # that step took the argmax alone and sorted nothing.
+    sampled_slots = int(np.count_nonzero(plan.temperature > 0))
     if tracer.enabled:
       tracer.counter("serving/active_slots", plan.active_slots)
+      tracer.counter("serving/sampled_slots", sampled_slots)
       if self._recurrent:
         # Slots whose recurrent state this step zeroed: requests that
         # started (or restarted, after a requeue) here.
@@ -1447,7 +1483,8 @@ class ContinuousBatchingEngine:
           active_slots=plan.active_slots, num_slots=self.num_slots,
           prefill_tokens=pf_tokens,
           decode_tokens=dc_tokens, step_time_s=dt,
-          drafted_tokens=drafted, accepted_tokens=accepted)
+          drafted_tokens=drafted, accepted_tokens=accepted,
+          sampled_slots=sampled_slots)
       if self.paged:
         self.stats.note_blocks(self.scheduler.kv_blocks_free,
                                self.scheduler.kv_blocks_used,
@@ -1465,6 +1502,7 @@ class ContinuousBatchingEngine:
       record = {
           "active_slots": plan.active_slots,
           "slot_occupancy": plan.active_slots / self.num_slots,
+          "sampled_slots": sampled_slots,
           "prefill_tokens": pf_tokens,
           "decode_tokens": dc_tokens,
           "step_time_s": dt,

@@ -299,15 +299,17 @@ class SimReplica:
     finished = self.scheduler.commit(nxt, slot_ok=None)
     self._steps += 1
     pf_tokens, dc_tokens = plan.prefill_tokens, plan.decode_tokens
+    sampled_slots = int(np.count_nonzero(plan.temperature > 0))
     if self.stats is not None:
       self.stats.note_step(
           active_slots=plan.active_slots, num_slots=self.num_slots,
           prefill_tokens=pf_tokens, decode_tokens=dc_tokens,
-          step_time_s=dt)
+          step_time_s=dt, sampled_slots=sampled_slots)
     if self.registry is not None or self._slo is not None:
       record = {
           "active_slots": plan.active_slots,
           "slot_occupancy": plan.active_slots / self.num_slots,
+          "sampled_slots": sampled_slots,
           "prefill_tokens": pf_tokens,
           "decode_tokens": dc_tokens,
           "step_time_s": dt,

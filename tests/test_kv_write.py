@@ -252,6 +252,21 @@ def one_chip():
   return SingleDeviceSharding(topo.devices[0])
 
 
+def _compiled_text(jitted, *args) -> str:
+  """Optimised HLO of ``jitted`` for ``args`` (shapes on the described
+  chip), compiled outside the persistent cache: an entry written for a
+  chip that is not attached cannot be read back and only warns."""
+  from jax.experimental.compilation_cache import compilation_cache
+  cache_was = jax.config.jax_enable_compilation_cache
+  jax.config.update("jax_enable_compilation_cache", False)
+  compilation_cache.reset_cache()
+  try:
+    return jitted.lower(*args).compile().as_text()
+  finally:
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+
+
 def test_compiled_for_v5e_holds_the_kernel_and_no_copy_of_a_leaf(
     one_chip):
   """``slot_cache_attend`` at the serving cells' shapes, compiled for a
@@ -266,16 +281,8 @@ def test_compiled_for_v5e_holds_the_kernel_and_no_copy_of_a_leaf(
   new, leaf = spec((B, CHUNK, H, hd)), spec((B, LC, H, hd))
   fn = lambda q, k, v, ck, cv, cur: slot_cache_attend(
       q, k, v, ck, cv, cur, dt, write_impl="pallas")
-  from jax.experimental.compilation_cache import compilation_cache
-  cache_was = jax.config.jax_enable_compilation_cache
-  jax.config.update("jax_enable_compilation_cache", False)
-  compilation_cache.reset_cache()
-  try:
-    text = jax.jit(fn, donate_argnums=(3, 4)).lower(
-        new, new, new, leaf, leaf, spec((B,), jnp.int32)).compile().as_text()
-  finally:
-    jax.config.update("jax_enable_compilation_cache", cache_was)
-    compilation_cache.reset_cache()
+  text = _compiled_text(jax.jit(fn, donate_argnums=(3, 4)),
+                        new, new, new, leaf, leaf, spec((B,), jnp.int32))
   entry = text[text.index("\nENTRY "):]
   calls = [l for l in entry.splitlines() if " custom-call(" in l
            and "kv_write" in l.split("=")[0]]
@@ -315,16 +322,7 @@ def test_ssm_scan_compiled_for_v5e_moves_the_state_once(one_chip):
           spec((B, C, Di), f32), spec((B, C, N), f32), spec((B, C, N), f32),
           spec((B, C, Di), bf16), spec((N, Di), f32), spec((Di,), f32),
           spec((B,), jnp.int32), spec((B,), jnp.bool_))
-  from jax.experimental.compilation_cache import compilation_cache
-  cache_was = jax.config.jax_enable_compilation_cache
-  jax.config.update("jax_enable_compilation_cache", False)
-  compilation_cache.reset_cache()
-  try:
-    text = jax.jit(ssm_scan_pallas, donate_argnums=0).lower(
-        *args).compile().as_text()
-  finally:
-    jax.config.update("jax_enable_compilation_cache", cache_was)
-    compilation_cache.reset_cache()
+  text = _compiled_text(jax.jit(ssm_scan_pallas, donate_argnums=0), *args)
   entry = text[text.index("\nENTRY "):]
   calls = [l for l in entry.splitlines() if " custom-call(" in l
            and "tpu_custom_call" in l]
@@ -339,3 +337,27 @@ def test_ssm_scan_compiled_for_v5e_moves_the_state_once(one_chip):
     if m and m.group(2).startswith(state):
       assert m.group(3) in ("parameter", "bitcast",
                             "get-tuple-element"), line
+
+
+@pytest.mark.parametrize("slots,vocab", [(96, 50304), (128, 65536)],
+                         ids=["gpt2m_cells", "hybrid_cell"])
+def test_sampling_tail_compiled_for_v5e_sorts_in_a_branch_alone(
+    one_chip, slots, vocab):
+  """``sample_token_slots`` (serving/engine.py) at the serving cells'
+  logits, compiled for a described v5e (here, beside the other compiles:
+  one process describes the chip): the TPU compiler keeps both
+  conditionals and the one sort inside a branch, so a step whose slots
+  are all greedy runs no sort; flattened into a select, both sides
+  would run and the gate would buy nothing."""
+  from easyparallellibrary_tpu.serving import sample_token_slots
+  from easyparallellibrary_tpu.testing.hlo import op_sites
+  spec = lambda shape, d: jax.ShapeDtypeStruct(shape, d, sharding=one_chip)
+  text = _compiled_text(
+      jax.jit(sample_token_slots), spec((slots, vocab), jnp.float32),
+      spec((slots, 2), jnp.uint32), spec((slots,), jnp.float32),
+      spec((slots,), jnp.int32), spec((slots,), jnp.float32))
+  unconditional, conditional = op_sites(text, "sort")
+  assert unconditional == [] and len(conditional) == 1, (
+      unconditional, conditional)
+  always, nested = op_sites(text, "conditional")
+  assert len(always) == 1 and len(nested) == 1, (always, nested)
