@@ -8,7 +8,8 @@ a user calls, and checks what comes out by the repo's own references:
   kernels         flash fwd + dk/dv + dq (resident and streaming) against
                   ``_dense_causal_attention``; paged attention (f32, bf16)
                   against ``paged_attention_reference``; all compiled
-  train           ``bench.py``'s GPT-350M trainer: 2 warm-up + 5 steps
+  train           the GPT-350M trainer at the largest batch that fits:
+                  2 warm-up + 5 steps
   serve           ``ContinuousBatchingEngine`` on the same model, 8
                   requests, contiguous then paged; greedy streams against
                   ``generate(use_cache=True)``, alone and beside one slot
@@ -46,8 +47,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 
-import bench
 import easyparallellibrary_tpu as epl
 from easyparallellibrary_tpu.kernels import (
     flash_attention, kv_write_pallas, kv_write_reference,
@@ -57,8 +58,10 @@ from easyparallellibrary_tpu.kernels.ssm_scan import (
 from easyparallellibrary_tpu.models import GPT, GPTConfig
 from easyparallellibrary_tpu.models.jamba import MAMBA, Jamba, JambaConfig
 from easyparallellibrary_tpu.models.gpt import (
-    _dense_causal_attention, generate, gpt_loss)
+    _dense_causal_attention, generate, gpt_loss, make_gpt_train_step)
 from easyparallellibrary_tpu.observability.device import specs_of
+from easyparallellibrary_tpu.parallel import (
+    TrainState, create_sharded_train_state, parallelize)
 from easyparallellibrary_tpu.serving import (
     ContinuousBatchingEngine, Request)
 from easyparallellibrary_tpu.testing import chaos
@@ -68,6 +71,28 @@ from easyparallellibrary_tpu.utils.pytree import tree_bytes
 
 MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
 REHEARSAL_EXIT = 2
+
+# Largest batch first; the smaller ones are tried only when the larger
+# one is refused for memory (RESOURCE_EXHAUSTED), never on another error.
+BATCH_CANDIDATES = (16, 12, 8)
+
+
+def gpt350m_config(**overrides) -> GPTConfig:
+  """GPT-350M: 24L, d 1024, 16 heads, d_ff 4096, vocab 32768, S 1024,
+  bf16.
+
+  loss_chunk: the vocab-32k LM head was the round-1 memory bottleneck —
+  chunked CE keeps the [B,S,V] logits out of HBM (tested equal to the
+  full loss).  pallas_flash + dots_flash: the 512-block flash kernel
+  removes the [B,H,S,S] score temps, and the dots_flash remat policy
+  saves the kernel outputs so the backward never re-runs the forward
+  kernel."""
+  kw = dict(vocab_size=32768, num_layers=24, num_heads=16, d_model=1024,
+            d_ff=4096, max_seq_len=1024, dtype=jnp.bfloat16, remat=True,
+            attn_impl="pallas_flash", remat_policy="dots_flash",
+            loss_chunk=256)
+  kw.update(overrides)
+  return GPTConfig(**kw)
 
 
 class SmokeFailure(Exception):
@@ -98,12 +123,12 @@ class Sizes:
 
   @staticmethod
   def real() -> "Sizes":
-    serve = bench.gpt350m_config(remat=False, attn_impl="xla",
+    serve = gpt350m_config(remat=False, attn_impl="xla",
                                  remat_policy="nothing", loss_chunk=0)
     return Sizes(
         rehearsal=False,
-        train_cfg=bench.gpt350m_config(),
-        batch_candidates=bench.BATCH_CANDIDATES,
+        train_cfg=gpt350m_config(),
+        batch_candidates=BATCH_CANDIDATES,
         serve_cfg=serve,
         cut_cfg=dataclasses.replace(serve, num_layers=2,
                                     dtype=jnp.float32),
@@ -278,6 +303,50 @@ def phase_kernels(sizes: Sizes) -> None:
 # ------------------------------------------------------------------ train --
 
 
+def seeded_batch(cfg: GPTConfig, batch_size: int, seed: int = 0):
+  ids = np.random.RandomState(seed).randint(
+      0, cfg.vocab_size, (batch_size, cfg.max_seq_len + 1))
+  return {"ids": jnp.asarray(ids, jnp.int32)}
+
+
+def build_trainer(model: GPT, mesh, batch, seed: int = 0):
+  """``(state, step)`` through the library's normal entry points: a
+  sharded AdamW train state and the config-dispatched GPT train step
+  compiled over ``mesh`` (what examples/train_gpt.py does)."""
+  tx = optax.adamw(3e-4, weight_decay=0.01)
+
+  def init_fn(r):
+    return TrainState.create(
+        apply_fn=model.apply,
+        params=model.init(r, batch["ids"][:, :-1])["params"], tx=tx)
+
+  state, shardings = create_sharded_train_state(
+      init_fn, mesh, jax.random.PRNGKey(seed))
+  return state, parallelize(make_gpt_train_step(model), mesh, shardings)
+
+
+def largest_batch_trainer(model: GPT, mesh, candidates=BATCH_CANDIDATES,
+                          per_replica: int = 1):
+  """Build the trainer at the first candidate batch that fits and take
+  its first step (compile and first execution are where a batch too
+  large for the chip is refused); ``per_replica`` scales the candidates
+  to a global batch.  Returns ``(state, step, batch, first_metrics)``."""
+  rng = jax.random.PRNGKey(0)
+  for i, cand in enumerate(candidates):
+    batch = seeded_batch(model.cfg, cand * per_replica)
+    state = step = None
+    try:
+      state, step = build_trainer(model, mesh, batch)
+      state, metrics = step(state, batch, rng)
+      return state, step, batch, jax.block_until_ready(metrics)
+    except jax.errors.JaxRuntimeError as e:
+      if "RESOURCE_EXHAUSTED" not in str(e) or i == len(candidates) - 1:
+        raise
+      print(f"chip_smoke: batch {cand} out of device memory, trying "
+            f"{candidates[i + 1]}", file=sys.stderr)
+  raise ValueError("no batch candidates")
+
+
 def flash_call_operands(hlo: str):
   """Operand shapes of every Mosaic custom call in an optimized HLO
   module, as ``{(shape, ...): count}``."""
@@ -329,7 +398,7 @@ def run_trainer(sizes: Sizes, devices, tensor_parallel: bool = False):
       f"{[d.id for d in mesh.devices.reshape(-1)]}")
 
   t0 = time.perf_counter()
-  state, step, batch, first = bench.largest_batch_trainer(
+  state, step, batch, first = largest_batch_trainer(
       model, mesh, candidates=sizes.batch_candidates,
       per_replica=len(devices))
   losses = [float(first["loss"])]
@@ -770,6 +839,18 @@ def phase_hybrid(sizes: Sizes) -> None:
 # ------------------------------------------------------------------- main --
 
 
+def require_tpu() -> jax.Device:
+  """The first device, which must be a TPU: a measurement path that
+  finds no chip fails, it does not fall back."""
+  dev = jax.devices()[0]
+  if dev.platform != "tpu":
+    raise SystemExit(
+        f"no TPU: jax {jax.__version__} found platform {dev.platform!r} "
+        f"({dev.device_kind!r}, {len(jax.devices())} device(s)); this "
+        "program measures on a TPU only")
+  return dev
+
+
 def main(argv=None) -> int:
   parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
   parser.add_argument(
@@ -792,7 +873,7 @@ def main(argv=None) -> int:
         "this is.  Nothing below is a pass or a measurement.")
     sizes = Sizes.toy()
   else:
-    bench.require_tpu()
+    require_tpu()
     sizes = Sizes.real()
 
   for name, phase in (("kernels", lambda: phase_kernels(sizes)),

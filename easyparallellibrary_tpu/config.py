@@ -332,9 +332,8 @@ class SequenceConfig(_Category):
       # Causal ring block layout: "zigzag" (default — half-chunks i and
       # 2n-1-i on device i) balances the causal mask so every device
       # does uniform half-block work each step, cutting causal ring
-      # compute ~2x; measured 1.84x fwd+bwd compiled (dense blocks, CPU
-      # mesh) and 1.54x interpret-mode (benchmarks/ring_layout.py,
-      # BASELINE.md round 4) — hence the default.  "contiguous" (block i
+      # compute ~2x (not yet measured on the chip) — hence the
+      # default.  "contiguous" (block i
       # on device i) is the fallback; non-causal rings and odd
       # per-device splits automatically use contiguous behavior, and
       # flash blocks additionally require tileable half-blocks (dense
@@ -648,7 +647,7 @@ class ServingConfig(_Category):
       # threshold below.  0 slope = rule off (the repo-wide idiom).
       "autoscale.predictive_window_s": 1.0,
       # Arrival-rate slope threshold in requests/s per second.  Tune
-      # via `make sim-bench`; must stay high enough that steady
+      # in the simulator (sim/); must stay high enough that steady
       # fault-free traffic (slope ~ 0) never fires it.
       "autoscale.predictive_slope": 0.0,
       # --- blue/green checkpoint rollout (serving/rollout.py,
@@ -874,10 +873,9 @@ class SimConfig(_Category):
       # aggregate decode throughput, so default sweeps run loaded but
       # not saturated).
       "arrival_rate_rps": 0.0,
-      # SimReplica step-cost physics, seconds per token.  0 = calibrate
-      # from the newest hardware-provenance serving record in
-      # BENCH_EVIDENCE.json (sim/replica.py::calibrate); set explicitly
-      # to model other hardware from its cost card.
+      # SimReplica step-cost physics, seconds per token.  0 = the
+      # placeholder constant in sim/replica.py (no chip's measurement);
+      # set explicitly to model hardware from its cost card.
       "prefill_token_cost_s": 0.0,
       "decode_token_cost_s": 0.0,
       # Fixed per-step host overhead (dispatch, bookkeeping) added to

@@ -675,8 +675,8 @@ def flash_attention_lse(q, k, v, causal: bool = True,
 
 
 # Autotuned block widths: {(S, d, itemsize): want}, loaded lazily from
-# flash_block_table.json next to this module when present (written by
-# benchmarks/flash_autotune.py on real hardware; format
+# flash_block_table.json next to this module when present (to be
+# written by an autotune run on real hardware; format
 # {"device": <device_kind>, "entries": {"S:d:itemsize": want}}).
 # Entries override the 512/1024 heuristic for their exact shape ONLY
 # when the file's device kind matches the current backend — widths
@@ -708,7 +708,7 @@ def _ensure_block_table() -> dict:
 
 
 def set_block_want(S: int, d: int, itemsize: int, want: int) -> None:
-  """Programmatic autotune-table entry (benchmarks/flash_autotune.py)."""
+  """Programmatic autotune-table entry."""
   _ensure_block_table()[(S, d, itemsize)] = int(want)
 
 

@@ -1,4 +1,4 @@
-"""BERT — bidirectional encoder family (BASELINE config 2: BERT-Large
+"""BERT — bidirectional encoder family (the reference's config 2: BERT-Large
 2-stage pipeline with 4 micro-batches, the reference's pipeline tutorial
 model, /root/reference/docs/en/tutorials/pipe.md:33-48).
 

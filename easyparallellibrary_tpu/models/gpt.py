@@ -2,7 +2,7 @@
 
 The reference keeps its model zoo in the external FastNN repo
 (/root/reference/README.md:18); this framework bundles the models because
-the benchmark matrix (BASELINE.md configs 2/4/5) needs them.  The model is
+the reference's benchmark matrix (configs 2/4/5) needs them.  The model is
 written TPU-first:
 
   * bf16 compute / fp32 params by default (MXU-friendly),
@@ -960,9 +960,9 @@ def make_gpt_smap_grad_fn(model: GPT, mesh=None, schedule: str = "1f1b"):
   Accepts the same (boxed) parameter tree as the other pipeline paths,
   so checkpoints move freely between engines.  ``schedule``: "1f1b"
   (default — manual wavefront, residual-ring memory bound, dead ramp
-  sub-ticks skipped; also the engine's best memory point, see
-  BASELINE.md round-3 table) or "gpipe" (autodiff order; worst temp
-  bytes of the four engines at the benchmark shape).  Returns
+  sub-ticks skipped; also the engine's best memory point by XLA's
+  memory plan) or "gpipe" (autodiff order; worst temp bytes of the
+  four engines).  Returns
   ``grad_fn(params, batch, rng) -> ((loss, metrics), grads)``.
 
   Tensor parallelism composes: the shard_map is manual over
@@ -1389,8 +1389,8 @@ def make_gpt_train_step(model: GPT, config=None):
           "pipeline.engine=%r runs the lockstep vmapped engine; the "
           "per-device shard_map engine (pipeline.engine='smap') "
           "measured lower compiled FLOPs, smaller temps and "
-          "stage-resident argument bytes at every attested composition "
-          "(BASELINE.md round-5 tables).", conf.pipeline.engine)
+          "stage-resident argument bytes at every attested composition.",
+          conf.pipeline.engine)
     use_1f1b = sched.remat_stage  # PreferBackward / PreferBackwardOptimizer
     if use_1f1b and cfg.pipeline_interleave > 1:
       get_logger().warning(

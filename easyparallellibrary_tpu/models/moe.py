@@ -130,8 +130,8 @@ class MoEMLP(nn.Module):
     # tokens replicated over the expert group, GSPMD lowers the
     # dispatch/combine einsums to local-compute + reductions, NOT
     # all-to-alls: every expert-group member touches every token, so EP
-    # stops scaling compute with the expert axis (measured: benchmarks/
-    # moe_a2a_share.py).  moe_impl="a2a" enforces distributed tokens.
+    # stops scaling compute with the expert axis.  moe_impl="a2a"
+    # enforces distributed tokens.
     # Fires ONCE per process.  The ACTUAL token sharding is inspected
     # first: a batch genuinely sharded over the expert axis suppresses
     # the advisory entirely; a positively-replicated sharding fires the

@@ -1,8 +1,8 @@
-"""ResNet — image model family (BASELINE configs 1 and 3).
+"""ResNet — image model family (the reference's configs 1 and 3).
 
 The reference repo has no in-tree model zoo (README.md:18 points at
 FastNN); the benchmark matrix needs ResNet-50 for the pure-DP config and
-the `split(8)` large-vocab-head config (/root/repo/BASELINE.md rows 1, 3).
+the `split(8)` large-vocab-head config.
 
 TPU notes:
   * Default norm is GroupNorm: batch-size independent and purely

@@ -19,9 +19,8 @@ One event substrate for the whole runtime:
   (``Compiled.cost_analysis()``/``memory_analysis()`` at warmup),
   per-site measured collective bytes feeding the overlap planner, and
   HBM watermark gauges (``observability.device.*``);
-* :mod:`perfgate` — ``make perf-gate``: cost-card and
-  BENCH_EVIDENCE.json invariants pinned in ``perf_budget.json``,
-  failing CI-style on regression.
+* :mod:`perfgate` — ``make perf-gate``: cost-card invariants pinned
+  in ``perf_budget.json``, failing CI-style on regression.
 
 Knobs: the ``observability.*`` config group (enabled / trace_path /
 ring_capacity / sample_rate / metrics_jsonl / slo.* / device.*).

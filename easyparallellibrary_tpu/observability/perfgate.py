@@ -1,26 +1,16 @@
-"""Perf regression gate: device-truth cost-card and benchmark-evidence
-invariants pinned in ``perf_budget.json`` (``make perf-gate``).
+"""Perf regression gate: cost-card invariants of the compiled twins,
+pinned in ``perf_budget.json`` (``make perf-gate``).
 
-BENCH_EVIDENCE.json was a write-only ledger: every benchmark appended
-evidence and nothing ever READ it, so a PR could double a twin's flops
-or regress a pinned episode and no gate noticed until a human re-ran a
-benchmark on a quiet box.  This module closes that: a checked-in budget
-file pins
-
-* **cost-card invariants** — per compiled twin (the deterministic tiny
-  reference geometry :func:`collect_cards` builds), bounds on the
-  numbers XLA itself reports at warmup via the device introspector
-  (observability/device.py): ``compile_count`` (the compile-once
-  contract as a number), ``flops_per_token``, ``kv_bytes_per_request``,
-  the static ``peak_hbm_bytes`` plan, and ``donation_verified``.  These
-  are COMPILER facts, not wall clocks — they are bit-stable on a noisy
-  1-core box, which is exactly why they gate where timing cannot.
-* **benchmark-evidence invariants** — selected structural metrics from
-  the latest BENCH_EVIDENCE.json record per pinned name (a failover
-  episode losing zero requests, the observability overhead staying
-  within budget).  Records are validated against the evidence schema
-  FIRST (``utils.bench_evidence.validate_record``) and a malformed
-  record FAILS the gate — refused, never silently skipped.
+A checked-in budget file pins, per compiled twin (the deterministic
+tiny reference geometry :func:`collect_cards` builds), bounds on the
+numbers XLA itself reports at warmup via the device introspector
+(observability/device.py): ``compile_count`` (the compile-once contract
+as a number), ``flops_per_token``, ``kv_bytes_per_request``, the static
+``peak_hbm_bytes`` plan, and ``donation_verified``.  These are COMPILER
+facts, not wall clocks — they are bit-stable on a noisy 1-core box,
+which is exactly why they gate where timing cannot.  The gate collects
+them afresh from the tree as it is; speed is the benchmark's business
+(``perfbench/``, ``PERF.md``).
 
 Budget entry forms (``perf_budget.json``)::
 
@@ -28,10 +18,7 @@ Budget entry forms (``perf_budget.json``)::
      "cost_cards": {
        "<twin label>": {"<metric>": {"max": 1.0}            # <= bound
                         | {"min": 1.0}                      # >= bound
-                        | {"max": ..., "min": ...}}},
-     "bench": [
-       {"metric": "<record name>", "path": "kill.orphans_after",
-        "op": "<=", "target": 0}]}
+                        | {"max": ..., "min": ...}}}}
 
 Bounds are written pre-inflated (``--write-budget`` applies the
 per-metric tolerances below to the measured values), so the check
@@ -51,14 +38,6 @@ import sys
 from typing import Any, Dict, List, Optional, Tuple
 
 from easyparallellibrary_tpu.utils.logging import get_logger
-
-_OPS = {
-    "<=": lambda v, t: v <= t,
-    ">=": lambda v, t: v >= t,
-    "<": lambda v, t: v < t,
-    ">": lambda v, t: v > t,
-    "==": lambda v, t: v == t,
-}
 
 # Tolerance applied per cost-card metric when GENERATING a budget from
 # measured cards (--write-budget): the bound ships pre-inflated so the
@@ -186,83 +165,23 @@ def check_cost_cards(budget: Dict[str, Any],
   return errs
 
 
-def _resolve_path(record: Dict[str, Any], dotted: str) -> Any:
-  cur: Any = record
-  for part in dotted.split("."):
-    if not isinstance(cur, dict) or part not in cur:
-      return None
-    cur = cur[part]
-  return cur
-
-
-def check_bench(budget: Dict[str, Any],
-                evidence_path: Optional[str] = None) -> List[str]:
-  """Violations of the budget's ``bench`` section against the latest
-  BENCH_EVIDENCE.json record per pinned metric.  EVERY record in the
-  ledger is schema-validated first; malformed records are refused as
-  violations, never silently skipped."""
-  from easyparallellibrary_tpu.utils import bench_evidence
-  errs: List[str] = []
-  records = bench_evidence.load_records(evidence_path)
-  for i, rec in enumerate(records):
-    for problem in bench_evidence.validate_record(rec):
-      errs.append(
-          f"bench evidence record #{i} "
-          f"({rec.get('metric') if isinstance(rec, dict) else '?'}): "
-          f"malformed — {problem}")
-  by_name: Dict[str, Dict[str, Any]] = {}
-  for rec in records:
-    if not isinstance(rec, dict):
-      continue
-    name = rec.get("metric")
-    prev = by_name.get(name)
-    if prev is None or (rec.get("unix_time", 0)
-                        > prev.get("unix_time", 0)):
-      by_name[name] = rec
-  for entry in budget.get("bench") or ():
-    name, dotted = entry["metric"], entry["path"]
-    op, target = entry.get("op", "<="), entry["target"]
-    where = f"bench[{name}].{dotted}"
-    rec = by_name.get(name)
-    if rec is None:
-      errs.append(f"{where}: no evidence record named {name!r}")
-      continue
-    value = _resolve_path(rec, dotted)
-    if isinstance(value, bool):
-      value = float(value)
-    if not isinstance(value, (int, float)):
-      errs.append(f"{where}: path missing or non-numeric "
-                  f"(got {value!r})")
-      continue
-    if op not in _OPS:
-      errs.append(f"{where}: unknown op {op!r}")
-      continue
-    if not _OPS[op](value, target):
-      errs.append(f"{where}: {value:g} violates '{op} {target:g}'")
-  return errs
-
-
 def run_gate(budget_path: Optional[str] = None,
-             evidence_path: Optional[str] = None,
              cards: Optional[Dict[str, Dict[str, float]]] = None
              ) -> List[str]:
   """The whole gate: load the budget, collect (or accept) measured
-  cards, check both sections.  Returns every violation."""
+  cards, check them.  Returns every violation."""
   budget = load_budget(budget_path)
-  errs: List[str] = []
-  if budget.get("cost_cards"):
-    if cards is None:
-      cards = collect_cards()
-    errs.extend(check_cost_cards(budget, cards))
-  errs.extend(check_bench(budget, evidence_path))
-  return errs
+  if not budget.get("cost_cards"):
+    return []
+  if cards is None:
+    cards = collect_cards()
+  return check_cost_cards(budget, cards)
 
 
 # ----------------------------------------------------------- generation
 
 
-def generate_budget(cards: Dict[str, Dict[str, float]],
-                    bench: Optional[List[Dict[str, Any]]] = None
+def generate_budget(cards: Dict[str, Dict[str, float]]
                     ) -> Dict[str, Any]:
   """A budget document pinning ``cards`` with the standard tolerances
   (the ``--write-budget`` path; the checked-in starter budget was
@@ -281,44 +200,21 @@ def generate_budget(cards: Dict[str, Dict[str, float]],
     cost_cards[label] = pins
   return {
       "version": 1,
-      "comment": "Perf budget: cost-card + bench-evidence invariants "
-                 "enforced by `make perf-gate` (observability/"
-                 "perfgate.py).  Regenerate with --write-budget ONLY "
-                 "when a perf change is intentional, and say why in "
-                 "the PR.",
+      "comment": "Perf budget: cost-card invariants enforced by "
+                 "`make perf-gate` (observability/perfgate.py).  "
+                 "Regenerate with --write-budget ONLY when a perf "
+                 "change is intentional, and say why in the PR.",
       "cost_cards": cost_cards,
-      "bench": bench if bench is not None else _DEFAULT_BENCH_PINS,
   }
-
-
-# Structural (non-wall-clock) evidence pins for the starter budget:
-# episodes must keep resolving every request, flagging zero recompiles,
-# leaking zero orphans, and closing the self-healing loop.
-_DEFAULT_BENCH_PINS: List[Dict[str, Any]] = [
-    {"metric": "observability_overhead", "path": "recompiles_flagged",
-     "op": "<=", "target": 0},
-    {"metric": "observability_overhead", "path": "within_5pct",
-     "op": ">=", "target": 1},
-    {"metric": "router_failover_process", "path": "kill.orphans_after",
-     "op": "<=", "target": 0},
-    {"metric": "router_failover_process", "path": "kill.kills",
-     "op": ">=", "target": 1},
-    {"metric": "self_heal", "path": "self_healing.scale_ups",
-     "op": ">=", "target": 1},
-    {"metric": "self_heal", "path": "self_healing.slo_recoveries",
-     "op": ">=", "target": 1},
-]
 
 
 def main(argv: Optional[List[str]] = None) -> int:
   parser = argparse.ArgumentParser(
       prog="python -m easyparallellibrary_tpu.observability.perfgate",
-      description="Perf regression gate over device cost cards and "
-                  "BENCH_EVIDENCE.json (perf_budget.json)")
+      description="Perf regression gate over device cost cards "
+                  "(perf_budget.json)")
   parser.add_argument("--budget", default=None,
                       help="budget file (default: repo perf_budget.json)")
-  parser.add_argument("--evidence", default=None,
-                      help="evidence file (default: BENCH_EVIDENCE.json)")
   parser.add_argument("--write-budget", action="store_true",
                       help="regenerate the budget from freshly "
                            "collected cards (tolerances applied) "
@@ -332,18 +228,16 @@ def main(argv: Optional[List[str]] = None) -> int:
       json.dump(doc, f, indent=1, sort_keys=False)
       f.write("\n")
     print(f"perf budget written: {budget_path} "
-          f"({len(doc['cost_cards'])} twin(s), "
-          f"{len(doc['bench'])} bench pin(s))")
+          f"({len(doc['cost_cards'])} twin(s))")
     return 0
-  violations = run_gate(budget_path, args.evidence)
+  violations = run_gate(budget_path)
   if violations:
     print(f"perf-gate: {len(violations)} violation(s):")
     for v in violations:
       print(f"  FAIL {v}")
     return 1
   budget = load_budget(budget_path)
-  print(f"perf-gate: OK ({len(budget.get('cost_cards') or {})} twin(s), "
-        f"{len(budget.get('bench') or ())} bench pin(s))")
+  print(f"perf-gate: OK ({len(budget.get('cost_cards') or {})} twin(s))")
   return 0
 
 

@@ -660,8 +660,8 @@ def validate_trace(trace: Union[str, Dict[str, Any], List[Dict[str, Any]]]
   both sides agree), no second start while a flow is open, and every
   started flow TERMINATES with an ``f`` (a failed-over request must
   reach retirement somewhere — a dangling flow is a lost request).
-  (``make trace-demo`` / ``make trace-fleet`` quick tests run this
-  over real emitted traces.)
+  (tests/test_observability.py and tests/test_observability_dist.py
+  run this over real emitted traces.)
   """
   if isinstance(trace, str):
     with open(trace) as f:

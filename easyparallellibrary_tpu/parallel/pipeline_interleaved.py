@@ -294,8 +294,7 @@ def make_smap_interleaved_grad_fn(feed_fn: Callable,
   collectives) are gated on TICK-GLOBAL schedule flags instead: every
   device takes the same branch, so their collectives stay rendezvous-
   safe while executing only on the ticks that need them (~M of T for
-  the emit) — the fix for the engine's ~K x boundary multiplier
-  (benchmarks/smap_overhead.py envelope).
+  the emit) — the fix for the engine's ~K x boundary multiplier.
   """
   S, K, M = num_stages, interleave, num_micro_batch
   sched = build_interleaved_schedule(S, K, M)
@@ -316,7 +315,7 @@ def make_smap_interleaved_grad_fn(feed_fn: Callable,
   # stage), so these [T] predicates gate them with every device taking
   # the same branch.  This removes ~(T - M)/T of the emit evaluations
   # and all rampless feed work — the dominant term of the engine's ~K x
-  # boundary multiplier (benchmarks/smap_overhead.py envelope).
+  # boundary multiplier.
   feed_need = sched.f_valid[:, 0] & (sched.f_chunk[:, 0] == 0)
   fb_need = sched.b_valid[:, 0] & (sched.b_chunk[:, 0] == 0)
 

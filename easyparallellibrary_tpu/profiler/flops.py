@@ -35,8 +35,8 @@ PEAK_FLOPS = {
 def peak_flops_info(device: Optional[jax.Device] = None
                     ) -> "tuple[float, str]":
   """(peak bf16 FLOP/s, table key) for the device kind.  The single
-  source of truth for every MFU denominator in the repo (bench.py imports
-  this — the tables must not fork and drift).  A device kind the table
+  source of truth for every MFU denominator in the package (the tables
+  must not fork and drift).  A device kind the table
   does not know raises: an MFU against a guessed peak is not an MFU."""
   device = device or jax.devices()[0]
   kind = device.device_kind

@@ -31,7 +31,7 @@ standard serving quartet:
   signal mid-flight).
 
 The engine feeds these via the ``note_*`` hooks; ``summary()`` rolls
-them up for logs / ``MetricsWriter`` / BENCH_EVIDENCE records.  Host
+them up for logs / ``MetricsWriter``.  Host
 wall-clock only — nothing here touches the device or forces a sync
 beyond the engine's own per-step token fetch.
 """

@@ -32,8 +32,7 @@ fully-masked ring steps still rotate but contribute zeros (uniform SPMD
 work).  The contiguous layout wastes ~2x on causal masks; setting
 ``sequence.ring_layout="zigzag"`` assigns half-chunks ``(i, 2n-1-i)``
 to device i so every device carries an equal mix of early and late
-positions and per-step work is balanced (``_zz_fwd_pass`` below;
-measured delta in benchmarks/ring_layout.py).
+positions and per-step work is balanced (``_zz_fwd_pass`` below).
 """
 
 from __future__ import annotations

@@ -100,7 +100,7 @@ class FleetAutoscaler:
     self._demand_samples: Deque[Tuple[float, int]] = deque()
     self.predictive_fires = 0
     # First landed grow of this policy's lifetime — the time-to-react
-    # evidence `make heal-bench` compares predictive vs reactive on.
+    # a predictive policy is compared with a reactive one on.
     self.first_scale_up_t: Optional[float] = None
     self.scale_ups = 0
     self.scale_downs = 0

@@ -180,7 +180,7 @@ class FrontDoor:
     self._stop = threading.Event()
     self._driver: Optional[threading.Thread] = None
     self._server_thread: Optional[threading.Thread] = None
-    # Observable counters (benchmarks/frontdoor_bench.py).
+    # Observable counters (tests/test_serving_frontdoor.py).
     self.streamed_events = 0   # token batches pushed to stream queues
     self.overflow_sheds = 0    # slow-reader flows cancelled on overflow
     self.disconnect_cancels = 0

@@ -54,8 +54,7 @@ the capacity the canary's SLO evidence is judging.
 Pure host policy — injectable clock (the router's), driven from
 :meth:`Router.step` at sweep boundaries exactly like the autoscaler.
 Knobs: ``serving.rollout.*`` (docs/robustness.md "Blue/green
-rollout"); ``make chaos-rollout`` and ``make rollout-bench`` are the
-acceptance harnesses.
+rollout"); ``make chaos-rollout`` is the acceptance harness.
 """
 
 from __future__ import annotations

@@ -1233,7 +1233,7 @@ class Router:
     steady-state path needs no call here — bounded chunks ride every
     step reply, and a clean ``close()`` flushes the rest via the
     shutdown reply — but a caller exporting the merged trace while the
-    fleet is still up (``make trace-fleet``, the quick pins) drains
+    fleet is still up (tests/test_observability_dist.py) drains
     explicitly first.  Returns events harvested; inproc and injected
     replicas (no ``harvest`` endpoint) contribute zero."""
     total = 0

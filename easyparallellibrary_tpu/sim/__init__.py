@@ -4,7 +4,7 @@ Discrete-event simulation of the serving fleet at 100–1000-replica
 scale: the REAL policy stack (router dispatch/health/failover,
 admission ladder, engine autotuner, fleet autoscaler, rollout
 controller) runs unmodified over :class:`~easyparallellibrary_tpu.sim.
-replica.SimReplica` members whose device step is a calibrated
+replica.SimReplica` members whose device step is a configured
 :class:`~easyparallellibrary_tpu.sim.replica.CostModel` charge on a
 virtual clock — policy search in seconds instead of cluster-hours,
 with replay fidelity against a recorded real-fleet episode pinned in

@@ -13,8 +13,9 @@ failover, admission, autotune, autoscale, rollout all run unmodified —
 the simulator's claim is exactly "same policies, modeled physics".
 
 The episode loop itself lives in :func:`drive_episode` and is SHARED
-with the golden recorder (benchmarks/sim_golden.py), which drives a
-REAL fleet through the identical loop on the same virtual clock —
+with the golden recorder (tests/golden/record_sim_chaos_heal.py),
+which drives a REAL fleet through the identical loop on the same
+virtual clock —
 replay fidelity (tests/test_sim_replay.py) then rests on the policy
 objects and the record schema alone, never on two hand-mirrored
 loops drifting apart.
@@ -121,8 +122,8 @@ def drive_episode(router: Router, clock: SimClock, workload: Workload,
   arrivals, one router sweep, advance the clock (``fixed_dt`` or
   ``dt_fn()``), fast-forward over dead air, then ``settle_steps`` idle
   sweeps at ``idle_dt`` so de-escalation / scale-down land inside the
-  episode (actuators act between steps; mirrors benchmarks/
-  self_heal.py's settle).  Returns loop accounting + ``submit_at``."""
+  episode (actuators act between steps).  Returns loop accounting +
+  ``submit_at``."""
   if (fixed_dt is None) == (dt_fn is None):
     raise ValueError("exactly one of fixed_dt / dt_fn must be given")
   n = len(workload)

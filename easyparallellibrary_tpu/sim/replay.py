@@ -6,10 +6,10 @@ enough to search policy space"): a chaos-heal episode recorded from
 the REAL fleet — overload burst, breach, autotune escalation,
 scale-up, recovery, drain-back — replayed in the simulator must
 produce the SAME actuation sequence: same actuators, same knob
-transitions, same order.  ``benchmarks/sim_golden.py`` records the
-golden file (tests/golden/sim_chaos_heal.json) by driving a real
-two-replica fleet on a fixed-dt virtual clock; this module replays it
-sim-side; ``tests/test_sim_replay.py`` pins the equality quick.
+transitions, same order.  ``tests/golden/record_sim_chaos_heal.py``
+records the golden file (tests/golden/sim_chaos_heal.json) by driving
+a real two-replica fleet on a fixed-dt virtual clock; this module
+replays it sim-side; ``tests/test_sim_replay.py`` pins the equality quick.
 
 What makes equality achievable rather than aspirational: both sides
 run the identical policy objects over the identical per-step record
@@ -51,8 +51,7 @@ def replay(golden: Dict[str, Any]) -> Dict[str, Any]:
   same way the recorder normalized the real one).
 
   Resets the ambient SLO monitor: a replay is a fresh episode and its
-  breach/actuation log must start empty (same contract as
-  benchmarks/self_heal.py's per-episode reset).
+  breach/actuation log must start empty.
   """
   slo_lib.reset()
   config = epl.Config(golden["config"])

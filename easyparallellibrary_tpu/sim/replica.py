@@ -57,15 +57,15 @@ from easyparallellibrary_tpu.utils import vclock
 # between real and simulated episodes.
 _STATS_PUBLISH_EVERY = 50
 
-# Fallback per-token device cost when BENCH_EVIDENCE.json holds no
-# hardware decode_throughput record (fresh clone): ~400 tok/s, the
-# order of magnitude this repo's TPU measurements sit at.
+# Per-token device cost when the configuration sets none: ~400 tok/s.
+# A placeholder for the policy stack to run against, not a measurement
+# of any chip; set ``sim.*_token_cost_s`` to model real hardware.
 _DEFAULT_TOKEN_COST_S = 1.0 / 400.0
 
 
 @dataclasses.dataclass
 class CostModel:
-  """Linear step-time physics calibrated from measured evidence.
+  """Linear step-time physics priced from the configuration.
 
   ``step_time = overhead + prefill_tokens * pf + decode_tokens * dc``
   — the first-order shape of the fused step (token-proportional
@@ -86,49 +86,14 @@ class CostModel:
             + decode_tokens * self.decode_token_cost_s)
 
   @classmethod
-  def calibrate(cls, path: Optional[str] = None,
-                step_overhead_s: float = 5e-5) -> "CostModel":
-    """Per-token cost from the most recent HARDWARE decode_throughput
-    record in BENCH_EVIDENCE.json (sim-provenance records are refused
-    as calibration sources — a simulator calibrated on its own output
-    would be circular; utils/bench_evidence.py run_context)."""
-    from easyparallellibrary_tpu.utils import bench_evidence
-    recs = [r for r in bench_evidence.load_records(path)
-            if r.get("metric") == "decode_throughput"
-            and r.get("provenance", "hardware") == "hardware"]
-    if not recs:
-      return cls(_DEFAULT_TOKEN_COST_S, _DEFAULT_TOKEN_COST_S,
-                 step_overhead_s, source="default")
-    rec = max(recs, key=lambda r: r.get("unix_time", 0))
-    tps = None
-    cont = rec.get("continuous")
-    if isinstance(cont, dict):
-      tps = cont.get("tokens_per_s")
-    if tps is None:
-      tps = rec.get("tokens_per_s") or rec.get("value")
-    if not isinstance(tps, (int, float)) or tps <= 0:
-      return cls(_DEFAULT_TOKEN_COST_S, _DEFAULT_TOKEN_COST_S,
-                 step_overhead_s, source="default")
-    per_tok = 1.0 / float(tps)
-    return cls(per_tok, per_tok, step_overhead_s,
-               source=f"decode_throughput@{rec.get('unix_time', 0):.0f}")
-
-  @classmethod
   def from_config(cls, config=None) -> "CostModel":
-    """``sim.*`` costs when set (> 0), else evidence calibration."""
-    root = config if config is not None else Env.get().config
-    sconf = root.sim
-    if sconf.prefill_token_cost_s > 0 and sconf.decode_token_cost_s > 0:
-      return cls(sconf.prefill_token_cost_s, sconf.decode_token_cost_s,
-                 sconf.step_overhead_s, source="config")
-    base = cls.calibrate(step_overhead_s=sconf.step_overhead_s)
-    if sconf.prefill_token_cost_s > 0:
-      base.prefill_token_cost_s = sconf.prefill_token_cost_s
-      base.source += "+config"
-    if sconf.decode_token_cost_s > 0:
-      base.decode_token_cost_s = sconf.decode_token_cost_s
-      base.source += "+config"
-    return base
+    """``sim.*`` costs where set (> 0), else the default constant."""
+    sconf = (config if config is not None else Env.get().config).sim
+    pf, dc = sconf.prefill_token_cost_s, sconf.decode_token_cost_s
+    source = ("default", "default+config", "config")[(pf > 0) + (dc > 0)]
+    return cls(pf if pf > 0 else _DEFAULT_TOKEN_COST_S,
+               dc if dc > 0 else _DEFAULT_TOKEN_COST_S,
+               sconf.step_overhead_s, source=source)
 
 
 class SimReplicaDead(RuntimeError):

@@ -34,7 +34,7 @@ circular weight placement there).  The per-rank formulation CAN express
 it: ``pipeline.engine="smap"`` with ``pipeline_interleave=K > 1``
 dispatches the table-driven interleaved engine
 (parallel/pipeline_interleaved.py) whose real branches shrink the ramp
-to 2(S-1) + (K-1)S one-chunk ticks — see BASELINE.md round 4.
+to 2(S-1) + (K-1)S one-chunk ticks.
 """
 
 from __future__ import annotations

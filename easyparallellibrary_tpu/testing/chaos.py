@@ -599,13 +599,10 @@ def poisson_trace(rate_per_s: float, n: int, seed: int = 0,
                   first_at_zero: bool = True) -> np.ndarray:
   """Arrival-time offsets (seconds, ascending) for `n` requests of a
   Poisson process at `rate_per_s` — THE arrival model for every
-  overload/serving-throughput episode (benchmarks/decode_throughput.py
-  and serving_overload.py both draw from here, so the traffic shape
-  cannot silently diverge).  Pass ``rng`` to draw from an existing
-  generator (benchmarks thread one seeded stream through arrivals +
-  prompts + lengths); ``first_at_zero=False`` keeps the sampled first
-  gap (decode_throughput's historical trace — its BENCH_EVIDENCE
-  records stay seed-comparable across commits)."""
+  overload episode of the chaos tests (one source, so the traffic
+  shape cannot silently diverge).  Pass ``rng`` to draw from an
+  existing generator (one seeded stream through arrivals + prompts +
+  lengths); ``first_at_zero=False`` keeps the sampled first gap."""
   if rate_per_s <= 0:
     raise ValueError(f"rate_per_s must be > 0: {rate_per_s}")
   if rng is None:

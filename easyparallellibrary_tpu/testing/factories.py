@@ -11,8 +11,7 @@ no ambient state): identical kwargs on the same backend yield
 bit-identical params in every process, which is what makes
 cross-process failover exactly as bit-exact as the in-process kind.
 
-Used by ``make chaos-proc`` (tests/test_serving_transport.py) and the
-process half of ``make router-bench`` (benchmarks/router_failover.py).
+Used by ``make chaos-proc`` (tests/test_serving_transport.py).
 """
 
 from __future__ import annotations

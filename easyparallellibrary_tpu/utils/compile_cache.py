@@ -1,8 +1,8 @@
 """Persistent XLA compile cache at a place that can be set from outside.
 
 Every process on the chip starts with no compiled code, and compiling the
-24-layer step is a large part of a cold run.  Entry points (``bench.py``,
-``chip_smoke.py``) call :func:`configure` before their first compile:
+24-layer step is a large part of a cold run.  Entry points
+(``chip_smoke.py``) call :func:`configure` before their first compile:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set — jax already honours it; this module
   sets no directory.

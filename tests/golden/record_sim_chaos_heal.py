@@ -5,7 +5,7 @@ steps, the full SLO monitor + autotuner + autoscaler stack) through
 ONE deterministic overload episode — burst above capacity, breach,
 scale-up, autotune escalation, recovery, drain-back — and writes
 everything the simulator needs to reproduce it to
-``tests/golden/sim_chaos_heal.json``:
+``sim_chaos_heal.json`` beside this file:
 
 * the exact config knobs, fleet geometry and request shapes;
 * the arrival times (seeded xorshift, stored verbatim);
@@ -18,15 +18,15 @@ everything the simulator needs to reproduce it to
   its breach/recovery counters.
 
 The episode loop is ``sim.fleet.drive_episode`` — the SAME function
-the simulator runs — so the replay pin (tests/test_sim_replay.py,
-``make perf-gate``'s replay.sequence_match) compares policy behavior,
-not two hand-written harnesses.  ``autoscale.sync_spawn`` is pinned on
-so the real scale-up takes the synchronous ``Router.add_replica`` path
-the simulator's replica factory mirrors.
+the simulator runs — so the replay pin (tests/test_sim_replay.py)
+compares policy behavior, not two hand-written harnesses.
+``autoscale.sync_spawn`` is pinned on so the real scale-up takes the
+synchronous ``Router.add_replica`` path the simulator's replica factory
+mirrors.
 
-Run: ``python benchmarks/sim_golden.py`` (CPU, ~a minute; re-run only
-when a policy change legitimately changes the actuation story — the
-diff of the golden file then documents exactly what changed).
+Run: ``make sim-golden`` (on the CPU; re-run only when a policy change
+legitimately changes the actuation story — the diff of the golden file
+then documents exactly what changed).
 """
 
 from __future__ import annotations
@@ -37,8 +37,8 @@ import sys
 
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
-    __file__))))
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -51,7 +51,7 @@ from easyparallellibrary_tpu.models import GPT, GPTConfig  # noqa: E402
 from easyparallellibrary_tpu.observability import slo as slo_lib  # noqa: E402
 from easyparallellibrary_tpu.observability.registry import (  # noqa: E402
     MetricRegistry)
-from easyparallellibrary_tpu.serving import Request, Router  # noqa: E402
+from easyparallellibrary_tpu.serving import Router  # noqa: E402
 from easyparallellibrary_tpu.sim.arrivals import (  # noqa: E402
     Workload, overload_times)
 from easyparallellibrary_tpu.sim.engine import SimClock, XorShift  # noqa: E402
@@ -59,8 +59,7 @@ from easyparallellibrary_tpu.sim.fleet import (  # noqa: E402
     actuation_sequence, drive_episode, warm_fleet)
 
 GOLDEN_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "tests", "golden", "sim_chaos_heal.json")
+    os.path.dirname(os.path.abspath(__file__)), "sim_chaos_heal.json")
 
 # Episode geometry.  All of it lands in the golden file; the comments
 # explain the choices, the FILE is the contract.
@@ -74,7 +73,7 @@ MAX_NEW = 8
 WARM_MAX_NEW = 2
 FIXED_DT = 2e-3      # virtual seconds per busy sweep
 IDLE_DT = 5e-3       # virtual seconds per settle sweep
-SETTLE_STEPS = 400   # mirrors benchmarks/self_heal.py's settle
+SETTLE_STEPS = 400
 ARRIVAL_SEED = 11
 N_BURST = 120
 N_RECOVER = 40
@@ -148,7 +147,8 @@ def record(path: str = GOLDEN_PATH) -> dict:
       "description": "chaos-heal episode recorded from a REAL "
                      "2-replica fleet on a fixed-dt virtual clock; "
                      "the simulator must replay the same actuation "
-                     "sequence (benchmarks/sim_golden.py)",
+                     "sequence (tests/golden/"
+                     "record_sim_chaos_heal.py)",
       "config": config_dict,
       "num_replicas": NUM_REPLICAS,
       "num_slots": NUM_SLOTS,

@@ -203,9 +203,8 @@ def test_unknown_attn_impl_raises():
 
 def test_block_autotune_table_overrides_heuristic():
   """VERDICT r3 item 6 infrastructure: _default_block consults the
-  autotuned (S, d, itemsize) table (written by
-  benchmarks/flash_autotune.py on hardware) and keeps the 512/1024
-  heuristic for unswept shapes."""
+  autotuned (S, d, itemsize) table (to be written by an autotune run
+  on hardware) and keeps the 512/1024 heuristic for unswept shapes."""
   import importlib
   fa = importlib.import_module(
       "easyparallellibrary_tpu.kernels.flash_attention")

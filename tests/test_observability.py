@@ -192,7 +192,7 @@ def traced_run(tmp_path_factory):
 
 @pytest.mark.quick
 def test_trace_schema_valid(traced_run):
-  """Acceptance + `make trace-demo` CI check: the emitted Chrome-trace
+  """Acceptance: the emitted Chrome-trace
   JSON is schema-valid — traceEvents list, required keys per event,
   monotonic ts, strictly paired B/E — and Perfetto-loadable in shape."""
   events = validate_trace(traced_run["trace_path"])

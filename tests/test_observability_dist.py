@@ -1,7 +1,7 @@
 """Cross-process distributed tracing (ISSUE 20): child trace harvest,
 clock-aligned fleet timelines, end-to-end latency decomposition.
 
-The acceptance contract (`make trace-fleet`): with two ProcessTransport
+The acceptance contract: with two ProcessTransport
 replicas — each recording into its OWN tracer ring — SIGKILL of one
 mid-decode still yields ONE merged schema-valid Perfetto trace in which
 the failed-over request is a single connected flow spanning the parent

@@ -344,8 +344,7 @@ def test_dense_ring_matches_full_attention_both_layouts():
   """`sequence.ring_impl="dense"` (plain-XLA blocks — the pallas-free
   fallback and the compiled measurement path for the layout benchmarks)
   matches full attention, fwd and grad, under both causal layouts.
-  Round-4 note: ring_layout now DEFAULTS to zigzag (1.65x compiled win,
-  BASELINE.md)."""
+  ring_layout DEFAULTS to zigzag."""
   for layout in ("contiguous", "zigzag"):
     epl.init(epl.Config({"sequence.parallelism": "ring",
                          "sequence.axis_size": 8,

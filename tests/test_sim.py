@@ -5,8 +5,6 @@ The replay-fidelity anchor (golden chaos-heal episode) lives in
 tests/test_sim_replay.py.
 """
 
-import json
-
 import numpy as np
 import pytest
 
@@ -106,22 +104,21 @@ def test_make_workload_kinds_and_unknown():
 # ---------------------------------------------------------- cost model
 
 
-def test_cost_model_refuses_sim_provenance(tmp_path):
-  path = str(tmp_path / "ev.json")
-  with open(path, "w") as f:
-    json.dump({"records": [
-        {"metric": "decode_throughput", "unix_time": 2.0,
-         "provenance": "sim",
-         "continuous": {"tokens_per_s": 1000.0}},
-        {"metric": "decode_throughput", "unix_time": 1.0,
-         "provenance": "hardware",
-         "continuous": {"tokens_per_s": 500.0}},
-    ]}, f)
-  cm = CostModel.calibrate(path)
-  # The newer record is sim-tagged: calibration must use the older
-  # HARDWARE one (1/500), never the simulator's own output (1/1000).
-  assert cm.decode_token_cost_s == pytest.approx(1.0 / 500.0)
-  assert "decode_throughput" in cm.source
+@pytest.mark.parametrize("sim,source,prefill,decode", [
+    ({}, "default", None, None),
+    ({"prefill_token_cost_s": 1e-3, "decode_token_cost_s": 2e-3},
+     "config", 1e-3, 2e-3),
+    ({"decode_token_cost_s": 2e-3}, "default+config", None, 2e-3),
+])
+def test_cost_model_from_config(sim, source, prefill, decode):
+  """A step is priced from ``sim.*`` alone: a cost the configuration
+  leaves at 0 is the default constant, whatever files lie around."""
+  from easyparallellibrary_tpu.sim.replica import _DEFAULT_TOKEN_COST_S
+  cm = CostModel.from_config(epl.Config({"sim": sim}))
+  assert cm.source == source
+  assert cm.prefill_token_cost_s == (prefill or _DEFAULT_TOKEN_COST_S)
+  assert cm.decode_token_cost_s == (decode or _DEFAULT_TOKEN_COST_S)
+  assert cm.step_overhead_s == 5e-5
 
 
 def test_cost_model_step_time_linear():

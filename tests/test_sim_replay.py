@@ -1,7 +1,8 @@
 """Golden replay-fidelity pin — the simulator subsystem's anchor.
 
-The recorded REAL-fleet chaos-heal episode (benchmarks/sim_golden.py
--> tests/golden/sim_chaos_heal.json) must replay in the simulator to
+The recorded REAL-fleet chaos-heal episode
+(tests/golden/record_sim_chaos_heal.py -> tests/golden/
+sim_chaos_heal.json) must replay in the simulator to
 the IDENTICAL actuation sequence: same actuators, same knob
 transitions, same order.  This is what licenses using the simulator
 for policy search at 100-1000-replica scale (docs/simulator.md) —

@@ -4,7 +4,7 @@
 block-shape rules included — on any host.  The CPU tests run the kernels
 interpreted, where those rules do not apply, so a kernel Mosaic would
 refuse stayed green here until it met a chip (the paged kernel did).
-Shapes are the serving engine's and bench.py's; only tracing happens, so
+Shapes are the serving engine's and chip_smoke.py's; only tracing happens, so
 these take seconds.
 """
 
@@ -34,7 +34,7 @@ def _flash_loss(q, k, v):
 
 
 @pytest.mark.parametrize("B,S,H,D,dtype", [
-    (2, 1024, 16, 64, jnp.bfloat16),      # bench.py's shape: resident
+    (2, 1024, 16, 64, jnp.bfloat16),      # chip_smoke.py's: resident
     (1, 16384, 2, 64, jnp.bfloat16),      # past _RESIDENT_MAX_BYTES
     (1, 1024, 16, 64, jnp.float32),
 ])
