@@ -53,6 +53,9 @@ import easyparallellibrary_tpu as epl
 from easyparallellibrary_tpu.kernels import (
     flash_attention, kv_write_pallas, kv_write_reference,
     paged_attention_pallas, paged_attention_reference)
+from easyparallellibrary_tpu.kernels.slot_attention import (
+    SLOT_ATTN, block_positions, slot_attention_pallas,
+    slot_attention_reference)
 from easyparallellibrary_tpu.kernels.ssm_scan import (
     SSM_SCAN, ssm_scan_pallas, ssm_scan_reference)
 from easyparallellibrary_tpu.models import GPT, GPTConfig
@@ -286,17 +289,68 @@ def check_kv_write(B, Lc, H, hd, C, dtype, rehearsal: bool) -> None:
       f"{jnp.dtype(dtype).name}: K and V leaves bit-identical")
 
 
+def check_slot_attn(B, Lc, H, hd, C, dtype, rehearsal: bool,
+                    kv_heads: int = 0) -> None:
+  """The live-rows attend against the einsums over every row: cursors at
+  a leaf's start, on and across a block's edge and at the last legal
+  window, a partial chunk, an idle slot, and NaN in every row at or
+  beyond a slot's bound (the kernel must not read them; the reference
+  gets the clean leaves)."""
+  Hkv = kv_heads or H
+  r = np.random.RandomState(3)
+  q = jnp.asarray(r.randn(B, C, H, hd), dtype)
+  ck, cv = (r.randn(B, Lc, Hkv, hd).astype(np.float32) for _ in range(2))
+  block = block_positions((B, Lc, Hkv, hd), dtype, C, H)
+  cursors = np.asarray(
+      ([0, block - C // 2, Lc - C, block] + list(r.randint(0, Lc - C, B)))
+      [:B], np.int32)
+  num_valid = np.asarray(([C, C, C, 1, 0, C // 2 or 1] + [1] * B)[:B],
+                         np.int32)
+  dirty_k, dirty_v = ck.copy(), cv.copy()
+  for b in range(B):
+    bound = cursors[b] + num_valid[b] if num_valid[b] else 0
+    dirty_k[b, bound:] = np.nan
+    dirty_v[b, bound:] = np.nan
+  args = (q, jnp.asarray(dirty_k, dtype), jnp.asarray(dirty_v, dtype),
+          jnp.asarray(cursors), jnp.asarray(num_valid))
+  # Both sides at the highest precision: float32 operands then multiply
+  # as float32 in the kernel too (16-bit ones are exact either way).
+  with jax.default_matmul_precision("highest"):
+    kernel = compile_here(
+        functools.partial(slot_attention_pallas.__wrapped__,
+                          interpret=rehearsal),
+        *args, mosaic_calls=1, rehearsal=rehearsal)
+    got = np.asarray(kernel(*args), np.float32)
+    ref = np.asarray(jax.jit(slot_attention_reference)(
+        q.astype(jnp.float32), jnp.asarray(ck, dtype).astype(jnp.float32),
+        jnp.asarray(cv, dtype).astype(jnp.float32), jnp.asarray(cursors)))
+  real = (np.arange(C)[None] < num_valid[:, None])[:, :, None, None]
+  check(np.isfinite(got).all(), "slot_attn output not finite")
+  check((np.where(real, 0, got) == 0).all(),
+        "slot_attn: rows it does not compute are not zeros")
+  err = rel_err(np.where(real, got, 0), np.where(real, ref, 0))
+  tol = 2e-2 if dtype == jnp.bfloat16 else 5e-4
+  check(err <= tol, f"slot_attn {jnp.dtype(dtype).name}: error {err:.3g} "
+        f"of the reference's max, tol {tol}")
+  say(f"  slot_attn slots{B} Lc{Lc} H{H}/{Hkv} hd{hd} chunk{C} block"
+      f"{block} {jnp.dtype(dtype).name}: {err:.2e} of the reference's "
+      "max, NaN beyond the bounds unread, idle rows zeros")
+
+
 def phase_kernels(sizes: Sizes) -> None:
   for shape in sizes.flash_shapes:
     check_flash(*shape, rehearsal=sizes.rehearsal)
   for dtype in (jnp.float32, jnp.bfloat16):
     check_paged(*sizes.paged_shape, dtype, rehearsal=sizes.rehearsal)
     check_kv_write(*sizes.kv_write_shape, dtype, rehearsal=sizes.rehearsal)
+    check_slot_attn(*sizes.kv_write_shape, dtype, rehearsal=sizes.rehearsal)
+    check_slot_attn(*sizes.kv_write_shape, dtype, rehearsal=sizes.rehearsal,
+                    kv_heads=1)
   say("PASS kernels: flash fwd/bwd "
-      + ("at toy shapes, paged and kv_write f32 + bf16, all INTERPRETED"
-         if sizes.rehearsal else
-         "resident + streaming, paged and kv_write f32 + bf16, all "
-         "compiled")
+      + ("at toy shapes, paged, kv_write and slot_attn f32 + bf16, all "
+         "INTERPRETED" if sizes.rehearsal else
+         "resident + streaming, paged, kv_write and slot_attn (every head "
+         "its own K/V, and all on one) f32 + bf16, all compiled")
       + ", within tolerance")
 
 
@@ -525,17 +579,33 @@ def serve(model, params, prompts, new_tokens: int, paged: bool,
       f"{model.cfg.num_layers} layers: its one sort sits in branch "
       f"computation {in_branch[0]} of a conditional, none on the path "
       "every step takes")
+  if not paged:
+    say(f"  contiguous engine: attend {eng.slot_attn_impl}, kv write "
+        f"{eng.kv_write_impl}")
   if not rehearsal:
-    impl = eng._paged_impl if paged else eng.kv_write_impl
-    check(impl == "pallas",
-          f"{'paged attend' if paged else 'cache write'} resolved to "
-          f"{impl!r}, not the kernel")
+    impls = ((eng._paged_impl,) if paged else
+             (eng.kv_write_impl, eng.slot_attn_impl))
+    check(all(impl == "pallas" for impl in impls),
+          f"{'paged attend' if paged else 'cache write and attend'} "
+          f"resolved to {impls}, not the kernel")
+    # One paged attend a layer; one write and one attend a layer.
+    layers = model.cfg.num_layers
     calls = hlo.count(MOSAIC_CALL)
-    check(calls == model.cfg.num_layers,
+    check(calls == layers * len(impls),
           f"{calls} Mosaic custom calls in the fused "
-          f"{'paged' if paged else 'contiguous'} step, expected one per "
-          f"layer ({model.cfg.num_layers})")
+          f"{'paged' if paged else 'contiguous'} step, expected "
+          f"{len(impls)} per layer ({layers} layers)")
+    if not paged:
+      attends = named_calls(hlo, SLOT_ATTN)
+      check(attends == layers,
+            f"{attends} slot_attn calls in the contiguous step, expected "
+            f"one per layer ({layers})")
   return out
+
+
+def named_calls(hlo: str, name: str) -> int:
+  """Custom calls of the kernel ``name`` in an optimized HLO module."""
+  return len(re.findall(rf"%{name}[.\d]* = ", hlo))
 
 
 def reference_streams(model, params, prompts, new_tokens: int):
@@ -791,7 +861,8 @@ def phase_hybrid(sizes: Sizes) -> None:
   check(spy._cache_size() == 1,
         f"hybrid fused step compiled {spy._cache_size()} times")
   n_mamba = cfg.layer_kinds().count(MAMBA)
-  say(f"  hybrid engine: {len(prompts)} requests, kv write "
+  say(f"  hybrid engine: {len(prompts)} requests, attend "
+      f"{eng.slot_attn_impl}, kv write "
       f"{eng.kv_write_impl}, ssm scan {eng.ssm_scan_impl}, cache "
       f"{eng.cache_layout}")
   if not sizes.rehearsal:
@@ -799,11 +870,17 @@ def phase_hybrid(sizes: Sizes) -> None:
           f"hybrid engine resolved kv write {eng.kv_write_impl!r}, ssm "
           f"scan {eng.ssm_scan_impl!r}: not the kernels")
     hlo = spy.inner.lower(*spy.specs).compile().as_text()
-    scans = len(re.findall(rf"%{SSM_SCAN}[.\d]* = ", hlo))
-    check(scans == n_mamba and hlo.count(MOSAIC_CALL) == cfg.num_layers,
-          f"{scans} ssm_scan calls and {hlo.count(MOSAIC_CALL)} Mosaic "
-          f"calls in the hybrid step, expected {n_mamba} and "
-          f"{cfg.num_layers}")
+    # One scan a Mamba layer; one write and, as the rule takes grouped
+    # heads, one attend an attention layer.
+    scans, attends = named_calls(hlo, SSM_SCAN), named_calls(hlo, SLOT_ATTN)
+    n_attn = cfg.num_layers - n_mamba
+    check(eng.slot_attn_impl == "pallas" and scans == n_mamba
+          and attends == n_attn
+          and hlo.count(MOSAIC_CALL) == n_mamba + 2 * n_attn,
+          f"attend {eng.slot_attn_impl!r}; {scans} ssm_scan, {attends} "
+          f"slot_attn and {hlo.count(MOSAIC_CALL)} Mosaic calls in the "
+          f"hybrid step, expected {n_mamba}, {n_attn} and "
+          f"{n_mamba + 2 * n_attn}")
   # One fused call, the kernel against the reference lowering, on the
   # same inputs: prefill chunks, decodes and idle slots side by side.
   from easyparallellibrary_tpu.models.gpt import slot_step_logits
@@ -818,13 +895,15 @@ def phase_hybrid(sizes: Sizes) -> None:
   for impl in (kernel_impl, "reference"):
     kv, cursors = kv_lib.allocate_kv_cache(cfg, N, C)
     step = jax.jit(functools.partial(
-        slot_step_logits, model, ssm_scan_impl=impl))
+        slot_step_logits, model, ssm_scan_impl=impl, slot_attn_impl=impl))
     # the second call runs on the state the first carried over
     for reset in (first, jnp.zeros_like(first)):
       got, kv = step(params, kv, tokens, cursors, num_valid=num_valid,
                      reset=reset)
       cursors = cursors + num_valid
-    logits[impl] = got[np.asarray(num_valid) > 0]
+    # the positions a slot really fed: beyond them the attend kernel
+    # gives zeros where the reference gives what the masked rows hold
+    logits[impl] = got[np.arange(C)[None] < np.asarray(num_valid)[:, None]]
   err = rel_err(logits[kernel_impl], logits["reference"])
   tol = 1e-4 if jnp.dtype(cfg.dtype).itemsize == 4 else 3e-2
   check(err <= tol, f"hybrid step logits, kernel against the reference "

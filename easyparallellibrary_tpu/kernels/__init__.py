@@ -1,6 +1,8 @@
 from easyparallellibrary_tpu.kernels.flash_attention import flash_attention
 from easyparallellibrary_tpu.kernels.kv_write import (
     kv_write_pallas, kv_write_reference)
+from easyparallellibrary_tpu.kernels.slot_attention import (
+    slot_attention_pallas, slot_attention_reference)
 from easyparallellibrary_tpu.kernels.ssm_scan import (
     ssm_scan_pallas, ssm_scan_reference)
 from easyparallellibrary_tpu.kernels.paged_attention import (
@@ -13,5 +15,6 @@ __all__ = [
     "kv_write_pallas", "kv_write_reference",
     "paged_attention", "paged_attention_pallas",
     "paged_attention_reference", "set_paged_attention_impl",
+    "slot_attention_pallas", "slot_attention_reference",
     "ssm_scan_pallas", "ssm_scan_reference",
 ]

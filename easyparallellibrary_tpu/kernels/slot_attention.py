@@ -1,0 +1,387 @@
+"""Slot-cache attention over the live rows only — Pallas TPU kernel.
+
+The attend half of ``models.gpt.slot_cache_attend``: every slot's ``C``
+new query positions against that slot's own contiguous K/V cache, causal
+at the slot's cursor.  One algorithm, two lowerings:
+
+* **reference** — two einsums: ``q`` against ALL ``Lc`` rows of ALL
+  slots, masked afterwards, a float32 softmax over the whole ``[B, H, C,
+  Lc]`` score tensor.  A dead slot, and every row beyond a live slot's
+  cursor, costs what a full one costs.  Correct everywhere, and what the
+  kernel is tested against.
+* **pallas** — one launch a layer named ``slot_attn``, grid over the
+  LIVE slots (``num_valid > 0``; their number is a value, the grid's
+  first dimension is dynamic and the program compiles once) and K/V
+  blocks of :func:`block_positions` rows.  Each slot's cursor and bound
+  (``cursor + num_valid``) are scalar-prefetched: the kernel reads of a
+  slot's K and V only the blocks under its bound, and an idle slot costs
+  neither a DMA nor a grid step — a step beyond the bound maps to the
+  first block of the next live slot (fetched behind the arithmetic of
+  the last live block, held until its turn) or to the block the pipeline
+  already holds, so it issues no DMA of its own, and its arithmetic is
+  skipped.  Scores, the running max, sum and
+  accumulator of the online softmax live in VMEM; no ``[B, H, C, Lc]``
+  tensor exists in HBM.  It addresses the leaf position-minor (``[slot,
+  H, hd, position]``), the view ``kernels/kv_write.py`` writes through,
+  so on a TPU the transposes around both calls are the same bitcasts.
+
+Arithmetic: scores accumulate in float32 from the compute-dtype ``q``
+and K, the softmax runs in float32, probabilities are cast to the
+compute dtype for the V contraction, which accumulates in float32 and is
+normalised once at the end.  Equal to the reference to rounding, not bit
+for bit (a blocked softmax sums in another order).
+
+What comes out of rows the kernel does not compute: an idle slot's
+rows, positions ``>= num_valid`` of a partial chunk and the rows that
+pad a short chunk are ZEROS.  Nothing at or beyond a slot's bound
+reaches the output: scores there are masked, and V is zeroed there as
+well (the last live block's tail may hold a previous occupant's rows,
+and the leaf's edge block lanes no row at all; ``0 * NaN = NaN``).
+
+Grouped K/V heads (``H_kv < H``, models/jamba.py) ride the query-row
+axis: the ``G = H / H_kv`` query heads of a K/V head are ``G * C`` rows
+against that head's one K and V.
+
+Dispatch rule (:func:`resolve_slot_attn_impl`, the twin of
+``resolve_kv_write_impl``): the kernel when the backend is a TPU, the
+leaf sits whole on one chip and the shapes fit (:func:`slot_attn_fits`);
+the reference everywhere else.  It reads the backend and what it is
+handed — no configuration field, environment variable or setter;
+``interpret`` runs the kernel in Pallas interpreter mode (the CPU parity
+tests, by name or by patching :func:`_backend_impl`).  The engine
+resolves it once when it builds its step and records it
+(``engine.slot_attn_impl``, trace metadata ``serving/slot_attn_impl``).
+
+Shapes: ``q`` ``[B, C, H, hd]``; ``cached_k/cached_v`` ``[B, Lc, H_kv,
+hd]`` AFTER this step's window write; ``cursors``, ``num_valid`` int32
+``[B]``.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from easyparallellibrary_tpu.env import Env
+
+NEG_INF = -1e30
+# The kernel's name in a device trace (see ``flash_attention.FLASH_FWD``).
+# The benchmark reads it (PERF.md section 3).
+SLOT_ATTN = "slot_attn"
+
+IMPLS = ("pallas", "reference", "interpret")
+
+LANES = 128
+# Positions a K/V block may span, widest first.  A block is the unit of
+# both the skip and the DMA: wide enough to stream near the memory's
+# rate (``kv_write``'s 128-position tiles reach 46% of it), narrow
+# enough that a short request does not pay for a long allocation.
+_BLOCKS = (2048, 1024, 512, 256, 128)
+# One K (or V) block ``[H_kv, hd, block]`` may take this much.
+_BLOCK_BYTES = 512 * 1024
+# VMEM the kernel may ask for: K and V blocks double-buffered, q and the
+# output block double-buffered, the float32 accumulator, max and sum,
+# and three score-sized float32 temporaries.  v5e's scoped default is 16
+# MiB.
+_VMEM_BUDGET = 12 * 1024 * 1024
+
+
+def _backend_impl() -> str:
+  """The lowering this backend takes when the shapes allow it.  The CPU
+  parity tests patch it to ``interpret``."""
+  return "pallas" if jax.default_backend() == "tpu" else "reference"
+
+
+def _query_rows(chunk: int, group: int, dtype) -> int:
+  """Query rows of one K/V head, padded to whole sublane tiles of
+  ``dtype`` (a one-token decode is one row)."""
+  tile = 8 * 4 // jnp.dtype(dtype).itemsize
+  return -(-chunk * group // tile) * tile
+
+
+def block_positions(cache_shape, dtype, chunk: int, num_heads: int) -> int:
+  """Positions per K/V block for a ``[B, Lc, H_kv, hd]`` leaf: the widest
+  of :data:`_BLOCKS` whose K block stays within :data:`_BLOCK_BYTES`,
+  does not outgrow the leaf and leaves the kernel within its VMEM
+  budget; 0 if none does."""
+  _, Lc, Hkv, hd = cache_shape
+  size = jnp.dtype(dtype).itemsize
+  rows = _query_rows(chunk, num_heads // Hkv, dtype)
+  for block in _BLOCKS:
+    if block > Lc or Hkv * hd * block * size > _BLOCK_BYTES:
+      continue
+    vmem = (4 * Hkv * hd * block * size        # K, V, double-buffered
+            + 4 * Hkv * rows * hd * size       # q, out, double-buffered
+            + Hkv * rows * (hd + 2 * LANES) * 4    # acc, max, sum
+            + 3 * Hkv * rows * block * 4)      # scores, probabilities
+    if vmem <= _VMEM_BUDGET:
+      return block
+  return 0
+
+
+def slot_attn_fits(cache_shape, dtype, chunk: int, num_heads: int) -> bool:
+  """Whether the kernel can tile a ``[B, Lc, H_kv, hd]`` leaf of
+  ``dtype`` for ``chunk`` query positions of ``num_heads`` heads: a
+  32-bit or 16-bit float leaf of at least one whole 128-position tile
+  whose ``hd`` fills whole sublane tiles, query heads in whole groups, a
+  chunk no wider than a tile (so the causal edge touches two blocks at
+  most) and a block within the budgets."""
+  _, Lc, Hkv, hd = cache_shape
+  dtype = jnp.dtype(dtype)
+  if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
+    return False
+  if Lc < LANES or not 1 <= chunk <= LANES:
+    return False
+  if hd % (8 * 4 // dtype.itemsize) or num_heads % Hkv:
+    return False
+  return block_positions(cache_shape, dtype, chunk, num_heads) > 0
+
+
+def resolve_slot_attn_impl(cache_shape, dtype, chunk: int, num_heads: int,
+                           sharded: bool = False) -> str:
+  """The dispatch rule: the backend's lowering (``pallas`` on a TPU,
+  ``reference`` elsewhere), and ``reference`` whenever the leaf lives on
+  a multi-device mesh (``sharded``: the SPMD partitioner cannot split a
+  Mosaic call) or the shapes do not fit (:func:`slot_attn_fits`)."""
+  impl = _backend_impl()
+  if impl != "reference" and (
+      sharded or not slot_attn_fits(cache_shape, dtype, chunk, num_heads)):
+    return "reference"
+  return impl
+
+
+# -------------------------------------------------------------- reference --
+
+
+def slot_attention_reference(q, cached_k, cached_v, cursors):
+  """Every query against every row of its slot's cache, masked to the
+  causal prefix ``j <= cursor + i``: nothing newer, nothing stale."""
+  B, C, H, hd = q.shape
+  Lc, Hkv = cached_k.shape[1:3]
+  dtype = q.dtype
+  scale = 1.0 / jnp.sqrt(hd).astype(dtype)
+  # Grouped heads: query head h reads K/V head h // (H / H_kv); the
+  # group is one more axis of the same two contractions.
+  if Hkv != H:
+    q = q.reshape(B, C, Hkv, H // Hkv, hd)
+  qk, pv = (("bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd") if Hkv == H else
+            ("bqhgd,bkhd->bhgqk", "bhgqk,bkhd->bqhgd"))
+  logits = jnp.einsum(qk, q, cached_k) * scale
+  pos = cursors[:, None, None, None] + jnp.arange(C)[None, None, :, None]
+  valid = jnp.arange(Lc)[None, None, None, :] <= pos
+  if Hkv != H:
+    valid = valid[:, :, None]
+  logits = jnp.where(valid, logits, jnp.asarray(-1e9, logits.dtype))
+  probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+  out = jnp.einsum(pv, probs.astype(dtype), cached_v)
+  return out.reshape(B, C, H, hd)
+
+
+# ----------------------------------------------------------------- pallas --
+
+
+def _slot_attn_kernel(order_ref, live_ref, cur_ref, bound_ref, pos_ref,
+                      q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                      block: int, num_blocks: int, scale: float):
+  """One (slot, K/V block) grid step: score the block against every
+  query row of every head, fold it into the online softmax carried in
+  VMEM scratch, emit on the slot's last step.
+
+  Values keep ``[heads, rows, .]``: one batched matmul over the heads
+  for the scores (``[rows, hd] x [hd, block]``, K as the leaf holds it)
+  and one for the V contraction (over the block's positions, the lanes
+  of both operands).  ``order_ref`` names the slot of this grid row
+  (live slots only are visited), ``live_ref`` is the index maps' alone,
+  ``pos_ref`` holds each query row's position in the chunk (rows beyond
+  the chunk carry one no slot reaches)."""
+  b = order_ref[pl.program_id(0)]
+  kb = pl.program_id(1)
+  cur = cur_ref[b]
+  bound = bound_ref[b]
+
+  @pl.when(kb == 0)
+  def _init():
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+  def fold(edge: bool):
+    q, k, v = q_ref[0], k_ref[0], v_ref[0]
+    # 16-bit operands multiply exactly on the MXU whatever precision the
+    # caller's context names (and Mosaic refuses a float32 contraction
+    # of them); float32 operands follow the context, as the einsums do.
+    precision = None if q.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+    s = jax.lax.dot_general(
+        q, k, (((2,), (1,)), ((0,), (0,))), precision=precision,
+        preferred_element_type=jnp.float32) * scale    # [Hkv, rows, block]
+    if edge:
+      # The block holds rows at or beyond the cursor: query row i sees
+      # key j iff j <= cursor + i, and nothing at or beyond the bound
+      # (its own chunk's invalid tail, a previous occupant's rows, the
+      # leaf's edge lanes) may reach the sums, through K or through V.
+      col = kb * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+      s = jnp.where((col <= cur + pos_ref[...][None])
+                    & (col < bound), s, NEG_INF)
+      vcol = kb * block + jax.lax.broadcasted_iota(jnp.int32, v.shape, 2)
+      v = jnp.where(vcol < bound, v, jnp.zeros_like(v))
+    m_prev = m_ref[...][:, :, :1]
+    l_prev = l_ref[...][:, :, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    # A row with nothing visible yet (beyond the slot's chunk) keeps
+    # m = NEG_INF and sums ones: finite, and zeroed when emitted.
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(
+        l_prev * corr + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((2,), (2,)), ((0,), (0,))),
+        precision=precision,
+        preferred_element_type=jnp.float32)            # [Hkv, rows, hd]
+
+  live = kb * block < bound
+  behind = (kb + 1) * block <= cur     # every row of it under the cursor
+
+  @pl.when(live & behind)
+  def _interior():
+    fold(edge=False)
+
+  @pl.when(live & jnp.logical_not(behind))
+  def _edge():
+    fold(edge=True)
+
+  @pl.when(kb == num_blocks - 1)
+  def _emit():
+    l_col = jnp.maximum(l_ref[...][:, :, :1], 1e-30)
+    real = pos_ref[...][None] < bound - cur          # [1, rows, 1]
+    o_ref[0] = jnp.where(real, acc_ref[...] / l_col, 0.0).astype(
+        o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "block"))
+def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
+                          interpret: bool = False,
+                          block: Optional[int] = None):
+  """The live-rows attend; ``interpret`` runs the kernel in Pallas
+  interpreter mode (any backend), ``block`` overrides
+  :func:`block_positions` (tests and measurement).  Jitted, so that the
+  layers of one step share one trace and one Mosaic lowering of the
+  kernel, as ``kv_write_pallas`` does; XLA inlines the calls."""
+  B, C, H, hd = q.shape
+  _, Lc, Hkv, _ = cached_k.shape
+  G = H // Hkv
+  dtype = cached_k.dtype
+  if block is None:
+    block = block_positions(cached_k.shape, dtype, C, H)
+  nb = pl.cdiv(Lc, block)
+  rows = _query_rows(C, G, dtype)
+
+  # The write clamps its window into the leaf (kv_write.py); the read
+  # follows it, so the two stay one contract outside it too.
+  cur = jnp.clip(cursors.astype(jnp.int32), 0, Lc - C)
+  nv = (jnp.full((B,), C, jnp.int32) if num_valid is None
+        else jnp.clip(num_valid.astype(jnp.int32), 0, C))
+  alive = nv > 0
+  bound = jnp.where(alive, cur + nv, 0)
+  # The grid visits the live slots alone, in slot order: its first
+  # dimension is their number, a value and not a shape (one compile), and
+  # ``order`` names the slot of each of its rows.  An idle slot costs no
+  # grid step and no DMA; its output block is never written and is
+  # zeroed below.
+  # (The i-th live slot is the number of slots with at most i live ones
+  # up to and including themselves: no sort, no scatter.)
+  upto = jnp.cumsum(alive, dtype=jnp.int32)
+  order = jnp.minimum(
+      jnp.sum(upto[None, :] <= jnp.arange(B)[:, None], axis=1,
+              dtype=jnp.int32), B - 1)
+  live = jnp.maximum(upto[-1:], 1)
+  # Each query row's position in its chunk: rows are (group, position),
+  # padding rows carry a position no slot reaches.
+  pos = jnp.arange(rows, dtype=jnp.int32)
+  pos = jnp.where(pos < G * C, pos % C, C)[:, None]
+
+  # Position-minor views of the leaves: bitcasts on the TPU, and the
+  # inverse of the ones kv_write returned through.
+  to_minor = lambda x: jnp.transpose(x, (0, 2, 3, 1))
+  qr = q.astype(dtype).reshape(B, C, Hkv, G, hd).transpose(0, 2, 3, 1, 4)
+  qr = qr.reshape(B, Hkv, G * C, hd)
+  if rows != G * C:
+    qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - G * C), (0, 0)))
+
+  def kv_idx(i, kb, order, live, cur, bound):
+    # A step beyond its slot's bound points at the first block of the
+    # next live slot, which so streams in behind the arithmetic of this
+    # slot's last block and is held until its turn; the last live slot's
+    # stay on the block the pipeline holds.  Either way such steps issue
+    # no DMA of their own.
+    b = order[i]
+    ahead = order[jnp.minimum(i + 1, B - 1)]
+    reads = kb * block < bound[b]
+    more = i + 1 < live[0]
+    held = jnp.maximum(bound[b] - 1, 0) // block
+    return (jnp.where(reads, b, jnp.where(more, ahead, b)), 0, 0,
+            jnp.where(reads, kb, jnp.where(more, 0, held)))
+
+  row_spec = pl.BlockSpec((1, Hkv, rows, hd),
+                          lambda i, kb, order, *_: (order[i], 0, 0, 0))
+  kv_spec = pl.BlockSpec((1, Hkv, hd, block), kv_idx)
+  grid_spec = pltpu.PrefetchScalarGridSpec(
+      num_scalar_prefetch=4,
+      grid=(live[0], nb),
+      in_specs=[pl.BlockSpec((rows, 1), lambda i, kb, *_: (0, 0)),
+                row_spec, kv_spec, kv_spec],
+      out_specs=row_spec,
+      scratch_shapes=[
+          pltpu.VMEM((Hkv, rows, LANES), jnp.float32),   # running max
+          pltpu.VMEM((Hkv, rows, LANES), jnp.float32),   # running sum
+          pltpu.VMEM((Hkv, rows, hd), jnp.float32),      # accumulator
+      ],
+  )
+  kwargs = {}
+  if not interpret:
+    kwargs["compiler_params"] = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"))
+  out = pl.pallas_call(
+      functools.partial(_slot_attn_kernel, block=block, num_blocks=nb,
+                        scale=1.0 / math.sqrt(hd)),
+      grid_spec=grid_spec,
+      out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, hd), dtype),
+      interpret=interpret,
+      name=SLOT_ATTN,
+      **kwargs,
+  )(order, live, cur, bound, pos, qr, to_minor(cached_k),
+    to_minor(cached_v))
+  out = jnp.where(alive[:, None, None, None], out, 0)
+  out = out[:, :, :G * C].reshape(B, Hkv, G, C, hd)
+  return out.transpose(0, 3, 1, 2, 4).reshape(B, C, H, hd)
+
+
+# --------------------------------------------------------------- dispatch --
+
+
+def slot_attention(q, cached_k, cached_v, cursors, num_valid=None,
+                   impl: Optional[str] = None):
+  """Attend each slot's chunk over its own cache (module docstring);
+  returns ``out [B, C, H, hd]``.  ``impl=None`` applies the dispatch rule
+  to the shapes at hand, and takes the leaf as spread over chips
+  whenever a multi-device mesh has been built (the legacy ``generate()``
+  decode); the serving engine resolves the impl from its own mesh and
+  passes it."""
+  if impl is None:
+    cluster = Env.get().cluster
+    mesh = cluster.built_mesh if cluster is not None else None
+    impl = resolve_slot_attn_impl(
+        cached_k.shape, cached_k.dtype, q.shape[1], q.shape[2],
+        sharded=mesh is not None and mesh.size > 1)
+  if impl not in IMPLS:
+    raise ValueError(f"impl must be one of {IMPLS} or None; got {impl!r}")
+  if impl == "reference":
+    return slot_attention_reference(q, cached_k, cached_v, cursors)
+  return slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid,
+                               interpret=impl == "interpret")

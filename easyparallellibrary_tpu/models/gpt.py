@@ -103,7 +103,7 @@ from easyparallellibrary_tpu.utils.sharding import constrain as _constrain  # no
 
 
 def slot_cache_attend(q, k, v, cached_k, cached_v, cursors, dtype,
-                      write_impl=None):
+                      write_impl=None, attn_impl=None, num_valid=None):
   """Slot-indexed KV-cache attention — the shared core of the legacy
   single-request decode step and the serving engine's fused
   prefill+decode step (serving/engine.py).
@@ -129,51 +129,49 @@ def slot_cache_attend(q, k, v, cached_k, cached_v, cursors, dtype,
   reused slot only ever attends to positions its own tokens have
   written.
 
-  FINITENESS INVARIANT: masking zeroes a stale position's softmax
-  probability, but the probability-weighted V sum still contracts over
-  every cache position and ``0 * NaN = NaN`` — so callers must never
-  leave NON-FINITE values in cache rows they will not overwrite before
-  the next read.  Garbage-but-finite stale rows are fine (their exact-0
-  probability annihilates them).  The one producer of non-finite rows
-  is a poisoned device step under serving resilience: the engine zeroes
-  the bad step's writes before the slot is read again — a retried
-  slot's rows above its committed cursor, a quarantined slot whole
-  (engine._sanitize_slots) — so the invariant holds without taxing
-  this hot path.
+  ``num_valid`` (int32 ``[B]``; ``None`` = all ``C`` positions real,
+  which is what ``generate()``'s decode means) says how many of the
+  chunk's positions each slot really feeds; 0 is an idle slot.  A slot's
+  device cursor outlives its request (the engine zeroes it only when the
+  next request starts), so the cursor alone cannot tell a dead slot:
+  ``cursors[b] + num_valid[b]`` is the slot's BOUND, the first row no
+  valid query of this step can see.
+
+  FINITENESS INVARIANT: the reference attend masks a stale position's
+  softmax probability to zero, but its probability-weighted V sum still
+  contracts over every cache position and ``0 * NaN = NaN`` — so callers
+  must never leave NON-FINITE values in cache rows they will not
+  overwrite before the next read.  Garbage-but-finite stale rows are
+  fine (their exact-0 probability annihilates them).  The one producer
+  of non-finite rows is a poisoned device step under serving resilience:
+  the engine zeroes the bad step's writes before the slot is read again
+  — a retried slot's rows above its committed cursor, a quarantined slot
+  whole (engine._sanitize_slots) — so the invariant holds without taxing
+  this hot path.  The kernel attend never reads a row at or beyond a slot's bound except inside the bound's own block, where it
+  masks V as well as the scores, so no stale row reaches its output
+  whatever it holds; callers keep the invariant all the same, because
+  which attend a step was built with is the rule's to decide.  What the
+  kernel does not compute — an idle slot, positions ``>= num_valid`` —
+  comes out as zeros where the reference gives garbage-but-finite
+  values; either way the next layer writes finite K/V for those
+  positions and nothing reads their logits.
 
   The window write has two lowerings with bit-identical results
-  (kernels/kv_write.py): ``write_impl`` names one, ``None`` applies the
-  dispatch rule to the shapes at hand (the serving engine resolves it
-  once when it builds its step and passes it down).
+  (kernels/kv_write.py), and so has the attend, equal to rounding
+  (kernels/slot_attention.py: two einsums over every row of every slot,
+  or one kernel that reads the rows under each slot's bound alone).  ``write_impl`` / ``attn_impl`` name them, ``None`` applies
+  each dispatch rule to the shapes at hand (the serving engine resolves
+  both once when it builds its step and passes them down).
 
   Returns ``(out [B, C, H, hd], new_cached_k, new_cached_v)``.
   """
   from easyparallellibrary_tpu.kernels.kv_write import kv_write
-  B, C, H, hd = q.shape
-  Lc = cached_k.shape[1]
-  scale = 1.0 / jnp.sqrt(hd).astype(dtype)
-
+  from easyparallellibrary_tpu.kernels.slot_attention import slot_attention
   cached_k, cached_v = kv_write(cached_k, cached_v, k, v, cursors,
                                 impl=write_impl)
-  Hkv = cached_k.shape[2]
-  # Grouped heads: query head h reads K/V head h // (H / H_kv); the
-  # group is one more axis of the same two contractions.
-  if Hkv != H:
-    q = q.reshape(B, C, Hkv, H // Hkv, hd)
-  qk, pv = (("bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd") if Hkv == H else
-            ("bqhgd,bkhd->bhgqk", "bhgqk,bkhd->bqhgd"))
-  logits = jnp.einsum(qk, q, cached_k) * scale
-  # Key position j is visible to query i (absolute position cursor+i)
-  # iff j <= cursor + i: the query's own causal prefix, nothing newer,
-  # nothing stale.
-  pos = cursors[:, None, None, None] + jnp.arange(C)[None, None, :, None]
-  valid = jnp.arange(Lc)[None, None, None, :] <= pos
-  if Hkv != H:
-    valid = valid[:, :, None]
-  logits = jnp.where(valid, logits, jnp.asarray(-1e9, logits.dtype))
-  probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-  out = jnp.einsum(pv, probs.astype(dtype), cached_v)
-  return out.reshape(B, C, H, hd), cached_k, cached_v
+  out = slot_attention(q, cached_k, cached_v, cursors, num_valid,
+                       impl=attn_impl)
+  return out.astype(dtype), cached_k, cached_v
 
 
 @dataclasses.dataclass
@@ -270,7 +268,8 @@ def paged_step_logits(model, params, kv, tokens, slot_ids, positions,
 
 
 def slot_step_logits(model, params, kv, tokens, cursors,
-                     kv_write_impl=None, **state_args):
+                     kv_write_impl=None, slot_attn_impl=None,
+                     num_valid=None, **state_args):
   """Multi-token scoring on the shared slot-cache core — THE device entry
   every serving component steps through.
 
@@ -288,12 +287,16 @@ def slot_step_logits(model, params, kv, tokens, cursors,
     distributions come back in the same call
     (serving/speculative/verify.py).
 
-  ``kv_write_impl`` is the resolved lowering of the cache write
-  (kernels/kv_write.py; ``None`` resolves it from the shapes).
-  ``state_args`` go to a model whose layers keep recurrent state beside
-  K/V (models/jamba.py: ``num_valid``, ``reset``, ``ssm_scan_impl``) — a
-  recurrence, unlike a cache under a cursor, must be told how many of the
-  chunk's positions are real; a GPT takes none.
+  ``kv_write_impl`` and ``slot_attn_impl`` are the resolved lowerings of
+  the cache write and of the attend (kernels/kv_write.py,
+  kernels/slot_attention.py; ``None`` resolves each from the shapes).
+  ``num_valid`` (int32 ``[num_slots]``; ``None`` = every position of
+  every slot is real) says how many of the chunk's positions each slot
+  feeds: the attend reads no cache row at or beyond ``cursors +
+  num_valid`` and none at all of an idle slot (``num_valid == 0``), and
+  a recurrence advances by exactly that many.  ``state_args`` go to a
+  model whose layers keep recurrent state beside K/V (models/jamba.py:
+  ``reset``, ``ssm_scan_impl``); a GPT takes none.
 
   Returns ``(logits [num_slots, C, vocab], new_kv)``; the caller owns
   cursor advancement (and, for speculation, rollback to the last
@@ -301,7 +304,8 @@ def slot_step_logits(model, params, kv, tokens, cursors,
   """
   logits, mut = model.apply(
       {"params": params, "cache": kv}, tokens, decode=True,
-      slot_cursors=cursors, kv_write_impl=kv_write_impl,
+      slot_cursors=cursors, num_valid=num_valid,
+      kv_write_impl=kv_write_impl, slot_attn_impl=slot_attn_impl,
       mutable=["cache"], **state_args)
   return logits, mut["cache"]
 
@@ -330,12 +334,15 @@ def _dense_causal_attention(q, k, v, dtype):
 class CausalSelfAttention(nn.Module):
   cfg: GPTConfig
   decode: bool = False
-  # Resolved lowering of the slot cache's window write (kernels/
-  # kv_write.py); None = resolve from the shapes when traced.
+  # Resolved lowerings of the slot cache's window write and of the
+  # attend over it (kernels/kv_write.py, kernels/slot_attention.py);
+  # None = resolve from the shapes when traced.
   kv_write_impl: Optional[str] = None
+  slot_attn_impl: Optional[str] = None
 
   @nn.compact
-  def __call__(self, x, slot_cursors=None, paged_info=None):
+  def __call__(self, x, slot_cursors=None, paged_info=None,
+               num_valid=None):
     cfg = self.cfg
     B, S, D = x.shape
     H = cfg.num_heads
@@ -363,7 +370,7 @@ class CausalSelfAttention(nn.Module):
           cfg.dtype)
       out = out[:, None]
     elif self.decode:
-      out = self._decode_attend(q, k, v, slot_cursors)
+      out = self._decode_attend(q, k, v, slot_cursors, num_valid)
     elif cfg.attn_impl == "ring":
       from easyparallellibrary_tpu.sequence.ring_attention import (
           ring_attention)
@@ -389,7 +396,7 @@ class CausalSelfAttention(nn.Module):
                 param_dtype=cfg.param_dtype, name="proj")(out)
     return _constrain(out, _act_spec(cfg))
 
-  def _decode_attend(self, q, k, v, slot_cursors=None):
+  def _decode_attend(self, q, k, v, slot_cursors=None, num_valid=None):
     """KV-cached attention (VERDICT round-1 item 10).
 
     Two cache layouts share :func:`slot_cache_attend` as their math:
@@ -417,7 +424,8 @@ class CausalSelfAttention(nn.Module):
       cv = self.variable("cache", "cached_value", _missing_slot_cache)
       out, ck.value, cv.value = slot_cache_attend(
           q, k, v, ck.value, cv.value, slot_cursors, cfg.dtype,
-          write_impl=self.kv_write_impl)
+          write_impl=self.kv_write_impl, attn_impl=self.slot_attn_impl,
+          num_valid=num_valid)
       return out
 
     ck = self.variable("cache", "cached_key",
@@ -439,7 +447,7 @@ class CausalSelfAttention(nn.Module):
     cursors = jnp.broadcast_to(ci.value, (B,))
     out, ck.value, cv.value = slot_cache_attend(
         q, k, v, ck.value, cv.value, cursors, cfg.dtype,
-        write_impl=self.kv_write_impl)
+        write_impl=self.kv_write_impl, attn_impl=self.slot_attn_impl)
     ci.value = ci.value + 1
     return out
 
@@ -466,9 +474,11 @@ class Block(nn.Module):
   deterministic: bool = True
   decode: bool = False
   kv_write_impl: Optional[str] = None
+  slot_attn_impl: Optional[str] = None
 
   @nn.compact
-  def __call__(self, x, slot_cursors=None, paged_info=None):
+  def __call__(self, x, slot_cursors=None, paged_info=None,
+               num_valid=None):
     cfg = self.cfg
     drop = nn.Dropout(rate=cfg.dropout_rate,
                       deterministic=self.deterministic
@@ -476,8 +486,9 @@ class Block(nn.Module):
     y = LayerNorm(dtype=cfg.dtype, name="ln1")(x)
     x = x + drop(CausalSelfAttention(cfg, decode=self.decode,
                                      kv_write_impl=self.kv_write_impl,
+                                     slot_attn_impl=self.slot_attn_impl,
                                      name="attn")(y, slot_cursors,
-                                                  paged_info))
+                                                  paged_info, num_valid))
     y = LayerNorm(dtype=cfg.dtype, name="ln2")(x)
     if self.use_moe:
       from easyparallellibrary_tpu.models.moe import MoEMLP
@@ -627,7 +638,8 @@ class GPT(nn.Module):
   @nn.compact
   def __call__(self, ids, deterministic: bool = True,
                decode: bool = False, return_hidden: bool = False,
-               slot_cursors=None, paged_info=None, kv_write_impl=None):
+               slot_cursors=None, paged_info=None, kv_write_impl=None,
+               slot_attn_impl=None, num_valid=None):
     from easyparallellibrary_tpu.runtime.amp import resolve_model_dtypes
     cfg = resolve_model_dtypes(self.cfg)
     B, S = ids.shape
@@ -726,7 +738,9 @@ class GPT(nn.Module):
           (i % cfg.moe_every == cfg.moe_every - 1)
         x = block_cls(cfg, use_moe=use_moe, deterministic=deterministic,
                       decode=decode, kv_write_impl=kv_write_impl,
-                      name=f"block_{i}")(x, slot_cursors, paged_info)
+                      slot_attn_impl=slot_attn_impl,
+                      name=f"block_{i}")(x, slot_cursors, paged_info,
+                                         num_valid)
 
     x = LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
     if return_hidden:

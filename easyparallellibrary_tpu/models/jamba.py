@@ -135,9 +135,10 @@ class AttentionMixer(nn.Module):
   cfg: JambaConfig
   decode: bool = False
   kv_write_impl: Optional[str] = None
+  slot_attn_impl: Optional[str] = None
 
   @nn.compact
-  def __call__(self, h, slot_cursors=None):
+  def __call__(self, h, slot_cursors=None, num_valid=None):
     cfg = self.cfg
     B, S, _ = h.shape
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -149,7 +150,8 @@ class AttentionMixer(nn.Module):
       cv = self.variable("cache", "cached_value", _missing_slot_cache)
       out, ck.value, cv.value = slot_cache_attend(
           q, k, v, ck.value, cv.value, slot_cursors, cfg.dtype,
-          write_impl=self.kv_write_impl)
+          write_impl=self.kv_write_impl, attn_impl=self.slot_attn_impl,
+          num_valid=num_valid)
     else:
       out = gqa_causal_attention(q, k, v, cfg.dtype)
     return _dense(cfg, cfg.d_model, "o")(out.reshape(B, S, H * hd))
@@ -271,6 +273,7 @@ class JambaBlock(nn.Module):
   kind: str
   decode: bool = False
   kv_write_impl: Optional[str] = None
+  slot_attn_impl: Optional[str] = None
   ssm_scan_impl: Optional[str] = None
 
   @nn.compact
@@ -281,7 +284,8 @@ class JambaBlock(nn.Module):
     if self.kind == ATTENTION:
       mixed = AttentionMixer(cfg, decode=self.decode,
                              kv_write_impl=self.kv_write_impl,
-                             name="attn")(h, slot_cursors)
+                             slot_attn_impl=self.slot_attn_impl,
+                             name="attn")(h, slot_cursors, num_valid)
     else:
       mixed = MambaMixer(cfg, decode=self.decode,
                          ssm_scan_impl=self.ssm_scan_impl,
@@ -303,7 +307,8 @@ class Jamba(nn.Module):
   @nn.compact
   def __call__(self, ids, decode: bool = False, return_hidden: bool = False,
                slot_cursors=None, num_valid=None, reset=None,
-               kv_write_impl=None, ssm_scan_impl=None):
+               kv_write_impl=None, slot_attn_impl=None,
+               ssm_scan_impl=None):
     cfg = self.cfg
     if decode and slot_cursors is None:
       raise ValueError(
@@ -318,6 +323,7 @@ class Jamba(nn.Module):
     x = tok(ids).astype(cfg.dtype)
     for i, kind in enumerate(cfg.layer_kinds()):
       x = JambaBlock(cfg, kind, decode=decode, kv_write_impl=kv_write_impl,
+                     slot_attn_impl=slot_attn_impl,
                      ssm_scan_impl=ssm_scan_impl, name=f"block_{i}")(
                          x, slot_cursors, num_valid, reset)
     x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm_f")(x)

@@ -278,7 +278,8 @@ def format_fleet(fleet: Dict[str, Any]) -> str:
       f"{g('finished_requests'):.0f} finished, "
       f"{g('generated_tokens'):.0f} tokens, "
       f"occupancy {g('slot_occupancy_mean'):.2f}, "
-      f"sampling steps {g('sampling_step_share'):.2f}",
+      f"sampling steps {g('sampling_step_share'):.2f}, "
+      f"kv rows live {g('kv_read_share'):.2f}",
       f"  latency:    ttft p50 {g('ttft_p50_s') * 1e3:.1f}ms "
       f"p99 {g('ttft_p99_s') * 1e3:.1f}ms, "
       f"itl p50 {g('itl_p50_s') * 1e3:.2f}ms "

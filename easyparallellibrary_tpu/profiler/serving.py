@@ -147,6 +147,11 @@ class ServingStats:
     self._finished_order: Dict[Any, None] = {}
     self.steps = 0
     self.sampling_steps = 0     # steps with a slot at temperature > 0
+    # Cache rows under the bounds of the steps' live slots, and the rows
+    # the cache holds, summed over the steps: their ratio is the share
+    # of the cache a bounded attend reads (kernels/slot_attention.py).
+    self.live_kv_rows = 0
+    self.kv_rows = 0
     self.busy_time_s = 0.0
     self.prefill_tokens = 0
     self.decode_tokens = 0
@@ -306,8 +311,11 @@ class ServingStats:
   def note_step(self, active_slots: int, num_slots: int,
                 prefill_tokens: int, decode_tokens: int,
                 step_time_s: float, drafted_tokens: int = 0,
-                accepted_tokens: int = 0, sampled_slots: int = 0):
+                accepted_tokens: int = 0, sampled_slots: int = 0,
+                live_kv_rows: int = 0, kv_rows: int = 0):
     self.steps += 1
+    self.live_kv_rows += int(live_kv_rows)
+    self.kv_rows += int(kv_rows)
     if sampled_slots > 0:
       # The fused step sorts and draws only on these steps
       # (serving/engine.py sample_token_slots); a greedy step does not.
@@ -367,7 +375,8 @@ class ServingStats:
   # ----------------------------------------------------- wire round trip
 
   _STATE_SCALARS = (
-      "steps", "sampling_steps", "busy_time_s", "prefill_tokens",
+      "steps", "sampling_steps", "live_kv_rows", "kv_rows",
+      "busy_time_s", "prefill_tokens",
       "decode_tokens", "finished_requests", "generated_tokens",
       "drafted_tokens", "accepted_tokens", "shed_requests", "requeues",
       "bad_steps",
@@ -433,6 +442,10 @@ class ServingStats:
                                 if self.steps else 0.0),
         "sampling_step_share": (self.sampling_steps / self.steps
                                 if self.steps else 0.0),
+        # Share of the K/V cache's rows under a live slot's bound, over
+        # the steps: what an attend bounded per slot reads of it.
+        "kv_read_share": (self.live_kv_rows / self.kv_rows
+                          if self.kv_rows else 0.0),
         # Speculation (all 0.0 on a non-speculative engine): drafted vs
         # accepted totals, overall acceptance rate, and accepted-per-
         # step percentiles over the steps that drafted.
@@ -527,6 +540,9 @@ def fleet_summary(replica_stats: List["ServingStats"],
       "slot_occupancy_mean": occ,
       "sampling_step_share": (
           sum(s.sampling_steps for s in stats) / steps if steps else 0.0),
+      "kv_read_share": (
+          sum(s.live_kv_rows for s in stats)
+          / max(sum(s.kv_rows for s in stats), 1)),
       "drafted_tokens": float(drafted),
       "accepted_tokens": float(accepted),
       "acceptance_rate": (accepted / drafted) if drafted else 0.0,

@@ -327,12 +327,25 @@ class ContinuousBatchingEngine:
     # paged engine, whose pools take a scatter of flat rows).
     self.kv_write_impl = None if self.paged else kv_lib.kv_write_impl(
         cfg, self.num_slots, self.chunk, self.mesh)
+    # The attend over that cache (kernels/slot_attention.py): the
+    # kernel that reads each slot's live rows, or the einsums over every
+    # row of every slot — resolved once by the twin of the write's rule.
+    self.slot_attn_impl = None if self.paged else kv_lib.slot_attn_impl(
+        cfg, self.num_slots, self.chunk, self.mesh)
+    # Rows the cache holds for one layer: what ``serving/live_kv_rows``
+    # is a share of.
+    self._kv_rows = (self.num_blocks * self.block_size if self.paged else
+                     self.num_slots * kv_lib.cache_length(cfg, self.chunk))
     if self.kv_write_impl is not None:
-      # A run says which write it timed: a step that fell back shows
-      # "reference".  Metadata, so no ring eviction loses it.
+      # A run says which write and which attend it timed: a step that
+      # fell back shows "reference".  Metadata, so no ring eviction
+      # loses it.
       trace_lib.get_tracer().metadata(
           f"{self._track_prefix}/kv_write_impl",
           {"impl": self.kv_write_impl})
+      trace_lib.get_tracer().metadata(
+          f"{self._track_prefix}/slot_attn_impl",
+          {"impl": self.slot_attn_impl})
     # Recurrent state beside K/V (models/jamba.py): the scan's lowering
     # (kernels/ssm_scan.py), resolved once by the same kind of rule, and
     # what the cache holds of each kind of state.  None / absent for a
@@ -552,7 +565,7 @@ class ContinuousBatchingEngine:
                 f"{self._paged_impl} attend, "
                 f"{kv_lib.paged_cache_bytes(cfg, self.num_blocks, self.block_size) / 1e6:.1f} MB")
     else:
-      layout = (f"contiguous slots, xla attend, "
+      layout = (f"contiguous slots, {self.slot_attn_impl} attend, "
                 f"{self.kv_write_impl} kv write, "
                 f"{kv_lib.cache_bytes(cfg, self.num_slots, self.chunk) / 1e6:.1f} MB")
       if self._recurrent:
@@ -663,6 +676,7 @@ class ContinuousBatchingEngine:
         "num_slots": self.num_slots,
         "paged": self.paged,
         "kv_write_impl": self.kv_write_impl,
+        "slot_attn_impl": self.slot_attn_impl,
         "ssm_scan_impl": self.ssm_scan_impl,
         "recompiles": self._compile_sentinel.recompiles,
         "active_uids": [str(s.req.uid)
@@ -761,19 +775,23 @@ class ContinuousBatchingEngine:
     model = self.model
     C = self.chunk
     write_impl = self.kv_write_impl
+    attn_impl = self.slot_attn_impl
     scan_impl = self.ssm_scan_impl
     recurrent = self._recurrent
 
     def step(params, kv, cursors, tokens, num_valid, reset, keys,
              tok_index, temperature, top_k, top_p):
       cursors = jnp.where(reset, 0, cursors)
-      # A cache under a cursor masks what lies beyond it; a recurrence
-      # must be told how far each slot really advances and which slots
-      # start a request (stale state is masked by nothing).
-      state_args = dict(num_valid=num_valid, reset=reset,
+      # ``num_valid`` bounds what the attend reads of each slot's cache
+      # (an idle slot: nothing) and how far a recurrence advances; a
+      # recurrence must also be told which slots start a request (stale
+      # state is masked by nothing).
+      state_args = dict(reset=reset,
                         ssm_scan_impl=scan_impl) if recurrent else {}
       logits, kv = slot_step_logits(model, params, kv, tokens, cursors,
-                                    kv_write_impl=write_impl, **state_args)
+                                    kv_write_impl=write_impl,
+                                    slot_attn_impl=attn_impl,
+                                    num_valid=num_valid, **state_args)
       # Each slot's next-token logits sit at its LAST live chunk
       # position; idle slots (num_valid=0) read position 0 — garbage the
       # scheduler never consumes.
@@ -812,12 +830,15 @@ class ContinuousBatchingEngine:
     C = self.chunk
     K = self.drafter.k
     write_impl = self.kv_write_impl
+    attn_impl = self.slot_attn_impl
 
     def step(params, kv, cursors, tokens, num_valid, num_draft, reset,
              keys, tok_index, temperature, top_k, top_p):
       cursors = jnp.where(reset, 0, cursors)
       logits, kv = slot_step_logits(model, params, kv, tokens, cursors,
-                                    kv_write_impl=write_impl)
+                                    kv_write_impl=write_impl,
+                                    slot_attn_impl=attn_impl,
+                                    num_valid=num_valid)
       # base = non-draft tokens fed (prefill grant, or 1 for decode);
       # position base-1+j's logits are the target distribution for
       # draft j, and base-1+num_draft's feed the bonus token.  With
@@ -1448,9 +1469,14 @@ class ContinuousBatchingEngine:
     # plain step's own predicate reads (sample_token_slots), so 0 means
     # that step took the argmax alone and sorted nothing.
     sampled_slots = int(np.count_nonzero(plan.temperature > 0))
+    # Cache rows under the bounds of the slots the step fed (the plan's
+    # own sum of cursor + num_valid): what an attend bounded per slot
+    # reads of ``_kv_rows``, and all a roofline of it may count.
+    live_kv_rows = plan.live_kv_rows
     if tracer.enabled:
       tracer.counter("serving/active_slots", plan.active_slots)
       tracer.counter("serving/sampled_slots", sampled_slots)
+      tracer.counter("serving/live_kv_rows", live_kv_rows)
       if self._recurrent:
         # Slots whose recurrent state this step zeroed: requests that
         # started (or restarted, after a requeue) here.
@@ -1484,7 +1510,8 @@ class ContinuousBatchingEngine:
           prefill_tokens=pf_tokens,
           decode_tokens=dc_tokens, step_time_s=dt,
           drafted_tokens=drafted, accepted_tokens=accepted,
-          sampled_slots=sampled_slots)
+          sampled_slots=sampled_slots, live_kv_rows=live_kv_rows,
+          kv_rows=self._kv_rows)
       if self.paged:
         self.stats.note_blocks(self.scheduler.kv_blocks_free,
                                self.scheduler.kv_blocks_used,
@@ -1503,6 +1530,7 @@ class ContinuousBatchingEngine:
           "active_slots": plan.active_slots,
           "slot_occupancy": plan.active_slots / self.num_slots,
           "sampled_slots": sampled_slots,
+          "live_kv_rows": live_kv_rows,
           "prefill_tokens": pf_tokens,
           "decode_tokens": dc_tokens,
           "step_time_s": dt,

@@ -176,6 +176,24 @@ def kv_write_impl(cfg, num_slots: int, chunk: int,
       sharded=mesh is not None and mesh.size > 1)
 
 
+def slot_attn_impl(cfg, num_slots: int, chunk: int,
+                   mesh: Optional[Mesh] = None) -> Optional[str]:
+  """The lowering of the fused step's attend over the same K/V leaf —
+  the dispatch rule of kernels/slot_attention.py, resolved once like
+  :func:`kv_write_impl`: the kernel that reads each slot's live rows on
+  a TPU when the leaf sits whole on one chip and fits its blocks, the
+  einsums over every row everywhere else; ``None`` for a model without
+  an attention layer."""
+  from easyparallellibrary_tpu.kernels.slot_attention import (
+      resolve_slot_attn_impl)
+  if ATTENTION not in layer_kinds(cfg):
+    return None
+  shape = (num_slots, cache_length(cfg, chunk)) + kv_heads(cfg)
+  return resolve_slot_attn_impl(
+      shape, cfg.dtype, chunk, cfg.num_heads,
+      sharded=mesh is not None and mesh.size > 1)
+
+
 def ssm_scan_impl(cfg, num_slots: int, chunk: int,
                   mesh: Optional[Mesh] = None) -> Optional[str]:
   """The lowering of the fused step's selective scan over the recurrent

@@ -234,6 +234,9 @@ class StepPlan:
   prefill_tokens: int         # scheduled prompt tokens this step
   decode_tokens: int          # scheduled decode tokens this step
   active_slots: int
+  # Cache rows under the bounds of the slots this step feeds: the sum of
+  # cursor + num_valid (the host's mirror of the device cursor).
+  live_kv_rows: int = 0
 
 
 @dataclasses.dataclass
@@ -264,6 +267,7 @@ class PagedStepPlan:
   decode_tokens: int
   scheduled_tokens: int       # live flat positions (diagnostics)
   active_slots: int
+  live_kv_rows: int = 0       # as StepPlan's: resident + scheduled rows
 
 
 class _SlotState:
@@ -1333,6 +1337,8 @@ class FCFSScheduler:
       plan.temperature[slot] = req.temperature
       plan.top_k[slot] = req.top_k
       plan.top_p[slot] = req.top_p
+      plan.live_kv_rows += (self._resident_tokens(state)
+                            + int(plan.num_valid[slot]))
     plan.scheduled_tokens = pos
     plan.block_tables = self._tables.copy()
     if pos == 0:
@@ -1417,6 +1423,8 @@ class FCFSScheduler:
           # step's guaranteed token.
           remaining = req.max_new_tokens - len(state.generated)
           plan.draft_cap[slot] = max(0, min(spec_k, remaining - 1))
+      plan.live_kv_rows += (self._resident_tokens(state)
+                            + int(plan.num_valid[slot]))
     self._plan = plan
     return plan
 

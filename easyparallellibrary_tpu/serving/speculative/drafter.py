@@ -242,37 +242,47 @@ class DraftModelDrafter(Drafter):
     else:
       self._kv, self._cursors = kv_lib.allocate_kv_cache(
           self.model.cfg, engine.num_slots, engine.chunk, mesh)
+      geometry = (self.model.cfg, engine.num_slots, engine.chunk, mesh)
       self._fn = self._build_draft_fn(
-          engine.chunk, kv_lib.kv_write_impl(
-              self.model.cfg, engine.num_slots, engine.chunk, mesh))
+          engine.chunk, kv_lib.kv_write_impl(*geometry),
+          kv_lib.slot_attn_impl(*geometry))
 
-  def _build_draft_fn(self, chunk: int, kv_write_impl: str):
+  def _build_draft_fn(self, chunk: int, kv_write_impl: str,
+                      slot_attn_impl: str):
     from easyparallellibrary_tpu.models.gpt import slot_step_logits
     model, K, C = self.model, self.k, chunk
-    # One resolved cache-write lowering for the chunk-wide and the
-    # one-token calls alike (what fits a chunk fits one token).
+    # One resolved lowering of the cache write and one of the attend for
+    # the chunk-wide and the one-token calls alike (what fits a chunk
+    # fits one token).
     score = functools.partial(slot_step_logits,
-                              kv_write_impl=kv_write_impl)
+                              kv_write_impl=kv_write_impl,
+                              slot_attn_impl=slot_attn_impl)
 
     def draft(params, kv, cursors, tokens, num_valid, reset):
       cursors = jnp.where(reset, 0, cursors)
       # Mirror the engine's chunk: writes the same prefill K/V the
       # target wrote, and scores decode slots' last committed token.
-      logits, kv = score(model, params, kv, tokens, cursors)
+      logits, kv = score(model, params, kv, tokens, cursors,
+                         num_valid=num_valid)
       last = jnp.take_along_axis(
           logits, jnp.clip(num_valid - 1, 0, C - 1)[:, None, None],
           axis=1)[:, 0]
       toks = [jnp.argmax(last, axis=-1).astype(jnp.int32)]
       cur = cursors + num_valid
+      # The roll-out feeds one token to every slot the chunk fed; an
+      # idle slot's cache is not read.
+      fed = (num_valid > 0).astype(jnp.int32)
       for _ in range(1, K):
-        lg, kv = score(model, params, kv, toks[-1][:, None], cur)
+        lg, kv = score(model, params, kv, toks[-1][:, None], cur,
+                       num_valid=fed)
         toks.append(jnp.argmax(lg[:, 0], axis=-1).astype(jnp.int32))
         cur = cur + 1
       # Write-only feed of the final draft: its K/V must be cache-
       # resident too — if every draft is accepted the rolled-back cursor
       # covers its position, and a later step would attend garbage
       # there (the logits of this call are dead code XLA prunes).
-      _, kv = score(model, params, kv, toks[-1][:, None], cur)
+      _, kv = score(model, params, kv, toks[-1][:, None], cur,
+                    num_valid=fed)
       return jnp.stack(toks, axis=1), kv
 
     return jax.jit(draft, donate_argnums=(1,))

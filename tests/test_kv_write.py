@@ -307,6 +307,81 @@ def test_compiled_for_v5e_holds_the_kernel_and_no_copy_of_a_leaf(
   assert len(readers) == 2, entry
 
 
+@pytest.mark.parametrize("B,C,H,Hkv,hd,Lc", [
+    (96, CHUNK, 16, 16, 64, LC),        # the GPT-2 medium cells
+    (128, 8, 20, 1, 128, 8200),         # the hybrid cell: grouped heads
+], ids=["gpt2m_cells", "hybrid_cell"])
+def test_compiled_for_v5e_attends_in_one_kernel_with_no_score_tensor(
+    one_chip, B, C, H, Hkv, hd, Lc):
+  """``slot_cache_attend`` with both kernels (kernels/slot_attention.py
+  beside the write), compiled for a described v5e at the serving cells'
+  shapes: one ``kv_write`` and one ``slot_attn`` custom call, no score
+  tensor ``[B, H, C, Lc]`` in any form, and no instruction but the
+  kernels, parameters and bitcasts yields a whole leaf in the view the
+  kernels share (for GPT-2's position-minor leaf: none in any order, so
+  no copy of a leaf at all)."""
+  dt = jnp.bfloat16
+  spec = lambda shape, d=dt: jax.ShapeDtypeStruct(shape, d,
+                                                  sharding=one_chip)
+  new, leaf = spec((B, C, Hkv, hd)), spec((B, Lc, Hkv, hd))
+  fn = lambda q, k, v, ck, cv, cur, nv: slot_cache_attend(
+      q, k, v, ck, cv, cur, dt, write_impl="pallas", attn_impl="pallas",
+      num_valid=nv)
+  text = _compiled_text(
+      jax.jit(fn, donate_argnums=(3, 4)), spec((B, C, H, hd)), new, new,
+      leaf, leaf, spec((B,), jnp.int32), spec((B,), jnp.int32))
+  entry = text[text.index("\nENTRY "):]
+  named = lambda name: [l for l in entry.splitlines() if " custom-call("
+                        in l and name in l.split("=")[0]]
+  assert len(named("kv_write")) == 1 and len(named("slot_attn")) == 1, entry
+  assert " while(" not in text
+  assert not re.search(rf"\[{B},(?:{Hkv},{H // Hkv}|{H}),{C},{Lc}\]", text)
+  minor = f"bf16[{B},{Hkv},{hd},{Lc}]"
+  major = f"bf16[{B},{Lc},{Hkv},{hd}]"
+  # GPT-2's leaf is position-minor already: nothing may copy it in
+  # either order.  The hybrid's hd-minor leaf is copied into the shared
+  # view and back, counted below.
+  views = (minor, major) if hd < 128 else (minor,)
+  allowed = {"parameter", "bitcast", "get-tuple-element"} | (
+      {"copy"} if hd == 128 else set())
+  for line in entry.splitlines():
+    m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) (\S+?)\(", line)
+    if not m:
+      continue
+    if m.group(2).startswith(views):
+      assert m.group(3) in allowed, line
+  if hd == 128:
+    # An hd-minor leaf is relaid to position-minor for the write and
+    # back (PR 26); the attend reads that view and adds no relayout.
+    copies = [l for l in entry.splitlines() if re.match(
+        rf"\s*(?:ROOT )?%\S+ = bf16\[{B},{Lc},{Hkv},{hd}\]\S* copy\(", l)]
+    assert len(copies) == 4, entry
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_slot_attn_compiles_for_v5e_under_a_highest_precision_context(
+    one_chip, dtype):
+  """A caller's ``jax.default_matmul_precision("highest")`` (the chip
+  smoke's float32 cut runs under one) reaches the kernel's two
+  contractions when it is traced: Mosaic takes it for float32 operands
+  and refuses it for 16-bit ones ("Bad lhs type"), so the kernel names
+  the default for those itself."""
+  from easyparallellibrary_tpu.kernels.slot_attention import (
+      slot_attention_pallas)
+  B, C, H, hd = 8, CHUNK, 16, 64
+  spec = lambda shape, d=dtype: jax.ShapeDtypeStruct(shape, d,
+                                                     sharding=one_chip)
+  with jax.default_matmul_precision("highest"):
+    # a fresh function: the jitted wrapper's own trace cache is keyed on
+    # shapes, not on the context
+    text = _compiled_text(
+        jax.jit(lambda *a: slot_attention_pallas.__wrapped__(*a)),
+        spec((B, C, H, hd)), spec((B, LC, H, hd)), spec((B, LC, H, hd)),
+        spec((B,), jnp.int32), spec((B,), jnp.int32))
+  assert "slot_attn" in text
+
+
 def test_ssm_scan_compiled_for_v5e_moves_the_state_once(one_chip):
   """The selective-scan kernel (kernels/ssm_scan.py) at the hybrid cell's
   shapes, compiled for a described v5e (here beside the other compile of
