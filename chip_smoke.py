@@ -20,6 +20,16 @@ a user calls, and checks what comes out by the repo's own references:
                   AI21-Jamba2-3B's widths) through the engine: both
                   kernels resolved, one ``ssm_scan`` call a Mamba layer,
                   the fused step's logits against the reference lowering
+  experts         the sparse-expert decoder with a latent cache
+                  (models/glm_moe.py, GLM-4.7-Flash's widths): ``moe_gmm``,
+                  the one-leaf ``kv_write`` and the one-leaf ``slot_attn``
+                  at its cell's shapes (f32, bf16) against their reference
+                  lowerings; a three-layer cut (one dense, two expert
+                  layers, a vocabulary of 32768) through the engine: all
+                  three kernels resolved, their calls counted, the fused
+                  step's logits against the reference lowerings, and the
+                  served tokens against the teacher-forced full forward
+                  (expanded attention, ``ragged_dot``)
   four chips      (when the machine has four) the trainer as ``data:4``
                   and as ``data:2,model:2``
 
@@ -29,7 +39,8 @@ line of standard output of a passing run is
 
   {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
 
-``--rehearse-cpu`` runs the same control flow at toy sizes with the
+``--only <phase>`` runs that phase alone (no result line: not the whole
+proof).  ``--rehearse-cpu`` runs the same control flow at toy sizes with the
 kernels interpreted, to debug the script without a chip.  It says so, it
 prints no result line, and it always exits non-zero: it is not a pass.
 """
@@ -53,12 +64,15 @@ import easyparallellibrary_tpu as epl
 from easyparallellibrary_tpu.kernels import (
     flash_attention, kv_write_pallas, kv_write_reference,
     paged_attention_pallas, paged_attention_reference)
+from easyparallellibrary_tpu.kernels.moe_gmm import (
+    MOE_GMM, moe_gmm_pallas, moe_gmm_reference)
 from easyparallellibrary_tpu.kernels.slot_attention import (
     SLOT_ATTN, block_positions, slot_attention_pallas,
     slot_attention_reference)
 from easyparallellibrary_tpu.kernels.ssm_scan import (
     SSM_SCAN, ssm_scan_pallas, ssm_scan_reference)
 from easyparallellibrary_tpu.models import GPT, GPTConfig
+from easyparallellibrary_tpu.models.glm_moe import GlmMoe, GlmMoeConfig
 from easyparallellibrary_tpu.models.jamba import MAMBA, Jamba, JambaConfig
 from easyparallellibrary_tpu.models.gpt import (
     _dense_causal_attention, generate, gpt_loss, make_gpt_train_step)
@@ -123,6 +137,9 @@ class Sizes:
   kv_write_shape: tuple           # (slots, Lc, H, hd, chunk)
   hybrid_cfg: JambaConfig         # four layers of the hybrid decoder
   ssm_scan_shape: tuple           # (slots, d_state, d_inner, chunk)
+  experts_cfg: GlmMoeConfig       # one dense + two expert layers
+  moe_gmm_shapes: tuple           # (rows, K, N, experts) of a layer's two
+  latent_shape: tuple             # (slots, Lc, heads, latent, rank, chunk)
 
   @staticmethod
   def real() -> "Sizes":
@@ -149,7 +166,16 @@ class Sizes:
         # layers instead of two among 26; a cache for 1024 positions.
         hybrid_cfg=JambaConfig(num_layers=4, attn_layer_period=4,
                                attn_layer_offset=1, max_seq_len=1024),
-        ssm_scan_shape=(16, 16, 5120, 8))
+        ssm_scan_shape=(16, 16, 5120, 8),
+        # GLM-4.7-Flash's widths, every one of its 64 experts; the
+        # vocabulary cut to 32768 and the context to 1024 so that a
+        # float32 copy fits beside nothing else.
+        experts_cfg=GlmMoeConfig(vocab_size=32768, num_layers=3,
+                                 max_seq_len=1024, dtype=jnp.float32,
+                                 param_dtype=jnp.float32),
+        # The cell's: 96 slots x chunk 8 x 4 experts a token.
+        moe_gmm_shapes=((3072, 2048, 3072, 64), (3072, 1536, 2048, 64)),
+        latent_shape=(8, 4104, 20, 576, 512, 8))
 
   @staticmethod
   def toy() -> "Sizes":
@@ -173,7 +199,15 @@ class Sizes:
             num_kv_heads=1, attn_layer_period=4, attn_layer_offset=1,
             mamba_dt_rank=4, max_seq_len=128, dtype=jnp.float32,
             param_dtype=jnp.float32),
-        ssm_scan_shape=(4, 16, 128, 4))
+        ssm_scan_shape=(4, 16, 128, 4),
+        experts_cfg=GlmMoeConfig(
+            vocab_size=512, num_layers=3, d_model=128, d_ff=256,
+            moe_d_ff=128, num_heads=4, q_lora_rank=32, kv_lora_rank=32,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            n_routed_experts=8, num_experts_per_tok=2, max_seq_len=128,
+            dtype=jnp.float32, param_dtype=jnp.float32),
+        moe_gmm_shapes=((200, 128, 256, 8),),
+        latent_shape=(4, 136, 4, 40, 32, 8))
 
 
 def say(msg: str) -> None:
@@ -289,6 +323,18 @@ def check_kv_write(B, Lc, H, hd, C, dtype, rehearsal: bool) -> None:
       f"{jnp.dtype(dtype).name}: K and V leaves bit-identical")
 
 
+def edge_cases(r, B, Lc, C, block):
+  """``(cursors, num_valid)`` for an attend's check: a leaf's start, on
+  and across a block's edge, the last legal window, then random ones; a
+  partial chunk and an idle slot among them."""
+  cursors = np.asarray(
+      ([0, block - C // 2, Lc - C, block] + list(r.randint(0, Lc - C, B)))
+      [:B], np.int32)
+  num_valid = np.asarray(([C, C, C, 1, 0, C // 2 or 1] + [1] * B)[:B],
+                         np.int32)
+  return cursors, num_valid
+
+
 def check_slot_attn(B, Lc, H, hd, C, dtype, rehearsal: bool,
                     kv_heads: int = 0) -> None:
   """The live-rows attend against the einsums over every row: cursors at
@@ -301,11 +347,7 @@ def check_slot_attn(B, Lc, H, hd, C, dtype, rehearsal: bool,
   q = jnp.asarray(r.randn(B, C, H, hd), dtype)
   ck, cv = (r.randn(B, Lc, Hkv, hd).astype(np.float32) for _ in range(2))
   block = block_positions((B, Lc, Hkv, hd), dtype, C, H)
-  cursors = np.asarray(
-      ([0, block - C // 2, Lc - C, block] + list(r.randint(0, Lc - C, B)))
-      [:B], np.int32)
-  num_valid = np.asarray(([C, C, C, 1, 0, C // 2 or 1] + [1] * B)[:B],
-                         np.int32)
+  cursors, num_valid = edge_cases(r, B, Lc, C, block)
   dirty_k, dirty_v = ck.copy(), cv.copy()
   for b in range(B):
     bound = cursors[b] + num_valid[b] if num_valid[b] else 0
@@ -915,6 +957,175 @@ def phase_hybrid(sizes: Sizes) -> None:
       f"kernel against reference lowering {err:.2e}")
 
 
+# ---------------------------------------------------------------- experts --
+
+
+def check_moe_gmm(M, K, N, E, dtype, rehearsal: bool) -> None:
+  """The grouped matmul against ``ragged_dot``: ragged groups, empty ones
+  among them, one that straddles row tiles, and rows of no group."""
+  r = np.random.RandomState(5)
+  lhs = jnp.asarray(r.randn(M, K), dtype)
+  rhs = jnp.asarray(r.randn(E, K, N) / np.sqrt(K), dtype)
+  live = M // 2
+  cuts = np.sort(r.randint(0, live + 1, E - 1))
+  sizes = np.diff(np.concatenate([[0], cuts, [live]]))
+  sizes[1] += sizes[0]
+  sizes[0] = 0                                       # an empty first group
+  sizes = jnp.asarray(sizes, jnp.int32)
+  with jax.default_matmul_precision("highest"):
+    kernel = compile_here(
+        functools.partial(moe_gmm_pallas.__wrapped__, interpret=rehearsal),
+        lhs, rhs, sizes, mosaic_calls=1, rehearsal=rehearsal)
+    got = np.asarray(kernel(lhs, rhs, sizes), np.float32)
+    ref = np.asarray(jax.jit(moe_gmm_reference)(
+        lhs.astype(jnp.float32), rhs.astype(jnp.float32), sizes))
+  check((got[live:] == 0).all(), "moe_gmm: rows of no group are not zeros")
+  err = rel_err(got, ref)
+  tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+  check(err <= tol, f"moe_gmm {jnp.dtype(dtype).name}: error {err:.3g} of "
+        f"the reference's max, tol {tol}")
+  say(f"  moe_gmm rows{M} K{K} N{N} experts{E} {jnp.dtype(dtype).name}: "
+      f"{err:.2e} of the reference's max over {live} live rows, "
+      f"{int((np.asarray(sizes) == 0).sum())} empty groups, dead rows zeros")
+
+
+def check_latent_leaf(B, Lc, H, hd, rank, C, dtype, rehearsal: bool) -> None:
+  """The one-leaf forms: ``kv_write`` of a ``[B, Lc, 1, hd]`` leaf bit for
+  bit against ``dynamic_update_slice``, and ``slot_attn`` of ``H x C``
+  query rows against that one head, its values the leading ``rank``
+  columns of its keys, with NaN at and beyond every bound."""
+  r = np.random.RandomState(6)
+  leaf = r.randn(B, Lc, 1, hd).astype(np.float32)
+  rows = jnp.asarray(r.randn(B, C, 1, hd), dtype)
+  block = block_positions((B, Lc, 1, hd), dtype, C, H)
+  cursors, num_valid = edge_cases(r, B, Lc, C, block)
+  args = (jnp.asarray(leaf, dtype), None, rows, None, jnp.asarray(cursors))
+  write = compile_here(
+      functools.partial(kv_write_pallas, interpret=rehearsal),
+      *args, mosaic_calls=1, rehearsal=rehearsal)
+  got, _ = write(*args)
+  want, _ = jax.jit(kv_write_reference)(*args)
+  bits = lambda x: np.asarray(x).view(
+      {2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+  check((bits(got) == bits(want)).all(),
+        f"one-leaf kv_write {jnp.dtype(dtype).name} differs from the "
+        "reference")
+  clean = np.asarray(want.astype(jnp.float32))
+  dirty = clean.copy()
+  for b in range(B):
+    dirty[b, cursors[b] + num_valid[b] if num_valid[b] else 0:] = np.nan
+  q = jnp.asarray(r.randn(B, C, H, hd) / np.sqrt(hd), dtype)
+  scale = 1.0 / 16.0
+  with jax.default_matmul_precision("highest"):
+    attend = compile_here(
+        functools.partial(slot_attention_pallas.__wrapped__,
+                          interpret=rehearsal, v_width=rank, scale=scale),
+        q, jnp.asarray(dirty, dtype), None, jnp.asarray(cursors),
+        jnp.asarray(num_valid), mosaic_calls=1, rehearsal=rehearsal)
+    out = np.asarray(attend(q, jnp.asarray(dirty, dtype), None,
+                            jnp.asarray(cursors), jnp.asarray(num_valid)),
+                     np.float32)
+    ref = np.asarray(jax.jit(functools.partial(
+        slot_attention_reference, v_width=rank, scale=scale))(
+            q.astype(jnp.float32), jnp.asarray(clean), None,
+            jnp.asarray(cursors)))
+  real = (np.arange(C)[None] < num_valid[:, None])[:, :, None, None]
+  check(np.isfinite(out).all(), "one-leaf slot_attn output not finite")
+  err = rel_err(np.where(real, out, 0), np.where(real, ref, 0))
+  tol = 2e-2 if dtype == jnp.bfloat16 else 5e-4
+  check(err <= tol, f"one-leaf slot_attn {jnp.dtype(dtype).name}: error "
+        f"{err:.3g} of the reference's max, tol {tol}")
+  say(f"  latent leaf slots{B} Lc{Lc} heads{H}/1 width{hd} values{rank} "
+      f"chunk{C} block{block} {jnp.dtype(dtype).name}: kv_write "
+      f"bit-identical, slot_attn {err:.2e} of the reference's max, NaN "
+      "beyond the bounds unread")
+
+
+def phase_experts(sizes: Sizes) -> None:
+  from easyparallellibrary_tpu.models.gpt import slot_step_logits
+  from easyparallellibrary_tpu.serving import kv_cache as kv_lib
+  for dtype in (jnp.float32, jnp.bfloat16):
+    for shape in sizes.moe_gmm_shapes:
+      check_moe_gmm(*shape, dtype, rehearsal=sizes.rehearsal)
+    check_latent_leaf(*sizes.latent_shape, dtype, rehearsal=sizes.rehearsal)
+  cfg = sizes.experts_cfg
+  model = GlmMoe(cfg)
+  params = jax.jit(lambda: model.init(
+      jax.random.PRNGKey(2), jnp.zeros((1, 8), jnp.int32))["params"])()
+  prompts = seeded_requests(sizes, cfg)
+  n_moe = cfg.num_layers - cfg.first_k_dense
+  with jax.default_matmul_precision("highest"):
+    eng = ContinuousBatchingEngine(model, params)
+    spy = _StepSpecs(eng)
+    for uid, p in enumerate(prompts):
+      check(eng.submit(Request(uid=uid, prompt=p,
+                               max_new_tokens=sizes.new_tokens)),
+            f"request {uid} refused at admission")
+    out = eng.run()
+    for uid, p in enumerate(prompts):
+      check(uid in out and len(out[uid]) == len(p) + sizes.new_tokens,
+            f"expert-model request {uid} did not run to its length")
+    check(spy._cache_size() == 1,
+          f"expert model's fused step compiled {spy._cache_size()} times")
+    say(f"  expert engine: {len(prompts)} requests, attend "
+        f"{eng.slot_attn_impl}, kv write {eng.kv_write_impl}, expert "
+        f"matmul {eng.moe_gmm_impl}, cache {eng.cache_layout}")
+    if not sizes.rehearsal:
+      impls = (eng.kv_write_impl, eng.slot_attn_impl, eng.moe_gmm_impl)
+      hlo = spy.inner.lower(*spy.specs).compile().as_text()
+      calls = {n: named_calls(hlo, n) for n in (MOE_GMM, SLOT_ATTN,
+                                                "kv_write")}
+      want = {MOE_GMM: 2 * n_moe, SLOT_ATTN: cfg.num_layers,
+              "kv_write": cfg.num_layers}
+      check(all(i == "pallas" for i in impls) and calls == want,
+            f"expert engine resolved {impls}; custom calls {calls}, "
+            f"expected {want}")
+    # The served tokens against the teacher-forced full forward: expanded
+    # attention over the whole sequence, the experts by ragged_dot.
+    streams = [out[uid] for uid in range(len(prompts))]
+    ids = np.zeros((len(streams), cfg.max_seq_len), np.int32)
+    for i, s in enumerate(streams):
+      ids[i, :len(s)] = s
+    full = jax.jit(lambda p, ids: GlmMoe(cfg).apply(
+        {"params": p}, ids, moe_gmm_impl="reference").astype(jnp.float32))
+    logits = np.asarray(full(params, jnp.asarray(ids)))
+    gap = 0.0
+    for i, (p, s) in enumerate(zip(prompts, streams)):
+      rows = logits[i, len(p) - 1:len(s) - 1]
+      served = rows[np.arange(len(rows)), s[len(p):]]
+      gap = max(gap, float((rows.max(-1) - served).max()
+                           / np.abs(rows).max()))
+    check(gap <= 1e-4, f"a served token's logit lies {gap:.3g} of the "
+          "largest logit below the teacher-forced best (limit 1e-4)")
+    # One fused call, kernels against reference lowerings, on the same
+    # inputs: prefill chunks, decodes and idle slots side by side.
+    N, C = 8, eng.chunk
+    r = np.random.RandomState(4)
+    tokens = jnp.asarray(r.randint(0, cfg.vocab_size, (N, C)), jnp.int32)
+    num_valid = jnp.asarray([C, 1, 0, C // 2, 1, C, 0, 1], jnp.int32)
+    kernel_impl = "interpret" if sizes.rehearsal else "pallas"
+    got = {}
+    for impl in (kernel_impl, "reference"):
+      kv, cursors = kv_lib.allocate_kv_cache(cfg, N, C)
+      step = jax.jit(functools.partial(
+          slot_step_logits, model, kv_write_impl=impl, slot_attn_impl=impl,
+          moe_gmm_impl=impl))
+      for _ in range(2):       # the second call reads what the first wrote
+        lg, kv = step(params, kv, tokens, cursors, num_valid=num_valid)
+        cursors = cursors + num_valid
+      got[impl] = lg[np.arange(C)[None] < np.asarray(num_valid)[:, None]]
+  err = rel_err(got[kernel_impl], got["reference"])
+  tol = 1e-4 if jnp.dtype(cfg.dtype).itemsize == 4 else 3e-2
+  check(err <= tol, f"expert step logits, kernels against the reference "
+        f"lowerings: {err:.3g} of the largest logit (limit {tol})")
+  say(f"PASS experts: moe_gmm and the one-leaf kv_write and slot_attn f32 "
+      "+ bf16 " + ("INTERPRETED" if sizes.rehearsal else "compiled")
+      + f" against their references; {cfg.first_k_dense} dense + {n_moe} "
+      f"expert layers served, served tokens within {gap:.1e} of the "
+      f"teacher-forced best; step logits kernels against reference "
+      f"lowerings {err:.2e}")
+
+
 # ------------------------------------------------------------------- main --
 
 
@@ -936,6 +1147,10 @@ def main(argv=None) -> int:
       "--rehearse-cpu", action="store_true",
       help="toy sizes, interpreted kernels, on the CPU; always exits "
            f"{REHEARSAL_EXIT}; not a pass")
+  parser.add_argument(
+      "--only", default=None,
+      help="run this one phase (kernels, train, serve, hybrid, experts); "
+           "prints no result line")
   args = parser.parse_args(argv)
   t_start = time.perf_counter()
   cache_dir = compile_cache.configure()
@@ -958,11 +1173,17 @@ def main(argv=None) -> int:
   for name, phase in (("kernels", lambda: phase_kernels(sizes)),
                       ("train", lambda: phase_train(sizes, dev)),
                       ("serve", lambda: phase_serve(sizes)),
-                      ("hybrid", lambda: phase_hybrid(sizes))):
+                      ("hybrid", lambda: phase_hybrid(sizes)),
+                      ("experts", lambda: phase_experts(sizes))):
+    if args.only not in (None, name):
+      continue
     t0 = time.perf_counter()
     say(f"== {name}")
     phase()
     say(f"   ({name}: {time.perf_counter() - t0:.1f} s)")
+  if args.only is not None:
+    say(f"only {args.only!r} ran: not the whole proof, no result line")
+    return REHEARSAL_EXIT if args.rehearse_cpu else 0
   if count >= 4:
     t0 = time.perf_counter()
     say("== four chips")

@@ -1,6 +1,8 @@
 from easyparallellibrary_tpu.kernels.flash_attention import flash_attention
 from easyparallellibrary_tpu.kernels.kv_write import (
     kv_write_pallas, kv_write_reference)
+from easyparallellibrary_tpu.kernels.moe_gmm import (
+    moe_gmm_pallas, moe_gmm_reference)
 from easyparallellibrary_tpu.kernels.slot_attention import (
     slot_attention_pallas, slot_attention_reference)
 from easyparallellibrary_tpu.kernels.ssm_scan import (
@@ -13,6 +15,7 @@ from easyparallellibrary_tpu.kernels.paged_attention import (
 __all__ = [
     "flash_attention",
     "kv_write_pallas", "kv_write_reference",
+    "moe_gmm_pallas", "moe_gmm_reference",
     "paged_attention", "paged_attention_pallas",
     "paged_attention_reference", "set_paged_attention_impl",
     "slot_attention_pallas", "slot_attention_reference",

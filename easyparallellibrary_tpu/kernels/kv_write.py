@@ -37,6 +37,11 @@ the lowering ONCE when it builds its step and records it
 
 Shapes: ``cached_k/cached_v`` ``[B, Lc, H, hd]``; ``k/v`` ``[B, C, H,
 hd]``; ``cursors`` int32 ``[B]``.
+
+A layer whose cache is ONE leaf (models/glm_moe.py: the latent ``[B, Lc,
+1, 576]`` whose values are its keys' leading columns) passes ``cached_v =
+v = None``: the same call with one operand instead of two, and ``None``
+back in the second place.
 """
 
 from __future__ import annotations
@@ -109,6 +114,8 @@ def resolve_kv_write_impl(cache_shape, dtype, chunk: int,
 def kv_write_reference(cached_k, cached_v, k, v, cursors):
   """One ``dynamic_update_slice`` per slot at its own cursor."""
   def write(cache, new):
+    if cache is None:
+      return None
     return jax.vmap(
         lambda row, chunk, cur: jax.lax.dynamic_update_slice(
             row, chunk, (cur, 0, 0)))(cache, new.astype(cache.dtype),
@@ -125,10 +132,11 @@ def _window_tile(cur, j, chunk: int):
   return (cur + j * (chunk - 1)) // LANES
 
 
-def _kv_write_kernel(cur_ref, k_new_ref, v_new_ref, k_in_ref, v_in_ref,
-                     k_out_ref, v_out_ref, stage_ref, *, chunk: int):
+def _kv_write_kernel(cur_ref, *refs, chunk: int):
   """One (slot, tile) grid step: lay the slot's chunk over the lanes
-  ``[cursor, cursor + chunk)`` of this 128-position tile, for K and V.
+  ``[cursor, cursor + chunk)`` of this 128-position tile, for K and V
+  (``refs``: the leaves' chunks, their tiles in, their tiles out, the
+  staging tile; one leaf or two).
 
   Values keep ``[H, hd, position]`` — ``hd`` on sublanes, positions on
   lanes.  The chunk is staged into lanes ``[0, chunk)`` of a scratch
@@ -138,6 +146,9 @@ def _kv_write_kernel(cur_ref, k_new_ref, v_new_ref, k_in_ref, v_in_ref,
   twice: the block stays resident between the two steps, so nothing
   moves, and skipping the second pass saved no time on the chip (the
   tile's DMA bounds a step, not its arithmetic)."""
+  *refs, stage_ref = refs
+  n = len(refs) // 3
+  k_in_ref = refs[n]
   b = pl.program_id(0)
   j = pl.program_id(1)
   cur = cur_ref[b]
@@ -151,8 +162,7 @@ def _kv_write_kernel(cur_ref, k_new_ref, v_new_ref, k_in_ref, v_in_ref,
   # uint32 words its sublane pairs already are in a register.
   packed = k_in_ref.dtype.itemsize < 4
   as32 = (lambda x: pltpu.bitcast(x, jnp.uint32)) if packed else (lambda x: x)
-  for new_ref, in_ref, out_ref in ((k_new_ref, k_in_ref, k_out_ref),
-                                   (v_new_ref, v_in_ref, v_out_ref)):
+  for new_ref, in_ref, out_ref in zip(refs[:n], refs[n:2 * n], refs[2 * n:]):
     stage_ref[:, :, :chunk] = as32(new_ref[0])
     moved = pltpu.roll(stage_ref[...], shift, 2)
     merged = jnp.where(window, moved, as32(in_ref[0]))
@@ -170,6 +180,9 @@ def kv_write_pallas(cached_k, cached_v, k, v, cursors,
   B, Lc, H, hd = cached_k.shape
   C = k.shape[1]
   dtype = cached_k.dtype
+  caches, news = ((cached_k, cached_v), (k, v)) if cached_v is not None \
+      else ((cached_k,), (k,))
+  n = len(caches)
   # ``dynamic_update_slice`` clamps a start that would run the window
   # off the leaf; the contract keeps cursors inside (kv_cache.py), and
   # the clamp keeps the two lowerings equal outside it too.
@@ -187,8 +200,8 @@ def kv_write_pallas(cached_k, cached_v, k, v, cursors,
   grid_spec = pltpu.PrefetchScalarGridSpec(
       num_scalar_prefetch=1,
       grid=(B, n_tiles),
-      in_specs=[chunk_spec, chunk_spec, tile_spec, tile_spec],
-      out_specs=[tile_spec, tile_spec],
+      in_specs=[chunk_spec] * n + [tile_spec] * n,
+      out_specs=[tile_spec] * n,
       # 32-bit words: a 16-bit leaf packs two ``hd`` rows into each.
       scratch_shapes=[pltpu.VMEM((H, hd * dtype.itemsize // 4, LANES),
                                  jnp.float32 if dtype.itemsize == 4
@@ -199,19 +212,20 @@ def kv_write_pallas(cached_k, cached_v, k, v, cursors,
     kwargs["compiler_params"] = pltpu.CompilerParams(
         dimension_semantics=("arbitrary", "arbitrary"))
   leaf = jax.ShapeDtypeStruct((B, H, hd, Lc), dtype)
-  new_k, new_v = pl.pallas_call(
+  written = pl.pallas_call(
       functools.partial(_kv_write_kernel, chunk=C),
       grid_spec=grid_spec,
-      out_shape=[leaf, leaf],
-      # Operands count the scalar-prefetch cursors: 3, 4 are the leaves.
-      input_output_aliases={3: 0, 4: 1},
+      out_shape=[leaf] * n,
+      # Operands count the scalar-prefetch cursors: the leaves follow
+      # them and the chunks (3, 4 of two leaves; 2 of one).
+      input_output_aliases={1 + n + i: i for i in range(n)},
       interpret=interpret,
       name=KV_WRITE,
       **kwargs,
-  )(cursors, to_minor(k), to_minor(v), to_minor(cached_k),
-    to_minor(cached_v))
+  )(cursors, *map(to_minor, news), *map(to_minor, caches))
   to_major = lambda x: jnp.transpose(x, (0, 3, 1, 2))
-  return to_major(new_k), to_major(new_v)
+  return (to_major(written[0]),
+          to_major(written[1]) if n == 2 else None)
 
 
 # --------------------------------------------------------------- dispatch --
@@ -219,7 +233,8 @@ def kv_write_pallas(cached_k, cached_v, k, v, cursors,
 
 def kv_write(cached_k, cached_v, k, v, cursors, impl: Optional[str] = None):
   """Write each slot's K/V chunk at its cursor (module docstring);
-  returns ``(new_cached_k, new_cached_v)``.  ``impl=None`` applies the
+  returns ``(new_cached_k, new_cached_v)``, the second ``None`` for a
+  one-leaf layer (``cached_v = v = None``).  ``impl=None`` applies the
   dispatch rule to the shapes at hand, and takes the leaf as spread
   over chips whenever a multi-device mesh has been built (the legacy
   ``generate()`` decode); the serving engine resolves the impl from its

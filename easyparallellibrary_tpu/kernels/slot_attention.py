@@ -52,6 +52,15 @@ tests, by name or by patching :func:`_backend_impl`).  The engine
 resolves it once when it builds its step and records it
 (``engine.slot_attn_impl``, trace metadata ``serving/slot_attn_impl``).
 
+A layer whose cache is ONE leaf (models/glm_moe.py: the latent ``[B, Lc,
+1, 576]`` of absorbed multi-head latent attention) passes ``cached_v =
+None`` and ``v_width``: the values are the keys' leading ``v_width``
+columns.  The kernel takes them from the K block it already holds in VMEM
+(the leading sublanes of the position-minor block), so the leaf is read
+from HBM once a block, not once as keys and once as values, and the
+output is ``[B, C, H, v_width]``.  ``scale`` replaces ``1 / sqrt(hd)``
+where the queries' width is not the head size the softmax is scaled by.
+
 Shapes: ``q`` ``[B, C, H, hd]``; ``cached_k/cached_v`` ``[B, Lc, H_kv,
 hd]`` AFTER this step's window write; ``cursors``, ``num_valid`` int32
 ``[B]``.
@@ -159,13 +168,18 @@ def resolve_slot_attn_impl(cache_shape, dtype, chunk: int, num_heads: int,
 # -------------------------------------------------------------- reference --
 
 
-def slot_attention_reference(q, cached_k, cached_v, cursors):
+def slot_attention_reference(q, cached_k, cached_v, cursors,
+                             v_width: Optional[int] = None,
+                             scale: Optional[float] = None):
   """Every query against every row of its slot's cache, masked to the
   causal prefix ``j <= cursor + i``: nothing newer, nothing stale."""
   B, C, H, hd = q.shape
   Lc, Hkv = cached_k.shape[1:3]
   dtype = q.dtype
-  scale = 1.0 / jnp.sqrt(hd).astype(dtype)
+  if cached_v is None:
+    cached_v = cached_k[..., :v_width]
+  scale = (1.0 / jnp.sqrt(hd).astype(dtype) if scale is None
+           else jnp.asarray(scale, dtype))
   # Grouped heads: query head h reads K/V head h // (H / H_kv); the
   # group is one more axis of the same two contractions.
   if Hkv != H:
@@ -180,15 +194,15 @@ def slot_attention_reference(q, cached_k, cached_v, cursors):
   logits = jnp.where(valid, logits, jnp.asarray(-1e9, logits.dtype))
   probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
   out = jnp.einsum(pv, probs.astype(dtype), cached_v)
-  return out.reshape(B, C, H, hd)
+  return out.reshape(B, C, H, cached_v.shape[-1])
 
 
 # ----------------------------------------------------------------- pallas --
 
 
 def _slot_attn_kernel(order_ref, live_ref, cur_ref, bound_ref, pos_ref,
-                      q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                      block: int, num_blocks: int, scale: float):
+                      q_ref, k_ref, *refs, block: int, num_blocks: int,
+                      scale: float, v_width: Optional[int]):
   """One (slot, K/V block) grid step: score the block against every
   query row of every head, fold it into the online softmax carried in
   VMEM scratch, emit on the slot's last step.
@@ -199,7 +213,11 @@ def _slot_attn_kernel(order_ref, live_ref, cur_ref, bound_ref, pos_ref,
   of both operands).  ``order_ref`` names the slot of this grid row
   (live slots only are visited), ``live_ref`` is the index maps' alone,
   ``pos_ref`` holds each query row's position in the chunk (rows beyond
-  the chunk carry one no slot reaches)."""
+  the chunk carry one no slot reaches).  ``refs``: the V block (absent
+  for a one-leaf layer, whose values are the K block's leading
+  ``v_width`` sublanes), the output block and the three scratches."""
+  v_ref = refs[0] if v_width is None else None
+  o_ref, m_ref, l_ref, acc_ref = refs[-4:]
   b = order_ref[pl.program_id(0)]
   kb = pl.program_id(1)
   cur = cur_ref[b]
@@ -212,7 +230,8 @@ def _slot_attn_kernel(order_ref, live_ref, cur_ref, bound_ref, pos_ref,
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
   def fold(edge: bool):
-    q, k, v = q_ref[0], k_ref[0], v_ref[0]
+    q, k = q_ref[0], k_ref[0]
+    v = v_ref[0] if v_width is None else k[:, :v_width]
     # 16-bit operands multiply exactly on the MXU whatever precision the
     # caller's context names (and Mosaic refuses a float32 contraction
     # of them); float32 operands follow the context, as the einsums do.
@@ -264,10 +283,13 @@ def _slot_attn_kernel(order_ref, live_ref, cur_ref, bound_ref, pos_ref,
         o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "block"))
+@functools.partial(jax.jit, static_argnames=("interpret", "block",
+                                             "v_width", "scale"))
 def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
                           interpret: bool = False,
-                          block: Optional[int] = None):
+                          block: Optional[int] = None,
+                          v_width: Optional[int] = None,
+                          scale: Optional[float] = None):
   """The live-rows attend; ``interpret`` runs the kernel in Pallas
   interpreter mode (any backend), ``block`` overrides
   :func:`block_positions` (tests and measurement).  Jitted, so that the
@@ -277,6 +299,8 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
   _, Lc, Hkv, _ = cached_k.shape
   G = H // Hkv
   dtype = cached_k.dtype
+  # One leaf: no V operand, the values' width is ``v_width``.
+  vd = hd if cached_v is not None else v_width
   if block is None:
     block = block_positions(cached_k.shape, dtype, C, H)
   nb = pl.cdiv(Lc, block)
@@ -328,19 +352,20 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
     return (jnp.where(reads, b, jnp.where(more, ahead, b)), 0, 0,
             jnp.where(reads, kb, jnp.where(more, 0, held)))
 
-  row_spec = pl.BlockSpec((1, Hkv, rows, hd),
-                          lambda i, kb, order, *_: (order[i], 0, 0, 0))
+  row_spec = lambda width: pl.BlockSpec(
+      (1, Hkv, rows, width), lambda i, kb, order, *_: (order[i], 0, 0, 0))
   kv_spec = pl.BlockSpec((1, Hkv, hd, block), kv_idx)
+  leaves = [cached_k] if cached_v is None else [cached_k, cached_v]
   grid_spec = pltpu.PrefetchScalarGridSpec(
       num_scalar_prefetch=4,
       grid=(live[0], nb),
       in_specs=[pl.BlockSpec((rows, 1), lambda i, kb, *_: (0, 0)),
-                row_spec, kv_spec, kv_spec],
-      out_specs=row_spec,
+                row_spec(hd)] + [kv_spec] * len(leaves),
+      out_specs=row_spec(vd),
       scratch_shapes=[
           pltpu.VMEM((Hkv, rows, LANES), jnp.float32),   # running max
           pltpu.VMEM((Hkv, rows, LANES), jnp.float32),   # running sum
-          pltpu.VMEM((Hkv, rows, hd), jnp.float32),      # accumulator
+          pltpu.VMEM((Hkv, rows, vd), jnp.float32),      # accumulator
       ],
   )
   kwargs = {}
@@ -348,27 +373,32 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
     kwargs["compiler_params"] = pltpu.CompilerParams(
         dimension_semantics=("arbitrary", "arbitrary"))
   out = pl.pallas_call(
-      functools.partial(_slot_attn_kernel, block=block, num_blocks=nb,
-                        scale=1.0 / math.sqrt(hd)),
+      functools.partial(
+          _slot_attn_kernel, block=block, num_blocks=nb,
+          scale=1.0 / math.sqrt(hd) if scale is None else float(scale),
+          v_width=None if cached_v is not None else v_width),
       grid_spec=grid_spec,
-      out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, hd), dtype),
+      out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, vd), dtype),
       interpret=interpret,
       name=SLOT_ATTN,
       **kwargs,
-  )(order, live, cur, bound, pos, qr, to_minor(cached_k),
-    to_minor(cached_v))
+  )(order, live, cur, bound, pos, qr, *map(to_minor, leaves))
   out = jnp.where(alive[:, None, None, None], out, 0)
-  out = out[:, :, :G * C].reshape(B, Hkv, G, C, hd)
-  return out.transpose(0, 3, 1, 2, 4).reshape(B, C, H, hd)
+  out = out[:, :, :G * C].reshape(B, Hkv, G, C, vd)
+  return out.transpose(0, 3, 1, 2, 4).reshape(B, C, H, vd)
 
 
 # --------------------------------------------------------------- dispatch --
 
 
 def slot_attention(q, cached_k, cached_v, cursors, num_valid=None,
-                   impl: Optional[str] = None):
+                   impl: Optional[str] = None,
+                   v_width: Optional[int] = None,
+                   scale: Optional[float] = None):
   """Attend each slot's chunk over its own cache (module docstring);
-  returns ``out [B, C, H, hd]``.  ``impl=None`` applies the dispatch rule
+  returns ``out [B, C, H, hd]`` (``[B, C, H, v_width]`` for a one-leaf
+  layer: ``cached_v=None``, the values the keys' leading ``v_width``
+  columns).  ``impl=None`` applies the dispatch rule
   to the shapes at hand, and takes the leaf as spread over chips
   whenever a multi-device mesh has been built (the legacy ``generate()``
   decode); the serving engine resolves the impl from its own mesh and
@@ -381,7 +411,12 @@ def slot_attention(q, cached_k, cached_v, cursors, num_valid=None,
         sharded=mesh is not None and mesh.size > 1)
   if impl not in IMPLS:
     raise ValueError(f"impl must be one of {IMPLS} or None; got {impl!r}")
+  if (cached_v is None) != (v_width is not None):
+    raise ValueError("a one-leaf attend passes cached_v=None AND v_width; "
+                     "a K/V pair passes neither")
   if impl == "reference":
-    return slot_attention_reference(q, cached_k, cached_v, cursors)
+    return slot_attention_reference(q, cached_k, cached_v, cursors,
+                                    v_width=v_width, scale=scale)
   return slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid,
-                               interpret=impl == "interpret")
+                               interpret=impl == "interpret",
+                               v_width=v_width, scale=scale)

@@ -2,6 +2,7 @@ from easyparallellibrary_tpu.models.gpt import (
     GPT, GPTConfig, auto_parallel_gpt, make_gpt_train_step,
 )
 from easyparallellibrary_tpu.models.jamba import Jamba, JambaConfig
+from easyparallellibrary_tpu.models.glm_moe import GlmMoe, GlmMoeConfig
 from easyparallellibrary_tpu.models.bert import (
     Bert, BertConfig, bert_large_config,
 )
@@ -12,6 +13,7 @@ from easyparallellibrary_tpu.models.resnet import (
 __all__ = [
     "GPT", "GPTConfig", "auto_parallel_gpt", "make_gpt_train_step",
     "Jamba", "JambaConfig",
+    "GlmMoe", "GlmMoeConfig",
     "Bert", "BertConfig", "bert_large_config",
     "ResNet", "ResNetConfig", "resnet18_config", "resnet50_config",
 ]

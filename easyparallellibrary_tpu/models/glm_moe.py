@@ -1,0 +1,244 @@
+"""GLM-MoE — a sparse-expert decoder with multi-head latent attention.
+
+``model_type: glm4_moe_lite`` (GLM-4.7-Flash; the DeepSeek-V3 block at
+another size): pre-RMSNorm residual layers, every mixer multi-head latent
+attention (MLA) with rotary positions on a 64-wide slice of each query
+head and on ONE key slice all heads share; the feed-forward a dense
+SiLU-gated MLP in the first ``first_k_dense`` layers and routed experts
+without capacity beside a shared one in every other (models/moe.py:
+:class:`DroplessMoE`); a final RMSNorm and an UNTIED head.
+``perfbench/reference/glm4_moe_lite.py`` holds the same equations in plain
+float32 and the tests compare the two.  The multi-token-prediction module
+of the published checkpoint takes no part in next-token logits and is not
+built.
+
+MLA, per position with hidden state ``x``: ``c_q = RMSNorm(W_qa x)``;
+``[q_nope | q_rope] = W_qb c_q`` per head; ``[c_kv | k_r] = W_kva x``;
+``c = RMSNorm(c_kv)``; rotary on ``q_rope`` and ``k_r``; ``[k_nope | v] =
+W_kvb c`` per head; ``score = (q_nope . k_nope + q_rope . k_r) /
+sqrt(nope + rope)``, causal softmax, ``W_o`` over the heads' ``sum p v``.
+
+Two forms of the same attention:
+
+* the full forward (``decode=False``) EXPANDS ``k_nope`` and ``v`` for the
+  whole sequence, as the reference does;
+* slot mode (``decode=True``, the serving engine) keeps per position only
+  the LATENT ``[c | rot(k_r)]`` — ``kv_lora_rank + qk_rope_head_dim``
+  values, one cache leaf a layer ``[slots, Lc, 1, 576]`` (kind
+  :data:`LATENT` in ``serving/kv_cache.py``) where expanded keys and
+  values would take ``heads x (256 + 256)`` — and attends in the ABSORBED
+  form: ``q' = [q_nope W_kvb^K | q_rope]``, ``score = q' . latent``,
+  ``o = (sum p latent[:rank]) W_kvb^V``.  The heads become query rows
+  against one head whose values are the leading ``kv_lora_rank`` columns
+  of its keys (``kernels/kv_write.py`` and ``kernels/slot_attention.py``
+  in their one-leaf form).  Equal to the expanded form up to rounding:
+  matrix products re-associated.
+
+A latent row, like a K/V row, is addressed under a cursor, so a partly
+valid chunk is harmless; but nothing here can restore a cache the paged
+layout, prefix caching or speculation would need (no paged pool of latent
+rows is built): ``serving/_capabilities.py`` refuses them.
+
+Precision: the residual stream and the matmuls in ``cfg.dtype``; norms,
+rotary angles, the softmax and the router (scores, choice, weights) in
+float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from easyparallellibrary_tpu.models.gpt import _missing_slot_cache
+from easyparallellibrary_tpu.models.jamba import (
+    GatedMLP, RMSNorm, _boxed, _dense)
+from easyparallellibrary_tpu.models.moe import DroplessMoE
+from easyparallellibrary_tpu.ops import Embedding
+
+# What a layer keeps per slot (serving/kv_cache.py reads
+# ``cfg.layer_kinds()``): one latent leaf, no K/V pair.
+LATENT = "latent"
+
+
+@dataclasses.dataclass(frozen=True)
+class GlmMoeConfig:
+  vocab_size: int = 154880
+  num_layers: int = 47
+  d_model: int = 2048
+  d_ff: int = 10240                  # the leading dense layers' MLP
+  moe_d_ff: int = 1536               # one expert's width
+  num_heads: int = 20
+  q_lora_rank: int = 768
+  kv_lora_rank: int = 512
+  qk_nope_head_dim: int = 192
+  qk_rope_head_dim: int = 64
+  v_head_dim: int = 256
+  n_routed_experts: int = 64
+  n_shared_experts: int = 1
+  num_experts_per_tok: int = 4
+  first_k_dense: int = 1
+  routed_scaling_factor: float = 1.8
+  norm_topk_prob: bool = True
+  rope_theta: float = 1e6
+  rms_norm_eps: float = 1e-5
+  max_seq_len: int = 4096            # served context; the cache's length
+  dtype: Any = jnp.bfloat16
+  param_dtype: Any = jnp.bfloat16
+
+  @property
+  def latent_dim(self) -> int:
+    """Values a position keeps: the compressed K/V and the shared rotary
+    key."""
+    return self.kv_lora_rank + self.qk_rope_head_dim
+
+  @property
+  def qk_head_dim(self) -> int:
+    return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+  def layer_kinds(self) -> tuple:
+    """Every layer keeps one latent leaf."""
+    return (LATENT,) * self.num_layers
+
+
+def rotary(x, positions, theta: float):
+  """Rotate-half rotary embedding over ALL of ``x``'s last axis: ``x``
+  ``[B, S, H, d]``, ``positions`` int ``[B, S]``; pair ``i`` is ``(x[i],
+  x[i + d/2])`` turned by ``position * theta^(-2i/d)``.  float32 inside."""
+  d = x.shape[-1]
+  freq = jnp.exp(jnp.arange(d // 2, dtype=jnp.float32)
+                 * (-2.0 * jnp.log(theta) / d))
+  ang = positions.astype(jnp.float32)[:, :, None, None] * freq
+  cos, sin = jnp.cos(ang), jnp.sin(ang)
+  x32 = x.astype(jnp.float32)
+  a, b = x32[..., :d // 2], x32[..., d // 2:]
+  return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                         -1).astype(x.dtype)
+
+
+class LatentAttention(nn.Module):
+  cfg: GlmMoeConfig
+  decode: bool = False
+  kv_write_impl: Optional[str] = None
+  slot_attn_impl: Optional[str] = None
+
+  @nn.compact
+  def __call__(self, h, positions, slot_cursors=None, num_valid=None):
+    cfg = self.cfg
+    B, S, _ = h.shape
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
+    c_q = norm("q_norm")(_dense(cfg, cfg.q_lora_rank, "q_a")(h))
+    q = _dense(cfg, H * (dn + dr), "q_b")(c_q).reshape(B, S, H, dn + dr)
+    q_nope = q[..., :dn]
+    q_rope = rotary(q[..., dn:], positions, cfg.rope_theta)
+    kv = _dense(cfg, r + dr, "kv_a")(h)
+    c = norm("kv_norm")(kv[..., :r])
+    k_r = rotary(kv[..., None, r:], positions, cfg.rope_theta)  # [B,S,1,dr]
+    w_kvb = jnp.asarray(self.param(
+        "kv_b", _boxed(nn.initializers.normal(stddev=0.02), 2),
+        (r, H * (dn + dv)), cfg.param_dtype), cfg.dtype).reshape(
+            r, H, dn + dv)
+    scale = float(cfg.qk_head_dim) ** -0.5
+    if self.decode:
+      from easyparallellibrary_tpu.kernels.kv_write import kv_write
+      from easyparallellibrary_tpu.kernels.slot_attention import (
+          slot_attention)
+      latent = self.variable("cache", "cached_latent", _missing_slot_cache)
+      rows = jnp.concatenate([c[:, :, None], k_r], -1)       # [B,S,1,r+dr]
+      latent.value, _ = kv_write(latent.value, None, rows, None,
+                                 slot_cursors, impl=self.kv_write_impl)
+      q_abs = jnp.concatenate(
+          [jnp.einsum("bshd,rhd->bshr", q_nope, w_kvb[..., :dn]), q_rope],
+          -1)                                                # [B,S,H,r+dr]
+      o_lat = slot_attention(q_abs, latent.value, None, slot_cursors,
+                             num_valid, impl=self.slot_attn_impl,
+                             v_width=r, scale=scale).astype(cfg.dtype)
+      out = jnp.einsum("bshr,rhd->bshd", o_lat, w_kvb[..., dn:])
+    else:
+      kv_full = jnp.einsum("bsr,rhd->bshd", c, w_kvb)
+      k = jnp.concatenate(
+          [kv_full[..., :dn], jnp.broadcast_to(k_r, (B, S, H, dr))], -1)
+      qf = jnp.concatenate([q_nope, q_rope], -1)
+      logits = jnp.einsum("bqhd,bkhd->bhqk", qf, k) * jnp.asarray(
+          scale, cfg.dtype)
+      causal = jnp.tril(jnp.ones((S, S), jnp.bool_))
+      logits = jnp.where(causal, logits, jnp.asarray(-1e9, logits.dtype))
+      probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+      out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(cfg.dtype),
+                       kv_full[..., dn:])
+    return _dense(cfg, cfg.d_model, "o")(out.reshape(B, S, H * dv))
+
+
+class GlmMoeBlock(nn.Module):
+  cfg: GlmMoeConfig
+  dense: bool
+  decode: bool = False
+  kv_write_impl: Optional[str] = None
+  slot_attn_impl: Optional[str] = None
+  moe_gmm_impl: Optional[str] = None
+
+  @nn.compact
+  def __call__(self, x, positions, slot_cursors=None, num_valid=None):
+    cfg = self.cfg
+    norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
+    x = x + LatentAttention(
+        cfg, decode=self.decode, kv_write_impl=self.kv_write_impl,
+        slot_attn_impl=self.slot_attn_impl, name="latent")(
+            norm("norm_in")(x), positions, slot_cursors, num_valid)
+    h = norm("norm_ff")(x)
+    if self.dense:
+      return x + GatedMLP(cfg, name="mlp")(h)
+    # Only live positions are routed: a chunk's tail beyond ``num_valid``
+    # and an idle slot's rows reach no expert.
+    live = None if num_valid is None else (
+        jnp.arange(x.shape[1])[None] < num_valid[:, None])
+    return x + DroplessMoE(cfg, moe_gmm_impl=self.moe_gmm_impl,
+                           name="moe")(h, live)
+
+
+class GlmMoe(nn.Module):
+  """Decoder-only LM.  ``__call__(ids) -> logits`` is the full forward
+  (expanded attention); ``decode=True`` with ``slot_cursors`` is the
+  serving engine's slot mode (module docstring): token ``i`` of slot ``b``
+  sits at position ``slot_cursors[b] + i``, ``num_valid`` int32
+  ``[slots]`` says how many of the chunk's positions each slot feeds
+  (``None``: all) — what the attend reads and what the experts are
+  handed."""
+
+  cfg: GlmMoeConfig
+
+  @nn.compact
+  def __call__(self, ids, decode: bool = False, return_hidden: bool = False,
+               slot_cursors=None, num_valid=None, kv_write_impl=None,
+               slot_attn_impl=None, moe_gmm_impl=None):
+    cfg = self.cfg
+    if decode and slot_cursors is None:
+      raise ValueError(
+          "GlmMoe decodes in slot mode only: pass slot_cursors= and a slot "
+          "cache from serving.kv_cache.allocate_kv_cache (the serving "
+          "engine does)")
+    if slot_cursors is not None and not decode:
+      raise ValueError("slot_cursors is a decode-mode argument (serving "
+                       "engine); pass decode=True")
+    B, S = ids.shape
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    if slot_cursors is not None:
+      positions = positions + slot_cursors.astype(jnp.int32)[:, None]
+    x = Embedding(cfg.vocab_size, cfg.d_model, parallel="none",
+                  param_dtype=cfg.param_dtype, name="embed")(ids).astype(
+                      cfg.dtype)
+    for i in range(cfg.num_layers):
+      x = GlmMoeBlock(cfg, dense=i < cfg.first_k_dense, decode=decode,
+                      kv_write_impl=kv_write_impl,
+                      slot_attn_impl=slot_attn_impl,
+                      moe_gmm_impl=moe_gmm_impl, name=f"block_{i}")(
+                          x, positions, slot_cursors, num_valid)
+    x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm_f")(x)
+    if return_hidden:
+      return x
+    return _dense(cfg, cfg.vocab_size, "lm_head")(x)

@@ -269,7 +269,7 @@ def paged_step_logits(model, params, kv, tokens, slot_ids, positions,
 
 def slot_step_logits(model, params, kv, tokens, cursors,
                      kv_write_impl=None, slot_attn_impl=None,
-                     num_valid=None, **state_args):
+                     num_valid=None, stats: bool = False, **state_args):
   """Multi-token scoring on the shared slot-cache core — THE device entry
   every serving component steps through.
 
@@ -294,19 +294,26 @@ def slot_step_logits(model, params, kv, tokens, cursors,
   every slot is real) says how many of the chunk's positions each slot
   feeds: the attend reads no cache row at or beyond ``cursors +
   num_valid`` and none at all of an idle slot (``num_valid == 0``), and
-  a recurrence advances by exactly that many.  ``state_args`` go to a
-  model whose layers keep recurrent state beside K/V (models/jamba.py:
-  ``reset``, ``ssm_scan_impl``); a GPT takes none.
+  a recurrence advances by exactly that many, and a dropless expert
+  layer routes exactly those positions.  A model with positional
+  arithmetic of its own (models/glm_moe.py: rotary) takes token ``i``'s
+  position from the same ``cursors[b] + i``.  ``state_args`` go to a
+  model that asks for more (models/jamba.py: ``reset``,
+  ``ssm_scan_impl``; models/glm_moe.py: ``moe_gmm_impl``); a GPT takes
+  none.  ``stats`` also returns what the model sowed into its ``stats``
+  collection (an expert layer's load).
 
-  Returns ``(logits [num_slots, C, vocab], new_kv)``; the caller owns
-  cursor advancement (and, for speculation, rollback to the last
-  accepted position).
+  Returns ``(logits [num_slots, C, vocab], new_kv)`` and, with
+  ``stats``, the sown tree; the caller owns cursor advancement (and, for
+  speculation, rollback to the last accepted position).
   """
   logits, mut = model.apply(
       {"params": params, "cache": kv}, tokens, decode=True,
       slot_cursors=cursors, num_valid=num_valid,
       kv_write_impl=kv_write_impl, slot_attn_impl=slot_attn_impl,
-      mutable=["cache"], **state_args)
+      mutable=["cache", "stats"] if stats else ["cache"], **state_args)
+  if stats:
+    return logits, mut["cache"], mut.get("stats", {})
   return logits, mut["cache"]
 
 

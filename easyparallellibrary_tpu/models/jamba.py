@@ -109,7 +109,7 @@ class RMSNorm(nn.Module):
     return y.astype(self.dtype)
 
 
-def _dense(cfg: JambaConfig, features: int, name: str):
+def _dense(cfg, features: int, name: str):
   return Dense(features, use_bias=False, parallel="none", dtype=cfg.dtype,
                param_dtype=cfg.param_dtype,
                kernel_init=nn.initializers.normal(stddev=0.02), name=name)
@@ -258,13 +258,18 @@ class MambaMixer(nn.Module):
 
 
 class GatedMLP(nn.Module):
-  cfg: JambaConfig
+  """``down(silu(gate(h)) * up(h))``; ``cfg`` gives ``d_model`` and the
+  dtypes, ``d_ff`` the width where a model has more than one
+  (models/glm_moe.py: its dense layer and its shared expert)."""
+  cfg: Any
+  d_ff: Optional[int] = None
 
   @nn.compact
   def __call__(self, h):
     cfg = self.cfg
-    gate = _dense(cfg, cfg.d_ff, "gate")(h)
-    up = _dense(cfg, cfg.d_ff, "up")(h)
+    d_ff = self.d_ff or cfg.d_ff
+    gate = _dense(cfg, d_ff, "gate")(h)
+    up = _dense(cfg, d_ff, "up")(h)
     return _dense(cfg, cfg.d_model, "down")(jax.nn.silu(gate) * up)
 
 

@@ -18,12 +18,25 @@ out as a hack.  Here the layer contract is explicit:
   * overflow tokens beyond capacity are dropped (standard Switch
     semantics); a load-balancing auxiliary loss is sown into the
     ``losses`` collection.
+
+That is :class:`MoEMLP`, the TRAINING layer.  The SERVING layer is
+:class:`DroplessMoE` at the end of this module (models/glm_moe.py uses
+it): no capacity and no dropped token.  A step's positions are flattened,
+each live one is assigned its ``top_k`` experts (:func:`noaux_tc_route`:
+sigmoid scores, a selection bias that enters the choice and not the
+weights), the assignments are sorted by expert (:func:`sort_by_expert`),
+and two grouped matmuls a layer (kernels/moe_gmm.py: gate and up as one,
+then down) multiply each expert's rows by its own matrices; the results
+are weighted and gathered back.  Every shape is fixed by ``positions x
+top_k``, so the fused step compiles once; a dead position (beyond a
+slot's ``num_valid``, an idle slot) is assigned to NO expert: its rows
+sort behind the last group and are not multiplied.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -327,3 +340,120 @@ class MoEMLP(nn.Module):
              init_fn=lambda: jnp.float32(0),
              reduce_fn=lambda a, b: a + b)
     return out.reshape(B, S, D)
+
+
+# ------------------------------------------------------ dropless serving --
+
+
+def noaux_tc_route(x, router_kernel, bias, top_k: int, scale: float,
+                   norm: bool = True):
+  """The ``noaux_tc`` router with one group (DeepSeek-V3's, as GLM-4.7
+  configures it: ``n_group`` 1, ``topk_group`` 1): ``s = sigmoid(x W_g)``
+  in float32 whatever ``x``'s dtype; the ``top_k`` largest of ``s + bias``
+  are CHOSEN (the bias steers the choice only); their weights are the
+  unbiased ``s``, normalised over the chosen (``norm``) and times
+  ``scale``.  ``x`` ``[N, D]`` -> ``(chosen int32 [N, top_k], weights
+  float32 [N, top_k])``."""
+  scores = jax.nn.sigmoid(jnp.matmul(
+      x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+      precision=jax.lax.Precision.HIGHEST))
+  _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+  weights = jnp.take_along_axis(scores, chosen, axis=-1)
+  if norm:
+    weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+  return chosen.astype(jnp.int32), weights * scale
+
+
+def sort_by_expert(chosen, live, num_experts: int):
+  """Sort a step's ``N x top_k`` assignments by expert.  ``chosen`` int32
+  ``[N, top_k]``, ``live`` bool ``[N]`` (``None``: every position).  A
+  dead position's assignments go to expert ``num_experts``, which does
+  not exist: they sort behind the last group and count in no group size.
+  Returns ``(order, group_sizes)``: ``order`` int32 ``[N * top_k]``, the
+  flat assignment (position ``// top_k``, choice ``% top_k``) at each
+  sorted row, stable; ``group_sizes`` int32 ``[num_experts]``, summing to
+  (live positions) x ``top_k``."""
+  flat = chosen.reshape(-1)
+  if live is not None:
+    flat = jnp.where(jnp.repeat(live, chosen.shape[1]), flat, num_experts)
+  order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+  # Rows up to and including each expert's: one fused compare-and-count
+  # (a binary search would be a serial loop of scalar steps on a TPU).
+  ends = jnp.sum(flat[None, :] <= jnp.arange(num_experts)[:, None], axis=1,
+                 dtype=jnp.int32)
+  return order, jnp.diff(ends, prepend=0)
+
+
+def dropless_experts(x, chosen, weights, live, w_gate_up, w_down,
+                     impl: Optional[str] = None):
+  """``sum_i weights[n, i] * Expert_{chosen[n, i]}(x[n])`` for the live
+  positions of ``x`` ``[N, D]``, each expert a SiLU-gated MLP:
+  ``w_gate_up`` ``[E, D, 2 F]`` (gate columns, then up), ``w_down`` ``[E,
+  F, D]``.  Returns ``(y [N, D]`` in ``x``'s dtype, zeros at dead
+  positions, ``group_sizes [E])``.  ``impl`` names the grouped matmul's
+  lowering (kernels/moe_gmm.py; ``None`` resolves it from the shapes)."""
+  from easyparallellibrary_tpu.kernels.moe_gmm import moe_gmm
+  N, k = chosen.shape
+  E, _, F2 = w_gate_up.shape
+  order, sizes = sort_by_expert(chosen, live, E)
+  rows = x[order // k]                                    # [N k, D]
+  h = moe_gmm(rows, w_gate_up, sizes, impl=impl)
+  h = jax.nn.silu(h[:, :F2 // 2]) * h[:, F2 // 2:]
+  out = moe_gmm(h, w_down, sizes, impl=impl)              # [N k, D]
+  # Back to position order: the inverse of a permutation is its argsort
+  # (a second small sort; a scatter is a serial loop on a TPU), then a
+  # gather of the rows.
+  out = out[jnp.argsort(order)].reshape(N, k, -1).astype(jnp.float32)
+  y = jnp.sum(out * weights[..., None], axis=1)
+  if live is not None:
+    y = jnp.where(live[:, None], y, 0.0)
+  return y.astype(x.dtype), sizes
+
+
+class DroplessMoE(nn.Module):
+  """Routed experts without capacity beside shared ones: ``Shared(x) +
+  sum_i w_i Expert_i(x)`` (module docstring).  ``cfg`` gives ``d_model``,
+  ``n_routed_experts``, ``num_experts_per_tok``, ``moe_d_ff``,
+  ``n_shared_experts``, ``routed_scaling_factor``, ``norm_topk_prob`` and
+  the dtypes.  ``live`` bool ``[..]`` over ``x``'s leading axes says which
+  positions are routed (``None``: all); the shared expert runs for every
+  position (fixed shapes), and what it gives a dead one nothing reads.
+
+  Sows ``expert_load`` into the ``stats`` collection: the busiest
+  expert's assignments over the mean (1.0 = even; 0 when nothing is
+  live)."""
+
+  cfg: Any
+  moe_gmm_impl: Optional[str] = None
+
+  @nn.compact
+  def __call__(self, x, live=None):
+    from easyparallellibrary_tpu.models.jamba import (
+        GatedMLP, _boxed as boxed)
+    cfg = self.cfg
+    E, k, F, D = (cfg.n_routed_experts, cfg.num_experts_per_tok,
+                  cfg.moe_d_ff, cfg.d_model)
+    normal = nn.initializers.normal(stddev=0.02)
+    router = self.param("router_kernel", boxed(normal, 2), (D, E),
+                        jnp.float32)
+    bias = self.param("e_score_correction_bias",
+                      boxed(nn.initializers.zeros_init(), 1), (E,),
+                      jnp.float32)
+    w_gate_up = self.param("experts_gate_up", boxed(normal, 3),
+                           (E, D, 2 * F), cfg.param_dtype)
+    w_down = self.param("experts_down", boxed(normal, 3), (E, F, D),
+                        cfg.param_dtype)
+    flat = x.reshape(-1, D)
+    flat_live = None if live is None else live.reshape(-1)
+    chosen, weights = noaux_tc_route(
+        flat, router, bias, k, cfg.routed_scaling_factor,
+        cfg.norm_topk_prob)
+    y, sizes = dropless_experts(
+        flat, chosen, weights, flat_live, jnp.asarray(w_gate_up, cfg.dtype),
+        jnp.asarray(w_down, cfg.dtype), impl=self.moe_gmm_impl)
+    total = jnp.sum(sizes).astype(jnp.float32)
+    self.sow("stats", "expert_load",
+             jnp.max(sizes).astype(jnp.float32) * E
+             / jnp.maximum(total, 1.0))
+    shared = GatedMLP(cfg, d_ff=cfg.n_shared_experts * F, name="shared")(x)
+    return shared + y.reshape(x.shape)

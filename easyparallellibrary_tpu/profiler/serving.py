@@ -151,6 +151,12 @@ class ServingStats:
     # the cache holds, summed over the steps: their ratio is the share
     # of the cache a bounded attend reads (kernels/slot_attention.py).
     self.live_kv_rows = 0
+    # Dropless expert layers (0 on a model without them): live positions
+    # routed, and the sum over the steps that routed of the busiest
+    # expert's load over the mean.
+    self.routed_positions = 0
+    self.expert_steps = 0
+    self.expert_load_sum = 0.0
     self.kv_rows = 0
     self.busy_time_s = 0.0
     self.prefill_tokens = 0
@@ -312,8 +318,13 @@ class ServingStats:
                 prefill_tokens: int, decode_tokens: int,
                 step_time_s: float, drafted_tokens: int = 0,
                 accepted_tokens: int = 0, sampled_slots: int = 0,
-                live_kv_rows: int = 0, kv_rows: int = 0):
+                live_kv_rows: int = 0, kv_rows: int = 0,
+                routed_positions: int = 0, expert_load_max: float = 0.0):
     self.steps += 1
+    if routed_positions > 0:
+      self.routed_positions += int(routed_positions)
+      self.expert_steps += 1
+      self.expert_load_sum += float(expert_load_max)
     self.live_kv_rows += int(live_kv_rows)
     self.kv_rows += int(kv_rows)
     if sampled_slots > 0:
@@ -376,6 +387,7 @@ class ServingStats:
 
   _STATE_SCALARS = (
       "steps", "sampling_steps", "live_kv_rows", "kv_rows",
+      "routed_positions", "expert_steps", "expert_load_sum",
       "busy_time_s", "prefill_tokens",
       "decode_tokens", "finished_requests", "generated_tokens",
       "drafted_tokens", "accepted_tokens", "shed_requests", "requeues",
@@ -446,6 +458,15 @@ class ServingStats:
         # the steps: what an attend bounded per slot reads of it.
         "kv_read_share": (self.live_kv_rows / self.kv_rows
                           if self.kv_rows else 0.0),
+        # Dropless expert layers (0.0 without them): live positions a
+        # step routed, and the busiest expert's load over the mean
+        # (worst layer), averaged over the steps that routed.
+        "routed_positions_per_step": (
+            self.routed_positions / self.expert_steps
+            if self.expert_steps else 0.0),
+        "expert_load_max_mean": (
+            self.expert_load_sum / self.expert_steps
+            if self.expert_steps else 0.0),
         # Speculation (all 0.0 on a non-speculative engine): drafted vs
         # accepted totals, overall acceptance rate, and accepted-per-
         # step percentiles over the steps that drafted.
@@ -543,6 +564,12 @@ def fleet_summary(replica_stats: List["ServingStats"],
       "kv_read_share": (
           sum(s.live_kv_rows for s in stats)
           / max(sum(s.kv_rows for s in stats), 1)),
+      "routed_positions_per_step": (
+          sum(s.routed_positions for s in stats)
+          / max(sum(s.expert_steps for s in stats), 1)),
+      "expert_load_max_mean": (
+          sum(s.expert_load_sum for s in stats)
+          / max(sum(s.expert_steps for s in stats), 1)),
       "drafted_tokens": float(drafted),
       "accepted_tokens": float(accepted),
       "acceptance_rate": (accepted / drafted) if drafted else 0.0,

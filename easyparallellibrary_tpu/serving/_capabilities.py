@@ -17,7 +17,9 @@ ROADMAP_PP_SERVING = (
     "pipeline-parallel serving is ROADMAP item R9 ('Multi-chip serving'; "
     "docs/serving.md 'Current limits')")
 ROADMAP_MOE_SERVING = (
-    "MoE serving (expert decode inside the fused step) is ROADMAP item R4 "
+    "the GPT block's capacity-bounded experts (models/moe.py MoEMLP) are "
+    "a training layer: dropless expert decode inside the fused step is "
+    "models/glm_moe.py's; what is still missing is ROADMAP item R4 "
     "('Sparse experts that serve'; docs/serving.md 'Current limits')")
 ROADMAP_DRAFT_DISTILL = (
     "a drafter trained on the target's tokenizer is not queued; ROADMAP "
@@ -29,6 +31,13 @@ ROADMAP_RECURRENT_STATE = (
     "guarded retry) are ROADMAP item R7 ('Recurrent state beside KV'; "
     "docs/serving.md 'Current limits'); serve it through the contiguous "
     "cache with those features off")
+ROADMAP_LATENT_CACHE = (
+    "the latent cache of multi-head latent attention is one leaf a layer "
+    "in the contiguous slot cache only: a paged pool of latent rows, their "
+    "prefix cache, and the rollback speculation and the guarded retry need "
+    "are ROADMAP item R5 ('A latent cache'; docs/serving.md 'Current "
+    "limits'); serve it through the contiguous cache with those features "
+    "off")
 ROADMAP_PREEMPTION = (
     "priority reorders ADMISSION, and on the paged engine "
     "(serving.paged.enabled) a RUNNING throughput-class slot is "
@@ -82,11 +91,15 @@ def check_request_fields(req) -> None:
 def check_servable(cfg, role: str = "the serving engine") -> None:
   """Reject model configs the serving stack cannot run.
 
-  ``cfg`` is a :class:`models.gpt.GPTConfig` or a
-  :class:`models.jamba.JambaConfig` (a config without ``pipeline_stages``
-  / ``num_experts`` has neither); ``role`` names the component doing the
-  rejecting so a draft-model failure reads differently from a
-  target-model one.
+  ``cfg`` is a :class:`models.gpt.GPTConfig`, a
+  :class:`models.jamba.JambaConfig` or a
+  :class:`models.glm_moe.GlmMoeConfig` (a config without
+  ``pipeline_stages`` / ``num_experts`` has neither); ``role`` names the
+  component doing the rejecting so a draft-model failure reads
+  differently from a target-model one.  Refused are the GPT block's
+  experts (``num_experts``: MoEMLP drops what overflows a capacity and
+  has no slot mode); the dropless experts of models/glm_moe.py
+  (``n_routed_experts``) are served.
   """
   stages = getattr(cfg, "pipeline_stages", 1)
   if stages > 1:
@@ -119,6 +132,21 @@ def check_recurrent_state(cfg, feature: str) -> None:
         f"layers ({type(cfg).__name__}) — {ROADMAP_RECURRENT_STATE}")
 
 
+def check_latent_cache(cfg, feature: str) -> None:
+  """Reject ``feature`` (the paged cache, prefix caching, speculative
+  decoding, the guarded retry, a draft model) for a model whose layers
+  keep a latent leaf in place of a K/V pair (``cfg.layer_kinds()``,
+  models/glm_moe.py): each of them is built for ``cached_key`` /
+  ``cached_value`` pairs (the block pool, its radix tree, the rows a
+  rejected draft or a retried step leaves behind).  ONE message for
+  every such composition."""
+  from easyparallellibrary_tpu.serving.kv_cache import has_latent_cache
+  if has_latent_cache(cfg):
+    raise ValueError(
+        f"{feature} is not available for a model with a latent cache "
+        f"({type(cfg).__name__}) — {ROADMAP_LATENT_CACHE}")
+
+
 def check_draft_compatible(target_cfg, draft_cfg) -> None:
   """Reject draft models whose shapes cannot verify against the target.
 
@@ -131,6 +159,7 @@ def check_draft_compatible(target_cfg, draft_cfg) -> None:
   check_servable(draft_cfg, role="a draft model")
   check_recurrent_state(draft_cfg, "a draft model (its rejected drafts "
                         "roll back)")
+  check_latent_cache(draft_cfg, "a draft model")
   if draft_cfg.vocab_size != target_cfg.vocab_size:
     raise ValueError(
         f"draft model vocab_size {draft_cfg.vocab_size} != target "

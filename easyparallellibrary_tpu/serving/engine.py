@@ -75,7 +75,8 @@ from easyparallellibrary_tpu.observability.registry import (
     SERVING_NAMESPACE, MetricRegistry)
 from easyparallellibrary_tpu.serving import kv_cache as kv_lib
 from easyparallellibrary_tpu.serving._capabilities import (
-    check_draft_fits_chunk, check_recurrent_state, check_servable)
+    check_draft_fits_chunk, check_latent_cache, check_recurrent_state,
+    check_servable)
 from easyparallellibrary_tpu.serving.resilience import (
     AdmissionController, BadStepPolicy, DEGRADE_LEVELS)
 from easyparallellibrary_tpu.serving.scheduler import (
@@ -202,6 +203,19 @@ def _resolve_mesh(mesh):
   return None
 
 
+def _weak_method(obj, name: str):
+  """``getattr(obj, name)`` as a callable that does not keep ``obj``
+  alive; a call after ``obj`` is gone returns ``None``."""
+  ref = weakref.ref(obj)
+
+  def call(*args, **kwargs):
+    target = ref()
+    if target is not None:
+      return getattr(target, name)(*args, **kwargs)
+    return None
+  return call
+
+
 class ContinuousBatchingEngine:
   """Slot-based continuous-batching decode engine for a (non-pipelined)
   GPT.
@@ -296,6 +310,7 @@ class ContinuousBatchingEngine:
     self.paged = paged if paged is not None else pconf.enabled
     if self.paged:
       check_recurrent_state(cfg, "the paged cache (serving.paged)")
+      check_latent_cache(cfg, "the paged cache (serving.paged)")
     eff_batch = max_batch if max_batch is not None else conf.max_batch
     if self.paged:
       self.block_size = (block_size if block_size is not None
@@ -355,11 +370,24 @@ class ContinuousBatchingEngine:
         cfg, self.num_slots, self.chunk, self.mesh)
     self.cache_layout = None
     if self._recurrent:
-      self.cache_layout = kv_lib.cache_layout(cfg, self.num_slots,
-                                              self.chunk)
       trace_lib.get_tracer().metadata(
           f"{self._track_prefix}/ssm_scan_impl",
           {"impl": self.ssm_scan_impl})
+    # Dropless experts inside the fused step (models/glm_moe.py): the
+    # grouped matmul's lowering (kernels/moe_gmm.py), resolved once by its
+    # rule; None for a model without routed experts.
+    self.moe_gmm_impl = kv_lib.moe_gmm_impl(
+        cfg, self.num_slots, self.chunk, self.mesh)
+    self._experts = self.moe_gmm_impl is not None
+    if self._experts:
+      trace_lib.get_tracer().metadata(
+          f"{self._track_prefix}/moe_gmm_impl",
+          {"impl": self.moe_gmm_impl})
+    if self._recurrent or kv_lib.has_latent_cache(cfg):
+      # What the cache holds of each kind of state: K/V, recurrent
+      # state, latent rows.
+      self.cache_layout = kv_lib.cache_layout(cfg, self.num_slots,
+                                              self.chunk)
       trace_lib.get_tracer().metadata(
           f"{self._track_prefix}/cache_layout", dict(self.cache_layout))
     # Copy-on-write prefix caching (serving.prefix_cache.*;
@@ -370,12 +398,14 @@ class ContinuousBatchingEngine:
                            else pc_conf.enabled)
     if self.prefix_caching:
       check_recurrent_state(cfg, "prefix caching (serving.prefix_cache)")
+      check_latent_cache(cfg, "prefix caching (serving.prefix_cache)")
     self.drafter = self._resolve_drafter(conf, drafter, speculative,
                                          draft_model, draft_params)
     if self.drafter is not None:
       check_recurrent_state(
           cfg, "speculative decoding (serving.speculative: rejected "
           "drafts roll back)")
+      check_latent_cache(cfg, "speculative decoding (serving.speculative)")
     self.scheduler = FCFSScheduler(
         num_slots=self.num_slots, prefill_chunk=self.chunk,
         max_seq_len=cfg.max_seq_len, prefill_token_budget=budget,
@@ -397,6 +427,7 @@ class ContinuousBatchingEngine:
       check_recurrent_state(
           cfg, "the guarded step (serving.resilience: a retried step "
           "needs the state it started from)")
+      check_latent_cache(cfg, "the guarded step (serving.resilience)")
     self.stats = stats
     if self._resilient and self.stats is None:
       # The degradation ladder reads measured ITL from ServingStats;
@@ -413,7 +444,12 @@ class ContinuousBatchingEngine:
     # memory linearly with requests served).
     self.finished: Dict[Any, FinishedRequest] = {}
     self._finished_limit = conf.finished_limit
-    self.scheduler.on_finish.append(self._record_finished)
+    # Hooks the engine hands its own parts call back WEAKLY: a bound
+    # method would close a cycle (engine -> scheduler -> hook -> engine),
+    # and an engine in a cycle keeps its cache on the device until the
+    # collector happens to run, not until its owner lets go of it — which
+    # is too late for an owner about to fill the chip with something else.
+    self.scheduler.on_finish.append(_weak_method(self, "_record_finished"))
     if self.stats is not None:
       stats_obj = self.stats
       self.scheduler.on_admit.append(stats_obj.note_admitted)
@@ -537,8 +573,8 @@ class ContinuousBatchingEngine:
     # transparent.
     self._compile_sentinel = slo_lib.CompileSentinel(
         self._twin_label,
-        lambda: self._step_fn._cache_size(),
-        on_recompile=[self._note_recompile])
+        _weak_method(self, "_step_cache_size"),
+        on_recompile=[_weak_method(self, "_note_recompile")])
     if self._slo is not None:
       # The monitor consumes this engine's registry records (it IS a
       # registry sink) and merges this engine's scheduler/allocator
@@ -574,6 +610,10 @@ class ContinuousBatchingEngine:
                    f"{lay['kv_bytes'] / 1e6:.1f} MB + {lay['state_leaves']} "
                    f"recurrent-state leaves {lay['state_bytes'] / 1e6:.1f} "
                    f"MB, {self.ssm_scan_impl} ssm scan")
+      elif self.cache_layout is not None:
+        layout += (f": {self.cache_layout['latent_leaves']} latent leaves")
+      if self._experts:
+        layout += f", {self.moe_gmm_impl} expert matmul"
     get_logger().info(
         "serving engine: %d slots x chunk %d (%s, %s), "
         "prefill budget %s, max batch %d, speculation %s, resilience %s",
@@ -643,6 +683,9 @@ class ContinuousBatchingEngine:
         sig[name] = f"{v.dtype}{list(v.shape)}"
     return sig
 
+  def _step_cache_size(self) -> int:
+    return self._step_fn._cache_size()
+
   def _note_recompile(self, label: str, cache_size: int,
                       new_compiles: int, signature) -> None:
     """CompileSentinel subscriber: surface an unexpected fused-step
@@ -678,6 +721,7 @@ class ContinuousBatchingEngine:
         "kv_write_impl": self.kv_write_impl,
         "slot_attn_impl": self.slot_attn_impl,
         "ssm_scan_impl": self.ssm_scan_impl,
+        "moe_gmm_impl": self.moe_gmm_impl,
         "recompiles": self._compile_sentinel.recompiles,
         "active_uids": [str(s.req.uid)
                         for s in sched.active.values()][:32],
@@ -778,6 +822,8 @@ class ContinuousBatchingEngine:
     attn_impl = self.slot_attn_impl
     scan_impl = self.ssm_scan_impl
     recurrent = self._recurrent
+    gmm_impl = self.moe_gmm_impl
+    experts = self._experts
 
     def step(params, kv, cursors, tokens, num_valid, reset, keys,
              tok_index, temperature, top_k, top_p):
@@ -788,10 +834,12 @@ class ContinuousBatchingEngine:
       # state is masked by nothing).
       state_args = dict(reset=reset,
                         ssm_scan_impl=scan_impl) if recurrent else {}
-      logits, kv = slot_step_logits(model, params, kv, tokens, cursors,
-                                    kv_write_impl=write_impl,
-                                    slot_attn_impl=attn_impl,
-                                    num_valid=num_valid, **state_args)
+      if experts:
+        state_args["moe_gmm_impl"] = gmm_impl
+      logits, kv, *sown = slot_step_logits(
+          model, params, kv, tokens, cursors, kv_write_impl=write_impl,
+          slot_attn_impl=attn_impl, num_valid=num_valid, stats=experts,
+          **state_args)
       # Each slot's next-token logits sit at its LAST live chunk
       # position; idle slots (num_valid=0) read position 0 — garbage the
       # scheduler never consumes.
@@ -801,19 +849,23 @@ class ContinuousBatchingEngine:
       step_keys = jax.vmap(jax.random.fold_in)(keys, tok_index)
       nxt = sample_token_slots(last.astype(jnp.float32), step_keys,
                                temperature, top_k, top_p)
+      # An expert model also hands back ONE float: the busiest expert's
+      # load over the mean, worst layer (``serving/expert_load_max``).
+      nxt = (nxt, jnp.max(jnp.stack(jax.tree_util.tree_leaves(sown)))
+             ) if experts else (nxt,)
       if not guard:
-        return nxt, kv, cursors + num_valid
+        return *nxt, kv, cursors + num_valid
       # In-jit finiteness verdict on exactly the rows commit consumes
       # (the PR-2 sentinel pattern): a bad slot's cursor stays put, so
       # its K/V writes beyond the old cursor are unreachable garbage the
       # retry overwrites — device state never advances on a bad step.
       slot_ok = (jnp.all(jnp.isfinite(last), axis=-1)
                  | (num_valid == 0))
-      return nxt, slot_ok, kv, jnp.where(slot_ok, cursors + num_valid,
-                                         cursors)
+      return *nxt, slot_ok, kv, jnp.where(slot_ok, cursors + num_valid,
+                                          cursors)
 
     return self._jit_step(step, donate, n_rep_in=8,
-                          n_rep_out=2 if guard else 1)
+                          n_rep_out=1 + int(experts) + int(guard))
 
   def _build_spec_step(self, donate: bool, guard: bool = False):
     """The speculative twin of :meth:`_build_step`: the SAME single
@@ -1388,15 +1440,21 @@ class ContinuousBatchingEngine:
     drafted = accepted = 0
     slot_ok = None
     num_draft = None
+    expert_load = None
     try:
       if self.drafter is not None:
         # Propose BEFORE the token block gains drafts: the draft
         # model's mirror call needs the same plan the target sees.
         num_draft = self._propose_drafts(tracer, plan)
       t0_us = tracer.now_us()
+      # The plain step of an expert model returns its load beside the
+      # tokens (speculation is refused for the only such model).
+      with_load = self._experts and num_draft is None
       fetched, slot_ok, t1_us = self._dispatch_and_fetch(
           tracer, t0_us, self._step_args(plan, num_draft),
-          n_fetch=1 if num_draft is None else 2)
+          n_fetch=(1 if num_draft is None else 2) + int(with_load))
+      if with_load:
+        *fetched, expert_load = fetched
       # ``fetched`` is (next_tokens,) or, speculative, (committed,
       # n_committed): commit()'s own positional arguments.
       n_committed = fetched[1] if num_draft is not None else None
@@ -1473,6 +1531,8 @@ class ContinuousBatchingEngine:
     # own sum of cursor + num_valid): what an attend bounded per slot
     # reads of ``_kv_rows``, and all a roofline of it may count.
     live_kv_rows = plan.live_kv_rows
+    routed_positions = int(plan.num_valid.sum()) if self._experts else 0
+    expert_load_max = float(expert_load) if expert_load is not None else 0.0
     if tracer.enabled:
       tracer.counter("serving/active_slots", plan.active_slots)
       tracer.counter("serving/sampled_slots", sampled_slots)
@@ -1481,6 +1541,11 @@ class ContinuousBatchingEngine:
         # Slots whose recurrent state this step zeroed: requests that
         # started (or restarted, after a requeue) here.
         tracer.counter("serving/state_resets", int(plan.reset.sum()))
+      if self._experts:
+        # Live positions the step handed its expert layers (each goes to
+        # ``num_experts_per_tok`` experts), and how unevenly they fell.
+        tracer.counter("serving/routed_positions", routed_positions)
+        tracer.counter("serving/expert_load_max", expert_load_max)
       if self.paged:
         # Block-pool occupancy rides the counter tracks next to
         # active_slots, so Perfetto shows pool pressure against load.
@@ -1511,7 +1576,8 @@ class ContinuousBatchingEngine:
           decode_tokens=dc_tokens, step_time_s=dt,
           drafted_tokens=drafted, accepted_tokens=accepted,
           sampled_slots=sampled_slots, live_kv_rows=live_kv_rows,
-          kv_rows=self._kv_rows)
+          kv_rows=self._kv_rows, routed_positions=routed_positions,
+          expert_load_max=expert_load_max)
       if self.paged:
         self.stats.note_blocks(self.scheduler.kv_blocks_free,
                                self.scheduler.kv_blocks_used,
@@ -1535,6 +1601,9 @@ class ContinuousBatchingEngine:
           "decode_tokens": dc_tokens,
           "step_time_s": dt,
       }
+      if self._experts:
+        record["routed_positions"] = routed_positions
+        record["expert_load_max"] = expert_load_max
       if self.paged:
         # The block-pool gauges (ROADMAP item 1 satellite): pool
         # occupancy, internal fragmentation, and preemption count under

@@ -25,7 +25,11 @@ layer from the model's own layer kinds (:func:`cache_leaves`): K/V rows
 under the slot's cursor for attention layers, and for recurrent layers
 (models/jamba.py's Mamba mixer) the convolution's last inputs and the
 scan's float32 state, which have no position axis and which no cursor can
-roll back (serving/_capabilities.py ``check_recurrent_state``).
+roll back (serving/_capabilities.py ``check_recurrent_state``).  A third
+kind, the LATENT leaf of multi-head latent attention (models/glm_moe.py),
+is addressed under the cursor like K/V but is ONE tensor a layer, whose
+values are its keys' leading columns (``check_latent_cache`` refuses what
+only a K/V pair is built for).
 
 Placement: the cache is materialized directly into its sharded layout on
 the mesh (same jit-with-out-shardings trick as
@@ -54,6 +58,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from easyparallellibrary_tpu import constants
+from easyparallellibrary_tpu.models.glm_moe import LATENT
 from easyparallellibrary_tpu.models.jamba import ATTENTION, MAMBA
 
 # Pool index of the reserved null/trash block: block tables default-fill
@@ -89,9 +94,18 @@ def has_recurrent_state(cfg) -> bool:
   return MAMBA in layer_kinds(cfg)
 
 
+def has_latent_cache(cfg) -> bool:
+  """Whether some layer keeps one latent leaf in place of a K/V pair
+  (serving/_capabilities.py refuses what is built for pairs only)."""
+  return LATENT in layer_kinds(cfg)
+
+
 def kv_heads(cfg) -> Tuple[int, int]:
-  """``(H_kv, hd)`` of one K/V row: the model's K/V head count (its query
-  heads when it has no fewer) and the head size."""
+  """``(H_kv, hd)`` of one cache row under a cursor: the model's K/V head
+  count (its query heads when it has no fewer) and the head size; for a
+  latent leaf one head of ``kv_lora_rank + qk_rope_head_dim`` values."""
+  if has_latent_cache(cfg):
+    return 1, cfg.latent_dim
   if cfg.d_model % cfg.num_heads:
     raise ValueError(f"d_model {cfg.d_model} must divide into "
                      f"{cfg.num_heads} heads")
@@ -109,15 +123,22 @@ def cache_leaves(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
   * Mamba: ``{"mamba": {"conv_state": [num_slots, d_conv - 1, d_inner]``
     in the compute dtype (the convolution's last inputs, which are
     produced in it), ``"ssm_state": [num_slots, d_state, d_inner]``
-    float32}}``, no position axis: the whole state is the request's.
+    float32}}``, no position axis: the whole state is the request's;
+  * latent: ``{"latent": {"cached_latent": [num_slots, Lc, 1,
+    kv_lora_rank + qk_rope_head_dim]}}`` in the compute dtype, read under
+    the slot's cursor as keys and, its leading ``kv_lora_rank`` columns,
+    as values.
   """
-  H, hd = kv_heads(cfg)
-  kv = jax.ShapeDtypeStruct((num_slots, cache_length(cfg, chunk), H, hd),
-                            cfg.dtype)
+  kinds = layer_kinds(cfg)
+  if ATTENTION in kinds or LATENT in kinds:
+    kv = jax.ShapeDtypeStruct(
+        (num_slots, cache_length(cfg, chunk)) + kv_heads(cfg), cfg.dtype)
   out = {}
-  for i, kind in enumerate(layer_kinds(cfg)):
+  for i, kind in enumerate(kinds):
     if kind == ATTENTION:
       out[f"block_{i}"] = {"attn": {"cached_key": kv, "cached_value": kv}}
+    elif kind == LATENT:
+      out[f"block_{i}"] = {"latent": {"cached_latent": kv}}
     elif kind == MAMBA:
       out[f"block_{i}"] = {"mamba": {
           "conv_state": jax.ShapeDtypeStruct(
@@ -157,6 +178,13 @@ def kv_cache_shardings(cfg, mesh: Optional[Mesh]):
   return kv, rep
 
 
+def _has_rows(cfg) -> bool:
+  """Whether some layer keeps rows under a cursor (a K/V pair or a latent
+  leaf): what the window write and the attend work on."""
+  kinds = layer_kinds(cfg)
+  return ATTENTION in kinds or LATENT in kinds
+
+
 def kv_write_impl(cfg, num_slots: int, chunk: int,
                   mesh: Optional[Mesh] = None) -> Optional[str]:
   """The lowering of the fused step's window write into the cache
@@ -165,10 +193,11 @@ def kv_write_impl(cfg, num_slots: int, chunk: int,
   Pallas kernel on a TPU when the leaf sits whole on one chip and fits
   the kernel's tiles, ``vmap(dynamic_update_slice)`` everywhere else.
   Resolved once by whoever builds a step over the cache; ``None`` for a
-  model without an attention layer."""
+  model without an attention layer.  A latent leaf is written by the same
+  kernel in its one-leaf form."""
   from easyparallellibrary_tpu.kernels.kv_write import (
       resolve_kv_write_impl)
-  if ATTENTION not in layer_kinds(cfg):
+  if not _has_rows(cfg):
     return None
   shape = (num_slots, cache_length(cfg, chunk)) + kv_heads(cfg)
   return resolve_kv_write_impl(
@@ -186,7 +215,7 @@ def slot_attn_impl(cfg, num_slots: int, chunk: int,
   an attention layer."""
   from easyparallellibrary_tpu.kernels.slot_attention import (
       resolve_slot_attn_impl)
-  if ATTENTION not in layer_kinds(cfg):
+  if not _has_rows(cfg):
     return None
   shape = (num_slots, cache_length(cfg, chunk)) + kv_heads(cfg)
   return resolve_slot_attn_impl(
@@ -208,6 +237,27 @@ def ssm_scan_impl(cfg, num_slots: int, chunk: int,
   return resolve_ssm_scan_impl(
       (num_slots, cfg.mamba_d_state, cfg.d_inner), cfg.dtype, chunk,
       sharded=mesh is not None and mesh.size > 1)
+
+
+def moe_gmm_impl(cfg, num_slots: int, chunk: int,
+                 mesh: Optional[Mesh] = None) -> Optional[str]:
+  """The lowering of the fused step's grouped matmuls over a dropless
+  expert layer's sorted assignments (``num_slots x chunk x
+  num_experts_per_tok`` rows) — the dispatch rule of kernels/moe_gmm.py
+  applied to both of a layer's products (gate and up as one, then down),
+  resolved once like :func:`kv_write_impl`: the kernel only if it takes
+  both; ``None`` for a model without such a layer."""
+  E = getattr(cfg, "n_routed_experts", 0)
+  if not E:
+    return None
+  from easyparallellibrary_tpu.kernels.moe_gmm import resolve_moe_gmm_impl
+  rows = num_slots * chunk * cfg.num_experts_per_tok
+  D, F = cfg.d_model, cfg.moe_d_ff
+  sharded = mesh is not None and mesh.size > 1
+  impls = {resolve_moe_gmm_impl((rows, k), (E, k, n), cfg.dtype,
+                                sharded=sharded)
+           for k, n in ((D, 2 * F), (F, D))}
+  return impls.pop() if len(impls) == 1 else "reference"
 
 
 def allocate_kv_cache(cfg, num_slots: int, chunk: int,
@@ -247,14 +297,20 @@ def allocate_kv_cache(cfg, num_slots: int, chunk: int,
 
 def cache_layout(cfg, num_slots: int, chunk: int) -> Dict[str, int]:
   """What the slot cache holds, by kind of state: bytes and leaves of
-  K/V (under a cursor) and of recurrent state (no position axis).  The
-  engine records it (trace metadata ``serving/cache_layout``)."""
-  out = {"kv_bytes": 0, "kv_leaves": 0, "state_bytes": 0, "state_leaves": 0}
-  for leaf in jax.tree_util.tree_leaves(cache_leaves(cfg, num_slots, chunk)):
-    kind = "kv" if len(leaf.shape) == 4 else "state"
-    out[f"{kind}_bytes"] += (int(np.prod(leaf.shape))
-                             * jnp.dtype(leaf.dtype).itemsize)
-    out[f"{kind}_leaves"] += 1
+  K/V (under a cursor), of recurrent state (no position axis) and, for a
+  model that has them, of latent rows (under a cursor, one leaf a layer).
+  The engine records it (trace metadata ``serving/cache_layout``)."""
+  names = {ATTENTION: "kv", MAMBA: "state", LATENT: "latent"}
+  kinds = layer_kinds(cfg)
+  out = {f"{name}_{what}": 0
+         for name in ["kv", "state"] + ["latent"] * (LATENT in kinds)
+         for what in ("bytes", "leaves")}
+  leaves = cache_leaves(cfg, num_slots, chunk)
+  for i, kind in enumerate(kinds):
+    for leaf in jax.tree_util.tree_leaves(leaves[f"block_{i}"]):
+      out[f"{names[kind]}_bytes"] += (int(np.prod(leaf.shape))
+                                      * jnp.dtype(leaf.dtype).itemsize)
+      out[f"{names[kind]}_leaves"] += 1
   return out
 
 
@@ -263,7 +319,7 @@ def cache_bytes(cfg, num_slots: int, chunk: int) -> int:
   convolution and scan state) — the number the admission knobs trade
   against HBM."""
   layout = cache_layout(cfg, num_slots, chunk)
-  return layout["kv_bytes"] + layout["state_bytes"]
+  return sum(v for k, v in layout.items() if k.endswith("_bytes"))
 
 
 # ------------------------------------------------------------ paged cache --

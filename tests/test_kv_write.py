@@ -436,3 +436,158 @@ def test_sampling_tail_compiled_for_v5e_sorts_in_a_branch_alone(
       unconditional, conditional)
   always, nested = op_sites(text, "conditional")
   assert len(always) == 1 and len(nested) == 1, (always, nested)
+
+
+# ------------------------------------------------------- the one-leaf form
+# A layer whose cache is ONE tensor (models/glm_moe.py: the latent of
+# multi-head latent attention): ``cached_v = v = None``.
+
+LATENT = 576                   # kv_lora_rank 512 + qk_rope_head_dim 64
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("hd,C", [(LATENT, 8), (40, 8), (64, 1)],
+                         ids=["latent_576", "hd_40", "decode"])
+def test_one_leaf_is_written_bit_identical(hd, C, dtype):
+  """hd 576 (the latent row), a width that is no lane multiple, and a
+  one-token decode: the whole leaf equals ``dynamic_update_slice``'s and
+  the second place comes back ``None`` from both lowerings."""
+  if dtype == jnp.bfloat16 and hd % 16:
+    hd = 48                    # 16-bit rows fill whole sublane tiles
+  Lc = 264
+  cursors = jnp.asarray([0, 1, 120, 124, 127, 128, 250, Lc - C], jnp.int32)
+  ck, _, k, _ = _operands(len(cursors), Lc, 1, hd, C, dtype, seed=hd)
+  assert kvw.kv_write_fits(ck.shape, dtype, C)
+  got, none = kvw.kv_write(ck, None, k, None, cursors, impl="interpret")
+  want, none_too = kvw.kv_write(ck, None, k, None, cursors,
+                                impl="reference")
+  assert none is None and none_too is None
+  assert (_bits(got) == _bits(want)).all()
+
+
+def test_two_leaves_are_what_they_were_beside_the_one_leaf_form():
+  """The pair's results do not depend on the one-leaf generalisation:
+  K as one leaf equals K of the pair, bit for bit."""
+  cursors = jnp.asarray([3, 125, 200], jnp.int32)
+  ck, cv, k, v = _operands(3, 264, 2, 32, 8, jnp.float32, seed=9)
+  pair_k, pair_v = kvw.kv_write(ck, cv, k, v, cursors, impl="interpret")
+  one_k, _ = kvw.kv_write(ck, None, k, None, cursors, impl="interpret")
+  one_v, _ = kvw.kv_write(cv, None, v, None, cursors, impl="interpret")
+  assert (_bits(pair_k) == _bits(one_k)).all()
+  assert (_bits(pair_v) == _bits(one_v)).all()
+
+
+@pytest.mark.parametrize("backend,sharded,want", [
+    ("pallas", False, "pallas"), ("pallas", True, "reference"),
+    ("reference", False, "reference")],
+    ids=["tpu", "tpu_on_a_mesh", "cpu"])
+def test_rule_takes_the_latent_leaf_on_a_tpu(monkeypatch, backend, sharded,
+                                             want):
+  """The cell's leaf ``[96, 4104, 1, 576]``: the kernel on a TPU, the
+  reference on the CPU and on a mesh of chips."""
+  _backend_takes(monkeypatch, backend)
+  for dtype in (jnp.bfloat16, jnp.float32):
+    assert kvw.resolve_kv_write_impl((96, 4104, 1, LATENT), dtype, 8,
+                                     sharded=sharded) == want
+
+
+def test_one_leaf_write_compiles_for_v5e_with_no_copy_of_the_leaf(one_chip):
+  """The latent leaf at the cell's size, compiled for a described v5e: one
+  ``kv_write`` custom call whose ONE leaf operand aliases its output, and
+  no copy of the leaf."""
+  B, Lc, C = 96, 4104, 8
+  spec = lambda shape, d=jnp.bfloat16: jax.ShapeDtypeStruct(
+      shape, d, sharding=one_chip)
+  fn = lambda leaf, rows, cur: kvw.kv_write(leaf, None, rows, None, cur,
+                                            impl="pallas")[0]
+  text = _compiled_text(jax.jit(fn, donate_argnums=(0,)),
+                        spec((B, Lc, 1, LATENT)), spec((B, C, 1, LATENT)),
+                        spec((B,), jnp.int32))
+  entry = text[text.index("\nENTRY "):]
+  calls = [l for l in entry.splitlines() if " custom-call(" in l
+           and "kv_write" in l.split("=")[0]]
+  assert len(calls) == 1, entry
+  assert "output_to_operand_aliasing={{}: (2, {})}" in calls[0], calls[0]
+  assert " while(" not in text
+  for line in entry.splitlines():
+    m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) (\S+?)\(", line)
+    if m and (f"[{B},{Lc},1,{LATENT}]" in m.group(2)
+              or f"[{B},1,{LATENT},{Lc}]" in m.group(2)):
+      assert m.group(3) in ("parameter", "bitcast", "get-tuple-element",
+                            "custom-call"), line
+
+
+# ------------------------------------ the expert decoder, compiled for v5e
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("highest", [False, True],
+                         ids=["default_precision", "highest_precision"])
+def test_moe_gmm_compiles_for_v5e_at_the_cells_shapes(one_chip, highest,
+                                                      dtype):
+  """Both grouped matmuls of an expert layer, 3072 sorted assignments over
+  64 experts, compiled for a described v5e: ONE Mosaic custom call named
+  ``moe_gmm`` each, also when the caller's context says ``highest``
+  (which Mosaic refuses for bfloat16 operands unless the kernel names its
+  own: the trap PERF.md section 6 names)."""
+  gmm = importlib.import_module("easyparallellibrary_tpu.kernels.moe_gmm")
+  spec = lambda shape, d=dtype: jax.ShapeDtypeStruct(shape, d,
+                                                     sharding=one_chip)
+  for K, N in ((2048, 3072), (1536, 2048)):
+    fn = jax.jit(lambda a, b, s: gmm.moe_gmm_pallas.__wrapped__(a, b, s))
+    with jax.default_matmul_precision("highest" if highest else "default"):
+      text = _compiled_text(fn, spec((3072, K)), spec((64, K, N)),
+                            spec((64,), jnp.int32))
+    calls = [l for l in text.splitlines() if " custom-call(" in l
+             and 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == 1 and "moe_gmm" in calls[0].split("=")[0], calls
+
+
+def test_expert_step_compiled_for_v5e_holds_its_three_kernels(one_chip):
+  """The fused step of a two-layer cut (one dense, one expert layer) of
+  models/glm_moe.py at GLM-4.7-Flash's widths, 96 slots x chunk 8,
+  compiled for a described v5e as the engine builds it: one ``kv_write``
+  and one ``slot_attn`` a layer over ONE latent leaf, two ``moe_gmm`` an
+  expert layer, no copy of a ``[96, 4104, 1, 576]`` leaf in either order
+  of its dimensions, no ``[positions, 64, ..]`` dispatch tensor, no
+  ``while`` loop (a scatter or a binary search would be one)."""
+  import types
+  from flax import linen as nn
+  from easyparallellibrary_tpu.models.glm_moe import GlmMoe, GlmMoeConfig
+  epl.init()
+  slots, C = 96, 8
+  cfg = GlmMoeConfig(num_layers=2, vocab_size=32768)
+  model = GlmMoe(cfg)
+  on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+  params = jax.tree_util.tree_map(on_chip, nn.meta.unbox(jax.eval_shape(
+      lambda: model.init(jax.random.PRNGKey(0),
+                         jnp.zeros((1, 8), jnp.int32))["params"])))
+  kv = jax.tree_util.tree_map(on_chip, kv_lib.cache_leaves(cfg, slots, C))
+  engine = types.SimpleNamespace(
+      model=model, chunk=C, kv_write_impl="pallas", slot_attn_impl="pallas",
+      ssm_scan_impl=None, _recurrent=False, moe_gmm_impl="pallas",
+      _experts=True,
+      _jit_step=lambda step, donate, **kw: jax.jit(step,
+                                                   donate_argnums=(1, 2)))
+  step = ContinuousBatchingEngine._build_step(engine, True)
+  spec = lambda shape, d: jax.ShapeDtypeStruct(shape, d, sharding=one_chip)
+  i32, f32 = jnp.int32, jnp.float32
+  text = _compiled_text(
+      step, params, kv, spec((slots,), i32), spec((slots, C), i32),
+      spec((slots,), i32), spec((slots,), jnp.bool_),
+      spec((slots, 2), jnp.uint32), spec((slots,), i32),
+      spec((slots,), f32), spec((slots,), i32), spec((slots,), f32))
+  calls = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
+  assert (calls("kv_write"), calls("slot_attn"), calls("moe_gmm")) == (
+      2, 2, 2), text.count("tpu_custom_call")
+  assert " while(" not in text
+  for line in text.splitlines():
+    m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) (\S+?)\(", line)
+    if m and (m.group(2).startswith(f"bf16[{slots},4104,1,576]")
+              or m.group(2).startswith(f"bf16[{slots},1,576,4104]")):
+      assert m.group(3) in ("parameter", "bitcast", "get-tuple-element",
+                            "custom-call"), line
+  assert not re.search(rf"\[{slots * C},64,\d+", text)

@@ -345,3 +345,80 @@ def test_engine_on_a_mesh_of_chips_takes_the_reference(monkeypatch):
   mesh = epl.Env.get().cluster.build_mesh()
   assert kv_lib.slot_attn_impl(SERVE, 3, 8, mesh) == "reference"
   assert kv_lib.slot_attn_impl(SERVE, 3, 8, None) == "interpret"
+
+
+# ------------------------------------------------------- the one-leaf form
+# A layer whose cache is ONE tensor (models/glm_moe.py: absorbed multi-head
+# latent attention): ``cached_v=None``, the values are the keys' leading
+# ``v_width`` columns, and the scores' scale is the caller's.
+
+
+def _check_one_leaf(H, hd, vw, C, dtype, block=None, poison=True, Lc=LC):
+  cur = np.asarray([0, 77, 256 - C, 256, 256 - C // 2 - 1, Lc - C], np.int32)
+  nv = np.asarray([C, C, 1, max(C // 2, 1), 0, C], np.int32)
+  B = len(cur)
+  r = np.random.RandomState(hd + C)
+  q = jnp.asarray(r.standard_normal((B, C, H, hd)) / np.sqrt(hd), dtype)
+  leaf = r.standard_normal((B, Lc, 1, hd)).astype(np.float32)
+  scale = 0.37
+  want = sa.slot_attention_reference(
+      q, jnp.asarray(leaf, dtype), None, jnp.asarray(cur), v_width=vw,
+      scale=scale)
+  dirty = leaf.copy()
+  if poison:
+    for b in range(B):
+      dirty[b, cur[b] + nv[b] if nv[b] else 0:] = np.nan
+  got = sa.slot_attention_pallas(
+      q, jnp.asarray(dirty, dtype), None, jnp.asarray(cur), jnp.asarray(nv),
+      interpret=True, block=block, v_width=vw, scale=scale)
+  assert got.shape == (B, C, H, vw) == want.shape
+  got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+  assert np.isfinite(got).all()
+  real = (np.arange(C)[None] < nv[:, None])[:, :, None, None]
+  np.testing.assert_allclose(np.where(real, got, 0), np.where(real, want, 0),
+                             atol=TOL[dtype], rtol=TOL[dtype])
+  assert (np.where(real, 0, got) == 0).all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("H,hd,vw,C", [
+    (20, 576, 512, 8), (4, 48, 32, 8), (4, 64, 16, 1), (2, 64, 64, 16)],
+    ids=["latent_576_values_512", "values_32_of_48", "decode",
+         "values_all_of_the_keys"])
+def test_one_leaf_equals_the_reference_and_reads_no_nan(H, hd, vw, C, dtype):
+  """``v_width < hd`` (and ``== hd``), hd 576 with 20 heads as query rows
+  of one head, a one-token decode; NaN in every row at or beyond a bound
+  (it would reach the output through K or through the values taken from
+  it) stays unread; idle slots and dead positions are zeros."""
+  _check_one_leaf(H, hd, vw, C, dtype, block=256,
+                  Lc=520 if hd == 576 else LC)
+
+
+def test_one_leaf_with_the_rules_own_block():
+  """The block the rule picks for the latent leaf: 256 rows of 576
+  bfloat16 values (288 KiB, within the 512 KiB a K block may take)."""
+  assert sa.block_positions((96, 4104, 1, 576), jnp.bfloat16, 8, 20) == 256
+  _check_one_leaf(4, 64, 32, 8, jnp.bfloat16, block=None)
+
+
+def test_one_leaf_call_is_refused_half_said():
+  q, ck, _ = _operands(2, 8, 4, 1, 64, LC, jnp.float32)
+  cur = jnp.zeros((2,), jnp.int32)
+  with pytest.raises(ValueError, match="one-leaf attend"):
+    sa.slot_attention(q, jnp.asarray(ck), None, cur, impl="reference")
+  with pytest.raises(ValueError, match="one-leaf attend"):
+    sa.slot_attention(q, jnp.asarray(ck), jnp.asarray(ck), cur,
+                      impl="reference", v_width=32)
+
+
+@pytest.mark.parametrize("backend,sharded,want", [
+    ("pallas", False, "pallas"), ("pallas", True, "reference"),
+    ("reference", False, "reference")],
+    ids=["tpu", "tpu_on_a_mesh", "cpu"])
+def test_rule_takes_the_latent_leaf_on_a_tpu(monkeypatch, backend, sharded,
+                                             want):
+  _backend_takes(monkeypatch, backend)
+  for dtype in (jnp.bfloat16, jnp.float32):
+    assert sa.resolve_slot_attn_impl((96, 4104, 1, 576), dtype, 8, 20,
+                                     sharded=sharded) == want
