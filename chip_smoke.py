@@ -7,7 +7,12 @@ a user calls, and checks what comes out by the repo's own references:
 
   kernels         flash fwd + dk/dv + dq (resident and streaming) against
                   ``_dense_causal_attention``; paged attention (f32, bf16)
-                  against ``paged_attention_reference``; all compiled
+                  against ``paged_attention_reference``; ``kv_write``
+                  (bit for bit) and ``slot_attn`` against their reference
+                  lowerings at the serving cells' leaves, kept in rows
+                  (GPT-2 medium ``[96, 1040, 1024]`` chunk 16, the hybrid
+                  ``[128, 8200, 128]`` chunk 8) and in positions; all
+                  compiled
   train           the GPT-350M trainer at the largest batch that fits:
                   2 warm-up + 5 steps
   serve           ``ContinuousBatchingEngine`` on the same model, 8
@@ -134,7 +139,7 @@ class Sizes:
   new_tokens: int
   flash_shapes: tuple             # (B, H, S, D, dtype)
   paged_shape: tuple              # (T, H, hd, block, table width)
-  kv_write_shape: tuple           # (slots, Lc, H, hd, chunk)
+  kv_shapes: tuple                # (slots, Lc, H, H_kv, hd, chunk) each
   hybrid_cfg: JambaConfig         # four layers of the hybrid decoder
   ssm_scan_shape: tuple           # (slots, d_state, d_inner, chunk)
   experts_cfg: GlmMoeConfig       # one dense + two expert layers
@@ -160,8 +165,13 @@ class Sizes:
         flash_shapes=((2, 16, 1024, 64, jnp.bfloat16),
                       (1, 2, 16384, 64, jnp.bfloat16)),
         paged_shape=(16, 16, 64, 16, 8),
-        # The serving cache's leaf: 1024 + one chunk of slack.
-        kv_write_shape=(8, 1040, 16, 64, 16),
+        # The serving cells' leaves (1024 + one chunk of slack of GPT-2
+        # medium's 16 heads of 64, 8192 + a chunk of the hybrid's one K/V
+        # head of 128 under 20 query heads: both fill whole lane tiles
+        # and are kept in rows), and every head on one narrow K/V head,
+        # which stays in positions.
+        kv_shapes=((96, 1040, 16, 16, 64, 16), (128, 8200, 20, 1, 128, 8),
+                   (8, 1040, 16, 1, 64, 16)),
         # AI21-Jamba2-3B's widths, one attention layer among three Mamba
         # layers instead of two among 26; a cache for 1024 positions.
         hybrid_cfg=JambaConfig(num_layers=4, attn_layer_period=4,
@@ -193,7 +203,7 @@ class Sizes:
         flash_shapes=((1, 2, 128, 32, jnp.float32),
                       (1, 1, 256, 32, jnp.float32)),
         paged_shape=(6, 4, 32, 8, 4),
-        kv_write_shape=(4, 136, 4, 32, 8),
+        kv_shapes=((4, 136, 4, 4, 32, 8), (4, 136, 4, 1, 32, 8)),
         hybrid_cfg=JambaConfig(
             vocab_size=512, num_layers=4, d_model=64, d_ff=128, num_heads=4,
             num_kv_heads=1, attn_layer_period=4, attn_layer_offset=1,
@@ -298,29 +308,53 @@ def check_paged(T, H, hd, bs, MB, dtype, rehearsal: bool) -> None:
       f"max abs error {np.abs(got32 - ref32).max():.2e}")
 
 
-def check_kv_write(B, Lc, H, hd, C, dtype, rehearsal: bool) -> None:
+def leaf_orders(Hkv, hd):
+  """The orders a ``[slots, Lc, H_kv, hd]`` leaf can be kept in: positions
+  always (what every leaf was, and what a narrow one stays), rows where
+  the heads' width fills whole lane tiles (what ``cache_leaves`` then
+  makes it)."""
+  return ("rows", "positions") if (Hkv * hd) % 128 == 0 else ("positions",)
+
+
+def in_order(x, order):
+  """A ``[slots, Lc, H_kv, hd]`` leaf in ``order``."""
+  return x.reshape(x.shape[:2] + (-1,)) if order == "rows" else x
+
+
+def check_kv_write(B, Lc, H, hd, C, dtype, order, rehearsal: bool) -> None:
   """The in-place window write against ``vmap(dynamic_update_slice)``,
-  bit for bit over both whole leaves: cursors at a leaf's start, across
-  a 128-position boundary, and at the last legal window."""
+  bit for bit over both whole leaves kept in ``order``: cursors at a
+  leaf's start, across a 128-position boundary (and so across a stripe's
+  edge, on an odd row), and at the last legal window.  The rows form is
+  also told that two slots are idle: theirs it must leave as they were."""
   r = np.random.RandomState(2)
-  ck, cv = (jnp.asarray(r.randn(B, Lc, H, hd), dtype) for _ in range(2))
+  ck, cv = (in_order(jnp.asarray(r.randn(B, Lc, H, hd), dtype), order)
+            for _ in range(2))
   k, v = (jnp.asarray(r.randn(B, C, H, hd), dtype) for _ in range(2))
   cursors = jnp.asarray(
       ([0, 128 - C // 2, Lc - C, 127] + list(r.randint(0, Lc - C, B)))[:B],
       jnp.int32)
-  args = (ck, cv, k, v, cursors)
+  fed = np.ones((B,), bool)
+  if order == "rows":
+    fed[[1, B - 1]] = False
+  args = (ck, cv, k, v, cursors, jnp.asarray(fed, jnp.int32))
   kernel = compile_here(
       functools.partial(kv_write_pallas, interpret=rehearsal),
       *args, mosaic_calls=1, rehearsal=rehearsal)
   bits = lambda x: np.asarray(x).view(
       {2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
-  for name, g, w in zip("KV", kernel(*args),
-                        jax.jit(kv_write_reference)(*args)):
-    check((bits(g) == bits(w)).all(),
-          f"kv_write {name} leaf {jnp.dtype(dtype).name} differs from the "
-          f"reference in {(bits(g) != bits(w)).sum()} elements")
+  for name, g, w, old in zip("KV", kernel(*args),
+                             jax.jit(kv_write_reference)(*args[:5]),
+                             (ck, cv)):
+    check((bits(g)[fed] == bits(w)[fed]).all(),
+          f"kv_write {name} leaf {jnp.dtype(dtype).name} in {order} differs "
+          f"from the reference in {(bits(g)[fed] != bits(w)[fed]).sum()} "
+          "elements")
+    check((bits(g)[~fed] == bits(old)[~fed]).all(),
+          f"kv_write {name} leaf in {order}: an idle slot was written")
   say(f"  kv_write slots{B} Lc{Lc} H{H} hd{hd} chunk{C} "
-      f"{jnp.dtype(dtype).name}: K and V leaves bit-identical")
+      f"{jnp.dtype(dtype).name} in {order}: K and V leaves bit-identical"
+      + (", idle slots untouched" if order == "rows" else ""))
 
 
 def edge_cases(r, B, Lc, C, block):
@@ -335,25 +369,25 @@ def edge_cases(r, B, Lc, C, block):
   return cursors, num_valid
 
 
-def check_slot_attn(B, Lc, H, hd, C, dtype, rehearsal: bool,
-                    kv_heads: int = 0) -> None:
-  """The live-rows attend against the einsums over every row: cursors at
-  a leaf's start, on and across a block's edge and at the last legal
-  window, a partial chunk, an idle slot, and NaN in every row at or
-  beyond a slot's bound (the kernel must not read them; the reference
-  gets the clean leaves)."""
-  Hkv = kv_heads or H
+def check_slot_attn(B, Lc, H, Hkv, hd, C, dtype, order,
+                    rehearsal: bool) -> None:
+  """The live-rows attend over leaves kept in ``order`` against the
+  einsums over every row: cursors at a leaf's start, on and across a
+  block's edge and at the last legal window, a partial chunk, an idle
+  slot, and NaN in every row at or beyond a slot's bound (the kernel must
+  not read them; the reference gets the clean leaves)."""
   r = np.random.RandomState(3)
   q = jnp.asarray(r.randn(B, C, H, hd), dtype)
   ck, cv = (r.randn(B, Lc, Hkv, hd).astype(np.float32) for _ in range(2))
-  block = block_positions((B, Lc, Hkv, hd), dtype, C, H)
+  block = block_positions(in_order(ck, order).shape, dtype, C, H, hd)
   cursors, num_valid = edge_cases(r, B, Lc, C, block)
   dirty_k, dirty_v = ck.copy(), cv.copy()
   for b in range(B):
     bound = cursors[b] + num_valid[b] if num_valid[b] else 0
     dirty_k[b, bound:] = np.nan
     dirty_v[b, bound:] = np.nan
-  args = (q, jnp.asarray(dirty_k, dtype), jnp.asarray(dirty_v, dtype),
+  args = (q, in_order(jnp.asarray(dirty_k, dtype), order),
+          in_order(jnp.asarray(dirty_v, dtype), order),
           jnp.asarray(cursors), jnp.asarray(num_valid))
   # Both sides at the highest precision: float32 operands then multiply
   # as float32 in the kernel too (16-bit ones are exact either way).
@@ -364,19 +398,21 @@ def check_slot_attn(B, Lc, H, hd, C, dtype, rehearsal: bool,
         *args, mosaic_calls=1, rehearsal=rehearsal)
     got = np.asarray(kernel(*args), np.float32)
     ref = np.asarray(jax.jit(slot_attention_reference)(
-        q.astype(jnp.float32), jnp.asarray(ck, dtype).astype(jnp.float32),
-        jnp.asarray(cv, dtype).astype(jnp.float32), jnp.asarray(cursors)))
+        q.astype(jnp.float32),
+        in_order(jnp.asarray(ck, dtype).astype(jnp.float32), order),
+        in_order(jnp.asarray(cv, dtype).astype(jnp.float32), order),
+        jnp.asarray(cursors)))
   real = (np.arange(C)[None] < num_valid[:, None])[:, :, None, None]
   check(np.isfinite(got).all(), "slot_attn output not finite")
   check((np.where(real, 0, got) == 0).all(),
         "slot_attn: rows it does not compute are not zeros")
   err = rel_err(np.where(real, got, 0), np.where(real, ref, 0))
   tol = 2e-2 if dtype == jnp.bfloat16 else 5e-4
-  check(err <= tol, f"slot_attn {jnp.dtype(dtype).name}: error {err:.3g} "
-        f"of the reference's max, tol {tol}")
+  check(err <= tol, f"slot_attn {jnp.dtype(dtype).name} in {order}: error "
+        f"{err:.3g} of the reference's max, tol {tol}")
   say(f"  slot_attn slots{B} Lc{Lc} H{H}/{Hkv} hd{hd} chunk{C} block"
-      f"{block} {jnp.dtype(dtype).name}: {err:.2e} of the reference's "
-      "max, NaN beyond the bounds unread, idle rows zeros")
+      f"{block} {jnp.dtype(dtype).name} in {order}: {err:.2e} of the "
+      "reference's max, NaN beyond the bounds unread, idle rows zeros")
 
 
 def phase_kernels(sizes: Sizes) -> None:
@@ -384,15 +420,19 @@ def phase_kernels(sizes: Sizes) -> None:
     check_flash(*shape, rehearsal=sizes.rehearsal)
   for dtype in (jnp.float32, jnp.bfloat16):
     check_paged(*sizes.paged_shape, dtype, rehearsal=sizes.rehearsal)
-    check_kv_write(*sizes.kv_write_shape, dtype, rehearsal=sizes.rehearsal)
-    check_slot_attn(*sizes.kv_write_shape, dtype, rehearsal=sizes.rehearsal)
-    check_slot_attn(*sizes.kv_write_shape, dtype, rehearsal=sizes.rehearsal,
-                    kv_heads=1)
+    for B, Lc, H, Hkv, hd, C in sizes.kv_shapes:
+      for order in leaf_orders(Hkv, hd):
+        check_kv_write(B, Lc, Hkv, hd, C, dtype, order,
+                       rehearsal=sizes.rehearsal)
+        check_slot_attn(B, Lc, H, Hkv, hd, C, dtype, order,
+                        rehearsal=sizes.rehearsal)
   say("PASS kernels: flash fwd/bwd "
-      + ("at toy shapes, paged, kv_write and slot_attn f32 + bf16, all "
-         "INTERPRETED" if sizes.rehearsal else
-         "resident + streaming, paged, kv_write and slot_attn (every head "
-         "its own K/V, and all on one) f32 + bf16, all compiled")
+      + ("at toy shapes, paged, kv_write and slot_attn f32 + bf16 in rows "
+         "and in positions, all INTERPRETED" if sizes.rehearsal else
+         "resident + streaming, paged, kv_write and slot_attn at the "
+         "serving cells' leaves (GPT-2 medium's, the hybrid's one K/V "
+         "head, and every head on one narrow head) f32 + bf16, in rows and "
+         "in positions, all compiled")
       + ", within tolerance")
 
 

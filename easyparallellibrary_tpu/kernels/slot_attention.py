@@ -21,9 +21,26 @@ at the slot's cursor.  One algorithm, two lowerings:
   already holds, so it issues no DMA of its own, and its arithmetic is
   skipped.  Scores, the running max, sum and
   accumulator of the online softmax live in VMEM; no ``[B, H, C, Lc]``
-  tensor exists in HBM.  It addresses the leaf position-minor (``[slot,
-  H, hd, position]``), the view ``kernels/kv_write.py`` writes through,
-  so on a TPU the transposes around both calls are the same bitcasts.
+  tensor exists in HBM.  It reads the leaf AS IT LIES, in the form its
+  rank asks for, the one ``kernels/kv_write.py`` writes through:
+
+  - **rows** (rank 3, ``[slot, position, H_kv * hd]``): K and V blocks
+    arrive ``[block, W]``, the scores contract over the lanes of both
+    operands (the transposed-right-hand-side product) and the values
+    product is plain; edge rows are masked on the sublane axis.  Where
+    ``hd`` is a whole number of lane tiles a head is its own lanes; where
+    it is a fraction of one (64: GPT-2), a lane tile holds ``128 / hd``
+    heads and is treated as one unit — the unit's query rows are stacked
+    once per head, each copy zeroed outside its own head's lanes, so one
+    ``[n x rows, 128] x [128, block]`` and one ``[n x rows, block] x
+    [block, 128]`` product serve the unit's heads, nothing is sliced
+    below a lane tile, and the wanted lanes of each copy are selected on
+    the way out.  ``q`` goes in and the output comes out as ``[B, C, H x
+    hd]``: no transpose around the call (grouped heads alone ride the
+    query rows, a small transpose of ``q`` and of the output).
+  - **positions** (rank 4, kept position-minor by the TPU, ``[slot, H,
+    hd, position]``): one batched product over the heads, K as the leaf
+    holds it; the transposes around the call are bitcasts.
 
 Arithmetic: scores accumulate in float32 from the compute-dtype ``q``
 and K, the softmax runs in float32, probabilities are cast to the
@@ -61,9 +78,9 @@ from HBM once a block, not once as keys and once as values, and the
 output is ``[B, C, H, v_width]``.  ``scale`` replaces ``1 / sqrt(hd)``
 where the queries' width is not the head size the softmax is scaled by.
 
-Shapes: ``q`` ``[B, C, H, hd]``; ``cached_k/cached_v`` ``[B, Lc, H_kv,
-hd]`` AFTER this step's window write; ``cursors``, ``num_valid`` int32
-``[B]``.
+Shapes: ``q`` ``[B, C, H, hd]``; ``cached_k/cached_v`` ``[B, Lc, H_kv x
+hd]`` (rows) or ``[B, Lc, H_kv, hd]`` (positions) AFTER this step's
+window write; ``cursors``, ``num_valid`` int32 ``[B]``.
 """
 
 from __future__ import annotations
@@ -107,60 +124,104 @@ def _backend_impl() -> str:
   return "pallas" if jax.default_backend() == "tpu" else "reference"
 
 
+def sublane_tile(dtype) -> int:
+  """Rows of one sublane tile of ``dtype``: 8 of 32 bits, 16 of 16."""
+  return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
 def _query_rows(chunk: int, group: int, dtype) -> int:
   """Query rows of one K/V head, padded to whole sublane tiles of
   ``dtype`` (a one-token decode is one row)."""
-  tile = 8 * 4 // jnp.dtype(dtype).itemsize
+  tile = sublane_tile(dtype)
   return -(-chunk * group // tile) * tile
 
 
-def block_positions(cache_shape, dtype, chunk: int, num_heads: int) -> int:
-  """Positions per K/V block for a ``[B, Lc, H_kv, hd]`` leaf: the widest
+def _geometry(cache_shape, head_dim: Optional[int]):
+  """``(Lc, H_kv, hd, rows)`` of a leaf of either order; a leaf kept in
+  rows (rank 3) needs ``head_dim`` to tell its heads apart."""
+  if len(cache_shape) == 3:
+    _, Lc, W = cache_shape
+    return Lc, W // head_dim, head_dim, True
+  _, Lc, Hkv, hd = cache_shape
+  return Lc, Hkv, hd, False
+
+
+def _unit(hd: int):
+  """Lanes of one unit of the rows form and the heads it holds: a head of
+  whole lane tiles is its own unit, smaller heads share a lane tile."""
+  width = max(hd, LANES)
+  return width, width // hd
+
+
+def block_positions(cache_shape, dtype, chunk: int, num_heads: int,
+                    head_dim: Optional[int] = None) -> int:
+  """Positions per K/V block for a leaf of either order: the widest
   of :data:`_BLOCKS` whose K block stays within :data:`_BLOCK_BYTES`,
   does not outgrow the leaf and leaves the kernel within its VMEM
   budget; 0 if none does."""
-  _, Lc, Hkv, hd = cache_shape
+  Lc, Hkv, hd, rows_form = _geometry(cache_shape, head_dim)
   size = jnp.dtype(dtype).itemsize
   rows = _query_rows(chunk, num_heads // Hkv, dtype)
+  W = Hkv * hd
+  width, stack = _unit(hd)
   for block in _BLOCKS:
-    if block > Lc or Hkv * hd * block * size > _BLOCK_BYTES:
+    if block > Lc or W * block * size > _BLOCK_BYTES:
       continue
-    vmem = (4 * Hkv * hd * block * size        # K, V, double-buffered
-            + 4 * Hkv * rows * hd * size       # q, out, double-buffered
-            + Hkv * rows * (hd + 2 * LANES) * 4    # acc, max, sum
-            + 3 * Hkv * rows * block * 4)      # scores, probabilities
+    vmem = (4 * W * block * size               # K, V, double-buffered
+            + 4 * rows * W * size)             # q, out, double-buffered
+    if rows_form:
+      stacked = (W // width) * stack * rows    # every unit's stacked rows
+      vmem += (stacked * width * (size + 4)    # stacked q, acc
+               + 2 * stacked * LANES * 4       # max, sum
+               + 3 * stacked * block * 4)      # scores, probabilities
+    else:
+      vmem += (Hkv * rows * (hd + 2 * LANES) * 4    # acc, max, sum
+               + 3 * Hkv * rows * block * 4)   # scores, probabilities
     if vmem <= _VMEM_BUDGET:
       return block
   return 0
 
 
-def slot_attn_fits(cache_shape, dtype, chunk: int, num_heads: int) -> bool:
-  """Whether the kernel can tile a ``[B, Lc, H_kv, hd]`` leaf of
-  ``dtype`` for ``chunk`` query positions of ``num_heads`` heads: a
-  32-bit or 16-bit float leaf of at least one whole 128-position tile
-  whose ``hd`` fills whole sublane tiles, query heads in whole groups, a
-  chunk no wider than a tile (so the causal edge touches two blocks at
-  most) and a block within the budgets."""
-  _, Lc, Hkv, hd = cache_shape
+def slot_attn_fits(cache_shape, dtype, chunk: int, num_heads: int,
+                   head_dim: Optional[int] = None) -> bool:
+  """Whether the kernel can tile a leaf of ``dtype`` for ``chunk`` query
+  positions of ``num_heads`` heads, in the form its rank asks for: a
+  32-bit or 16-bit float leaf of at least 128 positions, query heads in
+  whole groups, a chunk no wider than 128 (so the causal edge touches
+  two blocks at most), a block within the budgets, and
+
+  * rows ``[B, Lc, H_kv x hd]`` (``head_dim`` given): an ``hd`` that is a
+    whole number of lane tiles or divides one;
+  * positions ``[B, Lc, H_kv, hd]``: an ``hd`` that fills whole sublane
+    tiles."""
+  Lc, Hkv, hd, rows_form = _geometry(cache_shape, head_dim)
   dtype = jnp.dtype(dtype)
   if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
     return False
   if Lc < LANES or not 1 <= chunk <= LANES:
     return False
-  if hd % (8 * 4 // dtype.itemsize) or num_heads % Hkv:
+  if Hkv < 1 or num_heads % Hkv:
     return False
-  return block_positions(cache_shape, dtype, chunk, num_heads) > 0
+  if rows_form:
+    if cache_shape[2] != Hkv * hd or (hd % LANES and LANES % hd):
+      return False
+  elif hd % sublane_tile(dtype):
+    return False
+  return block_positions(cache_shape, dtype, chunk, num_heads, head_dim) > 0
 
 
 def resolve_slot_attn_impl(cache_shape, dtype, chunk: int, num_heads: int,
-                           sharded: bool = False) -> str:
+                           sharded: bool = False,
+                           head_dim: Optional[int] = None) -> str:
   """The dispatch rule: the backend's lowering (``pallas`` on a TPU,
   ``reference`` elsewhere), and ``reference`` whenever the leaf lives on
   a multi-device mesh (``sharded``: the SPMD partitioner cannot split a
-  Mosaic call) or the shapes do not fit (:func:`slot_attn_fits`)."""
+  Mosaic call) or the shapes do not fit (:func:`slot_attn_fits`).  A
+  leaf kept in rows (rank 3) names its heads' width in ``head_dim``."""
   impl = _backend_impl()
   if impl != "reference" and (
-      sharded or not slot_attn_fits(cache_shape, dtype, chunk, num_heads)):
+      sharded or not slot_attn_fits(cache_shape, dtype, chunk, num_heads,
+                                    head_dim)):
     return "reference"
   return impl
 
@@ -174,7 +235,12 @@ def slot_attention_reference(q, cached_k, cached_v, cursors,
   """Every query against every row of its slot's cache, masked to the
   causal prefix ``j <= cursor + i``: nothing newer, nothing stale."""
   B, C, H, hd = q.shape
-  Lc, Hkv = cached_k.shape[1:3]
+  Lc = cached_k.shape[1]
+  if cached_k.ndim == 3:
+    # Kept in rows: heads apart again, free on an ``hd``-minor array.
+    cached_k = cached_k.reshape(B, Lc, -1, hd)
+    cached_v = cached_v.reshape(B, Lc, -1, hd)
+  Hkv = cached_k.shape[2]
   dtype = q.dtype
   if cached_v is None:
     cached_v = cached_k[..., :v_width]
@@ -200,12 +266,147 @@ def slot_attention_reference(q, cached_k, cached_v, cursors,
 # ----------------------------------------------------------------- pallas --
 
 
+def live_order(alive):
+  """``(order, live)`` for a grid over the live slots alone, in slot
+  order: ``live`` (int32 ``[1]``, at least 1) is their number, a value
+  and not a shape, so a grid whose first dimension it is compiles once;
+  ``order[i]`` names the slot of grid row ``i``.  (The i-th live slot is
+  the number of slots with at most i live ones up to and including
+  themselves: no sort, no scatter.)  ``kernels/kv_write.py`` visits the
+  slots a step feeds through the same pair."""
+  B = alive.shape[0]
+  upto = jnp.cumsum(alive, dtype=jnp.int32)
+  order = jnp.minimum(
+      jnp.sum(upto[None, :] <= jnp.arange(B)[:, None], axis=1,
+              dtype=jnp.int32), B - 1)
+  return order, jnp.maximum(upto[-1:], 1)
+
+
+def _online_softmax_fold(s, m_ref, l_ref, at):
+  """Fold one block's masked scores ``s [.., rows, block]`` into the
+  running max and sum held at index ``at`` of their scratches (``...``
+  in the positions form, a unit in the rows form); returns the block's
+  unnormalised probabilities and the factor that rescales what the
+  accumulator already holds."""
+  m_all = m_ref[at]
+  m_prev = m_all[..., :1]
+  l_prev = l_ref[at][..., :1]
+  m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+  # A row with nothing visible yet (beyond the slot's chunk) keeps
+  # m = NEG_INF and sums ones: finite, and zeroed when emitted.
+  p = jnp.exp(s - m_new)
+  corr = jnp.exp(m_prev - m_new)
+  m_ref[at] = jnp.broadcast_to(m_new, m_all.shape)
+  l_ref[at] = jnp.broadcast_to(
+      l_prev * corr + jnp.sum(p, axis=-1, keepdims=True), m_all.shape)
+  return p, corr
+
+
+def _slot_attn_rows_kernel(order_ref, live_ref, cur_ref, bound_ref, pos_ref,
+                           q_ref, k_ref, v_ref, o_ref, qs_ref, m_ref, l_ref,
+                           acc_ref, *, block: int, num_blocks: int,
+                           scale: float, hd: int):
+  """One (slot, K/V block) grid step of the rows form: K and V blocks
+  ``[block, W]`` as the leaf holds them, ``q`` and the output ``[rows,
+  W]``.
+
+  The lanes are walked a UNIT at a time (:func:`_unit`): a head of whole
+  lane tiles, or one lane tile of ``n`` smaller heads.  On a slot's first
+  step the unit's query rows are stacked ``n`` times into ``qs_ref``,
+  copy ``s`` zeroed outside head ``s``'s lanes, so that the scores
+  contraction over the unit's lanes (of both operands: K is not
+  transposed) gives each copy its own head's scores, and the values
+  product gives copy ``s`` its head's output in that head's own lanes:
+  the copies are merged by a lane select when the slot is emitted.  The
+  units' scores are stacked on the sublanes, ``[units x n x rows,
+  block]``, so the softmax runs once over all of them and the units'
+  products stand side by side with nothing between them for the MXUs to
+  wait on; the three softmax scratches are stacked the same way.
+  ``pos_ref`` holds each stacked row's position in the chunk."""
+  del live_ref
+  rows, W = q_ref.shape[1:]
+  width, stack = _unit(hd)
+  units = W // width
+  R = stack * rows                     # stacked query rows of one unit
+  b = order_ref[pl.program_id(0)]
+  kb = pl.program_id(1)
+  cur = cur_ref[b]
+  bound = bound_ref[b]
+  lanes = lambda u: slice(u * width, (u + 1) * width)
+  if stack > 1:
+    head = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1) // hd
+
+  @pl.when(kb == 0)
+  def _init():
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    for u in range(units):
+      q = q_ref[0, :, lanes(u)]
+      for s in range(stack):
+        qs_ref[u * R + s * rows:u * R + (s + 1) * rows] = (
+            q if stack == 1 else jnp.where(head == s, q, jnp.zeros_like(q)))
+
+  def fold(edge: bool):
+    # 16-bit operands multiply exactly on the MXU whatever precision the
+    # caller's context names (and Mosaic refuses a float32 contraction
+    # of them); float32 operands follow the context, as the einsums do.
+    precision = (None if q_ref.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    s = jnp.concatenate([
+        jax.lax.dot_general(
+            qs_ref[u * R:(u + 1) * R], k_ref[0, :, lanes(u)],
+            (((1,), (1,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)
+        for u in range(units)], axis=0) * scale      # [units x R, block]
+    vs = [v_ref[0, :, lanes(u)] for u in range(units)]
+    if edge:
+      # The block holds rows at or beyond the cursor: query row i sees
+      # key j iff j <= cursor + i, and nothing at or beyond the bound
+      # (its own chunk's invalid tail, a previous occupant's rows, the
+      # leaf's edge rows) may reach the sums, through K or through V.
+      col = kb * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+      s = jnp.where((col <= cur + pos_ref[...]) & (col < bound), s, NEG_INF)
+      stale = kb * block + jax.lax.broadcasted_iota(
+          jnp.int32, (block, width), 0) >= bound
+      vs = [jnp.where(stale, jnp.zeros_like(v), v) for v in vs]
+    p, corr = _online_softmax_fold(s, m_ref, l_ref, ...)
+    p = p.astype(vs[0].dtype)
+    acc_ref[...] = acc_ref[...] * corr + jnp.concatenate([
+        jax.lax.dot_general(
+            p[u * R:(u + 1) * R], vs[u], (((1,), (0,)), ((), ())),
+            precision=precision, preferred_element_type=jnp.float32)
+        for u in range(units)], axis=0)              # [units x R, width]
+
+  live = kb * block < bound
+  behind = (kb + 1) * block <= cur     # every row of it under the cursor
+
+  @pl.when(live & behind)
+  def _interior():
+    fold(edge=False)
+
+  @pl.when(live & jnp.logical_not(behind))
+  def _edge():
+    fold(edge=True)
+
+  @pl.when(kb == num_blocks - 1)
+  def _emit():
+    l_col = jnp.maximum(l_ref[...][:, :1], 1e-30)
+    out = jnp.where(pos_ref[...] < bound - cur, acc_ref[...] / l_col, 0.0)
+    for u in range(units):
+      merged = out[u * R:u * R + rows]
+      for s in range(1, stack):
+        merged = jnp.where(
+            head == s, out[u * R + s * rows:u * R + (s + 1) * rows], merged)
+      o_ref[0, :, lanes(u)] = merged.astype(o_ref.dtype)
+
+
 def _slot_attn_kernel(order_ref, live_ref, cur_ref, bound_ref, pos_ref,
                       q_ref, k_ref, *refs, block: int, num_blocks: int,
                       scale: float, v_width: Optional[int]):
-  """One (slot, K/V block) grid step: score the block against every
-  query row of every head, fold it into the online softmax carried in
-  VMEM scratch, emit on the slot's last step.
+  """One (slot, K/V block) grid step of the positions form: score the
+  block against every query row of every head, fold it into the online
+  softmax carried in VMEM scratch, emit on the slot's last step.
 
   Values keep ``[heads, rows, .]``: one batched matmul over the heads
   for the scores (``[rows, hd] x [hd, block]``, K as the leaf holds it)
@@ -249,16 +450,7 @@ def _slot_attn_kernel(order_ref, live_ref, cur_ref, bound_ref, pos_ref,
                     & (col < bound), s, NEG_INF)
       vcol = kb * block + jax.lax.broadcasted_iota(jnp.int32, v.shape, 2)
       v = jnp.where(vcol < bound, v, jnp.zeros_like(v))
-    m_prev = m_ref[...][:, :, :1]
-    l_prev = l_ref[...][:, :, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    # A row with nothing visible yet (beyond the slot's chunk) keeps
-    # m = NEG_INF and sums ones: finite, and zeroed when emitted.
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(
-        l_prev * corr + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
+    p, corr = _online_softmax_fold(s, m_ref, l_ref, ...)
     acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
         p.astype(v.dtype), v, (((2,), (2,)), ((0,), (0,))),
         precision=precision,
@@ -296,13 +488,13 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
   layers of one step share one trace and one Mosaic lowering of the
   kernel, as ``kv_write_pallas`` does; XLA inlines the calls."""
   B, C, H, hd = q.shape
-  _, Lc, Hkv, _ = cached_k.shape
+  Lc, Hkv, _, rows_form = _geometry(cached_k.shape, hd)
   G = H // Hkv
   dtype = cached_k.dtype
   # One leaf: no V operand, the values' width is ``v_width``.
   vd = hd if cached_v is not None else v_width
   if block is None:
-    block = block_positions(cached_k.shape, dtype, C, H)
+    block = block_positions(cached_k.shape, dtype, C, H, hd)
   nb = pl.cdiv(Lc, block)
   rows = _query_rows(C, G, dtype)
 
@@ -313,30 +505,14 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
         else jnp.clip(num_valid.astype(jnp.int32), 0, C))
   alive = nv > 0
   bound = jnp.where(alive, cur + nv, 0)
-  # The grid visits the live slots alone, in slot order: its first
-  # dimension is their number, a value and not a shape (one compile), and
-  # ``order`` names the slot of each of its rows.  An idle slot costs no
-  # grid step and no DMA; its output block is never written and is
-  # zeroed below.
-  # (The i-th live slot is the number of slots with at most i live ones
-  # up to and including themselves: no sort, no scatter.)
-  upto = jnp.cumsum(alive, dtype=jnp.int32)
-  order = jnp.minimum(
-      jnp.sum(upto[None, :] <= jnp.arange(B)[:, None], axis=1,
-              dtype=jnp.int32), B - 1)
-  live = jnp.maximum(upto[-1:], 1)
+  # The grid visits the live slots alone, in slot order (:func:`live_order`).
+  # An idle slot costs no grid step and no DMA; its output block is never
+  # written and is zeroed below.
+  order, live = live_order(alive)
   # Each query row's position in its chunk: rows are (group, position),
   # padding rows carry a position no slot reaches.
   pos = jnp.arange(rows, dtype=jnp.int32)
   pos = jnp.where(pos < G * C, pos % C, C)[:, None]
-
-  # Position-minor views of the leaves: bitcasts on the TPU, and the
-  # inverse of the ones kv_write returned through.
-  to_minor = lambda x: jnp.transpose(x, (0, 2, 3, 1))
-  qr = q.astype(dtype).reshape(B, C, Hkv, G, hd).transpose(0, 2, 3, 1, 4)
-  qr = qr.reshape(B, Hkv, G * C, hd)
-  if rows != G * C:
-    qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - G * C), (0, 0)))
 
   def kv_idx(i, kb, order, live, cur, bound):
     # A step beyond its slot's bound points at the first block of the
@@ -349,12 +525,73 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
     reads = kb * block < bound[b]
     more = i + 1 < live[0]
     held = jnp.maximum(bound[b] - 1, 0) // block
-    return (jnp.where(reads, b, jnp.where(more, ahead, b)), 0, 0,
+    return (jnp.where(reads, b, jnp.where(more, ahead, b)),
             jnp.where(reads, kb, jnp.where(more, 0, held)))
+
+  kwargs = {}
+  if not interpret:
+    kwargs["compiler_params"] = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"))
+  scale = 1.0 / math.sqrt(hd) if scale is None else float(scale)
+  # Query rows of one K/V head are (group, position): a transpose of
+  # ``q`` and of the output for grouped heads alone.
+  qr = q.astype(dtype).reshape(B, C, Hkv, G, hd)
+
+  if rows_form:
+    W = Hkv * hd
+    width, stack = _unit(hd)
+    stacked = (W // width) * stack * rows      # every unit's stacked rows
+    qr = qr.transpose(0, 3, 1, 2, 4).reshape(B, G * C, W)
+    if rows != G * C:
+      qr = jnp.pad(qr, ((0, 0), (0, rows - G * C), (0, 0)))
+    row_spec = pl.BlockSpec((1, rows, W),
+                            lambda i, kb, order, *_: (order[i], 0, 0))
+    def rows_idx(*a):
+      b, kb = kv_idx(*a)
+      return b, kb, 0
+
+    kv_spec = pl.BlockSpec((1, block, W), rows_idx)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(live[0], nb),
+        in_specs=[pl.BlockSpec((stacked, 1), lambda i, kb, *_: (0, 0)),
+                  row_spec, kv_spec, kv_spec],
+        out_specs=row_spec,
+        scratch_shapes=[
+            pltpu.VMEM((stacked, width), dtype),          # stacked q
+            pltpu.VMEM((stacked, LANES), jnp.float32),    # running max
+            pltpu.VMEM((stacked, LANES), jnp.float32),    # running sum
+            pltpu.VMEM((stacked, width), jnp.float32),    # accumulator
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_slot_attn_rows_kernel, block=block,
+                          num_blocks=nb, scale=scale, hd=hd),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, rows, W), dtype),
+        interpret=interpret,
+        name=SLOT_ATTN,
+        **kwargs,
+    )(order, live, cur, bound, jnp.tile(pos, (stacked // rows, 1)), qr,
+      cached_k, cached_v)
+    out = jnp.where(alive[:, None, None], out, 0)
+    out = out[:, :G * C].reshape(B, G, C, Hkv, hd)
+    return out.transpose(0, 2, 3, 1, 4).reshape(B, C, H, hd)
+
+  # Position-minor views of the leaves: bitcasts on the TPU, and the
+  # inverse of the ones kv_write returned through.
+  to_minor = lambda x: jnp.transpose(x, (0, 2, 3, 1))
+  qr = qr.transpose(0, 2, 3, 1, 4).reshape(B, Hkv, G * C, hd)
+  if rows != G * C:
+    qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - G * C), (0, 0)))
 
   row_spec = lambda width: pl.BlockSpec(
       (1, Hkv, rows, width), lambda i, kb, order, *_: (order[i], 0, 0, 0))
-  kv_spec = pl.BlockSpec((1, Hkv, hd, block), kv_idx)
+  def minor_idx(*a):
+    b, kb = kv_idx(*a)
+    return b, 0, 0, kb
+
+  kv_spec = pl.BlockSpec((1, Hkv, hd, block), minor_idx)
   leaves = [cached_k] if cached_v is None else [cached_k, cached_v]
   grid_spec = pltpu.PrefetchScalarGridSpec(
       num_scalar_prefetch=4,
@@ -368,14 +605,9 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
           pltpu.VMEM((Hkv, rows, vd), jnp.float32),      # accumulator
       ],
   )
-  kwargs = {}
-  if not interpret:
-    kwargs["compiler_params"] = pltpu.CompilerParams(
-        dimension_semantics=("arbitrary", "arbitrary"))
   out = pl.pallas_call(
       functools.partial(
-          _slot_attn_kernel, block=block, num_blocks=nb,
-          scale=1.0 / math.sqrt(hd) if scale is None else float(scale),
+          _slot_attn_kernel, block=block, num_blocks=nb, scale=scale,
           v_width=None if cached_v is not None else v_width),
       grid_spec=grid_spec,
       out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, vd), dtype),
@@ -408,7 +640,7 @@ def slot_attention(q, cached_k, cached_v, cursors, num_valid=None,
     mesh = cluster.built_mesh if cluster is not None else None
     impl = resolve_slot_attn_impl(
         cached_k.shape, cached_k.dtype, q.shape[1], q.shape[2],
-        sharded=mesh is not None and mesh.size > 1)
+        sharded=mesh is not None and mesh.size > 1, head_dim=q.shape[3])
   if impl not in IMPLS:
     raise ValueError(f"impl must be one of {IMPLS} or None; got {impl!r}")
   if (cached_v is None) != (v_width is not None):

@@ -110,9 +110,11 @@ def slot_cache_attend(q, k, v, cached_k, cached_v, cursors, dtype,
 
   ``q``/``k``/``v`` are ``[B, C, H, hd]`` projections of this step's C
   new tokens per slot (C == 1 for pure decode), ``cached_k``/``cached_v``
-  are ``[B, Lc, H, hd]`` per-slot caches, and ``cursors`` is an int32
-  ``[B]`` vector of write offsets — how many tokens each slot already
-  holds.  Grouped K/V heads (models/jamba.py): ``k``/``v`` and the cache
+  are per-slot caches in either order (``[B, Lc, H x hd]``, kept in rows,
+  or ``[B, Lc, H, hd]``: serving/kv_cache.py's order note; the write and
+  the attend read the order off the leaf's rank), and ``cursors`` is an
+  int32 ``[B]`` vector of write offsets — how many tokens each slot
+  already holds.  Grouped K/V heads (models/jamba.py): ``k``/``v`` and the cache
   leaves may carry ``H_kv < H`` heads, each shared by ``H / H_kv`` query
   heads; with ``H_kv == H`` the program is the one it always was.  Token ``i`` of slot ``b`` lands at cache position
   ``cursors[b] + i`` and attends causally over positions
@@ -127,7 +129,9 @@ def slot_cache_attend(q, k, v, cached_k, cached_v, cursors, dtype,
   the cursor ever reaches it (the next chunk's write window covers it).
   Stale K/V from a previous slot occupant is masked the same way — a
   reused slot only ever attends to positions its own tokens have
-  written.
+  written.  An IDLE slot's window (``num_valid == 0``) may stay
+  unwritten (the rows form of the write visits fed slots only): nothing
+  reads it for the same reason.
 
   ``num_valid`` (int32 ``[B]``; ``None`` = all ``C`` positions real,
   which is what ``generate()``'s decode means) says how many of the
@@ -168,7 +172,7 @@ def slot_cache_attend(q, k, v, cached_k, cached_v, cursors, dtype,
   from easyparallellibrary_tpu.kernels.kv_write import kv_write
   from easyparallellibrary_tpu.kernels.slot_attention import slot_attention
   cached_k, cached_v = kv_write(cached_k, cached_v, k, v, cursors,
-                                impl=write_impl)
+                                num_valid, impl=write_impl)
   out = slot_attention(q, cached_k, cached_v, cursors, num_valid,
                        impl=attn_impl)
   return out.astype(dtype), cached_k, cached_v

@@ -16,8 +16,11 @@ slot_cursors=..., mutable=["cache"])``, ``models.gpt.slot_step_logits``)
 and keeps TWO kinds of per-slot state in the ``cache`` collection, which
 ``serving/kv_cache.py`` allocates from :meth:`JambaConfig.layer_kinds`:
 
-* attention layers: ``cached_key`` / ``cached_value`` ``[slots, Lc, H_kv,
-  hd]`` under a cursor, through ``models.gpt.slot_cache_attend``;
+* attention layers: ``cached_key`` / ``cached_value`` under a cursor,
+  through ``models.gpt.slot_cache_attend``: ``[slots, Lc, H_kv x hd]``,
+  kept in rows, where that width fills whole lane tiles (the published
+  one head of 128 does), ``[slots, Lc, H_kv, hd]`` elsewhere
+  (serving/kv_cache.py, order note);
 * Mamba layers: ``conv_state`` ``[slots, d_conv - 1, d_inner]`` (the last
   inputs of the convolution) and ``ssm_state`` float32 ``[slots, d_state,
   d_inner]`` (state-major: the channels ride the lanes).

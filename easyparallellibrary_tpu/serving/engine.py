@@ -368,7 +368,6 @@ class ContinuousBatchingEngine:
     self._recurrent = kv_lib.has_recurrent_state(cfg)
     self.ssm_scan_impl = kv_lib.ssm_scan_impl(
         cfg, self.num_slots, self.chunk, self.mesh)
-    self.cache_layout = None
     if self._recurrent:
       trace_lib.get_tracer().metadata(
           f"{self._track_prefix}/ssm_scan_impl",
@@ -383,11 +382,15 @@ class ContinuousBatchingEngine:
       trace_lib.get_tracer().metadata(
           f"{self._track_prefix}/moe_gmm_impl",
           {"impl": self.moe_gmm_impl})
-    if self._recurrent or kv_lib.has_latent_cache(cfg):
-      # What the cache holds of each kind of state: K/V, recurrent
-      # state, latent rows.
-      self.cache_layout = kv_lib.cache_layout(cfg, self.num_slots,
-                                              self.chunk)
+    # What the contiguous cache holds of each kind of state (K/V,
+    # recurrent state, latent rows) and the ORDER its leaves under a
+    # cursor are kept in (``kv_order``: rows or positions,
+    # serving/kv_cache.py), which says which form of the write and of the
+    # attend the step runs.  None on a paged engine (its pool has its own
+    # layout).
+    self.cache_layout = None if self.paged else kv_lib.cache_layout(
+        cfg, self.num_slots, self.chunk)
+    if self.cache_layout is not None:
       trace_lib.get_tracer().metadata(
           f"{self._track_prefix}/cache_layout", dict(self.cache_layout))
     # Copy-on-write prefix caching (serving.prefix_cache.*;
@@ -521,8 +524,10 @@ class ContinuousBatchingEngine:
     # than the bad step's (speculation degraded off, drafter fault,
     # prefill budget tightened between steps).  Separate tiny program;
     # dispatched only on bad-step events, compiles once.  Every leaf it
-    # sees is K/V with a position axis: a model with recurrent state (no
-    # such axis) is refused the guarded step above.  The SAME
+    # sees is K/V with a position axis, in either order (rank 3 kept in
+    # rows, rank 4 in positions; the mask follows the leaf's rank): a
+    # model with recurrent state (no such axis) is refused the guarded
+    # step above.  The SAME
     # program serves both layouts: dim 0 is slots (contiguous) or pool
     # blocks (paged), dim 1 rows within — the paged host side maps slot
     # block lists to (block mask, per-block start row) and always
@@ -531,9 +536,10 @@ class ContinuousBatchingEngine:
     self._sanitize_fn = jax.jit(
         lambda kv, mask, start: jax.tree_util.tree_map(
             lambda x: jnp.where(
-                mask[:, None, None, None]
-                & (jnp.arange(x.shape[1])[None, :, None, None]
-                   >= start[:, None, None, None]),
+                jnp.expand_dims(
+                    mask[:, None] & (jnp.arange(x.shape[1])[None]
+                                     >= start[:, None]),
+                    tuple(range(2, x.ndim))),
                 jnp.zeros((), x.dtype), x), kv),
         donate_argnums=0) if self._resilient else None
     if self._sanitize_fn is not None and self._introspector is not None:
@@ -601,17 +607,18 @@ class ContinuousBatchingEngine:
                 f"{self._paged_impl} attend, "
                 f"{kv_lib.paged_cache_bytes(cfg, self.num_blocks, self.block_size) / 1e6:.1f} MB")
     else:
-      layout = (f"contiguous slots, {self.slot_attn_impl} attend, "
+      lay = self.cache_layout
+      layout = (f"contiguous slots kept in {lay['kv_order']}, "
+                f"{self.slot_attn_impl} attend, "
                 f"{self.kv_write_impl} kv write, "
                 f"{kv_lib.cache_bytes(cfg, self.num_slots, self.chunk) / 1e6:.1f} MB")
       if self._recurrent:
-        lay = self.cache_layout
         layout += (f": {lay['kv_leaves']} K/V leaves "
                    f"{lay['kv_bytes'] / 1e6:.1f} MB + {lay['state_leaves']} "
                    f"recurrent-state leaves {lay['state_bytes'] / 1e6:.1f} "
                    f"MB, {self.ssm_scan_impl} ssm scan")
-      elif self.cache_layout is not None:
-        layout += (f": {self.cache_layout['latent_leaves']} latent leaves")
+      elif "latent_leaves" in lay:
+        layout += f": {lay['latent_leaves']} latent leaves"
       if self._experts:
         layout += f", {self.moe_gmm_impl} expert matmul"
     get_logger().info(
@@ -720,6 +727,7 @@ class ContinuousBatchingEngine:
         "paged": self.paged,
         "kv_write_impl": self.kv_write_impl,
         "slot_attn_impl": self.slot_attn_impl,
+        "kv_order": (self.cache_layout or {}).get("kv_order"),
         "ssm_scan_impl": self.ssm_scan_impl,
         "moe_gmm_impl": self.moe_gmm_impl,
         "recompiles": self._compile_sentinel.recompiles,

@@ -271,6 +271,11 @@ class FrontDoor:
             self.overflow_sheds += 1
           r.cancel(uid)
         self._overflow_cancels = []
+        # As for a "cancel" command: the shed flow's fin rides the NEXT
+        # cycle, so kick one even if it was the fleet's last request —
+        # otherwise its stream never gets its ``done`` event and a live
+        # reader waits on keepalives for ever.
+        self._kick = True
 
   def _handle_command(self, cmd: Tuple[Any, ...]) -> None:
     r = self.router

@@ -45,6 +45,20 @@ every slot's cursor (static shapes — masking, not shape, expresses
 partial validity), so the window must never clamp against the end of the
 buffer; ``jax.lax.dynamic_update_slice`` would otherwise shift the write
 and corrupt earlier positions.
+
+Order note: a K/V leaf is kept in ROWS, ``[num_slots, Lc, H_kv x hd]``
+(heads folded into the minor dimension, head-major), wherever the heads'
+width fills whole 128-lane tiles (:func:`kv_leaf_shape`: GPT-2 medium's
+1024, the hybrid's one head of 128): the TPU then keeps a position's
+values contiguous, the step's K/V chunk IS rows of that width, a window
+is a stripe of rows, and both kernels read the leaf as it lies.  A
+narrower leaf stays ``[num_slots, Lc, H_kv, hd]``, which the TPU keeps
+POSITION-minor (``hd`` minor would pad its lanes), and so does the latent
+leaf (576 wide, 4.5 lane tiles: padding it would cost memory the cell
+does not have).  The order is one rule on the shape, read off the leaf's
+rank by everything downstream (rank 3 = rows, rank 4 = positions): no
+configuration field, environment variable or setter.  The paged pool
+keeps ``[num_blocks, block_size, H, hd]``.
 """
 
 from __future__ import annotations
@@ -113,13 +127,29 @@ def kv_heads(cfg) -> Tuple[int, int]:
           cfg.d_model // cfg.num_heads)
 
 
+def kv_leaf_shape(cfg, num_slots: int, chunk: int) -> Tuple[int, ...]:
+  """Shape of one leaf of rows under a cursor, in the order it is kept
+  (module docstring, order note): a K/V pair whose heads' width ``H_kv x
+  hd`` is a whole number of 128-lane tiles, in a 16-bit or 32-bit float,
+  is kept in rows, ``[num_slots, Lc, H_kv x hd]``; every other leaf (a
+  narrower pair, the latent leaf) ``[num_slots, Lc, H_kv, hd]``."""
+  Hkv, hd = kv_heads(cfg)
+  lead = (num_slots, cache_length(cfg, chunk))
+  if (not has_latent_cache(cfg) and (Hkv * hd) % 128 == 0
+      and jnp.dtype(cfg.dtype) in (jnp.dtype(jnp.bfloat16),
+                                   jnp.dtype(jnp.float32))):
+    return lead + (Hkv * hd,)
+  return lead + (Hkv, hd)
+
+
 def cache_leaves(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
   """The slot cache as shapes: the pytree :func:`allocate_kv_cache`
   fills, one entry a layer BY ITS KIND — one manager, two kinds of state:
 
   * attention: ``{"attn": {"cached_key", "cached_value"}}``, each
-    ``[num_slots, Lc, H_kv, hd]`` in the compute dtype, read under the
-    slot's cursor;
+    ``[num_slots, Lc, H_kv x hd]`` (rows) or ``[num_slots, Lc, H_kv, hd]``
+    (positions; :func:`kv_leaf_shape`) in the compute dtype, read under
+    the slot's cursor;
   * Mamba: ``{"mamba": {"conv_state": [num_slots, d_conv - 1, d_inner]``
     in the compute dtype (the convolution's last inputs, which are
     produced in it), ``"ssm_state": [num_slots, d_state, d_inner]``
@@ -131,8 +161,8 @@ def cache_leaves(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
   """
   kinds = layer_kinds(cfg)
   if ATTENTION in kinds or LATENT in kinds:
-    kv = jax.ShapeDtypeStruct(
-        (num_slots, cache_length(cfg, chunk)) + kv_heads(cfg), cfg.dtype)
+    kv = jax.ShapeDtypeStruct(kv_leaf_shape(cfg, num_slots, chunk),
+                              cfg.dtype)
   out = {}
   for i, kind in enumerate(kinds):
     if kind == ATTENTION:
@@ -150,10 +180,13 @@ def cache_leaves(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
   return out
 
 
-def kv_spec() -> P:
-  """PartitionSpec of one K/V leaf ``[num_slots, Lc, H, hd]``: heads
-  over the TP axis, slots/positions replicated."""
-  return P(None, None, constants.MODEL_AXIS, None)
+def kv_spec(rank: int = 4) -> P:
+  """PartitionSpec of one K/V leaf: heads over the TP axis, slots and
+  positions replicated.  Heads are dimension 2 of ``[num_slots, Lc, H,
+  hd]`` and, head-major, the whole of dimension 2 of a leaf kept in rows
+  (``rank`` 3), so the split over ``model`` cuts whole heads either
+  way."""
+  return P(*((None, None, constants.MODEL_AXIS) + (None,) * (rank - 3)))
 
 
 def kv_cache_shardings(cfg, mesh: Optional[Mesh]):
@@ -162,23 +195,28 @@ def kv_cache_shardings(cfg, mesh: Optional[Mesh]):
 
   K/V heads shard over ``model`` only when the cache's head count
   actually divides the axis; otherwise the leaf is replicated (a 1-sized
-  or absent model axis degrades to replication anyway).  Recurrent state
-  is replicated: a sharded state is not built.
+  or absent model axis degrades to replication anyway).  K/V is told from
+  everything else by its key (``attn``), not its rank (a leaf kept in
+  rows has the rank recurrent state has).  Recurrent state is replicated:
+  a sharded state is not built; so is a latent leaf (one head).
   """
   if mesh is None:
     return None, None
   sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
   tp = sizes.get(constants.MODEL_AXIS, 1)
-  heads = NamedSharding(
-      mesh, kv_spec() if tp > 1 and kv_heads(cfg)[0] % tp == 0 else P())
+  split = tp > 1 and kv_heads(cfg)[0] % tp == 0
   rep = NamedSharding(mesh, P())
-  kv = jax.tree_util.tree_map(
-      lambda leaf: heads if len(leaf.shape) == 4 else rep,
-      cache_leaves(cfg, 1, 1))
+
+  def place(path, leaf):
+    if split and any(getattr(k, "key", None) == "attn" for k in path):
+      return NamedSharding(mesh, kv_spec(len(leaf.shape)))
+    return rep
+
+  kv = jax.tree_util.tree_map_with_path(place, cache_leaves(cfg, 1, 1))
   return kv, rep
 
 
-def _has_rows(cfg) -> bool:
+def _under_cursor(cfg) -> bool:
   """Whether some layer keeps rows under a cursor (a K/V pair or a latent
   leaf): what the window write and the attend work on."""
   kinds = layer_kinds(cfg)
@@ -197,11 +235,10 @@ def kv_write_impl(cfg, num_slots: int, chunk: int,
   kernel in its one-leaf form."""
   from easyparallellibrary_tpu.kernels.kv_write import (
       resolve_kv_write_impl)
-  if not _has_rows(cfg):
+  if not _under_cursor(cfg):
     return None
-  shape = (num_slots, cache_length(cfg, chunk)) + kv_heads(cfg)
   return resolve_kv_write_impl(
-      shape, cfg.dtype, chunk,
+      kv_leaf_shape(cfg, num_slots, chunk), cfg.dtype, chunk,
       sharded=mesh is not None and mesh.size > 1)
 
 
@@ -215,12 +252,12 @@ def slot_attn_impl(cfg, num_slots: int, chunk: int,
   an attention layer."""
   from easyparallellibrary_tpu.kernels.slot_attention import (
       resolve_slot_attn_impl)
-  if not _has_rows(cfg):
+  if not _under_cursor(cfg):
     return None
-  shape = (num_slots, cache_length(cfg, chunk)) + kv_heads(cfg)
   return resolve_slot_attn_impl(
-      shape, cfg.dtype, chunk, cfg.num_heads,
-      sharded=mesh is not None and mesh.size > 1)
+      kv_leaf_shape(cfg, num_slots, chunk), cfg.dtype, chunk, cfg.num_heads,
+      sharded=mesh is not None and mesh.size > 1,
+      head_dim=kv_heads(cfg)[1])
 
 
 def ssm_scan_impl(cfg, num_slots: int, chunk: int,
@@ -295,11 +332,15 @@ def allocate_kv_cache(cfg, num_slots: int, chunk: int,
   return jax.jit(build, out_shardings=(kv_shardings, cur_sharding))()
 
 
-def cache_layout(cfg, num_slots: int, chunk: int) -> Dict[str, int]:
+def cache_layout(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
   """What the slot cache holds, by kind of state: bytes and leaves of
   K/V (under a cursor), of recurrent state (no position axis) and, for a
-  model that has them, of latent rows (under a cursor, one leaf a layer).
-  The engine records it (trace metadata ``serving/cache_layout``)."""
+  model that has them, of latent rows (under a cursor, one leaf a layer);
+  and ``kv_order``, the order the leaves under a cursor are kept in
+  (``"rows"`` or ``"positions"``: module docstring, order note; ``None``
+  for a model that keeps none), which says which form of the window write
+  and of the attend a step runs.  The engine records it (trace metadata
+  ``serving/cache_layout``)."""
   names = {ATTENTION: "kv", MAMBA: "state", LATENT: "latent"}
   kinds = layer_kinds(cfg)
   out = {f"{name}_{what}": 0
@@ -311,6 +352,9 @@ def cache_layout(cfg, num_slots: int, chunk: int) -> Dict[str, int]:
       out[f"{names[kind]}_bytes"] += (int(np.prod(leaf.shape))
                                       * jnp.dtype(leaf.dtype).itemsize)
       out[f"{names[kind]}_leaves"] += 1
+  out["kv_order"] = None if not _under_cursor(cfg) else (
+      "rows" if len(kv_leaf_shape(cfg, num_slots, chunk)) == 3
+      else "positions")
   return out
 
 
@@ -361,7 +405,8 @@ def allocate_paged_kv_cache(cfg, num_blocks: int, block_size: int,
   Returns the ``"cache"``-collection pytree GPT's paged decode
   reads/writes: ``{"block_i": {"attn": {"cached_key"/"cached_value":
   [num_blocks, block_size, H, hd]}}}``.  Heads sit at the same axis
-  index as the slot layout, so :func:`kv_cache_shardings` serves both.
+  index as in the slot layout of either order (dimension 2, head-major),
+  so :func:`kv_cache_shardings` serves both.
   Block ``NULL_BLOCK`` is the reserved trash block (module constant).
   """
   mb = blocks_per_slot(cfg, block_size)

@@ -454,6 +454,7 @@ def test_the_engine_says_what_it_holds_and_counts_what_it_routed(both):
   assert meta["serving/slot_attn_impl"] == {"impl": "reference"}
   assert meta["serving/cache_layout"] == eng.cache_layout
   assert meta["serving/cache_layout"]["latent_leaves"] == 3
+  assert meta["serving/cache_layout"]["kv_order"] == "positions"
   counters = lambda name: [ev["args"]["value"] for ev in events
                            if ev["ph"] == "C" and ev["name"] == name]
   routed = counters("serving/routed_positions")

@@ -462,9 +462,10 @@ def test_the_engine_says_what_it_holds_and_counts_resets(both):
 
 
 def test_a_gpt_engine_is_what_it_was():
-  """No recurrent state: no scan, no layout, no reset counter, and the
-  fused step is handed no state arguments (its greedy streams against
-  ``generate()`` are tests/test_serving.py's)."""
+  """No recurrent state: no scan, no reset counter, a layout that holds
+  K/V alone (every contiguous engine says what it holds and in which
+  order), and the fused step is handed no state arguments (its greedy
+  streams against ``generate()`` are tests/test_serving.py's)."""
   epl.init()
   cfg = GPTConfig(vocab_size=64, num_layers=2, num_heads=4, d_model=32,
                   d_ff=64, max_seq_len=48, dtype=jnp.float32)
@@ -481,10 +482,12 @@ def test_a_gpt_engine_is_what_it_was():
     names = {ev["name"] for ev in tracer.events()}
   finally:
     trace_lib.install(None)
-  assert eng.ssm_scan_impl is None and eng.cache_layout is None
-  assert "serving/kv_write_impl" in names
-  assert not names & {"serving/state_resets", "serving/ssm_scan_impl",
-                      "serving/cache_layout"}
+  assert eng.ssm_scan_impl is None
+  assert eng.cache_layout == {
+      "kv_bytes": 4 * 2 * 52 * 32 * 4, "kv_leaves": 4, "state_bytes": 0,
+      "state_leaves": 0, "kv_order": "positions"}
+  assert {"serving/kv_write_impl", "serving/cache_layout"} <= names
+  assert not names & {"serving/state_resets", "serving/ssm_scan_impl"}
   kv, _ = kv_lib.allocate_kv_cache(cfg, 2, 4)
   assert set(kv["block_0"]) == {"attn"}
   assert kv["block_0"]["attn"]["cached_key"].shape == (2, 52, 4, 8)

@@ -7,9 +7,13 @@ the WHOLE cache leaf bit-identical to ``vmap(dynamic_update_slice)``
 the kernel cannot tile, the engine commits the same tokens and ends on
 the same cursors under either lowering, and the program compiled for a
 described v5e holds the kernel in place of the scatter loop with no copy
-of a cache leaf.
+of a cache leaf.  Every case runs in both ORDERS a leaf is kept in
+(serving/kv_cache.py, order note): ``positions`` ``[B, Lc, H, hd]`` and
+``rows`` ``[B, Lc, H x hd]``, whose kernel also leaves an idle slot's
+window unwritten.
 """
 
+import dataclasses
 import importlib
 import re
 
@@ -28,9 +32,18 @@ from easyparallellibrary_tpu.serving import (
 from easyparallellibrary_tpu.serving.speculative import NgramDrafter
 
 kvw = importlib.import_module("easyparallellibrary_tpu.kernels.kv_write")
+sa = importlib.import_module(
+    "easyparallellibrary_tpu.kernels.slot_attention")
 
 MAX_SEQ, CHUNK = 1024, 16
 LC = MAX_SEQ + CHUNK           # 1040: the cell's leaf, 8 tiles and 16 rows
+
+
+ORDERS = ("positions", "rows")
+in_both_orders = pytest.mark.parametrize("order", ORDERS)
+# Heads of the toy leaves: 2 x 16 stays in positions, 2 x 64 = 128 fills
+# a lane tile and is kept in rows.
+HEADS = {"positions": (2, 16), "rows": (2, 64)}
 
 
 def _backend_takes(monkeypatch, impl):
@@ -43,9 +56,9 @@ def _bits(x):
   return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
 
 
-def _operands(B, Lc, H, hd, C, dtype, seed=0):
-  """Random leaves and chunks, salted with the values arithmetic would
-  not carry through unchanged."""
+def _operands(B, Lc, H, hd, C, dtype, seed=0, order="positions"):
+  """Random leaves (in ``order``) and chunks, salted with the values
+  arithmetic would not carry through unchanged."""
   r = np.random.RandomState(seed)
 
   def salted(shape):
@@ -55,7 +68,8 @@ def _operands(B, Lc, H, hd, C, dtype, seed=0):
         [-0.0, np.nan, np.inf, -np.inf], 6)
     return jnp.asarray(x, dtype)
 
-  return (salted((B, Lc, H, hd)), salted((B, Lc, H, hd)),
+  leaf = (B, Lc, H * hd) if order == "rows" else (B, Lc, H, hd)
+  return (salted(leaf), salted(leaf),
           salted((B, C, H, hd)), salted((B, C, H, hd)))
 
 
@@ -74,9 +88,14 @@ CURSORS = {
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 @pytest.mark.parametrize("case", sorted(CURSORS))
-def test_kernel_leaves_the_whole_leaf_bit_identical(case, dtype):
+@in_both_orders
+def test_kernel_leaves_the_whole_leaf_bit_identical(order, case, dtype):
+  """Odd and even cursors, windows inside one tile (stripe) and across
+  two, the last legal window; bfloat16 packs two rows into a sublane
+  word, which the rows form moves through float32."""
   cur = jnp.asarray(CURSORS[case], jnp.int32)
-  ck, cv, k, v = _operands(len(CURSORS[case]), LC, 2, 16, CHUNK, dtype)
+  ck, cv, k, v = _operands(len(CURSORS[case]), LC, *HEADS[order], CHUNK,
+                           dtype, order=order)
   assert kvw.kv_write_fits(ck.shape, dtype, CHUNK)
   want_k, want_v = kvw.kv_write_reference(ck, cv, k, v, cur)
   got_k, got_v = jax.jit(
@@ -92,9 +111,11 @@ def test_kernel_leaves_the_whole_leaf_bit_identical(case, dtype):
     (32, 288, [0, 97, 127, 200, 256]),  # a wider chunk straddles earlier
     (128, 384, [0, 1, 127, 128, 256]),  # a window as wide as a tile
 ], ids=["decode_1", "chunk_32", "chunk_128"])
-def test_other_chunk_widths_are_bit_identical(C, Lc, cursors):
+@in_both_orders
+def test_other_chunk_widths_are_bit_identical(order, C, Lc, cursors):
   cur = jnp.asarray(cursors, jnp.int32)
-  ck, cv, k, v = _operands(len(cursors), Lc, 2, 16, C, jnp.bfloat16, seed=1)
+  ck, cv, k, v = _operands(len(cursors), Lc, *HEADS[order], C, jnp.bfloat16,
+                           seed=1, order=order)
   assert kvw.kv_write_fits(ck.shape, ck.dtype, C)
   want = kvw.kv_write_reference(ck, cv, k, v, cur)
   got = kvw.kv_write(ck, cv, k, v, cur, impl="interpret")
@@ -110,7 +131,16 @@ def test_other_chunk_widths_are_bit_identical(C, Lc, cursors):
                                                    # proven on
     ((4, 272, 64, 128), jnp.float32, 16, False),   # tiles over the VMEM
     ((4, 272, 2, 16), jnp.float32, 16, True),      # leaf spread over chips
-], ids=["short_leaf", "wide_chunk", "odd_hd", "f16", "vmem", "sharded"])
+    # kept in rows
+    ((4, 12, 128), jnp.bfloat16, 4, False),        # under one stripe
+    ((4, 1200, 128), jnp.float32, 160, False),     # window over 128 rows
+    ((4, 272, 96), jnp.float32, 16, False),        # W not whole lane tiles
+    ((4, 272, 128), jnp.float16, 16, False),       # a dtype not proven
+    ((4, 272, 16384), jnp.float32, 128, False),    # stripes over the VMEM
+    ((4, 272, 128), jnp.float32, 16, True),        # leaf spread over chips
+], ids=["short_leaf", "wide_chunk", "odd_hd", "f16", "vmem", "sharded",
+        "rows_short_leaf", "rows_wide_chunk", "rows_odd_width", "rows_f16",
+        "rows_vmem", "rows_sharded"])
 def test_what_the_kernel_declines_takes_the_reference(
     monkeypatch, shape, dtype, chunk, sharded):
   for impl in ("interpret", "pallas"):
@@ -119,8 +149,11 @@ def test_what_the_kernel_declines_takes_the_reference(
         "reference"
 
 
-def test_rule_follows_the_backend(monkeypatch):
-  shape = (4, LC, 2, 16)
+@in_both_orders
+def test_rule_follows_the_backend(monkeypatch, order):
+  ck, cv, k, v = _operands(4, LC, *HEADS[order], CHUNK, jnp.bfloat16,
+                           order=order)
+  shape = ck.shape
   assert kvw.resolve_kv_write_impl(shape, jnp.bfloat16, CHUNK) == \
       "reference"                      # this backend is the CPU
   monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -128,18 +161,20 @@ def test_rule_follows_the_backend(monkeypatch):
   assert kvw.resolve_kv_write_impl(shape, jnp.bfloat16, CHUNK,
                                    sharded=True) == "reference"
   # A typo'd impl must not fall through to the kernel.
-  ck, cv, k, v = _operands(*shape, CHUNK, jnp.bfloat16)
   with pytest.raises(ValueError, match="impl must be one of"):
     kvw.kv_write(ck, cv, k, v, jnp.zeros((4,), jnp.int32), impl="mosaic")
 
 
+@in_both_orders
 def test_declined_shape_runs_the_reference_through_the_dispatcher(
-    monkeypatch):
-  """A backend that takes the kernel, a leaf shorter than a tile:
-  ``slot_cache_attend`` (impl unresolved, as ``generate()`` calls it)
-  must come out equal to the reference write, not fail in the kernel."""
+    monkeypatch, order):
+  """A backend that takes the kernel, a leaf shorter than a tile (in rows:
+  one whose width is no whole lane tile, which ``cache_leaves`` would not
+  fold but the reference must still take): ``slot_cache_attend`` (impl
+  unresolved, as ``generate()`` calls it) must come out equal to the
+  reference write, not fail in the kernel."""
   _backend_takes(monkeypatch, "interpret")
-  ck, cv, k, v = _operands(3, 36, 2, 16, 4, jnp.float32)
+  ck, cv, k, v = _operands(3, 36, 2, 16, 4, jnp.float32, order=order)
   cur = jnp.asarray([0, 7, 32], jnp.int32)
   _, got_k, got_v = slot_cache_attend(k, k, v, ck, cv, cur, jnp.float32)
   want_k, want_v = kvw.kv_write_reference(ck, cv, k, v, cur)
@@ -151,16 +186,20 @@ def test_declined_shape_runs_the_reference_through_the_dispatcher(
 
 SERVE = GPTConfig(vocab_size=64, num_layers=2, num_heads=2, d_model=32,
                   d_ff=64, max_seq_len=256, dtype=jnp.float32)
+# The same cut with heads that fill a lane tile (2 x 64 = 128): its
+# leaves are kept in rows (the tier-1 cuts are narrower and never fold).
+SERVE_IN = {"positions": SERVE,
+            "rows": dataclasses.replace(SERVE, d_model=128, d_ff=256)}
 
 
-def _serve(monkeypatch, impl, drafter=None):
+def _serve(monkeypatch, impl, drafter=None, order="positions"):
   """Five greedy requests over three slots, prompts long enough that
   prefill chunks and decode tokens share steps and that decode cursors
   walk through a tile boundary one row at a time."""
   _backend_takes(monkeypatch, impl)
   tracer = trace_lib.install(trace_lib.Tracer(enabled=True))
   try:
-    model = GPT(SERVE)
+    model = GPT(SERVE_IN[order])
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, 4), jnp.int32))["params"]
     eng = ContinuousBatchingEngine(model, params, num_slots=3,
@@ -182,13 +221,16 @@ def _serve(monkeypatch, impl, drafter=None):
 
 @pytest.mark.parametrize("drafter", [None, "ngram"],
                          ids=["fused_step", "speculative_step"])
-def test_engine_commits_the_same_under_either_lowering(monkeypatch,
+@in_both_orders
+def test_engine_commits_the_same_under_either_lowering(monkeypatch, order,
                                                        drafter):
   epl.init()
   mk = lambda: NgramDrafter(k=3, ngram_max=3) if drafter else None
   eng_k, out_k, cur_k, facts_k, model, params, prompts = _serve(
-      monkeypatch, "interpret", mk())
-  eng_r, out_r, cur_r, facts_r, *_ = _serve(monkeypatch, "reference", mk())
+      monkeypatch, "interpret", mk(), order)
+  eng_r, out_r, cur_r, facts_r, *_ = _serve(monkeypatch, "reference", mk(),
+                                            order)
+  assert eng_k.cache_layout["kv_order"] == order
   # Which write each run timed is on record, not inferred.
   assert eng_k.kv_write_impl == "interpret"
   assert eng_r.kv_write_impl == "reference"
@@ -230,12 +272,148 @@ def test_trace_metadata_outlives_the_ring_and_clear():
                           and e["name"] != "serving/kv_write_impl"]
 
 
-def test_engine_on_a_mesh_of_chips_takes_the_reference(monkeypatch):
+@in_both_orders
+def test_engine_on_a_mesh_of_chips_takes_the_reference(monkeypatch, order):
   _backend_takes(monkeypatch, "interpret")
   epl.init(epl.Config({"cluster.mesh_shape": "data:4,model:2"}))
   mesh = epl.Env.get().cluster.build_mesh()
-  assert kv_lib.kv_write_impl(SERVE, 3, 8, mesh) == "reference"
-  assert kv_lib.kv_write_impl(SERVE, 3, 8, None) == "interpret"
+  assert kv_lib.kv_write_impl(SERVE_IN[order], 3, 8, mesh) == "reference"
+  assert kv_lib.kv_write_impl(SERVE_IN[order], 3, 8, None) == "interpret"
+
+
+# ------------------------------------------------- the order, and who reads it
+
+
+def test_idle_slots_are_left_unwritten_by_the_rows_form():
+  """``num_valid == 0``: the rows kernel visits fed slots only, so an
+  idle slot's leaf is bit for bit what it was, a fed slot's what the
+  reference makes it; with no slot fed at all nothing moves (the grid's
+  one row writes its stripe back as it was).  The positions form, which
+  does not read ``num_valid``, writes every window as it always did."""
+  cur = jnp.asarray([5, 100, 0, 1024, 17, 640], jnp.int32)
+  nv = jnp.asarray([0, 16, 0, 3, 1, 0], jnp.int32)
+  fed = np.asarray(nv) > 0
+  for dtype in (jnp.bfloat16, jnp.float32):
+    ck, cv, k, v = _operands(6, LC, 2, 64, CHUNK, dtype, order="rows")
+    want = kvw.kv_write_reference(ck, cv, k, v, cur)
+    got = kvw.kv_write(ck, cv, k, v, cur, nv, impl="interpret")
+    for g, w, old in zip(got, want, (ck, cv)):
+      np.testing.assert_array_equal(_bits(g)[fed], _bits(w)[fed])
+      np.testing.assert_array_equal(_bits(g)[~fed], _bits(old)[~fed])
+    none = kvw.kv_write(ck, cv, k, v, cur, jnp.zeros_like(nv),
+                        impl="interpret")
+    np.testing.assert_array_equal(_bits(none[0]), _bits(ck))
+    np.testing.assert_array_equal(_bits(none[1]), _bits(cv))
+  ck, cv, k, v = _operands(6, LC, 2, 16, CHUNK, jnp.float32)
+  got = kvw.kv_write(ck, cv, k, v, cur, nv, impl="interpret")
+  want = kvw.kv_write_reference(ck, cv, k, v, cur)
+  np.testing.assert_array_equal(_bits(got[0]), _bits(want[0]))
+
+
+def test_cache_leaves_fold_where_the_heads_fill_the_lanes():
+  """One rule on the shape: GPT-2 medium (16 x 64 = 1024), GPT-2 large
+  (20 x 64 = 1280) and the hybrid (one K/V head of 128) are kept in rows,
+  the same bytes as before; a narrow cut, a dtype the kernels were not
+  proven on and the 576-wide latent leaf (4.5 lane tiles) stay as they
+  were.  ``cache_layout`` says which."""
+  from easyparallellibrary_tpu.models.glm_moe import GlmMoeConfig
+  from easyparallellibrary_tpu.models.jamba import JambaConfig
+  gpt = lambda **kw: GPTConfig(vocab_size=64, max_seq_len=1024,
+                               dtype=jnp.bfloat16, **kw)
+  cases = [
+      (gpt(num_layers=2, num_heads=16, d_model=1024, d_ff=64), 96, 16,
+       (96, 1040, 1024), "rows"),
+      (gpt(num_layers=2, num_heads=20, d_model=1280, d_ff=64), 4, 16,
+       (4, 1040, 1280), "rows"),
+      (JambaConfig(num_layers=8, max_seq_len=8192), 128, 8,
+       (128, 8200, 128), "rows"),
+      (gpt(num_layers=2, num_heads=2, d_model=32, d_ff=64), 4, 16,
+       (4, 1040, 2, 16), "positions"),
+      (dataclasses.replace(gpt(num_layers=2, num_heads=16, d_model=1024,
+                               d_ff=64), dtype=jnp.float16), 4, 16,
+       (4, 1040, 16, 64), "positions"),
+      (GlmMoeConfig(num_layers=2, vocab_size=64, max_seq_len=4096), 96, 8,
+       (96, 4104, 1, 576), "positions"),
+  ]
+  for cfg, slots, chunk, shape, order in cases:
+    assert kv_lib.kv_leaf_shape(cfg, slots, chunk) == shape
+    leaves = kv_lib.cache_leaves(cfg, slots, chunk)
+    under_cursor = [l for path, l in
+                    jax.tree_util.tree_leaves_with_path(leaves)
+                    if path[1].key in ("attn", "latent")]
+    assert under_cursor and {l.shape for l in under_cursor} == {shape}
+    layout = kv_lib.cache_layout(cfg, slots, chunk)
+    assert layout["kv_order"] == order
+    size = jnp.dtype(cfg.dtype).itemsize
+    assert layout["kv_bytes" if "latent_bytes" not in layout
+                  else "latent_bytes"] == (
+        len(under_cursor) * int(np.prod(shape)) * size)
+    assert kv_lib.cache_bytes(cfg, slots, chunk) == sum(
+        int(np.prod(l.shape)) * jnp.dtype(l.dtype).itemsize
+        for l in jax.tree_util.tree_leaves(leaves))
+
+
+def test_shardings_tell_kv_by_its_key_and_split_a_folded_leaf_over_model():
+  """A leaf kept in rows has the rank recurrent state has: K/V is told by
+  its key (``attn``).  On ``model:2`` the folded leaf splits its minor
+  dimension (head-major: whole heads), a leaf in positions its heads, and
+  recurrent state and a one-head leaf stay replicated."""
+  from jax.sharding import PartitionSpec as P
+  from easyparallellibrary_tpu.models.jamba import JambaConfig
+  epl.init(epl.Config({"cluster.mesh_shape": "data:4,model:2"}))
+  mesh = epl.Env.get().cluster.build_mesh()
+  spec_of = lambda cfg: {
+      path[-1].key: sh.spec for path, sh in
+      jax.tree_util.tree_leaves_with_path(
+          kv_lib.kv_cache_shardings(cfg, mesh)[0])}
+  rows = spec_of(SERVE_IN["rows"])
+  assert rows == {"cached_key": P(None, None, "model"),
+                  "cached_value": P(None, None, "model")}
+  assert spec_of(SERVE) == {"cached_key": P(None, None, "model", None),
+                            "cached_value": P(None, None, "model", None)}
+  # The hybrid's one K/V head does not divide the axis; its recurrent
+  # state never splits.  Two K/V heads of 64 do, state still does not.
+  hybrid = JambaConfig(num_layers=8, d_model=512, num_heads=4,
+                       num_kv_heads=1, d_ff=64, mamba_dt_rank=4,
+                       vocab_size=64, max_seq_len=128)
+  assert len(kv_lib.kv_leaf_shape(hybrid, 2, 4)) == 3
+  assert set(spec_of(hybrid).values()) == {P()}
+  wide = dataclasses.replace(hybrid, d_model=256, num_kv_heads=2)
+  got = spec_of(wide)
+  assert got["cached_key"] == got["cached_value"] == P(None, None, "model")
+  assert got["conv_state"] == got["ssm_state"] == P()
+  kv, _ = kv_lib.allocate_kv_cache(wide, 2, 4, mesh)
+  placed = {path[-1].key: x.sharding.spec for path, x in
+            jax.tree_util.tree_leaves_with_path(kv)}
+  assert placed == got
+
+
+def test_sanitize_zeroes_the_same_rows_in_both_orders():
+  """The guarded engine's sanitize program follows the leaf's rank: the
+  masked slots' rows from their start row up are zeros, everything else
+  is untouched, in a cache kept in rows as in one kept in positions."""
+  epl.init()
+  mask = np.asarray([True, False, True])
+  start = np.asarray([0, 5, 100], np.int32)
+  zeroed = {}
+  for order in ORDERS:
+    model = GPT(SERVE_IN[order])
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 4), jnp.int32))["params"]
+    eng = ContinuousBatchingEngine(model, params, num_slots=3,
+                                   prefill_chunk=8, resilience=True)
+    assert eng.cache_layout["kv_order"] == order
+    ones = jax.tree_util.tree_map(jnp.ones_like, eng._kv)
+    out = eng._sanitize_fn(ones, mask, start)
+    for leaf in jax.tree_util.tree_leaves(out):
+      x = np.asarray(leaf).reshape(leaf.shape[0], leaf.shape[1], -1)
+      rows = (x == 0).all(axis=-1)
+      assert ((x == 0) | (x == 1)).all() and (rows == (x == 0).any(-1)).all()
+      zeroed.setdefault(order, rows)
+      np.testing.assert_array_equal(rows, zeroed[order])
+  np.testing.assert_array_equal(zeroed["rows"], zeroed["positions"])
+  want = mask[:, None] & (np.arange(264)[None] >= start[:, None])
+  np.testing.assert_array_equal(zeroed["rows"], want)
 
 
 # --------------------------------------------------- compiled for the chip
@@ -267,63 +445,87 @@ def _compiled_text(jitted, *args) -> str:
     compilation_cache.reset_cache()
 
 
+def _leaf_lines(entry, *shapes):
+  """``(opcode, line)`` of the entry computation's instructions whose
+  result is an array of one of ``shapes`` (HLO shape prefixes)."""
+  for line in entry.splitlines():
+    m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) (\S+?)\(", line)
+    if m and m.group(2).startswith(shapes):
+      yield m.group(3), line
+
+
+@in_both_orders
 def test_compiled_for_v5e_holds_the_kernel_and_no_copy_of_a_leaf(
-    one_chip):
-  """``slot_cache_attend`` at the serving cells' shapes, compiled for a
-  described v5e: one ``kv_write`` custom call whose two leaf operands
-  alias its outputs, no ``while`` left of the scatter loop, and nothing
-  but the two attention fusions reads a whole leaf (no copy, no
-  relayout: the transposes around the call are bitcasts)."""
+    one_chip, order):
+  """``slot_cache_attend`` at the GPT-2 medium cells' shapes, in the order
+  the cells keep their leaves in (rows) and in the one they did
+  (positions), compiled for a described v5e: one ``kv_write`` custom call
+  whose two leaf operands alias its outputs, no ``while`` left of the
+  scatter loop, and no copy or relayout of a leaf.  In positions the
+  transposes around the call are bitcasts and the reference attend's two
+  fusions read the written leaves straight from the kernel; in rows the
+  attend is the kernel (the einsums would relay the whole leaf out of
+  rows: the two rules take and decline a leaf together)."""
   B, H, hd = 96, 16, 64
   dt = jnp.bfloat16
   spec = lambda shape, d=dt: jax.ShapeDtypeStruct(shape, d,
                                                   sharding=one_chip)
-  new, leaf = spec((B, CHUNK, H, hd)), spec((B, LC, H, hd))
+  shape = (B, LC, H * hd) if order == "rows" else (B, LC, H, hd)
+  new, leaf = spec((B, CHUNK, H, hd)), spec(shape)
   fn = lambda q, k, v, ck, cv, cur: slot_cache_attend(
-      q, k, v, ck, cv, cur, dt, write_impl="pallas")
+      q, k, v, ck, cv, cur, dt, write_impl="pallas",
+      attn_impl="pallas" if order == "rows" else "reference")
   text = _compiled_text(jax.jit(fn, donate_argnums=(3, 4)),
                         new, new, new, leaf, leaf, spec((B,), jnp.int32))
   entry = text[text.index("\nENTRY "):]
   calls = [l for l in entry.splitlines() if " custom-call(" in l
            and "kv_write" in l.split("=")[0]]
   assert len(calls) == 1, entry
+  # The leaves follow the scalar-prefetch vectors and the two chunks:
+  # the cursors in positions; in rows the grid's dynamic bound (the
+  # number of fed slots) and four vectors.
+  first = 7 if order == "rows" else 3
   aliasing = re.search(r"output_to_operand_aliasing=\{(.*?)\}, ", calls[0])
-  assert aliasing and "{0}: (3, {})" in aliasing.group(1) \
-      and "{1}: (4, {})" in aliasing.group(1), calls[0]
+  assert aliasing and f"{{0}}: ({first}, {{}})" in aliasing.group(1) \
+      and f"{{1}}: ({first + 1}, {{}})" in aliasing.group(1), calls[0]
   assert " while(" not in text
   # Instructions of the entry computation whose result is a whole leaf
-  # (in either order of its dimensions): parameters, bitcasts and the
+  # (in any order of its dimensions): parameters, bitcasts and the
   # kernel's own results only.
-  leaf_shapes = (f"bf16[{B},{LC},{H},{hd}]", f"bf16[{B},{H},{hd},{LC}]")
-  for line in entry.splitlines():
-    m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) (\S+?)\(", line)
-    if not m or not m.group(2).startswith(leaf_shapes):
-      continue
-    assert m.group(3) in ("parameter", "bitcast", "get-tuple-element"), line
-  # The two attention fusions take the written leaves straight from the
-  # kernel, through a bitcast.
-  readers = [l for l in entry.splitlines() if " fusion(" in l
-             and "kind=kOutput" in l]
-  assert len(readers) == 2, entry
+  for opcode, line in _leaf_lines(
+      entry, f"bf16[{B},{LC},{H},{hd}]", f"bf16[{B},{H},{hd},{LC}]",
+      f"bf16[{B},{LC},{H * hd}]"):
+    assert opcode in ("parameter", "bitcast", "get-tuple-element"), line
+  if order == "positions":
+    # The two attention fusions take the written leaves straight from
+    # the kernel, through a bitcast.
+    readers = [l for l in entry.splitlines() if " fusion(" in l
+               and "kind=kOutput" in l]
+    assert len(readers) == 2, entry
 
 
 @pytest.mark.parametrize("B,C,H,Hkv,hd,Lc", [
     (96, CHUNK, 16, 16, 64, LC),        # the GPT-2 medium cells
     (128, 8, 20, 1, 128, 8200),         # the hybrid cell: grouped heads
 ], ids=["gpt2m_cells", "hybrid_cell"])
+@in_both_orders
 def test_compiled_for_v5e_attends_in_one_kernel_with_no_score_tensor(
-    one_chip, B, C, H, Hkv, hd, Lc):
+    one_chip, order, B, C, H, Hkv, hd, Lc):
   """``slot_cache_attend`` with both kernels (kernels/slot_attention.py
   beside the write), compiled for a described v5e at the serving cells'
   shapes: one ``kv_write`` and one ``slot_attn`` custom call, no score
-  tensor ``[B, H, C, Lc]`` in any form, and no instruction but the
-  kernels, parameters and bitcasts yields a whole leaf in the view the
-  kernels share (for GPT-2's position-minor leaf: none in any order, so
-  no copy of a leaf at all)."""
+  tensor ``[B, H, C, Lc]`` in any form.  Kept in ROWS, as the cells keep
+  them, no instruction but the kernels, parameters and bitcasts yields a
+  whole leaf in any order of its dimensions: no copy and no transpose of
+  a leaf, GPT-2's or the hybrid's.  Kept in POSITIONS (what both were):
+  none for GPT-2's position-minor leaf, and the hybrid's hd-minor leaf
+  is copied into the view the kernels share and back, which is what
+  keeping it in rows took away."""
   dt = jnp.bfloat16
   spec = lambda shape, d=dt: jax.ShapeDtypeStruct(shape, d,
                                                   sharding=one_chip)
-  new, leaf = spec((B, C, Hkv, hd)), spec((B, Lc, Hkv, hd))
+  shape = (B, Lc, Hkv * hd) if order == "rows" else (B, Lc, Hkv, hd)
+  new, leaf = spec((B, C, Hkv, hd)), spec(shape)
   fn = lambda q, k, v, ck, cv, cur, nv: slot_cache_attend(
       q, k, v, ck, cv, cur, dt, write_impl="pallas", attn_impl="pallas",
       num_valid=nv)
@@ -338,19 +540,16 @@ def test_compiled_for_v5e_attends_in_one_kernel_with_no_score_tensor(
   assert not re.search(rf"\[{B},(?:{Hkv},{H // Hkv}|{H}),{C},{Lc}\]", text)
   minor = f"bf16[{B},{Hkv},{hd},{Lc}]"
   major = f"bf16[{B},{Lc},{Hkv},{hd}]"
-  # GPT-2's leaf is position-minor already: nothing may copy it in
-  # either order.  The hybrid's hd-minor leaf is copied into the shared
-  # view and back, counted below.
-  views = (minor, major) if hd < 128 else (minor,)
+  folded = f"bf16[{B},{Lc},{Hkv * hd}]"
+  # The hybrid's hd-minor leaf in positions is copied into the shared
+  # view and back, counted below; nothing else may copy a leaf.
+  relaid = order == "positions" and hd == 128
+  views = (minor,) if relaid else (minor, major, folded)
   allowed = {"parameter", "bitcast", "get-tuple-element"} | (
-      {"copy"} if hd == 128 else set())
-  for line in entry.splitlines():
-    m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) (\S+?)\(", line)
-    if not m:
-      continue
-    if m.group(2).startswith(views):
-      assert m.group(3) in allowed, line
-  if hd == 128:
+      {"copy"} if relaid else set())
+  for opcode, line in _leaf_lines(entry, *views):
+    assert opcode in allowed, line
+  if relaid:
     # An hd-minor leaf is relaid to position-minor for the write and
     # back (PR 26); the attend reads that view and adds no relayout.
     copies = [l for l in entry.splitlines() if re.match(
@@ -360,8 +559,9 @@ def test_compiled_for_v5e_attends_in_one_kernel_with_no_score_tensor(
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
+@in_both_orders
 def test_slot_attn_compiles_for_v5e_under_a_highest_precision_context(
-    one_chip, dtype):
+    one_chip, order, dtype):
   """A caller's ``jax.default_matmul_precision("highest")`` (the chip
   smoke's float32 cut runs under one) reaches the kernel's two
   contractions when it is traced: Mosaic takes it for float32 operands
@@ -372,12 +572,13 @@ def test_slot_attn_compiles_for_v5e_under_a_highest_precision_context(
   B, C, H, hd = 8, CHUNK, 16, 64
   spec = lambda shape, d=dtype: jax.ShapeDtypeStruct(shape, d,
                                                      sharding=one_chip)
+  leaf = spec((B, LC, H * hd) if order == "rows" else (B, LC, H, hd))
   with jax.default_matmul_precision("highest"):
     # a fresh function: the jitted wrapper's own trace cache is keyed on
     # shapes, not on the context
     text = _compiled_text(
         jax.jit(lambda *a: slot_attention_pallas.__wrapped__(*a)),
-        spec((B, C, H, hd)), spec((B, LC, H, hd)), spec((B, LC, H, hd)),
+        spec((B, C, H, hd)), leaf, leaf,
         spec((B,), jnp.int32), spec((B,), jnp.int32))
   assert "slot_attn" in text
 

@@ -328,9 +328,14 @@ def test_slow_reader_overflow_sheds_only_its_flow():
   model, params = _model_and_params()
   prompts = _prompts((6, 5), seed=7)
   oracle = _oracle(model, params, prompts[1], 8)
+  # The front door's own bound stays at its default: the NEIGHBOUR's
+  # reader is a real client thread, and under a loaded machine a bound of
+  # 2 batches sheds it too (it then never streamed bit-exactly, and
+  # before the shed path kicked a cycle, never got its ``done`` event:
+  # the intermittent hang of ROADMAP D9).  Only the stuck flow is bounded
+  # at 2, by hand.
   router = Router(model, params, num_replicas=1, num_slots=2,
-                  prefill_chunk=4,
-                  config=_config(reactor=True, stream_buffer=2))
+                  prefill_chunk=4, config=_config(reactor=True))
   with FrontDoor(router) as fd:
     # An infinitely slow reader, as the server sees one: its stream
     # state exists but nothing ever drains the queue.

@@ -7,9 +7,11 @@ kernel (interpreted here, as ``tests/test_kv_write.py`` runs the write)
 equals the einsum reference to rounding for every valid query, gives
 zeros for what it does not compute (idle slots, the invalid tail of a
 chunk), lets nothing at or beyond a slot's bound reach its output, the
-dispatch rule declines what the kernel cannot tile, and an engine built
-on it commits the same greedy tokens as one built on the reference and
-compiles once.
+dispatch rule declines what the kernel cannot tile (an engine built on it
+is tests/test_slot_attention_engine.py's: a file of its own, so that the
+two halves run on two workers).  Every case runs in both ORDERS a leaf is kept in
+(serving/kv_cache.py, order note): ``positions`` ``[B, Lc, H_kv, hd]`` and
+``rows`` ``[B, Lc, H_kv x hd]``.
 """
 
 import importlib
@@ -19,15 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import easyparallellibrary_tpu as epl
-from easyparallellibrary_tpu.models import GPT, GPTConfig
-from easyparallellibrary_tpu.models.gpt import generate, slot_cache_attend
-from easyparallellibrary_tpu.observability import trace as trace_lib
-from easyparallellibrary_tpu.profiler.serving import ServingStats
-from easyparallellibrary_tpu.serving import (
-    ContinuousBatchingEngine, Request, kv_cache as kv_lib)
-from easyparallellibrary_tpu.serving.speculative import (
-    DraftModelDrafter, NgramDrafter)
+from easyparallellibrary_tpu.models.gpt import slot_cache_attend
 
 sa = importlib.import_module(
     "easyparallellibrary_tpu.kernels.slot_attention")
@@ -35,6 +29,18 @@ kvw = importlib.import_module("easyparallellibrary_tpu.kernels.kv_write")
 
 LC = 1040                      # the cells' leaf: 1024 + a chunk of 16
 TOL = {jnp.float32: 2e-6, jnp.bfloat16: 2e-2}
+ORDERS = ("positions", "rows")
+in_both_orders = pytest.mark.parametrize("order", ORDERS)
+
+
+def _leaf(x, order):
+  """A ``[B, Lc, H_kv, hd]`` leaf in ``order``: heads folded into the
+  minor dimension for ``rows``."""
+  return x.reshape(x.shape[:2] + (-1,)) if order == "rows" else x
+
+
+def _shape(B, Lc, Hkv, hd, order):
+  return (B, Lc, Hkv * hd) if order == "rows" else (B, Lc, Hkv, hd)
 
 
 def _backend_takes(monkeypatch, impl):
@@ -57,14 +63,22 @@ def _cursors(C, block):
   return [0, 77, block - C, block, block - C // 2 - 1, LC - C]
 
 
-def _check(q, ck, cv, cur, nv, dtype, block=None, poison=False):
+def _check(q, ck, cv, cur, nv, dtype, block=None, poison=False,
+           order="positions"):
   """Kernel against reference on the rows ``nv`` says are real; zeros
   elsewhere.  ``poison`` plants NaN in every row at or beyond a slot's
-  bound (and in the whole of an idle slot) before the kernel reads."""
+  bound (and in the whole of an idle slot) before the kernel reads.  The
+  reference reads the leaf in ``order`` too: its two forms must agree."""
   B, C = q.shape[:2]
   cur, nv = np.asarray(cur, np.int32), np.asarray(nv, np.int32)
   want = sa.slot_attention_reference(
-      q, jnp.asarray(ck, dtype), jnp.asarray(cv, dtype), jnp.asarray(cur))
+      q, jnp.asarray(_leaf(ck, order), dtype),
+      jnp.asarray(_leaf(cv, order), dtype), jnp.asarray(cur))
+  if order == "rows":
+    np.testing.assert_array_equal(
+        np.asarray(want, np.float32), np.asarray(sa.slot_attention_reference(
+            q, jnp.asarray(ck, dtype), jnp.asarray(cv, dtype),
+            jnp.asarray(cur)), np.float32))
   if poison:
     ck, cv = ck.copy(), cv.copy()
     for b in range(B):
@@ -72,7 +86,8 @@ def _check(q, ck, cv, cur, nv, dtype, block=None, poison=False):
       ck[b, bound:] = np.nan
       cv[b, bound:] = np.nan
   got = sa.slot_attention_pallas(
-      q, jnp.asarray(ck, dtype), jnp.asarray(cv, dtype), jnp.asarray(cur),
+      q, jnp.asarray(_leaf(ck, order), dtype),
+      jnp.asarray(_leaf(cv, order), dtype), jnp.asarray(cur),
       jnp.asarray(nv), interpret=True, block=block)
   got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
   assert np.isfinite(got).all()
@@ -90,36 +105,45 @@ def _check(q, ck, cv, cur, nv, dtype, block=None, poison=False):
                          ids=["mha_hd64", "mha_hd128", "one_kv_head",
                               "grouped_hd64"])
 @pytest.mark.parametrize("C", [1, 8, 16])
-def test_kernel_equals_the_reference_to_rounding(C, heads, dtype):
+@in_both_orders
+def test_kernel_equals_the_reference_to_rounding(order, C, heads, dtype):
+  """Heads of 64 (two to a lane tile in rows: the stacked-query form)
+  and of 128 (a head is its own lanes), one K/V head under 20 query
+  heads (the hybrid) and groups of four."""
   H, Hkv, hd = heads
   block = 256
   cur = _cursors(C, block)
   q, ck, cv = _operands(len(cur), C, H, Hkv, hd, LC, dtype, seed=C)
-  assert sa.slot_attn_fits((len(cur), LC, Hkv, hd), dtype, C, H)
-  _check(q, ck, cv, cur, [C] * len(cur), dtype, block=block)
+  assert sa.slot_attn_fits(_shape(len(cur), LC, Hkv, hd, order), dtype, C,
+                           H, hd)
+  _check(q, ck, cv, cur, [C] * len(cur), dtype, block=block, order=order)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_the_rules_own_block_and_no_bound_given(dtype):
+@in_both_orders
+def test_the_rules_own_block_and_no_bound_given(order, dtype):
   """The block the rule picks for the leaf (512 KiB of K: 1024 of 1040
   rows in bfloat16, so the edge block holds 16), and ``num_valid=None``
   as ``generate()``'s decode calls it: every position real."""
   C, H, hd = 16, 4, 64
   cur = [0, 500, 1008, 1023, LC - C]
   q, ck, cv = _operands(len(cur), C, H, H, hd, LC, dtype, seed=3)
-  assert sa.block_positions((len(cur), LC, H, hd), dtype, C, H) == \
+  assert sa.block_positions(_shape(len(cur), LC, H, hd, order), dtype, C,
+                            H, hd) == \
       {jnp.float32: 512, jnp.bfloat16: 1024}[dtype]
-  got = _check(q, ck, cv, cur, [C] * len(cur), dtype)
+  got = _check(q, ck, cv, cur, [C] * len(cur), dtype, order=order)
   unbounded = sa.slot_attention_pallas(
-      q, jnp.asarray(ck, dtype), jnp.asarray(cv, dtype),
+      q, jnp.asarray(_leaf(ck, order), dtype),
+      jnp.asarray(_leaf(cv, order), dtype),
       jnp.asarray(cur, jnp.int32), interpret=True)
   np.testing.assert_array_equal(np.asarray(unbounded, np.float32), got)
 
 
 @pytest.mark.parametrize("heads", [(4, 4, 64), (20, 1, 128)],
                          ids=["mha", "one_kv_head"])
-def test_idle_slots_and_partial_chunks_come_out_zeros(heads):
+@in_both_orders
+def test_idle_slots_and_partial_chunks_come_out_zeros(order, heads):
   """``num_valid`` 0 (an idle slot, whatever its stale cursor says) and
   the tail of a partial chunk: zeros, and the live rows beside them
   unmoved — also when every slot before the first live one idles."""
@@ -128,17 +152,19 @@ def test_idle_slots_and_partial_chunks_come_out_zeros(heads):
   cur = [300, 0, 513, 255, 1000, 64, 900]
   nv = [0, 0, 3, 8, 1, 0, 5]
   q, ck, cv = _operands(len(cur), C, H, Hkv, hd, LC, jnp.float32, seed=5)
-  got = _check(q, ck, cv, cur, nv, jnp.float32, block=256)
+  got = _check(q, ck, cv, cur, nv, jnp.float32, block=256, order=order)
   assert (got[[0, 1, 5]] == 0).all() and (got[2, 3:] == 0).all()
   assert np.abs(got[3]).min() > 0
   # every slot idle: the grid's one row lands on an idle slot
-  assert (_check(q, ck, cv, cur, [0] * len(cur), jnp.float32) == 0).all()
+  assert (_check(q, ck, cv, cur, [0] * len(cur), jnp.float32,
+                 order=order) == 0).all()
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("block", [256, None], ids=["block_256", "ruled"])
-def test_nan_beyond_the_bound_does_not_reach_the_output(block, dtype):
+@in_both_orders
+def test_nan_beyond_the_bound_does_not_reach_the_output(order, block, dtype):
   """Rows at or beyond ``cursor + num_valid`` hold NaN — in the bound's
   own block, in later blocks, in the leaf's edge block, in idle slots:
   the output is the clean cache's."""
@@ -146,29 +172,42 @@ def test_nan_beyond_the_bound_does_not_reach_the_output(block, dtype):
   cur = [0, 100, 250, 256, 511, 700, 1024, 40]
   nv = [16, 1, 16, 7, 2, 0, 16, 0]
   q, ck, cv = _operands(len(cur), C, H, H, hd, LC, dtype, seed=7)
-  _check(q, ck, cv, cur, nv, dtype, block=block, poison=True)
+  _check(q, ck, cv, cur, nv, dtype, block=block, poison=True, order=order)
 
 
 # ---------------------------------------------------------------- the rule
 
 
-@pytest.mark.parametrize("shape,dtype,chunk,heads,sharded", [
-    ((4, 36, 2, 16), jnp.float32, 4, 2, False),      # a toy leaf
-    ((4, 120, 2, 16), jnp.float32, 4, 2, False),     # under one tile
-    ((4, 1200, 2, 16), jnp.float32, 160, 2, False),  # chunk over a tile
-    ((4, 272, 2, 12), jnp.float32, 16, 2, False),    # hd not whole sublanes
-    ((4, 272, 2, 16), jnp.float16, 16, 2, False),    # a dtype not proven
-    ((4, 272, 3, 16), jnp.float32, 16, 4, False),    # heads not in groups
-    ((4, 272, 64, 256), jnp.float32, 128, 64, False),  # over the VMEM
-    ((4, 272, 2, 16), jnp.float32, 16, 2, True),     # leaf spread over chips
+@pytest.mark.parametrize("shape,dtype,chunk,heads,sharded,hd", [
+    ((4, 36, 2, 16), jnp.float32, 4, 2, False, None),      # a toy leaf
+    ((4, 120, 2, 16), jnp.float32, 4, 2, False, None),     # under one tile
+    ((4, 1200, 2, 16), jnp.float32, 160, 2, False, None),  # chunk over a tile
+    ((4, 272, 2, 12), jnp.float32, 16, 2, False, None),    # hd not whole
+                                                           # sublanes
+    ((4, 272, 2, 16), jnp.float16, 16, 2, False, None),    # a dtype not proven
+    ((4, 272, 3, 16), jnp.float32, 16, 4, False, None),    # heads not in groups
+    ((4, 272, 64, 256), jnp.float32, 128, 64, False, None),  # over the VMEM
+    ((4, 272, 2, 16), jnp.float32, 16, 2, True, None),     # leaf spread over
+                                                           # chips
+    # kept in rows
+    ((4, 120, 128), jnp.float32, 4, 2, False, 64),       # under 128 rows
+    ((4, 1200, 128), jnp.float32, 160, 2, False, 64),    # chunk over 128
+    ((4, 272, 384), jnp.float32, 16, 4, False, 96),      # hd no part of a tile
+    ((4, 272, 384), jnp.float32, 16, 2, False, 192),     # nor whole tiles
+    ((4, 272, 256), jnp.float16, 16, 4, False, 64),      # a dtype not proven
+    ((4, 272, 384), jnp.float32, 16, 4, False, 128),     # heads not in groups
+    ((4, 272, 16384), jnp.float32, 128, 128, False, 128),  # over the VMEM
+    ((4, 272, 128), jnp.float32, 16, 2, True, 64),       # leaf spread over chips
 ], ids=["toy_leaf", "short_leaf", "wide_chunk", "odd_hd", "f16",
-        "odd_groups", "vmem", "sharded"])
+        "odd_groups", "vmem", "sharded", "rows_short_leaf", "rows_wide_chunk",
+        "rows_hd_96", "rows_hd_192", "rows_f16", "rows_odd_groups",
+        "rows_vmem", "rows_sharded"])
 def test_what_the_kernel_declines_takes_the_reference(
-    monkeypatch, shape, dtype, chunk, heads, sharded):
+    monkeypatch, shape, dtype, chunk, heads, sharded, hd):
   for impl in ("interpret", "pallas"):
     _backend_takes(monkeypatch, impl)
     assert sa.resolve_slot_attn_impl(shape, dtype, chunk, heads,
-                                     sharded) == "reference"
+                                     sharded, head_dim=hd) == "reference"
 
 
 @pytest.mark.parametrize("shape,chunk,heads,block", [
@@ -176,16 +215,21 @@ def test_what_the_kernel_declines_takes_the_reference(
     ((128, 8200, 1, 128), 8, 20, 2048),    # the hybrid cell
     ((8, 1024, 16, 64), 1, 16, 256),       # generate()'s decode
 ], ids=["gpt2m_cells", "hybrid_cell", "decode_1"])
+@in_both_orders
 def test_rule_follows_the_backend_and_sizes_the_block(
-    monkeypatch, shape, chunk, heads, block):
+    monkeypatch, order, shape, chunk, heads, block):
+  """The cells' leaves as they were kept (positions) and as they are
+  (rows: the same bytes a block, so the same blocks)."""
   dt = jnp.bfloat16
-  assert sa.resolve_slot_attn_impl(shape, dt, chunk, heads) == \
-      "reference"                      # this backend is the CPU
+  hd = shape[3]
+  shape = _shape(*shape, order)
+  rule = lambda **kw: sa.resolve_slot_attn_impl(shape, dt, chunk, heads,
+                                                head_dim=hd, **kw)
+  assert rule() == "reference"         # this backend is the CPU
   monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-  assert sa.resolve_slot_attn_impl(shape, dt, chunk, heads) == "pallas"
-  assert sa.resolve_slot_attn_impl(shape, dt, chunk, heads,
-                                   sharded=True) == "reference"
-  assert sa.block_positions(shape, dt, chunk, heads) == block
+  assert rule() == "pallas"
+  assert rule(sharded=True) == "reference"
+  assert sa.block_positions(shape, dt, chunk, heads, hd) == block
 
 
 def test_a_typo_is_refused_and_a_declined_shape_runs_the_reference(
@@ -204,14 +248,17 @@ def test_a_typo_is_refused_and_a_declined_shape_runs_the_reference(
   np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_slot_cache_attend_writes_then_reads_under_either_lowering():
+@in_both_orders
+def test_slot_cache_attend_writes_then_reads_under_either_lowering(order):
   """The whole of ``slot_cache_attend``: the chunk's own K/V are in the
   cache before the attend reads it, under both pairs of lowerings, and
-  the leaves come back bit-identical."""
-  C, H, hd = 8, 2, 16
+  the leaves come back bit-identical (every slot is fed: an idle slot's
+  window is the rows write's to leave, tests/test_kv_write.py)."""
+  C, H, hd = 8, 2, (64 if order == "rows" else 16)
   q, ck, cv = _operands(4, C, H, H, hd, 264, jnp.float32, seed=11)
   k = _operands(4, C, H, H, hd, 264, jnp.float32, seed=12)[0]
   v = _operands(4, C, H, H, hd, 264, jnp.float32, seed=13)[0]
+  ck, cv = _leaf(ck, order), _leaf(cv, order)
   cur = jnp.asarray([0, 120, 128, 250], jnp.int32)
   nv = jnp.asarray([8, 8, 3, 8], jnp.int32)
   run = lambda impl: slot_cache_attend(
@@ -224,127 +271,6 @@ def test_slot_cache_attend_writes_then_reads_under_either_lowering():
   real = (np.arange(C)[None] < np.asarray(nv)[:, None])[:, :, None, None]
   np.testing.assert_allclose(np.where(real, out_k, 0),
                              np.where(real, out_r, 0), atol=2e-6)
-
-
-# ------------------------------------------------------------------ engine
-
-SERVE = GPTConfig(vocab_size=64, num_layers=2, num_heads=2, d_model=32,
-                  d_ff=64, max_seq_len=256, dtype=jnp.float32)
-PROMPTS = (118, 3, 121, 40, 126)
-
-
-def _model():
-  model = GPT(SERVE)
-  params = model.init(jax.random.PRNGKey(0),
-                      jnp.zeros((1, 4), jnp.int32))["params"]
-  r = np.random.RandomState(0)
-  prompts = [r.randint(0, 64, (n,)).astype(np.int32) for n in PROMPTS]
-  return model, params, prompts
-
-
-def _serve(monkeypatch, impl, drafter=None):
-  """Five greedy requests over three slots: prefill chunks and decode
-  tokens share steps, decode cursors walk through a tile boundary one
-  row at a time, a slot idles while the others finish."""
-  _backend_takes(monkeypatch, impl)
-  tracer = trace_lib.install(trace_lib.Tracer(enabled=True))
-  try:
-    model, params, prompts = _model()
-    eng = ContinuousBatchingEngine(
-        model, params, num_slots=3, prefill_chunk=8,
-        drafter=drafter(model, params) if drafter else None,
-        stats=ServingStats())
-    for i, p in enumerate(prompts):
-      eng.submit(Request(uid=i, prompt=p, max_new_tokens=14))
-    out = eng.run()
-    return eng, {u: np.asarray(t) for u, t in out.items()}, tracer.events()
-  finally:
-    trace_lib.install(None)
-
-
-DRAFTERS = {
-    "fused_step": None,
-    "speculative_step": lambda model, params: NgramDrafter(k=3, ngram_max=3),
-    "draft_model": lambda model, params: DraftModelDrafter(model, params,
-                                                           k=2),
-}
-
-
-@pytest.mark.parametrize("drafter", sorted(DRAFTERS))
-def test_engine_commits_the_same_greedy_tokens_under_either_attend(
-    monkeypatch, drafter):
-  epl.init()
-  eng_k, out_k, events = _serve(monkeypatch, "interpret", DRAFTERS[drafter])
-  eng_r, out_r, _ = _serve(monkeypatch, "reference", DRAFTERS[drafter])
-  # Which attend each run timed is on record, not inferred.
-  assert eng_k.slot_attn_impl == "interpret"
-  assert eng_r.slot_attn_impl == "reference"
-  facts = [e["args"] for e in events
-           if e["ph"] == "M" and e["name"] == "serving/slot_attn_impl"]
-  assert facts == [{"impl": "interpret"}]
-  assert eng_k._capture_context()["serving"]["slot_attn_impl"] == \
-      "interpret"
-  assert sorted(out_k) == sorted(out_r) == list(range(len(PROMPTS)))
-  for uid in out_k:
-    np.testing.assert_array_equal(out_k[uid], out_r[uid])
-  # Each step compiled once under its lowering.
-  assert eng_k._step_fn._cache_size() == eng_r._step_fn._cache_size() == 1
-
-
-def test_greedy_idle_greedy_compiles_once_and_equals_generate(monkeypatch):
-  """Requests, a drained engine whose every slot idles, requests again:
-  one compile, and the kernel-built engine still equals the one-request
-  oracle (whose decode takes the kernel too, ``C = 1``, no bound)."""
-  epl.init()
-  _backend_takes(monkeypatch, "interpret")
-  model, params, prompts = _model()
-  eng = ContinuousBatchingEngine(model, params, num_slots=3,
-                                 prefill_chunk=8)
-  eng.submit(Request(uid=0, prompt=prompts[0], max_new_tokens=6))
-  first = eng.run()
-  assert eng.scheduler.num_active == 0
-  eng.submit(Request(uid=1, prompt=prompts[2], max_new_tokens=14))
-  eng.submit(Request(uid=2, prompt=prompts[1], max_new_tokens=4))
-  second = eng.run()
-  assert eng._step_fn._cache_size() == 1
-  assert eng._compile_sentinel.recompiles == 0
-  for uid, prompt, n in ((0, prompts[0], 6), (1, prompts[2], 14)):
-    got = np.asarray({**first, **second}[uid])
-    want = np.asarray(generate(model, params,
-                               jnp.asarray(prompt)[None], n))[0]
-    np.testing.assert_array_equal(got, want)
-
-
-def test_live_kv_rows_is_counted_every_step(monkeypatch):
-  """``serving/live_kv_rows`` beside ``serving/active_slots``: the sum
-  over the step's fed slots of cursor + num_valid, from the plan; its
-  share of the cache's rows in ``ServingStats.summary()``."""
-  epl.init()
-  eng, _, events = _serve(monkeypatch, "reference")
-  rows = [e["args"]["value"] for e in events
-          if e["ph"] == "C" and e["name"] == "serving/live_kv_rows"]
-  slots = [e for e in events
-           if e["ph"] == "C" and e["name"] == "serving/active_slots"]
-  assert len(rows) == len(slots) == eng._steps
-  # The first step feeds a chunk of 8, 3 and 8 tokens to three fresh
-  # slots; no step's bound passes what a request can hold.
-  assert rows[0] == 8 + 3 + 8
-  assert max(rows) <= 3 * (max(PROMPTS) + 14)
-  cap = 3 * kv_lib.cache_length(SERVE, 8)
-  assert eng.stats.summary()["kv_read_share"] == pytest.approx(
-      sum(rows) / (len(rows) * cap))
-  # The rows the device cursors say were fed, step by step: every token
-  # of every request, once.
-  fed = sum(n + 14 - 1 for n in PROMPTS)
-  assert sum(b - a for a, b in zip([0] + rows, rows) if b > a) <= fed * 3
-
-
-def test_engine_on_a_mesh_of_chips_takes_the_reference(monkeypatch):
-  _backend_takes(monkeypatch, "interpret")
-  epl.init(epl.Config({"cluster.mesh_shape": "data:4,model:2"}))
-  mesh = epl.Env.get().cluster.build_mesh()
-  assert kv_lib.slot_attn_impl(SERVE, 3, 8, mesh) == "reference"
-  assert kv_lib.slot_attn_impl(SERVE, 3, 8, None) == "interpret"
 
 
 # ------------------------------------------------------- the one-leaf form
