@@ -35,6 +35,16 @@ a user calls, and checks what comes out by the repo's own references:
                   step's logits against the reference lowerings, and the
                   served tokens against the teacher-forced full forward
                   (expanded attention, ``ragged_dot``)
+  lfm2            the decoder of gated short convolutions beside grouped
+                  attention with routed experts and no shared one
+                  (models/lfm2_moe.py, LFM2-8B-A1B's widths): ``kv_write``
+                  and ``slot_attn`` in rows at its cell's leaf ``[128,
+                  4112, 512]`` (8 K/V heads of 64 under 32 query heads:
+                  heads that share a lane tile AND are grouped), ``moe_gmm``
+                  at its two products over 32 experts (f32, bf16) against
+                  their reference lowerings; a three-layer cut (conv +
+                  dense, attention + experts, conv + experts) through the
+                  engine as for ``experts``
   four chips      (when the machine has four) the trainer as ``data:4``
                   and as ``data:2,model:2``
 
@@ -79,6 +89,7 @@ from easyparallellibrary_tpu.kernels.ssm_scan import (
 from easyparallellibrary_tpu.models import GPT, GPTConfig
 from easyparallellibrary_tpu.models.glm_moe import GlmMoe, GlmMoeConfig
 from easyparallellibrary_tpu.models.jamba import MAMBA, Jamba, JambaConfig
+from easyparallellibrary_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
 from easyparallellibrary_tpu.models.gpt import (
     _dense_causal_attention, generate, gpt_loss, make_gpt_train_step)
 from easyparallellibrary_tpu.observability.device import specs_of
@@ -145,6 +156,9 @@ class Sizes:
   experts_cfg: GlmMoeConfig       # one dense + two expert layers
   moe_gmm_shapes: tuple           # (rows, K, N, experts) of a layer's two
   latent_shape: tuple             # (slots, Lc, heads, latent, rank, chunk)
+  lfm2_cfg: Lfm2MoeConfig         # conv + dense, attention + experts, conv
+  lfm2_kv_shape: tuple            # (slots, Lc, H, H_kv, hd, chunk)
+  lfm2_gmm_shapes: tuple          # (rows, K, N, experts) of a layer's two
 
   @staticmethod
   def real() -> "Sizes":
@@ -185,7 +199,18 @@ class Sizes:
                                  param_dtype=jnp.float32),
         # The cell's: 96 slots x chunk 8 x 4 experts a token.
         moe_gmm_shapes=((3072, 2048, 3072, 64), (3072, 1536, 2048, 64)),
-        latent_shape=(8, 4104, 20, 576, 512, 8))
+        latent_shape=(8, 4104, 20, 576, 512, 8),
+        # LFM2-8B-A1B's widths, every one of its 32 experts, one layer of
+        # each kind of mixer and of feed-forward; vocabulary and context
+        # cut as for the expert cut above.
+        lfm2_cfg=Lfm2MoeConfig(
+            vocab_size=32768, layer_types=("conv", "full_attention", "conv"),
+            num_dense_layers=1, max_seq_len=1024, dtype=jnp.float32,
+            param_dtype=jnp.float32),
+        # The cell's: 128 slots x (4096 + 16) positions x 8 K/V heads of
+        # 64; 128 slots x chunk 16 x 4 experts a token.
+        lfm2_kv_shape=(128, 4112, 32, 8, 64, 16),
+        lfm2_gmm_shapes=((8192, 2048, 3584, 32), (8192, 1792, 2048, 32)))
 
   @staticmethod
   def toy() -> "Sizes":
@@ -217,7 +242,14 @@ class Sizes:
             n_routed_experts=8, num_experts_per_tok=2, max_seq_len=128,
             dtype=jnp.float32, param_dtype=jnp.float32),
         moe_gmm_shapes=((200, 128, 256, 8),),
-        latent_shape=(4, 136, 4, 40, 32, 8))
+        latent_shape=(4, 136, 4, 40, 32, 8),
+        lfm2_cfg=Lfm2MoeConfig(
+            vocab_size=512, d_model=256, d_ff=256, moe_d_ff=128, num_heads=4,
+            num_kv_heads=2, layer_types=("conv", "full_attention", "conv"),
+            num_dense_layers=1, n_routed_experts=8, num_experts_per_tok=2,
+            max_seq_len=128, dtype=jnp.float32, param_dtype=jnp.float32),
+        lfm2_kv_shape=(4, 136, 4, 2, 64, 8),
+        lfm2_gmm_shapes=((200, 256, 256, 8),))
 
 
 def say(msg: str) -> None:
@@ -1081,19 +1113,20 @@ def check_latent_leaf(B, Lc, H, hd, rank, C, dtype, rehearsal: bool) -> None:
       "beyond the bounds unread")
 
 
-def phase_experts(sizes: Sizes) -> None:
+def serve_expert_cut(sizes: Sizes, model, want_calls: dict, what: str):
+  """A cut of an expert decoder through the engine: every request runs to
+  its length on ONE compile, every kernel resolved and counted
+  (``want_calls``: custom calls by name in the compiled step), the served
+  tokens against the teacher-forced full forward (the experts by
+  ``ragged_dot``), and one fused call's logits, kernels against reference
+  lowerings.  Returns ``(gap, err)`` of the last two."""
   from easyparallellibrary_tpu.models.gpt import slot_step_logits
   from easyparallellibrary_tpu.serving import kv_cache as kv_lib
-  for dtype in (jnp.float32, jnp.bfloat16):
-    for shape in sizes.moe_gmm_shapes:
-      check_moe_gmm(*shape, dtype, rehearsal=sizes.rehearsal)
-    check_latent_leaf(*sizes.latent_shape, dtype, rehearsal=sizes.rehearsal)
-  cfg = sizes.experts_cfg
-  model = GlmMoe(cfg)
+  cfg = model.cfg
   params = jax.jit(lambda: model.init(
       jax.random.PRNGKey(2), jnp.zeros((1, 8), jnp.int32))["params"])()
   prompts = seeded_requests(sizes, cfg)
-  n_moe = cfg.num_layers - cfg.first_k_dense
+  recurrent = kv_lib.has_recurrent_state(cfg)
   with jax.default_matmul_precision("highest"):
     eng = ContinuousBatchingEngine(model, params)
     spy = _StepSpecs(eng)
@@ -1104,29 +1137,26 @@ def phase_experts(sizes: Sizes) -> None:
     out = eng.run()
     for uid, p in enumerate(prompts):
       check(uid in out and len(out[uid]) == len(p) + sizes.new_tokens,
-            f"expert-model request {uid} did not run to its length")
+            f"{what} request {uid} did not run to its length")
     check(spy._cache_size() == 1,
-          f"expert model's fused step compiled {spy._cache_size()} times")
-    say(f"  expert engine: {len(prompts)} requests, attend "
+          f"{what}'s fused step compiled {spy._cache_size()} times")
+    say(f"  {what} engine: {len(prompts)} requests, attend "
         f"{eng.slot_attn_impl}, kv write {eng.kv_write_impl}, expert "
         f"matmul {eng.moe_gmm_impl}, cache {eng.cache_layout}")
     if not sizes.rehearsal:
       impls = (eng.kv_write_impl, eng.slot_attn_impl, eng.moe_gmm_impl)
       hlo = spy.inner.lower(*spy.specs).compile().as_text()
-      calls = {n: named_calls(hlo, n) for n in (MOE_GMM, SLOT_ATTN,
-                                                "kv_write")}
-      want = {MOE_GMM: 2 * n_moe, SLOT_ATTN: cfg.num_layers,
-              "kv_write": cfg.num_layers}
-      check(all(i == "pallas" for i in impls) and calls == want,
-            f"expert engine resolved {impls}; custom calls {calls}, "
-            f"expected {want}")
-    # The served tokens against the teacher-forced full forward: expanded
-    # attention over the whole sequence, the experts by ragged_dot.
+      calls = {n: named_calls(hlo, n) for n in want_calls}
+      check(all(i == "pallas" for i in impls) and calls == want_calls,
+            f"{what} engine resolved {impls}; custom calls {calls}, "
+            f"expected {want_calls}")
+    # The served tokens against the teacher-forced full forward: attention
+    # over the whole sequence, the experts by ragged_dot.
     streams = [out[uid] for uid in range(len(prompts))]
     ids = np.zeros((len(streams), cfg.max_seq_len), np.int32)
     for i, s in enumerate(streams):
       ids[i, :len(s)] = s
-    full = jax.jit(lambda p, ids: GlmMoe(cfg).apply(
+    full = jax.jit(lambda p, ids: model.apply(
         {"params": p}, ids, moe_gmm_impl="reference").astype(jnp.float32))
     logits = np.asarray(full(params, jnp.asarray(ids)))
     gap = 0.0
@@ -1143,6 +1173,7 @@ def phase_experts(sizes: Sizes) -> None:
     r = np.random.RandomState(4)
     tokens = jnp.asarray(r.randint(0, cfg.vocab_size, (N, C)), jnp.int32)
     num_valid = jnp.asarray([C, 1, 0, C // 2, 1, C, 0, 1], jnp.int32)
+    state_args = {"reset": jnp.zeros((N,), jnp.bool_)} if recurrent else {}
     kernel_impl = "interpret" if sizes.rehearsal else "pallas"
     got = {}
     for impl in (kernel_impl, "reference"):
@@ -1151,19 +1182,59 @@ def phase_experts(sizes: Sizes) -> None:
           slot_step_logits, model, kv_write_impl=impl, slot_attn_impl=impl,
           moe_gmm_impl=impl))
       for _ in range(2):       # the second call reads what the first wrote
-        lg, kv = step(params, kv, tokens, cursors, num_valid=num_valid)
+        lg, kv = step(params, kv, tokens, cursors, num_valid=num_valid,
+                      **state_args)
         cursors = cursors + num_valid
       got[impl] = lg[np.arange(C)[None] < np.asarray(num_valid)[:, None]]
   err = rel_err(got[kernel_impl], got["reference"])
   tol = 1e-4 if jnp.dtype(cfg.dtype).itemsize == 4 else 3e-2
-  check(err <= tol, f"expert step logits, kernels against the reference "
+  check(err <= tol, f"{what} step logits, kernels against the reference "
         f"lowerings: {err:.3g} of the largest logit (limit {tol})")
+  return gap, err
+
+
+def phase_experts(sizes: Sizes) -> None:
+  for dtype in (jnp.float32, jnp.bfloat16):
+    for shape in sizes.moe_gmm_shapes:
+      check_moe_gmm(*shape, dtype, rehearsal=sizes.rehearsal)
+    check_latent_leaf(*sizes.latent_shape, dtype, rehearsal=sizes.rehearsal)
+  cfg = sizes.experts_cfg
+  n_moe = cfg.num_layers - cfg.first_k_dense
+  gap, err = serve_expert_cut(
+      sizes, GlmMoe(cfg), {MOE_GMM: 2 * n_moe, SLOT_ATTN: cfg.num_layers,
+                           "kv_write": cfg.num_layers}, "expert model")
   say(f"PASS experts: moe_gmm and the one-leaf kv_write and slot_attn f32 "
       "+ bf16 " + ("INTERPRETED" if sizes.rehearsal else "compiled")
       + f" against their references; {cfg.first_k_dense} dense + {n_moe} "
       f"expert layers served, served tokens within {gap:.1e} of the "
       f"teacher-forced best; step logits kernels against reference "
       f"lowerings {err:.2e}")
+
+
+def phase_lfm2(sizes: Sizes) -> None:
+  B, Lc, H, Hkv, hd, C = sizes.lfm2_kv_shape
+  for dtype in (jnp.float32, jnp.bfloat16):
+    check_kv_write(B, Lc, Hkv, hd, C, dtype, "rows",
+                   rehearsal=sizes.rehearsal)
+    check_slot_attn(B, Lc, H, Hkv, hd, C, dtype, "rows",
+                    rehearsal=sizes.rehearsal)
+    for shape in sizes.lfm2_gmm_shapes:
+      check_moe_gmm(*shape, dtype, rehearsal=sizes.rehearsal)
+  cfg = sizes.lfm2_cfg
+  kinds = cfg.layer_kinds()
+  n_attn = sum(k == "attention" for k in kinds)
+  n_moe = cfg.num_layers - cfg.num_dense_layers
+  gap, err = serve_expert_cut(
+      sizes, Lfm2Moe(cfg), {MOE_GMM: 2 * n_moe, SLOT_ATTN: n_attn,
+                            "kv_write": n_attn}, "lfm2")
+  say(f"PASS lfm2: kv_write and slot_attn in rows on grouped heads that "
+      "share a lane tile, moe_gmm over "
+      f"{cfg.n_routed_experts} experts, f32 + bf16 "
+      + ("INTERPRETED" if sizes.rehearsal else "compiled")
+      + f" against their references; {len(kinds) - n_attn} conv + {n_attn} "
+      f"attention layers, {cfg.num_dense_layers} dense + {n_moe} expert, "
+      f"served tokens within {gap:.1e} of the teacher-forced best; step "
+      f"logits kernels against reference lowerings {err:.2e}")
 
 
 # ------------------------------------------------------------------- main --
@@ -1189,7 +1260,8 @@ def main(argv=None) -> int:
            f"{REHEARSAL_EXIT}; not a pass")
   parser.add_argument(
       "--only", default=None,
-      help="run this one phase (kernels, train, serve, hybrid, experts); "
+      help="run this one phase (kernels, train, serve, hybrid, experts, "
+           "lfm2); "
            "prints no result line")
   args = parser.parse_args(argv)
   t_start = time.perf_counter()
@@ -1214,7 +1286,8 @@ def main(argv=None) -> int:
                       ("train", lambda: phase_train(sizes, dev)),
                       ("serve", lambda: phase_serve(sizes)),
                       ("hybrid", lambda: phase_hybrid(sizes)),
-                      ("experts", lambda: phase_experts(sizes))):
+                      ("experts", lambda: phase_experts(sizes)),
+                      ("lfm2", lambda: phase_lfm2(sizes))):
     if args.only not in (None, name):
       continue
     t0 = time.perf_counter()

@@ -3,6 +3,7 @@ from easyparallellibrary_tpu.models.gpt import (
 )
 from easyparallellibrary_tpu.models.jamba import Jamba, JambaConfig
 from easyparallellibrary_tpu.models.glm_moe import GlmMoe, GlmMoeConfig
+from easyparallellibrary_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
 from easyparallellibrary_tpu.models.bert import (
     Bert, BertConfig, bert_large_config,
 )
@@ -14,6 +15,7 @@ __all__ = [
     "GPT", "GPTConfig", "auto_parallel_gpt", "make_gpt_train_step",
     "Jamba", "JambaConfig",
     "GlmMoe", "GlmMoeConfig",
+    "Lfm2Moe", "Lfm2MoeConfig",
     "Bert", "BertConfig", "bert_large_config",
     "ResNet", "ResNetConfig", "resnet18_config", "resnet50_config",
 ]

@@ -83,6 +83,7 @@ class GlmMoeConfig:
   first_k_dense: int = 1
   routed_scaling_factor: float = 1.8
   norm_topk_prob: bool = True
+  route_norm_eps: float = 1e-20      # added to the chosen scores' sum
   rope_theta: float = 1e6
   rms_norm_eps: float = 1e-5
   max_seq_len: int = 4096            # served context; the cache's length

@@ -300,10 +300,11 @@ def slot_step_logits(model, params, kv, tokens, cursors,
   num_valid`` and none at all of an idle slot (``num_valid == 0``), and
   a recurrence advances by exactly that many, and a dropless expert
   layer routes exactly those positions.  A model with positional
-  arithmetic of its own (models/glm_moe.py: rotary) takes token ``i``'s
-  position from the same ``cursors[b] + i``.  ``state_args`` go to a
-  model that asks for more (models/jamba.py: ``reset``,
-  ``ssm_scan_impl``; models/glm_moe.py: ``moe_gmm_impl``); a GPT takes
+  arithmetic of its own (models/glm_moe.py, models/lfm2_moe.py: rotary)
+  takes token ``i``'s position from the same ``cursors[b] + i``.
+  ``state_args`` go to a model that asks for more (models/jamba.py:
+  ``reset``, ``ssm_scan_impl``; models/glm_moe.py: ``moe_gmm_impl``;
+  models/lfm2_moe.py: ``reset`` AND ``moe_gmm_impl``); a GPT takes
   none.  ``stats`` also returns what the model sowed into its ``stats``
   collection (an expert layer's load).
 

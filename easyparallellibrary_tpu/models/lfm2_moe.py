@@ -1,0 +1,262 @@
+"""LFM2-MoE — gated short convolutions beside grouped attention, routed
+experts with no shared one.
+
+``model_type: lfm2_moe`` (LFM2-8B-A1B): pre-RMSNorm residual layers, ``h =
+x + Mixer(RMSNorm(x))``, ``y = h + FF(RMSNorm(h))``, no bias anywhere.
+Layer ``l`` mixes by what ``layer_types[l]`` names:
+
+* ``conv``: ``[B | C | u] = W_in x`` (three ``d_model``-wide thirds);
+  ``z = B * u``; ``c_t = sum_j w_j z_{t - (L - 1) + j}`` over the ``L =
+  conv_L_cache`` last products (depthwise, causal, tap ``L - 1`` on the
+  current token, no bias); ``out = W_out (C * c)``.  All a request carries
+  from one position to the next is the last ``L - 1`` products ``z``;
+* ``full_attention``: ``q`` as ``num_heads`` heads, ``k`` and ``v`` as
+  ``num_kv_heads``; an RMSNorm over each head of ``q`` and of ``k`` (one
+  gain of ``head_dim`` each), rotate-half rotary on all of both at the
+  token's index, causal softmax of ``q k^T / sqrt(head_dim)``, query head
+  ``h`` on K/V head ``h // (num_heads / num_kv_heads)``.  Cached: ``k``
+  AFTER its norm and rotary, and ``v``.
+
+The feed-forward is a dense SiLU-gated MLP in the first
+``num_dense_layers`` layers and routed experts without capacity and WITHOUT
+a shared expert in every other (models/moe.py: :class:`DroplessMoE` with
+``n_shared_experts`` 0; sigmoid scores, a bias that enters the choice
+only, the chosen scores over their sum plus 1e-6).  One RMSNorm after the
+last layer (the published code's ``embedding_norm``), logits over the TIED
+embedding.  ``perfbench/reference/lfm2_moe.py`` holds the same equations in
+plain float32 and the tests compare the two.
+
+Serving: slot mode (``decode=True`` with ``slot_cursors``) keeps per slot,
+through ``serving/kv_cache.py`` and :meth:`Lfm2MoeConfig.layer_kinds`,
+
+* attention layers: ``cached_key`` / ``cached_value`` under a cursor,
+  through ``models.gpt.slot_cache_attend`` (``[slots, Lc, H_kv x hd]``,
+  kept in rows, at the published 8 heads of 64);
+* conv layers: ``conv_state`` ``[slots, L - 1, d_model]``, the layer's
+  WHOLE state (kind :data:`CONV`).  Like the hybrid's recurrence it has no
+  position axis: it advances by exactly ``num_valid`` positions a slot (0
+  leaves it bit for bit; a chunk's positions beyond ``num_valid`` neither
+  read into nor advance it), a slot that starts a request (``reset``)
+  starts from zeros, and no cursor can roll it back: the paged layout,
+  prefix caching, speculation and the guarded retry refuse this model
+  (``serving/_capabilities.py``).
+
+Precision: the residual stream, the matmuls and the convolution window in
+``cfg.dtype`` (the window is two rows of products, not an accumulated
+state); norms, rotary angles, the softmax, the router and the three-tap sum
+in float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional
+
+import jax.numpy as jnp
+from flax import linen as nn
+
+from easyparallellibrary_tpu.models.glm_moe import rotary
+from easyparallellibrary_tpu.models.gpt import (
+    _missing_slot_cache, slot_cache_attend)
+from easyparallellibrary_tpu.models.jamba import (
+    ATTENTION, GatedMLP, RMSNorm, _boxed, _dense, _uniform, advance_window,
+    gqa_causal_attention)
+from easyparallellibrary_tpu.models.moe import DroplessMoE
+from easyparallellibrary_tpu.ops import Embedding
+
+# What a conv layer keeps per slot (serving/kv_cache.py reads
+# ``cfg.layer_kinds()``): the convolution's last inputs, nothing else.
+CONV = "conv"
+
+# ``layer_types`` of the published LFM2-8B-A1B: 18 conv, 6 attention.
+PUBLISHED_LAYER_TYPES = (
+    "conv", "conv", "full_attention", "conv", "conv", "conv",
+    "full_attention", "conv", "conv", "conv", "full_attention", "conv",
+    "conv", "conv", "full_attention", "conv", "conv", "conv",
+    "full_attention", "conv", "conv", "full_attention", "conv", "conv")
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2MoeConfig:
+  vocab_size: int = 65536
+  d_model: int = 2048
+  d_ff: int = 7168                   # the leading dense layers' MLP
+  moe_d_ff: int = 1792               # one expert's width
+  num_heads: int = 32
+  num_kv_heads: int = 8
+  conv_L_cache: int = 3
+  layer_types: tuple = PUBLISHED_LAYER_TYPES
+  num_dense_layers: int = 2
+  n_routed_experts: int = 32
+  n_shared_experts: int = 0
+  num_experts_per_tok: int = 4
+  routed_scaling_factor: float = 1.0
+  norm_topk_prob: bool = True
+  route_norm_eps: float = 1e-6
+  rope_theta: float = 1e6
+  norm_eps: float = 1e-5
+  max_seq_len: int = 4096            # served context; the cache's length
+  dtype: Any = jnp.bfloat16
+  param_dtype: Any = jnp.bfloat16
+
+  @property
+  def num_layers(self) -> int:
+    return len(self.layer_types)
+
+  @property
+  def head_dim(self) -> int:
+    return self.d_model // self.num_heads
+
+  def layer_kinds(self) -> tuple:
+    """Per layer, which state it keeps: :data:`CONV` for ``"conv"``,
+    ``attention`` (a K/V pair) for ``"full_attention"``."""
+    names = {"conv": CONV, "full_attention": ATTENTION}
+    unknown = set(self.layer_types) - set(names)
+    if unknown:
+      raise ValueError(f"layer_types may hold 'conv' and 'full_attention'; "
+                       f"got {sorted(unknown)}")
+    return tuple(names[t] for t in self.layer_types)
+
+
+class ShortConv(nn.Module):
+  """The gated short convolution (module docstring).  ``in_proj``'s
+  columns are ``B | C | u``; the taps are ``[L, d_model]``, tap ``L - 1``
+  on the current token."""
+  cfg: Lfm2MoeConfig
+  decode: bool = False
+
+  @nn.compact
+  def __call__(self, h, num_valid=None, reset=None):
+    cfg = self.cfg
+    B, C, D = h.shape
+    L = cfg.conv_L_cache
+    bcu = _dense(cfg, 3 * D, "in_proj")(h)
+    gate_b, gate_c, u = bcu[..., :D], bcu[..., D:2 * D], bcu[..., 2 * D:]
+    z = gate_b * u
+    conv_w = self.param("conv_w", _boxed(_uniform(L ** -0.5), 2), (L, D),
+                        cfg.param_dtype)
+    if self.decode:
+      state = self.variable("cache", "conv_state", _missing_slot_cache)
+      window = state.value
+      if reset is not None:
+        window = jnp.where(reset[:, None, None],
+                           jnp.zeros((), window.dtype), window)
+    else:
+      window = jnp.zeros((B, L - 1, D), z.dtype)
+    full = jnp.concatenate([window.astype(z.dtype), z], axis=1)
+    w32 = jnp.asarray(conv_w, jnp.float32)
+    conv = sum(full[:, j:j + C].astype(jnp.float32) * w32[j]
+               for j in range(L)).astype(cfg.dtype)
+    if self.decode:
+      state.value = advance_window(full, num_valid, L - 1)
+    return _dense(cfg, D, "out_proj")(gate_c * conv)
+
+
+class NormedAttention(nn.Module):
+  """Grouped attention whose queries and keys are normalised a head and
+  then rotated (module docstring)."""
+  cfg: Lfm2MoeConfig
+  decode: bool = False
+  kv_write_impl: Optional[str] = None
+  slot_attn_impl: Optional[str] = None
+
+  @nn.compact
+  def __call__(self, h, positions, slot_cursors=None, num_valid=None):
+    cfg = self.cfg
+    B, S, _ = h.shape
+    H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype, name=name)
+    q = _dense(cfg, H * hd, "q")(h).reshape(B, S, H, hd)
+    k = _dense(cfg, Hkv * hd, "k")(h).reshape(B, S, Hkv, hd)
+    v = _dense(cfg, Hkv * hd, "v")(h).reshape(B, S, Hkv, hd)
+    q = rotary(norm("q_norm")(q), positions, cfg.rope_theta)
+    k = rotary(norm("k_norm")(k), positions, cfg.rope_theta)
+    if self.decode:
+      ck = self.variable("cache", "cached_key", _missing_slot_cache)
+      cv = self.variable("cache", "cached_value", _missing_slot_cache)
+      out, ck.value, cv.value = slot_cache_attend(
+          q, k, v, ck.value, cv.value, slot_cursors, cfg.dtype,
+          write_impl=self.kv_write_impl, attn_impl=self.slot_attn_impl,
+          num_valid=num_valid)
+    else:
+      out = gqa_causal_attention(q, k, v, cfg.dtype)
+    return _dense(cfg, cfg.d_model, "o")(out.reshape(B, S, H * hd))
+
+
+class Lfm2MoeBlock(nn.Module):
+  cfg: Lfm2MoeConfig
+  kind: str
+  dense: bool
+  decode: bool = False
+  kv_write_impl: Optional[str] = None
+  slot_attn_impl: Optional[str] = None
+  moe_gmm_impl: Optional[str] = None
+
+  @nn.compact
+  def __call__(self, x, positions, slot_cursors=None, num_valid=None,
+               reset=None):
+    cfg = self.cfg
+    norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype, name=name)
+    h = norm("norm_in")(x)
+    if self.kind == ATTENTION:
+      mixed = NormedAttention(
+          cfg, decode=self.decode, kv_write_impl=self.kv_write_impl,
+          slot_attn_impl=self.slot_attn_impl, name="attn")(
+              h, positions, slot_cursors, num_valid)
+    else:
+      mixed = ShortConv(cfg, decode=self.decode, name="conv")(
+          h, num_valid, reset)
+    x = x + mixed
+    h = norm("norm_ff")(x)
+    if self.dense:
+      return x + GatedMLP(cfg, name="mlp")(h)
+    # Only live positions are routed: a chunk's tail beyond ``num_valid``
+    # and an idle slot's rows reach no expert.
+    live = None if num_valid is None else (
+        jnp.arange(x.shape[1])[None] < num_valid[:, None])
+    return x + DroplessMoE(cfg, moe_gmm_impl=self.moe_gmm_impl,
+                           name="moe")(h, live)
+
+
+class Lfm2Moe(nn.Module):
+  """Decoder-only LM.  ``__call__(ids) -> logits`` is the full forward
+  from an empty window; ``decode=True`` with ``slot_cursors`` is the
+  serving engine's slot mode (module docstring): token ``i`` of slot ``b``
+  sits at position ``slot_cursors[b] + i`` (what rotary turns by),
+  ``num_valid`` int32 ``[slots]`` says how many of the chunk's positions
+  each slot feeds (``None``: all) — what the attend reads, what the
+  experts are handed and how far a convolution window advances —
+  ``reset`` bool ``[slots]`` which slots start from an empty window."""
+
+  cfg: Lfm2MoeConfig
+
+  @nn.compact
+  def __call__(self, ids, decode: bool = False, return_hidden: bool = False,
+               slot_cursors=None, num_valid=None, reset=None,
+               kv_write_impl=None, slot_attn_impl=None, moe_gmm_impl=None):
+    cfg = self.cfg
+    if decode and slot_cursors is None:
+      raise ValueError(
+          "Lfm2Moe decodes in slot mode only: pass slot_cursors= and a "
+          "slot cache from serving.kv_cache.allocate_kv_cache (the serving "
+          "engine does)")
+    if slot_cursors is not None and not decode:
+      raise ValueError("slot_cursors is a decode-mode argument (serving "
+                       "engine); pass decode=True")
+    B, S = ids.shape
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    if slot_cursors is not None:
+      positions = positions + slot_cursors.astype(jnp.int32)[:, None]
+    tok = Embedding(cfg.vocab_size, cfg.d_model, parallel="none",
+                    param_dtype=cfg.param_dtype, name="embed")
+    x = tok(ids).astype(cfg.dtype)
+    for i, kind in enumerate(cfg.layer_kinds()):
+      x = Lfm2MoeBlock(cfg, kind, dense=i < cfg.num_dense_layers,
+                       decode=decode, kv_write_impl=kv_write_impl,
+                       slot_attn_impl=slot_attn_impl,
+                       moe_gmm_impl=moe_gmm_impl, name=f"block_{i}")(
+                           x, positions, slot_cursors, num_valid, reset)
+    x = RMSNorm(cfg.norm_eps, cfg.dtype, name="norm_f")(x)
+    if return_hidden:
+      return x
+    return tok.attend(x)

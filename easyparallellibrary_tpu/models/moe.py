@@ -20,8 +20,9 @@ out as a hack.  Here the layer contract is explicit:
     ``losses`` collection.
 
 That is :class:`MoEMLP`, the TRAINING layer.  The SERVING layer is
-:class:`DroplessMoE` at the end of this module (models/glm_moe.py uses
-it): no capacity and no dropped token.  A step's positions are flattened,
+:class:`DroplessMoE` at the end of this module (models/glm_moe.py and
+models/lfm2_moe.py use it): routed experts beside shared ones, if any, no
+capacity and no dropped token.  A step's positions are flattened,
 each live one is assigned its ``top_k`` experts (:func:`noaux_tc_route`:
 sigmoid scores, a selection bias that enters the choice and not the
 weights), the assignments are sorted by expert (:func:`sort_by_expert`),
@@ -346,21 +347,23 @@ class MoEMLP(nn.Module):
 
 
 def noaux_tc_route(x, router_kernel, bias, top_k: int, scale: float,
-                   norm: bool = True):
+                   norm: bool = True, norm_eps: float = 1e-20):
   """The ``noaux_tc`` router with one group (DeepSeek-V3's, as GLM-4.7
-  configures it: ``n_group`` 1, ``topk_group`` 1): ``s = sigmoid(x W_g)``
+  configures it: ``n_group`` 1, ``topk_group`` 1; LFM2's
+  ``use_expert_bias`` router is the same rule): ``s = sigmoid(x W_g)``
   in float32 whatever ``x``'s dtype; the ``top_k`` largest of ``s + bias``
   are CHOSEN (the bias steers the choice only); their weights are the
-  unbiased ``s``, normalised over the chosen (``norm``) and times
-  ``scale``.  ``x`` ``[N, D]`` -> ``(chosen int32 [N, top_k], weights
-  float32 [N, top_k])``."""
+  unbiased ``s``, normalised over the chosen (``norm``: divided by their
+  sum plus ``norm_eps``, GLM's 1e-20, LFM2's 1e-6) and times ``scale``.
+  ``x`` ``[N, D]`` -> ``(chosen int32 [N, top_k], weights float32 [N,
+  top_k])``."""
   scores = jax.nn.sigmoid(jnp.matmul(
       x.astype(jnp.float32), router_kernel.astype(jnp.float32),
       precision=jax.lax.Precision.HIGHEST))
   _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
   weights = jnp.take_along_axis(scores, chosen, axis=-1)
   if norm:
-    weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+    weights = weights / (jnp.sum(weights, -1, keepdims=True) + norm_eps)
   return chosen.astype(jnp.int32), weights * scale
 
 
@@ -411,17 +414,21 @@ def dropless_experts(x, chosen, weights, live, w_gate_up, w_down,
 
 
 class DroplessMoE(nn.Module):
-  """Routed experts without capacity beside shared ones: ``Shared(x) +
-  sum_i w_i Expert_i(x)`` (module docstring).  ``cfg`` gives ``d_model``,
-  ``n_routed_experts``, ``num_experts_per_tok``, ``moe_d_ff``,
-  ``n_shared_experts``, ``routed_scaling_factor``, ``norm_topk_prob`` and
-  the dtypes.  ``live`` bool ``[..]`` over ``x``'s leading axes says which
-  positions are routed (``None``: all); the shared expert runs for every
-  position (fixed shapes), and what it gives a dead one nothing reads.
+  """Routed experts without capacity beside shared ones, if any:
+  ``Shared(x) + sum_i w_i Expert_i(x)``, the routed sum alone where
+  ``n_shared_experts`` is 0 (no ``shared`` in the tree then; module
+  docstring).  ``cfg`` gives ``d_model``, ``n_routed_experts``,
+  ``num_experts_per_tok``, ``moe_d_ff``, ``n_shared_experts``,
+  ``routed_scaling_factor``, ``norm_topk_prob``, ``route_norm_eps`` (what
+  the normalisation adds to the chosen scores' sum) and the dtypes.  ``live`` bool ``[..]``
+  over ``x``'s leading axes says which positions are routed (``None``:
+  all); a shared expert runs for every position (fixed shapes), and what
+  it gives a dead one nothing reads.
 
-  Sows ``expert_load`` into the ``stats`` collection: the busiest
+  Sows into the ``stats`` collection ``expert_load``, the busiest
   expert's assignments over the mean (1.0 = even; 0 when nothing is
-  live)."""
+  live), and ``experts_touched``, how many experts have at least one live
+  assignment (the step streams those experts' weights and no others')."""
 
   cfg: Any
   moe_gmm_impl: Optional[str] = None
@@ -447,7 +454,7 @@ class DroplessMoE(nn.Module):
     flat_live = None if live is None else live.reshape(-1)
     chosen, weights = noaux_tc_route(
         flat, router, bias, k, cfg.routed_scaling_factor,
-        cfg.norm_topk_prob)
+        cfg.norm_topk_prob, cfg.route_norm_eps)
     y, sizes = dropless_experts(
         flat, chosen, weights, flat_live, jnp.asarray(w_gate_up, cfg.dtype),
         jnp.asarray(w_down, cfg.dtype), impl=self.moe_gmm_impl)
@@ -455,5 +462,9 @@ class DroplessMoE(nn.Module):
     self.sow("stats", "expert_load",
              jnp.max(sizes).astype(jnp.float32) * E
              / jnp.maximum(total, 1.0))
+    self.sow("stats", "experts_touched",
+             jnp.sum(sizes > 0).astype(jnp.float32))
+    if not cfg.n_shared_experts:
+      return y.reshape(x.shape)
     shared = GatedMLP(cfg, d_ff=cfg.n_shared_experts * F, name="shared")(x)
     return shared + y.reshape(x.shape)

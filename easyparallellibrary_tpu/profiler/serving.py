@@ -152,11 +152,13 @@ class ServingStats:
     # of the cache a bounded attend reads (kernels/slot_attention.py).
     self.live_kv_rows = 0
     # Dropless expert layers (0 on a model without them): live positions
-    # routed, and the sum over the steps that routed of the busiest
-    # expert's load over the mean.
+    # routed, and the sums over the steps that routed of the busiest
+    # expert's load over the mean and of the fewest experts a layer
+    # touched.
     self.routed_positions = 0
     self.expert_steps = 0
     self.expert_load_sum = 0.0
+    self.experts_touched_sum = 0.0
     self.kv_rows = 0
     self.busy_time_s = 0.0
     self.prefill_tokens = 0
@@ -319,12 +321,14 @@ class ServingStats:
                 step_time_s: float, drafted_tokens: int = 0,
                 accepted_tokens: int = 0, sampled_slots: int = 0,
                 live_kv_rows: int = 0, kv_rows: int = 0,
-                routed_positions: int = 0, expert_load_max: float = 0.0):
+                routed_positions: int = 0, expert_load_max: float = 0.0,
+                experts_touched_min: float = 0.0):
     self.steps += 1
     if routed_positions > 0:
       self.routed_positions += int(routed_positions)
       self.expert_steps += 1
       self.expert_load_sum += float(expert_load_max)
+      self.experts_touched_sum += float(experts_touched_min)
     self.live_kv_rows += int(live_kv_rows)
     self.kv_rows += int(kv_rows)
     if sampled_slots > 0:
@@ -388,7 +392,7 @@ class ServingStats:
   _STATE_SCALARS = (
       "steps", "sampling_steps", "live_kv_rows", "kv_rows",
       "routed_positions", "expert_steps", "expert_load_sum",
-      "busy_time_s", "prefill_tokens",
+      "experts_touched_sum", "busy_time_s", "prefill_tokens",
       "decode_tokens", "finished_requests", "generated_tokens",
       "drafted_tokens", "accepted_tokens", "shed_requests", "requeues",
       "bad_steps",
@@ -459,13 +463,18 @@ class ServingStats:
         "kv_read_share": (self.live_kv_rows / self.kv_rows
                           if self.kv_rows else 0.0),
         # Dropless expert layers (0.0 without them): live positions a
-        # step routed, and the busiest expert's load over the mean
-        # (worst layer), averaged over the steps that routed.
+        # step routed, the busiest expert's load over the mean (worst
+        # layer) and the fewest experts a layer touched (under the
+        # model's expert count: that step did not stream every expert's
+        # weights), each averaged over the steps that routed.
         "routed_positions_per_step": (
             self.routed_positions / self.expert_steps
             if self.expert_steps else 0.0),
         "expert_load_max_mean": (
             self.expert_load_sum / self.expert_steps
+            if self.expert_steps else 0.0),
+        "experts_touched_min_mean": (
+            self.experts_touched_sum / self.expert_steps
             if self.expert_steps else 0.0),
         # Speculation (all 0.0 on a non-speculative engine): drafted vs
         # accepted totals, overall acceptance rate, and accepted-per-
@@ -569,6 +578,9 @@ def fleet_summary(replica_stats: List["ServingStats"],
           / max(sum(s.expert_steps for s in stats), 1)),
       "expert_load_max_mean": (
           sum(s.expert_load_sum for s in stats)
+          / max(sum(s.expert_steps for s in stats), 1)),
+      "experts_touched_min_mean": (
+          sum(s.experts_touched_sum for s in stats)
           / max(sum(s.expert_steps for s in stats), 1)),
       "drafted_tokens": float(drafted),
       "accepted_tokens": float(accepted),

@@ -92,14 +92,15 @@ def check_servable(cfg, role: str = "the serving engine") -> None:
   """Reject model configs the serving stack cannot run.
 
   ``cfg`` is a :class:`models.gpt.GPTConfig`, a
-  :class:`models.jamba.JambaConfig` or a
-  :class:`models.glm_moe.GlmMoeConfig` (a config without
+  :class:`models.jamba.JambaConfig`, a
+  :class:`models.glm_moe.GlmMoeConfig` or a
+  :class:`models.lfm2_moe.Lfm2MoeConfig` (a config without
   ``pipeline_stages`` / ``num_experts`` has neither); ``role`` names the
   component doing the rejecting so a draft-model failure reads
   differently from a target-model one.  Refused are the GPT block's
   experts (``num_experts``: MoEMLP drops what overflows a capacity and
   has no slot mode); the dropless experts of models/glm_moe.py
-  (``n_routed_experts``) are served.
+  and models/lfm2_moe.py (``n_routed_experts``) are served.
   """
   stages = getattr(cfg, "pipeline_stages", 1)
   if stages > 1:
@@ -119,17 +120,19 @@ def check_servable(cfg, role: str = "the serving engine") -> None:
 def check_recurrent_state(cfg, feature: str) -> None:
   """Reject ``feature`` (the paged cache, prefix caching, speculative
   decoding, the guarded retry, a draft model) for a model some of whose
-  layers keep recurrent state (``cfg.layer_kinds()``, models/jamba.py):
-  each of them takes a request back to an earlier position by moving a
+  layers keep recurrent state (``cfg.layer_kinds()``: models/jamba.py's
+  ``mamba``, models/lfm2_moe.py's ``conv``): each of them takes a request back to an earlier position by moving a
   cursor or dropping blocks, and stale recurrent state is masked by
   nothing.  ONE message for every such composition.  Preemption by
   replay (scheduler.requeue_slot) is not among them: a replay starts
   from ``reset``."""
-  from easyparallellibrary_tpu.serving.kv_cache import has_recurrent_state
-  if has_recurrent_state(cfg):
+  from easyparallellibrary_tpu.serving.kv_cache import recurrent_kinds
+  kinds = recurrent_kinds(cfg)
+  if kinds:
     raise ValueError(
         f"{feature} is not available for a model with recurrent-state "
-        f"layers ({type(cfg).__name__}) — {ROADMAP_RECURRENT_STATE}")
+        f"layers ({type(cfg).__name__}) of kind {' and '.join(kinds)} — "
+        f"{ROADMAP_RECURRENT_STATE}")
 
 
 def check_latent_cache(cfg, feature: str) -> None:

@@ -176,6 +176,18 @@ def sample_token_slots(logits, keys, temperature, top_k, top_p):
   return jax.lax.cond(jnp.any(temperature > 0), sample, lambda: greedy)
 
 
+def _expert_stats(sown):
+  """What a step hands back of its expert layers' sown ``stats``
+  (models/moe.py ``DroplessMoE``), float32 ``[2]``: the busiest expert's
+  load over the mean, worst layer, and the fewest experts a layer
+  touched.  Reduced on the device, fetched with the tokens."""
+  named = lambda name: jnp.stack([
+      leaf for path, leaf in jax.tree_util.tree_leaves_with_path(sown)
+      if any(getattr(k, "key", None) == name for k in path)])
+  return jnp.stack([jnp.max(named("expert_load")),
+                    jnp.min(named("experts_touched"))])
+
+
 def _resolve_mesh(mesh):
   """The engine's placement mesh: the caller's, else the ambient Env
   mesh when one has been BUILT (never force-building one).
@@ -361,18 +373,20 @@ class ContinuousBatchingEngine:
       trace_lib.get_tracer().metadata(
           f"{self._track_prefix}/slot_attn_impl",
           {"impl": self.slot_attn_impl})
-    # Recurrent state beside K/V (models/jamba.py): the scan's lowering
-    # (kernels/ssm_scan.py), resolved once by the same kind of rule, and
-    # what the cache holds of each kind of state.  None / absent for a
-    # model whose every layer is attention.
+    # Recurrent state beside K/V (models/jamba.py's Mamba layers,
+    # models/lfm2_moe.py's conv layers): what the step must tell the
+    # model (``reset``), and for a Mamba layer the scan's lowering
+    # (kernels/ssm_scan.py), resolved once by the same kind of rule.
+    # None / absent for a model without such a layer.
     self._recurrent = kv_lib.has_recurrent_state(cfg)
     self.ssm_scan_impl = kv_lib.ssm_scan_impl(
         cfg, self.num_slots, self.chunk, self.mesh)
-    if self._recurrent:
+    if self.ssm_scan_impl is not None:
       trace_lib.get_tracer().metadata(
           f"{self._track_prefix}/ssm_scan_impl",
           {"impl": self.ssm_scan_impl})
-    # Dropless experts inside the fused step (models/glm_moe.py): the
+    # Dropless experts inside the fused step (models/glm_moe.py,
+    # models/lfm2_moe.py): the
     # grouped matmul's lowering (kernels/moe_gmm.py), resolved once by its
     # rule; None for a model without routed experts.
     self.moe_gmm_impl = kv_lib.moe_gmm_impl(
@@ -616,7 +630,9 @@ class ContinuousBatchingEngine:
         layout += (f": {lay['kv_leaves']} K/V leaves "
                    f"{lay['kv_bytes'] / 1e6:.1f} MB + {lay['state_leaves']} "
                    f"recurrent-state leaves {lay['state_bytes'] / 1e6:.1f} "
-                   f"MB, {self.ssm_scan_impl} ssm scan")
+                   f"MB")
+        if self.ssm_scan_impl is not None:
+          layout += f", {self.ssm_scan_impl} ssm scan"
       elif "latent_leaves" in lay:
         layout += f": {lay['latent_leaves']} latent leaves"
       if self._experts:
@@ -839,9 +855,12 @@ class ContinuousBatchingEngine:
       # ``num_valid`` bounds what the attend reads of each slot's cache
       # (an idle slot: nothing) and how far a recurrence advances; a
       # recurrence must also be told which slots start a request (stale
-      # state is masked by nothing).
-      state_args = dict(reset=reset,
-                        ssm_scan_impl=scan_impl) if recurrent else {}
+      # state is masked by nothing).  A model may have recurrent state,
+      # routed experts and positions of its own at once
+      # (models/lfm2_moe.py): each argument goes to the models that ask.
+      state_args = dict(reset=reset) if recurrent else {}
+      if scan_impl is not None:
+        state_args["ssm_scan_impl"] = scan_impl
       if experts:
         state_args["moe_gmm_impl"] = gmm_impl
       logits, kv, *sown = slot_step_logits(
@@ -857,10 +876,11 @@ class ContinuousBatchingEngine:
       step_keys = jax.vmap(jax.random.fold_in)(keys, tok_index)
       nxt = sample_token_slots(last.astype(jnp.float32), step_keys,
                                temperature, top_k, top_p)
-      # An expert model also hands back ONE float: the busiest expert's
-      # load over the mean, worst layer (``serving/expert_load_max``).
-      nxt = (nxt, jnp.max(jnp.stack(jax.tree_util.tree_leaves(sown)))
-             ) if experts else (nxt,)
+      # An expert model also hands back two floats in ONE array: the
+      # busiest expert's load over the mean, worst layer
+      # (``serving/expert_load_max``), and the fewest experts a layer
+      # touched (``serving/experts_touched_min``).
+      nxt = (nxt, _expert_stats(sown)) if experts else (nxt,)
       if not guard:
         return *nxt, kv, cursors + num_valid
       # In-jit finiteness verdict on exactly the rows commit consumes
@@ -1540,7 +1560,8 @@ class ContinuousBatchingEngine:
     # reads of ``_kv_rows``, and all a roofline of it may count.
     live_kv_rows = plan.live_kv_rows
     routed_positions = int(plan.num_valid.sum()) if self._experts else 0
-    expert_load_max = float(expert_load) if expert_load is not None else 0.0
+    expert_load_max, experts_touched_min = (
+        map(float, expert_load) if expert_load is not None else (0.0, 0.0))
     if tracer.enabled:
       tracer.counter("serving/active_slots", plan.active_slots)
       tracer.counter("serving/sampled_slots", sampled_slots)
@@ -1554,6 +1575,10 @@ class ContinuousBatchingEngine:
         # ``num_experts_per_tok`` experts), and how unevenly they fell.
         tracer.counter("serving/routed_positions", routed_positions)
         tracer.counter("serving/expert_load_max", expert_load_max)
+        # The fewest experts with a live assignment over the step's
+        # expert layers: under the model's expert count, the step did
+        # not stream every expert's weights.
+        tracer.counter("serving/experts_touched_min", experts_touched_min)
       if self.paged:
         # Block-pool occupancy rides the counter tracks next to
         # active_slots, so Perfetto shows pool pressure against load.
@@ -1585,7 +1610,8 @@ class ContinuousBatchingEngine:
           drafted_tokens=drafted, accepted_tokens=accepted,
           sampled_slots=sampled_slots, live_kv_rows=live_kv_rows,
           kv_rows=self._kv_rows, routed_positions=routed_positions,
-          expert_load_max=expert_load_max)
+          expert_load_max=expert_load_max,
+          experts_touched_min=experts_touched_min)
       if self.paged:
         self.stats.note_blocks(self.scheduler.kv_blocks_free,
                                self.scheduler.kv_blocks_used,
@@ -1612,6 +1638,7 @@ class ContinuousBatchingEngine:
       if self._experts:
         record["routed_positions"] = routed_positions
         record["expert_load_max"] = expert_load_max
+        record["experts_touched_min"] = experts_touched_min
       if self.paged:
         # The block-pool gauges (ROADMAP item 1 satellite): pool
         # occupancy, internal fragmentation, and preemption count under

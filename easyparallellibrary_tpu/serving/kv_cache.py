@@ -20,16 +20,17 @@ never attendable because the mask only exposes positions the current
 request's own tokens have written (see slot_cache_attend's docstring;
 tests/test_serving.py asserts the no-leakage property).
 
-Two kinds of per-slot state live in the contiguous cache, chosen per
-layer from the model's own layer kinds (:func:`cache_leaves`): K/V rows
-under the slot's cursor for attention layers, and for recurrent layers
-(models/jamba.py's Mamba mixer) the convolution's last inputs and the
-scan's float32 state, which have no position axis and which no cursor can
-roll back (serving/_capabilities.py ``check_recurrent_state``).  A third
-kind, the LATENT leaf of multi-head latent attention (models/glm_moe.py),
-is addressed under the cursor like K/V but is ONE tensor a layer, whose
-values are its keys' leading columns (``check_latent_cache`` refuses what
-only a K/V pair is built for).
+Four kinds of per-slot state live in the contiguous cache, chosen per
+layer from the model's own layer kinds (:func:`cache_leaves`).  Two are
+rows under the slot's cursor: the K/V pair of an attention layer, and the
+LATENT leaf of multi-head latent attention (models/glm_moe.py), which is
+ONE tensor a layer whose values are its keys' leading columns
+(``check_latent_cache`` refuses what only a K/V pair is built for).  Two
+are recurrent, with no position axis, and no cursor can roll them back
+(serving/_capabilities.py ``check_recurrent_state``): a Mamba layer's
+convolution window and float32 scan state (models/jamba.py), and a CONV
+layer's window alone, the whole state of a gated short convolution
+(models/lfm2_moe.py).
 
 Placement: the cache is materialized directly into its sharded layout on
 the mesh (same jit-with-out-shardings trick as
@@ -74,6 +75,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from easyparallellibrary_tpu import constants
 from easyparallellibrary_tpu.models.glm_moe import LATENT
 from easyparallellibrary_tpu.models.jamba import ATTENTION, MAMBA
+from easyparallellibrary_tpu.models.lfm2_moe import CONV
+
+# Layer kinds whose state is a recurrence's: no position axis, nothing a
+# cursor can roll back.
+RECURRENT = (MAMBA, CONV)
 
 # Pool index of the reserved null/trash block: block tables default-fill
 # with it (unallocated table slots resolve there), and the fused step's
@@ -102,10 +108,18 @@ def layer_kinds(cfg) -> Tuple[str, ...]:
       (ATTENTION,) * cfg.num_layers)
 
 
+def recurrent_kinds(cfg) -> Tuple[str, ...]:
+  """The kinds of recurrent state the model's layers keep (a Mamba layer's
+  window and scan state, a conv layer's window), in :data:`RECURRENT`'s
+  order: what a refusal names."""
+  kinds = layer_kinds(cfg)
+  return tuple(kind for kind in RECURRENT if kind in kinds)
+
+
 def has_recurrent_state(cfg) -> bool:
   """Whether some layer keeps a recurrence's state, which no cursor can
   roll back (serving/_capabilities.py refuses what would need to)."""
-  return MAMBA in layer_kinds(cfg)
+  return bool(recurrent_kinds(cfg))
 
 
 def has_latent_cache(cfg) -> bool:
@@ -144,7 +158,8 @@ def kv_leaf_shape(cfg, num_slots: int, chunk: int) -> Tuple[int, ...]:
 
 def cache_leaves(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
   """The slot cache as shapes: the pytree :func:`allocate_kv_cache`
-  fills, one entry a layer BY ITS KIND — one manager, two kinds of state:
+  fills, one entry a layer BY ITS KIND — one manager for every kind of
+  state:
 
   * attention: ``{"attn": {"cached_key", "cached_value"}}``, each
     ``[num_slots, Lc, H_kv x hd]`` (rows) or ``[num_slots, Lc, H_kv, hd]``
@@ -154,6 +169,9 @@ def cache_leaves(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
     in the compute dtype (the convolution's last inputs, which are
     produced in it), ``"ssm_state": [num_slots, d_state, d_inner]``
     float32}}``, no position axis: the whole state is the request's;
+  * conv: ``{"conv": {"conv_state": [num_slots, conv_L_cache - 1,
+    d_model]}}`` in the compute dtype (the last products the gated short
+    convolution carries), the layer's whole state;
   * latent: ``{"latent": {"cached_latent": [num_slots, Lc, 1,
     kv_lora_rank + qk_rope_head_dim]}}`` in the compute dtype, read under
     the slot's cursor as keys and, its leading ``kv_lora_rank`` columns,
@@ -175,6 +193,10 @@ def cache_leaves(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
               (num_slots, cfg.mamba_d_conv - 1, cfg.d_inner), cfg.dtype),
           "ssm_state": jax.ShapeDtypeStruct(
               (num_slots, cfg.mamba_d_state, cfg.d_inner), jnp.float32)}}
+    elif kind == CONV:
+      out[f"block_{i}"] = {"conv": {
+          "conv_state": jax.ShapeDtypeStruct(
+              (num_slots, cfg.conv_L_cache - 1, cfg.d_model), cfg.dtype)}}
     else:
       raise ValueError(f"layer {i}: no cache for layer kind {kind!r}")
   return out
@@ -265,9 +287,9 @@ def ssm_scan_impl(cfg, num_slots: int, chunk: int,
   """The lowering of the fused step's selective scan over the recurrent
   state :func:`allocate_kv_cache` builds — the dispatch rule of
   kernels/ssm_scan.py applied to its ``ssm_state`` leaf, resolved once
-  like :func:`kv_write_impl`; ``None`` for a model without recurrent
-  state."""
-  if not has_recurrent_state(cfg):
+  like :func:`kv_write_impl`; ``None`` for a model without a Mamba layer
+  (a conv layer's window is advanced by selects XLA fuses: no kernel)."""
+  if MAMBA not in layer_kinds(cfg):
     return None
   from easyparallellibrary_tpu.kernels.ssm_scan import (
       resolve_ssm_scan_impl)
@@ -305,7 +327,7 @@ def allocate_kv_cache(cfg, num_slots: int, chunk: int,
   Returns ``(kv, cursors)``: ``kv`` is a pytree shaped exactly like the
   ``"cache"`` collection the model's slot-mode decode reads/writes
   (:func:`cache_leaves`: K/V leaves for attention layers, convolution and
-  scan state for Mamba layers), all zero; ``cursors`` the int32
+  scan state for Mamba layers, the window of a conv layer), all zero; ``cursors`` the int32
   ``[num_slots]`` write-offset vector (all zero).  With a mesh, every
   leaf materializes already sharded (jit + out_shardings — no
   host-memory spike, no transfer).
@@ -334,14 +356,16 @@ def allocate_kv_cache(cfg, num_slots: int, chunk: int,
 
 def cache_layout(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
   """What the slot cache holds, by kind of state: bytes and leaves of
-  K/V (under a cursor), of recurrent state (no position axis) and, for a
+  K/V (under a cursor), of recurrent state (no position axis: ``state_*``
+  counts a Mamba layer's two leaves and a conv layer's one) and, for a
   model that has them, of latent rows (under a cursor, one leaf a layer);
   and ``kv_order``, the order the leaves under a cursor are kept in
   (``"rows"`` or ``"positions"``: module docstring, order note; ``None``
   for a model that keeps none), which says which form of the window write
   and of the attend a step runs.  The engine records it (trace metadata
   ``serving/cache_layout``)."""
-  names = {ATTENTION: "kv", MAMBA: "state", LATENT: "latent"}
+  names = {ATTENTION: "kv", MAMBA: "state", CONV: "state",
+           LATENT: "latent"}
   kinds = layer_kinds(cfg)
   out = {f"{name}_{what}": 0
          for name in ["kv", "state"] + ["latent"] * (LATENT in kinds)

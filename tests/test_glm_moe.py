@@ -328,7 +328,8 @@ def test_a_dead_position_cannot_change_a_live_one(both, impl):
           kv_write_impl=impl, slot_attn_impl=impl)
     outs.append((np.asarray(lg)[~dead], jax.tree_util.tree_leaves(stats)))
   np.testing.assert_array_equal(outs[0][0], outs[1][0])
-  assert len(outs[0][1]) == 2                     # one load an expert layer
+  # a load and a count of touched experts an expert layer
+  assert len(outs[0][1]) == 4
   np.testing.assert_array_equal(np.asarray(outs[0][1]),
                                 np.asarray(outs[1][1]))
   assert all(1.0 <= float(x) <= 8.0 for x in outs[0][1])

@@ -792,3 +792,51 @@ def test_expert_step_compiled_for_v5e_holds_its_three_kernels(one_chip):
       assert m.group(3) in ("parameter", "bitcast", "get-tuple-element",
                             "custom-call"), line
   assert not re.search(rf"\[{slots * C},64,\d+", text)
+
+
+def test_lfm2_step_compiled_for_v5e_holds_its_three_kernels(one_chip):
+  """The fused step of a two-layer cut (conv + dense, attention + experts)
+  of models/lfm2_moe.py at LFM2-8B-A1B's widths, 128 slots x chunk 16,
+  compiled for a described v5e as the engine builds it for a model with
+  recurrent state AND routed experts: one ``kv_write`` and one
+  ``slot_attn`` over the ``[128, 4112, 512]`` leaf kept in rows (8 K/V
+  heads of 64 under 32 query heads), two ``moe_gmm``, no copy or
+  transpose of a leaf, no ``while`` loop (the window is advanced by
+  selects)."""
+  import types
+  from flax import linen as nn
+  from easyparallellibrary_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
+  epl.init()
+  slots, C = 128, 16
+  cfg = Lfm2MoeConfig(layer_types=("conv", "full_attention"),
+                      num_dense_layers=1, vocab_size=32768)
+  model = Lfm2Moe(cfg)
+  on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+  params = jax.tree_util.tree_map(on_chip, nn.meta.unbox(jax.eval_shape(
+      lambda: model.init(jax.random.PRNGKey(0),
+                         jnp.zeros((1, 8), jnp.int32))["params"])))
+  kv = jax.tree_util.tree_map(on_chip, kv_lib.cache_leaves(cfg, slots, C))
+  engine = types.SimpleNamespace(
+      model=model, chunk=C, kv_write_impl="pallas", slot_attn_impl="pallas",
+      ssm_scan_impl=None, _recurrent=True, moe_gmm_impl="pallas",
+      _experts=True,
+      _jit_step=lambda step, donate, **kw: jax.jit(step,
+                                                   donate_argnums=(1, 2)))
+  step = ContinuousBatchingEngine._build_step(engine, True)
+  spec = lambda shape, d: jax.ShapeDtypeStruct(shape, d, sharding=one_chip)
+  i32, f32 = jnp.int32, jnp.float32
+  text = _compiled_text(
+      step, params, kv, spec((slots,), i32), spec((slots, C), i32),
+      spec((slots,), i32), spec((slots,), jnp.bool_),
+      spec((slots, 2), jnp.uint32), spec((slots,), i32),
+      spec((slots,), f32), spec((slots,), i32), spec((slots,), f32))
+  calls = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
+  assert (calls("kv_write"), calls("slot_attn"), calls("moe_gmm")) == (
+      1, 1, 2), text.count("tpu_custom_call")
+  assert " while(" not in text
+  for line in text.splitlines():
+    m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) (\S+?)\(", line)
+    if m and m.group(2).startswith(f"bf16[{slots},4112,"):
+      assert m.group(3) in ("parameter", "bitcast", "get-tuple-element",
+                            "custom-call"), line
