@@ -45,6 +45,11 @@ a user calls, and checks what comes out by the repo's own references:
                   their reference lowerings; a three-layer cut (conv +
                   dense, attention + experts, conv + experts) through the
                   engine as for ``experts``
+  overlap         (PR 33) GPT-2 medium and the LFM2 cut, 32 requests each
+                  through the engine's overlapped loop (step k+1 launched
+                  before step k's tokens are fetched) and through the
+                  serial one: every stream equal, one compile, no position
+                  wasted; the two median periods side by side
   four chips      (when the machine has four) the trainer as ``data:4``
                   and as ``data:2,model:2``
 
@@ -1237,6 +1242,76 @@ def phase_lfm2(sizes: Sizes) -> None:
       f"logits kernels against reference lowerings {err:.2e}")
 
 
+# ---------------------------------------------------------------- overlap --
+
+
+def serve_both_loops(model, params, sizes: Sizes, what: str, n: int = 32):
+  """``n`` requests through the engine's overlapped loop (step k+1 launched
+  before step k's tokens are fetched) and through the serial one on the
+  same program: every stream equal, one compile each, nothing wasted
+  (every request ends by length), and the median time between two
+  ``step()`` returns of each loop.  The period is a host clock around a
+  loop that waits for the device once a step; only a chip run gives it."""
+  cfg = model.cfg
+  prompts = seeded_requests(sizes, cfg, n=n)
+  streams, period_ms = {}, {}
+  for loop in ("serial", "overlapped"):
+    eng = ContinuousBatchingEngine(model, params, num_slots=n)
+    check(eng.step_overlap == "on",
+          f"{what}: the plain contiguous engine says step overlap "
+          f"{eng.step_overlap!r}")
+    if loop == "serial":
+      eng._overlap = False     # nothing in flight yet: same program
+    eng.submit(Request(uid="warm", prompt=prompts[0][:8], max_new_tokens=4))
+    eng.run()
+    for uid, p in enumerate(prompts):
+      check(eng.submit(Request(uid=uid, prompt=p,
+                               max_new_tokens=sizes.new_tokens)),
+            f"request {uid} refused at admission")
+    out, returns = {}, []
+    while eng.has_work:
+      for fin in eng.step():
+        out[fin.uid] = fin.tokens
+      returns.append(time.perf_counter())
+    for uid, p in enumerate(prompts):
+      check(uid in out and len(out[uid]) == len(p) + sizes.new_tokens
+            and eng.finished[uid].finish_reason == "length",
+            f"{what}, {loop}: request {uid} did not run to its length")
+    check(eng._step_fn._cache_size() == 1,
+          f"{what}, {loop}: fused step compiled "
+          f"{eng._step_fn._cache_size()} times")
+    check(eng.scheduler.wasted_positions == 0,
+          f"{what}, {loop}: {eng.scheduler.wasted_positions} positions "
+          "wasted on requests that end by length")
+    streams[loop] = out
+    period_ms[loop] = 1e3 * float(np.median(np.diff(returns)))
+    say(f"  {what}, {loop} loop: {eng._steps} steps of {n} slots x chunk "
+        f"{eng.chunk}, median period {period_ms[loop]:.3f} ms")
+    eng.close()
+    del eng
+  for uid in range(n):
+    at = first_difference(streams["overlapped"][uid], streams["serial"][uid])
+    check(at is None, f"{what} request {uid}: the overlapped loop's stream "
+          f"differs from the serial loop's at position {at}")
+  return period_ms
+
+
+def phase_overlap(sizes: Sizes) -> None:
+  epl.init(devices=jax.devices()[:1])
+  init = lambda model, seed: jax.jit(lambda: model.init(
+      jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))["params"])()
+  lines = []
+  for what, model, seed in (("GPT-2 medium", GPT(sizes.serve_cfg), 0),
+                            ("lfm2 cut", Lfm2Moe(sizes.lfm2_cfg), 2)):
+    ms = serve_both_loops(model, init(model, seed), sizes, what)
+    lines.append(f"{what} serial {ms['serial']:.3f} ms | overlapped "
+                 f"{ms['overlapped']:.3f} ms")
+  say("PASS overlap: 32 requests a model, every stream of the overlapped "
+      "loop equal to the serial loop's, one compile, none wasted; median "
+      "period " + "; ".join(lines)
+      + (" (REHEARSAL: no measurement)" if sizes.rehearsal else ""))
+
+
 # ------------------------------------------------------------------- main --
 
 
@@ -1261,8 +1336,7 @@ def main(argv=None) -> int:
   parser.add_argument(
       "--only", default=None,
       help="run this one phase (kernels, train, serve, hybrid, experts, "
-           "lfm2); "
-           "prints no result line")
+           "lfm2, overlap); prints no result line")
   args = parser.parse_args(argv)
   t_start = time.perf_counter()
   cache_dir = compile_cache.configure()
@@ -1287,7 +1361,8 @@ def main(argv=None) -> int:
                       ("serve", lambda: phase_serve(sizes)),
                       ("hybrid", lambda: phase_hybrid(sizes)),
                       ("experts", lambda: phase_experts(sizes)),
-                      ("lfm2", lambda: phase_lfm2(sizes))):
+                      ("lfm2", lambda: phase_lfm2(sizes)),
+                      ("overlap", lambda: phase_overlap(sizes))):
     if args.only not in (None, name):
       continue
     t0 = time.perf_counter()

@@ -147,6 +147,11 @@ class ServingStats:
     self._finished_order: Dict[Any, None] = {}
     self.steps = 0
     self.sampling_steps = 0     # steps with a slot at temperature > 0
+    # Steps launched with their predecessor still in flight (the engine's
+    # overlapped loop), and positions that ran for a request which had
+    # retired by the time its step committed.
+    self.overlapped_steps = 0
+    self.wasted_positions = 0
     # Cache rows under the bounds of the steps' live slots, and the rows
     # the cache holds, summed over the steps: their ratio is the share
     # of the cache a bounded attend reads (kernels/slot_attention.py).
@@ -322,8 +327,11 @@ class ServingStats:
                 accepted_tokens: int = 0, sampled_slots: int = 0,
                 live_kv_rows: int = 0, kv_rows: int = 0,
                 routed_positions: int = 0, expert_load_max: float = 0.0,
-                experts_touched_min: float = 0.0):
+                experts_touched_min: float = 0.0, overlapped: int = 0,
+                wasted_positions: int = 0):
     self.steps += 1
+    self.overlapped_steps += int(overlapped)
+    self.wasted_positions += int(wasted_positions)
     if routed_positions > 0:
       self.routed_positions += int(routed_positions)
       self.expert_steps += 1
@@ -390,7 +398,8 @@ class ServingStats:
   # ----------------------------------------------------- wire round trip
 
   _STATE_SCALARS = (
-      "steps", "sampling_steps", "live_kv_rows", "kv_rows",
+      "steps", "sampling_steps", "overlapped_steps", "wasted_positions",
+      "live_kv_rows", "kv_rows",
       "routed_positions", "expert_steps", "expert_load_sum",
       "experts_touched_sum", "busy_time_s", "prefill_tokens",
       "decode_tokens", "finished_requests", "generated_tokens",
@@ -458,6 +467,12 @@ class ServingStats:
                                 if self.steps else 0.0),
         "sampling_step_share": (self.sampling_steps / self.steps
                                 if self.steps else 0.0),
+        # Share of the steps launched while their predecessor still ran
+        # (0.0 on an engine whose loop is serial), and the positions that
+        # ran for requests already retired at their commit.
+        "step_overlap_share": (self.overlapped_steps / self.steps
+                               if self.steps else 0.0),
+        "wasted_positions": float(self.wasted_positions),
         # Share of the K/V cache's rows under a live slot's bound, over
         # the steps: what an attend bounded per slot reads of it.
         "kv_read_share": (self.live_kv_rows / self.kv_rows
@@ -570,6 +585,9 @@ def fleet_summary(replica_stats: List["ServingStats"],
       "slot_occupancy_mean": occ,
       "sampling_step_share": (
           sum(s.sampling_steps for s in stats) / steps if steps else 0.0),
+      "step_overlap_share": (
+          sum(s.overlapped_steps for s in stats) / steps if steps else 0.0),
+      "wasted_positions": float(sum(s.wasted_positions for s in stats)),
       "kv_read_share": (
           sum(s.live_kv_rows for s in stats)
           / max(sum(s.kv_rows for s in stats), 1)),

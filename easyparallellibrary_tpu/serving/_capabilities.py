@@ -66,6 +66,26 @@ FINISH_REASONS = ("length", "stop_token", "deadline", "cancelled",
 PRIORITIES = ("latency", "throughput")
 
 
+def step_overlap(*, paged: bool, speculative: bool, resilient: bool) -> str:
+  """Whether an engine may launch step k+1 before it has fetched step k's
+  tokens (serving/engine.py ``step``): ``"on"``, or ``"off: <reason>"``
+  for the engines whose NEXT plan needs this step's verdict.  Decided
+  once, at construction, from what the engine is; the plain step of the
+  contiguous cache needs nothing but the sampled token, which it takes
+  on the device."""
+  needs = [why for on, why in (
+      (paged, "the paged cache's block tables follow commits and "
+              "preemptions"),
+      (speculative, "the host drafts from committed tokens"),
+      (resilient, "the guarded step's verdict decides whether a cursor "
+                  "moved"),
+  ) if on]
+  if not needs:
+    return "on"
+  return ("off: the next plan needs this step's commit ("
+          + "; ".join(needs) + ")")
+
+
 def check_request_fields(req) -> None:
   """Validate a Request's lifecycle-control fields at submit time, so a
   typo'd priority class or negative deadline fails loudly instead of

@@ -58,6 +58,7 @@ speculative output keeps the sampling distribution, not the bitstream
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import time
 import weakref
@@ -76,7 +77,7 @@ from easyparallellibrary_tpu.observability.registry import (
 from easyparallellibrary_tpu.serving import kv_cache as kv_lib
 from easyparallellibrary_tpu.serving._capabilities import (
     check_draft_fits_chunk, check_latent_cache, check_recurrent_state,
-    check_servable)
+    check_servable, step_overlap)
 from easyparallellibrary_tpu.serving.resilience import (
     AdmissionController, BadStepPolicy, DEGRADE_LEVELS)
 from easyparallellibrary_tpu.serving.scheduler import (
@@ -226,6 +227,23 @@ def _weak_method(obj, name: str):
       return getattr(target, name)(*args, **kwargs)
     return None
   return call
+
+
+@dataclasses.dataclass
+class _LaunchedStep:
+  """A fused step between its launch and its commit: the plan it ran,
+  its outputs as they lie on the device (``tokens``: what the host
+  commits from; ``ok``: the guarded step's verdict), and the stamps of
+  its launch."""
+  plan: Any
+  tokens: tuple
+  ok: Any
+  num_draft: Optional[np.ndarray]
+  t0: float                    # time.monotonic() before the launch
+  t0_us: float                 # tracer clock, before the arguments
+  launched_us: float           # tracer clock, at the launch's return
+  overlapped: bool             # launched with its predecessor in flight
+  xla_ctx: Any = None          # device capture opened for this step
 
 
 class ContinuousBatchingEngine:
@@ -445,6 +463,24 @@ class ContinuousBatchingEngine:
           cfg, "the guarded step (serving.resilience: a retried step "
           "needs the state it started from)")
       check_latent_cache(cfg, "the guarded step (serving.resilience)")
+    # Whether step k+1 is launched while step k still runs (``step``):
+    # "on" for the plain step of the contiguous cache, whose one
+    # dependency on step k, the sampled token, is handed on inside the
+    # device; "off: <reason>" for the engines whose next plan needs this
+    # step's commit.  What the engine is decides, once.
+    self.step_overlap = step_overlap(
+        paged=self.paged, speculative=self.drafter is not None,
+        resilient=self._resilient)
+    self._overlap = self.step_overlap == "on"
+    trace_lib.get_tracer().metadata(
+        f"{self._track_prefix}/step_overlap", {"mode": self.step_overlap})
+    # The launched step whose tokens are still on the device (overlapped
+    # loop only), retirements a drain committed outside ``step()``, and
+    # the stamps the next step's spans and time sample start from.
+    self._inflight: Optional[_LaunchedStep] = None
+    self._held_finished: List[FinishedRequest] = []
+    self._last_fetch_us = 0.0
+    self._last_step_end = 0.0
     self.stats = stats
     if self._resilient and self.stats is None:
       # The degradation ladder reads measured ITL from ServingStats;
@@ -528,6 +564,13 @@ class ContinuousBatchingEngine:
     else:
       self._kv, self._cursors = kv_lib.allocate_kv_cache(
           cfg, self.num_slots, self.chunk, self.mesh)
+    # The plain step's own first output, as it lies on the device: what
+    # the NEXT step reads a ``from_prev`` slot's token from.  Shaped and
+    # placed as the cursors are, so the first call compiles the program
+    # every later call reuses.
+    self._prev_tokens = (jnp.zeros_like(self._cursors)
+                         if not self.paged and self.drafter is None
+                         else None)
     # Quarantine hygiene: a poisoned device step leaves non-finite K/V
     # in a bad slot's cache, and slot_cache_attend's V contraction
     # touches every cache row (0 * NaN = NaN), so the poison must be
@@ -638,10 +681,11 @@ class ContinuousBatchingEngine:
       if self._experts:
         layout += f", {self.moe_gmm_impl} expert matmul"
     get_logger().info(
-        "serving engine: %d slots x chunk %d (%s, %s), "
+        "serving engine: %d slots x chunk %d (%s, %s), step overlap %s, "
         "prefill budget %s, max batch %d, speculation %s, resilience %s",
         self.num_slots, self.chunk, layout,
         "mesh-sharded" if self.mesh is not None else "single-program",
+        self.step_overlap,
         budget or "uncapped", self.scheduler.max_batch,
         f"{type(self.drafter).__name__}(k={self.drafter.k})"
         if self.drafter is not None else "off",
@@ -746,6 +790,8 @@ class ContinuousBatchingEngine:
         "kv_order": (self.cache_layout or {}).get("kv_order"),
         "ssm_scan_impl": self.ssm_scan_impl,
         "moe_gmm_impl": self.moe_gmm_impl,
+        "step_overlap": self.step_overlap,
+        "wasted_positions": sched.wasted_positions,
         "recompiles": self._compile_sentinel.recompiles,
         "active_uids": [str(s.req.uid)
                         for s in sched.active.values()][:32],
@@ -849,8 +895,12 @@ class ContinuousBatchingEngine:
     gmm_impl = self.moe_gmm_impl
     experts = self._experts
 
-    def step(params, kv, cursors, tokens, num_valid, reset, keys,
-             tok_index, temperature, top_k, top_p):
+    def step(params, kv, cursors, tokens, num_valid, reset, prev,
+             from_prev, keys, tok_index, temperature, top_k, top_p):
+      # A slot planned past an uncommitted step (``from_prev``) decodes
+      # from that step's sample, which never left the device: ``prev`` is
+      # the previous step's first output as it lies there.
+      tokens = tokens.at[:, 0].set(jnp.where(from_prev, prev, tokens[:, 0]))
       cursors = jnp.where(reset, 0, cursors)
       # ``num_valid`` bounds what the attend reads of each slot's cache
       # (an idle slot: nothing) and how far a recurrence advances; a
@@ -892,7 +942,7 @@ class ContinuousBatchingEngine:
       return *nxt, slot_ok, kv, jnp.where(slot_ok, cursors + num_valid,
                                           cursors)
 
-    return self._jit_step(step, donate, n_rep_in=8,
+    return self._jit_step(step, donate, n_rep_in=10,
                           n_rep_out=1 + int(experts) + int(guard))
 
   def _build_spec_step(self, donate: bool, guard: bool = False):
@@ -1112,7 +1162,9 @@ class ContinuousBatchingEngine:
     (scheduler.snapshot_requests) — the failover/drain currency of the
     multi-replica router (serving/router.py): restoring them on another
     engine sharing the params source resumes each stream bit-exactly
-    via prefix replay."""
+    via prefix replay.  A step in flight is fetched and committed first;
+    what it retired is returned by the next ``step()``."""
+    self._drain(tolerant=True)
     return self.scheduler.snapshot_requests()
 
   def restore_request(self, snap: Dict[str, Any],
@@ -1132,19 +1184,53 @@ class ContinuousBatchingEngine:
     """Snapshot and REMOVE every queued + in-flight request (no finish
     records — they finish elsewhere).  The router's failover and
     drain-timeout migration path; the engine stays warm (cache, compiled
-    step and watchdog untouched) and can serve again immediately."""
-    return self.scheduler.evacuate()
+    step and watchdog untouched) and can serve again immediately.  A step
+    in flight is DROPPED, never committed: nothing may finish here once
+    its requests are to finish elsewhere, and the replay recomputes the
+    dropped samples bit for bit (the snapshots hold the committed
+    prefix)."""
+    step, self._inflight = self._inflight, None
+    if step is not None:
+      self._close_step(step)
+    return self.scheduler.evacuate()     # forgets the dropped step's plan
 
   @property
   def has_work(self) -> bool:
-    return self.scheduler.has_work
+    """True while anything is queued or active, a step is in flight, or a
+    drain's retirements wait for the next ``step()`` to return them."""
+    return (self.scheduler.has_work or self._inflight is not None
+            or bool(self._held_finished))
+
+  def _take_finished(self) -> List[FinishedRequest]:
+    held, self._held_finished = self._held_finished, []
+    return held + self.scheduler.take_finished()
+
+  def _drain(self, tolerant: bool = False) -> None:
+    """Fetch and commit the step in flight, launching nothing (overlapped
+    loop; a no-op otherwise).  What it retires is held for the next
+    ``step()``.  ``tolerant``: a device that fails the fetch costs the
+    step (the scheduler plans it again), not the caller."""
+    step, self._inflight = self._inflight, None
+    if step is None:
+      return
+    try:
+      self._held_finished.extend(
+          self._finish_step(trace_lib.get_tracer(), step, None))
+    except Exception as e:  # noqa: BLE001 — any device fault
+      if not tolerant:
+        raise
+      get_logger().warning(
+          "the step in flight was lost draining the engine (%s: %s); its "
+          "work is planned again", type(e).__name__, e)
 
   def close(self):
     """Release background resources (the hung-step watchdog thread).
     Idempotent; the engine remains usable for stepping afterwards —
     the watchdog simply stops firing.  Also runs automatically when the
     engine is garbage-collected (or at interpreter exit) and on
-    ``with`` exit, so un-closed engines never leak monitor threads."""
+    ``with`` exit, so un-closed engines never leak monitor threads.  A
+    step in flight is fetched and committed first."""
+    self._drain(tolerant=True)
     if self._watchdog is not None:
       self._watchdog.close()
       self._watchdog = None
@@ -1167,8 +1253,9 @@ class ContinuousBatchingEngine:
     values only — never called with device arrays."""
     if not tracer.enabled:
       return
-    for slot in np.nonzero(plan.num_valid)[0]:
-      slot = int(slot)
+    for slot, state, _, _ in plan.fed:
+      if self.scheduler.active.get(slot) is not state:
+        continue  # retired since it was planned: its span has closed
       track = self._slot_tracks[slot]
       extra = {}
       if self.paged:
@@ -1386,27 +1473,54 @@ class ContinuousBatchingEngine:
               *drafts, plan.num_valid > 0, *sampling)
     if num_draft is None:
       return (self.params, self._kv, self._cursors, plan.tokens,
-              plan.num_valid, plan.reset, *sampling)
+              plan.num_valid, plan.reset, self._prev_tokens,
+              plan.from_prev, *sampling)
     return (self.params, self._kv, self._cursors, plan.tokens,
             plan.num_valid + num_draft, num_draft, plan.reset, *sampling)
 
-  def _dispatch_and_fetch(self, tracer, t0_us: float, step_args,
-                          n_fetch: int):
-    """Launch the fused step and fetch what the host commits from: the
-    one place where all four twins cross to the device and back.  Every
-    twin returns ``(*tokens, [ok,] kv[, cursors])`` with ``n_fetch``
-    token arrays in front.  Returns ``(fetched, slot_ok, t1_us)``.
-
-    Spans, on the ``serving`` track: ``serving/device_step`` from
-    ``t0_us`` (stamped by the caller before it built ``step_args``) to
-    the return of the last fetch, tiled by ``serving/dispatch`` (host:
-    argument transfer and launch; the device has nothing to run while
-    it lasts) and ``serving/fetch`` (the device's run plus the way
-    back).  One clock read more than the device step alone; the
-    benchmark reads all three by name (PERF.md section 3)."""
-    self._note_step_specs(step_args)
-    out = self._step_fn(*step_args)
-    t_launched_us = tracer.now_us()
+  def _launch(self, tracer, plan, overlapped: bool) -> _LaunchedStep:
+    """[draft ->] gather the fused step's arguments and launch it: the
+    host half of the one place where all four twins cross to the device.
+    Returns at once, with the step's outputs still on the device; a
+    launch that raises leaves the plan abandoned (it never ran)."""
+    t0 = time.monotonic()
+    if self._watchdog is not None:
+      self._watchdog.arm(self._steps)
+    xla_ctx = None
+    if (self._pending_xla_dir is not None
+        and (self._inflight is None or self._inflight.xla_ctx is None)):
+      # Deep capture armed a device profile for the step AFTER the
+      # breach (observability.slo.capture_xla): the anomaly's immediate
+      # aftermath is the timeline worth keeping.  One capture at a time:
+      # with one still open around the step in flight, the next launch
+      # takes it.
+      xla_dir, self._pending_xla_dir = self._pending_xla_dir, None
+      xla_ctx = tracer.xla_trace(xla_dir)
+      xla_ctx.__enter__()
+    try:
+      num_draft = None
+      if self.drafter is not None:
+        # Propose BEFORE the token block gains drafts: the draft
+        # model's mirror call needs the same plan the target sees.
+        num_draft = self._propose_drafts(tracer, plan)
+      t0_us = tracer.now_us()
+      step_args = self._step_args(plan, num_draft)
+      self._note_step_specs(step_args)
+      out = self._step_fn(*step_args)
+      launched_us = tracer.now_us()
+    except BaseException:
+      self.scheduler.abandon(plan)
+      if self._watchdog is not None:
+        self._watchdog.disarm()
+      if xla_ctx is not None:
+        xla_ctx.__exit__(None, None, None)
+      raise
+    # Every twin returns ``(*tokens, [ok,] kv[, cursors])``: (next_tokens,)
+    # or, speculative, (committed, n_committed) — commit()'s own
+    # positional arguments — and the plain step of an expert model its
+    # load beside them (speculation is refused for the only such model).
+    n_fetch = ((1 if num_draft is None else 2)
+               + int(self._experts and num_draft is None))
     tokens, state = out[:n_fetch], out[n_fetch:]
     ok_dev = None
     if self._resilient:
@@ -1415,33 +1529,43 @@ class ContinuousBatchingEngine:
       (self._kv,) = state
     else:
       self._kv, self._cursors = state
-    slot_ok = None if ok_dev is None else jax.device_get(ok_dev)
-    # The step's ONE designated token fetch: explicit (device_get),
-    # so it stays visible — and legal — under
-    # jax.transfer_guard_device_to_host("disallow"); any OTHER
-    # device->host crossing in this loop is a bug the guard (and
-    # epl-lint's host-sync rule) catches.
-    fetched = [jax.device_get(t) for t in tokens]
-    t1_us = tracer.now_us()
-    tracer.span_at(
-        "serving/device_step", t0_us, t1_us, cat="serving",
-        track="serving",
-        children=(("serving/dispatch", t0_us, t_launched_us),
-                  ("serving/fetch", t_launched_us, t1_us)))
-    return fetched, slot_ok, t1_us
+    if self._prev_tokens is not None:
+      self._prev_tokens = tokens[0]
+    return _LaunchedStep(plan=plan, tokens=tokens, ok=ok_dev,
+                         num_draft=num_draft, t0=t0, t0_us=t0_us,
+                         launched_us=launched_us, overlapped=overlapped,
+                         xla_ctx=xla_ctx)
+
+  def _close_step(self, step: _LaunchedStep) -> None:
+    """What ends with a launched step whatever became of it: the
+    watchdog's arm and a device capture opened for it."""
+    if self._watchdog is not None:
+      self._watchdog.disarm()
+    if step.xla_ctx is not None:
+      step.xla_ctx.__exit__(None, None, None)
 
   def step(self) -> List[FinishedRequest]:
-    """One engine iteration: [degrade ->] plan -> [draft ->] fused
-    device step -> commit [-> bad-step policy].  Returns the requests
-    that retired this iteration (empty when idle), expiries and
-    cancellations included."""
+    """One engine iteration: [degrade ->] plan -> [draft ->] launch the
+    fused device step -> fetch -> commit [-> bad-step policy].  Returns
+    the requests that retired this iteration (empty when idle), expiries
+    and cancellations included.
+
+    Overlapped (``step_overlap == "on"``), the same stages run on TWO
+    steps: with step k in flight the call plans k+1, launches k+1, THEN
+    fetches k's tokens and commits k, so planning, the argument upload,
+    the launch, the commit and whatever the caller does between two calls
+    run while the device executes, and it goes from k to k+1 without
+    waiting for the host.  A caller sees a step's retirements one call
+    later than its launch, and ``has_work`` stays true while a step is in
+    flight; an engine with nothing in flight launches at once."""
     tracer = trace_lib.get_tracer()
     if self._autotuner is not None:
       # Knob moves land HERE — strictly between fused-step dispatches,
       # steering the plan built just below (compile-once: data only).
       self._autotuner.on_step(self._steps)
+    running = self._inflight
     with tracer.span("serving/plan", cat="serving", track="serving"):
-      plan = self.scheduler.plan_step()
+      plan = self.scheduler.plan_step(ahead=running is not None)
     if self._admission is not None:
       # Observe AFTER admission: the ladder's queue signal is the
       # backlog this step could NOT absorb — a one-shot burst that
@@ -1450,44 +1574,76 @@ class ContinuousBatchingEngine:
       # The resulting gates steer the NEXT plan; one step of lag is
       # the price of measuring the right signal.
       self._apply_degradation()
-    if plan is None:
-      # No device work, but plan-time expiries may have retired
-      # requests (e.g. every queued request's deadline passed).
-      return self.scheduler.take_finished()
-    t0 = time.monotonic()
-    if self._watchdog is not None:
-      self._watchdog.arm(self._steps)
-    xla_ctx = None
-    if self._pending_xla_dir is not None:
-      # Deep capture armed a device profile for the step AFTER the
-      # breach (observability.slo.capture_xla): the anomaly's immediate
-      # aftermath is the timeline worth keeping.
-      xla_dir, self._pending_xla_dir = self._pending_xla_dir, None
-      xla_ctx = tracer.xla_trace(xla_dir)
-      xla_ctx.__enter__()
+    launched = None
+    if plan is not None:
+      launched = self._launch(tracer, plan, overlapped=running is not None)
+    # The serial loop fetches what it just launched; the overlapped one
+    # what the call before launched, and leaves this launch in flight.
+    if self._overlap:
+      self._inflight = launched
+    done = running if self._overlap else launched
+    if done is None:
+      if launched is not None:
+        # The first step of a burst: nothing to wait for yet.
+        tracer.span_at(
+            "serving/device_step", launched.t0_us, launched.launched_us,
+            cat="serving", track="serving",
+            children=(("serving/dispatch", launched.t0_us,
+                       launched.launched_us),))
+      # No device work to commit, but plan-time expiries may have
+      # retired requests (e.g. every queued request's deadline passed).
+      return self._take_finished()
+    finished = self._finish_step(tracer, done, launched)
+    return self._take_finished() + finished
+
+  def _finish_step(self, tracer, step: _LaunchedStep,
+                   launched: Optional[_LaunchedStep]
+                   ) -> List[FinishedRequest]:
+    """Fetch what ``step`` left on the device, commit it and record it:
+    the device -> host half.  ``launched`` is the step this call launched
+    before it (``step`` itself in the serial loop, None in a drain).
+
+    Spans, on the ``serving`` track: ``serving/device_step`` from the
+    stamp before the launched step's arguments were gathered to the
+    return of the last fetch, tiled by ``serving/dispatch`` (host:
+    argument transfer and launch) and ``serving/fetch`` (the wait for
+    ``step``'s tokens and the way back).  In the serial loop both are of
+    one step and the device has nothing to run during the dispatch;
+    overlapped, the dispatch is of step k+1 and the fetch of step k,
+    which runs meanwhile.  Either way one dispatch starts per step.  The
+    benchmark reads all three by name (PERF.md section 3)."""
+    plan = step.plan
     drafted = accepted = 0
-    slot_ok = None
-    num_draft = None
+    num_draft = step.num_draft
     expert_load = None
+    t_fetch_us = (launched.launched_us if launched is not None
+                  else tracer.now_us())
     try:
-      if self.drafter is not None:
-        # Propose BEFORE the token block gains drafts: the draft
-        # model's mirror call needs the same plan the target sees.
-        num_draft = self._propose_drafts(tracer, plan)
-      t0_us = tracer.now_us()
-      # The plain step of an expert model returns its load beside the
-      # tokens (speculation is refused for the only such model).
-      with_load = self._experts and num_draft is None
-      fetched, slot_ok, t1_us = self._dispatch_and_fetch(
-          tracer, t0_us, self._step_args(plan, num_draft),
-          n_fetch=(1 if num_draft is None else 2) + int(with_load))
-      if with_load:
+      slot_ok = None if step.ok is None else jax.device_get(step.ok)
+      # The step's ONE designated token fetch: explicit (device_get),
+      # so it stays visible — and legal — under
+      # jax.transfer_guard_device_to_host("disallow"); any OTHER
+      # device->host crossing in this loop is a bug the guard (and
+      # epl-lint's host-sync rule) catches.
+      fetched = [jax.device_get(t) for t in step.tokens]
+      t1_us = tracer.now_us()
+      children = (("serving/fetch", t_fetch_us, t1_us),)
+      if launched is not None:
+        children = (("serving/dispatch", launched.t0_us,
+                     t_fetch_us),) + children
+      tracer.span_at(
+          "serving/device_step",
+          launched.t0_us if launched is not None else t_fetch_us, t1_us,
+          cat="serving", track="serving", children=children)
+      if self._experts and num_draft is None:
         *fetched, expert_load = fetched
-      # ``fetched`` is (next_tokens,) or, speculative, (committed,
-      # n_committed): commit()'s own positional arguments.
       n_committed = fetched[1] if num_draft is not None else None
-      self._trace_slot_spans(tracer, plan, t0_us, t1_us,
+      # The device ran this step from its launch or, overlapped, from
+      # the end of its predecessor: the slots' spans tile their tracks.
+      self._trace_slot_spans(tracer, plan,
+                             max(step.t0_us, self._last_fetch_us), t1_us,
                              num_draft, n_committed)
+      self._last_fetch_us = t1_us
       with tracer.span("serving/commit", cat="serving", track="serving"):
         finished = self.scheduler.commit(*fetched, slot_ok=slot_ok)
         if self.drafter is not None:
@@ -1501,11 +1657,16 @@ class ContinuousBatchingEngine:
         speculated = (num_draft > 0) & ok
         drafted = int(num_draft[ok].sum())
         accepted = int((n_committed[speculated] - 1).sum())
-    finally:
-      if self._watchdog is not None:
-        self._watchdog.disarm()
-      if xla_ctx is not None:
-        xla_ctx.__exit__(None, None, None)
+    except BaseException:
+      # The step's output is lost to the scheduler, and with it the step
+      # launched past it: back to the committed state.
+      self.scheduler.abandon()
+      later, self._inflight = self._inflight, None
+      for lost in (step, later):
+        if lost is not None:
+          self._close_step(lost)
+      raise
+    self._close_step(step)
     if slot_ok is not None:
       self._handle_bad_slots(plan, slot_ok)
       # Quarantine retirements ("failed") belong to this iteration.
@@ -1515,7 +1676,11 @@ class ContinuousBatchingEngine:
     # thunk only runs on the (rare) recompile path.
     self._compile_sentinel.check(
         signature_fn=lambda: self._describe_signature(plan))
-    dt = time.monotonic() - t0
+    # The step's time runs from its launch or, overlapped, from the end
+    # of its predecessor (the device ran them one after the other).
+    now = time.monotonic()
+    dt = now - max(step.t0, self._last_step_end)
+    self._last_step_end = now
     # Device introspection runs BELOW the dt cut, like every other
     # publish path: the warmup capture's AOT compile and the HBM
     # gauges' per-device memory_stats host RPC must never inflate the
@@ -1562,8 +1727,14 @@ class ContinuousBatchingEngine:
     routed_positions = int(plan.num_valid.sum()) if self._experts else 0
     expert_load_max, experts_touched_min = (
         map(float, expert_load) if expert_load is not None else (0.0, 0.0))
+    # Whether the step was launched with its predecessor in flight (0:
+    # the pipeline was empty, the first step after idle or a drain), and
+    # the positions it ran for requests that had retired by its commit.
+    overlapped = int(step.overlapped)
     if tracer.enabled:
       tracer.counter("serving/active_slots", plan.active_slots)
+      tracer.counter("serving/overlapped_steps", overlapped)
+      tracer.counter("serving/wasted_positions", plan.wasted)
       tracer.counter("serving/sampled_slots", sampled_slots)
       tracer.counter("serving/live_kv_rows", live_kv_rows)
       if self._recurrent:
@@ -1611,7 +1782,8 @@ class ContinuousBatchingEngine:
           sampled_slots=sampled_slots, live_kv_rows=live_kv_rows,
           kv_rows=self._kv_rows, routed_positions=routed_positions,
           expert_load_max=expert_load_max,
-          experts_touched_min=experts_touched_min)
+          experts_touched_min=experts_touched_min,
+          overlapped=overlapped, wasted_positions=plan.wasted)
       if self.paged:
         self.stats.note_blocks(self.scheduler.kv_blocks_free,
                                self.scheduler.kv_blocks_used,
@@ -1634,6 +1806,8 @@ class ContinuousBatchingEngine:
           "prefill_tokens": pf_tokens,
           "decode_tokens": dc_tokens,
           "step_time_s": dt,
+          "overlapped_steps": overlapped,
+          "wasted_positions": plan.wasted,
       }
       if self._experts:
         record["routed_positions"] = routed_positions
@@ -1713,13 +1887,18 @@ class ContinuousBatchingEngine:
           ) -> Dict[Any, np.ndarray]:
     """Drive until the queue drains (or ``max_steps``); returns
     ``{uid: prompt+generated}`` for every request finished during the
-    call (finish reasons: ``self.finished[uid].finish_reason``)."""
+    call (finish reasons: ``self.finished[uid].finish_reason``).  A step
+    still in flight when ``max_steps`` cuts the drive is fetched and
+    committed before the call returns: every step launched has committed."""
     out: Dict[Any, np.ndarray] = {}
     steps = 0
     while self.has_work and (max_steps is None or steps < max_steps):
       for fin in self.step():
         out[fin.uid] = fin.tokens
       steps += 1
+    self._drain()
+    for fin in self._take_finished():
+      out[fin.uid] = fin.tokens
     if self.registry is not None and self.stats is not None:
       # End-of-drive rollup (tokens/s, TTFT/ITL percentiles, occupancy,
       # speculation + resilience counters) under the serving/* namespace.

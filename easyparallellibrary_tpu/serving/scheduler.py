@@ -38,7 +38,9 @@ Responsibilities (and nothing else — device work lives in engine.py):
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import itertools
 import time
 import zlib
@@ -86,16 +88,34 @@ def next_flow_id() -> int:
 SNAPSHOT_VERSION = 2
 
 
+@functools.lru_cache(maxsize=None)
+def _host_device():
+  """The host's own JAX device, or None in a process whose JAX was held
+  to the accelerator alone."""
+  try:
+    return jax.devices("cpu")[0]
+  except RuntimeError:
+    return None
+
+
 def _request_key(req: "Request") -> np.ndarray:
   """The request's private PRNG stream key.  Deterministic in
   ``seed``/``uid`` and stable across processes (crc32, not Python's
   per-process-salted hash()), so a request migrated to another replica
-  — or a restarted server — reproduces the identical sample stream."""
+  — or a restarted server — reproduces the identical sample stream.
+
+  Made on the host's own device where there is one: on the accelerator
+  the little program would queue behind the fused step in flight, and
+  reading the key back would hold the plan of the next step until that
+  step had finished (serving/engine.py, the overlapped loop)."""
   if req.seed is not None:
     seed = req.seed
   else:
     seed = zlib.crc32(str(req.uid).encode())
-  return np.asarray(jax.random.PRNGKey(seed))
+  host = _host_device()
+  with (jax.default_device(host) if host is not None
+        else contextlib.nullcontext()):
+    return np.asarray(jax.random.PRNGKey(seed))
 
 
 @dataclasses.dataclass
@@ -218,7 +238,7 @@ class FinishedRequest:
   finish_reason: str          # serving._capabilities.FINISH_REASONS
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(eq=False)
 class StepPlan:
   """Device-ready arrays for one fused engine step (all [N] or [N, C])."""
   tokens: np.ndarray          # int32 [N, C] token chunk per slot
@@ -237,9 +257,22 @@ class StepPlan:
   # Cache rows under the bounds of the slots this step feeds: the sum of
   # cursor + num_valid (the host's mirror of the device cursor).
   live_kv_rows: int = 0
+  # Slots whose first chunk position is the PREVIOUS step's sample, which
+  # the host has not seen yet (planned past an uncommitted step): the
+  # engine's step takes it from that step's output on the device.
+  from_prev: Optional[np.ndarray] = None    # bool [N]
+  # What commit() needs of the plan it belongs to, whatever was planned
+  # or retired since: ``(slot, state, prefix positions fed, sampled)`` of
+  # the slots fed, in admission order, each with the state it was fed
+  # from; ``sampled``: the slot's sample is a generated token (its prefix
+  # ends inside this plan's feed).
+  fed: List[Any] = dataclasses.field(default_factory=list)
+  # Set by commit(): slots whose request had retired by then (a stop
+  # token or a cancellation seen one step late), their position dropped.
+  wasted: int = 0
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(eq=False)
 class PagedStepPlan:
   """Device-ready arrays for one token-flat fused step over the paged
   cache (serving/engine.py paged mode).  Flat arrays are [T] —
@@ -268,6 +301,8 @@ class PagedStepPlan:
   scheduled_tokens: int       # live flat positions (diagnostics)
   active_slots: int
   live_kv_rows: int = 0       # as StepPlan's: resident + scheduled rows
+  fed: List[Any] = dataclasses.field(default_factory=list)  # as StepPlan's
+  wasted: int = 0
 
 
 class _SlotState:
@@ -276,12 +311,19 @@ class _SlotState:
   ``prefix`` is what chunked prefill feeds: the prompt for a fresh
   request, prompt + already-committed tokens for a requeued one (the
   replay that reconstructs the slot's KV/cursor state exactly).
+
+  ``prompt_pos`` and ``generated`` are COMMITTED state: what snapshots,
+  requeues and the paged cache read.  ``fed_ahead`` / ``samples_ahead``
+  are what the outstanding (planned, not yet committed) plans add to
+  them: prefix positions fed, and generated tokens sampled whose values
+  the host has not seen.  Both are zero between a commit and the next
+  plan of the serial loop.
   """
 
   __slots__ = ("req", "slot", "prompt_pos", "generated", "key", "prefix",
                "submitted_at", "admitted_at", "first_token_at",
                "first_token_emitted", "requeues", "bad_streak",
-               "admit_seq", "reg_blocks")
+               "admit_seq", "reg_blocks", "fed_ahead", "samples_ahead")
 
   def __init__(self, req: Request, slot: int, submitted_at: float,
                now: float, carried: Optional["_SlotState"] = None,
@@ -289,6 +331,8 @@ class _SlotState:
     self.req = req
     self.slot = slot
     self.prompt_pos = 0                    # prefix tokens already fed
+    self.fed_ahead = 0
+    self.samples_ahead = 0
     self.submitted_at = submitted_at
     self.admitted_at = now
     self.bad_streak = 0                    # consecutive bad-step hits
@@ -321,6 +365,16 @@ class _SlotState:
   @property
   def prefilling(self) -> bool:
     return self.prompt_pos < len(self.prefix)
+
+  @property
+  def planned_pos(self) -> int:
+    """Prefix positions fed once every outstanding plan has run."""
+    return self.prompt_pos + self.fed_ahead
+
+  @property
+  def planned_generated(self) -> int:
+    """Tokens generated once every outstanding plan has run."""
+    return len(self.generated) + self.samples_ahead
 
 
 class _Pending:
@@ -366,6 +420,15 @@ class FCFSScheduler:
   ``commit(next_tokens)`` folds the step's sampled tokens back into
   per-request state and returns the requests that retired.  The engine
   owns the device half of the loop.
+
+  A plan advances its slots PROVISIONALLY (``_SlotState.fed_ahead`` /
+  ``samples_ahead``) and ``commit`` settles the oldest outstanding plan,
+  so ``plan_step(ahead=True)`` can plan step k+1 while step k's tokens
+  are still on the device: at most two plans are outstanding, committed
+  in order.  What the host learns only at commit k (a stop token, a
+  cancellation or a deadline seen since) retires the request then; its
+  position in the already planned k+1 is dropped at commit k+1
+  (``StepPlan.wasted``, ``wasted_positions``).
 
   The ``on_admit`` / ``on_first_token`` / ``on_finish`` hooks are LISTS
   of subscribers (append, don't assign) so stats, resilience and user
@@ -494,7 +557,11 @@ class FCFSScheduler:
     self._deadline_active = 0
     self.active: Dict[int, _SlotState] = {}   # slot -> state
     self._admit_order: List[int] = []         # slots, admission order
-    self._plan: Optional[StepPlan] = None
+    # Planned, not yet committed, oldest first (class docstring).
+    self._plans: Deque[Any] = deque()
+    # Positions of slots whose request had retired by the time their
+    # plan committed (cumulative).
+    self.wasted_positions = 0
     self._finished_buffer: List[FinishedRequest] = []
     self.on_admit: List[Callable[[Any], None]] = []      # fn(uid)
     self.on_first_token: List[Callable[[Any], None]] = []  # fn(uid)
@@ -891,7 +958,7 @@ class FCFSScheduler:
     self.pending.clear()
     self._latency_pending = 0
     self._deadline_pending = 0
-    self._plan = None
+    self._plans.clear()
     return snaps
 
   # ----------------------------------------------------------------- plan
@@ -919,8 +986,9 @@ class FCFSScheduler:
     if budget_left > 0:
       # Already-active prefill slots have first claim on the budget.
       budget_left -= sum(
-          min(self.chunk, len(s.prefix) - s.prompt_pos)
-          for s in self.active.values() if s.prefilling)
+          min(self.chunk, len(s.prefix) - s.planned_pos)
+          for s in self.active.values()
+          if s.planned_pos < len(s.prefix))
     while self.pending:
       idx = self._next_pending_index()
       entry = self.pending[idx]
@@ -1011,10 +1079,11 @@ class FCFSScheduler:
     prefill this is the fed prefix; after it, the decode input token's
     position is always ``len(prompt) + len(generated) - 1`` (a requeued
     replay's generated prefix is both inside ``prefix`` AND in
-    ``generated``, which this accounting absorbs)."""
-    if state.prefilling:
-      return state.prompt_pos
-    return len(state.req.prompt) + len(state.generated) - 1
+    ``generated``, which this accounting absorbs).  Counted past the
+    outstanding plans: what is resident once they have run."""
+    if state.planned_pos < len(state.prefix):
+      return state.planned_pos
+    return len(state.req.prompt) + state.planned_generated - 1
 
   def slot_blocks(self, slot: int) -> List[int]:
     """The slot's current block list (engine sanitize + tests)."""
@@ -1224,7 +1293,6 @@ class FCFSScheduler:
     lowest-priority slot, and a still-short allocation shrinks the grant
     (the slot resumes next step)."""
     if not self.active:
-      self._plan = None
       return None
     T, N, MB = self.token_budget, self.num_slots, self._mb
     plan = PagedStepPlan(
@@ -1339,17 +1407,44 @@ class FCFSScheduler:
       plan.top_p[slot] = req.top_p
       plan.live_kv_rows += (self._resident_tokens(state)
                             + int(plan.num_valid[slot]))
+      self._note_fed(plan, slot, state)
     plan.scheduled_tokens = pos
     plan.block_tables = self._tables.copy()
     if pos == 0:
       # Every active slot starved (pool exhausted, budget zero): no
       # device work this iteration.
-      self._plan = None
       return None
-    self._plan = plan
+    self._plans.append(plan)
     return plan
 
-  def plan_step(self) -> Optional[StepPlan]:
+  def _note_fed(self, plan, slot: int, state: _SlotState) -> None:
+    """Record that ``plan`` feeds ``slot`` from ``state`` and advance the
+    state provisionally by it (class docstring); ``_settle`` takes the
+    advance back when the plan commits or is abandoned."""
+    fed = int(plan.num_valid[slot]) if plan.prefilling[slot] else 0
+    state.fed_ahead += fed
+    # The slot's sample is a generated token once its prefix is fed.
+    sampled = state.planned_pos >= len(state.prefix)
+    state.samples_ahead += sampled
+    plan.fed.append((slot, state, fed, sampled))
+
+  @staticmethod
+  def _settle(plan) -> None:
+    """Take back what ``plan`` advanced provisionally."""
+    for _, state, fed, sampled in plan.fed:
+      state.fed_ahead -= fed
+      state.samples_ahead -= sampled
+
+  def abandon(self, plan=None) -> None:
+    """Forget ``plan`` (default: every outstanding plan) without
+    committing it: a step that was planned and never ran, or whose
+    output was lost.  The committed state is untouched, so the next plan
+    feeds the same work again."""
+    for old in ([plan] if plan is not None else list(self._plans)):
+      self._plans.remove(old)
+      self._settle(old)
+
+  def plan_step(self, ahead: bool = False) -> Optional[StepPlan]:
     """Build the next fused step's inputs, or None when idle.
 
     Order: expire dead requests, admit (priority first, then FCFS),
@@ -1358,7 +1453,21 @@ class FCFSScheduler:
     prefill chunks are granted FCFS in admission order until the
     per-step budget runs out — a starved prefill slot simply carries
     ``num_valid=0`` this step and resumes next step.
+
+    ``ahead=True`` plans PAST the one outstanding plan, whose step is
+    still running (class docstring): each slot goes on from where that
+    plan leaves it, a slot that plan brings to ``max_new_tokens`` is fed
+    nothing more (it retires at that plan's commit), and a decoding
+    slot's input token is that plan's sample (``from_prev``).  Without
+    it an outstanding plan is a step that never ran: it is abandoned and
+    planned again.
     """
+    if not ahead:
+      self.abandon()
+    elif len(self._plans) > 1 or self.paged:
+      raise RuntimeError(
+          "plan_step(ahead=True) plans past ONE uncommitted step of the "
+          "contiguous cache; commit() the oldest plan first")
     self.expire()
     if self.prefix_cache is not None:
       # Session TTL sweep before admission, so an expired session can
@@ -1369,7 +1478,6 @@ class FCFSScheduler:
     if self.paged:
       return self._plan_flat()
     if not self.active:
-      self._plan = None
       return None
     N, C = self.num_slots, self.chunk
     plan = StepPlan(
@@ -1384,7 +1492,7 @@ class FCFSScheduler:
         draft_cap=np.zeros((N,), np.int32),
         prefilling=np.zeros((N,), bool),
         prefill_tokens=0, decode_tokens=0,
-        active_slots=len(self.active))
+        active_slots=len(self.active), from_prev=np.zeros((N,), bool))
     budget = self._effective_budget()
     spec_k = self.effective_spec_k        # hoisted: loop-invariant
     for slot in self._admit_order:
@@ -1392,28 +1500,38 @@ class FCFSScheduler:
       if state is None:
         continue
       req = state.req
+      # Where the outstanding plan (if any) leaves the slot.
+      pos = state.planned_pos
+      generated = state.planned_generated
+      prefilling = pos < len(state.prefix)
+      if not prefilling and generated >= req.max_new_tokens:
+        # Its last token is on the device: nothing more to feed.  The
+        # slot is held, and idle, until that step commits and retires it.
+        plan.active_slots -= 1
+        continue
       plan.keys[slot] = state.key
-      plan.tok_index[slot] = len(state.generated)
+      plan.tok_index[slot] = generated
       plan.temperature[slot] = req.temperature
       plan.top_k[slot] = req.top_k
       plan.top_p[slot] = req.top_p
       # Nothing fed yet (fresh slot, or a requeued request starting its
       # replay): zero the cursor before this step's writes.
-      plan.reset[slot] = state.prompt_pos == 0
-      if state.prefilling:
-        remaining = len(state.prefix) - state.prompt_pos
-        grant = min(C, remaining)
+      plan.reset[slot] = pos == 0
+      if prefilling:
+        grant = min(C, len(state.prefix) - pos)
         if budget > 0:
           grant = min(grant, max(budget - plan.prefill_tokens, 0))
         if grant == 0:
           continue  # budget-starved this step; resumes next step
-        chunk = state.prefix[state.prompt_pos:state.prompt_pos + grant]
-        plan.tokens[slot, :grant] = chunk
+        plan.tokens[slot, :grant] = state.prefix[pos:pos + grant]
         plan.num_valid[slot] = grant
         plan.prefilling[slot] = True
         plan.prefill_tokens += grant
       else:
-        plan.tokens[slot, 0] = state.generated[-1]
+        if state.samples_ahead:
+          plan.from_prev[slot] = True   # the sample is still on the device
+        else:
+          plan.tokens[slot, 0] = state.generated[-1]
         plan.num_valid[slot] = 1
         plan.decode_tokens += 1
         if (spec_k > 0 and self.spec_enabled
@@ -1421,11 +1539,14 @@ class FCFSScheduler:
           # Drafting past the request's remaining budget is pure waste:
           # at most (remaining - 1) drafts can commit alongside the
           # step's guaranteed token.
-          remaining = req.max_new_tokens - len(state.generated)
+          remaining = req.max_new_tokens - generated
           plan.draft_cap[slot] = max(0, min(spec_k, remaining - 1))
       plan.live_kv_rows += (self._resident_tokens(state)
                             + int(plan.num_valid[slot]))
-    self._plan = plan
+      self._note_fed(plan, slot, state)
+    if not plan.fed and ahead:
+      return None   # every slot's remaining work is already on the device
+    self._plans.append(plan)
     return plan
 
   def slot_histories(self, plan: StepPlan) -> Dict[int, np.ndarray]:
@@ -1505,23 +1626,27 @@ class FCFSScheduler:
     commits apply stop-token and ``max_new_tokens`` checks PER TOKEN in
     commit order, so a stop token appearing mid-draft retires the
     request and discards the rest of its accepted drafts."""
-    if self._plan is None:
+    if not self._plans:
       raise RuntimeError("commit() without a preceding plan_step()")
-    plan, self._plan = self._plan, None
+    plan = self._plans.popleft()
+    self._settle(plan)
     tokens = np.asarray(next_tokens)
     if tokens.ndim == 1:
       tokens = tokens[:, None]
     if num_committed is None:
       num_committed = np.ones((tokens.shape[0],), np.int32)
     now = self.clock()
-    for slot in list(self._admit_order):
-      state = self.active.get(slot)
-      if state is None or plan.num_valid[slot] == 0:
+    for slot, state, _, _ in plan.fed:
+      if self.active.get(slot) is not state:
+        # Retired (or requeued) since the plan was made: a stop token, a
+        # cancellation or a deadline seen one step late.  The position
+        # ran for nothing and its sample goes nowhere.
+        plan.wasted += 1
         continue
       if slot_ok is not None and not slot_ok[slot]:
         continue  # bad step: state untouched — next plan retries exactly
       req = state.req
-      if state.prefilling:
+      if plan.prefilling[slot]:
         state.prompt_pos += int(plan.num_valid[slot])
         if state.prefilling:
           # More prompt to feed; discard the sample — but the chunk
@@ -1572,4 +1697,5 @@ class FCFSScheduler:
       # registered via _retire; `is state` guards the stale reference.
       if self.prefix_cache is not None and self.active.get(slot) is state:
         self._register_cached(state)
+    self.wasted_positions += plan.wasted
     return self.take_finished()

@@ -779,6 +779,7 @@ def test_expert_step_compiled_for_v5e_holds_its_three_kernels(one_chip):
   text = _compiled_text(
       step, params, kv, spec((slots,), i32), spec((slots, C), i32),
       spec((slots,), i32), spec((slots,), jnp.bool_),
+      spec((slots,), i32), spec((slots,), jnp.bool_),   # prev, from_prev
       spec((slots, 2), jnp.uint32), spec((slots,), i32),
       spec((slots,), f32), spec((slots,), i32), spec((slots,), f32))
   calls = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
@@ -829,6 +830,7 @@ def test_lfm2_step_compiled_for_v5e_holds_its_three_kernels(one_chip):
   text = _compiled_text(
       step, params, kv, spec((slots,), i32), spec((slots, C), i32),
       spec((slots,), i32), spec((slots,), jnp.bool_),
+      spec((slots,), i32), spec((slots,), jnp.bool_),   # prev, from_prev
       spec((slots, 2), jnp.uint32), spec((slots,), i32),
       spec((slots,), f32), spec((slots,), i32), spec((slots,), f32))
   calls = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
