@@ -6,9 +6,11 @@ prints, without leaving the terminal for Perfetto:
 * a **span table** — per span name: count, total/mean/p50/p99 duration
   and share of the trace's wall clock (where did the run's time go);
 * **request timelines** — per serving request: queue wait, prefill
-  time/chunks, decode steps, speculation drafted/accepted, TTFT,
+  time/steps, decode time/steps, speculation drafted/accepted, TTFT,
   total latency and finish reason (where did THIS request's latency
-  go); merged multi-process traces with front-door instrumentation
+  go), read from its ``serving/queued``, ``serving/prefill`` and
+  ``serving/decode`` phase spans; merged multi-process traces with
+  front-door instrumentation
   add the hop decomposition — client-observed TTFT, ingress and wire
   columns (docs/observability.md "Distributed tracing");
 * with ``--metrics <metrics.jsonl>``, the **fleet rollup** — the last
@@ -101,8 +103,12 @@ def span_table(spans: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
 def request_timelines(events: List[Dict[str, Any]]
                       ) -> List[Dict[str, Any]]:
   """Per-request lifecycle rollup from the serving instrumentation:
-  request spans (cat ``serving.request``), the prefill/decode/speculate
-  chunk spans nested in them, and the submit/first_token instants —
+  request spans (cat ``serving.request``), the ``serving/prefill`` and
+  ``serving/decode`` phase spans that tile them (their ``steps`` and
+  ``tokens`` args; ``prefill_us`` / ``decode_us`` are the phases' wall
+  time, steps that starved the slot of budget included), the
+  ``serving/queued`` span that ended where the occupancy began, and the
+  submit/first_token instants —
   plus the resilience events (docs/robustness.md "Serving resilience"):
   per-uid requeue counts, and rows for requests that never reached a
   slot (shed at submit, expired or cancelled in the queue), whose whole
@@ -116,6 +122,13 @@ def request_timelines(events: List[Dict[str, Any]]
   to first SSE byte: harvest-rebased wire + stream-delivery gap; small
   negatives are clock-offset noise and reported as-is)."""
   spans, _ = pair_spans(events)
+  # uid -> its waits in the queue, ``(end, duration)``: one an admission,
+  # and one that ended there for a request that never got a slot.
+  queued: Dict[str, List[Tuple[float, float]]] = {}
+  for sp in spans:
+    if sp["name"] == "serving/queued" and "uid" in sp["args"]:
+      queued.setdefault(str(sp["args"]["uid"]), []).append(
+          (sp["ts"] + sp["dur"], sp["dur"]))
   submits: Dict[str, float] = {}
   first_tokens: Dict[str, float] = {}
   fd_requests: Dict[str, float] = {}
@@ -155,24 +168,30 @@ def request_timelines(events: List[Dict[str, Any]]
              if s["pid"] == req["pid"] and s["tid"] == req["tid"]
              and s["name"] != req["name"]
              and t0 <= s["ts"] and s["ts"] + s["dur"] <= t1 + 1e-9]
-    phase_us = {ph: sum(s["dur"] for s in inner if s["name"] == ph)
-                for ph in ("prefill", "decode", "speculate")}
-    drafted = sum(s["args"].get("drafted", 0) for s in inner
-                  if s["name"] == "speculate")
-    accepted = sum(s["args"].get("accepted", 0) for s in inner
-                   if s["name"] == "speculate")
-    # Paged engine: each per-step span carries the slot's block count
-    # (engine._trace_slot_spans); the request's peak is its KV
-    # footprint high-water mark in blocks.  0 on a contiguous engine.
+    phases = {name: [s for s in inner if s["name"] == name]
+              for name in ("serving/prefill", "serving/decode")}
+
+    def total(name, key):
+      return sum(s["args"].get(key, 0) for s in phases[name])
+
+    # Paged engine: each phase span carries the slot's block count at
+    # its end (scheduler._trace_phase_end), and an occupancy only grows
+    # it: the request's KV footprint high-water mark in blocks.  0 on a
+    # contiguous engine.
     kv_blocks_peak = max(
         (s["args"].get("kv_blocks", 0) for s in inner), default=0)
+    # The wait that ended where this occupancy's prefill began (one
+    # stamp); a trace without the span falls back on the submit instant.
+    waits = [d for end, d in queued.get(uid, ())
+             if t0 - 1e-9 <= end <= t1 + 1e-9]
     submit = submits.get(uid)
     ttft = first_tokens.get(uid)
     fd_req = fd_requests.get(uid)
     fd_byte = fd_first_bytes.get(uid)
     requests.append({
         "uid": uid,
-        "queue_wait_us": (t0 - submit) if submit is not None else None,
+        "queue_wait_us": waits[0] if waits else (
+            (t0 - submit) if submit is not None else None),
         "ingress_us": (submit - fd_req)
                       if None not in (submit, fd_req) else None,
         "client_ttft_us": (fd_byte - fd_req)
@@ -184,12 +203,14 @@ def request_timelines(events: List[Dict[str, Any]]
         "total_us": req["dur"],
         "ttft_us": (ttft - (submit if submit is not None else t0))
                    if ttft is not None else None,
-        "prefill_us": phase_us["prefill"],
-        "prefill_chunks": sum(1 for s in inner if s["name"] == "prefill"),
-        "decode_steps": sum(1 for s in inner
-                            if s["name"] in ("decode", "speculate")),
-        "decode_us": phase_us["decode"] + phase_us["speculate"],
-        "drafted": drafted, "accepted": accepted,
+        "prefill_us": sum(s["dur"] for s in phases["serving/prefill"]),
+        "prefill_chunks": total("serving/prefill", "steps"),
+        "prefill_tokens": total("serving/prefill", "tokens"),
+        "decode_steps": total("serving/decode", "steps"),
+        "decode_us": sum(s["dur"] for s in phases["serving/decode"]),
+        "decode_tokens": total("serving/decode", "tokens"),
+        "drafted": total("serving/decode", "drafted"),
+        "accepted": total("serving/decode", "accepted"),
         "kv_blocks_peak": kv_blocks_peak,
         # Blocks mapped by reference from the prefix cache at admission
         # (scheduler._admit stamps the request span).  0 without the
@@ -209,16 +230,19 @@ def request_timelines(events: List[Dict[str, Any]]
       continue
     submit = submits.get(uid)
     fd_req = fd_requests.get(uid)
+    # Its last wait: the one that ended where the queue resolved it.
+    last_wait = max(queued.get(uid, ()), default=None)
     requests.append({
         "uid": uid,
-        "queue_wait_us": (ts - submit) if submit is not None else None,
+        "queue_wait_us": last_wait[1] if last_wait is not None else (
+            (ts - submit) if submit is not None else None),
         "ingress_us": (submit - fd_req)
                       if None not in (submit, fd_req) else None,
         "client_ttft_us": None, "wire_us": None,
         "admitted_ts_us": ts,
         "total_us": None, "ttft_us": None,
-        "prefill_us": 0.0, "prefill_chunks": 0,
-        "decode_steps": 0, "decode_us": 0.0,
+        "prefill_us": 0.0, "prefill_chunks": 0, "prefill_tokens": 0,
+        "decode_steps": 0, "decode_us": 0.0, "decode_tokens": 0,
         "drafted": 0, "accepted": 0, "kv_blocks_peak": 0,
         "blk_reused": 0,
         "new_tokens": None, "finish_reason": reason,
@@ -499,7 +523,7 @@ def format_report(events: List[Dict[str, Any]]) -> str:
                  + (f"{'fd-ttft':>9}{'ingress':>9}{'wire':>9}"
                     if hops else "")
                  + f"{'prefill':>10}"
-                 f"{'chunks':>7}{'decode':>10}{'steps':>6}{'drafted':>8}"
+                 f"{'steps':>7}{'decode':>10}{'steps':>6}{'drafted':>8}"
                  f"{'accepted':>9}{'rq':>4}"
                  + (f"{'blk':>5}" if paged else "")
                  + (f"{'blk-reused':>11}" if reuse else "")

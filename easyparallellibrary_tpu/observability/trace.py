@@ -58,14 +58,18 @@ boundaries.
 The export is standard Chrome trace-event JSON: load it at
 ``ui.perfetto.dev`` or ``chrome://tracing``.  Device-side XLA timelines
 are attached with :meth:`Tracer.xla_trace`, which brackets a
-``jax.profiler`` capture with a host span so the two timelines
-correlate by wall clock.
+``jax.profiler`` capture with a host span and writes one anchor
+annotation into it whose start is also in the span's args, so the host's
+spans can be laid on the capture's clock.
 """
 
 from __future__ import annotations
 
 import contextlib
+import gc
+import itertools
 import json
+import operator
 import os
 import threading
 import time
@@ -81,6 +85,12 @@ _Event = Tuple[str, str, str, float, int, Optional[Dict[str, Any]]]
 # the wire — tids are tracer-local and get re-assigned per remote pid
 # on ingest, so two processes' "serving/slot0" tracks never collide.
 _ENC = {"separators": (",", ":"), "default": str}
+
+# The one annotation :meth:`Tracer.xla_trace` writes into a capture.
+XLA_ANCHOR = "epl/xla_anchor"
+
+_FLOW_PHASES = ("s", "t", "f")
+_EVENT_TS = operator.itemgetter(3)
 
 
 class _NullSpan:
@@ -322,7 +332,7 @@ class Tracer:
     start."""
     if not self.enabled:
       return
-    if phase not in ("s", "t", "f"):
+    if phase not in _FLOW_PHASES:
       raise ValueError(f"flow phase must be 's', 't' or 'f': {phase!r}")
     a = dict(args) if args else {}
     a["id"] = int(flow_id)
@@ -334,17 +344,27 @@ class Tracer:
     """Bracket a ``jax.profiler`` device-trace capture with a host span,
     so the XLA timeline (TensorBoard/Perfetto from ``log_dir``) and this
     tracer's host timeline correlate.  The capture runs whether or not
-    the tracer is enabled — the span is recorded only when it is."""
+    the tracer is enabled — the span is recorded only when it is.
+
+    The capture holds one ``jax.profiler.TraceAnnotation`` named
+    :data:`XLA_ANCHOR`, written as soon as the profiler runs; its start
+    is also read on this tracer's clock and kept in the span's args
+    (``anchor_us``).  The difference between the annotation's start in
+    the capture and ``anchor_us`` puts every host span on the capture's
+    clock (the device trace has its own epoch)."""
     import jax
     from easyparallellibrary_tpu.utils.logging import get_logger
     jax.profiler.start_trace(log_dir)
-    t0 = self.now_us()
+    t0 = anchor_us = self.now_us()
+    with jax.profiler.TraceAnnotation(XLA_ANCHOR):
+      pass
     try:
       yield
     finally:
       jax.profiler.stop_trace()
       self.span_at(name, t0, self.now_us(), cat="xla",
-                   args={"log_dir": os.path.abspath(log_dir)})
+                   args={"log_dir": os.path.abspath(log_dir),
+                         "anchor": XLA_ANCHOR, "anchor_us": anchor_us})
       get_logger().info("xla trace written to %s", log_dir)
 
   # ------------------------------------------- cross-process harvest --
@@ -472,9 +492,23 @@ class Tracer:
     recorded retroactively via :meth:`span_at` land in buffer order,
     not time order; the stable sort restores B-before-E at equal
     timestamps, and each pid's stream is already monotonic so the
-    merge preserves per-pid order)."""
+    merge preserves per-pid order).
+
+    The collector is held off meanwhile: the call makes a dict an event
+    and nothing that could form a cycle, and with it left on its passes
+    over the process's whole heap were most of the time a long window
+    took to hand over."""
     import jax
     pid = jax.process_index()
+    collecting = gc.isenabled()
+    gc.disable()
+    try:
+      return self._events_of(pid)
+    finally:
+      if collecting:
+        gc.enable()
+
+  def _events_of(self, pid: int) -> List[Dict[str, Any]]:
     with self._lock:  # a concurrent append must not mutate mid-snapshot
       events = list(self._events)
       tracks = sorted(self._tracks.items(), key=lambda kv: kv[1])
@@ -500,18 +534,22 @@ class Tracer:
                     "tid": tid, "args": {"name": name}})
         out.append({"ph": "M", "name": "thread_sort_index", "pid": rpid,
                     "tid": tid, "args": {"sort_index": tid}})
-    merged = [(e, pid) for e in events]
-    for rpid, _label, _rtracks, revents in remote:
-      merged.extend((e, rpid) for e in revents)
-    for (ph, name, cat, ts, tid, args), epid in sorted(
-        merged, key=lambda e: e[0][3]):
+    if remote:
+      merged = [(e, pid) for e in events]
+      for rpid, _label, _rtracks, revents in remote:
+        merged.extend((e, rpid) for e in revents)
+      merged.sort(key=lambda e: e[0][3])
+    else:
+      events.sort(key=_EVENT_TS)
+      merged = zip(events, itertools.repeat(pid))
+    for (ph, name, cat, ts, tid, args), epid in merged:
       ev: Dict[str, Any] = {"ph": ph, "name": name, "ts": ts,
                             "pid": epid, "tid": tid}
       if cat:
         ev["cat"] = cat
       if ph == "i":
         ev["s"] = "t"
-      if ph in ("s", "t", "f") and args is not None and "id" in args:
+      elif ph in _FLOW_PHASES and args is not None and "id" in args:
         # Flow events carry their id top-level (Chrome trace format) and
         # bind to the ENCLOSING slice ("bp": "e") so the arrow anchors
         # on the request span the flow event was recorded inside.

@@ -476,10 +476,9 @@ class ContinuousBatchingEngine:
         f"{self._track_prefix}/step_overlap", {"mode": self.step_overlap})
     # The launched step whose tokens are still on the device (overlapped
     # loop only), retirements a drain committed outside ``step()``, and
-    # the stamps the next step's spans and time sample start from.
+    # the stamp the next step's time sample starts from.
     self._inflight: Optional[_LaunchedStep] = None
     self._held_finished: List[FinishedRequest] = []
-    self._last_fetch_us = 0.0
     self._last_step_end = 0.0
     self.stats = stats
     if self._resilient and self.stats is None:
@@ -610,9 +609,10 @@ class ContinuousBatchingEngine:
               (self._kv, np.zeros((rows,), bool),
                np.zeros((rows,), np.int32))),
           compile_count=1)
-    # Perfetto track name per slot (the scheduler's lifecycle spans and
-    # the engine's per-step spans must land on the same track);
-    # precomputed so the per-step tracing loop does no string work.
+    # Perfetto track name per slot (the scheduler's lifecycle and phase
+    # spans and the speculating engine's per-step ``speculate`` spans
+    # must land on the same track); precomputed so that loop does no
+    # string work.
     self._slot_tracks = [_slot_track(i, self._track_prefix)
                          for i in range(self.num_slots)]
     self._steps = 0
@@ -725,13 +725,6 @@ class ContinuousBatchingEngine:
   # --------------------------------------------------- resilience hooks
 
   def _on_degrade_transition(self, old: int, new: int, signals):
-    tracer = trace_lib.get_tracer()
-    if tracer.enabled:
-      tracer.instant(
-          "serving/degraded", cat="serving", track="serving",
-          args={"from": DEGRADE_LEVELS[old], "to": DEGRADE_LEVELS[new],
-                **signals})
-      tracer.counter("serving/degraded_level", new)
     if self.stats is not None:
       self.stats.note_degraded(new)
 
@@ -1243,39 +1236,24 @@ class ContinuousBatchingEngine:
     self.close()
     return False
 
-  def _trace_slot_spans(self, tracer, plan, t0_us: float, t1_us: float,
-                        num_draft=None, n_committed=None):
-    """Per-slot timeline spans for one fused step: the single device
-    call covers every active slot, so each slot's prefill / decode /
-    speculate span shares its bounds and nests inside the request
-    lifecycle span opened at admission (scheduler._admit).  Speculating
-    slots carry drafted/accepted counts in their span args.  Host
+  def _trace_speculation(self, tracer, plan, t0_us: float, t1_us: float,
+                         num_draft, n_committed):
+    """One ``speculate`` span a slot that drafted this step, over the
+    step's bounds and with its ``drafted`` / ``accepted``, inside the
+    request's ``serving/decode`` (scheduler).  Speculating engines only:
+    their loop is serial, so the step lies between two commits.  Host
     values only — never called with device arrays."""
     if not tracer.enabled:
       return
     for slot, state, _, _ in plan.fed:
-      if self.scheduler.active.get(slot) is not state:
-        continue  # retired since it was planned: its span has closed
-      track = self._slot_tracks[slot]
-      extra = {}
+      nd = int(num_draft[slot])
+      if nd == 0 or self.scheduler.active.get(slot) is not state:
+        continue  # (retired since it was planned: its span has closed)
+      args = {"drafted": nd, "accepted": int(n_committed[slot]) - 1}
       if self.paged:
-        # Per-request block occupancy in the timeline (report.py rolls
-        # this up as each request's peak KV blocks held).
-        extra["kv_blocks"] = len(self.scheduler.slot_blocks(slot))
-      if plan.prefilling[slot]:
-        tracer.span_at("prefill", t0_us, t1_us, cat="serving",
-                       track=track,
-                       args={"tokens": int(plan.num_valid[slot]), **extra})
-      elif num_draft is not None and int(num_draft[slot]) > 0:
-        tracer.span_at(
-            "speculate", t0_us, t1_us, cat="serving", track=track,
-            args={"drafted": int(num_draft[slot]),
-                  "accepted": int(n_committed[slot]) - 1, **extra})
-      else:
-        tracer.span_at("decode", t0_us, t1_us, cat="serving",
-                       track=track,
-                       args={"tok_index": int(plan.tok_index[slot]),
-                             **extra})
+        args["kv_blocks"] = len(self.scheduler.slot_blocks(slot))
+      tracer.span_at("speculate", t0_us, t1_us, cat="serving",
+                     track=self._slot_tracks[slot], args=args)
 
   def _apply_degradation(self):
     """Feed the ladder this iteration's post-admission load signals and
@@ -1344,10 +1322,6 @@ class ContinuousBatchingEngine:
               "drafts this step (logged once; see "
               "serving/drafter_failures)", type(self.drafter).__name__,
               type(e).__name__, e)
-        if tracer.enabled:
-          tracer.instant("serving/drafter_failure", cat="serving",
-                         track="serving",
-                         args={"error": type(e).__name__})
         # Partial draft writes before the failure are harmless: with
         # zero drafts every decode slot's num_valid stays 1, so the
         # written positions are masked garbage the step never reads.
@@ -1364,12 +1338,6 @@ class ContinuousBatchingEngine:
                                      exercised=exercised)
     if not bad:
       return bad
-    tracer = trace_lib.get_tracer()
-    retries = sum(1 for a in actions.values() if a == BadStepPolicy.RETRY)
-    if tracer.enabled:
-      tracer.instant(
-          "serving/bad_step", cat="serving", track="serving",
-          args={"slots": bad, "retries": retries})
     get_logger().warning(
         "bad device step (non-finite logits) on slot(s) %s: %s", bad,
         {s: a for s, a in actions.items()})
@@ -1585,9 +1553,10 @@ class ContinuousBatchingEngine:
     if done is None:
       if launched is not None:
         # The first step of a burst: nothing to wait for yet.
+        step_arg = {"step": self._steps + 1} if tracer.enabled else None
         tracer.span_at(
             "serving/device_step", launched.t0_us, launched.launched_us,
-            cat="serving", track="serving",
+            cat="serving", track="serving", args=step_arg,
             children=(("serving/dispatch", launched.t0_us,
                        launched.launched_us),))
       # No device work to commit, but plan-time expiries may have
@@ -1611,7 +1580,13 @@ class ContinuousBatchingEngine:
     one step and the device has nothing to run during the dispatch;
     overlapped, the dispatch is of step k+1 and the fetch of step k,
     which runs meanwhile.  Either way one dispatch starts per step.  The
-    benchmark reads all three by name (PERF.md section 3)."""
+    benchmark reads all three by name (PERF.md section 3).  ``args.step``
+    of ``serving/device_step`` is the step whose fetch the span holds (a
+    span that is all dispatch: the step it launched), as the per-step
+    record numbers them.  Then ``serving/commit`` and ``serving/publish``
+    (``_publish_step``): with ``serving/plan`` the host's turn is named
+    from end to end, and a step records the same events whatever is live
+    (a speculating engine adds a ``speculate`` span a drafting slot)."""
     plan = step.plan
     drafted = accepted = 0
     num_draft = step.num_draft
@@ -1627,6 +1602,7 @@ class ContinuousBatchingEngine:
       # epl-lint's host-sync rule) catches.
       fetched = [jax.device_get(t) for t in step.tokens]
       t1_us = tracer.now_us()
+      step_arg = {"step": self._steps + 1} if tracer.enabled else None
       children = (("serving/fetch", t_fetch_us, t1_us),)
       if launched is not None:
         children = (("serving/dispatch", launched.t0_us,
@@ -1634,18 +1610,18 @@ class ContinuousBatchingEngine:
       tracer.span_at(
           "serving/device_step",
           launched.t0_us if launched is not None else t_fetch_us, t1_us,
-          cat="serving", track="serving", children=children)
+          cat="serving", track="serving", args=step_arg,
+          children=children)
       if self._experts and num_draft is None:
         *fetched, expert_load = fetched
-      n_committed = fetched[1] if num_draft is not None else None
-      # The device ran this step from its launch or, overlapped, from
-      # the end of its predecessor: the slots' spans tile their tracks.
-      self._trace_slot_spans(tracer, plan,
-                             max(step.t0_us, self._last_fetch_us), t1_us,
-                             num_draft, n_committed)
-      self._last_fetch_us = t1_us
+      n_committed = None
+      if num_draft is not None:
+        n_committed = fetched[1]
+        self._trace_speculation(tracer, plan, step.t0_us, t1_us, num_draft,
+                                n_committed)
       with tracer.span("serving/commit", cat="serving", track="serving"):
-        finished = self.scheduler.commit(*fetched, slot_ok=slot_ok)
+        finished = self.scheduler.commit(*fetched, slot_ok=slot_ok,
+                                         num_draft=num_draft)
         if self.drafter is not None:
           self.drafter.observe_commit(self._cursors)
       if num_draft is not None:
@@ -1666,6 +1642,19 @@ class ContinuousBatchingEngine:
         if lost is not None:
           self._close_step(lost)
       raise
+    with tracer.span("serving/publish", cat="serving", track="serving"):
+      self._publish_step(tracer, step, slot_ok, finished, drafted, accepted,
+                         expert_load)
+    return finished
+
+  def _publish_step(self, tracer, step: _LaunchedStep, slot_ok, finished,
+                    drafted: int, accepted: int, expert_load) -> None:
+    """What a committed step leaves behind, the tail of the host's turn
+    (span ``serving/publish``): the watchdog's arm and a device capture
+    closed, the bad-slot policy (its retirements join ``finished``), the
+    compile sentinel, the introspector, the step's counters, the stats
+    sample and the per-step record to writer, registry and SLO monitor."""
+    plan = step.plan
     self._close_step(step)
     if slot_ok is not None:
       self._handle_bad_slots(plan, slot_ok)
@@ -1750,29 +1739,6 @@ class ContinuousBatchingEngine:
         # expert layers: under the model's expert count, the step did
         # not stream every expert's weights.
         tracer.counter("serving/experts_touched_min", experts_touched_min)
-      if self.paged:
-        # Block-pool occupancy rides the counter tracks next to
-        # active_slots, so Perfetto shows pool pressure against load.
-        tracer.counter("serving/kv_blocks_used",
-                       self.scheduler.kv_blocks_used)
-        tracer.counter("serving/kv_blocks_free",
-                       self.scheduler.kv_blocks_free)
-        if self.prefix_caching:
-          # Prefix-cache effectiveness next to pool pressure: hit/miss
-          # and reuse counters plus the tree's resident footprint.
-          tracer.counter("serving/prefix_hits",
-                         self.scheduler.prefix_hits)
-          tracer.counter("serving/prefix_misses",
-                         self.scheduler.prefix_misses)
-          tracer.counter("serving/prefix_blocks_reused",
-                         self.scheduler.prefix_blocks_reused)
-          tracer.counter("serving/prefix_evictions",
-                         self.scheduler.prefix_evictions)
-          tracer.counter("serving/prefix_cached_blocks",
-                         self.scheduler.prefix_cached_blocks)
-      if drafted:
-        tracer.counter("serving/drafted_tokens", drafted)
-        tracer.counter("serving/accepted_tokens", accepted)
     if self.stats is not None:
       self.stats.note_step(
           active_slots=plan.active_slots, num_slots=self.num_slots,
@@ -1881,7 +1847,6 @@ class ContinuousBatchingEngine:
             self._steps,
             MetricRegistry.namespaced(SERVING_NAMESPACE,
                                       self.stats.summary()))
-    return finished
 
   def run(self, max_steps: Optional[int] = None
           ) -> Dict[Any, np.ndarray]:

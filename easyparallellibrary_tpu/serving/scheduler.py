@@ -34,6 +34,14 @@ Responsibilities (and nothing else — device work lives in engine.py):
   (same KV content, same cursors-as-committed-token-count, same
   ``tok_index`` RNG fold), so a quarantined request's final output is
   bit-identical to an undisturbed run.
+
+With the ambient tracer on, a request's PHASES are spans of category
+``serving`` under one ``uid`` (docs/observability.md): ``serving/queued``
+on a queue lane from submit to admission, then ``serving/prefill`` and
+``serving/decode`` tiling its ``request <uid>`` span on the slot's track.
+Each is ONE ``span_at`` written when the phase ends, its start a stamp of
+the tracer's clock kept on the queue entry or the slot state; the
+scheduler's own ``clock`` is injectable and never mixed with it.
 """
 
 from __future__ import annotations
@@ -41,6 +49,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import heapq
 import itertools
 import time
 import zlib
@@ -305,6 +314,24 @@ class PagedStepPlan:
   wasted: int = 0
 
 
+class _Phase:
+  """The phase a traced request is in on its slot: when it started on the
+  tracer's clock and what the steps committed since have fed it.  Made at
+  admission, and only with the tracer on."""
+
+  __slots__ = ("t0_us", "decoding", "steps", "base", "drafted", "accepted")
+
+  def __init__(self, t0_us: float, base: int, decoding: bool = False):
+    self.t0_us = t0_us
+    self.decoding = decoding  # ``serving/decode``, else ``serving/prefill``
+    self.steps = 0            # committed steps that fed the slot
+    # Prefix positions fed (prefill) or tokens generated (decode) when the
+    # phase started: its ``tokens`` is the count at its end less this.
+    self.base = base
+    self.drafted = 0          # speculation, decode only
+    self.accepted = 0
+
+
 class _SlotState:
   """Host mirror of one occupied slot.
 
@@ -323,7 +350,8 @@ class _SlotState:
   __slots__ = ("req", "slot", "prompt_pos", "generated", "key", "prefix",
                "submitted_at", "admitted_at", "first_token_at",
                "first_token_emitted", "requeues", "bad_streak",
-               "admit_seq", "reg_blocks", "fed_ahead", "samples_ahead")
+               "admit_seq", "reg_blocks", "fed_ahead", "samples_ahead",
+               "phase")
 
   def __init__(self, req: Request, slot: int, submitted_at: float,
                now: float, carried: Optional["_SlotState"] = None,
@@ -346,6 +374,7 @@ class _SlotState:
     # cache — the commit-time registration watermark, so the tree walk
     # only runs when a new full block completes.
     self.reg_blocks = 0
+    self.phase: Optional[_Phase] = None    # set by a traced admission
     if carried is not None:
       self.generated: List[int] = carried.generated
       self.key = carried.key
@@ -382,13 +411,17 @@ class _Pending:
   slot state of a requeued one (its committed prefix replays through
   prefill on readmission)."""
 
-  __slots__ = ("req", "submitted_at", "carried")
+  __slots__ = ("req", "submitted_at", "carried", "queued_us", "lane")
 
   def __init__(self, req: Request, submitted_at: float,
                carried: Optional[_SlotState] = None):
     self.req = req
     self.submitted_at = submitted_at
     self.carried = carried
+    # Traced entries only (``_trace_enqueue``): when it joined the queue
+    # on the tracer's clock, and the queue lane its span will lie on.
+    self.queued_us: Optional[float] = None
+    self.lane: Optional[int] = None
 
   @property
   def prefix_len(self) -> int:
@@ -542,6 +575,13 @@ class FCFSScheduler:
     # Slot-track namespace for this scheduler's lifecycle spans
     # (replicas pass serving/replica<i> so fleet tracks stay distinct).
     self.track_prefix = track_prefix
+    # Queue lanes, ``<prefix>/queue/<i>``: queued requests overlap each
+    # other and the slot's previous occupant, and spans of one name may
+    # not overlap on one track, so a traced entry takes the lowest free
+    # lane at submit and frees it at admission.  As many lanes as the
+    # queue was ever deep while traced.
+    self._queue_tracks: List[str] = []
+    self._free_lanes: List[int] = []       # a heap
     self.allocator = SlotAllocator(num_slots)
     self.pending: Deque[_Pending] = deque()
     # Count of queued latency-class entries, maintained at every
@@ -636,11 +676,13 @@ class FCFSScheduler:
     minted = req.flow_id is None
     if minted:
       req = dataclasses.replace(req, flow_id=next_flow_id())
-    self.pending.append(_Pending(req, self.clock()))
+    entry = _Pending(req, self.clock())
+    self.pending.append(entry)
     self._latency_pending += req.priority == "latency"
     self._deadline_pending += self._has_deadline(req)
     tracer = trace_lib.get_tracer()
     if tracer.enabled:  # args dicts are not free; skip them when off
+      self._trace_enqueue(tracer, entry)
       tracer.instant(
           "serving/submit", cat="serving", track="serving/requests",
           args={"uid": str(req.uid), "prompt_tokens": int(prompt.size),
@@ -668,6 +710,87 @@ class FCFSScheduler:
     out, self._finished_buffer = self._finished_buffer, []
     return out
 
+  # ------------------------------------------------- request-phase spans
+
+  def _trace_enqueue(self, tracer, entry: _Pending,
+                     now_us: Optional[float] = None) -> None:
+    """``entry`` joins the queue: its ``serving/queued`` span starts now
+    (``now_us``: at the end of the phase it left a slot in) on the lowest
+    free queue lane."""
+    entry.queued_us = tracer.now_us() if now_us is None else now_us
+    if self._free_lanes:
+      entry.lane = heapq.heappop(self._free_lanes)
+    else:
+      entry.lane = len(self._queue_tracks)
+      self._queue_tracks.append(f"{self.track_prefix}/queue/{entry.lane}")
+
+  def _trace_dequeue(self, tracer, entry: _Pending,
+                     end_us: Optional[float] = None,
+                     reason: Optional[str] = None) -> None:
+    """``entry`` leaves the queue (admitted at ``end_us``; or now, with
+    ``reason``: expired, cancelled or migrated there): its lane is free
+    again and, traced from submit to here, its ``serving/queued`` span is
+    written."""
+    if entry.lane is None:
+      return                          # queued while the tracer was off
+    lane, entry.lane = entry.lane, None
+    heapq.heappush(self._free_lanes, lane)
+    if not tracer.enabled:
+      return
+    if end_us is None:
+      end_us = tracer.now_us()
+    carried = entry.carried
+    args = self._phase_args(entry.req)
+    args["requeues"] = int(carried.requeues) if carried is not None else 0
+    if reason is not None:
+      args["finish_reason"] = reason
+    tracer.span_at("serving/queued", entry.queued_us, end_us,
+                   cat="serving", track=self._queue_tracks[lane], args=args)
+
+  @staticmethod
+  def _phase_args(req: Request) -> Dict[str, Any]:
+    """What the phase spans of one request share."""
+    args: Dict[str, Any] = {"uid": str(req.uid)}
+    if req.flow_id is not None:
+      args["flow_id"] = int(req.flow_id)
+    return args
+
+  def _trace_phase_end(self, tracer, state: _SlotState,
+                       reason: Optional[str] = None) -> Optional[float]:
+    """The phase ``state`` is in ends now: its span is written on the
+    slot's track, inside ``request <uid>`` (``reason``: why, where that is
+    not the next phase starting).  Returns the stamp, for whatever starts
+    where it ends; None with the tracer off.  A request admitted with the
+    tracer off has no phase and records none.  Call it while the slot
+    still holds its blocks."""
+    phase, state.phase = state.phase, None
+    if not tracer.enabled:
+      return None     # switched off since admission: the phase is dropped
+    end_us = tracer.now_us()
+    if phase is None:
+      return end_us
+    args = self._phase_args(state.req)
+    args["steps"] = phase.steps
+    if phase.decoding:
+      args["tokens"] = len(state.generated) - phase.base
+      if self.spec_k > 0:
+        args["drafted"] = phase.drafted
+        args["accepted"] = phase.accepted
+    else:
+      args["tokens"] = state.prompt_pos - phase.base
+      if phase.base:                # admitted warm (paged prefix cache)
+        args["prefix_blocks_reused"] = phase.base // self.block_size
+    if self.paged:
+      # The occupancy's block high-water mark: it only ever grows.
+      args["kv_blocks"] = len(self._slot_blocks.get(state.slot, ()))
+    if reason is not None:
+      args["finish_reason"] = reason
+    tracer.span_at(
+        "serving/decode" if phase.decoding else "serving/prefill",
+        phase.t0_us, end_us, cat="serving",
+        track=_slot_track(state.slot, self.track_prefix), args=args)
+    return end_us
+
   # ------------------------------------------------------ lifecycle ctl
 
   def _finish_unadmitted(self, entry: _Pending, reason: str):
@@ -682,6 +805,7 @@ class FCFSScheduler:
         new_tokens=len(generated),
         finish_reason=reason)
     tracer = trace_lib.get_tracer()
+    self._trace_dequeue(tracer, entry, reason=reason)
     if tracer.enabled:
       tracer.instant(
           f"serving/{reason}", cat="serving", track="serving/requests",
@@ -772,11 +896,12 @@ class FCFSScheduler:
     del self.active[slot]
     self._admit_order.remove(slot)
     self.allocator.free(slot)
+    tracer = trace_lib.get_tracer()
+    left_us = self._trace_phase_end(tracer, state, "requeued")
     self._release_blocks(slot)
     self._deadline_active -= self._has_deadline(state.req)
     state.requeues += 1
     state.bad_streak = 0
-    tracer = trace_lib.get_tracer()
     if tracer.enabled:
       if state.req.flow_id is not None:
         # Flow step INSIDE the closing span, so the arc anchors on this
@@ -795,8 +920,11 @@ class FCFSScheduler:
                 "reason": reason,
                 "committed_prefix": int(len(state.req.prompt)
                                         + len(state.generated))})
-    self.pending.appendleft(
-        _Pending(state.req, state.submitted_at, carried=state))
+    entry = _Pending(state.req, state.submitted_at, carried=state)
+    if tracer.enabled:
+      # Back in the queue from where its phase on the slot ended.
+      self._trace_enqueue(tracer, entry, left_us)
+    self.pending.appendleft(entry)
     self._latency_pending += state.req.priority == "latency"
     self._deadline_pending += self._has_deadline(state.req)
     return state.req.uid
@@ -918,6 +1046,7 @@ class FCFSScheduler:
     self._deadline_pending += self._has_deadline(req)
     tracer = trace_lib.get_tracer()
     if tracer.enabled:
+      self._trace_enqueue(tracer, entry)
       tracer.instant(
           "serving/restore", cat="serving", track="serving/requests",
           args={"uid": str(req.uid),
@@ -942,6 +1071,7 @@ class FCFSScheduler:
       state = self.active.pop(slot)
       self._admit_order.remove(slot)
       self.allocator.free(slot)
+      self._trace_phase_end(tracer, state, "migrated")
       self._release_blocks(slot)
       self._deadline_active -= self._has_deadline(state.req)
       if tracer.enabled:
@@ -955,6 +1085,8 @@ class FCFSScheduler:
             track=_slot_track(slot, self.track_prefix),
             args={"finish_reason": "migrated",
                   "new_tokens": int(len(state.generated))})
+    for entry in self.pending:
+      self._trace_dequeue(tracer, entry, reason="migrated")
     self.pending.clear()
     self._latency_pending = 0
     self._deadline_pending = 0
@@ -1046,10 +1178,11 @@ class FCFSScheduler:
           state.prompt_pos = reused * self.block_size
           state.reg_blocks = reused
       # The request's lifecycle span opens on its slot's track and stays
-      # open until _retire — every per-step prefill/decode span the
-      # engine records for this slot nests inside it, so one Perfetto
-      # track row reads as the request's complete timeline.
+      # open until _retire — its ``serving/prefill`` and ``serving/decode``
+      # phases tile it, so one Perfetto track row reads as the request's
+      # complete timeline.
       tracer = trace_lib.get_tracer()
+      admit_us = None
       if tracer.enabled:
         args = {"uid": str(req.uid),
                 "prompt_tokens": int(len(req.prompt)),
@@ -1067,6 +1200,11 @@ class FCFSScheduler:
           tracer.flow("t", req.flow_id,
                       track=_slot_track(slot, self.track_prefix),
                       args={"uid": str(req.uid)})
+        # ONE stamp ends the wait in the queue and starts the prefill.
+        admit_us = tracer.now_us()
+        state.phase = _Phase(admit_us, state.prompt_pos)
+      if entry.lane is not None:
+        self._trace_dequeue(tracer, entry, admit_us)
       if state.requeues == 0:
         for fn in self.on_admit:
           fn(req.uid)
@@ -1574,6 +1712,8 @@ class FCFSScheduler:
     del self.active[slot]
     self._admit_order.remove(slot)
     self.allocator.free(slot)
+    tracer = trace_lib.get_tracer()
+    self._trace_phase_end(tracer, state, reason)
     # Session KV persistence: register the retiring request's completed
     # blocks BEFORE releasing the slot's references, so a multi-turn
     # follow-up (its next prompt = this conversation's full history)
@@ -1584,7 +1724,6 @@ class FCFSScheduler:
       self._register_cached(state)
     self._release_blocks(slot)
     self._deadline_active -= self._has_deadline(state.req)
-    tracer = trace_lib.get_tracer()
     if tracer.enabled:
       if state.req.flow_id is not None:
         tracer.flow("f", state.req.flow_id,
@@ -1609,7 +1748,8 @@ class FCFSScheduler:
 
   def commit(self, next_tokens: np.ndarray,
              num_committed: Optional[np.ndarray] = None,
-             slot_ok: Optional[np.ndarray] = None
+             slot_ok: Optional[np.ndarray] = None,
+             num_draft: Optional[np.ndarray] = None
              ) -> List[FinishedRequest]:
     """Fold one step's committed tokens back into request state; returns
     this iteration's retirements (commit-time plus any buffered
@@ -1625,7 +1765,9 @@ class FCFSScheduler:
     whose "next token" is still dictated by the prompt.  Multi-token
     commits apply stop-token and ``max_new_tokens`` checks PER TOKEN in
     commit order, so a stop token appearing mid-draft retires the
-    request and discards the rest of its accepted drafts."""
+    request and discards the rest of its accepted drafts.  ``num_draft``
+    (int [N], speculative engines) is what each slot drafted, for the
+    request's ``serving/decode`` span alone."""
     if not self._plans:
       raise RuntimeError("commit() without a preceding plan_step()")
     plan = self._plans.popleft()
@@ -1636,6 +1778,7 @@ class FCFSScheduler:
     if num_committed is None:
       num_committed = np.ones((tokens.shape[0],), np.int32)
     now = self.clock()
+    tracer = trace_lib.get_tracer()
     for slot, state, _, _ in plan.fed:
       if self.active.get(slot) is not state:
         # Retired (or requeued) since the plan was made: a stop token, a
@@ -1646,6 +1789,12 @@ class FCFSScheduler:
       if slot_ok is not None and not slot_ok[slot]:
         continue  # bad step: state untouched — next plan retries exactly
       req = state.req
+      phase = state.phase                 # None unless admitted traced
+      if phase is not None:
+        phase.steps += 1
+        if num_draft is not None and phase.decoding:
+          phase.drafted += int(num_draft[slot])
+          phase.accepted += int(num_committed[slot]) - 1
       if plan.prefilling[slot]:
         state.prompt_pos += int(plan.num_valid[slot])
         if state.prefilling:
@@ -1656,10 +1805,17 @@ class FCFSScheduler:
           if self.prefix_cache is not None:
             self._register_cached(state)
           continue
+        if phase is not None:
+          # The prefix is fed and this commit emits the occupancy's first
+          # token: ``serving/prefill`` ends and ``serving/decode`` starts
+          # on one stamp.  A requeued request's replay ends here too.
+          first_us = self._trace_phase_end(tracer, state)
+          if first_us is not None:
+            state.phase = _Phase(first_us, len(state.generated) + 1,
+                                 decoding=True)
         if not state.first_token_emitted:
           state.first_token_emitted = True
           state.first_token_at = now
-          tracer = trace_lib.get_tracer()
           if tracer.enabled:
             tracer.instant(
                 "serving/first_token", cat="serving",

@@ -18,6 +18,7 @@ budget is paid once.
 import json
 import statistics
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -217,10 +218,12 @@ def test_trace_schema_valid(traced_run):
 @pytest.mark.quick
 def test_request_lifecycle_tracks_complete(traced_run):
   """Acceptance: every request has one complete lifecycle — submit
-  instant, an admit->retire span carrying the finish reason, at least
-  one prefill chunk and one decode/speculate span nested in it on the
-  same slot track, a first-token instant, and (since the same-params
-  drafter always drafts) speculate spans with accepted counts."""
+  instant, an admit->retire span carrying the finish reason, tiled on
+  the same slot track by ONE ``serving/prefill`` and ONE
+  ``serving/decode`` phase span that carry its uid, the steps that fed it
+  and the tokens they fed, a first-token instant, and (since the
+  same-params drafter always drafts) per-step ``speculate`` spans with
+  accepted counts nested in the decode phase, which sums them."""
   events = validate_trace(traced_run["trace_path"])
   spans, unmatched = report.pair_spans(events)
   assert unmatched == 0
@@ -234,27 +237,43 @@ def test_request_lifecycle_tracks_complete(traced_run):
   assert set(traced_run["uids"]) <= submits
   assert set(traced_run["uids"]) <= firsts
   speculated = 0
-  for uid in traced_run["uids"]:
+  for i, uid in enumerate(traced_run["uids"]):
     req = by_uid[uid]
     t0, t1 = req["ts"], req["ts"] + req["dur"]
     inner = [s for s in spans if s["tid"] == req["tid"]
-             and s["name"] in ("prefill", "decode", "speculate")
+             and s["name"] in ("serving/prefill", "serving/decode",
+                               "speculate")
              and t0 <= s["ts"] and s["ts"] + s["dur"] <= t1 + 1e-9]
-    assert any(s["name"] == "prefill" for s in inner), uid
-    decodes = [s for s in inner if s["name"] in ("decode", "speculate")]
-    assert decodes, uid
+    (prefill,) = [s for s in inner if s["name"] == "serving/prefill"]
+    (decode,) = [s for s in inner if s["name"] == "serving/decode"]
+    assert prefill["args"]["uid"] == decode["args"]["uid"] == uid
+    assert prefill["ts"] + prefill["dur"] == decode["ts"]
+    assert prefill["args"]["steps"] >= 1
+    assert prefill["args"]["tokens"] == len(traced_run["outputs"][uid]) \
+        - (5 + i)
+    # the first token is the prefill's; the rest are the decode's
+    assert decode["args"]["tokens"] == 5 + i - 1
+    assert 1 <= decode["args"]["steps"] <= decode["args"]["tokens"]
     assert req["args"]["finish_reason"] == "length"
     assert req["args"]["new_tokens"] >= 1
+    drafted = accepted = 0
     for s in inner:
       if s["name"] == "speculate":
         assert s["args"]["drafted"] >= 1
         assert 0 <= s["args"]["accepted"] <= s["args"]["drafted"]
+        assert decode["ts"] <= s["ts"]
+        assert s["ts"] + s["dur"] <= decode["ts"] + decode["dur"]
+        drafted += s["args"]["drafted"]
+        accepted += s["args"]["accepted"]
         speculated += 1
+    assert decode["args"]["drafted"] == drafted
+    assert decode["args"]["accepted"] == accepted
   assert speculated > 0, "no speculate spans despite a drafting engine"
   # The per-request report rolls the same events up without error.
   timelines = {t["uid"]: t for t in report.request_timelines(events)}
   assert set(traced_run["uids"]) <= set(timelines)
   assert all(t["ttft_us"] is not None and t["prefill_chunks"] >= 1
+             and t["decode_steps"] >= 1 and t["queue_wait_us"] is not None
              for t in timelines.values())
 
 
@@ -301,6 +320,271 @@ def test_fit_phase_spans_and_namespaced_auto_metrics(traced_run):
   assert all(k in ("step", "time") or k.split("/")[0] in
              ("train", "serving", "comm", "resilience")
              for l in lines for k in l)
+
+
+# -------------------------------------------------- request-phase spans
+
+
+@pytest.fixture(scope="module")
+def tiny_gpt():
+  gpt = GPT(TINY)
+  return gpt, gpt.init(jax.random.PRNGKey(0),
+                       jnp.zeros((1, 4), jnp.int32))["params"]
+
+
+@pytest.fixture
+def phase_tracer():
+  epl.init()
+  tracer = trace_lib.install(trace_lib.Tracer(enabled=True))
+  yield tracer
+  trace_lib.reset()
+
+
+def _phase_engine(tiny_gpt, *, serial=False, **kw):
+  model, params = tiny_gpt
+  eng = ContinuousBatchingEngine(model, params, prefill_chunk=4,
+                                 **{"num_slots": 2, **kw})
+  if serial:
+    eng._overlap = False          # the serial loop, same compiled step
+  return eng
+
+
+def _request(i, n=5, new=4, **kw):
+  prompt = np.random.RandomState(i).randint(0, 64, (n,)).astype(np.int32)
+  return Request(uid=f"r{i}", prompt=prompt, max_new_tokens=new, **kw)
+
+
+def _phases(events):
+  """``{uid: {name: [span, ...]}}`` of the phase spans, and the request
+  spans by uid, after the schema check."""
+  spans, unmatched = report.pair_spans(validate_trace(events))
+  assert unmatched == 0
+  out, requests = {}, {}
+  for s in spans:
+    if s["name"] in ("serving/queued", "serving/prefill", "serving/decode"):
+      assert s["cat"] == "serving"
+      out.setdefault(s["args"]["uid"], {}).setdefault(
+          s["name"], []).append(s)
+    elif s["cat"] == "serving.request":
+      requests.setdefault(s["args"]["uid"], []).append(s)
+  return out, requests
+
+
+def _end(span):
+  """Where a paired span ends, to the rounding of ``ts + (end - ts)``."""
+  return pytest.approx(span["ts"] + span["dur"], abs=1e-6)
+
+
+@pytest.mark.parametrize("serial", [False, True],
+                         ids=["overlapped", "serial"])
+def test_phases_tile_submit_to_retire_under_one_uid(tiny_gpt, phase_tracer,
+                                                    serial):
+  """Three times more requests than slots: every request's queued,
+  prefill and decode spans share its uid and flow id and tile submit ->
+  retire with shared stamps, the two on the slot inside ``request
+  <uid>``; their args count the steps and the tokens."""
+  eng = _phase_engine(tiny_gpt, serial=serial)
+  assert eng.step_overlap == "on"
+  reqs = [_request(i, n=3 + 2 * i, new=3 + i) for i in range(6)]
+  for r in reqs[:4]:
+    eng.submit(r)
+  for _ in range(3):
+    eng.step()
+  for r in reqs[4:]:
+    eng.submit(r)
+  eng.run()
+  events = phase_tracer.events()
+  phases, requests = _phases(events)
+  submits = {e["args"]["uid"]: e["ts"] for e in events
+             if e["ph"] == "i" and e["name"] == "serving/submit"}
+  tracks = {e["tid"]: e["args"]["name"] for e in events
+            if e["ph"] == "M" and e["name"] == "thread_name"}
+  for r in reqs:
+    mine = phases[r.uid]
+    (queued,), (prefill,), (decode,) = (
+        mine["serving/queued"], mine["serving/prefill"],
+        mine["serving/decode"])
+    (req,) = requests[r.uid]
+    flow = {s["args"]["flow_id"] for s in (queued, prefill, decode)}
+    assert len(flow) == 1
+    # stamped before the submit instant, in the same call
+    assert queued["ts"] <= submits[r.uid] < queued["ts"] + 1e3
+    assert _end(queued) == prefill["ts"]
+    assert _end(prefill) == decode["ts"]
+    assert req["ts"] <= prefill["ts"]
+    assert decode["ts"] + decode["dur"] <= req["ts"] + req["dur"] + 1e-6
+    assert prefill["tid"] == decode["tid"] == req["tid"]
+    assert tracks[queued["tid"]].startswith("serving/queue/")
+    assert queued["args"]["requeues"] == 0
+    assert prefill["args"]["tokens"] == len(r.prompt)
+    assert prefill["args"]["steps"] == -(-len(r.prompt) // 4)
+    assert decode["args"]["tokens"] == r.max_new_tokens - 1
+    assert decode["args"]["steps"] == r.max_new_tokens - 1
+    assert "drafted" not in decode["args"]
+  # four queued at once at most: the pool is as deep as the queue was
+  lanes = {n for n in tracks.values() if n.startswith("serving/queue/")}
+  assert lanes == {f"serving/queue/{i}" for i in range(len(lanes))}
+  assert 2 <= len(lanes) <= 4
+  rows = {t["uid"]: t for t in report.request_timelines(events)}
+  for r in reqs:
+    row = rows[r.uid]
+    assert row["queue_wait_us"] == phases[r.uid]["serving/queued"][0]["dur"]
+    assert row["prefill_tokens"] == len(r.prompt)
+    assert row["decode_steps"] == r.max_new_tokens - 1
+  assert "wait" in report.format_report(events)
+
+
+def _case_cancelled_and_expired_in_queue(tiny_gpt):
+  eng = _phase_engine(tiny_gpt)
+  for i in range(6):
+    eng.submit(_request(i, deadline_s=1e-4 if i == 5 else 0.0))
+  assert eng.cancel("r4")
+  time.sleep(0.002)
+  eng.run()
+  return eng, {"r4": "cancelled", "r5": "deadline"}
+
+
+def _case_paged_requeue(tiny_gpt):
+  eng = _phase_engine(tiny_gpt, paged=True, block_size=4)
+  for i in range(4):
+    eng.submit(_request(i, n=6, new=6))
+  for _ in range(4):
+    eng.step()
+  slot = next(iter(eng.scheduler.active))
+  uid = eng.scheduler.requeue_slot(slot, reason="preempted")
+  eng.run()
+  return eng, {uid: "requeued"}
+
+
+def _case_evacuated(tiny_gpt):
+  eng = _phase_engine(tiny_gpt)
+  for i in range(5):
+    eng.submit(_request(i, new=6))
+  for _ in range(4):
+    eng.step()
+  snaps = eng.evacuate()
+  assert len(snaps) == 5 and not eng.has_work
+  for snap in snaps:                      # and it serves them again
+    eng.restore_request(snap)
+  eng.run()
+  return eng, {f"r{i}": "migrated" for i in range(5)}
+
+
+@pytest.mark.parametrize("case", [
+    _case_cancelled_and_expired_in_queue, _case_paged_requeue,
+    _case_evacuated], ids=["cancelled-expired-in-queue", "paged-requeue",
+                           "evacuated"])
+def test_trace_stays_valid_through_the_lifecycle(tiny_gpt, phase_tracer,
+                                                 case):
+  """Queueing behind two slots with a request cancelled and one expired
+  in the queue, a paged requeue, an ``evacuate()``: spans of one name
+  never overlap on a track, every span closes, and the phase that ended
+  early says why."""
+  eng, reasons = case(tiny_gpt)
+  events = phase_tracer.events()
+  phases, requests = _phases(events)      # validate_trace inside
+  # what the benchmark's runners assume: B/E of one (name, tid) alternate
+  open_now = set()
+  for ev in events:
+    if ev["ph"] in "BE" and ev["name"].startswith("serving/"):
+      key = (ev["name"], ev["tid"])
+      assert (key in open_now) == (ev["ph"] == "E"), ev
+      (open_now.remove if ev["ph"] == "E" else open_now.add)(key)
+  assert not open_now
+  assert sorted(eng.scheduler._free_lanes) == list(
+      range(len(eng.scheduler._queue_tracks)))      # every lane given back
+  for uid, why in reasons.items():
+    mine = phases[uid]
+    closed = [s for name in mine for s in mine[name]
+              if s["args"].get("finish_reason") == why]
+    assert len(closed) == 1, (uid, mine)
+    if why in ("cancelled", "deadline"):
+      assert closed[0]["name"] == "serving/queued" and uid not in requests
+      continue
+    # left its slot (or the queue) and came back: a second wait that
+    # starts where the phase on the slot ended, then a second prefill
+    assert len(mine["serving/queued"]) == 2
+    again = mine["serving/queued"][1]
+    if closed[0]["name"] != "serving/queued":
+      assert len(mine["serving/prefill"]) == 2
+      assert len(requests[uid]) == 2
+    if why == "requeued":
+      assert again["ts"] == _end(closed[0])
+      assert again["args"]["requeues"] == 1
+      assert closed[0]["args"]["kv_blocks"] >= 1
+    assert eng.finished[uid].finish_reason == "length"
+
+
+def _events_of_one_decode_step(tiny_gpt, tracer, live):
+  eng = _phase_engine(tiny_gpt, num_slots=16)
+  for i in range(live):
+    eng.submit(_request(i, n=3, new=12))
+  for _ in range(4):
+    eng.step()
+  assert eng.scheduler.num_active == live
+  assert not any(s.prefilling for s in eng.scheduler.active.values())
+  before = tracer._n_appended
+  eng.step()
+  return tracer._n_appended - before
+
+
+def test_events_a_step_do_not_depend_on_live_slots(tiny_gpt, phase_tracer):
+  two = _events_of_one_decode_step(tiny_gpt, phase_tracer, 2)
+  sixteen = _events_of_one_decode_step(tiny_gpt, phase_tracer, 16)
+  # plan, device_step + dispatch + fetch, commit, publish: 12 B/E events;
+  # active_slots, overlapped_steps, wasted_positions, sampled_slots,
+  # live_kv_rows: 5 counters
+  assert two == sixteen == 17
+
+
+def test_disabled_tracer_stamps_and_keeps_nothing(tiny_gpt, monkeypatch):
+  """Off, the scheduler reads the tracer's clock nowhere, takes no lane
+  and makes no phase (the engine's launch stamps are what they were)."""
+  epl.init()
+  tracer = trace_lib.install(trace_lib.Tracer(enabled=False))
+  now_us = tracer.now_us
+
+  def stamped():
+    caller = sys._getframe(1).f_code.co_filename
+    assert not caller.endswith("scheduler.py"), "stamped while off"
+    return now_us()
+
+  monkeypatch.setattr(tracer, "now_us", stamped)
+  try:
+    eng = _phase_engine(tiny_gpt)
+    for i in range(5):
+      eng.submit(_request(i))
+    assert all(e.queued_us is None and e.lane is None
+               for e in eng.scheduler.pending)
+    eng.step(), eng.step()
+    assert eng.scheduler.active
+    assert all(s.phase is None for s in eng.scheduler.active.values())
+    eng.scheduler.requeue_slot(next(iter(eng.scheduler.active)))
+    eng.cancel("r4")
+    eng.run()
+    assert eng.scheduler._queue_tracks == [] == eng.scheduler._free_lanes
+    assert tracer.pending == 0
+  finally:
+    trace_lib.reset()
+
+
+def test_xla_trace_anchor_is_stamped_inside_its_span(tmp_path):
+  """A device capture made by the program carries one annotation whose
+  start is also in the bracketing span's args, on the tracer's clock."""
+  tracer = trace_lib.Tracer(enabled=True)
+  with tracer.xla_trace(str(tmp_path / "xla")):
+    jax.block_until_ready(jnp.ones((8, 8)) @ jnp.ones((8, 8)))
+  (b,) = [e for e in tracer.events() if e["ph"] == "B"]
+  (e,) = [e for e in tracer.events() if e["ph"] == "E"]
+  assert b["name"] == "xla_trace" and b["cat"] == "xla"
+  assert b["args"]["anchor"] == trace_lib.XLA_ANCHOR
+  assert b["ts"] <= b["args"]["anchor_us"] <= e["ts"]
+  # ... and the capture holds the annotation under that name
+  (pb,) = (tmp_path / "xla").rglob("*.xplane.pb")
+  data = jax.profiler.ProfileData.from_file(str(pb))
+  names = {ev.name for plane in data.planes for line in plane.lines
+           for ev in line.events}
+  assert trace_lib.XLA_ANCHOR in names
 
 
 def test_tracer_is_sync_free_under_transfer_guard():

@@ -164,7 +164,7 @@ def test_merged_export_per_pid_tracks_and_metadata():
   re-assigned per pid) plus process_name metadata — and the merged
   trace passes the validator."""
   child = Tracer(ring_capacity=64)
-  with child.span("prefill", cat="serving", track="serving/slot0"):
+  with child.span("serving/prefill", cat="serving", track="serving/slot0"):
     child.instant("serving/first_token", cat="serving",
                   args={"uid": "7"})
   child.flow("t", 42, track="serving/requests")
@@ -201,7 +201,7 @@ def test_close_remote_ends_dangling_spans_at_death():
   parent = Tracer(ring_capacity=64)
   parent.ingest_remote(7, [
       ["B", "request 3", "serving.request", 100.0, "slot0", None],
-      ["B", "decode", "serving", 120.0, "slot0", None],
+      ["B", "serving/decode", "serving", 120.0, "slot0", None],
       ["i", "tick", "", 130.0, "slot0", None],
   ], offset_us=0.0)
   with pytest.raises(ValueError, match="unclosed span"):
@@ -209,7 +209,7 @@ def test_close_remote_ends_dangling_spans_at_death():
   assert parent.close_remote(7, reason="killed") == 2
   events = validate_trace(parent.events())
   ends = [e for e in events if e["ph"] == "E"]
-  assert [e["name"] for e in ends] == ["decode", "request 3"]
+  assert [e["name"] for e in ends] == ["serving/decode", "request 3"]
   assert all(e["ts"] == 130.0 for e in ends)
   assert all(e["args"]["finish_reason"] == "killed" for e in ends)
   assert parent.close_remote(7) == 0, "idempotent"
@@ -283,9 +283,13 @@ def test_report_hop_breakdown_columns():
       _base(0, 200.0, name="serving/submit", args={"uid": uid}),
       _base(7, 300.0, ph="B", name="req r1", tid=5,
             cat="serving.request", args={"uid": uid}),
-      _base(7, 310.0, ph="B", name="prefill", tid=5, cat="serving"),
-      _base(7, 350.0, ph="E", name="prefill", tid=5, cat="serving"),
+      _base(7, 310.0, ph="B", name="serving/prefill", tid=5, cat="serving",
+            args={"uid": uid, "steps": 2, "tokens": 7}),
+      _base(7, 350.0, ph="E", name="serving/prefill", tid=5, cat="serving"),
       _base(7, 350.0, name="serving/first_token", args={"uid": uid}),
+      _base(7, 350.0, ph="B", name="serving/decode", tid=5, cat="serving",
+            args={"uid": uid, "steps": 3, "tokens": 3}),
+      _base(7, 400.0, ph="E", name="serving/decode", tid=5, cat="serving"),
       _base(7, 400.0, ph="E", name="req r1", tid=5,
             cat="serving.request", args={"finish_reason": "stop"}),
       _base(0, 460.0, name="frontdoor/first_byte", args={"uid": uid}),
@@ -295,7 +299,9 @@ def test_report_hop_breakdown_columns():
   assert row["ingress_us"] == 100.0
   assert row["client_ttft_us"] == 360.0
   assert row["wire_us"] == 110.0
-  assert row["prefill_us"] == 40.0
+  assert row["prefill_us"] == 40.0 and row["decode_us"] == 50.0
+  assert (row["prefill_chunks"], row["prefill_tokens"]) == (2, 7)
+  assert (row["decode_steps"], row["decode_tokens"]) == (3, 3)
   text = report.format_report(events)
   assert "fd-ttft" in text and "wire" in text
   assert "360us" in text
@@ -312,8 +318,9 @@ def test_report_inner_spans_keyed_by_pid_and_tid():
             cat="serving.request", args={"uid": "a"}),
       # Same tid, same window, DIFFERENT pid: must not be attributed
       # to request "a".
-      _base(8, 110.0, ph="B", name="prefill", tid=5, cat="serving"),
-      _base(8, 150.0, ph="E", name="prefill", tid=5, cat="serving"),
+      _base(8, 110.0, ph="B", name="serving/prefill", tid=5, cat="serving",
+            args={"uid": "a", "steps": 1, "tokens": 4}),
+      _base(8, 150.0, ph="E", name="serving/prefill", tid=5, cat="serving"),
       _base(7, 200.0, ph="E", name="req a", tid=5,
             cat="serving.request", args={"finish_reason": "stop"}),
   ]

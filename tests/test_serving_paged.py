@@ -339,7 +339,7 @@ def test_paged_geometry_validation():
 
 
 def test_paged_timeline_blocks_in_report():
-  """The per-request timeline shows block occupancy: per-step spans
+  """The per-request timeline shows block occupancy: the phase spans
   carry kv_blocks and report.py rolls up each request's peak."""
   from easyparallellibrary_tpu.observability import trace as trace_lib
   from easyparallellibrary_tpu.observability.report import (
