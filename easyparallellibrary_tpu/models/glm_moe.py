@@ -53,7 +53,8 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from easyparallellibrary_tpu.models.gpt import _missing_slot_cache
+from easyparallellibrary_tpu.models.gpt import (
+    _missing_slot_cache, flat_ids)
 from easyparallellibrary_tpu.models.jamba import (
     GatedMLP, RMSNorm, _boxed, _dense)
 from easyparallellibrary_tpu.models.moe import DroplessMoE
@@ -127,7 +128,8 @@ class LatentAttention(nn.Module):
   slot_attn_impl: Optional[str] = None
 
   @nn.compact
-  def __call__(self, h, positions, slot_cursors=None, num_valid=None):
+  def __call__(self, h, positions, slot_cursors=None, num_valid=None,
+               rows=None):
     cfg = self.cfg
     B, S, _ = h.shape
     H, r = cfg.num_heads, cfg.kv_lora_rank
@@ -149,17 +151,24 @@ class LatentAttention(nn.Module):
       from easyparallellibrary_tpu.kernels.kv_write import kv_write
       from easyparallellibrary_tpu.kernels.slot_attention import (
           slot_attention)
+      # ``h`` is the step's token-flat batch [T, 1, D]
+      # (models/gpt.py:SlotRows); the window write and the attend take
+      # their operands as [slots, C, ...], everything around them stays
+      # flat.
       latent = self.variable("cache", "cached_latent", _missing_slot_cache)
-      rows = jnp.concatenate([c[:, :, None], k_r], -1)       # [B,S,1,r+dr]
-      latent.value, _ = kv_write(latent.value, None, rows, None,
+      new = jnp.concatenate([c[:, :, None], k_r], -1)        # [T,1,1,r+dr]
+      latent.value, _ = kv_write(latent.value, None,
+                                 rows.to_slots(new[:, 0]), None,
                                  slot_cursors, impl=self.kv_write_impl)
       q_abs = jnp.concatenate(
           [jnp.einsum("bshd,rhd->bshr", q_nope, w_kvb[..., :dn]), q_rope],
-          -1)                                                # [B,S,H,r+dr]
-      o_lat = slot_attention(q_abs, latent.value, None, slot_cursors,
-                             num_valid, impl=self.slot_attn_impl,
-                             v_width=r, scale=scale).astype(cfg.dtype)
-      out = jnp.einsum("bshr,rhd->bshd", o_lat, w_kvb[..., dn:])
+          -1)                                                # [T,1,H,r+dr]
+      o_lat = slot_attention(rows.to_slots(q_abs[:, 0]), latent.value, None,
+                             slot_cursors, num_valid,
+                             impl=self.slot_attn_impl, v_width=r,
+                             scale=scale).astype(cfg.dtype)
+      out = jnp.einsum("bshr,rhd->bshd", rows.to_flat(o_lat)[:, None],
+                       w_kvb[..., dn:])
     else:
       kv_full = jnp.einsum("bsr,rhd->bshd", c, w_kvb)
       k = jnp.concatenate(
@@ -184,22 +193,23 @@ class GlmMoeBlock(nn.Module):
   moe_gmm_impl: Optional[str] = None
 
   @nn.compact
-  def __call__(self, x, positions, slot_cursors=None, num_valid=None):
+  def __call__(self, x, positions, slot_cursors=None, num_valid=None,
+               rows=None):
     cfg = self.cfg
     norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
     x = x + LatentAttention(
         cfg, decode=self.decode, kv_write_impl=self.kv_write_impl,
         slot_attn_impl=self.slot_attn_impl, name="latent")(
-            norm("norm_in")(x), positions, slot_cursors, num_valid)
+            norm("norm_in")(x), positions, slot_cursors, num_valid, rows)
     h = norm("norm_ff")(x)
     if self.dense:
       return x + GatedMLP(cfg, name="mlp")(h)
-    # Only live positions are routed: a chunk's tail beyond ``num_valid``
-    # and an idle slot's rows reach no expert.
-    live = None if num_valid is None else (
-        jnp.arange(x.shape[1])[None] < num_valid[:, None])
+    # Only live positions are routed: a chunk's tail beyond ``num_valid``,
+    # an idle slot's positions and the flat batch's padding rows reach no
+    # expert.
     return x + DroplessMoE(cfg, moe_gmm_impl=self.moe_gmm_impl,
-                           name="moe")(h, live)
+                           name="moe")(
+                               h, None if rows is None else rows.live)
 
 
 class GlmMoe(nn.Module):
@@ -209,14 +219,17 @@ class GlmMoe(nn.Module):
   sits at position ``slot_cursors[b] + i``, ``num_valid`` int32
   ``[slots]`` says how many of the chunk's positions each slot feeds
   (``None``: all) — what the attend reads and what the experts are
-  handed."""
+  handed.  In slot mode the position-wise layers run on the token-flat
+  batch ``rows`` describes (models/gpt.py:SlotRows; every position of
+  every slot when none is handed in) and the logits are those of the
+  rows it names."""
 
   cfg: GlmMoeConfig
 
   @nn.compact
   def __call__(self, ids, decode: bool = False, return_hidden: bool = False,
                slot_cursors=None, num_valid=None, kv_write_impl=None,
-               slot_attn_impl=None, moe_gmm_impl=None):
+               slot_attn_impl=None, moe_gmm_impl=None, rows=None):
     cfg = self.cfg
     if decode and slot_cursors is None:
       raise ValueError(
@@ -227,9 +240,11 @@ class GlmMoe(nn.Module):
       raise ValueError("slot_cursors is a decode-mode argument (serving "
                        "engine); pass decode=True")
     B, S = ids.shape
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    if slot_cursors is not None:
-      positions = positions + slot_cursors.astype(jnp.int32)[:, None]
+    if decode:
+      rows, ids = flat_ids(ids, slot_cursors, num_valid, rows)
+      positions = rows.positions
+    else:
+      positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     x = Embedding(cfg.vocab_size, cfg.d_model, parallel="none",
                   param_dtype=cfg.param_dtype, name="embed")(ids).astype(
                       cfg.dtype)
@@ -238,7 +253,10 @@ class GlmMoe(nn.Module):
                       kv_write_impl=kv_write_impl,
                       slot_attn_impl=slot_attn_impl,
                       moe_gmm_impl=moe_gmm_impl, name=f"block_{i}")(
-                          x, positions, slot_cursors, num_valid)
+                          x, positions, slot_cursors, num_valid, rows)
+    if decode:
+      # The last norm and the head run on the rows that are read.
+      x = rows.head_rows(x)
     x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm_f")(x)
     if return_hidden:
       return x

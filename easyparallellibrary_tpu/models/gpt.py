@@ -271,9 +271,127 @@ def paged_step_logits(model, params, kv, tokens, slot_ids, positions,
   return logits[:, 0], mut["cache"]
 
 
+@dataclasses.dataclass
+class SlotRows:
+  """The map between a fused step's ``[slots, C]`` chunk positions and the
+  token-flat batch its position-wise layers run on, threaded through a
+  model to every layer that owns per-slot state (the contiguous cache's
+  twin of :class:`PagedInfo`; a PLAIN dataclass for the same reason).
+  Built once a step by :func:`slot_rows`.
+
+  In slot mode a model's residual stream is ``[T, 1, D]``, one token a
+  batch row as in the paged step: row ``t`` is chunk position ``i`` of
+  slot ``b``, the live positions of slot 0 first, then slot 1's, and so
+  on; rows at or beyond the step's live total are padding.  Embedding,
+  norms, projections, MLPs, routers and experts see that batch and
+  nothing else.  A mixer that owns per-slot state (the K/V window and
+  the attend, a recurrence, a convolution window) takes its operands
+  :meth:`to_slots`, runs in the ``[slots, C, ...]`` layout its kernels
+  are written for, and hands its result :meth:`to_flat`.  Both moves are
+  gathers of whole rows (a scatter is a serial loop on a TPU): a dead
+  chunk position reads some other row's values, which nothing reads
+  after it, exactly as it held garbage before; a padding row is
+  gathered by no position.
+
+  ``src`` int32 ``[T]`` — the ``slot * C + i`` each flat row reads;
+  ``dst`` int32 ``[slots * C]`` — the flat row each chunk position reads
+  back; both ``None`` at full width (``T == slots x C``), where the map
+  is a reshape.  ``live`` bool ``[T, 1]`` — rows that carry a live
+  position (``None``: all; what a dropless expert layer routes).
+  ``positions`` int32 ``[T, 1]`` — each row's absolute position,
+  ``cursors[b] + i``.  ``head`` int32 ``[slots]`` or ``[slots, R]`` —
+  the flat rows whose logits the caller asked for (``None``: every chunk
+  position's).
+  """
+  slots: int
+  chunk: int
+  src: Any
+  dst: Any
+  live: Any
+  positions: Any
+  head: Any = None
+
+  def to_slots(self, flat):
+    """``[T, ...]`` -> ``[slots, C, ...]``.  Rows are gathered whole,
+    as ``[T, features]``: lane-dense whatever the trailing axes are."""
+    tail = flat.shape[1:]
+    if self.dst is not None:
+      flat = jnp.take(flat.reshape(flat.shape[0], -1), self.dst, axis=0,
+                      mode="clip")
+    return flat.reshape(self.slots, self.chunk, *tail)
+
+  def to_flat(self, x):
+    """``[slots, C, ...]`` -> ``[T, ...]``, gathered as :meth:`to_slots`
+    gathers."""
+    tail = x.shape[2:]
+    x = x.reshape(self.slots * self.chunk, -1)
+    if self.src is not None:
+      x = jnp.take(x, self.src, axis=0, mode="clip")
+    return x.reshape(x.shape[0], *tail)
+
+  def head_rows(self, x):
+    """The rows of ``x`` ``[T, 1, D]`` the head runs on: ``[slots, D]``
+    or ``[slots, R, D]`` as ``head`` asks, ``[slots, C, D]`` without."""
+    if self.head is None:
+      return self.to_slots(x[:, 0])
+    return jnp.take(x[:, 0], self.head, axis=0, mode="clip")
+
+
+def slot_rows(cursors, num_valid, slots: int, chunk: int,
+              width: Optional[int] = None, head_pos=None) -> SlotRows:
+  """The step's :class:`SlotRows`.  ``width`` is the flat batch's static
+  row count ``T`` (``None`` or ``slots x chunk``: full width, the map a
+  reshape); under a narrower one no step may hold more than ``T`` live
+  positions (the engine derives ``T`` and hands it to the scheduler as
+  its plans' ceiling, serving/engine.py:flat_width).  From ``num_valid`` alone: an
+  exclusive cumulative sum gives slot ``b``'s live positions the rows
+  ``[start_b, start_b + num_valid_b)``; the inverse, which slot a row
+  belongs to, is one compare-and-count (a binary search would be a
+  serial loop of scalar steps on a TPU).  ``head_pos`` int32 ``[slots]``
+  or ``[slots, R]`` names chunk positions whose logits are wanted."""
+  N, C = slots, chunk
+  i32 = jnp.int32
+  cursors = cursors.astype(i32)
+  # [slots] against ``head_pos``, which is [slots] or [slots, R]
+  per_slot = lambda v: v.reshape((N,) + (1,) * (head_pos.ndim - 1))
+  if width is None or width >= N * C:
+    positions = cursors[:, None] + jnp.arange(C, dtype=i32)[None]
+    live = None if num_valid is None else (
+        jnp.arange(C)[None] < num_valid[:, None]).reshape(N * C, 1)
+    head = None if head_pos is None else (
+        per_slot(jnp.arange(N, dtype=i32)) * C + head_pos)
+    return SlotRows(N, C, None, None, live, positions.reshape(N * C, 1),
+                    head)
+  T = width
+  ends = jnp.cumsum(num_valid.astype(i32))
+  starts = ends - num_valid
+  row = jnp.arange(T, dtype=i32)
+  slot = jnp.minimum(
+      jnp.sum(ends[None, :] <= row[:, None], axis=1, dtype=i32), N - 1)
+  i = row - jnp.take(starts, slot)
+  # Indices beyond either side (a padding row's, a dead chunk position's)
+  # are clipped where they are used (``SlotRows``' gathers).
+  dst = (starts[:, None] + jnp.arange(C, dtype=i32)[None]).reshape(N * C)
+  head = None if head_pos is None else per_slot(starts) + head_pos
+  return SlotRows(N, C, slot * C + i, dst, (row < ends[-1])[:, None],
+                  (jnp.take(cursors, slot) + i)[:, None], head)
+
+
+def flat_ids(ids, slot_cursors, num_valid, rows=None):
+  """A slot-mode call's map and its token ids as the flat batch takes
+  them: ``(rows, ids [T, 1])``.  A caller that hands no map in
+  (``model.apply(..., decode=True, slot_cursors=...)`` directly) gets the
+  full-width one, every position of every slot."""
+  if rows is None:
+    rows = slot_rows(slot_cursors, num_valid, *ids.shape)
+  return rows, rows.to_flat(ids)[:, None]
+
+
 def slot_step_logits(model, params, kv, tokens, cursors,
                      kv_write_impl=None, slot_attn_impl=None,
-                     num_valid=None, stats: bool = False, **state_args):
+                     num_valid=None, stats: bool = False,
+                     width: Optional[int] = None, head_pos=None,
+                     **state_args):
   """Multi-token scoring on the shared slot-cache core — THE device entry
   every serving component steps through.
 
@@ -308,13 +426,25 @@ def slot_step_logits(model, params, kv, tokens, cursors,
   none.  ``stats`` also returns what the model sowed into its ``stats``
   collection (an expert layer's load).
 
-  Returns ``(logits [num_slots, C, vocab], new_kv)`` and, with
-  ``stats``, the sown tree; the caller owns cursor advancement (and, for
-  speculation, rollback to the last accepted position).
+  The position-wise layers run on a token-flat batch (:class:`SlotRows`)
+  of ``width`` rows, which must hold the step's live positions:
+  ``None`` is ``num_slots x C``, every position of every slot, through
+  the same model code.  ``head_pos`` (int32 ``[num_slots]`` or
+  ``[num_slots, R]``: chunk positions) gathers the rows the head runs on
+  BEFORE the head: the one a slot samples from, or a speculating step's
+  ``K + 1``.
+
+  Returns ``(logits, new_kv)`` — ``logits`` ``[num_slots, C, vocab]``,
+  or ``[num_slots, vocab]`` / ``[num_slots, R, vocab]`` as ``head_pos``
+  asks — and, with ``stats``, the sown tree; the caller owns cursor
+  advancement (and, for speculation, rollback to the last accepted
+  position).
   """
+  rows = slot_rows(cursors, num_valid, *tokens.shape, width=width,
+                   head_pos=head_pos)
   logits, mut = model.apply(
       {"params": params, "cache": kv}, tokens, decode=True,
-      slot_cursors=cursors, num_valid=num_valid,
+      slot_cursors=cursors, num_valid=num_valid, rows=rows,
       kv_write_impl=kv_write_impl, slot_attn_impl=slot_attn_impl,
       mutable=["cache", "stats"] if stats else ["cache"], **state_args)
   if stats:
@@ -354,7 +484,7 @@ class CausalSelfAttention(nn.Module):
 
   @nn.compact
   def __call__(self, x, slot_cursors=None, paged_info=None,
-               num_valid=None):
+               num_valid=None, rows=None):
     cfg = self.cfg
     B, S, D = x.shape
     H = cfg.num_heads
@@ -364,12 +494,25 @@ class CausalSelfAttention(nn.Module):
 
     qkv = Dense(3 * D, parallel=col, use_bias=False, dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype, name="qkv")(x)
-    qkv = qkv.reshape(B, S, 3, H, head_dim)
-    # Heads ride the model axis (column-parallel QKV already produced the
-    # sharded feature dim; this re-expresses it on the head dim).
-    qkv = _constrain(qkv, P(constants.DATA_AXIS, None, None,
-                            constants.MODEL_AXIS, None))
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+    if rows is not None:
+      # Slot mode: x is the step's token-flat batch [T, 1, D]
+      # (:class:`SlotRows`); the window write and the attend own per-slot
+      # state and take their operands as [slots, C, H, hd], each gathered
+      # as its own block of whole rows (the three column blocks of the
+      # fused projection, cut before any reshape to heads).
+      q, k, v = (
+          _constrain(
+              rows.to_slots(qkv[:, 0, i * D:(i + 1) * D]).reshape(
+                  rows.slots, rows.chunk, H, head_dim),
+              P(constants.DATA_AXIS, None, constants.MODEL_AXIS, None))
+          for i in range(3))
+    else:
+      qkv = qkv.reshape(B, S, 3, H, head_dim)
+      # Heads ride the model axis (column-parallel QKV already produced
+      # the sharded feature dim; this re-expresses it on the head dim).
+      qkv = _constrain(qkv, P(constants.DATA_AXIS, None, None,
+                              constants.MODEL_AXIS, None))
+      q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
 
     if paged_info is not None:
       # Flat-token paged decode (serving/engine.py paged mode): x is
@@ -383,6 +526,8 @@ class CausalSelfAttention(nn.Module):
       out = out[:, None]
     elif self.decode:
       out = self._decode_attend(q, k, v, slot_cursors, num_valid)
+      if rows is not None:
+        out = rows.to_flat(out)[:, None]
     elif cfg.attn_impl == "ring":
       from easyparallellibrary_tpu.sequence.ring_attention import (
           ring_attention)
@@ -490,7 +635,7 @@ class Block(nn.Module):
 
   @nn.compact
   def __call__(self, x, slot_cursors=None, paged_info=None,
-               num_valid=None):
+               num_valid=None, rows=None):
     cfg = self.cfg
     drop = nn.Dropout(rate=cfg.dropout_rate,
                       deterministic=self.deterministic
@@ -500,7 +645,8 @@ class Block(nn.Module):
                                      kv_write_impl=self.kv_write_impl,
                                      slot_attn_impl=self.slot_attn_impl,
                                      name="attn")(y, slot_cursors,
-                                                  paged_info, num_valid))
+                                                  paged_info, num_valid,
+                                                  rows))
     y = LayerNorm(dtype=cfg.dtype, name="ln2")(x)
     if self.use_moe:
       from easyparallellibrary_tpu.models.moe import MoEMLP
@@ -651,7 +797,7 @@ class GPT(nn.Module):
   def __call__(self, ids, deterministic: bool = True,
                decode: bool = False, return_hidden: bool = False,
                slot_cursors=None, paged_info=None, kv_write_impl=None,
-               slot_attn_impl=None, num_valid=None):
+               slot_attn_impl=None, num_valid=None, rows=None):
     from easyparallellibrary_tpu.runtime.amp import resolve_model_dtypes
     cfg = resolve_model_dtypes(self.cfg)
     B, S = ids.shape
@@ -676,13 +822,15 @@ class GPT(nn.Module):
       pos_slice = jnp.take(jnp.asarray(pos), pos_ids, axis=0)  # [T, 1, D]
       x = tok(ids).astype(cfg.dtype) + pos_slice.astype(cfg.dtype)
     elif slot_cursors is not None:
-      # Slot mode (serving): absolute positions come straight from the
-      # per-slot cursor vector — no pos_index variable; the engine owns
-      # cursor advancement.  Past-capacity positions of garbage token
-      # slots clip into range (their outputs are never consumed).
-      pos_ids = jnp.clip(slot_cursors[:, None] + jnp.arange(S)[None],
-                         0, cfg.max_seq_len - 1)
-      pos_slice = jnp.take(jnp.asarray(pos), pos_ids, axis=0)  # [B, S, D]
+      # Slot mode (serving): the step's token-flat batch, ids [T, 1]
+      # (:class:`SlotRows`; every position of every slot when no map is
+      # handed in).  Absolute positions come straight from the per-slot
+      # cursor vector — no pos_index variable; the engine owns cursor
+      # advancement.  Past-capacity positions of garbage rows clip into
+      # range (their outputs are never consumed).
+      rows, ids = flat_ids(ids, slot_cursors, num_valid, rows)
+      pos_ids = jnp.clip(rows.positions, 0, cfg.max_seq_len - 1)
+      pos_slice = jnp.take(jnp.asarray(pos), pos_ids, axis=0)  # [T, 1, D]
       x = tok(ids).astype(cfg.dtype) + pos_slice.astype(cfg.dtype)
     elif decode:
       # Absolute positions while stepping: the cursor mirrors the
@@ -752,8 +900,12 @@ class GPT(nn.Module):
                       decode=decode, kv_write_impl=kv_write_impl,
                       slot_attn_impl=slot_attn_impl,
                       name=f"block_{i}")(x, slot_cursors, paged_info,
-                                         num_valid)
+                                         num_valid, rows)
 
+    if rows is not None:
+      # The head and the last norm run on the rows that are read: the
+      # one a slot samples from where the caller named it.
+      x = rows.head_rows(x)
     x = LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
     if return_hidden:
       return x

@@ -49,7 +49,7 @@ import numpy as np
 from flax import linen as nn
 
 from easyparallellibrary_tpu.models.gpt import (
-    _missing_slot_cache, slot_cache_attend)
+    _missing_slot_cache, flat_ids, slot_cache_attend)
 from easyparallellibrary_tpu.ops import Dense, Embedding
 
 # What a layer keeps per slot: the cache manager's vocabulary
@@ -141,7 +141,7 @@ class AttentionMixer(nn.Module):
   slot_attn_impl: Optional[str] = None
 
   @nn.compact
-  def __call__(self, h, slot_cursors=None, num_valid=None):
+  def __call__(self, h, slot_cursors=None, num_valid=None, rows=None):
     cfg = self.cfg
     B, S, _ = h.shape
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -149,12 +149,16 @@ class AttentionMixer(nn.Module):
     k = _dense(cfg, Hkv * hd, "k")(h).reshape(B, S, Hkv, hd)
     v = _dense(cfg, Hkv * hd, "v")(h).reshape(B, S, Hkv, hd)
     if self.decode:
+      # ``h`` is the step's token-flat batch [T, 1, D]
+      # (models/gpt.py:SlotRows); the window write and the attend take
+      # their operands as [slots, C, ...].
       ck = self.variable("cache", "cached_key", _missing_slot_cache)
       cv = self.variable("cache", "cached_value", _missing_slot_cache)
       out, ck.value, cv.value = slot_cache_attend(
-          q, k, v, ck.value, cv.value, slot_cursors, cfg.dtype,
-          write_impl=self.kv_write_impl, attn_impl=self.slot_attn_impl,
-          num_valid=num_valid)
+          *(rows.to_slots(t[:, 0]) for t in (q, k, v)), ck.value, cv.value,
+          slot_cursors, cfg.dtype, write_impl=self.kv_write_impl,
+          attn_impl=self.slot_attn_impl, num_valid=num_valid)
+      out = rows.to_flat(out)[:, None]
     else:
       out = gqa_causal_attention(q, k, v, cfg.dtype)
     return _dense(cfg, cfg.d_model, "o")(out.reshape(B, S, H * hd))
@@ -207,14 +211,20 @@ class MambaMixer(nn.Module):
   ssm_scan_impl: Optional[str] = None
 
   @nn.compact
-  def __call__(self, h, num_valid=None, reset=None):
+  def __call__(self, h, num_valid=None, reset=None, rows=None):
     from easyparallellibrary_tpu.kernels.ssm_scan import ssm_scan
     cfg = self.cfg
-    B, C, _ = h.shape
     Di, N, K, R = (cfg.d_inner, cfg.mamba_d_state, cfg.mamba_d_conv,
                    cfg.mamba_dt_rank)
     f32 = jnp.float32
     uz = _dense(cfg, 2 * Di, "in_proj")(h)
+    if self.decode:
+      # ``h`` is the step's token-flat batch [T, 1, D]
+      # (models/gpt.py:SlotRows): the convolution over a slot's window
+      # and the scan over its state run as [slots, C, ...], between the
+      # two projections.
+      uz = rows.to_slots(uz[:, 0])
+    B, C, _ = uz.shape
     u, z = uz[..., :Di], uz[..., Di:]
     conv_w = self.param("conv_w", _boxed(_uniform(K ** -0.5), 2), (K, Di),
                         cfg.param_dtype)
@@ -257,6 +267,7 @@ class MambaMixer(nn.Module):
                         impl=self.ssm_scan_impl)
     if self.decode:
       ssm_var.value = state
+      y = rows.to_flat(y)[:, None]
     return _dense(cfg, cfg.d_model, "out_proj")(y)
 
 
@@ -285,7 +296,8 @@ class JambaBlock(nn.Module):
   ssm_scan_impl: Optional[str] = None
 
   @nn.compact
-  def __call__(self, x, slot_cursors=None, num_valid=None, reset=None):
+  def __call__(self, x, slot_cursors=None, num_valid=None, reset=None,
+               rows=None):
     cfg = self.cfg
     norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
     h = norm("norm_in")(x)
@@ -293,11 +305,11 @@ class JambaBlock(nn.Module):
       mixed = AttentionMixer(cfg, decode=self.decode,
                              kv_write_impl=self.kv_write_impl,
                              slot_attn_impl=self.slot_attn_impl,
-                             name="attn")(h, slot_cursors, num_valid)
+                             name="attn")(h, slot_cursors, num_valid, rows)
     else:
       mixed = MambaMixer(cfg, decode=self.decode,
                          ssm_scan_impl=self.ssm_scan_impl,
-                         name="mamba")(h, num_valid, reset)
+                         name="mamba")(h, num_valid, reset, rows)
     x = x + mixed
     return x + GatedMLP(cfg, name="mlp")(norm("norm_ff")(x))
 
@@ -308,7 +320,10 @@ class Jamba(nn.Module):
   serving engine's slot mode (module docstring): ``num_valid`` int32
   ``[slots]`` says how many of the chunk's positions each slot's
   recurrent state takes (``None``: all), ``reset`` bool ``[slots]`` which
-  slots start from zero state."""
+  slots start from zero state.  In slot mode the position-wise layers run
+  on the token-flat batch ``rows`` describes (models/gpt.py:SlotRows;
+  every position of every slot when none is handed in) and the logits
+  are those of the rows it names."""
 
   cfg: JambaConfig
 
@@ -316,7 +331,7 @@ class Jamba(nn.Module):
   def __call__(self, ids, decode: bool = False, return_hidden: bool = False,
                slot_cursors=None, num_valid=None, reset=None,
                kv_write_impl=None, slot_attn_impl=None,
-               ssm_scan_impl=None):
+               ssm_scan_impl=None, rows=None):
     cfg = self.cfg
     if decode and slot_cursors is None:
       raise ValueError(
@@ -328,12 +343,17 @@ class Jamba(nn.Module):
                        "engine); pass decode=True")
     tok = Embedding(cfg.vocab_size, cfg.d_model, parallel="none",
                     param_dtype=cfg.param_dtype, name="embed")
+    if decode:
+      rows, ids = flat_ids(ids, slot_cursors, num_valid, rows)
     x = tok(ids).astype(cfg.dtype)
     for i, kind in enumerate(cfg.layer_kinds()):
       x = JambaBlock(cfg, kind, decode=decode, kv_write_impl=kv_write_impl,
                      slot_attn_impl=slot_attn_impl,
                      ssm_scan_impl=ssm_scan_impl, name=f"block_{i}")(
-                         x, slot_cursors, num_valid, reset)
+                         x, slot_cursors, num_valid, reset, rows)
+    if decode:
+      # The last norm and the head run on the rows that are read.
+      x = rows.head_rows(x)
     x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm_f")(x)
     if return_hidden:
       return x

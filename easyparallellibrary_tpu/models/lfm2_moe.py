@@ -57,7 +57,7 @@ from flax import linen as nn
 
 from easyparallellibrary_tpu.models.glm_moe import rotary
 from easyparallellibrary_tpu.models.gpt import (
-    _missing_slot_cache, slot_cache_attend)
+    _missing_slot_cache, flat_ids, slot_cache_attend)
 from easyparallellibrary_tpu.models.jamba import (
     ATTENTION, GatedMLP, RMSNorm, _boxed, _dense, _uniform, advance_window,
     gqa_causal_attention)
@@ -126,9 +126,9 @@ class ShortConv(nn.Module):
   decode: bool = False
 
   @nn.compact
-  def __call__(self, h, num_valid=None, reset=None):
+  def __call__(self, h, num_valid=None, reset=None, rows=None):
     cfg = self.cfg
-    B, C, D = h.shape
+    D = h.shape[-1]
     L = cfg.conv_L_cache
     bcu = _dense(cfg, 3 * D, "in_proj")(h)
     gate_b, gate_c, u = bcu[..., :D], bcu[..., D:2 * D], bcu[..., 2 * D:]
@@ -136,19 +136,25 @@ class ShortConv(nn.Module):
     conv_w = self.param("conv_w", _boxed(_uniform(L ** -0.5), 2), (L, D),
                         cfg.param_dtype)
     if self.decode:
+      # ``h`` is the step's token-flat batch [T, 1, D]
+      # (models/gpt.py:SlotRows): the convolution over a slot's window
+      # runs as [slots, C, D], everything around it stays flat.
+      z = rows.to_slots(z[:, 0])
       state = self.variable("cache", "conv_state", _missing_slot_cache)
       window = state.value
       if reset is not None:
         window = jnp.where(reset[:, None, None],
                            jnp.zeros((), window.dtype), window)
     else:
-      window = jnp.zeros((B, L - 1, D), z.dtype)
+      window = jnp.zeros((z.shape[0], L - 1, D), z.dtype)
+    C = z.shape[1]
     full = jnp.concatenate([window.astype(z.dtype), z], axis=1)
     w32 = jnp.asarray(conv_w, jnp.float32)
     conv = sum(full[:, j:j + C].astype(jnp.float32) * w32[j]
                for j in range(L)).astype(cfg.dtype)
     if self.decode:
       state.value = advance_window(full, num_valid, L - 1)
+      conv = rows.to_flat(conv)[:, None]
     return _dense(cfg, D, "out_proj")(gate_c * conv)
 
 
@@ -161,7 +167,8 @@ class NormedAttention(nn.Module):
   slot_attn_impl: Optional[str] = None
 
   @nn.compact
-  def __call__(self, h, positions, slot_cursors=None, num_valid=None):
+  def __call__(self, h, positions, slot_cursors=None, num_valid=None,
+               rows=None):
     cfg = self.cfg
     B, S, _ = h.shape
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -172,12 +179,16 @@ class NormedAttention(nn.Module):
     q = rotary(norm("q_norm")(q), positions, cfg.rope_theta)
     k = rotary(norm("k_norm")(k), positions, cfg.rope_theta)
     if self.decode:
+      # ``h`` is the step's token-flat batch [T, 1, D]
+      # (models/gpt.py:SlotRows); the window write and the attend take
+      # their operands as [slots, C, ...].
       ck = self.variable("cache", "cached_key", _missing_slot_cache)
       cv = self.variable("cache", "cached_value", _missing_slot_cache)
       out, ck.value, cv.value = slot_cache_attend(
-          q, k, v, ck.value, cv.value, slot_cursors, cfg.dtype,
-          write_impl=self.kv_write_impl, attn_impl=self.slot_attn_impl,
-          num_valid=num_valid)
+          *(rows.to_slots(t[:, 0]) for t in (q, k, v)), ck.value, cv.value,
+          slot_cursors, cfg.dtype, write_impl=self.kv_write_impl,
+          attn_impl=self.slot_attn_impl, num_valid=num_valid)
+      out = rows.to_flat(out)[:, None]
     else:
       out = gqa_causal_attention(q, k, v, cfg.dtype)
     return _dense(cfg, cfg.d_model, "o")(out.reshape(B, S, H * hd))
@@ -194,7 +205,7 @@ class Lfm2MoeBlock(nn.Module):
 
   @nn.compact
   def __call__(self, x, positions, slot_cursors=None, num_valid=None,
-               reset=None):
+               reset=None, rows=None):
     cfg = self.cfg
     norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype, name=name)
     h = norm("norm_in")(x)
@@ -202,20 +213,20 @@ class Lfm2MoeBlock(nn.Module):
       mixed = NormedAttention(
           cfg, decode=self.decode, kv_write_impl=self.kv_write_impl,
           slot_attn_impl=self.slot_attn_impl, name="attn")(
-              h, positions, slot_cursors, num_valid)
+              h, positions, slot_cursors, num_valid, rows)
     else:
       mixed = ShortConv(cfg, decode=self.decode, name="conv")(
-          h, num_valid, reset)
+          h, num_valid, reset, rows)
     x = x + mixed
     h = norm("norm_ff")(x)
     if self.dense:
       return x + GatedMLP(cfg, name="mlp")(h)
-    # Only live positions are routed: a chunk's tail beyond ``num_valid``
-    # and an idle slot's rows reach no expert.
-    live = None if num_valid is None else (
-        jnp.arange(x.shape[1])[None] < num_valid[:, None])
+    # Only live positions are routed: a chunk's tail beyond ``num_valid``,
+    # an idle slot's positions and the flat batch's padding rows reach no
+    # expert.
     return x + DroplessMoE(cfg, moe_gmm_impl=self.moe_gmm_impl,
-                           name="moe")(h, live)
+                           name="moe")(
+                               h, None if rows is None else rows.live)
 
 
 class Lfm2Moe(nn.Module):
@@ -226,14 +237,18 @@ class Lfm2Moe(nn.Module):
   ``num_valid`` int32 ``[slots]`` says how many of the chunk's positions
   each slot feeds (``None``: all) — what the attend reads, what the
   experts are handed and how far a convolution window advances —
-  ``reset`` bool ``[slots]`` which slots start from an empty window."""
+  ``reset`` bool ``[slots]`` which slots start from an empty window.  In
+  slot mode the position-wise layers run on the token-flat batch ``rows``
+  describes (models/gpt.py:SlotRows; every position of every slot when
+  none is handed in) and the logits are those of the rows it names."""
 
   cfg: Lfm2MoeConfig
 
   @nn.compact
   def __call__(self, ids, decode: bool = False, return_hidden: bool = False,
                slot_cursors=None, num_valid=None, reset=None,
-               kv_write_impl=None, slot_attn_impl=None, moe_gmm_impl=None):
+               kv_write_impl=None, slot_attn_impl=None, moe_gmm_impl=None,
+               rows=None):
     cfg = self.cfg
     if decode and slot_cursors is None:
       raise ValueError(
@@ -244,9 +259,11 @@ class Lfm2Moe(nn.Module):
       raise ValueError("slot_cursors is a decode-mode argument (serving "
                        "engine); pass decode=True")
     B, S = ids.shape
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    if slot_cursors is not None:
-      positions = positions + slot_cursors.astype(jnp.int32)[:, None]
+    if decode:
+      rows, ids = flat_ids(ids, slot_cursors, num_valid, rows)
+      positions = rows.positions
+    else:
+      positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     tok = Embedding(cfg.vocab_size, cfg.d_model, parallel="none",
                     param_dtype=cfg.param_dtype, name="embed")
     x = tok(ids).astype(cfg.dtype)
@@ -255,7 +272,11 @@ class Lfm2Moe(nn.Module):
                        decode=decode, kv_write_impl=kv_write_impl,
                        slot_attn_impl=slot_attn_impl,
                        moe_gmm_impl=moe_gmm_impl, name=f"block_{i}")(
-                           x, positions, slot_cursors, num_valid, reset)
+                           x, positions, slot_cursors, num_valid, reset,
+                           rows)
+    if decode:
+      # The last norm and the head run on the rows that are read.
+      x = rows.head_rows(x)
     x = RMSNorm(cfg.norm_eps, cfg.dtype, name="norm_f")(x)
     if return_hidden:
       return x

@@ -6,9 +6,16 @@ jitted step — compiled once, shapes never change — fuses
   * prefill of newly admitted requests (their next prompt chunk), and
   * one-token decode of every other active slot
 
-into a single ``[num_slots, chunk]`` model call against the slot KV
-cache (kv_cache.py), per-slot cursors selecting each slot's absolute
-positions and causal window (models/gpt.py ``slot_cache_attend``).
+into a single model call against the slot KV cache (kv_cache.py),
+per-slot cursors selecting each slot's absolute positions and causal
+window (models/gpt.py ``slot_cache_attend``).  The plan is a
+``[num_slots, chunk]`` block; the call does its position-wise work on
+the block's LIVE positions, packed in slot order into a token-flat
+batch of ``flat_width(num_slots, chunk)`` rows, moves to the
+``[num_slots, chunk, ...]`` layout only around the operations that own
+per-slot state (the window write, the attend, a recurrence), and runs
+the head on the one row a slot samples from (models/gpt.py ``SlotRows``;
+docs/serving.md "The flat batch").
 Requests therefore join and leave the batch every iteration with zero
 recompilation — iteration-level batching as in Orca (OSDI'22) — and the
 cache + cursor buffers are donated, so the engine's steady-state device
@@ -187,6 +194,24 @@ def _expert_stats(sown):
       if any(getattr(k, "key", None) == name for k in path)])
   return jnp.stack([jnp.max(named("expert_load")),
                     jnp.min(named("experts_touched"))])
+
+
+def flat_width(num_slots: int, chunk: int) -> int:
+  """Rows ``T`` of the token-flat batch the contiguous fused step runs its
+  position-wise layers on (models/gpt.py:SlotRows), static for the
+  engine's life: one compiled program a twin.  A step computes ``T`` rows
+  whatever is live, so ``T`` is sized for what a step holds, not for every
+  position of every slot: HALF of ``num_slots x chunk``, up to a multiple
+  of 128 (the sweep that chose the half: PERF.md, PR 36), never under
+  ``num_slots`` (every slot can always decode) and never above
+  ``num_slots x chunk`` (full width, where the map is a reshape and the
+  ceiling never binds).  A function of the two shapes an engine is given
+  and of nothing else, for every model and every twin.  The scheduler is
+  handed ``T`` as the ceiling of a plan's live positions
+  (serving/scheduler.py ``width``)."""
+  round_up = lambda n: -(-n // 128) * 128
+  return min(max(round_up(num_slots * chunk // 2), round_up(num_slots)),
+             num_slots * chunk)
 
 
 def _resolve_mesh(mesh):
@@ -441,9 +466,21 @@ class ContinuousBatchingEngine:
           cfg, "speculative decoding (serving.speculative: rejected "
           "drafts roll back)")
       check_latent_cache(cfg, "speculative decoding (serving.speculative)")
+    # Rows of the token-flat batch the contiguous step's position-wise
+    # layers run on (``flat_width``), the plain step's and the speculating
+    # one's alike; the scheduler keeps every plan, drafts included, within
+    # it.  0 on a paged engine (``token_budget`` is its width).
+    self.flat_width = 0 if self.paged else flat_width(self.num_slots,
+                                                      self.chunk)
+    if not self.paged:
+      trace_lib.get_tracer().metadata(
+          f"{self._track_prefix}/flat_width",
+          {"width": self.flat_width,
+           "positions": self.num_slots * self.chunk})
     self.scheduler = FCFSScheduler(
         num_slots=self.num_slots, prefill_chunk=self.chunk,
         max_seq_len=cfg.max_seq_len, prefill_token_budget=budget,
+        width=self.flat_width,
         max_batch=eff_batch,
         stop_token=stop_token if stop_token is not None
         else conf.stop_token,
@@ -665,7 +702,8 @@ class ContinuousBatchingEngine:
                 f"{kv_lib.paged_cache_bytes(cfg, self.num_blocks, self.block_size) / 1e6:.1f} MB")
     else:
       lay = self.cache_layout
-      layout = (f"contiguous slots kept in {lay['kv_order']}, "
+      layout = (f"flat width {self.flat_width}, "
+                f"contiguous slots kept in {lay['kv_order']}, "
                 f"{self.slot_attn_impl} attend, "
                 f"{self.kv_write_impl} kv write, "
                 f"{kv_lib.cache_bytes(cfg, self.num_slots, self.chunk) / 1e6:.1f} MB")
@@ -881,6 +919,7 @@ class ContinuousBatchingEngine:
     from easyparallellibrary_tpu.models.gpt import slot_step_logits
     model = self.model
     C = self.chunk
+    width = self.flat_width
     write_impl = self.kv_write_impl
     attn_impl = self.slot_attn_impl
     scan_impl = self.ssm_scan_impl
@@ -906,16 +945,15 @@ class ContinuousBatchingEngine:
         state_args["ssm_scan_impl"] = scan_impl
       if experts:
         state_args["moe_gmm_impl"] = gmm_impl
-      logits, kv, *sown = slot_step_logits(
+      # Each slot's next-token logits sit at its LAST live chunk
+      # position, and the head runs on that row alone; idle slots
+      # (num_valid=0) read position 0 — garbage the scheduler never
+      # consumes.
+      last, kv, *sown = slot_step_logits(
           model, params, kv, tokens, cursors, kv_write_impl=write_impl,
           slot_attn_impl=attn_impl, num_valid=num_valid, stats=experts,
+          width=width, head_pos=jnp.clip(num_valid - 1, 0, C - 1),
           **state_args)
-      # Each slot's next-token logits sit at its LAST live chunk
-      # position; idle slots (num_valid=0) read position 0 — garbage the
-      # scheduler never consumes.
-      last = jnp.take_along_axis(
-          logits, jnp.clip(num_valid - 1, 0, C - 1)[:, None, None],
-          axis=1)[:, 0]
       step_keys = jax.vmap(jax.random.fold_in)(keys, tok_index)
       nxt = sample_token_slots(last.astype(jnp.float32), step_keys,
                                temperature, top_k, top_p)
@@ -952,25 +990,27 @@ class ContinuousBatchingEngine:
     model = self.model
     C = self.chunk
     K = self.drafter.k
+    width = self.flat_width
     write_impl = self.kv_write_impl
     attn_impl = self.slot_attn_impl
 
     def step(params, kv, cursors, tokens, num_valid, num_draft, reset,
              keys, tok_index, temperature, top_k, top_p):
       cursors = jnp.where(reset, 0, cursors)
-      logits, kv = slot_step_logits(model, params, kv, tokens, cursors,
-                                    kv_write_impl=write_impl,
-                                    slot_attn_impl=attn_impl,
-                                    num_valid=num_valid)
       # base = non-draft tokens fed (prefill grant, or 1 for decode);
       # position base-1+j's logits are the target distribution for
-      # draft j, and base-1+num_draft's feed the bonus token.  With
-      # num_draft=0 row 0 is exactly the legacy step's `last` gather.
+      # draft j, and base-1+num_draft's feed the bonus token: the head
+      # runs on those K+1 rows a slot.  With num_draft=0 row 0 is
+      # exactly the plain step's `last` row.
       base = num_valid - num_draft
       pos = jnp.clip(base[:, None] - 1 + jnp.arange(K + 1)[None],
                      0, C - 1)
-      tgt = jnp.take_along_axis(
-          logits, pos[:, :, None], axis=1).astype(jnp.float32)
+      tgt, kv = slot_step_logits(model, params, kv, tokens, cursors,
+                                 kv_write_impl=write_impl,
+                                 slot_attn_impl=attn_impl,
+                                 num_valid=num_valid, width=width,
+                                 head_pos=pos)
+      tgt = tgt.astype(jnp.float32)
       dpos = jnp.clip(base[:, None] + jnp.arange(K)[None], 0, C - 1)
       drafts = jnp.take_along_axis(tokens, dpos, axis=1)
       committed, n_committed, accepted = verify_tokens(
@@ -1439,6 +1479,14 @@ class ContinuousBatchingEngine:
       return (self.params, self._kv, plan.tokens, plan.slot_ids,
               plan.positions, plan.valid, plan.block_tables, last_idx,
               *drafts, plan.num_valid > 0, *sampling)
+    live = plan.prefill_tokens + plan.decode_tokens + (
+        0 if num_draft is None else int(num_draft.sum()))
+    if live > self.flat_width:
+      # The scheduler's ceiling keeps every plan within the width; a
+      # plan beyond it would lose its last positions without a sign.
+      raise RuntimeError(
+          f"the plan holds {live} live positions, the step's flat width "
+          f"is {self.flat_width}")
     if num_draft is None:
       return (self.params, self._kv, self._cursors, plan.tokens,
               plan.num_valid, plan.reset, self._prev_tokens,
@@ -1713,7 +1761,16 @@ class ContinuousBatchingEngine:
     # own sum of cursor + num_valid): what an attend bounded per slot
     # reads of ``_kv_rows``, and all a roofline of it may count.
     live_kv_rows = plan.live_kv_rows
-    routed_positions = int(plan.num_valid.sum()) if self._experts else 0
+    # Live rows of the step's flat batch (the plan's sum of ``num_valid``
+    # and the drafts that rode it), of ``flat_width`` on the contiguous
+    # cache; and the positions the plan held back for want of a row (0:
+    # the width cut nothing this step).  An expert model's layers route
+    # the plan's own positions.
+    fed_positions = plan.prefill_tokens + plan.decode_tokens
+    flat_positions = fed_positions + (
+        0 if step.num_draft is None else int(step.num_draft.sum()))
+    flat_trimmed = plan.flat_trimmed
+    routed_positions = fed_positions if self._experts else 0
     expert_load_max, experts_touched_min = (
         map(float, expert_load) if expert_load is not None else (0.0, 0.0))
     # Whether the step was launched with its predecessor in flight (0:
@@ -1726,6 +1783,8 @@ class ContinuousBatchingEngine:
       tracer.counter("serving/wasted_positions", plan.wasted)
       tracer.counter("serving/sampled_slots", sampled_slots)
       tracer.counter("serving/live_kv_rows", live_kv_rows)
+      tracer.counter("serving/flat_positions", flat_positions)
+      tracer.counter("serving/flat_trimmed", flat_trimmed)
       if self._recurrent:
         # Slots whose recurrent state this step zeroed: requests that
         # started (or restarted, after a requeue) here.
@@ -1749,7 +1808,8 @@ class ContinuousBatchingEngine:
           kv_rows=self._kv_rows, routed_positions=routed_positions,
           expert_load_max=expert_load_max,
           experts_touched_min=experts_touched_min,
-          overlapped=overlapped, wasted_positions=plan.wasted)
+          overlapped=overlapped, wasted_positions=plan.wasted,
+          flat_positions=flat_positions, flat_trimmed=flat_trimmed)
       if self.paged:
         self.stats.note_blocks(self.scheduler.kv_blocks_free,
                                self.scheduler.kv_blocks_used,
@@ -1769,6 +1829,8 @@ class ContinuousBatchingEngine:
           "slot_occupancy": plan.active_slots / self.num_slots,
           "sampled_slots": sampled_slots,
           "live_kv_rows": live_kv_rows,
+          "flat_positions": flat_positions,
+          "flat_trimmed": flat_trimmed,
           "prefill_tokens": pf_tokens,
           "decode_tokens": dc_tokens,
           "step_time_s": dt,

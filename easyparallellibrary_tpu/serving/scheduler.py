@@ -279,6 +279,9 @@ class StepPlan:
   # Set by commit(): slots whose request had retired by then (a stop
   # token or a cancellation seen one step late), their position dropped.
   wasted: int = 0
+  # Positions (prompt tokens, drafts) this plan held back because the
+  # fused step's flat batch had no row for them (``FCFSScheduler.width``).
+  flat_trimmed: int = 0
 
 
 @dataclasses.dataclass(eq=False)
@@ -312,6 +315,7 @@ class PagedStepPlan:
   live_kv_rows: int = 0       # as StepPlan's: resident + scheduled rows
   fed: List[Any] = dataclasses.field(default_factory=list)  # as StepPlan's
   wasted: int = 0
+  flat_trimmed: int = 0       # as StepPlan's; 0: the passes fit the budget
 
 
 class _Phase:
@@ -480,7 +484,7 @@ class FCFSScheduler:
                prefix_cache: bool = False,
                prefix_session_ttl_s: float = 0.0,
                prefix_max_cached_blocks: int = 0,
-               checkpoint_version: int = 0):
+               checkpoint_version: int = 0, width: int = 0):
     from easyparallellibrary_tpu.serving.kv_cache import (
         BlockAllocator, SlotAllocator)
     from easyparallellibrary_tpu.serving.prefix_cache import PrefixCache
@@ -557,6 +561,18 @@ class FCFSScheduler:
     self.spec_enabled = True
     # 0 = uncapped: every prefilling slot gets a full chunk each step.
     self.prefill_token_budget = prefill_token_budget
+    # Rows of the fused step's flat batch (serving/engine.py:flat_width;
+    # 0 = no ceiling): the most live positions ONE PLAN may hold.  A
+    # decoding slot's one row is never held back; the prefill grants share
+    # what the decoding slots leave, in admission order and through the
+    # path a prefill budget takes (``plan_step``): the grant that reaches
+    # the ceiling is cut short, the ones behind it wait, and all of them
+    # go on next step.  ``StepPlan.flat_trimmed`` counts what a plan held
+    # back for it.
+    if 0 < width < num_slots:
+      raise ValueError(f"width {width} must hold one row a slot: "
+                       f"{num_slots} slots")
+    self.width = width
     # Temporary degradation override (engine resilience): when > 0 the
     # effective per-step budget is min(budget or inf, override).
     self.budget_override = 0
@@ -1590,7 +1606,10 @@ class FCFSScheduler:
     token (decode latency is the metric continuous batching protects);
     prefill chunks are granted FCFS in admission order until the
     per-step budget runs out — a starved prefill slot simply carries
-    ``num_valid=0`` this step and resumes next step.
+    ``num_valid=0`` this step and resumes next step.  The fused step's
+    flat batch is a second ceiling on the same path (``width``): the
+    prefill grants share the rows the decoding slots leave, the grant
+    that reaches the last row is cut short and resumes at its cursor.
 
     ``ahead=True`` plans PAST the one outstanding plan, whose step is
     still running (class docstring): each slot goes on from where that
@@ -1632,6 +1651,7 @@ class FCFSScheduler:
         prefill_tokens=0, decode_tokens=0,
         active_slots=len(self.active), from_prev=np.zeros((N,), bool))
     budget = self._effective_budget()
+    room = self._prefill_room()
     spec_k = self.effective_spec_k        # hoisted: loop-invariant
     for slot in self._admit_order:
       state = self.active.get(slot)
@@ -1659,8 +1679,12 @@ class FCFSScheduler:
         grant = min(C, len(state.prefix) - pos)
         if budget > 0:
           grant = min(grant, max(budget - plan.prefill_tokens, 0))
+        if room is not None and grant > room - plan.prefill_tokens:
+          fits = max(room - plan.prefill_tokens, 0)
+          plan.flat_trimmed += grant - fits
+          grant = fits
         if grant == 0:
-          continue  # budget-starved this step; resumes next step
+          continue  # starved of budget or width this step; resumes next
         plan.tokens[slot, :grant] = state.prefix[pos:pos + grant]
         plan.num_valid[slot] = grant
         plan.prefilling[slot] = True
@@ -1684,8 +1708,37 @@ class FCFSScheduler:
       self._note_fed(plan, slot, state)
     if not plan.fed and ahead:
       return None   # every slot's remaining work is already on the device
+    if room is not None and spec_k > 0:
+      self._fit_drafts(plan)
     self._plans.append(plan)
     return plan
+
+  def _prefill_room(self) -> Optional[int]:
+    """Positions the next plan may hand to prefill under the flat batch's
+    width: the width less one row a decoding slot, which is never held
+    back.  None where no plan of the active slots could reach the width
+    (the usual case: nothing is counted)."""
+    if not self.width or len(self.active) * self.chunk <= self.width:
+      return None
+    decoding = sum(
+        1 for s in self.active.values()
+        if s.planned_pos >= len(s.prefix)
+        and s.planned_generated < s.req.max_new_tokens)
+    return self.width - decoding
+
+  def _fit_drafts(self, plan: StepPlan) -> None:
+    """Speculative drafts ride the rows the plan's own positions leave of
+    the width, in admission order; a draft that does not fit is not
+    proposed."""
+    spare = self.width - plan.prefill_tokens - plan.decode_tokens
+    if int(plan.draft_cap.sum()) <= spare:
+      return
+    for slot, *_ in plan.fed:
+      cap = int(plan.draft_cap[slot])
+      fits = min(cap, spare)
+      plan.flat_trimmed += cap - fits
+      plan.draft_cap[slot] = fits
+      spare -= fits
 
   def slot_histories(self, plan: StepPlan) -> Dict[int, np.ndarray]:
     """Committed tokens (prompt + generated) per draft-eligible slot of

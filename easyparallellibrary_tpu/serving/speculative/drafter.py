@@ -244,10 +244,10 @@ class DraftModelDrafter(Drafter):
           self.model.cfg, engine.num_slots, engine.chunk, mesh)
       geometry = (self.model.cfg, engine.num_slots, engine.chunk, mesh)
       self._fn = self._build_draft_fn(
-          engine.chunk, kv_lib.kv_write_impl(*geometry),
+          engine.chunk, engine.flat_width, kv_lib.kv_write_impl(*geometry),
           kv_lib.slot_attn_impl(*geometry))
 
-  def _build_draft_fn(self, chunk: int, kv_write_impl: str,
+  def _build_draft_fn(self, chunk: int, width: int, kv_write_impl: str,
                       slot_attn_impl: str):
     from easyparallellibrary_tpu.models.gpt import slot_step_logits
     model, K, C = self.model, self.k, chunk
@@ -262,11 +262,9 @@ class DraftModelDrafter(Drafter):
       cursors = jnp.where(reset, 0, cursors)
       # Mirror the engine's chunk: writes the same prefill K/V the
       # target wrote, and scores decode slots' last committed token.
-      logits, kv = score(model, params, kv, tokens, cursors,
-                         num_valid=num_valid)
-      last = jnp.take_along_axis(
-          logits, jnp.clip(num_valid - 1, 0, C - 1)[:, None, None],
-          axis=1)[:, 0]
+      last, kv = score(model, params, kv, tokens, cursors,
+                       num_valid=num_valid, width=width,
+                       head_pos=jnp.clip(num_valid - 1, 0, C - 1))
       toks = [jnp.argmax(last, axis=-1).astype(jnp.int32)]
       cur = cursors + num_valid
       # The roll-out feeds one token to every slot the chunk fed; an

@@ -746,6 +746,40 @@ def test_moe_gmm_compiles_for_v5e_at_the_cells_shapes(one_chip, highest,
     assert len(calls) == 1 and "moe_gmm" in calls[0].split("=")[0], calls
 
 
+def _abstract_step(model, slots, C, one_chip, **engine):
+  """The plain fused step as the engine builds it for ``model`` at
+  ``slots x C``, with every kernel's Pallas lowering, and abstract
+  arguments for it that sit on the described chip.  ``engine``: what else
+  ``_build_step`` reads of an engine (recurrent state, experts, the
+  scan's lowering)."""
+  import types
+  from flax import linen as nn
+  from easyparallellibrary_tpu.serving.engine import flat_width
+  on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+  params = jax.tree_util.tree_map(on_chip, nn.meta.unbox(jax.eval_shape(
+      lambda: model.init(jax.random.PRNGKey(0),
+                         jnp.zeros((1, 8), jnp.int32))["params"])))
+  kv = jax.tree_util.tree_map(
+      on_chip, kv_lib.cache_leaves(model.cfg, slots, C))
+  engine = types.SimpleNamespace(**{**dict(
+      model=model, num_slots=slots, chunk=C,
+      flat_width=flat_width(slots, C), kv_write_impl="pallas",
+      slot_attn_impl="pallas", ssm_scan_impl=None, _recurrent=False,
+      moe_gmm_impl=None, _experts=False,
+      _jit_step=lambda step, donate, **kw: jax.jit(
+          step, donate_argnums=(1, 2))), **engine})
+  step = ContinuousBatchingEngine._build_step(engine, True)
+  spec = lambda shape, d: jax.ShapeDtypeStruct(shape, d, sharding=one_chip)
+  i32, f32 = jnp.int32, jnp.float32
+  return step, (
+      params, kv, spec((slots,), i32), spec((slots, C), i32),
+      spec((slots,), i32), spec((slots,), jnp.bool_),
+      spec((slots,), i32), spec((slots,), jnp.bool_),   # prev, from_prev
+      spec((slots, 2), jnp.uint32), spec((slots,), i32),
+      spec((slots,), f32), spec((slots,), i32), spec((slots,), f32))
+
+
 def test_expert_step_compiled_for_v5e_holds_its_three_kernels(one_chip):
   """The fused step of a two-layer cut (one dense, one expert layer) of
   models/glm_moe.py at GLM-4.7-Flash's widths, 96 slots x chunk 8,
@@ -754,34 +788,13 @@ def test_expert_step_compiled_for_v5e_holds_its_three_kernels(one_chip):
   expert layer, no copy of a ``[96, 4104, 1, 576]`` leaf in either order
   of its dimensions, no ``[positions, 64, ..]`` dispatch tensor, no
   ``while`` loop (a scatter or a binary search would be one)."""
-  import types
-  from flax import linen as nn
   from easyparallellibrary_tpu.models.glm_moe import GlmMoe, GlmMoeConfig
   epl.init()
   slots, C = 96, 8
   cfg = GlmMoeConfig(num_layers=2, vocab_size=32768)
-  model = GlmMoe(cfg)
-  on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=one_chip)
-  params = jax.tree_util.tree_map(on_chip, nn.meta.unbox(jax.eval_shape(
-      lambda: model.init(jax.random.PRNGKey(0),
-                         jnp.zeros((1, 8), jnp.int32))["params"])))
-  kv = jax.tree_util.tree_map(on_chip, kv_lib.cache_leaves(cfg, slots, C))
-  engine = types.SimpleNamespace(
-      model=model, chunk=C, kv_write_impl="pallas", slot_attn_impl="pallas",
-      ssm_scan_impl=None, _recurrent=False, moe_gmm_impl="pallas",
-      _experts=True,
-      _jit_step=lambda step, donate, **kw: jax.jit(step,
-                                                   donate_argnums=(1, 2)))
-  step = ContinuousBatchingEngine._build_step(engine, True)
-  spec = lambda shape, d: jax.ShapeDtypeStruct(shape, d, sharding=one_chip)
-  i32, f32 = jnp.int32, jnp.float32
-  text = _compiled_text(
-      step, params, kv, spec((slots,), i32), spec((slots, C), i32),
-      spec((slots,), i32), spec((slots,), jnp.bool_),
-      spec((slots,), i32), spec((slots,), jnp.bool_),   # prev, from_prev
-      spec((slots, 2), jnp.uint32), spec((slots,), i32),
-      spec((slots,), f32), spec((slots,), i32), spec((slots,), f32))
+  step, args = _abstract_step(GlmMoe(cfg), slots, C, one_chip,
+                              moe_gmm_impl="pallas", _experts=True)
+  text = _compiled_text(step, *args)
   calls = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
   assert (calls("kv_write"), calls("slot_attn"), calls("moe_gmm")) == (
       2, 2, 2), text.count("tpu_custom_call")
@@ -804,35 +817,15 @@ def test_lfm2_step_compiled_for_v5e_holds_its_three_kernels(one_chip):
   heads of 64 under 32 query heads), two ``moe_gmm``, no copy or
   transpose of a leaf, no ``while`` loop (the window is advanced by
   selects)."""
-  import types
-  from flax import linen as nn
   from easyparallellibrary_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
   epl.init()
   slots, C = 128, 16
   cfg = Lfm2MoeConfig(layer_types=("conv", "full_attention"),
                       num_dense_layers=1, vocab_size=32768)
-  model = Lfm2Moe(cfg)
-  on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=one_chip)
-  params = jax.tree_util.tree_map(on_chip, nn.meta.unbox(jax.eval_shape(
-      lambda: model.init(jax.random.PRNGKey(0),
-                         jnp.zeros((1, 8), jnp.int32))["params"])))
-  kv = jax.tree_util.tree_map(on_chip, kv_lib.cache_leaves(cfg, slots, C))
-  engine = types.SimpleNamespace(
-      model=model, chunk=C, kv_write_impl="pallas", slot_attn_impl="pallas",
-      ssm_scan_impl=None, _recurrent=True, moe_gmm_impl="pallas",
-      _experts=True,
-      _jit_step=lambda step, donate, **kw: jax.jit(step,
-                                                   donate_argnums=(1, 2)))
-  step = ContinuousBatchingEngine._build_step(engine, True)
-  spec = lambda shape, d: jax.ShapeDtypeStruct(shape, d, sharding=one_chip)
-  i32, f32 = jnp.int32, jnp.float32
-  text = _compiled_text(
-      step, params, kv, spec((slots,), i32), spec((slots, C), i32),
-      spec((slots,), i32), spec((slots,), jnp.bool_),
-      spec((slots,), i32), spec((slots,), jnp.bool_),   # prev, from_prev
-      spec((slots, 2), jnp.uint32), spec((slots,), i32),
-      spec((slots,), f32), spec((slots,), i32), spec((slots,), f32))
+  step, args = _abstract_step(Lfm2Moe(cfg), slots, C, one_chip,
+                              _recurrent=True, moe_gmm_impl="pallas",
+                              _experts=True)
+  text = _compiled_text(step, *args)
   calls = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
   assert (calls("kv_write"), calls("slot_attn"), calls("moe_gmm")) == (
       1, 1, 2), text.count("tpu_custom_call")
@@ -842,3 +835,66 @@ def test_lfm2_step_compiled_for_v5e_holds_its_three_kernels(one_chip):
     if m and m.group(2).startswith(f"bf16[{slots},4112,"):
       assert m.group(3) in ("parameter", "bitcast", "get-tuple-element",
                             "custom-call"), line
+
+
+def _flat_cuts():
+  """Two-layer cuts of the four decoders at their cells' widths and
+  geometry: ``name -> (model, slots, C, engine attributes, kernel calls a
+  step (kv_write, slot_attn, ssm_scan, moe_gmm), vocabulary)``."""
+  from easyparallellibrary_tpu.models.glm_moe import GlmMoe, GlmMoeConfig
+  from easyparallellibrary_tpu.models.jamba import Jamba, JambaConfig
+  from easyparallellibrary_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
+  experts = dict(moe_gmm_impl="pallas", _experts=True)
+  return {
+      "gpt2m": (GPT(GPTConfig(vocab_size=50304, num_layers=2, num_heads=16,
+                              d_model=1024, d_ff=4096, max_seq_len=1024)),
+                96, 16, {}, (2, 2, 0, 0), 50304),
+      "jamba2": (Jamba(JambaConfig(num_layers=2, attn_layer_period=2,
+                                   attn_layer_offset=1, vocab_size=32768)),
+                 128, 8, dict(_recurrent=True, ssm_scan_impl="pallas"),
+                 (1, 1, 1, 0), 32768),
+      "glm47": (GlmMoe(GlmMoeConfig(num_layers=2, vocab_size=32768)),
+                96, 8, experts, (2, 2, 0, 2), 32768),
+      "lfm2": (Lfm2Moe(Lfm2MoeConfig(
+          layer_types=("conv", "full_attention"), num_dense_layers=1,
+          vocab_size=32768)), 128, 16, dict(_recurrent=True, **experts),
+               (1, 1, 0, 2), 32768)}
+
+
+@pytest.mark.parametrize("name", ["gpt2m", "jamba2", "glm47", "lfm2"])
+def test_flat_step_for_v5e_multiplies_the_width_and_heads_the_slots(
+    one_chip, name):
+  """The plain fused step as the engine builds it, lowered and compiled
+  for a described v5e: every matrix product of a position-wise layer has
+  ``flat_width(slots, C)`` rows and none ``slots x C``, the head's has
+  ``slots``, and the kernels are called as often a step as they were.  The
+  one exception is named: a Mamba layer's two small projections between
+  its convolution and its scan (``x_proj``: 5120 -> 192, ``dt_proj``: 160
+  -> 5120) stay with the ``[slots, C, ..]`` operands the scan takes."""
+  from easyparallellibrary_tpu.serving.engine import flat_width
+  epl.init()
+  model, slots, C, engine, kernel_calls, vocab = _flat_cuts()[name]
+  step, args = _abstract_step(model, slots, C, one_chip, **engine)
+  T = flat_width(slots, C)
+  assert T < slots * C
+  dots = []     # (lhs shape, result's last dimension)
+  for line in step.lower(*args).as_text().splitlines():
+    m = re.search(r"stablehlo\.dot_general .*: \(tensor<([\dx]+)x\w+>, "
+                  r"tensor<[\dx]+x\w+>\) -> tensor<([\dx]+)x\w+>", line)
+    if m:
+      dots.append(([int(d) for d in m.group(1).split("x")],
+                   int(m.group(2).split("x")[-1])))
+  heads = [lhs for lhs, out in dots if out == vocab]
+  assert heads == [[slots, heads[0][-1]]], heads
+  wide = [(lhs, out) for lhs, out in dots
+          if lhs[0] == slots * C or lhs[:2] == [slots, C]]
+  if name == "jamba2":
+    assert sorted(out for _, out in wide) == [192, 5120], wide
+  else:
+    assert not wide, wide
+  assert sum(lhs[0] == T for lhs, _ in dots) >= 8, dots
+  text = _compiled_text(step, *args)
+  calls = lambda kernel: len(re.findall(rf"%{kernel}[.\d]* = ", text))
+  assert tuple(calls(k) for k in (
+      "kv_write", "slot_attn", "ssm_scan", "moe_gmm")) == kernel_calls
+  assert " while(" not in text
