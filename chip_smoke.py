@@ -45,6 +45,16 @@ a user calls, and checks what comes out by the repo's own references:
                   their reference lowerings; a three-layer cut (conv +
                   dense, attention + experts, conv + experts) through the
                   engine as for ``experts``
+  dots3           (PR 39) the decoder whose full layers select the rows
+                  they attend and whose others attend behind a window
+                  (models/dots3_note.py, dots3-note-prev's widths): the
+                  ring ``kv_write``, ``dsa_index``, ``kth_largest``,
+                  ``slot_attn_sel`` and ``slot_attn_win`` at its cell's
+                  leaves (12,832 rows, rings of 640, chunk 32, selection
+                  2048, window 513; f32, bf16) against their references; a
+                  three-layer cut (full + dense, full + experts, window +
+                  experts, 8 of 256 experts held) through the engine as
+                  for ``experts``
   overlap         (PR 33) GPT-2 medium and the LFM2 cut, 32 requests each
                   through the engine's overlapped loop (step k+1 launched
                   before step k's tokens are fetched) and through the
@@ -91,7 +101,11 @@ from easyparallellibrary_tpu.kernels.slot_attention import (
     slot_attention_reference)
 from easyparallellibrary_tpu.kernels.ssm_scan import (
     SSM_SCAN, ssm_scan_pallas, ssm_scan_reference)
+from easyparallellibrary_tpu.kernels import dsa_index as dsa_lib
+from easyparallellibrary_tpu.kernels import slot_attention as slot_attn_lib
 from easyparallellibrary_tpu.models import GPT, GPTConfig
+from easyparallellibrary_tpu.models.dots3_note import (
+    FULL, SLIDING, Dots3Note, Dots3NoteConfig)
 from easyparallellibrary_tpu.models.glm_moe import GlmMoe, GlmMoeConfig
 from easyparallellibrary_tpu.models.jamba import MAMBA, Jamba, JambaConfig
 from easyparallellibrary_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
@@ -164,6 +178,9 @@ class Sizes:
   lfm2_cfg: Lfm2MoeConfig         # conv + dense, attention + experts, conv
   lfm2_kv_shape: tuple            # (slots, Lc, H, H_kv, hd, chunk)
   lfm2_gmm_shapes: tuple          # (rows, K, N, experts) of a layer's two
+  dots3_cfg: Dots3NoteConfig      # full + dense, full + experts, window
+  dots3_shapes: tuple             # (slots, Lc, chunk, ring rows) of the
+                                  # kernels' checks
 
   @staticmethod
   def real() -> "Sizes":
@@ -215,7 +232,20 @@ class Sizes:
         # The cell's: 128 slots x (4096 + 16) positions x 8 K/V heads of
         # 64; 128 slots x chunk 16 x 4 experts a token.
         lfm2_kv_shape=(128, 4112, 32, 8, 64, 16),
-        lfm2_gmm_shapes=((8192, 2048, 3584, 32), (8192, 1792, 2048, 32)))
+        lfm2_gmm_shapes=((8192, 2048, 3584, 32), (8192, 1792, 2048, 32)),
+        # dots3-note-prev's widths, one layer of each kind of mixer and of
+        # feed-forward, 8 of its 256 experts held (the router keeps its
+        # width); vocabulary and context cut as above, and the selection
+        # (128 rows) and the window (129: a ring of 256 rows) cut so that
+        # a request of a few hundred positions discards rows and wraps.
+        dots3_cfg=Dots3NoteConfig(
+            vocab_size=8192, layer_types=(FULL, FULL, SLIDING),
+            experts_held=(8, 8), index_topk=128, sliding_window=129,
+            max_seq_len=1024, dtype=jnp.float32, param_dtype=jnp.float32),
+        # The cell's leaves (12,800 + 32 positions, rings of 640 rows) at
+        # its chunk, on 8 slots (the references' score tensors for 32
+        # would not fit).
+        dots3_shapes=(8, 12832, 32, 640))
 
   @staticmethod
   def toy() -> "Sizes":
@@ -254,7 +284,18 @@ class Sizes:
             num_dense_layers=1, n_routed_experts=8, num_experts_per_tok=2,
             max_seq_len=128, dtype=jnp.float32, param_dtype=jnp.float32),
         lfm2_kv_shape=(4, 136, 4, 2, 64, 8),
-        lfm2_gmm_shapes=((200, 256, 256, 8),))
+        lfm2_gmm_shapes=((200, 256, 256, 8),),
+        dots3_cfg=Dots3NoteConfig(
+            vocab_size=512, layer_types=(FULL, FULL, SLIDING), d_model=128,
+            d_ff=256, moe_d_ff=128, num_heads=8, q_lora_rank=32,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, index_n_heads=2, index_head_dim=128,
+            index_topk=16, sliding_window=33, swa_num_heads=8,
+            swa_q_lora_rank=32, swa_kv_lora_rank=48, swa_qk_nope_head_dim=24,
+            swa_qk_rope_head_dim=8, swa_v_head_dim=16, n_routed_experts=8,
+            experts_held=(2, 4), num_experts_per_tok=2, max_seq_len=256,
+            dtype=jnp.float32, param_dtype=jnp.float32),
+        dots3_shapes=(4, 264, 8, 128))
 
 
 def say(msg: str) -> None:
@@ -1118,13 +1159,18 @@ def check_latent_leaf(B, Lc, H, hd, rank, C, dtype, rehearsal: bool) -> None:
       "beyond the bounds unread")
 
 
-def serve_expert_cut(sizes: Sizes, model, want_calls: dict, what: str):
+def serve_expert_cut(sizes: Sizes, model, want_calls: dict, what: str,
+                     more_impls: tuple = (), gmm_may_decline: bool = False):
   """A cut of an expert decoder through the engine: every request runs to
   its length on ONE compile, every kernel resolved and counted
   (``want_calls``: custom calls by name in the compiled step), the served
   tokens against the teacher-forced full forward (the experts by
   ``ragged_dot``), and one fused call's logits, kernels against reference
-  lowerings.  Returns ``(gap, err)`` of the last two."""
+  lowerings (``more_impls``: further lowerings the model's step takes by
+  name; ``gmm_may_decline``: the grouped matmul's rule may take the
+  reference, as it does for float32 experts of a hidden size of 5120,
+  whose tiles pass its VMEM budget: then no ``moe_gmm`` call is expected).
+  Returns ``(gap, err)`` of the last two."""
   from easyparallellibrary_tpu.models.gpt import slot_step_logits
   from easyparallellibrary_tpu.serving import kv_cache as kv_lib
   cfg = model.cfg
@@ -1150,6 +1196,8 @@ def serve_expert_cut(sizes: Sizes, model, want_calls: dict, what: str):
         f"matmul {eng.moe_gmm_impl}, cache {eng.cache_layout}")
     if not sizes.rehearsal:
       impls = (eng.kv_write_impl, eng.slot_attn_impl, eng.moe_gmm_impl)
+      if gmm_may_decline and eng.moe_gmm_impl == "reference":
+        impls, want_calls = impls[:2], dict(want_calls, **{MOE_GMM: 0})
       hlo = spy.inner.lower(*spy.specs).compile().as_text()
       calls = {n: named_calls(hlo, n) for n in want_calls}
       check(all(i == "pallas" for i in impls) and calls == want_calls,
@@ -1180,12 +1228,16 @@ def serve_expert_cut(sizes: Sizes, model, want_calls: dict, what: str):
     num_valid = jnp.asarray([C, 1, 0, C // 2, 1, C, 0, 1], jnp.int32)
     state_args = {"reset": jnp.zeros((N,), jnp.bool_)} if recurrent else {}
     kernel_impl = "interpret" if sizes.rehearsal else "pallas"
+    # Where the grouped matmul's rule declined the experts' shapes, the
+    # kernel side runs it as the engine did.
+    declined = gmm_may_decline and eng.moe_gmm_impl == "reference"
     got = {}
     for impl in (kernel_impl, "reference"):
       kv, cursors = kv_lib.allocate_kv_cache(cfg, N, C)
       step = jax.jit(functools.partial(
           slot_step_logits, model, kv_write_impl=impl, slot_attn_impl=impl,
-          moe_gmm_impl=impl))
+          moe_gmm_impl="reference" if declined else impl,
+          **{name: impl for name in more_impls}))
       for _ in range(2):       # the second call reads what the first wrote
         lg, kv = step(params, kv, tokens, cursors, num_valid=num_valid,
                       **state_args)
@@ -1240,6 +1292,219 @@ def phase_lfm2(sizes: Sizes) -> None:
       f"attention layers, {cfg.num_dense_layers} dense + {n_moe} expert, "
       f"served tokens within {gap:.1e} of the teacher-forced best; step "
       f"logits kernels against reference lowerings {err:.2e}")
+
+
+# ------------------------------------------------------------------ dots3 --
+
+
+def _launches(chunk: int) -> int:
+  """Launches of a tiled kernel a call: the decoding slots take one of
+  their own where the chunk is tiled (``slot_attention.split_decodes``)."""
+  return 1 if chunk % 8 or chunk == 8 else 2
+
+
+def _bits(x):
+  return np.asarray(x).view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+def check_ring_write(B, R, W, C, dtype, rehearsal: bool) -> None:
+  """``kv_write`` of a ring ``[B, R, 1, W]`` bit for bit against the rows
+  written at their positions modulo ``R``: windows at the ring's start,
+  across a tile's edge, across the ring's END (two stripes, the second at
+  the leaf's head) and many turns in."""
+  r = np.random.RandomState(11)
+  leaf = jnp.asarray(r.randn(B, R, 1, W), dtype)
+  rows = jnp.asarray(r.randn(B, C, 1, W), dtype)
+  cursors = jnp.asarray(([0, 128 - C // 2, R - C // 2, R - 1, 7 * R + R - 3]
+                         + list(r.randint(0, 20 * R, B)))[:B], jnp.int32)
+  args = (leaf, None, rows, None, cursors)
+  write = compile_here(
+      functools.partial(kv_write_pallas, interpret=rehearsal, ring=True),
+      *args, mosaic_calls=1, rehearsal=rehearsal)
+  got, _ = write(*args)
+  want, _ = jax.jit(functools.partial(kv_write_reference, ring=True))(*args)
+  check((_bits(got) == _bits(want)).all(),
+        f"ring kv_write {jnp.dtype(dtype).name} differs from the reference")
+  say(f"  ring write slots{B} rows{R} width{W} chunk{C} "
+      f"{jnp.dtype(dtype).name}: bit-identical, "
+      f"{int((np.asarray(cursors) % R + C > R).sum())} windows across the "
+      "ring's end")
+
+
+def check_dsa_index(B, Lc, C, Hi, d, top_k, dtype, rehearsal: bool):
+  """``dsa_index`` against the einsum over every head (NaN in the index
+  rows at and beyond every bound), and ``kth_largest`` of its scores
+  against a sort.  Returns what the selected attend's check goes on with:
+  ``(cursors, num_valid, scores, thresholds)``."""
+  r = np.random.RandomState(12)
+  q = jnp.asarray(r.randn(B, C, Hi, d), dtype)
+  w = jnp.asarray(r.randn(B, C, Hi), jnp.float32)
+  keys = r.randn(B, Lc, d).astype(np.float32)
+  cursors, num_valid = edge_cases(r, B, Lc, C, 512 if Lc > 1024 else 128)
+  cur, nv = jnp.asarray(cursors), jnp.asarray(num_valid)
+  with jax.default_matmul_precision("highest"):
+    want = np.asarray(jax.jit(dsa_lib.dsa_index_reference)(
+        q, w, jnp.asarray(keys, dtype), cur, nv))
+    for b in range(B):
+      keys[b, cursors[b] + num_valid[b]:] = np.nan
+    dirty = jnp.asarray(keys, dtype)
+    kernel = compile_here(
+        functools.partial(dsa_lib.dsa_index_pallas.__wrapped__,
+                          interpret=rehearsal),
+        q, w, dirty, cur, nv, mosaic_calls=_launches(C),
+        rehearsal=rehearsal)
+    scores = kernel(q, w, dirty, cur, nv)
+  got = np.asarray(scores)
+  real = np.arange(C)[None] < num_valid[:, None]
+  check(np.isfinite(got[real]).all(), "dsa_index read a row beyond a bound")
+  err = rel_err(got[real], want[real])
+  tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+  check(err <= tol, f"dsa_index {jnp.dtype(dtype).name}: error {err:.3g} "
+        f"of the reference's max, tol {tol}")
+  t = cursors[:, None] + np.arange(C)[None]
+  k_each = np.clip(t + 1, 1, top_k).reshape(-1)
+  flat = jnp.where(jnp.asarray(real.reshape(-1, 1)),
+                   scores.reshape(B * C, Lc), dsa_lib.MASKED)
+  thr = np.asarray(jax.jit(dsa_lib.kth_largest)(flat, jnp.asarray(k_each)))
+  by_sort = np.sort(np.asarray(flat), 1)[:, ::-1][np.arange(B * C), k_each - 1]
+  check((thr == by_sort).all(), "kth_largest differs from a sort")
+  say(f"  dsa_index slots{B} Lc{Lc} chunk{C} heads{Hi} width{d} "
+      f"{jnp.dtype(dtype).name}: {err:.2e} of the reference's max, NaN "
+      f"beyond the bounds unread; the {top_k}th largest of every live "
+      "query's scores equals a sort's")
+  return cur, nv, flat.reshape(B, C, Lc), jnp.asarray(thr).reshape(B, C)
+
+
+def check_selected_attend(B, Lc, C, H, W, rank, picked, dtype,
+                          rehearsal: bool) -> None:
+  """``slot_attn_sel`` against the masked einsums: ``picked`` is what
+  :func:`check_dsa_index` returned (cursors, bounds, scores, thresholds);
+  NaN in every latent row at or beyond a bound."""
+  cur, nv, scores, thr = picked
+  r = np.random.RandomState(13)
+  leaf = r.randn(B, Lc, 1, W).astype(np.float32)
+  q = jnp.asarray(r.randn(B, C, H, W) / np.sqrt(W), dtype)
+  scale = 1.0 / np.sqrt(192.0)
+  cursors, num_valid = np.asarray(cur), np.asarray(nv)
+  with jax.default_matmul_precision("highest"):
+    ref = np.asarray(jax.jit(functools.partial(
+        slot_attn_lib.slot_attention_selected_reference, v_width=rank,
+        scale=scale))(q.astype(jnp.float32), jnp.asarray(leaf), scores, thr,
+                      cur), np.float32)
+    for b in range(B):
+      leaf[b, cursors[b] + num_valid[b]:] = np.nan
+    dirty = jnp.asarray(leaf, dtype)
+    attend = compile_here(
+        functools.partial(
+            slot_attn_lib.slot_attention_selected_pallas.__wrapped__,
+            interpret=rehearsal, v_width=rank, scale=scale),
+        q, dirty, scores, thr, cur, nv, mosaic_calls=_launches(C),
+        rehearsal=rehearsal)
+    out = np.asarray(attend(q, dirty, scores, thr, cur, nv), np.float32)
+  real = (np.arange(C)[None] < num_valid[:, None])[:, :, None, None]
+  check(np.isfinite(out).all(), "slot_attn_sel output not finite")
+  err = rel_err(np.where(real, out, 0), np.where(real, ref, 0))
+  tol = 2e-2 if dtype == jnp.bfloat16 else 5e-4
+  check(err <= tol, f"slot_attn_sel {jnp.dtype(dtype).name}: error "
+        f"{err:.3g} of the reference's max, tol {tol}")
+  say(f"  selected attend slots{B} Lc{Lc} heads{H}/1 width{W} values{rank} "
+      f"chunk{C} {jnp.dtype(dtype).name}: {err:.2e} of the reference's "
+      "max, NaN beyond the bounds unread")
+
+
+def check_window_attend(B, R, C, H, W, rank, window, dtype,
+                        rehearsal: bool) -> None:
+  """``slot_attn_win`` over rings filled position by position (never
+  written rows and the dead rows of this step's write hold NaN) against
+  plain attention over each query's window of the slot's history."""
+  r = np.random.RandomState(14)
+  q = jnp.asarray(r.randn(B, C, H, W) / np.sqrt(W), dtype)
+  cursors = np.asarray(([0, window - 3, R - C // 2, 3 * R + 5]
+                        + list(r.randint(0, 4 * R, B)))[:B], np.int32)
+  num_valid = np.asarray(([C, C, C, 1, 0, C // 2 or 1] + [1] * B)[:B],
+                         np.int32)
+  top = int((cursors + C).max())
+  hist = r.randn(B, top, W).astype(np.float32)
+  ring = np.full((B, R, 1, W), np.nan, np.float32)
+  for b in range(B):
+    for p in range(max(0, cursors[b] + num_valid[b] - R),
+                   cursors[b] + num_valid[b]):
+      ring[b, p % R, 0] = hist[b, p]
+    for p in range(cursors[b] + num_valid[b], cursors[b] + C):
+      ring[b, p % R, 0] = np.nan
+  scale = 1.0 / 16.0
+  cur, nv = jnp.asarray(cursors), jnp.asarray(num_valid)
+  with jax.default_matmul_precision("highest"):
+    attend = compile_here(
+        functools.partial(
+            slot_attn_lib.slot_attention_window_pallas.__wrapped__,
+            interpret=rehearsal, window=window, v_width=rank, scale=scale),
+        q, jnp.asarray(ring, dtype), cur, nv, mosaic_calls=_launches(C),
+        rehearsal=rehearsal)
+    out = np.asarray(attend(q, jnp.asarray(ring, dtype), cur, nv),
+                     np.float32)
+
+  # Plain attention over each live query's window, on the host, from
+  # the history as the leaf's dtype holds it.
+  held = np.asarray(jnp.asarray(hist, dtype).astype(jnp.float32))
+  qs = np.asarray(q.astype(jnp.float32))
+  worst, peak = 0.0, 1e-30
+  for b in range(B):
+    for i in range(num_valid[b]):
+      t = cursors[b] + i
+      keys = held[b, max(0, t - window + 1):t + 1]
+      s_ = qs[b, i] @ keys.T * scale
+      p = np.exp(s_ - s_.max(-1, keepdims=True))
+      want = (p / p.sum(-1, keepdims=True)) @ keys[:, :rank]
+      worst = max(worst, float(np.abs(out[b, i] - want).max()))
+      peak = max(peak, float(np.abs(want).max()))
+  check(np.isfinite(out).all(), "slot_attn_win output not finite")
+  dead = np.arange(C)[None] >= num_valid[:, None]
+  check((out[dead] == 0).all(), "slot_attn_win: dead positions not zeros")
+  err = worst / peak
+  tol = 2e-2 if dtype == jnp.bfloat16 else 5e-4
+  check(err <= tol, f"slot_attn_win {jnp.dtype(dtype).name}: error "
+        f"{err:.3g} of the reference's max, tol {tol}")
+  say(f"  window attend slots{B} ring{R} heads{H}/1 width{W} values{rank} "
+      f"window{window} chunk{C} {jnp.dtype(dtype).name}: {err:.2e} of "
+      "plain attention over each window, unwritten and dead rows unread")
+
+
+def phase_dots3(sizes: Sizes) -> None:
+  cfg = sizes.dots3_cfg
+  B, Lc, C, R = sizes.dots3_shapes
+  full, swa = cfg.latent_dims(FULL), cfg.latent_dims(SLIDING)
+  if sizes.rehearsal:
+    top_k, window = cfg.index_topk, cfg.sliding_window
+  else:
+    # The kernels at the cell's own sizes, whatever the served cut's.
+    top_k, window = 2048, 513
+  for dtype in (jnp.float32, jnp.bfloat16):
+    check_ring_write(B, R, swa.latent_dim, C, dtype, sizes.rehearsal)
+    picked = check_dsa_index(B, Lc, C, full.indexer.num_heads,
+                             full.indexer.head_dim, top_k, dtype,
+                             sizes.rehearsal)
+    check_selected_attend(B, Lc, C, full.num_heads, full.latent_dim,
+                          full.kv_lora_rank, picked, dtype, sizes.rehearsal)
+    check_window_attend(B, R, C, swa.num_heads, swa.latent_dim,
+                        swa.kv_lora_rank, window, dtype, sizes.rehearsal)
+  kinds = cfg.layer_types
+  n_full, n_win = kinds.count(FULL), kinds.count(SLIDING)
+  n_moe = cfg.num_layers - cfg.first_k_dense
+  gap, err = serve_expert_cut(
+      sizes, Dots3Note(cfg),
+      {MOE_GMM: 2 * n_moe, slot_attn_lib.SLOT_ATTN_SEL: 2 * n_full,
+       slot_attn_lib.SLOT_ATTN_WIN: 2 * n_win, dsa_lib.DSA_INDEX: 2 * n_full,
+       "kv_write": 2 * n_full + n_win}, "dots3",
+      more_impls=("dsa_index_impl",), gmm_may_decline=True)
+  say(f"PASS dots3: the ring kv_write, dsa_index, kth_largest, "
+      "slot_attn_sel and slot_attn_win f32 + bf16 "
+      + ("INTERPRETED" if sizes.rehearsal else "compiled")
+      + f" against their references; {n_full} selecting + {n_win} window "
+      f"layers, {cfg.first_k_dense} dense + {n_moe} expert holding "
+      f"{cfg.experts_held[1]} of {cfg.n_routed_experts}, served tokens "
+      f"within {gap:.1e} of the teacher-forced best; step logits kernels "
+      f"against reference lowerings {err:.2e}")
 
 
 # ---------------------------------------------------------------- overlap --
@@ -1336,7 +1601,7 @@ def main(argv=None) -> int:
   parser.add_argument(
       "--only", default=None,
       help="run this one phase (kernels, train, serve, hybrid, experts, "
-           "lfm2, overlap); prints no result line")
+           "lfm2, dots3, overlap); prints no result line")
   args = parser.parse_args(argv)
   t_start = time.perf_counter()
   cache_dir = compile_cache.configure()
@@ -1362,6 +1627,7 @@ def main(argv=None) -> int:
                       ("hybrid", lambda: phase_hybrid(sizes)),
                       ("experts", lambda: phase_experts(sizes)),
                       ("lfm2", lambda: phase_lfm2(sizes)),
+                      ("dots3", lambda: phase_dots3(sizes)),
                       ("overlap", lambda: phase_overlap(sizes))):
     if args.only not in (None, name):
       continue

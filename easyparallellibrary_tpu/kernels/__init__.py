@@ -1,4 +1,6 @@
 from easyparallellibrary_tpu.kernels.flash_attention import flash_attention
+from easyparallellibrary_tpu.kernels.dsa_index import (
+    dsa_index_pallas, dsa_index_reference)
 from easyparallellibrary_tpu.kernels.kv_write import (
     kv_write_pallas, kv_write_reference)
 from easyparallellibrary_tpu.kernels.moe_gmm import (
@@ -13,6 +15,7 @@ from easyparallellibrary_tpu.kernels.paged_attention import (
 )
 
 __all__ = [
+    "dsa_index_pallas", "dsa_index_reference",
     "flash_attention",
     "kv_write_pallas", "kv_write_reference",
     "moe_gmm_pallas", "moe_gmm_reference",

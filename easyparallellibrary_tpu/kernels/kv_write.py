@@ -65,6 +65,13 @@ A layer whose cache is ONE leaf (models/glm_moe.py: the latent ``[B, Lc,
 1, 576]`` whose values are its keys' leading columns) passes ``cached_v =
 v = None``: the same call with one operand instead of two, and ``None``
 back in the second place.
+
+A one-leaf cache kept as a RING (``ring=True``; models/dots3_note.py's
+window layers: ``[B, R, 1, W]``, ``R`` a whole number of 128-position
+tiles) takes position ``p`` at row ``p mod R``: the window starts at
+``cursor mod R`` and a chunk that crosses ``R`` lands in two stripes, the
+second at the leaf's head.  The positions form only (what a latent leaf is
+kept in); the reference writes the same rows by their indices.
 """
 
 from __future__ import annotations
@@ -112,10 +119,12 @@ def stripe_rows(chunk: int, dtype) -> int:
   return -(-chunk // tile) * tile
 
 
-def kv_write_fits(cache_shape, dtype, chunk: int) -> bool:
+def kv_write_fits(cache_shape, dtype, chunk: int,
+                  ring: bool = False) -> bool:
   """Whether the kernel can tile a leaf of ``dtype`` for ``chunk``-wide
   windows, in the form its rank asks for: a 32-bit or 16-bit float leaf,
-  a window of at most 128 positions, blocks within the VMEM budget, and
+  a window of at most 128 positions, blocks within the VMEM budget, a
+  ring only in positions and in whole 128-position tiles, and
 
   * rows ``[B, Lc, W]``: ``W`` whole lane tiles and at least one whole
     stripe (:func:`stripe_rows`);
@@ -126,6 +135,8 @@ def kv_write_fits(cache_shape, dtype, chunk: int) -> bool:
   if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
     return False
   if not 1 <= chunk <= LANES:
+    return False
+  if ring and (len(cache_shape) != 4 or cache_shape[1] % LANES):
     return False
   if len(cache_shape) == 3:
     _, Lc, W = cache_shape
@@ -141,14 +152,14 @@ def kv_write_fits(cache_shape, dtype, chunk: int) -> bool:
 
 
 def resolve_kv_write_impl(cache_shape, dtype, chunk: int,
-                          sharded: bool = False) -> str:
+                          sharded: bool = False, ring: bool = False) -> str:
   """The dispatch rule: the backend's lowering (``pallas`` on a TPU,
   ``reference`` elsewhere), and ``reference`` whenever the leaf lives on
   a multi-device mesh (``sharded``: the SPMD partitioner cannot split a
   Mosaic call) or the shapes do not fit (:func:`kv_write_fits`)."""
   impl = _backend_impl()
   if impl != "reference" and (
-      sharded or not kv_write_fits(cache_shape, dtype, chunk)):
+      sharded or not kv_write_fits(cache_shape, dtype, chunk, ring)):
     return "reference"
   return impl
 
@@ -156,14 +167,21 @@ def resolve_kv_write_impl(cache_shape, dtype, chunk: int,
 # -------------------------------------------------------------- reference --
 
 
-def kv_write_reference(cached_k, cached_v, k, v, cursors):
+def kv_write_reference(cached_k, cached_v, k, v, cursors,
+                       ring: bool = False):
   """One ``dynamic_update_slice`` per slot at its own cursor, a leaf of
   either order (the chunk takes the leaf's own trailing dimensions: heads
-  folded into rows or apart)."""
+  folded into rows or apart).  A ring takes the chunk's rows at their
+  positions modulo its length."""
   def write(cache, new):
     if cache is None:
       return None
     new = new.astype(cache.dtype).reshape(new.shape[:2] + cache.shape[2:])
+    if ring:
+      at = jnp.mod(cursors[:, None] + jnp.arange(new.shape[1])[None],
+                   cache.shape[1])
+      return jax.vmap(lambda row, chunk, idx: row.at[idx].set(chunk))(
+          cache, new, at)
     start = (0,) * (cache.ndim - 2)
     return jax.vmap(
         lambda row, chunk, cur: jax.lax.dynamic_update_slice(
@@ -260,7 +278,18 @@ def _kv_write_rows(caches, news, cursors, num_valid, interpret: bool):
   return written[0], (written[1] if n == 2 else None)
 
 
-def _kv_write_kernel(cur_ref, *refs, chunk: int):
+def _ring_tile(cur, j, chunk: int, ring: int):
+  """The 128-position tile of a ring that holds the window's first (``j
+  == 0``) or last (``j == 1``) row; the last row of a window that crosses
+  the ring's end lies at the leaf's head (``ring`` is a whole number of
+  tiles, so such a window wraps between tiles, or, in a ring of one tile,
+  inside it)."""
+  last = cur + chunk - 1
+  return jnp.where(j == 0, cur, jnp.where(last >= ring, last - ring,
+                                          last)) // LANES
+
+
+def _kv_write_kernel(cur_ref, *refs, chunk: int, ring: int = 0):
   """One (slot, tile) grid step of the positions form: lay the slot's
   chunk over the lanes ``[cursor, cursor + chunk)`` of this 128-position
   tile, for K and V (``refs``: the leaves' chunks, their tiles in, their
@@ -282,10 +311,18 @@ def _kv_write_kernel(cur_ref, *refs, chunk: int):
   cur = cur_ref[b]
   # Where the window starts relative to this tile: negative in the second
   # tile of a straddling window.
-  off = cur - _window_block(cur, j, chunk, LANES) * LANES
-  shift = jnp.where(off < 0, off + LANES, off)
   lane = jax.lax.broadcasted_iota(jnp.int32, stage_ref.shape, 2)
-  window = (lane >= off) & (lane < off + chunk)
+  if ring:
+    # A lane is in the window iff its row lies less than a chunk ahead of
+    # the cursor, going round the ring; the chunk's row i belongs at lane
+    # (cursor + i) mod 128 in whichever tile holds it.
+    ahead = _ring_tile(cur, j, chunk, ring) * LANES + lane - cur
+    window = jnp.where(ahead < 0, ahead + ring, ahead) < chunk
+    shift = cur % LANES
+  else:
+    off = cur - _window_block(cur, j, chunk, LANES) * LANES
+    shift = jnp.where(off < 0, off + LANES, off)
+    window = (lane >= off) & (lane < off + chunk)
   # Mosaic rotates 32-bit lanes only: a 16-bit leaf goes through as the
   # uint32 words its sublane pairs already are in a register.
   packed = k_in_ref.dtype.itemsize < 4
@@ -297,9 +334,9 @@ def _kv_write_kernel(cur_ref, *refs, chunk: int):
     out_ref[0] = pltpu.bitcast(merged, out_ref.dtype) if packed else merged
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "ring"))
 def kv_write_pallas(cached_k, cached_v, k, v, cursors, num_valid=None,
-                    interpret: bool = False):
+                    interpret: bool = False, ring: bool = False):
   """The in-place window write, in the form the leaf's rank asks for
   (module docstring); ``interpret`` runs the kernel in Pallas
   interpreter mode (any backend).  ``num_valid`` (rows form: the slots
@@ -317,7 +354,8 @@ def kv_write_pallas(cached_k, cached_v, k, v, cursors, num_valid=None,
   # ``dynamic_update_slice`` clamps a start that would run the window
   # off the leaf; the contract keeps cursors inside (kv_cache.py), and
   # the clamp keeps the two lowerings equal outside it too.
-  cursors = jnp.clip(cursors.astype(jnp.int32), 0, Lc - C)
+  cursors = (jnp.mod(cursors.astype(jnp.int32), Lc) if ring
+             else jnp.clip(cursors.astype(jnp.int32), 0, Lc - C))
   if cached_k.ndim == 3:
     news = [x.astype(dtype).reshape(x.shape[:2] + cached_k.shape[2:])
             for x in news]
@@ -329,6 +367,8 @@ def kv_write_pallas(cached_k, cached_v, k, v, cursors, num_valid=None,
   n_tiles = 1 if C == 1 else 2
 
   def tile_idx(b, j, cur):
+    if ring:
+      return (b, 0, 0, _ring_tile(cur[b], j, C, Lc))
     return (b, 0, 0, _window_block(cur[b], j, C, LANES))
 
   chunk_spec = pl.BlockSpec((1, H, hd, C), lambda b, j, cur: (b, 0, 0, 0))
@@ -349,7 +389,7 @@ def kv_write_pallas(cached_k, cached_v, k, v, cursors, num_valid=None,
         dimension_semantics=("arbitrary", "arbitrary"))
   leaf = jax.ShapeDtypeStruct((B, H, hd, Lc), dtype)
   written = pl.pallas_call(
-      functools.partial(_kv_write_kernel, chunk=C),
+      functools.partial(_kv_write_kernel, chunk=C, ring=Lc if ring else 0),
       grid_spec=grid_spec,
       out_shape=[leaf] * n,
       # Operands count the scalar-prefetch cursors: the leaves follow
@@ -368,12 +408,13 @@ def kv_write_pallas(cached_k, cached_v, k, v, cursors, num_valid=None,
 
 
 def kv_write(cached_k, cached_v, k, v, cursors, num_valid=None,
-             impl: Optional[str] = None):
+             impl: Optional[str] = None, ring: bool = False):
   """Write each slot's K/V chunk at its cursor (module docstring);
   returns ``(new_cached_k, new_cached_v)``, the second ``None`` for a
   one-leaf layer (``cached_v = v = None``).  ``num_valid`` (``None`` =
   every slot is fed) lets the rows form skip the slots the step does not
-  feed.  ``impl=None`` applies the
+  feed.  ``ring`` writes a one-leaf cache as a ring (module docstring).
+  ``impl=None`` applies the
   dispatch rule to the shapes at hand, and takes the leaf as spread
   over chips whenever a multi-device mesh has been built (the legacy
   ``generate()`` decode); the serving engine resolves the impl from its
@@ -383,10 +424,10 @@ def kv_write(cached_k, cached_v, k, v, cursors, num_valid=None,
     mesh = cluster.built_mesh if cluster is not None else None
     impl = resolve_kv_write_impl(
         cached_k.shape, cached_k.dtype, k.shape[1],
-        sharded=mesh is not None and mesh.size > 1)
+        sharded=mesh is not None and mesh.size > 1, ring=ring)
   if impl not in IMPLS:
     raise ValueError(f"impl must be one of {IMPLS} or None; got {impl!r}")
   if impl == "reference":
-    return kv_write_reference(cached_k, cached_v, k, v, cursors)
+    return kv_write_reference(cached_k, cached_v, k, v, cursors, ring=ring)
   return kv_write_pallas(cached_k, cached_v, k, v, cursors, num_valid,
-                         interpret=impl == "interpret")
+                         interpret=impl == "interpret", ring=ring)

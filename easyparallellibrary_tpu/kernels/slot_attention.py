@@ -620,6 +620,441 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
   return out.transpose(0, 3, 1, 2, 4).reshape(B, C, H, vd)
 
 
+# ------------------------------------- one leaf, selected or behind a window --
+#
+# Two more forms of the ONE-LEAF attend (models/dots3_note.py), each under
+# a kernel name of its own so that a device trace tells their time apart:
+#
+# * ``slot_attn_sel``: a query sees row ``s <= t`` iff ``s`` is in its
+#   SELECTION.  The selection reaches the kernel as the index scores
+#   ``[B, C, Lc]`` float32 (kernels/dsa_index.py) and one threshold a
+#   query: ``s`` is selected iff ``score(t, s) >= threshold(t)``, which is
+#   exact top-k wherever no two of a query's scores are equal.  Every block
+#   under the tile's bound is fetched and scored and the unselected rows
+#   are masked: a gathered or block-skipping form is not built.
+# * ``slot_attn_win``: the leaf is a RING of ``R`` rows (``R`` a multiple
+#   of 128, at least ``window + chunk - 1``): position ``p`` lives at row
+#   ``p mod R``, so row ``j`` holds the newest position ``p < bound`` with
+#   ``p mod R = j``, and a query at ``t`` sees it iff ``t - window < p <=
+#   t``.  Blocks wholly outside every window of the tile are neither
+#   fetched nor computed.
+#
+# Both run on a grid over the step's live (slot, position TILE) pairs
+# (:func:`live_tiles`; their number is a value, the program compiles once)
+# and the leaf's blocks: a tile is ``tile_positions`` chunk positions of
+# every head, rows ordered (position, head) as the projection leaves them,
+# so ``q`` and the output need no transpose.  The leaf is re-read a live
+# tile; the online softmax, the masks and the arithmetic are
+# :func:`_slot_attn_kernel`'s.  A slot that feeds ONE position (a decode)
+# would pay a whole tile for it, so a chunk of tiles is served by two
+# launches under the one name (:func:`split_decodes`): the slots that feed
+# more than one position on tiles of positions, those that feed one on
+# tiles of that one position.  Both write into ONE output buffer that
+# starts as zeros and is aliased through them, each under every slot's true
+# bound: no select, slice or concatenation of ``[slots, chunk]``-sized
+# tensors follows a launch, and the tile a launch with nothing to do still
+# visits holds what the other launch writes there.
+
+SLOT_ATTN_SEL = "slot_attn_sel"
+SLOT_ATTN_WIN = "slot_attn_win"
+
+# Query rows of one tile the kernels aim at (positions x heads).
+_TILE_ROWS = 1024
+# VMEM the two kernels may ask for, and the limit they hand Mosaic (v5e's
+# scoped default of 16 MiB leaves no room for the score temporaries).
+_TILE_VMEM_BUDGET = 24 * 1024 * 1024
+_TILE_VMEM_LIMIT = 48 * 1024 * 1024
+
+
+def tile_positions(chunk: int, num_heads: int) -> int:
+  """Chunk positions of one query tile: 8 (a float32 sublane tile: the
+  selection's scores are read a tile of positions at a time) while the
+  tile stays within :data:`_TILE_ROWS` rows and divides the chunk, else
+  the whole chunk."""
+  if chunk % 8 == 0 and 8 * num_heads <= _TILE_ROWS:
+    return 8
+  return chunk
+
+
+def live_tiles(num_valid, chunk: int, tile: int):
+  """``(slot, tile index, count)`` for a grid over the live (slot,
+  position tile) pairs in slot order: slot ``b`` has ``ceil(num_valid[b]
+  / tile)`` of them.  ``count`` (int32 ``[1]``, at least 1) is a value.
+  The twin of :func:`live_order`, one level down."""
+  B = num_valid.shape[0]
+  per = (num_valid.astype(jnp.int32) + tile - 1) // tile
+  ends = jnp.cumsum(per, dtype=jnp.int32)
+  item = jnp.arange(B * (chunk // tile), dtype=jnp.int32)
+  slot = jnp.minimum(
+      jnp.sum(ends[None, :] <= item[:, None], axis=1, dtype=jnp.int32), B - 1)
+  first = jnp.take(ends - per, slot)
+  return (slot, jnp.clip(item - first, 0, chunk // tile - 1),
+          jnp.maximum(ends[-1:], 1))
+
+
+def split_decodes(num_valid, chunk: int):
+  """``(num_valid of the slots that feed more than one position, 0 or 1
+  for those that feed exactly one)`` where a chunk is wide enough to be
+  tiled (a multiple of 8 above 8), ``None`` where one launch serves
+  all."""
+  if chunk % 8 or chunk == 8 or num_valid is None:
+    return None
+  nv = num_valid.astype(jnp.int32)
+  one = nv == 1
+  return jnp.where(one, 0, nv), one.astype(jnp.int32)
+
+
+def _tile_block(L: int, W: int, dtype, rows: int, vd: int,
+                ring: bool) -> int:
+  """Leaf positions per block for a query tile of ``rows`` rows: the
+  widest of :data:`_BLOCKS` (a ring: the widest 128-multiple that divides
+  it, the ring itself first) that keeps the kernel within
+  :data:`_TILE_VMEM_BUDGET`; 0 if none does."""
+  size = jnp.dtype(dtype).itemsize
+  if ring:
+    blocks = [b for b in range(L, 0, -LANES) if L % b == 0 and b <= 1024]
+  else:
+    blocks = [b for b in _BLOCKS if b <= L]
+  for block in blocks:
+    vmem = (2 * W * block * size                 # the leaf's block
+            + 2 * rows * (W + vd) * size         # q, out
+            + rows * (vd + 2 * LANES) * 4        # acc, max, sum
+            + 3 * rows * block * 4)              # scores, probabilities
+    if vmem <= _TILE_VMEM_BUDGET:
+      return block
+  return 0
+
+
+def tile_attn_fits(cache_shape, dtype, chunk: int, num_heads: int,
+                   v_width: int, ring: bool = False) -> bool:
+  """Whether the selected (``ring`` false) or the windowed form can tile
+  a one-leaf cache ``[B, L, 1, W]`` of ``dtype``: a 16- or 32-bit float,
+  a width of whole sublane tiles, a chunk of whole tiles of positions,
+  a leaf of at least 128 rows (a ring: whole 128-row tiles), heads in
+  whole sublane tiles, and a block within the budget."""
+  if len(cache_shape) != 4 or cache_shape[2] != 1:
+    return False
+  _, L, _, W = cache_shape
+  dtype = jnp.dtype(dtype)
+  if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
+    return False
+  tile = sublane_tile(dtype)
+  if W % tile or v_width % tile or num_heads % tile:
+    return False
+  if L < LANES or (ring and L % LANES) or not 1 <= chunk <= LANES:
+    return False
+  tp = tile_positions(chunk, num_heads)
+  return _tile_block(L, W, dtype, tp * num_heads, v_width, ring) > 0
+
+
+def resolve_tile_attn_impl(cache_shape, dtype, chunk: int, num_heads: int,
+                           v_width: int, ring: bool = False,
+                           sharded: bool = False) -> str:
+  """The dispatch rule of the two forms, as
+  :func:`resolve_slot_attn_impl`."""
+  impl = _backend_impl()
+  if impl != "reference" and (
+      sharded or not tile_attn_fits(cache_shape, dtype, chunk, num_heads,
+                                    v_width, ring)):
+    return "reference"
+  return impl
+
+
+def ring_positions(bound, length: int):
+  """The position each row of a ring of ``length`` rows holds when the
+  newest position written is ``bound - 1``: the largest ``p < bound``
+  with ``p mod length = row`` (negative: the row was never written).
+  ``bound`` int32 ``[...]`` -> ``[..., length]``."""
+  newest = bound[..., None] - 1
+  row = jnp.arange(length, dtype=jnp.int32)
+  return newest - jnp.mod(newest - row, length)
+
+
+def slot_attention_selected_reference(q, latent, scores, threshold, cursors,
+                                      v_width: int, scale: float):
+  """Every query against every row of its slot's leaf, masked to the
+  causal prefix AND the query's selection (``scores >= threshold``)."""
+  B, C, H, W = q.shape
+  Lc = latent.shape[1]
+  dtype = q.dtype
+  keys = latent[:, :, 0]
+  logits = jnp.einsum("bqhd,bkd->bhqk", q, keys) * jnp.asarray(scale, dtype)
+  pos = cursors[:, None, None] + jnp.arange(C)[None, :, None]
+  seen = (jnp.arange(Lc)[None, None, :] <= pos) & (
+      scores >= threshold[..., None])
+  logits = jnp.where(seen[:, None], logits, jnp.asarray(-1e9, logits.dtype))
+  probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+  return jnp.einsum("bhqk,bkd->bqhd", probs.astype(dtype),
+                    keys[..., :v_width])
+
+
+def slot_attention_window_reference(q, ring, cursors, num_valid, window: int,
+                                    v_width: int, scale: float):
+  """Every query against every row of its slot's ring, masked to the
+  positions ``t - window < p <= t`` the rows hold."""
+  B, C, H, W = q.shape
+  R = ring.shape[1]
+  dtype = q.dtype
+  nv = (jnp.full((B,), C, jnp.int32) if num_valid is None
+        else num_valid.astype(jnp.int32))
+  keys = ring[:, :, 0]
+  held = ring_positions(cursors.astype(jnp.int32) + nv, R)[:, None, :]
+  t = cursors[:, None, None] + jnp.arange(C)[None, :, None]
+  seen = (held >= 0) & (held <= t) & (held > t - window)
+  logits = jnp.einsum("bqhd,bkd->bhqk", q, keys) * jnp.asarray(scale, dtype)
+  logits = jnp.where(seen[:, None], logits, jnp.asarray(-1e9, logits.dtype))
+  probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+  # A row no query of the slot sees may hold anything (a previous
+  # occupant's rows, a dead position's): keep it out of the product.
+  live = jnp.any(seen, axis=1)[..., None]
+  values = jnp.where(live, keys[..., :v_width], jnp.zeros((), dtype))
+  return jnp.einsum("bhqk,bkd->bqhd", probs.astype(dtype), values)
+
+
+def _tile_attn_kernel(slot_ref, tile_ref, count_ref, cur_ref, bound_ref,
+                      starts_ref, into_ref, pos_ref, q_ref, k_ref, *refs,
+                      block: int, num_blocks: int, scale: float,
+                      v_width: int, tp: int, heads: int,
+                      window: Optional[int], ring: int):
+  """One (live tile, leaf block) grid step of the selected (``window``
+  None) or the windowed form.  ``q_ref`` ``[tp, heads, W]``, the tile's
+  rows of the flat batch, taken as ``tp x heads`` rows (position, head);
+  ``k_ref`` ``[1, 1, W, block]``, position-minor; ``pos_ref`` each query
+  row's position in its tile; ``into_ref`` the output as it was handed in
+  (aliased, untouched).  ``refs``: the selected form's score block ``[1,
+  tp, block]`` and thresholds ``[1, tp, 1]``, then the output block and the
+  three scratches."""
+  del count_ref, starts_ref, into_ref
+  o_ref, m_ref, l_ref, acc_ref = refs[-4:]
+  i = pl.program_id(0)
+  kb = pl.program_id(1)
+  b = slot_ref[i]
+  first = tile_ref[i] * tp                  # the tile's first chunk position
+  cur = cur_ref[b]
+  bound = bound_ref[b]
+  # Positions of the tile's first and last live query.
+  t_lo = cur + first
+  t_hi = jnp.minimum(bound, t_lo + tp) - 1
+
+  @pl.when(kb == 0)
+  def _init():
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+  col0 = kb * block
+  if window is None:
+    live = col0 <= t_hi
+  else:
+    # Row j of the ring holds base + j up to the newest row, base - R + j
+    # beyond it; the block is live if one of its rows holds a position in
+    # [t_lo - window + 1, t_hi].
+    newest = jnp.maximum(bound - 1, 0)
+    base = (newest // ring) * ring
+    r0 = newest - base
+    lo = jnp.maximum(t_lo - window + 1, 0)
+    last = col0 + block - 1
+    live = (bound > 0) & (
+        ((col0 <= r0) & (base + jnp.minimum(last, r0) >= lo))
+        | ((last > r0) & (base - ring + last >= lo)))
+
+  @pl.when(live)
+  def _fold():
+    q, k = q_ref[...].reshape(tp * heads, -1), k_ref[0, 0]
+    precision = None if q.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+    s = jax.lax.dot_general(
+        q, k, (((1,), (0,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32) * scale       # [rows, block]
+    t = t_lo + pos_ref[...]                                # [rows, 1]
+    col = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    vcol = col0 + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    if window is None:
+      sc_ref, thr_ref = refs[0], refs[1]
+      picked = jnp.where(sc_ref[0] >= thr_ref[0], 0.0, NEG_INF)  # [tp, block]
+      s = s + jnp.concatenate(
+          [jnp.broadcast_to(picked[p:p + 1], (heads, block))
+           for p in range(tp)], axis=0)
+      s = jnp.where((col <= t) & (col < bound), s, NEG_INF)
+      dead = vcol >= bound
+    else:
+      held = jnp.where(col <= r0, base + col, base - ring + col)
+      s = jnp.where((held >= 0) & (held <= t) & (held > t - window)
+                    & (t < bound), s, NEG_INF)
+      vheld = jnp.where(vcol <= r0, base + vcol, base - ring + vcol)
+      dead = vheld < lo
+    # Nothing of a row no query sees may reach the sums through V either
+    # (``0 * NaN = NaN``).
+    v = k[:v_width]
+    v = jnp.where(dead, jnp.zeros_like(v), v)
+    p, corr = _online_softmax_fold(s, m_ref, l_ref, ...)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (1,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32)                # [rows, vd]
+
+  @pl.when(kb == num_blocks - 1)
+  def _emit():
+    l_col = jnp.maximum(l_ref[...][:, :1], 1e-30)
+    real = t_lo + pos_ref[...] < bound
+    o_ref[0] = jnp.where(real, acc_ref[...] / l_col, 0.0).astype(o_ref.dtype)
+
+
+def _tile_attention(q, leaf, cursors, num_valid, scores, threshold,
+                    window: Optional[int], interpret: bool,
+                    block: Optional[int], v_width: int, scale: float,
+                    starts=None, chunk: Optional[int] = None):
+  """The two forms' shared call: ``window`` None is the selected form
+  (``scores``, ``threshold`` given), else the leaf is a ring.  ``q`` is
+  ``[B, C, H, W]``, or, with ``starts`` and ``chunk``, the step's
+  token-flat batch ``[T, H, W]`` in which slot ``b``'s live positions are
+  the rows from ``starts[b]`` on (models/gpt.py:SlotRows): a tile's query
+  rows are read where they lie, no copy of them into ``[slots, chunk]``
+  order.  Decoding slots take a launch of their own on their one position
+  (:func:`split_decodes`)."""
+  B = cursors.shape[0]
+  H, W = q.shape[-2:]
+  if starts is None:
+    chunk = q.shape[1]
+    q = q.reshape(B * chunk, H, W)
+    starts = jnp.arange(B, dtype=jnp.int32) * chunk
+  else:
+    # A slot's last tile may start within a tile of the batch's end.
+    q = jnp.pad(q, ((0, tile_positions(chunk, H)), (0, 0), (0, 0)))
+  L = leaf.shape[1]
+  nv = (jnp.full((B,), chunk, jnp.int32) if num_valid is None
+        else jnp.clip(num_valid.astype(jnp.int32), 0, chunk))
+  if window is not None:
+    cur = jnp.maximum(cursors.astype(jnp.int32), 0)
+  else:
+    cur = jnp.clip(cursors.astype(jnp.int32), 0, L - chunk)
+  bound = jnp.where(nv > 0, cur + nv, 0)
+  launch = functools.partial(
+      _tile_launch, q.astype(leaf.dtype), starts.astype(jnp.int32), leaf,
+      cur, bound, window=window, interpret=interpret, block=block,
+      v_width=v_width, scale=scale)
+  # Every launch writes the tiles it visits into ONE buffer that starts
+  # as zeros (aliased in and out): what no tile covers stays zeros, and
+  # the decoding slots' launch lands beside the other's with no merge.
+  # Both work under every slot's TRUE bound, so the one tile a launch
+  # with nothing to do still visits (a grid has at least one step) writes
+  # what the other launch writes there.
+  out = jnp.zeros((B, chunk * H, v_width), leaf.dtype)
+  split = split_decodes(num_valid, chunk)
+  if split is None:
+    out = launch(out, chunk, nv, scores, threshold)
+  else:
+    many, one = split
+    first = lambda x: None if x is None else x[:, :1]
+    out = launch(out, chunk, many, scores, threshold)
+    out = launch(out, 1, one, first(scores), first(threshold))
+  return out.reshape(B, chunk, H, v_width)
+
+
+def _tile_launch(q, starts, leaf, cur, bound, into, C: int, feeds, scores,
+                 threshold, *, window: Optional[int], interpret: bool,
+                 block: Optional[int], v_width: int, scale: float):
+  """One launch over the (slot, tile of ``C``'s positions) pairs that
+  cover the first ``feeds[b]`` positions of each slot (int32 ``[B]``, at
+  most ``C``); ``q`` ``[T, H, W]``, slot ``b``'s position ``i`` at row
+  ``starts[b] + i``, ``cur`` and ``bound`` each slot's cursor and true
+  bound.  ``into`` ``[B, chunk x H, v_width]`` is the output, handed in:
+  the launch writes the tiles it visits (their positions at or beyond the
+  bound as zeros) and leaves every other row as it was."""
+  H, W = q.shape[1:]
+  L = leaf.shape[1]
+  dtype = leaf.dtype
+  ring = window is not None
+  tp = tile_positions(C, H)
+  rows = tp * H
+  if block is None:
+    block = _tile_block(L, W, dtype, rows, v_width, ring)
+  nb = pl.cdiv(L, block)
+  slot, tile, count = live_tiles(feeds, C, tp)
+  pos = (jnp.arange(rows, dtype=jnp.int32) // H)[:, None]
+
+  def leaf_idx(i, kb, slot, tile, count, cur, bound, starts):
+    # A step beyond the tile's last live block (a dead one: the kernel
+    # decides, this only follows) stays on a block the pipeline holds, so
+    # it issues no DMA of its own.
+    b = slot[i]
+    if ring:
+      held = jnp.maximum(bound[b] - 1, 0) % L // block
+      return b, 0, 0, jnp.where(bound[b] > 0, kb, held)
+    t_hi = jnp.minimum(bound[b], cur[b] + (tile[i] + 1) * tp) - 1
+    return b, 0, 0, jnp.minimum(kb, jnp.maximum(t_hi, 0) // block)
+
+  # The tile's query rows, read where they lie in the flat batch: an
+  # offset in rows, not in blocks (every dimension an element offset).
+  q_spec = pl.BlockSpec(
+      (pl.Element(tp), pl.Element(H), pl.Element(W)),
+      lambda i, kb, slot, tile, count, cur, bound, starts: (
+          starts[slot[i]] + tile[i] * tp, 0, 0))
+  in_specs = [pl.BlockSpec(memory_space=pl.ANY),
+              pl.BlockSpec((rows, 1), lambda i, kb, *_: (0, 0)),
+              q_spec, pl.BlockSpec((1, 1, W, block), leaf_idx)]
+  operands = [into, pos, q, jnp.transpose(leaf, (0, 2, 3, 1))]
+  if not ring:
+    def score_idx(i, kb, slot, tile, *rest):
+      return slot[i], tile[i], leaf_idx(i, kb, slot, tile, *rest)[3]
+    in_specs += [pl.BlockSpec((1, tp, block), score_idx),
+                 pl.BlockSpec((1, tp, 1),
+                              lambda i, kb, slot, tile, *_: (slot[i], tile[i],
+                                                             0))]
+    operands += [scores.astype(jnp.float32),
+                 threshold.astype(jnp.float32)[..., None]]
+  kwargs = {}
+  if not interpret:
+    kwargs["compiler_params"] = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"),
+        vmem_limit_bytes=_TILE_VMEM_LIMIT)
+  out = pl.pallas_call(
+      functools.partial(
+          _tile_attn_kernel, block=block, num_blocks=nb, scale=float(scale),
+          v_width=v_width, tp=tp, heads=H, window=window, ring=L),
+      grid_spec=pltpu.PrefetchScalarGridSpec(
+          num_scalar_prefetch=6,
+          grid=(count[0], nb),
+          in_specs=in_specs,
+          out_specs=pl.BlockSpec(
+              (1, rows, v_width),
+              lambda i, kb, slot, tile, *_: (slot[i], tile[i], 0)),
+          scratch_shapes=[
+              pltpu.VMEM((rows, LANES), jnp.float32),      # running max
+              pltpu.VMEM((rows, LANES), jnp.float32),      # running sum
+              pltpu.VMEM((rows, v_width), jnp.float32),    # accumulator
+          ]),
+      out_shape=jax.ShapeDtypeStruct(into.shape, dtype),
+      # Operands count the six scalar-prefetch arrays: ``into`` follows.
+      input_output_aliases={6: 0},
+      interpret=interpret,
+      name=SLOT_ATTN_WIN if ring else SLOT_ATTN_SEL,
+      **kwargs,
+  )(slot, tile, count, cur, bound, starts, *operands)
+  return out
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "block",
+                                             "v_width", "scale", "chunk"))
+def slot_attention_selected_pallas(q, latent, scores, threshold, cursors,
+                                   num_valid=None, interpret: bool = False,
+                                   block: Optional[int] = None, starts=None,
+                                   chunk: Optional[int] = None, *,
+                                   v_width: int, scale: float):
+  return _tile_attention(q, latent, cursors, num_valid, scores, threshold,
+                         None, interpret, block, v_width, scale, starts,
+                         chunk)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "interpret", "block",
+                                             "v_width", "scale", "chunk"))
+def slot_attention_window_pallas(q, ring, cursors, num_valid=None,
+                                 interpret: bool = False,
+                                 block: Optional[int] = None, starts=None,
+                                 chunk: Optional[int] = None, *, window: int,
+                                 v_width: int, scale: float):
+  return _tile_attention(q, ring, cursors, num_valid, None, None, window,
+                         interpret, block, v_width, scale, starts, chunk)
+
+
 # --------------------------------------------------------------- dispatch --
 
 
@@ -652,3 +1087,51 @@ def slot_attention(q, cached_k, cached_v, cursors, num_valid=None,
   return slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid,
                                interpret=impl == "interpret",
                                v_width=v_width, scale=scale)
+
+
+def _check_impl(impl: str) -> None:
+  if impl not in IMPLS:
+    raise ValueError(f"impl must be one of {IMPLS}; got {impl!r}")
+
+
+def slot_attention_selected(q, latent, scores, threshold, cursors,
+                            num_valid=None, *, impl: str, v_width: int,
+                            scale: float, starts=None):
+  """Attend each slot's chunk over the SELECTED rows of its one-leaf
+  cache ``[B, Lc, 1, W]``: query ``i`` of slot ``b`` sees row ``s <=
+  cursors[b] + i`` iff ``scores[b, i, s] >= threshold[b, i]`` (the index
+  scores of kernels/dsa_index.py and the query's k-th largest).  The
+  values are the rows' leading ``v_width`` columns; ``out [B, C, H,
+  v_width]``.  ``impl`` is resolved by the caller
+  (:func:`resolve_tile_attn_impl`).  The kernel also takes ``q`` as the
+  step's flat batch ``[T, H, W]`` with ``starts`` (int32 ``[B]``: the
+  flat row of each slot's first position), which spares the copy into
+  ``[B, C]`` order; the reference takes ``[B, C, H, W]`` only."""
+  _check_impl(impl)
+  if impl == "reference":
+    return slot_attention_selected_reference(q, latent, scores, threshold,
+                                             cursors, v_width, scale)
+  return slot_attention_selected_pallas(
+      q, latent, scores, threshold, cursors, num_valid,
+      interpret=impl == "interpret", starts=starts,
+      chunk=None if starts is None else scores.shape[1], v_width=v_width,
+      scale=scale)
+
+
+def slot_attention_window(q, ring, cursors, num_valid=None, *, impl: str,
+                          window: int, v_width: int, scale: float,
+                          starts=None, chunk: Optional[int] = None):
+  """Attend each slot's chunk over a RING leaf ``[B, R, 1, W]`` (position
+  ``p`` at row ``p mod R``, written through ``kv_write(..., ring=True)``):
+  query ``i`` of slot ``b``, at ``t = cursors[b] + i``, sees the positions
+  ``t - window < p <= t``.  ``out [B, C, H, v_width]``.  ``starts`` and
+  ``chunk``: ``q`` as the flat batch, as :func:`slot_attention_selected`
+  takes it."""
+  _check_impl(impl)
+  if impl == "reference":
+    return slot_attention_window_reference(q, ring, cursors, num_valid,
+                                           window, v_width, scale)
+  return slot_attention_window_pallas(
+      q, ring, cursors, num_valid, interpret=impl == "interpret",
+      starts=starts, chunk=chunk, window=window, v_width=v_width,
+      scale=scale)

@@ -4,6 +4,9 @@ from easyparallellibrary_tpu.models.gpt import (
 from easyparallellibrary_tpu.models.jamba import Jamba, JambaConfig
 from easyparallellibrary_tpu.models.glm_moe import GlmMoe, GlmMoeConfig
 from easyparallellibrary_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
+from easyparallellibrary_tpu.models.dots3_note import (
+    Dots3Note, Dots3NoteConfig,
+)
 from easyparallellibrary_tpu.models.bert import (
     Bert, BertConfig, bert_large_config,
 )
@@ -16,6 +19,7 @@ __all__ = [
     "Jamba", "JambaConfig",
     "GlmMoe", "GlmMoeConfig",
     "Lfm2Moe", "Lfm2MoeConfig",
+    "Dots3Note", "Dots3NoteConfig",
     "Bert", "BertConfig", "bert_large_config",
     "ResNet", "ResNetConfig", "resnet18_config", "resnet50_config",
 ]

@@ -106,6 +106,61 @@ class GlmMoeConfig:
     return (LATENT,) * self.num_layers
 
 
+@dataclasses.dataclass(frozen=True)
+class IndexerDims:
+  """The indexer of a latent attention that SELECTS the rows it reads
+  (DeepSeek-V3.2's, models/dots3_note.py): ``num_heads`` index heads of
+  ``head_dim``, the leading ``rope_dim`` of each rotated, one index key a
+  position, the ``top_k`` best-scoring rows attended."""
+  num_heads: int
+  head_dim: int
+  top_k: int
+  rope_dim: int
+  layer_norm_eps: float = 1e-6
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentDims:
+  """The sizes and options of ONE multi-head latent attention: what
+  :class:`LatentAttention` is built from.  GLM-MoE's layers all share one
+  (:func:`glm_latent_dims`); a model whose layers differ
+  (models/dots3_note.py) hands each layer its own.  ``q_rescale`` /
+  ``kv_rescale`` multiply the two normed latents; ``gate`` adds a sigmoid
+  gate, one value a head, on the heads' outputs; ``window`` limits a query
+  at ``t`` to the positions ``t - window < s <= t`` (slot mode then keeps
+  the latent leaf as a ring); ``indexer`` limits it to the rows an indexer
+  selects."""
+  num_heads: int
+  q_lora_rank: int
+  kv_lora_rank: int
+  qk_nope_head_dim: int
+  qk_rope_head_dim: int
+  v_head_dim: int
+  rope_theta: float
+  q_rescale: float = 1.0
+  kv_rescale: float = 1.0
+  gate: bool = False
+  window: Optional[int] = None
+  indexer: Optional[IndexerDims] = None
+
+  @property
+  def scale(self) -> float:
+    return float(self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+
+  @property
+  def latent_dim(self) -> int:
+    return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+def glm_latent_dims(cfg) -> LatentDims:
+  """The one latent attention of a :class:`GlmMoeConfig`."""
+  return LatentDims(
+      num_heads=cfg.num_heads, q_lora_rank=cfg.q_lora_rank,
+      kv_lora_rank=cfg.kv_lora_rank, qk_nope_head_dim=cfg.qk_nope_head_dim,
+      qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
+      rope_theta=cfg.rope_theta)
+
+
 def rotary(x, positions, theta: float):
   """Rotate-half rotary embedding over ALL of ``x``'s last axis: ``x``
   ``[B, S, H, d]``, ``positions`` int ``[B, S]``; pair ``i`` is ``(x[i],
@@ -121,53 +176,141 @@ def rotary(x, positions, theta: float):
                          -1).astype(x.dtype)
 
 
+class LayerNorm(nn.Module):
+  """``(x - mean) * rsqrt(var + eps) * g + b`` in float32, gain and bias
+  float32 parameters (the indexer's key norm)."""
+  eps: float
+  dtype: Any
+
+  @nn.compact
+  def __call__(self, x):
+    g = self.param("scale", _boxed(nn.initializers.ones_init(), 1),
+                   (x.shape[-1],), jnp.float32)
+    b = self.param("bias", _boxed(nn.initializers.zeros_init(), 1),
+                   (x.shape[-1],), jnp.float32)
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, -1, keepdims=True)
+    y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True)
+                          + self.eps) * g + b
+    return y.astype(self.dtype)
+
+
+def _rotate_leading(x, positions, theta: float, width: int):
+  """Rotary on the leading ``width`` of ``x`` ``[B, S, H, d]``'s last
+  axis, the rest as it is."""
+  return jnp.concatenate(
+      [rotary(x[..., :width], positions, theta), x[..., width:]], -1)
+
+
 class LatentAttention(nn.Module):
-  cfg: GlmMoeConfig
+  """Multi-head latent attention (module docstring), shared by every model
+  that has one: ``cfg`` gives ``d_model``, ``rms_norm_eps`` and the dtypes,
+  ``dims`` the attention's own sizes and options (``None``: ``cfg`` is a
+  :class:`GlmMoeConfig` and says them itself)."""
+  cfg: Any
   decode: bool = False
   kv_write_impl: Optional[str] = None
   slot_attn_impl: Optional[str] = None
+  dims: Optional[LatentDims] = None
+  dsa_index_impl: Optional[str] = None
 
   @nn.compact
   def __call__(self, h, positions, slot_cursors=None, num_valid=None,
                rows=None):
     cfg = self.cfg
+    dims = self.dims if self.dims is not None else glm_latent_dims(cfg)
     B, S, _ = h.shape
-    H, r = cfg.num_heads, cfg.kv_lora_rank
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
-    c_q = norm("q_norm")(_dense(cfg, cfg.q_lora_rank, "q_a")(h))
+    H, r = dims.num_heads, dims.kv_lora_rank
+    dn, dr, dv = (dims.qk_nope_head_dim, dims.qk_rope_head_dim,
+                  dims.v_head_dim)
+    norm = lambda name, rescale=1.0: RMSNorm(
+        cfg.rms_norm_eps, cfg.dtype, rescale, name=name)
+    c_q = norm("q_norm", dims.q_rescale)(
+        _dense(cfg, dims.q_lora_rank, "q_a")(h))
     q = _dense(cfg, H * (dn + dr), "q_b")(c_q).reshape(B, S, H, dn + dr)
     q_nope = q[..., :dn]
-    q_rope = rotary(q[..., dn:], positions, cfg.rope_theta)
+    q_rope = rotary(q[..., dn:], positions, dims.rope_theta)
     kv = _dense(cfg, r + dr, "kv_a")(h)
-    c = norm("kv_norm")(kv[..., :r])
-    k_r = rotary(kv[..., None, r:], positions, cfg.rope_theta)  # [B,S,1,dr]
+    c = norm("kv_norm", dims.kv_rescale)(kv[..., :r])
+    k_r = rotary(kv[..., None, r:], positions, dims.rope_theta)  # [B,S,1,dr]
     w_kvb = jnp.asarray(self.param(
         "kv_b", _boxed(nn.initializers.normal(stddev=0.02), 2),
         (r, H * (dn + dv)), cfg.param_dtype), cfg.dtype).reshape(
             r, H, dn + dv)
-    scale = float(cfg.qk_head_dim) ** -0.5
+    scale = dims.scale
+    ix = dims.indexer
+    if ix is not None:
+      # The indexer: index queries from the query latent, ONE index key a
+      # position from the layer's input, a weight an index head.
+      q_ix = _rotate_leading(
+          _dense(cfg, ix.num_heads * ix.head_dim, "index_q")(c_q).reshape(
+              B, S, ix.num_heads, ix.head_dim),
+          positions, dims.rope_theta, ix.rope_dim)
+      k_ix = _rotate_leading(
+          LayerNorm(ix.layer_norm_eps, cfg.dtype, name="index_k_norm")(
+              _dense(cfg, ix.head_dim, "index_k")(h))[:, :, None],
+          positions, dims.rope_theta, ix.rope_dim)[:, :, 0]
+      w_ix = _dense(cfg, ix.num_heads, "index_w")(h).astype(jnp.float32)
     if self.decode:
       from easyparallellibrary_tpu.kernels.kv_write import kv_write
       from easyparallellibrary_tpu.kernels.slot_attention import (
-          slot_attention)
+          slot_attention, slot_attention_selected, slot_attention_window)
       # ``h`` is the step's token-flat batch [T, 1, D]
       # (models/gpt.py:SlotRows); the window write and the attend take
       # their operands as [slots, C, ...], everything around them stays
       # flat.
       latent = self.variable("cache", "cached_latent", _missing_slot_cache)
       new = jnp.concatenate([c[:, :, None], k_r], -1)        # [T,1,1,r+dr]
+      # Behind a window the leaf is a ring: position p at row p mod its
+      # length.
       latent.value, _ = kv_write(latent.value, None,
                                  rows.to_slots(new[:, 0]), None,
-                                 slot_cursors, impl=self.kv_write_impl)
+                                 slot_cursors, impl=self.kv_write_impl,
+                                 ring=dims.window is not None)
       q_abs = jnp.concatenate(
           [jnp.einsum("bshd,rhd->bshr", q_nope, w_kvb[..., :dn]), q_rope],
-          -1)                                                # [T,1,H,r+dr]
-      o_lat = slot_attention(rows.to_slots(q_abs[:, 0]), latent.value, None,
-                             slot_cursors, num_valid,
-                             impl=self.slot_attn_impl, v_width=r,
-                             scale=scale).astype(cfg.dtype)
-      out = jnp.einsum("bshr,rhd->bshd", rows.to_flat(o_lat)[:, None],
+          -1)[:, 0]                                          # [T,H,r+dr]
+      # The selected and the windowed kernels read a tile's query rows
+      # where they lie in the flat batch; every other attend takes them
+      # in [slots, C] order.
+      starts = None
+      if (dims.window is not None or ix is not None) and (
+          self.slot_attn_impl != "reference" and rows.dst is not None):
+        starts = rows.dst.reshape(rows.slots, rows.chunk)[:, 0]
+      else:
+        q_abs = rows.to_slots(q_abs)                         # [slots,C,H,r+dr]
+      if dims.window is not None:
+        o_lat = slot_attention_window(
+            q_abs, latent.value, slot_cursors, num_valid,
+            impl=self.slot_attn_impl, window=dims.window, v_width=r,
+            scale=scale, starts=starts, chunk=rows.chunk)
+      elif ix is not None:
+        from easyparallellibrary_tpu.kernels.dsa_index import (
+            dsa_index, kth_largest)
+        index = self.variable("cache", "cached_index", _missing_slot_cache)
+        index.value, _ = kv_write(index.value, None,
+                                  rows.to_slots(k_ix[:, 0]), None,
+                                  slot_cursors, num_valid,
+                                  impl=self.kv_write_impl)
+        scores = dsa_index(rows.to_slots(q_ix[:, 0]),
+                           rows.to_slots(w_ix[:, 0]), index.value,
+                           slot_cursors, num_valid,
+                           impl=self.dsa_index_impl)         # [slots,C,Lc]
+        # Each live query's k-th largest score, on the flat batch: the
+        # rows at or above it are the query's selection.
+        k_each = jnp.clip(rows.positions[:, 0] + 1, 1, ix.top_k)
+        threshold = rows.to_slots(
+            kth_largest(rows.to_flat(scores), k_each)[:, None])[..., 0]
+        o_lat = slot_attention_selected(
+            q_abs, latent.value, scores, threshold, slot_cursors, num_valid,
+            impl=self.slot_attn_impl, v_width=r, scale=scale, starts=starts)
+      else:
+        o_lat = slot_attention(q_abs, latent.value, None,
+                               slot_cursors, num_valid,
+                               impl=self.slot_attn_impl, v_width=r,
+                               scale=scale)
+      out = jnp.einsum("bshr,rhd->bshd",
+                       rows.to_flat(o_lat.astype(cfg.dtype))[:, None],
                        w_kvb[..., dn:])
     else:
       kv_full = jnp.einsum("bsr,rhd->bshd", c, w_kvb)
@@ -177,10 +320,27 @@ class LatentAttention(nn.Module):
       logits = jnp.einsum("bqhd,bkhd->bhqk", qf, k) * jnp.asarray(
           scale, cfg.dtype)
       causal = jnp.tril(jnp.ones((S, S), jnp.bool_))
+      if dims.window is not None:
+        causal &= ~jnp.tril(jnp.ones((S, S), jnp.bool_), -dims.window)
+      if ix is not None:
+        from easyparallellibrary_tpu.kernels.dsa_index import (
+            MASKED, kth_largest)
+        dots = jnp.einsum("bqhd,bkd->bqhk", q_ix, k_ix,
+                          preferred_element_type=jnp.float32)
+        index_scores = jnp.where(
+            causal, jnp.sum(jax.nn.relu(dots) * w_ix[..., None], 2), MASKED)
+        k_each = jnp.minimum(jnp.arange(S) + 1, ix.top_k)
+        threshold = kth_largest(index_scores.reshape(B * S, S),
+                                jnp.tile(k_each, B)).reshape(B, S, 1)
+        causal = (causal & (index_scores >= threshold))[:, None]
       logits = jnp.where(causal, logits, jnp.asarray(-1e9, logits.dtype))
       probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
       out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(cfg.dtype),
                        kv_full[..., dn:])
+    if dims.gate:
+      # One value a head, from the layer's input, on the heads' outputs.
+      gate = jax.nn.sigmoid(_dense(cfg, H, "gate")(h).astype(jnp.float32))
+      out = (out.astype(jnp.float32) * gate[..., None]).astype(cfg.dtype)
     return _dense(cfg, cfg.d_model, "o")(out.reshape(B, S, H * dv))
 
 

@@ -98,9 +98,12 @@ def _boxed(init, ndim: int):
 
 class RMSNorm(nn.Module):
   """``x * rsqrt(mean(x^2) + eps) * g`` in float32; the gain is a float32
-  parameter whatever the weights' dtype."""
+  parameter whatever the weights' dtype.  ``rescale`` is a constant the
+  result is multiplied by before it is rounded (models/dots3_note.py: the
+  rescaled latents of its attention); 1 leaves the arithmetic as it is."""
   eps: float
   dtype: Any
+  rescale: float = 1.0
 
   @nn.compact
   def __call__(self, x):
@@ -109,6 +112,8 @@ class RMSNorm(nn.Module):
     x = x.astype(jnp.float32)
     y = x * jax.lax.rsqrt(
         jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * g
+    if self.rescale != 1.0:
+      y = y * self.rescale
     return y.astype(self.dtype)
 
 

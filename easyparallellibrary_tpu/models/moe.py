@@ -367,18 +367,28 @@ def noaux_tc_route(x, router_kernel, bias, top_k: int, scale: float,
   return chosen.astype(jnp.int32), weights * scale
 
 
-def sort_by_expert(chosen, live, num_experts: int):
+def sort_by_expert(chosen, live, num_experts: int, first: int = 0):
   """Sort a step's ``N x top_k`` assignments by expert.  ``chosen`` int32
   ``[N, top_k]``, ``live`` bool ``[N]`` (``None``: every position).  A
   dead position's assignments go to expert ``num_experts``, which does
   not exist: they sort behind the last group and count in no group size.
+  ``num_experts`` counts the experts HELD here, the router's experts
+  ``[first, first + num_experts)``: an assignment to an expert outside
+  them is dead in the same way (another chip's work).
   Returns ``(order, group_sizes)``: ``order`` int32 ``[N * top_k]``, the
   flat assignment (position ``// top_k``, choice ``% top_k``) at each
   sorted row, stable; ``group_sizes`` int32 ``[num_experts]``, summing to
-  (live positions) x ``top_k``."""
+  the live assignments that fell on held experts ((live positions) x
+  ``top_k`` where all are held)."""
   flat = chosen.reshape(-1)
+  if first:
+    flat = flat - first
   if live is not None:
     flat = jnp.where(jnp.repeat(live, chosen.shape[1]), flat, num_experts)
+  if first:
+    # An expert below the held range; one above it already sorts behind
+    # the last group and counts in no group size.
+    flat = jnp.where(flat < 0, num_experts, flat)
   order = jnp.argsort(flat, stable=True).astype(jnp.int32)
   # Rows up to and including each expert's: one fused compare-and-count
   # (a binary search would be a serial loop of scalar steps on a TPU).
@@ -388,17 +398,20 @@ def sort_by_expert(chosen, live, num_experts: int):
 
 
 def dropless_experts(x, chosen, weights, live, w_gate_up, w_down,
-                     impl: Optional[str] = None):
+                     impl: Optional[str] = None, first: int = 0):
   """``sum_i weights[n, i] * Expert_{chosen[n, i]}(x[n])`` for the live
   positions of ``x`` ``[N, D]``, each expert a SiLU-gated MLP:
   ``w_gate_up`` ``[E, D, 2 F]`` (gate columns, then up), ``w_down`` ``[E,
-  F, D]``.  Returns ``(y [N, D]`` in ``x``'s dtype, zeros at dead
-  positions, ``group_sizes [E])``.  ``impl`` names the grouped matmul's
+  F, D]``.  The stacks hold the router's experts ``[first, first + E)``;
+  the sum runs over the chosen experts among them (an absent expert's
+  term is another chip's: nothing stands in for it).  Returns ``(y [N,
+  D]`` in ``x``'s dtype, zeros at dead positions, ``group_sizes [E])``.
+  ``impl`` names the grouped matmul's
   lowering (kernels/moe_gmm.py; ``None`` resolves it from the shapes)."""
   from easyparallellibrary_tpu.kernels.moe_gmm import moe_gmm
   N, k = chosen.shape
   E, _, F2 = w_gate_up.shape
-  order, sizes = sort_by_expert(chosen, live, E)
+  order, sizes = sort_by_expert(chosen, live, E, first)
   rows = x[order // k]                                    # [N k, D]
   h = moe_gmm(rows, w_gate_up, sizes, impl=impl)
   h = jax.nn.silu(h[:, :F2 // 2]) * h[:, F2 // 2:]
@@ -425,6 +438,14 @@ class DroplessMoE(nn.Module):
   all); a shared expert runs for every position (fixed shapes), and what
   it gives a dead one nothing reads.
 
+  ``cfg.experts_held = (first, count)`` (absent or ``None``: all) TELLS
+  the layer which of the router's experts it holds, one chip's share of
+  a layer divided over several: the router keeps its width and its
+  ``num_experts_per_tok``, the weights are normalised over ALL the chosen,
+  held or not, the stacks are ``[count, D, 2 F]`` and ``[count, F, D]``,
+  and the result is ``Shared(x)`` plus the held experts' terms of the sum.
+  No code stands in for the absent chips.
+
   Sows into the ``stats`` collection ``expert_load``, the busiest
   expert's assignments over the mean (1.0 = even; 0 when nothing is
   live), and ``experts_touched``, how many experts have at least one live
@@ -438,14 +459,15 @@ class DroplessMoE(nn.Module):
     from easyparallellibrary_tpu.models.jamba import (
         GatedMLP, _boxed as boxed)
     cfg = self.cfg
-    E, k, F, D = (cfg.n_routed_experts, cfg.num_experts_per_tok,
-                  cfg.moe_d_ff, cfg.d_model)
+    k, F, D = cfg.num_experts_per_tok, cfg.moe_d_ff, cfg.d_model
+    held = getattr(cfg, "experts_held", None)
+    first, E = held if held is not None else (0, cfg.n_routed_experts)
     normal = nn.initializers.normal(stddev=0.02)
-    router = self.param("router_kernel", boxed(normal, 2), (D, E),
-                        jnp.float32)
+    router = self.param("router_kernel", boxed(normal, 2),
+                        (D, cfg.n_routed_experts), jnp.float32)
     bias = self.param("e_score_correction_bias",
-                      boxed(nn.initializers.zeros_init(), 1), (E,),
-                      jnp.float32)
+                      boxed(nn.initializers.zeros_init(), 1),
+                      (cfg.n_routed_experts,), jnp.float32)
     w_gate_up = self.param("experts_gate_up", boxed(normal, 3),
                            (E, D, 2 * F), cfg.param_dtype)
     w_down = self.param("experts_down", boxed(normal, 3), (E, F, D),
@@ -457,8 +479,10 @@ class DroplessMoE(nn.Module):
         cfg.norm_topk_prob, cfg.route_norm_eps)
     y, sizes = dropless_experts(
         flat, chosen, weights, flat_live, jnp.asarray(w_gate_up, cfg.dtype),
-        jnp.asarray(w_down, cfg.dtype), impl=self.moe_gmm_impl)
+        jnp.asarray(w_down, cfg.dtype), impl=self.moe_gmm_impl, first=first)
     total = jnp.sum(sizes).astype(jnp.float32)
+    if held is not None:
+      self.sow("stats", "held_assignments", total)
     self.sow("stats", "expert_load",
              jnp.max(sizes).astype(jnp.float32) * E
              / jnp.maximum(total, 1.0))

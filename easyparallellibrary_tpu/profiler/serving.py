@@ -170,6 +170,14 @@ class ServingStats:
     self.expert_steps = 0
     self.expert_load_sum = 0.0
     self.experts_touched_sum = 0.0
+    # Attends that read less than every row under a bound, and expert
+    # layers that hold a share (models/dots3_note.py; 0 elsewhere), summed
+    # over the steps: index rows scored, rows the selection kept, rows a
+    # window kept, assignments that fell on held experts.
+    self.index_rows = 0
+    self.selected_rows = 0
+    self.window_rows = 0
+    self.held_assignments = 0
     self.kv_rows = 0
     self.busy_time_s = 0.0
     self.prefill_tokens = 0
@@ -325,6 +333,17 @@ class ServingStats:
     not a gauge."""
     self.recompiles += int(n)
 
+  def note_sparse_step(self, index_rows: int, selected_rows: int,
+                       window_rows: int, held_assignments: float):
+    """What a step of a model with selecting or windowed attends and a
+    share of the experts adds to :meth:`note_step`'s sample (one full
+    layer's index and selected rows, one window layer's rows, the held
+    assignments summed over the expert layers)."""
+    self.index_rows += int(index_rows)
+    self.selected_rows += int(selected_rows)
+    self.window_rows += int(window_rows)
+    self.held_assignments += int(held_assignments)
+
   # ----------------------------------------------------------------- step
 
   def note_step(self, active_slots: int, num_slots: int,
@@ -412,7 +431,8 @@ class ServingStats:
       "live_kv_rows", "kv_rows", "flat_positions", "flat_trimmed",
       "flat_trimmed_steps",
       "routed_positions", "expert_steps", "expert_load_sum",
-      "experts_touched_sum", "busy_time_s", "prefill_tokens",
+      "experts_touched_sum", "index_rows", "selected_rows", "window_rows",
+      "held_assignments", "busy_time_s", "prefill_tokens",
       "decode_tokens", "finished_requests", "generated_tokens",
       "drafted_tokens", "accepted_tokens", "shed_requests", "requeues",
       "bad_steps",
@@ -508,6 +528,14 @@ class ServingStats:
         "experts_touched_min_mean": (
             self.experts_touched_sum / self.expert_steps
             if self.expert_steps else 0.0),
+        # Selecting and windowed attends, a share of the experts (0.0
+        # without them), a step: index rows a full layer scored, rows its
+        # selection kept, rows a window layer kept, assignments that fell
+        # on held experts (all expert layers).
+        **{f"{name}_per_step": (getattr(self, name) / self.steps
+                                if self.steps else 0.0)
+           for name in ("index_rows", "selected_rows", "window_rows",
+                        "held_assignments")},
         # Speculation (all 0.0 on a non-speculative engine): drafted vs
         # accepted totals, overall acceptance rate, and accepted-per-
         # step percentiles over the steps that drafted.
@@ -622,6 +650,10 @@ def fleet_summary(replica_stats: List["ServingStats"],
       "experts_touched_min_mean": (
           sum(s.experts_touched_sum for s in stats)
           / max(sum(s.expert_steps for s in stats), 1)),
+      **{f"{name}_per_step": (
+          sum(getattr(s, name) for s in stats) / steps if steps else 0.0)
+         for name in ("index_rows", "selected_rows", "window_rows",
+                      "held_assignments")},
       "drafted_tokens": float(drafted),
       "accepted_tokens": float(accepted),
       "acceptance_rate": (accepted / drafted) if drafted else 0.0,

@@ -38,6 +38,22 @@ ROADMAP_LATENT_CACHE = (
     "are ROADMAP item R5 ('A latent cache'; docs/serving.md 'Current "
     "limits'); serve it through the contiguous cache with those features "
     "off")
+ROADMAP_SPARSE_LATENT = (
+    "a latent attention that selects its rows keeps the indexer's keys "
+    "beside the latent leaf in the contiguous slot cache only, and its "
+    "selection is found over a slot's contiguous rows: a paged pool of "
+    "latent and index rows is ROADMAP item R5 ('A latent cache'), "
+    "selection inside the paged kernel ROADMAP item R10 ('Attention that "
+    "selects what it reads'; docs/serving.md 'Current limits'); serve it "
+    "through the contiguous cache with those features off")
+ROADMAP_WINDOW_LATENT = (
+    "a latent attention behind a window keeps a ring of window + chunk "
+    "rows a slot, which overwrites what a cursor moved back would need "
+    "again and has no paged form: a paged pool per layer kind that frees "
+    "the blocks a window has left is ROADMAP item R6 ('Window and full "
+    "layers in one cache'), the latent rows' pool ROADMAP item R5 ('A "
+    "latent cache'; docs/serving.md 'Current limits'); serve it through "
+    "the contiguous cache with those features off")
 ROADMAP_PREEMPTION = (
     "priority reorders ADMISSION, and on the paged engine "
     "(serving.paged.enabled) a RUNNING throughput-class slot is "
@@ -113,8 +129,9 @@ def check_servable(cfg, role: str = "the serving engine") -> None:
 
   ``cfg`` is a :class:`models.gpt.GPTConfig`, a
   :class:`models.jamba.JambaConfig`, a
-  :class:`models.glm_moe.GlmMoeConfig` or a
-  :class:`models.lfm2_moe.Lfm2MoeConfig` (a config without
+  :class:`models.glm_moe.GlmMoeConfig`, a
+  :class:`models.lfm2_moe.Lfm2MoeConfig` or a
+  :class:`models.dots3_note.Dots3NoteConfig` (a config without
   ``pipeline_stages`` / ``num_experts`` has neither); ``role`` names the
   component doing the rejecting so a draft-model failure reads
   differently from a target-model one.  Refused are the GPT block's
@@ -159,15 +176,26 @@ def check_latent_cache(cfg, feature: str) -> None:
   """Reject ``feature`` (the paged cache, prefix caching, speculative
   decoding, the guarded retry, a draft model) for a model whose layers
   keep a latent leaf in place of a K/V pair (``cfg.layer_kinds()``,
-  models/glm_moe.py): each of them is built for ``cached_key`` /
-  ``cached_value`` pairs (the block pool, its radix tree, the rows a
+  models/glm_moe.py), that leaf and an indexer's keys, or a ring behind a
+  window (models/dots3_note.py): each of them is built for ``cached_key``
+  / ``cached_value`` pairs (the block pool, its radix tree, the rows a
   rejected draft or a retried step leaves behind).  ONE message for
-  every such composition."""
-  from easyparallellibrary_tpu.serving.kv_cache import has_latent_cache
-  if has_latent_cache(cfg):
+  every such composition and kind."""
+  from easyparallellibrary_tpu.serving.kv_cache import (
+      LATENT_KINDS, latent_kinds)
+  why = dict(zip(LATENT_KINDS, (
+      ("a latent cache", ROADMAP_LATENT_CACHE),
+      ("latent attention that selects its rows (sparse_latent layers)",
+       ROADMAP_SPARSE_LATENT),
+      ("latent attention behind a window (window_latent layers)",
+       ROADMAP_WINDOW_LATENT))))
+  kinds = latent_kinds(cfg)
+  if kinds:
+    # One message a kind, all of a model's kinds in one refusal.
     raise ValueError(
-        f"{feature} is not available for a model with a latent cache "
-        f"({type(cfg).__name__}) — {ROADMAP_LATENT_CACHE}")
+        f"{feature} is not available for a model with "
+        f"{' and '.join(why[k][0] for k in kinds)} "
+        f"({type(cfg).__name__}) — {'; '.join(why[k][1] for k in kinds)}")
 
 
 def check_draft_compatible(target_cfg, draft_cfg) -> None:

@@ -186,14 +186,33 @@ def sample_token_slots(logits, keys, temperature, top_k, top_p):
 
 def _expert_stats(sown):
   """What a step hands back of its expert layers' sown ``stats``
-  (models/moe.py ``DroplessMoE``), float32 ``[2]``: the busiest expert's
+  (models/moe.py ``DroplessMoE``), float32 ``[2]`` (``[3]`` where the
+  layers hold a share of their experts): the busiest expert's
   load over the mean, worst layer, and the fewest experts a layer
   touched.  Reduced on the device, fetched with the tokens."""
-  named = lambda name: jnp.stack([
+  named = lambda name: [
       leaf for path, leaf in jax.tree_util.tree_leaves_with_path(sown)
-      if any(getattr(k, "key", None) == name for k in path)])
-  return jnp.stack([jnp.max(named("expert_load")),
-                    jnp.min(named("experts_touched"))])
+      if any(getattr(k, "key", None) == name for k in path)]
+  out = [jnp.max(jnp.stack(named("expert_load"))),
+         jnp.min(jnp.stack(named("experts_touched")))]
+  held = named("held_assignments")
+  if held:
+    # Layers that hold a share of their experts (``cfg.experts_held``):
+    # the live assignments that fell on held ones, summed over the
+    # layers, third in the same array.
+    out.append(jnp.sum(jnp.stack(held)))
+  return jnp.stack(out)
+
+
+def _rows_up_to(resident, num_valid, most: int) -> int:
+  """``sum over live queries of min(t + 1, most)``: slot ``b``'s live
+  queries sit at ``t = resident[b] + i``, ``i < num_valid[b]``.  Closed
+  form a slot (the queries still under ``most`` count an arithmetic
+  series, the others ``most`` each), summed with numpy."""
+  r, n = resident.astype(np.int64), num_valid.astype(np.int64)
+  under = np.clip(most - r, 0, n)
+  return int(np.sum(under * (r + 1) + under * (under - 1) // 2
+                    + (n - under) * most))
 
 
 def flat_width(num_slots: int, chunk: int) -> int:
@@ -439,6 +458,28 @@ class ContinuousBatchingEngine:
       trace_lib.get_tracer().metadata(
           f"{self._track_prefix}/moe_gmm_impl",
           {"impl": self.moe_gmm_impl})
+    # A share of the routed experts (``cfg.experts_held``: one chip of
+    # several a layer is divided over): which of the router's experts the
+    # layers hold.  None: all.
+    self.experts_held = getattr(cfg, "experts_held", None)
+    if self.experts_held is not None:
+      trace_lib.get_tracer().metadata(
+          f"{self._track_prefix}/experts_held",
+          {"first": self.experts_held[0], "count": self.experts_held[1],
+           "published": cfg.n_routed_experts})
+    # Attends that read less than every row under a slot's bound
+    # (models/dots3_note.py): the lowering of a selecting layer's index
+    # scores (kernels/dsa_index.py), resolved once by its rule, and what
+    # the step's counters count rows up to (``_sparse``: the selection's
+    # size and the window); None for a model without such layers.
+    self.dsa_index_impl = None if self.paged else kv_lib.dsa_index_impl(
+        cfg, self.num_slots, self.chunk, self.mesh)
+    self._sparse = None
+    if self.dsa_index_impl is not None:
+      trace_lib.get_tracer().metadata(
+          f"{self._track_prefix}/dsa_index_impl",
+          {"impl": self.dsa_index_impl})
+      self._sparse = (cfg.index_topk, cfg.sliding_window)
     # What the contiguous cache holds of each kind of state (K/V,
     # recurrent state, latent rows) and the ORDER its leaves under a
     # cursor are kept in (``kv_order``: rows or positions,
@@ -716,6 +757,11 @@ class ContinuousBatchingEngine:
           layout += f", {self.ssm_scan_impl} ssm scan"
       elif "latent_leaves" in lay:
         layout += f": {lay['latent_leaves']} latent leaves"
+        if "index_leaves" in lay:
+          layout += (f", {lay['index_leaves']} index leaves "
+                     f"({self.dsa_index_impl} index scores), "
+                     f"{lay['window_leaves']} window rings "
+                     f"{lay['window_bytes'] / 1e6:.1f} MB")
       if self._experts:
         layout += f", {self.moe_gmm_impl} expert matmul"
     get_logger().info(
@@ -821,6 +867,7 @@ class ContinuousBatchingEngine:
         "kv_order": (self.cache_layout or {}).get("kv_order"),
         "ssm_scan_impl": self.ssm_scan_impl,
         "moe_gmm_impl": self.moe_gmm_impl,
+        "dsa_index_impl": self.dsa_index_impl,
         "step_overlap": self.step_overlap,
         "wasted_positions": sched.wasted_positions,
         "recompiles": self._compile_sentinel.recompiles,
@@ -925,6 +972,7 @@ class ContinuousBatchingEngine:
     scan_impl = self.ssm_scan_impl
     recurrent = self._recurrent
     gmm_impl = self.moe_gmm_impl
+    index_impl = self.dsa_index_impl
     experts = self._experts
 
     def step(params, kv, cursors, tokens, num_valid, reset, prev,
@@ -945,6 +993,8 @@ class ContinuousBatchingEngine:
         state_args["ssm_scan_impl"] = scan_impl
       if experts:
         state_args["moe_gmm_impl"] = gmm_impl
+      if index_impl is not None:
+        state_args["dsa_index_impl"] = index_impl
       # Each slot's next-token logits sit at its LAST live chunk
       # position, and the head runs on that row alone; idle slots
       # (num_valid=0) read position 0 — garbage the scheduler never
@@ -1771,8 +1821,19 @@ class ContinuousBatchingEngine:
         0 if step.num_draft is None else int(step.num_draft.sum()))
     flat_trimmed = plan.flat_trimmed
     routed_positions = fed_positions if self._experts else 0
-    expert_load_max, experts_touched_min = (
+    expert_load_max, experts_touched_min, *held = (
         map(float, expert_load) if expert_load is not None else (0.0, 0.0))
+    held_assignments = held[0] if held else 0.0
+    if self._sparse is not None:
+      # Three sums over the plan: the index rows one selecting layer's
+      # queries score (every row under the slot's bound), the rows its
+      # selection keeps (``min(t + 1, top_k)`` a live query at ``t``) and
+      # the rows a window layer keeps (``min(t + 1, window)``).
+      index_rows = int(np.sum(plan.num_valid.astype(np.int64)
+                              * (plan.resident + plan.num_valid)))
+      selected_rows, window_rows = (
+          _rows_up_to(plan.resident, plan.num_valid, k)
+          for k in self._sparse)
     # Whether the step was launched with its predecessor in flight (0:
     # the pipeline was empty, the first step after idle or a drain), and
     # the positions it ran for requests that had retired by its commit.
@@ -1798,6 +1859,15 @@ class ContinuousBatchingEngine:
         # expert layers: under the model's expert count, the step did
         # not stream every expert's weights.
         tracer.counter("serving/experts_touched_min", experts_touched_min)
+      if self.experts_held is not None:
+        # Live assignments that fell on the experts this chip holds, all
+        # expert layers: of ``routed_positions x num_experts_per_tok`` a
+        # layer.
+        tracer.counter("serving/held_assignments", held_assignments)
+      if self._sparse is not None:
+        tracer.counter("serving/index_rows", index_rows)
+        tracer.counter("serving/selected_rows", selected_rows)
+        tracer.counter("serving/window_rows", window_rows)
     if self.stats is not None:
       self.stats.note_step(
           active_slots=plan.active_slots, num_slots=self.num_slots,
@@ -1810,6 +1880,9 @@ class ContinuousBatchingEngine:
           experts_touched_min=experts_touched_min,
           overlapped=overlapped, wasted_positions=plan.wasted,
           flat_positions=flat_positions, flat_trimmed=flat_trimmed)
+      if self._sparse is not None:
+        self.stats.note_sparse_step(index_rows, selected_rows, window_rows,
+                                    held_assignments)
       if self.paged:
         self.stats.note_blocks(self.scheduler.kv_blocks_free,
                                self.scheduler.kv_blocks_used,
@@ -1841,6 +1914,12 @@ class ContinuousBatchingEngine:
         record["routed_positions"] = routed_positions
         record["expert_load_max"] = expert_load_max
         record["experts_touched_min"] = experts_touched_min
+      if self.experts_held is not None:
+        record["held_assignments"] = held_assignments
+      if self._sparse is not None:
+        record["index_rows"] = index_rows
+        record["selected_rows"] = selected_rows
+        record["window_rows"] = window_rows
       if self.paged:
         # The block-pool gauges (ROADMAP item 1 satellite): pool
         # occupancy, internal fragmentation, and preemption count under

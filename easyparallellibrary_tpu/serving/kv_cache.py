@@ -20,12 +20,18 @@ never attendable because the mask only exposes positions the current
 request's own tokens have written (see slot_cache_attend's docstring;
 tests/test_serving.py asserts the no-leakage property).
 
-Four kinds of per-slot state live in the contiguous cache, chosen per
-layer from the model's own layer kinds (:func:`cache_leaves`).  Two are
-rows under the slot's cursor: the K/V pair of an attention layer, and the
+Six kinds of per-slot state live in the contiguous cache, chosen per
+layer from the model's own layer kinds (:func:`cache_leaves`).  Three are
+rows under the slot's cursor: the K/V pair of an attention layer; the
 LATENT leaf of multi-head latent attention (models/glm_moe.py), which is
 ONE tensor a layer whose values are its keys' leading columns
-(``check_latent_cache`` refuses what only a K/V pair is built for).  Two
+(``check_latent_cache`` refuses what only a K/V pair is built for); and
+the SPARSE_LATENT pair of a latent attention that selects its rows
+(models/dots3_note.py): that latent leaf and, beside it, the indexer's
+keys ``[num_slots, Lc, index_head_dim]``.  One is rows that do NOT grow
+with the served context: the WINDOW_LATENT leaf of a latent attention
+behind a window, a ring of ``cfg.ring_length(chunk)`` rows (position
+``p`` at row ``p mod R``), whose width and head count are its own.  Two
 are recurrent, with no position axis, and no cursor can roll them back
 (serving/_capabilities.py ``check_recurrent_state``): a Mamba layer's
 convolution window and float32 scan state (models/jamba.py), and a CONV
@@ -73,6 +79,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from easyparallellibrary_tpu import constants
+from easyparallellibrary_tpu.models.dots3_note import (
+    FULL, SLIDING, SPARSE_LATENT, WINDOW_LATENT)
 from easyparallellibrary_tpu.models.glm_moe import LATENT
 from easyparallellibrary_tpu.models.jamba import ATTENTION, MAMBA
 from easyparallellibrary_tpu.models.lfm2_moe import CONV
@@ -80,6 +88,12 @@ from easyparallellibrary_tpu.models.lfm2_moe import CONV
 # Layer kinds whose state is a recurrence's: no position axis, nothing a
 # cursor can roll back.
 RECURRENT = (MAMBA, CONV)
+# Layer kinds that keep latent rows in place of a K/V pair: one leaf under
+# the cursor; that leaf and the indexer's keys; one ring.
+LATENT_KINDS = (LATENT, SPARSE_LATENT, WINDOW_LATENT)
+# The layer type whose ``cfg.latent_dims`` says a kind's sizes, where a
+# model's latent kinds differ by layer (models/dots3_note.py).
+_LAYER_TYPE = {SPARSE_LATENT: FULL, WINDOW_LATENT: SLIDING}
 
 # Pool index of the reserved null/trash block: block tables default-fill
 # with it (unallocated table slots resolve there), and the fused step's
@@ -122,17 +136,45 @@ def has_recurrent_state(cfg) -> bool:
   return bool(recurrent_kinds(cfg))
 
 
+def latent_kinds(cfg) -> Tuple[str, ...]:
+  """The kinds of latent cache the model's layers keep, in
+  :data:`LATENT_KINDS`' order: what a refusal names."""
+  kinds = layer_kinds(cfg)
+  return tuple(kind for kind in LATENT_KINDS if kind in kinds)
+
+
 def has_latent_cache(cfg) -> bool:
-  """Whether some layer keeps one latent leaf in place of a K/V pair
+  """Whether some layer keeps latent rows in place of a K/V pair
   (serving/_capabilities.py refuses what is built for pairs only)."""
-  return LATENT in layer_kinds(cfg)
+  return bool(latent_kinds(cfg))
+
+
+def latent_leaf_shapes(cfg, kind: str, num_slots: int,
+                       chunk: int) -> Dict[str, Tuple[int, ...]]:
+  """The leaves a layer of a latent ``kind`` keeps, by name.  A model
+  with one latent attention (models/glm_moe.py) says its row's width as
+  ``cfg.latent_dim``; one whose layers differ (models/dots3_note.py) says
+  each kind's through ``cfg.latent_dims(layer_type)``."""
+  Lc = cache_length(cfg, chunk)
+  if kind == LATENT:
+    return {"cached_latent": (num_slots, Lc, 1, cfg.latent_dim)}
+  if kind not in _LAYER_TYPE:
+    raise ValueError(f"{kind!r} is no latent kind: {LATENT_KINDS}")
+  dims = cfg.latent_dims(_LAYER_TYPE[kind])
+  if kind == SPARSE_LATENT:
+    return {"cached_latent": (num_slots, Lc, 1, dims.latent_dim),
+            "cached_index": (num_slots, Lc, dims.indexer.head_dim)}
+  return {"cached_latent": (num_slots, cfg.ring_length(chunk), 1,
+                            dims.latent_dim)}
 
 
 def kv_heads(cfg) -> Tuple[int, int]:
   """``(H_kv, hd)`` of one cache row under a cursor: the model's K/V head
   count (its query heads when it has no fewer) and the head size; for a
-  latent leaf one head of ``kv_lora_rank + qk_rope_head_dim`` values."""
-  if has_latent_cache(cfg):
+  model with ONE latent attention one head of ``kv_lora_rank +
+  qk_rope_head_dim`` values (a model whose latent kinds differ has no one
+  answer: :func:`latent_leaf_shapes`)."""
+  if LATENT in layer_kinds(cfg):
     return 1, cfg.latent_dim
   if cfg.d_model % cfg.num_heads:
     raise ValueError(f"d_model {cfg.d_model} must divide into "
@@ -147,6 +189,9 @@ def kv_leaf_shape(cfg, num_slots: int, chunk: int) -> Tuple[int, ...]:
   hd`` is a whole number of 128-lane tiles, in a 16-bit or 32-bit float,
   is kept in rows, ``[num_slots, Lc, H_kv x hd]``; every other leaf (a
   narrower pair, the latent leaf) ``[num_slots, Lc, H_kv, hd]``."""
+  if SPARSE_LATENT in layer_kinds(cfg):
+    return latent_leaf_shapes(cfg, SPARSE_LATENT, num_slots,
+                              chunk)["cached_latent"]
   Hkv, hd = kv_heads(cfg)
   lead = (num_slots, cache_length(cfg, chunk))
   if (not has_latent_cache(cfg) and (Hkv * hd) % 128 == 0
@@ -175,7 +220,13 @@ def cache_leaves(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
   * latent: ``{"latent": {"cached_latent": [num_slots, Lc, 1,
     kv_lora_rank + qk_rope_head_dim]}}`` in the compute dtype, read under
     the slot's cursor as keys and, its leading ``kv_lora_rank`` columns,
-    as values.
+    as values;
+  * sparse latent: that leaf and ``"cached_index": [num_slots, Lc,
+    index_head_dim]`` (kept in rows), the keys the layer's indexer scores
+    to select what the attend reads;
+  * window latent: ``{"latent": {"cached_latent": [num_slots, R, 1,
+    width]}}``, a ring of ``R = cfg.ring_length(chunk)`` rows whatever the
+    served context (:func:`latent_leaf_shapes`).
   """
   kinds = layer_kinds(cfg)
   if ATTENTION in kinds or LATENT in kinds:
@@ -187,6 +238,10 @@ def cache_leaves(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
       out[f"block_{i}"] = {"attn": {"cached_key": kv, "cached_value": kv}}
     elif kind == LATENT:
       out[f"block_{i}"] = {"latent": {"cached_latent": kv}}
+    elif kind in (SPARSE_LATENT, WINDOW_LATENT):
+      out[f"block_{i}"] = {"latent": {
+          name: jax.ShapeDtypeStruct(shape, cfg.dtype) for name, shape in
+          latent_leaf_shapes(cfg, kind, num_slots, chunk).items()}}
     elif kind == MAMBA:
       out[f"block_{i}"] = {"mamba": {
           "conv_state": jax.ShapeDtypeStruct(
@@ -226,7 +281,8 @@ def kv_cache_shardings(cfg, mesh: Optional[Mesh]):
     return None, None
   sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
   tp = sizes.get(constants.MODEL_AXIS, 1)
-  split = tp > 1 and kv_heads(cfg)[0] % tp == 0
+  split = (tp > 1 and ATTENTION in layer_kinds(cfg)
+           and kv_heads(cfg)[0] % tp == 0)
   rep = NamedSharding(mesh, P())
 
   def place(path, leaf):
@@ -242,7 +298,20 @@ def _under_cursor(cfg) -> bool:
   """Whether some layer keeps rows under a cursor (a K/V pair or a latent
   leaf): what the window write and the attend work on."""
   kinds = layer_kinds(cfg)
-  return ATTENTION in kinds or LATENT in kinds
+  return ATTENTION in kinds or has_latent_cache(cfg)
+
+
+def _one_impl(impls) -> str:
+  """The one lowering a step's calls of a kernel share: the kernel only
+  if every leaf it is called on takes it."""
+  impls = set(impls)
+  return impls.pop() if len(impls) == 1 else "reference"
+
+
+def _mixed_latent(cfg) -> bool:
+  """Whether the model's latent kinds differ by layer
+  (models/dots3_note.py): each kind's leaves are resolved apart."""
+  return any(kind in _LAYER_TYPE for kind in layer_kinds(cfg))
 
 
 def kv_write_impl(cfg, num_slots: int, chunk: int,
@@ -259,9 +328,17 @@ def kv_write_impl(cfg, num_slots: int, chunk: int,
       resolve_kv_write_impl)
   if not _under_cursor(cfg):
     return None
+  sharded = mesh is not None and mesh.size > 1
+  if _mixed_latent(cfg):
+    # Every leaf of every latent kind, a window layer's as a ring.
+    return _one_impl(
+        resolve_kv_write_impl(shape, cfg.dtype, chunk, sharded=sharded,
+                              ring=kind == WINDOW_LATENT)
+        for kind in latent_kinds(cfg) for shape in
+        latent_leaf_shapes(cfg, kind, num_slots, chunk).values())
   return resolve_kv_write_impl(
       kv_leaf_shape(cfg, num_slots, chunk), cfg.dtype, chunk,
-      sharded=mesh is not None and mesh.size > 1)
+      sharded=sharded)
 
 
 def slot_attn_impl(cfg, num_slots: int, chunk: int,
@@ -273,13 +350,41 @@ def slot_attn_impl(cfg, num_slots: int, chunk: int,
   einsums over every row everywhere else; ``None`` for a model without
   an attention layer."""
   from easyparallellibrary_tpu.kernels.slot_attention import (
-      resolve_slot_attn_impl)
+      resolve_slot_attn_impl, resolve_tile_attn_impl)
   if not _under_cursor(cfg):
     return None
+  sharded = mesh is not None and mesh.size > 1
+  if _mixed_latent(cfg):
+    # The selected and the windowed forms of the one-leaf attend.
+    impls = []
+    for kind in latent_kinds(cfg):
+      dims = cfg.latent_dims(_LAYER_TYPE[kind])
+      shape = latent_leaf_shapes(cfg, kind, num_slots,
+                                 chunk)["cached_latent"]
+      impls.append(resolve_tile_attn_impl(
+          shape, cfg.dtype, chunk, dims.num_heads, dims.kv_lora_rank,
+          ring=kind == WINDOW_LATENT, sharded=sharded))
+    return _one_impl(impls)
   return resolve_slot_attn_impl(
       kv_leaf_shape(cfg, num_slots, chunk), cfg.dtype, chunk, cfg.num_heads,
-      sharded=mesh is not None and mesh.size > 1,
-      head_dim=kv_heads(cfg)[1])
+      sharded=sharded, head_dim=kv_heads(cfg)[1])
+
+
+def dsa_index_impl(cfg, num_slots: int, chunk: int,
+                   mesh: Optional[Mesh] = None) -> Optional[str]:
+  """The lowering of the index scores of a layer that selects what it
+  attends — the dispatch rule of kernels/dsa_index.py applied to its index
+  leaf, resolved once like :func:`kv_write_impl`; ``None`` for a model
+  without such a layer."""
+  if SPARSE_LATENT not in layer_kinds(cfg):
+    return None
+  from easyparallellibrary_tpu.kernels.dsa_index import (
+      resolve_dsa_index_impl)
+  return resolve_dsa_index_impl(
+      latent_leaf_shapes(cfg, SPARSE_LATENT, num_slots,
+                         chunk)["cached_index"],
+      cfg.dtype, chunk, cfg.latent_dims(FULL).indexer.num_heads,
+      sharded=mesh is not None and mesh.size > 1)
 
 
 def ssm_scan_impl(cfg, num_slots: int, chunk: int,
@@ -309,14 +414,16 @@ def moe_gmm_impl(cfg, num_slots: int, chunk: int,
   E = getattr(cfg, "n_routed_experts", 0)
   if not E:
     return None
+  held = getattr(cfg, "experts_held", None)
+  if held is not None:
+    E = held[1]          # the stacks hold this chip's experts
   from easyparallellibrary_tpu.kernels.moe_gmm import resolve_moe_gmm_impl
   rows = num_slots * chunk * cfg.num_experts_per_tok
   D, F = cfg.d_model, cfg.moe_d_ff
   sharded = mesh is not None and mesh.size > 1
-  impls = {resolve_moe_gmm_impl((rows, k), (E, k, n), cfg.dtype,
-                                sharded=sharded)
-           for k, n in ((D, 2 * F), (F, D))}
-  return impls.pop() if len(impls) == 1 else "reference"
+  return _one_impl(resolve_moe_gmm_impl((rows, k), (E, k, n), cfg.dtype,
+                                        sharded=sharded)
+                   for k, n in ((D, 2 * F), (F, D)))
 
 
 def allocate_kv_cache(cfg, num_slots: int, chunk: int,
@@ -358,24 +465,33 @@ def cache_layout(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
   """What the slot cache holds, by kind of state: bytes and leaves of
   K/V (under a cursor), of recurrent state (no position axis: ``state_*``
   counts a Mamba layer's two leaves and a conv layer's one) and, for a
-  model that has them, of latent rows (under a cursor, one leaf a layer);
-  and ``kv_order``, the order the leaves under a cursor are kept in
+  model that has them, of latent rows (under a cursor, one leaf a layer),
+  of an indexer's keys (``index_*``, under the cursor beside a latent
+  leaf) and of window rings (``window_*``, whose bytes do not depend on
+  the served context); and ``kv_order``, the order the leaves under a
+  cursor are kept in
   (``"rows"`` or ``"positions"``: module docstring, order note; ``None``
   for a model that keeps none), which says which form of the window write
   and of the attend a step runs.  The engine records it (trace metadata
   ``serving/cache_layout``)."""
   names = {ATTENTION: "kv", MAMBA: "state", CONV: "state",
-           LATENT: "latent"}
+           LATENT: "latent", SPARSE_LATENT: "latent",
+           WINDOW_LATENT: "window"}
   kinds = layer_kinds(cfg)
+  groups = ["kv", "state"]
+  groups += ["latent"] * (LATENT in kinds or SPARSE_LATENT in kinds)
+  groups += ["index"] * (SPARSE_LATENT in kinds)
+  groups += ["window"] * (WINDOW_LATENT in kinds)
   out = {f"{name}_{what}": 0
-         for name in ["kv", "state"] + ["latent"] * (LATENT in kinds)
-         for what in ("bytes", "leaves")}
+         for name in groups for what in ("bytes", "leaves")}
   leaves = cache_leaves(cfg, num_slots, chunk)
   for i, kind in enumerate(kinds):
-    for leaf in jax.tree_util.tree_leaves(leaves[f"block_{i}"]):
-      out[f"{names[kind]}_bytes"] += (int(np.prod(leaf.shape))
-                                      * jnp.dtype(leaf.dtype).itemsize)
-      out[f"{names[kind]}_leaves"] += 1
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+        leaves[f"block_{i}"]):
+      name = "index" if path[-1].key == "cached_index" else names[kind]
+      out[f"{name}_bytes"] += (int(np.prod(leaf.shape))
+                               * jnp.dtype(leaf.dtype).itemsize)
+      out[f"{name}_leaves"] += 1
   out["kv_order"] = None if not _under_cursor(cfg) else (
       "rows" if len(kv_leaf_shape(cfg, num_slots, chunk)) == 3
       else "positions")

@@ -266,6 +266,10 @@ class StepPlan:
   # Cache rows under the bounds of the slots this step feeds: the sum of
   # cursor + num_valid (the host's mirror of the device cursor).
   live_kv_rows: int = 0
+  # Per fed slot, the rows resident before this step (the host's mirror of
+  # the device cursor the step starts from): what an engine whose attends
+  # read less than every row under the bound counts their rows from.
+  resident: Optional[np.ndarray] = None     # int32 [N]
   # Slots whose first chunk position is the PREVIOUS step's sample, which
   # the host has not seen yet (planned past an uncommitted step): the
   # engine's step takes it from that step's output on the device.
@@ -1649,7 +1653,8 @@ class FCFSScheduler:
         draft_cap=np.zeros((N,), np.int32),
         prefilling=np.zeros((N,), bool),
         prefill_tokens=0, decode_tokens=0,
-        active_slots=len(self.active), from_prev=np.zeros((N,), bool))
+        active_slots=len(self.active), from_prev=np.zeros((N,), bool),
+        resident=np.zeros((N,), np.int32))
     budget = self._effective_budget()
     room = self._prefill_room()
     spec_k = self.effective_spec_k        # hoisted: loop-invariant
@@ -1703,7 +1708,8 @@ class FCFSScheduler:
           # step's guaranteed token.
           remaining = req.max_new_tokens - generated
           plan.draft_cap[slot] = max(0, min(spec_k, remaining - 1))
-      plan.live_kv_rows += (self._resident_tokens(state)
+      plan.resident[slot] = self._resident_tokens(state)
+      plan.live_kv_rows += (int(plan.resident[slot])
                             + int(plan.num_valid[slot]))
       self._note_fed(plan, slot, state)
     if not plan.fed and ahead:

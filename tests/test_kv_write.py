@@ -766,7 +766,7 @@ def _abstract_step(model, slots, C, one_chip, **engine):
       model=model, num_slots=slots, chunk=C,
       flat_width=flat_width(slots, C), kv_write_impl="pallas",
       slot_attn_impl="pallas", ssm_scan_impl=None, _recurrent=False,
-      moe_gmm_impl=None, _experts=False,
+      moe_gmm_impl=None, _experts=False, dsa_index_impl=None,
       _jit_step=lambda step, donate, **kw: jax.jit(
           step, donate_argnums=(1, 2))), **engine})
   step = ContinuousBatchingEngine._build_step(engine, True)
@@ -835,6 +835,43 @@ def test_lfm2_step_compiled_for_v5e_holds_its_three_kernels(one_chip):
     if m and m.group(2).startswith(f"bf16[{slots},4112,"):
       assert m.group(3) in ("parameter", "bitcast", "get-tuple-element",
                             "custom-call"), line
+
+
+def test_dots3_step_compiled_for_v5e_holds_its_kernels(one_chip):
+  """The fused step of a two-layer cut (a selecting layer + dense, a window
+  layer + 32 held experts of 256) of models/dots3_note.py at
+  dots3-note-prev's widths and its cell's geometry, 32 slots x chunk 32 at
+  a context of 12,800, compiled for a described v5e as the engine builds
+  it: three ``kv_write`` (the latent leaf, the index leaf in rows, the
+  ring), two each of ``dsa_index``, ``slot_attn_sel`` and ``slot_attn_win``
+  (the slots that feed several positions, then the decoding ones), two
+  ``moe_gmm``; no copy of a cache leaf in either order of its
+  dimensions; no ``[slots, chunk, heads, Lc]`` score tensor; the one
+  ``while`` is the threshold's 32 counting passes."""
+  from easyparallellibrary_tpu.models.dots3_note import (
+      FULL, SLIDING, Dots3Note, Dots3NoteConfig)
+  epl.init()
+  slots, C = 32, 32
+  cfg = Dots3NoteConfig(vocab_size=19008, layer_types=(FULL, SLIDING),
+                        experts_held=(0, 32), max_seq_len=12800)
+  step, args = _abstract_step(Dots3Note(cfg), slots, C, one_chip,
+                              moe_gmm_impl="pallas", _experts=True,
+                              dsa_index_impl="pallas")
+  text = _compiled_text(step, *args)
+  calls = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
+  assert [calls(n) for n in ("kv_write", "dsa_index", "slot_attn_sel",
+                             "slot_attn_win", "moe_gmm", "slot_attn")] == [
+      3, 2, 2, 2, 2, 0], text.count("tpu_custom_call")
+  assert text.count(" while(") == 1
+  leaves = [f"bf16[{slots},12832,1,576]", f"bf16[{slots},1,576,12832]",
+            f"bf16[{slots},12832,128]", f"bf16[{slots},640,1,1088]",
+            f"bf16[{slots},1,1088,640]"]
+  for line in text.splitlines():
+    m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) (\S+?)\(", line)
+    if m and m.group(2).startswith(tuple(leaves)):
+      assert m.group(3) in ("parameter", "bitcast", "get-tuple-element",
+                            "custom-call"), line
+  assert not re.search(rf"\[{slots},{C},64,12832\]", text)
 
 
 def _flat_cuts():
