@@ -68,7 +68,8 @@ from flax import linen as nn
 
 from easyparallellibrary_tpu.models.glm_moe import (
     IndexerDims, LatentAttention, LatentDims)
-from easyparallellibrary_tpu.models.gpt import flat_ids
+from easyparallellibrary_tpu.models.gpt import (
+    SplitLayer, child_of, flat_ids, slot_layers)
 from easyparallellibrary_tpu.models.jamba import GatedMLP, RMSNorm, _dense
 from easyparallellibrary_tpu.models.moe import DroplessMoE
 from easyparallellibrary_tpu.ops import Embedding
@@ -181,15 +182,22 @@ class Dots3NoteBlock(nn.Module):
 
   @nn.compact
   def __call__(self, x, positions, slot_cursors=None, num_valid=None,
-               rows=None):
+               rows=None, part=None, carry=None):
     cfg = self.cfg
     norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
-    x = x + LatentAttention(
+    # In three parts where the step asks (models/gpt.py:SplitLayer).
+    latent = LatentAttention(
         cfg, decode=self.decode, kv_write_impl=self.kv_write_impl,
         slot_attn_impl=self.slot_attn_impl,
         dims=cfg.latent_dims(self.layer_type),
-        dsa_index_impl=self.dsa_index_impl, name="latent")(
-            norm("norm_in")(x), positions, slot_cursors, num_valid, rows)
+        dsa_index_impl=self.dsa_index_impl, name="latent")
+    if part == "mix":
+      return latent(carry, positions, slot_cursors, num_valid, rows, part)
+    mixed = latent(carry if part == "post" else norm("norm_in")(x),
+                   positions, slot_cursors, num_valid, rows, part)
+    if part == "pre":
+      return mixed
+    x = x + mixed
     h = norm("norm_ff")(x)
     if self.dense:
       return x + GatedMLP(cfg, name="mlp")(h)
@@ -235,13 +243,20 @@ class Dots3Note(nn.Module):
     x = Embedding(cfg.vocab_size, cfg.d_model, parallel="none",
                   param_dtype=cfg.param_dtype, name="embed")(ids).astype(
                       cfg.dtype)
-    for i, layer_type in enumerate(cfg.layer_types):
-      x = Dots3NoteBlock(
+    def layer(i, layer_type):
+      block = child_of(lambda parent: Dots3NoteBlock(
           cfg, layer_type=layer_type, dense=i < cfg.first_k_dense,
           decode=decode, kv_write_impl=kv_write_impl,
           slot_attn_impl=slot_attn_impl, moe_gmm_impl=moe_gmm_impl,
-          dsa_index_impl=dsa_index_impl, name=f"block_{i}")(
-              x, positions, slot_cursors, num_valid, rows)
+          dsa_index_impl=dsa_index_impl, name=f"block_{i}", parent=parent))
+      # In slot mode a layer takes each row's position from the map of
+      # the rows it is handed (``slot_layers``); its latent, index and
+      # ring leaves stay outside a two-width step's conditionals.
+      return SplitLayer(lambda mdl, rows, x, **part: block(mdl)(
+          x, positions if rows is None else rows.positions, slot_cursors,
+          num_valid, rows, **part))
+    layers = [layer(i, t) for i, t in enumerate(cfg.layer_types)]
+    x = slot_layers(self, rows, x, layers)
     if decode:
       # The last norm and the head run on the rows that are read.
       x = rows.head_rows(x)

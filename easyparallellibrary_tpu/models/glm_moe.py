@@ -54,11 +54,12 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from easyparallellibrary_tpu.models.gpt import (
-    _missing_slot_cache, flat_ids)
+    SplitLayer, _missing_slot_cache, child_of, flat_ids, slot_layers)
 from easyparallellibrary_tpu.models.jamba import (
     GatedMLP, RMSNorm, _boxed, _dense)
 from easyparallellibrary_tpu.models.moe import DroplessMoE
 from easyparallellibrary_tpu.ops import Embedding
+from easyparallellibrary_tpu.ops.layers import HeldParams
 
 # What a layer keeps per slot (serving/kv_cache.py reads
 # ``cfg.layer_kinds()``): one latent leaf, no K/V pair.
@@ -176,7 +177,7 @@ def rotary(x, positions, theta: float):
                          -1).astype(x.dtype)
 
 
-class LayerNorm(nn.Module):
+class LayerNorm(HeldParams, nn.Module):
   """``(x - mean) * rsqrt(var + eps) * g + b`` in float32, gain and bias
   float32 parameters (the indexer's key norm)."""
   eps: float
@@ -202,7 +203,7 @@ def _rotate_leading(x, positions, theta: float, width: int):
       [rotary(x[..., :width], positions, theta), x[..., width:]], -1)
 
 
-class LatentAttention(nn.Module):
+class LatentAttention(HeldParams, nn.Module):
   """Multi-head latent attention (module docstring), shared by every model
   that has one: ``cfg`` gives ``d_model``, ``rms_norm_eps`` and the dtypes,
   ``dims`` the attention's own sizes and options (``None``: ``cfg`` is a
@@ -215,133 +216,175 @@ class LatentAttention(nn.Module):
   dsa_index_impl: Optional[str] = None
 
   @nn.compact
-  def __call__(self, h, positions, slot_cursors=None, num_valid=None,
-               rows=None):
+  def __call__(self, h, positions=None, slot_cursors=None, num_valid=None,
+               rows=None, part=None):
     cfg = self.cfg
     dims = self.dims if self.dims is not None else glm_latent_dims(cfg)
-    B, S, _ = h.shape
     H, r = dims.num_heads, dims.kv_lora_rank
     dn, dr, dv = (dims.qk_nope_head_dim, dims.qk_rope_head_dim,
                   dims.v_head_dim)
-    norm = lambda name, rescale=1.0: RMSNorm(
-        cfg.rms_norm_eps, cfg.dtype, rescale, name=name)
-    c_q = norm("q_norm", dims.q_rescale)(
-        _dense(cfg, dims.q_lora_rank, "q_a")(h))
-    q = _dense(cfg, H * (dn + dr), "q_b")(c_q).reshape(B, S, H, dn + dr)
-    q_nope = q[..., :dn]
-    q_rope = rotary(q[..., dn:], positions, dims.rope_theta)
-    kv = _dense(cfg, r + dr, "kv_a")(h)
-    c = norm("kv_norm", dims.kv_rescale)(kv[..., :r])
-    k_r = rotary(kv[..., None, r:], positions, dims.rope_theta)  # [B,S,1,dr]
-    w_kvb = jnp.asarray(self.param(
-        "kv_b", _boxed(nn.initializers.normal(stddev=0.02), 2),
-        (r, H * (dn + dv)), cfg.param_dtype), cfg.dtype).reshape(
-            r, H, dn + dv)
-    scale = dims.scale
     ix = dims.indexer
-    if ix is not None:
-      # The indexer: index queries from the query latent, ONE index key a
-      # position from the layer's input, a weight an index head.
-      q_ix = _rotate_leading(
-          _dense(cfg, ix.num_heads * ix.head_dim, "index_q")(c_q).reshape(
-              B, S, ix.num_heads, ix.head_dim),
-          positions, dims.rope_theta, ix.rope_dim)
-      k_ix = _rotate_leading(
-          LayerNorm(ix.layer_norm_eps, cfg.dtype, name="index_k_norm")(
-              _dense(cfg, ix.head_dim, "index_k")(h))[:, :, None],
-          positions, dims.rope_theta, ix.rope_dim)[:, :, 0]
-      w_ix = _dense(cfg, ix.num_heads, "index_w")(h).astype(jnp.float32)
-    if self.decode:
-      from easyparallellibrary_tpu.kernels.kv_write import kv_write
-      from easyparallellibrary_tpu.kernels.slot_attention import (
-          slot_attention, slot_attention_selected, slot_attention_window)
+    held = []
+
+    def w_kvb():
+      if not held:
+        held.append(jnp.asarray(self.param(
+            "kv_b", _boxed(nn.initializers.normal(stddev=0.02), 2),
+            (r, H * (dn + dv)), cfg.param_dtype), cfg.dtype).reshape(
+                r, H, dn + dv))
+      return held[0]
+
+    def gated_out(out, gate):
+      if gate is not None:
+        out = (out.astype(jnp.float32) * gate[..., None]).astype(cfg.dtype)
+      return _dense(cfg, cfg.d_model, "o")(
+          out.reshape(*out.shape[:2], H * dv))
+
+    # In slot mode the whole call is its three parts in turn
+    # (models/gpt.py:SplitLayer), ``h`` from the second on the carry.
+    if part in (None, "pre"):
+      B, S, _ = h.shape
+      norm = lambda name, rescale=1.0: RMSNorm(
+          cfg.rms_norm_eps, cfg.dtype, rescale, name=name)
+      c_q = norm("q_norm", dims.q_rescale)(
+          _dense(cfg, dims.q_lora_rank, "q_a")(h))
+      q = _dense(cfg, H * (dn + dr), "q_b")(c_q).reshape(B, S, H, dn + dr)
+      q_nope = q[..., :dn]
+      q_rope = rotary(q[..., dn:], positions, dims.rope_theta)
+      kv = _dense(cfg, r + dr, "kv_a")(h)
+      c = norm("kv_norm", dims.kv_rescale)(kv[..., :r])
+      k_r = rotary(kv[..., None, r:], positions, dims.rope_theta)  # [B,S,1,dr]
+      if ix is not None:
+        # The indexer: index queries from the query latent, ONE index key a
+        # position from the layer's input, a weight an index head.
+        q_ix = _rotate_leading(
+            _dense(cfg, ix.num_heads * ix.head_dim, "index_q")(c_q).reshape(
+                B, S, ix.num_heads, ix.head_dim),
+            positions, dims.rope_theta, ix.rope_dim)
+        k_ix = _rotate_leading(
+            LayerNorm(ix.layer_norm_eps, cfg.dtype, name="index_k_norm")(
+                _dense(cfg, ix.head_dim, "index_k")(h))[:, :, None],
+            positions, dims.rope_theta, ix.rope_dim)[:, :, 0]
+        w_ix = _dense(cfg, ix.num_heads, "index_w")(h).astype(jnp.float32)
+      # One value a head, from the layer's input, on the heads' outputs.
+      gate = None if not dims.gate else jax.nn.sigmoid(
+          _dense(cfg, H, "gate")(h).astype(jnp.float32))
+      if not self.decode:
+        return gated_out(self._dense_attend(
+            q_nope, q_rope, c, k_r, w_kvb(),
+            None if ix is None else (q_ix, k_ix, w_ix), dims), gate)
       # ``h`` is the step's token-flat batch [T, 1, D]
       # (models/gpt.py:SlotRows); the window write and the attend take
       # their operands as [slots, C, ...], everything around them stays
       # flat.
-      latent = self.variable("cache", "cached_latent", _missing_slot_cache)
       new = jnp.concatenate([c[:, :, None], k_r], -1)        # [T,1,1,r+dr]
-      # Behind a window the leaf is a ring: position p at row p mod its
-      # length.
-      latent.value, _ = kv_write(latent.value, None,
-                                 rows.to_slots(new[:, 0]), None,
-                                 slot_cursors, impl=self.kv_write_impl,
-                                 ring=dims.window is not None)
       q_abs = jnp.concatenate(
-          [jnp.einsum("bshd,rhd->bshr", q_nope, w_kvb[..., :dn]), q_rope],
+          [jnp.einsum("bshd,rhd->bshr", q_nope, w_kvb()[..., :dn]), q_rope],
           -1)[:, 0]                                          # [T,H,r+dr]
+      index = () if ix is None else tuple(
+          rows.to_slots(t[:, 0]) for t in (k_ix, q_ix, w_ix))
       # The selected and the windowed kernels read a tile's query rows
       # where they lie in the flat batch; every other attend takes them
       # in [slots, C] order.
-      starts = None
       if (dims.window is not None or ix is not None) and (
           self.slot_attn_impl != "reference" and rows.dst is not None):
-        starts = rows.dst.reshape(rows.slots, rows.chunk)[:, 0]
+        h = (gate, q_abs), (rows.to_slots(new[:, 0]), None, *index)
       else:
-        q_abs = rows.to_slots(q_abs)                         # [slots,C,H,r+dr]
-      if dims.window is not None:
-        o_lat = slot_attention_window(
-            q_abs, latent.value, slot_cursors, num_valid,
-            impl=self.slot_attn_impl, window=dims.window, v_width=r,
-            scale=scale, starts=starts, chunk=rows.chunk)
-      elif ix is not None:
-        from easyparallellibrary_tpu.kernels.dsa_index import (
-            dsa_index, kth_largest)
-        index = self.variable("cache", "cached_index", _missing_slot_cache)
-        index.value, _ = kv_write(index.value, None,
-                                  rows.to_slots(k_ix[:, 0]), None,
-                                  slot_cursors, num_valid,
-                                  impl=self.kv_write_impl)
-        scores = dsa_index(rows.to_slots(q_ix[:, 0]),
-                           rows.to_slots(w_ix[:, 0]), index.value,
-                           slot_cursors, num_valid,
-                           impl=self.dsa_index_impl)         # [slots,C,Lc]
-        # Each live query's k-th largest score, on the flat batch: the
-        # rows at or above it are the query's selection.
-        k_each = jnp.clip(rows.positions[:, 0] + 1, 1, ix.top_k)
-        threshold = rows.to_slots(
-            kth_largest(rows.to_flat(scores), k_each)[:, None])[..., 0]
-        o_lat = slot_attention_selected(
-            q_abs, latent.value, scores, threshold, slot_cursors, num_valid,
-            impl=self.slot_attn_impl, v_width=r, scale=scale, starts=starts)
-      else:
-        o_lat = slot_attention(q_abs, latent.value, None,
-                               slot_cursors, num_valid,
-                               impl=self.slot_attn_impl, v_width=r,
-                               scale=scale)
-      out = jnp.einsum("bshr,rhd->bshd",
-                       rows.to_flat(o_lat.astype(cfg.dtype))[:, None],
-                       w_kvb[..., dn:])
+        h = (gate,), (rows.to_slots(new[:, 0]), rows.to_slots(q_abs),
+                      *index)
+      if part == "pre":
+        return h
+    if part in (None, "mix"):
+      h = self._mix(*h, dims, slot_cursors, num_valid, rows)
+      if part == "mix":
+        return h
+    (gate,), o_lat = h
+    # the attend's latent rows [slots, C, H, r] -> [rows, 1, H, dv]
+    return gated_out(jnp.einsum("bshr,rhd->bshd",
+                                rows.to_flat(o_lat)[:, None],
+                                w_kvb()[..., dn:]), gate)
+
+  def _mix(self, rowwise, whole, dims, slot_cursors, num_valid, rows):
+    """The per-slot work between the two position-wise parts, on
+    ``[slots, C, ..]`` whatever rows those ran on: the latent (and index)
+    window write, the index scores and their thresholds, the attend.
+    ``rowwise`` ``(gate [T, 1, H] or None[, q_abs [T, H, r + dr]])``,
+    ``whole`` ``(new, q_abs or None[, k_ix, q_ix, w_ix])``.  Returns
+    ``((gate,), o_lat [slots, C, H, r])``."""
+    from easyparallellibrary_tpu.kernels.kv_write import kv_write
+    from easyparallellibrary_tpu.kernels.slot_attention import (
+        slot_attention, slot_attention_selected, slot_attention_window)
+    r, ix, scale = dims.kv_lora_rank, dims.indexer, dims.scale
+    gate, *flat_q = rowwise
+    new, q_abs, *index = whole
+    starts = None
+    if flat_q:
+      (q_abs,) = flat_q
+      starts = rows.dst.reshape(rows.slots, rows.chunk)[:, 0]
+    latent = self.variable("cache", "cached_latent", _missing_slot_cache)
+    # Behind a window the leaf is a ring: position p at row p mod its
+    # length.
+    latent.value, _ = kv_write(latent.value, None, new, None, slot_cursors,
+                               impl=self.kv_write_impl,
+                               ring=dims.window is not None)
+    if dims.window is not None:
+      o_lat = slot_attention_window(
+          q_abs, latent.value, slot_cursors, num_valid,
+          impl=self.slot_attn_impl, window=dims.window, v_width=r,
+          scale=scale, starts=starts, chunk=rows.chunk)
+    elif ix is not None:
+      from easyparallellibrary_tpu.kernels.dsa_index import (
+          dsa_index, kth_largest)
+      k_ix, q_ix, w_ix = index
+      leaf = self.variable("cache", "cached_index", _missing_slot_cache)
+      leaf.value, _ = kv_write(leaf.value, None, k_ix, None, slot_cursors,
+                               num_valid, impl=self.kv_write_impl)
+      scores = dsa_index(q_ix, w_ix, leaf.value, slot_cursors, num_valid,
+                         impl=self.dsa_index_impl)             # [slots,C,Lc]
+      # Each live query's k-th largest score, on the flat batch: the
+      # rows at or above it are the query's selection.
+      k_each = jnp.clip(rows.positions[:, 0] + 1, 1, ix.top_k)
+      threshold = rows.to_slots(
+          kth_largest(rows.to_flat(scores), k_each)[:, None])[..., 0]
+      o_lat = slot_attention_selected(
+          q_abs, latent.value, scores, threshold, slot_cursors, num_valid,
+          impl=self.slot_attn_impl, v_width=r, scale=scale, starts=starts)
     else:
-      kv_full = jnp.einsum("bsr,rhd->bshd", c, w_kvb)
-      k = jnp.concatenate(
-          [kv_full[..., :dn], jnp.broadcast_to(k_r, (B, S, H, dr))], -1)
-      qf = jnp.concatenate([q_nope, q_rope], -1)
-      logits = jnp.einsum("bqhd,bkhd->bhqk", qf, k) * jnp.asarray(
-          scale, cfg.dtype)
-      causal = jnp.tril(jnp.ones((S, S), jnp.bool_))
-      if dims.window is not None:
-        causal &= ~jnp.tril(jnp.ones((S, S), jnp.bool_), -dims.window)
-      if ix is not None:
-        from easyparallellibrary_tpu.kernels.dsa_index import (
-            MASKED, kth_largest)
-        dots = jnp.einsum("bqhd,bkd->bqhk", q_ix, k_ix,
-                          preferred_element_type=jnp.float32)
-        index_scores = jnp.where(
-            causal, jnp.sum(jax.nn.relu(dots) * w_ix[..., None], 2), MASKED)
-        k_each = jnp.minimum(jnp.arange(S) + 1, ix.top_k)
-        threshold = kth_largest(index_scores.reshape(B * S, S),
-                                jnp.tile(k_each, B)).reshape(B, S, 1)
-        causal = (causal & (index_scores >= threshold))[:, None]
-      logits = jnp.where(causal, logits, jnp.asarray(-1e9, logits.dtype))
-      probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-      out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(cfg.dtype),
-                       kv_full[..., dn:])
-    if dims.gate:
-      # One value a head, from the layer's input, on the heads' outputs.
-      gate = jax.nn.sigmoid(_dense(cfg, H, "gate")(h).astype(jnp.float32))
-      out = (out.astype(jnp.float32) * gate[..., None]).astype(cfg.dtype)
-    return _dense(cfg, cfg.d_model, "o")(out.reshape(B, S, H * dv))
+      o_lat = slot_attention(q_abs, latent.value, None, slot_cursors,
+                             num_valid, impl=self.slot_attn_impl, v_width=r,
+                             scale=scale)
+    return (gate,), o_lat.astype(self.cfg.dtype)
+
+  def _dense_attend(self, q_nope, q_rope, c, k_r, w_kvb, index, dims):
+    """The full forward's attend over its own sequence: ``[B, S, H, dv]``."""
+    cfg = self.cfg
+    B, S, H, dn = q_nope.shape
+    kv_full = jnp.einsum("bsr,rhd->bshd", c, w_kvb)
+    k = jnp.concatenate(
+        [kv_full[..., :dn],
+         jnp.broadcast_to(k_r, (B, S, H, k_r.shape[-1]))], -1)
+    qf = jnp.concatenate([q_nope, q_rope], -1)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", qf, k) * jnp.asarray(
+        dims.scale, cfg.dtype)
+    causal = jnp.tril(jnp.ones((S, S), jnp.bool_))
+    if dims.window is not None:
+      causal &= ~jnp.tril(jnp.ones((S, S), jnp.bool_), -dims.window)
+    if index is not None:
+      from easyparallellibrary_tpu.kernels.dsa_index import (
+          MASKED, kth_largest)
+      q_ix, k_ix, w_ix = index
+      dots = jnp.einsum("bqhd,bkd->bqhk", q_ix, k_ix,
+                        preferred_element_type=jnp.float32)
+      index_scores = jnp.where(
+          causal, jnp.sum(jax.nn.relu(dots) * w_ix[..., None], 2), MASKED)
+      k_each = jnp.minimum(jnp.arange(S) + 1, dims.indexer.top_k)
+      threshold = kth_largest(index_scores.reshape(B * S, S),
+                              jnp.tile(k_each, B)).reshape(B, S, 1)
+      causal = (causal & (index_scores >= threshold))[:, None]
+    logits = jnp.where(causal, logits, jnp.asarray(-1e9, logits.dtype))
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(cfg.dtype),
+                      kv_full[..., dn:])
 
 
 class GlmMoeBlock(nn.Module):
@@ -354,13 +397,20 @@ class GlmMoeBlock(nn.Module):
 
   @nn.compact
   def __call__(self, x, positions, slot_cursors=None, num_valid=None,
-               rows=None):
+               rows=None, part=None, carry=None):
     cfg = self.cfg
     norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
-    x = x + LatentAttention(
+    # In three parts where the step asks (models/gpt.py:SplitLayer).
+    latent = LatentAttention(
         cfg, decode=self.decode, kv_write_impl=self.kv_write_impl,
-        slot_attn_impl=self.slot_attn_impl, name="latent")(
-            norm("norm_in")(x), positions, slot_cursors, num_valid, rows)
+        slot_attn_impl=self.slot_attn_impl, name="latent")
+    if part == "mix":
+      return latent(carry, positions, slot_cursors, num_valid, rows, part)
+    mixed = latent(carry if part == "post" else norm("norm_in")(x),
+                   positions, slot_cursors, num_valid, rows, part)
+    if part == "pre":
+      return mixed
+    x = x + mixed
     h = norm("norm_ff")(x)
     if self.dense:
       return x + GatedMLP(cfg, name="mlp")(h)
@@ -408,12 +458,19 @@ class GlmMoe(nn.Module):
     x = Embedding(cfg.vocab_size, cfg.d_model, parallel="none",
                   param_dtype=cfg.param_dtype, name="embed")(ids).astype(
                       cfg.dtype)
-    for i in range(cfg.num_layers):
-      x = GlmMoeBlock(cfg, dense=i < cfg.first_k_dense, decode=decode,
-                      kv_write_impl=kv_write_impl,
-                      slot_attn_impl=slot_attn_impl,
-                      moe_gmm_impl=moe_gmm_impl, name=f"block_{i}")(
-                          x, positions, slot_cursors, num_valid, rows)
+    def layer(i):
+      block = child_of(lambda parent: GlmMoeBlock(
+          cfg, dense=i < cfg.first_k_dense, decode=decode,
+          kv_write_impl=kv_write_impl, slot_attn_impl=slot_attn_impl,
+          moe_gmm_impl=moe_gmm_impl, name=f"block_{i}", parent=parent))
+      # In slot mode a layer takes each row's position from the map of
+      # the rows it is handed (``slot_layers``); its latent leaf stays
+      # outside a two-width step's conditionals.
+      return SplitLayer(lambda mdl, rows, x, **part: block(mdl)(
+          x, positions if rows is None else rows.positions, slot_cursors,
+          num_valid, rows, **part))
+    layers = [layer(i) for i in range(cfg.num_layers)]
+    x = slot_layers(self, rows, x, layers)
     if decode:
       # The last norm and the head run on the rows that are read.
       x = rows.head_rows(x)

@@ -28,7 +28,7 @@ from jax.sharding import PartitionSpec as P
 
 from easyparallellibrary_tpu import constants
 from easyparallellibrary_tpu.ops import Dense, Embedding
-from easyparallellibrary_tpu.ops.layers import LayerNorm  # noqa: E501
+from easyparallellibrary_tpu.ops.layers import HeldParams, LayerNorm
 from easyparallellibrary_tpu.ops.losses import (
     distributed_sparse_softmax_cross_entropy_with_logits,
 )
@@ -302,6 +302,13 @@ class SlotRows:
   ``cursors[b] + i``.  ``head`` int32 ``[slots]`` or ``[slots, R]`` —
   the flat rows whose logits the caller asked for (``None``: every chunk
   position's).
+
+  ``narrow`` (static; ``None``: one width) is a second, smaller row count
+  the layers may run on, and ``fits`` (bool scalar) says whether this
+  step's live positions fit it: live rows are contiguous from row 0, so
+  a step that fits computes the first ``narrow`` rows and nothing else
+  (:func:`slot_layers`).  The maps, the embedding, the head and the
+  sampler are built at ``T`` rows, once.
   """
   slots: int
   chunk: int
@@ -310,6 +317,8 @@ class SlotRows:
   live: Any
   positions: Any
   head: Any = None
+  narrow: Optional[int] = None
+  fits: Any = None
 
   def to_slots(self, flat):
     """``[T, ...]`` -> ``[slots, C, ...]``.  Rows are gathered whole,
@@ -329,6 +338,15 @@ class SlotRows:
       x = jnp.take(x, self.src, axis=0, mode="clip")
     return x.reshape(x.shape[0], *tail)
 
+  def first(self, n: int) -> "SlotRows":
+    """The map of the batch's first ``n`` rows: what the layers see when
+    they run on those alone.  :meth:`to_slots` then gathers from ``n``
+    rows (a live position's row lies among them, a dead one's index is
+    clipped), :meth:`to_flat` gathers ``n``."""
+    return dataclasses.replace(
+        self, src=self.src[:n], live=self.live[:n],
+        positions=self.positions[:n], head=None, narrow=None, fits=None)
+
   def head_rows(self, x):
     """The rows of ``x`` ``[T, 1, D]`` the head runs on: ``[slots, D]``
     or ``[slots, R, D]`` as ``head`` asks, ``[slots, C, D]`` without."""
@@ -337,8 +355,112 @@ class SlotRows:
     return jnp.take(x[:, 0], self.head, axis=0, mode="clip")
 
 
+def child_of(make):
+  """``get(parent) -> make(parent)``, made once a parent: flax builds a
+  named submodule once under a parent, and :func:`slot_layers` calls a
+  :class:`SplitLayer`'s parts under the model itself and under the copies
+  of it a conditional's sides run on."""
+  made = {}
+
+  def get(parent):
+    if id(parent) not in made:
+      made[id(parent)] = (parent, make(parent))   # the parent kept: its id
+    return made[id(parent)][1]
+  return get
+
+
+@dataclasses.dataclass
+class SplitLayer:
+  """A layer of :func:`slot_layers` whose mixer owns a cache leaf that
+  grows with the context (a K/V pair, a latent, an index), in three parts
+  so that the leaf's write and its attend stand OUTSIDE the conditional of
+  a two-width step and in the program once.  ``call(mdl, rows, x)`` is the
+  whole layer; with ``part=`` one of
+
+  * ``"pre"``: ``x`` -> ``carry``: the norm and the projections before
+    the mixer, to the mixer's operands;
+  * ``"mix"`` (``x`` is ``None``): ``carry`` -> ``carry``: the window
+    write and the attend, on ``[slots, C, ..]`` whatever rows the other
+    two ran on;
+  * ``"post"``: ``x``, ``carry`` -> ``x``: the output projection, the
+    residual and everything after it.
+
+  ``carry`` is ``(rowwise, whole)``: a tuple of arrays with the flat
+  batch's rows in front (or ``None``) and a tree of anything else.  Which
+  layers a stack splits is said where the stack is built: each split
+  layer costs the step a conditional (55 to 150 us on a v5e), each such
+  leaf left inside one rests on the compiler writing it in place there
+  (:class:`GPT` leaves its blocks whole and says why)."""
+  call: Any
+
+  def __call__(self, mdl, rows, x, **part):
+    return self.call(mdl, rows, x, **part)
+
+
+def slot_layers(model, rows: SlotRows, x, layers):
+  """A slot-mode model's stack of layers on the token-flat batch ``x``
+  ``[T, 1, D]``: ``layers[i](mdl, rows, x) -> x`` runs layer ``i`` as a
+  submodule of ``mdl`` on the rows ``rows`` maps.
+
+  With one width (and outside slot mode, ``rows`` ``None``) this is the
+  loop.  With a ``narrow`` one, what is position-wise stands in
+  conditionals on ``rows.fits`` inside the ONE compiled program: the
+  narrow side runs it on ``x``'s first ``narrow`` rows under
+  :meth:`SlotRows.first`'s map, where every live position lies, and
+  fills its results up to ``T`` rows with zeros, which no live position
+  reads; the wide side runs it as it is.  A plain layer stands there
+  whole (its kernels take ``[slots, C, ..]`` operands on either side): so
+  a run of them is ONE conditional.  A :class:`SplitLayer` ends the run
+  after its ``"pre"`` part, has its ``"mix"`` part outside, and starts the
+  next run with its ``"post"`` part: a cache leaf it owns is no operand
+  that a conditional changes, so nothing rests on the compiler proving an
+  in-place write safe inside one (it copied such leaves whole, 100 to 540
+  MB each, in four of the six serving cells: PERF.md, PR 41).  What is
+  outside — the maps, the embedding, the mixers of split layers, the last
+  norm, the head on ``[slots, ..]`` rows, the sampler — is in the program
+  once."""
+  if rows is None or rows.narrow is None:
+    for layer in layers:
+      x = layer(model, rows, x)
+    return x
+  n, T = rows.narrow, x.shape[0]
+  cut = lambda tree: jax.tree_util.tree_map(lambda y: y[:n], tree)
+  fill = lambda tree: jax.tree_util.tree_map(
+      lambda y: jnp.pad(y, ((0, T - n),) + ((0, 0),) * (y.ndim - 1)), tree)
+
+  def conditional(steps, x, carry):
+    def run(rows):
+      def fn(mdl, x, carry):
+        for step in steps:
+          x, carry = step(mdl, rows, x, carry)
+        return x, carry
+      return fn
+
+    def narrow(mdl, x, carry):
+      x, (rowwise, whole) = run(rows.first(n))(
+          mdl, x[:n], (cut(carry[0]), carry[1]))
+      return fill(x), (fill(rowwise), whole)
+
+    return nn.cond(rows.fits, narrow, run(rows), model, x, carry)
+
+  steps, carry = [], ((), ())
+  for layer in layers:
+    if isinstance(layer, SplitLayer):
+      steps.append(lambda mdl, rows, x, carry, layer=layer: (
+          x, layer(mdl, rows, x, part="pre")))
+      x, carry = conditional(steps, x, carry)
+      carry = layer(model, rows, None, part="mix", carry=carry)
+      steps = [lambda mdl, rows, x, carry, layer=layer: (
+          layer(mdl, rows, x, part="post", carry=carry), ((), ()))]
+    else:
+      steps.append(lambda mdl, rows, x, carry, layer=layer: (
+          layer(mdl, rows, x), carry))
+  return conditional(steps, x, carry)[0]
+
+
 def slot_rows(cursors, num_valid, slots: int, chunk: int,
-              width: Optional[int] = None, head_pos=None) -> SlotRows:
+              width: Optional[int] = None, head_pos=None,
+              narrow: Optional[int] = None) -> SlotRows:
   """The step's :class:`SlotRows`.  ``width`` is the flat batch's static
   row count ``T`` (``None`` or ``slots x chunk``: full width, the map a
   reshape); under a narrower one no step may hold more than ``T`` live
@@ -348,7 +470,10 @@ def slot_rows(cursors, num_valid, slots: int, chunk: int,
   ``[start_b, start_b + num_valid_b)``; the inverse, which slot a row
   belongs to, is one compare-and-count (a binary search would be a
   serial loop of scalar steps on a TPU).  ``head_pos`` int32 ``[slots]``
-  or ``[slots, R]`` names chunk positions whose logits are wanted."""
+  or ``[slots, R]`` names chunk positions whose logits are wanted.
+  ``narrow`` (``None``, or at least ``width``: none) is the second row
+  count a step whose live positions fit it computes
+  (:func:`slot_layers`; serving/engine.py:narrow_width)."""
   N, C = slots, chunk
   i32 = jnp.int32
   cursors = cursors.astype(i32)
@@ -373,8 +498,11 @@ def slot_rows(cursors, num_valid, slots: int, chunk: int,
   # are clipped where they are used (``SlotRows``' gathers).
   dst = (starts[:, None] + jnp.arange(C, dtype=i32)[None]).reshape(N * C)
   head = None if head_pos is None else per_slot(starts) + head_pos
+  if narrow is not None and narrow >= T:
+    narrow = None
   return SlotRows(N, C, slot * C + i, dst, (row < ends[-1])[:, None],
-                  (jnp.take(cursors, slot) + i)[:, None], head)
+                  (jnp.take(cursors, slot) + i)[:, None], head, narrow,
+                  None if narrow is None else ends[-1] <= narrow)
 
 
 def flat_ids(ids, slot_cursors, num_valid, rows=None):
@@ -391,7 +519,7 @@ def slot_step_logits(model, params, kv, tokens, cursors,
                      kv_write_impl=None, slot_attn_impl=None,
                      num_valid=None, stats: bool = False,
                      width: Optional[int] = None, head_pos=None,
-                     **state_args):
+                     narrow: Optional[int] = None, **state_args):
   """Multi-token scoring on the shared slot-cache core — THE device entry
   every serving component steps through.
 
@@ -432,7 +560,9 @@ def slot_step_logits(model, params, kv, tokens, cursors,
   the same model code.  ``head_pos`` (int32 ``[num_slots]`` or
   ``[num_slots, R]``: chunk positions) gathers the rows the head runs on
   BEFORE the head: the one a slot samples from, or a speculating step's
-  ``K + 1``.
+  ``K + 1``.  ``narrow`` is a second, smaller width in the same program:
+  a step whose live positions fit it runs its layers on that many rows
+  (:func:`slot_layers`), its head as at ``width``.
 
   Returns ``(logits, new_kv)`` — ``logits`` ``[num_slots, C, vocab]``,
   or ``[num_slots, vocab]`` / ``[num_slots, R, vocab]`` as ``head_pos``
@@ -441,7 +571,7 @@ def slot_step_logits(model, params, kv, tokens, cursors,
   position).
   """
   rows = slot_rows(cursors, num_valid, *tokens.shape, width=width,
-                   head_pos=head_pos)
+                   head_pos=head_pos, narrow=narrow)
   logits, mut = model.apply(
       {"params": params, "cache": kv}, tokens, decode=True,
       slot_cursors=cursors, num_valid=num_valid, rows=rows,
@@ -787,7 +917,7 @@ def _lm_head(cfg: GPTConfig, name=None) -> "Dense":
                dtype=cfg.dtype, param_dtype=cfg.param_dtype, name=name)
 
 
-class GPT(nn.Module):
+class GPT(HeldParams, nn.Module):
   """Decoder-only LM.  `__call__(ids) -> logits`; `loss(params-free)` via
   :func:`gpt_loss`."""
 
@@ -893,14 +1023,21 @@ class GPT(nn.Module):
         block_cls = nn.checkpoint(
             Block, policy=_remat_policy(cfg.remat_policy),
             prevent_cse=False)
-      for i in range(cfg.num_layers):
+      # Whole layers, so all of them stand in ONE conditional of a
+      # two-width step with their K/V pairs (``slot_layers``): a
+      # conditional a layer costs a GPT-2 step more than the narrow width
+      # saves it, and the compiler writes these pairs in place there
+      # (tests/test_kv_write.py compiles the cell's 24 layers for a v5e).
+      def layer(i):
         use_moe = cfg.num_experts > 0 and \
           (i % cfg.moe_every == cfg.moe_every - 1)
-        x = block_cls(cfg, use_moe=use_moe, deterministic=deterministic,
-                      decode=decode, kv_write_impl=kv_write_impl,
-                      slot_attn_impl=slot_attn_impl,
-                      name=f"block_{i}")(x, slot_cursors, paged_info,
-                                         num_valid, rows)
+        return lambda mdl, rows, x: block_cls(
+            cfg, use_moe=use_moe, deterministic=deterministic,
+            decode=decode, kv_write_impl=kv_write_impl,
+            slot_attn_impl=slot_attn_impl, name=f"block_{i}", parent=mdl)(
+                x, slot_cursors, paged_info, num_valid, rows)
+      x = slot_layers(self, rows, x,
+                      [layer(i) for i in range(cfg.num_layers)])
 
     if rows is not None:
       # The head and the last norm run on the rows that are read: the

@@ -49,8 +49,10 @@ import numpy as np
 from flax import linen as nn
 
 from easyparallellibrary_tpu.models.gpt import (
-    _missing_slot_cache, flat_ids, slot_cache_attend)
+    SplitLayer, _missing_slot_cache, child_of, flat_ids, slot_cache_attend,
+    slot_layers)
 from easyparallellibrary_tpu.ops import Dense, Embedding
+from easyparallellibrary_tpu.ops.layers import HeldParams
 
 # What a layer keeps per slot: the cache manager's vocabulary
 # (serving/kv_cache.py reads ``cfg.layer_kinds()``).
@@ -96,7 +98,7 @@ def _boxed(init, ndim: int):
   return nn.with_partitioning(init, (None,) * ndim)
 
 
-class RMSNorm(nn.Module):
+class RMSNorm(HeldParams, nn.Module):
   """``x * rsqrt(mean(x^2) + eps) * g`` in float32; the gain is a float32
   parameter whatever the weights' dtype.  ``rescale`` is a constant the
   result is multiplied by before it is rounded (models/dots3_note.py: the
@@ -146,27 +148,38 @@ class AttentionMixer(nn.Module):
   slot_attn_impl: Optional[str] = None
 
   @nn.compact
-  def __call__(self, h, slot_cursors=None, num_valid=None, rows=None):
+  def __call__(self, h, slot_cursors=None, num_valid=None, rows=None,
+               part=None):
     cfg = self.cfg
-    B, S, _ = h.shape
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = _dense(cfg, H * hd, "q")(h).reshape(B, S, H, hd)
-    k = _dense(cfg, Hkv * hd, "k")(h).reshape(B, S, Hkv, hd)
-    v = _dense(cfg, Hkv * hd, "v")(h).reshape(B, S, Hkv, hd)
-    if self.decode:
+    out_proj = lambda: _dense(cfg, cfg.d_model, "o")
+    # In slot mode the whole call is its three parts in turn
+    # (models/gpt.py:SplitLayer), ``h`` from the second on the carry.
+    if part in (None, "pre"):
+      B, S, _ = h.shape
+      q = _dense(cfg, H * hd, "q")(h).reshape(B, S, H, hd)
+      k = _dense(cfg, Hkv * hd, "k")(h).reshape(B, S, Hkv, hd)
+      v = _dense(cfg, Hkv * hd, "v")(h).reshape(B, S, Hkv, hd)
+      if not self.decode:
+        return out_proj()(gqa_causal_attention(q, k, v, cfg.dtype).reshape(
+            B, S, H * hd))
       # ``h`` is the step's token-flat batch [T, 1, D]
       # (models/gpt.py:SlotRows); the window write and the attend take
       # their operands as [slots, C, ...].
+      h = (), tuple(rows.to_slots(t[:, 0]) for t in (q, k, v))
+      if part == "pre":
+        return h
+    if part in (None, "mix"):
       ck = self.variable("cache", "cached_key", _missing_slot_cache)
       cv = self.variable("cache", "cached_value", _missing_slot_cache)
       out, ck.value, cv.value = slot_cache_attend(
-          *(rows.to_slots(t[:, 0]) for t in (q, k, v)), ck.value, cv.value,
-          slot_cursors, cfg.dtype, write_impl=self.kv_write_impl,
-          attn_impl=self.slot_attn_impl, num_valid=num_valid)
-      out = rows.to_flat(out)[:, None]
-    else:
-      out = gqa_causal_attention(q, k, v, cfg.dtype)
-    return _dense(cfg, cfg.d_model, "o")(out.reshape(B, S, H * hd))
+          *h[1], ck.value, cv.value, slot_cursors, cfg.dtype,
+          write_impl=self.kv_write_impl, attn_impl=self.slot_attn_impl,
+          num_valid=num_valid)
+      h = (), out
+      if part == "mix":
+        return h
+    return out_proj()(rows.to_flat(h[1]).reshape(-1, 1, H * hd))
 
 
 def advance_window(full, num_valid, keep: int):
@@ -205,7 +218,7 @@ def _uniform(bound: float):
   return init
 
 
-class MambaMixer(nn.Module):
+class MambaMixer(HeldParams, nn.Module):
   """The Mamba-1 mixer with Jamba's three inner norms.  Parameters the
   recurrence depends on are float32 (``A_log`` and ``D`` state-major
   ``[d_state, d_inner]`` / ``[d_inner]``, the ``dt`` bias); the
@@ -302,19 +315,25 @@ class JambaBlock(nn.Module):
 
   @nn.compact
   def __call__(self, x, slot_cursors=None, num_valid=None, reset=None,
-               rows=None):
+               rows=None, part=None, carry=None):
     cfg = self.cfg
     norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
-    h = norm("norm_in")(x)
     if self.kind == ATTENTION:
-      mixed = AttentionMixer(cfg, decode=self.decode,
+      # In three parts where the step asks (models/gpt.py:SplitLayer).
+      mixer = AttentionMixer(cfg, decode=self.decode,
                              kv_write_impl=self.kv_write_impl,
-                             slot_attn_impl=self.slot_attn_impl,
-                             name="attn")(h, slot_cursors, num_valid, rows)
+                             slot_attn_impl=self.slot_attn_impl, name="attn")
+      if part == "mix":
+        return mixer(carry, slot_cursors, num_valid, rows, part)
+      mixed = mixer(carry if part == "post" else norm("norm_in")(x),
+                    slot_cursors, num_valid, rows, part)
+      if part == "pre":
+        return mixed
     else:
       mixed = MambaMixer(cfg, decode=self.decode,
                          ssm_scan_impl=self.ssm_scan_impl,
-                         name="mamba")(h, num_valid, reset, rows)
+                         name="mamba")(norm("norm_in")(x), num_valid, reset,
+                                       rows)
     x = x + mixed
     return x + GatedMLP(cfg, name="mlp")(norm("norm_ff")(x))
 
@@ -351,11 +370,18 @@ class Jamba(nn.Module):
     if decode:
       rows, ids = flat_ids(ids, slot_cursors, num_valid, rows)
     x = tok(ids).astype(cfg.dtype)
-    for i, kind in enumerate(cfg.layer_kinds()):
-      x = JambaBlock(cfg, kind, decode=decode, kv_write_impl=kv_write_impl,
-                     slot_attn_impl=slot_attn_impl,
-                     ssm_scan_impl=ssm_scan_impl, name=f"block_{i}")(
-                         x, slot_cursors, num_valid, reset, rows)
+    def layer(i, kind):
+      block = child_of(lambda parent: JambaBlock(
+          cfg, kind, decode=decode, kv_write_impl=kv_write_impl,
+          slot_attn_impl=slot_attn_impl, ssm_scan_impl=ssm_scan_impl,
+          name=f"block_{i}", parent=parent))
+      call = lambda mdl, rows, x, **part: block(mdl)(
+          x, slot_cursors, num_valid, reset, rows, **part)
+      # An attention layer's K/V window stays outside a two-width step's
+      # conditionals; a Mamba layer's state is a few MB and stands inside.
+      return SplitLayer(call) if kind == ATTENTION else call
+    layers = [layer(i, kind) for i, kind in enumerate(cfg.layer_kinds())]
+    x = slot_layers(self, rows, x, layers)
     if decode:
       # The last norm and the head run on the rows that are read.
       x = rows.head_rows(x)

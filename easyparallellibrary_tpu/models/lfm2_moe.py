@@ -57,12 +57,14 @@ from flax import linen as nn
 
 from easyparallellibrary_tpu.models.glm_moe import rotary
 from easyparallellibrary_tpu.models.gpt import (
-    _missing_slot_cache, flat_ids, slot_cache_attend)
+    SplitLayer, _missing_slot_cache, child_of, flat_ids, slot_cache_attend,
+    slot_layers)
 from easyparallellibrary_tpu.models.jamba import (
     ATTENTION, GatedMLP, RMSNorm, _boxed, _dense, _uniform, advance_window,
     gqa_causal_attention)
 from easyparallellibrary_tpu.models.moe import DroplessMoE
 from easyparallellibrary_tpu.ops import Embedding
+from easyparallellibrary_tpu.ops.layers import HeldParams
 
 # What a conv layer keeps per slot (serving/kv_cache.py reads
 # ``cfg.layer_kinds()``): the convolution's last inputs, nothing else.
@@ -118,7 +120,7 @@ class Lfm2MoeConfig:
     return tuple(names[t] for t in self.layer_types)
 
 
-class ShortConv(nn.Module):
+class ShortConv(HeldParams, nn.Module):
   """The gated short convolution (module docstring).  ``in_proj``'s
   columns are ``B | C | u``; the taps are ``[L, d_model]``, tap ``L - 1``
   on the current token."""
@@ -167,31 +169,41 @@ class NormedAttention(nn.Module):
   slot_attn_impl: Optional[str] = None
 
   @nn.compact
-  def __call__(self, h, positions, slot_cursors=None, num_valid=None,
-               rows=None):
+  def __call__(self, h, positions=None, slot_cursors=None, num_valid=None,
+               rows=None, part=None):
     cfg = self.cfg
-    B, S, _ = h.shape
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype, name=name)
-    q = _dense(cfg, H * hd, "q")(h).reshape(B, S, H, hd)
-    k = _dense(cfg, Hkv * hd, "k")(h).reshape(B, S, Hkv, hd)
-    v = _dense(cfg, Hkv * hd, "v")(h).reshape(B, S, Hkv, hd)
-    q = rotary(norm("q_norm")(q), positions, cfg.rope_theta)
-    k = rotary(norm("k_norm")(k), positions, cfg.rope_theta)
-    if self.decode:
+    out_proj = lambda: _dense(cfg, cfg.d_model, "o")
+    # In slot mode the whole call is its three parts in turn
+    # (models/gpt.py:SplitLayer), ``h`` from the second on the carry.
+    if part in (None, "pre"):
+      B, S, _ = h.shape
+      norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype, name=name)
+      q = _dense(cfg, H * hd, "q")(h).reshape(B, S, H, hd)
+      k = _dense(cfg, Hkv * hd, "k")(h).reshape(B, S, Hkv, hd)
+      v = _dense(cfg, Hkv * hd, "v")(h).reshape(B, S, Hkv, hd)
+      q = rotary(norm("q_norm")(q), positions, cfg.rope_theta)
+      k = rotary(norm("k_norm")(k), positions, cfg.rope_theta)
+      if not self.decode:
+        return out_proj()(gqa_causal_attention(q, k, v, cfg.dtype).reshape(
+            B, S, H * hd))
       # ``h`` is the step's token-flat batch [T, 1, D]
       # (models/gpt.py:SlotRows); the window write and the attend take
       # their operands as [slots, C, ...].
+      h = (), tuple(rows.to_slots(t[:, 0]) for t in (q, k, v))
+      if part == "pre":
+        return h
+    if part in (None, "mix"):
       ck = self.variable("cache", "cached_key", _missing_slot_cache)
       cv = self.variable("cache", "cached_value", _missing_slot_cache)
       out, ck.value, cv.value = slot_cache_attend(
-          *(rows.to_slots(t[:, 0]) for t in (q, k, v)), ck.value, cv.value,
-          slot_cursors, cfg.dtype, write_impl=self.kv_write_impl,
-          attn_impl=self.slot_attn_impl, num_valid=num_valid)
-      out = rows.to_flat(out)[:, None]
-    else:
-      out = gqa_causal_attention(q, k, v, cfg.dtype)
-    return _dense(cfg, cfg.d_model, "o")(out.reshape(B, S, H * hd))
+          *h[1], ck.value, cv.value, slot_cursors, cfg.dtype,
+          write_impl=self.kv_write_impl, attn_impl=self.slot_attn_impl,
+          num_valid=num_valid)
+      h = (), out
+      if part == "mix":
+        return h
+    return out_proj()(rows.to_flat(h[1]).reshape(-1, 1, H * hd))
 
 
 class Lfm2MoeBlock(nn.Module):
@@ -205,18 +217,23 @@ class Lfm2MoeBlock(nn.Module):
 
   @nn.compact
   def __call__(self, x, positions, slot_cursors=None, num_valid=None,
-               reset=None, rows=None):
+               reset=None, rows=None, part=None, carry=None):
     cfg = self.cfg
     norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype, name=name)
-    h = norm("norm_in")(x)
     if self.kind == ATTENTION:
-      mixed = NormedAttention(
+      # In three parts where the step asks (models/gpt.py:SplitLayer).
+      attn = NormedAttention(
           cfg, decode=self.decode, kv_write_impl=self.kv_write_impl,
-          slot_attn_impl=self.slot_attn_impl, name="attn")(
-              h, positions, slot_cursors, num_valid, rows)
+          slot_attn_impl=self.slot_attn_impl, name="attn")
+      if part == "mix":
+        return attn(carry, positions, slot_cursors, num_valid, rows, part)
+      mixed = attn(carry if part == "post" else norm("norm_in")(x),
+                   positions, slot_cursors, num_valid, rows, part)
+      if part == "pre":
+        return mixed
     else:
       mixed = ShortConv(cfg, decode=self.decode, name="conv")(
-          h, num_valid, reset, rows)
+          norm("norm_in")(x), num_valid, reset, rows)
     x = x + mixed
     h = norm("norm_ff")(x)
     if self.dense:
@@ -267,13 +284,21 @@ class Lfm2Moe(nn.Module):
     tok = Embedding(cfg.vocab_size, cfg.d_model, parallel="none",
                     param_dtype=cfg.param_dtype, name="embed")
     x = tok(ids).astype(cfg.dtype)
-    for i, kind in enumerate(cfg.layer_kinds()):
-      x = Lfm2MoeBlock(cfg, kind, dense=i < cfg.num_dense_layers,
-                       decode=decode, kv_write_impl=kv_write_impl,
-                       slot_attn_impl=slot_attn_impl,
-                       moe_gmm_impl=moe_gmm_impl, name=f"block_{i}")(
-                           x, positions, slot_cursors, num_valid, reset,
-                           rows)
+    def layer(i, kind):
+      block = child_of(lambda parent: Lfm2MoeBlock(
+          cfg, kind, dense=i < cfg.num_dense_layers, decode=decode,
+          kv_write_impl=kv_write_impl, slot_attn_impl=slot_attn_impl,
+          moe_gmm_impl=moe_gmm_impl, name=f"block_{i}", parent=parent))
+      # In slot mode a layer takes each row's position from the map of
+      # the rows it is handed (``slot_layers``).
+      call = lambda mdl, rows, x, **part: block(mdl)(
+          x, positions if rows is None else rows.positions, slot_cursors,
+          num_valid, reset, rows, **part)
+      # An attention layer's K/V window stays outside a two-width step's
+      # conditionals; a convolution's few inputs stand inside.
+      return SplitLayer(call) if kind == ATTENTION else call
+    layers = [layer(i, kind) for i, kind in enumerate(cfg.layer_kinds())]
+    x = slot_layers(self, rows, x, layers)
     if decode:
       # The last norm and the head run on the rows that are read.
       x = rows.head_rows(x)

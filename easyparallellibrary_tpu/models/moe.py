@@ -45,6 +45,7 @@ from flax import linen as nn
 from jax.sharding import PartitionSpec as P
 
 from easyparallellibrary_tpu import constants
+from easyparallellibrary_tpu.ops.layers import HeldParams
 
 
 from easyparallellibrary_tpu.utils.sharding import constrain as _constrain  # noqa: E402
@@ -426,7 +427,7 @@ def dropless_experts(x, chosen, weights, live, w_gate_up, w_down,
   return y.astype(x.dtype), sizes
 
 
-class DroplessMoE(nn.Module):
+class DroplessMoE(HeldParams, nn.Module):
   """Routed experts without capacity beside shared ones, if any:
   ``Shared(x) + sum_i w_i Expert_i(x)``, the routed sum alone where
   ``n_shared_experts`` is 0 (no ``shared`` in the tree then; module

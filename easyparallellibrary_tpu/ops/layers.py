@@ -32,7 +32,7 @@ from typing import Any, Callable, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
-from flax import struct
+from flax import errors, struct
 from jax.sharding import PartitionSpec as P
 
 from easyparallellibrary_tpu import constants
@@ -188,7 +188,51 @@ def _with_padded_partitioning(init: Callable, names,
   return wrapped
 
 
-class Dense(nn.Module):
+class HeldParams:
+  """Mixin before ``nn.Module`` for a module that declares parameters
+  (``self.param(name, init, shape, ...)``): a parameter that already
+  exists (every ``apply`` on a trained or seeded tree) is read and its
+  shape compared with the one asked for, directly.
+
+  flax's own ``param`` makes that check by evaluating the initializer
+  abstractly (``jax.eval_shape`` of a fresh closure, a trace of its own)
+  at EVERY access, about 6 ms each on the chip's host: a serving step of
+  28 layers reads ~330 parameters, and with its position-wise layers at
+  two widths more, seconds of a warm start (PERF.md, PR 41, has them with
+  and without).  Everything else is flax's: the same bookkeeping and the
+  same errors (a name in use, a shape that differs, a parameter declared
+  outside ``setup`` or a compact method), and flax's own way wherever this
+  one does not apply: a parameter that does not exist yet (``init``), a
+  shape that is not the initializer's first argument.  The emitted program
+  is the same to the byte.  tests/test_held_params.py holds every family's
+  serving step to it, so a new module that declares parameters without the
+  mixin is named there."""
+
+  def param(self, name, init_fn, *init_args, unbox: bool = True,
+            **init_kwargs):
+    shape = init_args[0] if init_args else None
+    if (not isinstance(shape, (tuple, list)) or self.scope is None
+        or not self.has_variable("params", name)):
+      return super().param(name, init_fn, *init_args, unbox=unbox,
+                           **init_kwargs)
+    # flax.linen.Module.param, then flax.core.Scope.param, but for the
+    # abstract evaluation
+    if not self._initialization_allowed:
+      raise ValueError("Parameters must be initialized in `setup()` or in a "
+                       "method wrapped in `@compact`")
+    if self._name_taken(name, collection="params"):
+      raise errors.NameInUseError("param", name, self.__class__.__name__)
+    self.scope.reserve(name, "params")
+    value = self.scope.get_variable("params", name)
+    held = nn.meta.unbox(value)
+    if tuple(shape) != jnp.shape(held):
+      raise errors.ScopeParamShapeError(
+          name, self.scope.path_text, jnp.shape(held), tuple(shape))
+    self._state.children[name] = "params"
+    return held if unbox else value
+
+
+class Dense(HeldParams, nn.Module):
   """Dense layer; tensor-parallel when called under a ``split`` scope.
 
   ``parallel``: "auto" (from ambient scope → column), "column", "row", or
@@ -302,7 +346,7 @@ class Dense(nn.Module):
     return y
 
 
-class LayerNorm(nn.LayerNorm):
+class LayerNorm(HeldParams, nn.LayerNorm):
   """LayerNorm with boxed (metadata-carrying) scale/bias, so pipeline
   stacking can shard them over the stage axis."""
   scale_init: Callable = nn.with_partitioning(
@@ -311,7 +355,7 @@ class LayerNorm(nn.LayerNorm):
       nn.initializers.zeros_init(), (None,))
 
 
-class Embedding(nn.Module):
+class Embedding(HeldParams, nn.Module):
   """Token embedding; vocab-sharded under a ``split`` scope.
 
   The reference has no embedding op in its split library (embeddings stay

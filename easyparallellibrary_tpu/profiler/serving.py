@@ -157,11 +157,13 @@ class ServingStats:
     # of the cache a bounded attend reads (kernels/slot_attention.py).
     self.live_kv_rows = 0
     # The fused step's flat batch (serving/engine.py:flat_width): live
-    # rows summed over the steps, positions its width held back, and the
-    # steps in which it held any.
+    # rows summed over the steps, positions its width held back, the
+    # steps in which it held any, and the steps that ran on the narrow
+    # width (serving/engine.py:narrow_width).
     self.flat_positions = 0
     self.flat_trimmed = 0
     self.flat_trimmed_steps = 0
+    self.flat_narrow_steps = 0
     # Dropless expert layers (0 on a model without them): live positions
     # routed, and the sums over the steps that routed of the busiest
     # expert's load over the mean and of the fewest experts a layer
@@ -354,13 +356,14 @@ class ServingStats:
                 routed_positions: int = 0, expert_load_max: float = 0.0,
                 experts_touched_min: float = 0.0, overlapped: int = 0,
                 wasted_positions: int = 0, flat_positions: int = 0,
-                flat_trimmed: int = 0):
+                flat_trimmed: int = 0, flat_narrow: int = 0):
     self.steps += 1
     self.overlapped_steps += int(overlapped)
     self.wasted_positions += int(wasted_positions)
     self.flat_positions += int(flat_positions)
     self.flat_trimmed += int(flat_trimmed)
     self.flat_trimmed_steps += int(flat_trimmed > 0)
+    self.flat_narrow_steps += int(flat_narrow)
     if routed_positions > 0:
       self.routed_positions += int(routed_positions)
       self.expert_steps += 1
@@ -429,7 +432,7 @@ class ServingStats:
   _STATE_SCALARS = (
       "steps", "sampling_steps", "overlapped_steps", "wasted_positions",
       "live_kv_rows", "kv_rows", "flat_positions", "flat_trimmed",
-      "flat_trimmed_steps",
+      "flat_trimmed_steps", "flat_narrow_steps",
       "routed_positions", "expert_steps", "expert_load_sum",
       "experts_touched_sum", "index_rows", "selected_rows", "window_rows",
       "held_assignments", "busy_time_s", "prefill_tokens",
@@ -508,12 +511,15 @@ class ServingStats:
         # the steps: what an attend bounded per slot reads of it.
         "kv_read_share": (self.live_kv_rows / self.kv_rows
                           if self.kv_rows else 0.0),
-        # The fused step's flat batch: live rows a step, and the share
-        # of the steps whose plan its width cut.
+        # The fused step's flat batch: live rows a step, the share of
+        # the steps whose plan its width cut, and the share that ran on
+        # the narrow width.
         "flat_positions_per_step": (self.flat_positions / self.steps
                                     if self.steps else 0.0),
         "flat_trimmed_step_share": (self.flat_trimmed_steps / self.steps
                                     if self.steps else 0.0),
+        "flat_narrow_step_share": (self.flat_narrow_steps / self.steps
+                                   if self.steps else 0.0),
         # Dropless expert layers (0.0 without them): live positions a
         # step routed, the busiest expert's load over the mean (worst
         # layer) and the fewest experts a layer touched (under the
@@ -640,6 +646,9 @@ def fleet_summary(replica_stats: List["ServingStats"],
           sum(s.flat_positions for s in stats) / steps if steps else 0.0),
       "flat_trimmed_step_share": (
           sum(s.flat_trimmed_steps for s in stats) / steps
+          if steps else 0.0),
+      "flat_narrow_step_share": (
+          sum(s.flat_narrow_steps for s in stats) / steps
           if steps else 0.0),
       "routed_positions_per_step": (
           sum(s.routed_positions for s in stats)

@@ -227,10 +227,35 @@ def flat_width(num_slots: int, chunk: int) -> int:
   ceiling never binds).  A function of the two shapes an engine is given
   and of nothing else, for every model and every twin.  The scheduler is
   handed ``T`` as the ceiling of a plan's live positions
-  (serving/scheduler.py ``width``)."""
+  (serving/scheduler.py ``width``).  The same program holds a second,
+  smaller width, derived from ``T`` by :func:`narrow_width` (half of it
+  up to a multiple of 128, never under ``num_slots`` up to one, and only
+  where that halves the rows), which a step whose live positions fit it
+  runs what is position-wise on."""
   round_up = lambda n: -(-n // 128) * 128
   return min(max(round_up(num_slots * chunk // 2), round_up(num_slots)),
              num_slots * chunk)
+
+
+def narrow_width(width: int, num_slots: int) -> int:
+  """Rows ``T_narrow`` of the flat batch's second width, derived as
+  ``flat_width`` is and by every twin alike: HALF of ``width``, up to a
+  multiple of 128, never under ``num_slots`` up to a multiple of 128.
+  Both widths stand in the ONE compiled step (models/gpt.py
+  ``slot_layers``): a step whose live positions fit ``T_narrow``, which
+  the step reads off the ``num_valid`` it is handed, runs what is
+  position-wise on that many rows; the head, the sampler and the mixers
+  that own a leaf over the context are in the program once.  Where the
+  rule does not halve the rows there is one width and no conditional: an
+  engine of few positions, where it gives ``width`` back, and one whose
+  half rounds up to more (384 -> 256: what a third of the position-wise
+  rows can save a step, 1.3 of 24.7 ms in the expert cell, is what that
+  cell's conditionals cost it on every step, 1.2 ms: PERF.md, PR 41).
+  The scheduler's ceiling stays ``width``: no plan is trimmed for the
+  narrow one."""
+  round_up = lambda n: -(-n // 128) * 128
+  narrow = max(round_up(-(-width // 2)), round_up(num_slots))
+  return narrow if 2 * narrow <= width else width
 
 
 def _resolve_mesh(mesh):
@@ -513,10 +538,13 @@ class ContinuousBatchingEngine:
     # it.  0 on a paged engine (``token_budget`` is its width).
     self.flat_width = 0 if self.paged else flat_width(self.num_slots,
                                                       self.chunk)
+    # The second width of the same program (``narrow_width``; equal to
+    # ``flat_width`` where there is none).
+    self.flat_narrow = narrow_width(self.flat_width, self.num_slots)
     if not self.paged:
       trace_lib.get_tracer().metadata(
           f"{self._track_prefix}/flat_width",
-          {"width": self.flat_width,
+          {"width": self.flat_width, "narrow": self.flat_narrow,
            "positions": self.num_slots * self.chunk})
     self.scheduler = FCFSScheduler(
         num_slots=self.num_slots, prefill_chunk=self.chunk,
@@ -743,7 +771,10 @@ class ContinuousBatchingEngine:
                 f"{kv_lib.paged_cache_bytes(cfg, self.num_blocks, self.block_size) / 1e6:.1f} MB")
     else:
       lay = self.cache_layout
-      layout = (f"flat width {self.flat_width}, "
+      layout = (f"flat width {self.flat_width}"
+                + (f" / {self.flat_narrow}"
+                   if self.flat_narrow < self.flat_width else "")
+                + ", "
                 f"contiguous slots kept in {lay['kv_order']}, "
                 f"{self.slot_attn_impl} attend, "
                 f"{self.kv_write_impl} kv write, "
@@ -966,7 +997,7 @@ class ContinuousBatchingEngine:
     from easyparallellibrary_tpu.models.gpt import slot_step_logits
     model = self.model
     C = self.chunk
-    width = self.flat_width
+    width, narrow = self.flat_width, self.flat_narrow
     write_impl = self.kv_write_impl
     attn_impl = self.slot_attn_impl
     scan_impl = self.ssm_scan_impl
@@ -1002,8 +1033,8 @@ class ContinuousBatchingEngine:
       last, kv, *sown = slot_step_logits(
           model, params, kv, tokens, cursors, kv_write_impl=write_impl,
           slot_attn_impl=attn_impl, num_valid=num_valid, stats=experts,
-          width=width, head_pos=jnp.clip(num_valid - 1, 0, C - 1),
-          **state_args)
+          width=width, narrow=narrow,
+          head_pos=jnp.clip(num_valid - 1, 0, C - 1), **state_args)
       step_keys = jax.vmap(jax.random.fold_in)(keys, tok_index)
       nxt = sample_token_slots(last.astype(jnp.float32), step_keys,
                                temperature, top_k, top_p)
@@ -1040,7 +1071,7 @@ class ContinuousBatchingEngine:
     model = self.model
     C = self.chunk
     K = self.drafter.k
-    width = self.flat_width
+    width, narrow = self.flat_width, self.flat_narrow
     write_impl = self.kv_write_impl
     attn_impl = self.slot_attn_impl
 
@@ -1059,7 +1090,7 @@ class ContinuousBatchingEngine:
                                  kv_write_impl=write_impl,
                                  slot_attn_impl=attn_impl,
                                  num_valid=num_valid, width=width,
-                                 head_pos=pos)
+                                 narrow=narrow, head_pos=pos)
       tgt = tgt.astype(jnp.float32)
       dpos = jnp.clip(base[:, None] + jnp.arange(K)[None], 0, C - 1)
       drafts = jnp.take_along_axis(tokens, dpos, axis=1)
@@ -1820,6 +1851,10 @@ class ContinuousBatchingEngine:
     flat_positions = fed_positions + (
         0 if step.num_draft is None else int(step.num_draft.sum()))
     flat_trimmed = plan.flat_trimmed
+    # 1 where the step ran its layers on the narrow width
+    # (``narrow_width``): the step's own predicate, on the host's copy of
+    # the sum it takes it from.
+    flat_narrow = int(flat_positions <= self.flat_narrow < self.flat_width)
     routed_positions = fed_positions if self._experts else 0
     expert_load_max, experts_touched_min, *held = (
         map(float, expert_load) if expert_load is not None else (0.0, 0.0))
@@ -1846,6 +1881,7 @@ class ContinuousBatchingEngine:
       tracer.counter("serving/live_kv_rows", live_kv_rows)
       tracer.counter("serving/flat_positions", flat_positions)
       tracer.counter("serving/flat_trimmed", flat_trimmed)
+      tracer.counter("serving/flat_narrow", flat_narrow)
       if self._recurrent:
         # Slots whose recurrent state this step zeroed: requests that
         # started (or restarted, after a requeue) here.
@@ -1879,7 +1915,8 @@ class ContinuousBatchingEngine:
           expert_load_max=expert_load_max,
           experts_touched_min=experts_touched_min,
           overlapped=overlapped, wasted_positions=plan.wasted,
-          flat_positions=flat_positions, flat_trimmed=flat_trimmed)
+          flat_positions=flat_positions, flat_trimmed=flat_trimmed,
+          flat_narrow=flat_narrow)
       if self._sparse is not None:
         self.stats.note_sparse_step(index_rows, selected_rows, window_rows,
                                     held_assignments)
@@ -1904,6 +1941,7 @@ class ContinuousBatchingEngine:
           "live_kv_rows": live_kv_rows,
           "flat_positions": flat_positions,
           "flat_trimmed": flat_trimmed,
+          "flat_narrow": flat_narrow,
           "prefill_tokens": pf_tokens,
           "decode_tokens": dc_tokens,
           "step_time_s": dt,

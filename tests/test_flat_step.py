@@ -13,9 +13,15 @@ grants the width trimmed; a request finishes with the same tokens whatever
 the width; a step with exactly ``T`` live rows, and one with none, read
 what they should.
 
-Toy widths, float32: 72 slots x chunk 8 is 576 positions on a flat batch
-of 384 (half of them, up to a multiple of 128; a toy engine whose half
+Toy widths, float32: 64 slots x chunk 8 is 512 positions on a flat batch
+of 256 (half of them, a multiple of 128; a toy engine whose half
 rounds up past ``slots x chunk`` runs at full width).
+
+The same program holds a second width, ``narrow_width(256, 64)`` = 128,
+which a step whose live positions fit it runs its layers on
+(``slot_layers``): every engine here has both, so every comparison
+above is also one of the two-width step, and the one-width engine (the
+derivation patched in the test) must serve the same tokens.
 """
 
 import os
@@ -39,16 +45,19 @@ from easyparallellibrary_tpu.serving import (  # noqa: E402
     ContinuousBatchingEngine, Request, engine as engine_lib,
     kv_cache as kv_lib)
 from easyparallellibrary_tpu.testing import chaos  # noqa: E402
+from perfbench.reference import dots3_note as dots3_ref  # noqa: E402
 from perfbench.reference import glm4_moe_lite as glm_ref  # noqa: E402
 from perfbench.reference import jamba as jamba_ref  # noqa: E402
 from perfbench.reference import lfm2_moe as lfm2_ref  # noqa: E402
+from perfbench.runners import epl_dots3_note as dots3_glue  # noqa: E402
 from perfbench.runners import epl_glm4_moe_lite as glm_glue  # noqa: E402
 from perfbench.runners import epl_jamba as jamba_glue  # noqa: E402
 from perfbench.runners import epl_lfm2_moe as lfm2_glue  # noqa: E402
 
 VOCAB = 256
-SLOTS, CHUNK = 72, 8
-WIDTH = 384                       # flat_width(72, 8): 288 up to 128s
+SLOTS, CHUNK = 64, 8
+WIDTH = 256                       # flat_width(64, 8): half of 512
+NARROW = 128                      # narrow_width(256, 64): half again
 F32 = {"dtype": "float32", "param_dtype": "float32"}
 GPT_CFG = GPTConfig(vocab_size=VOCAB, num_layers=2, num_heads=4, d_model=32,
                     d_ff=64, max_seq_len=128, dtype=jnp.float32)
@@ -72,6 +81,20 @@ LFM2_CFG = lfm2_ref.Lfm2MoeConfig(
     num_key_value_heads=2, conv_L_cache=3, num_dense_layers=1, num_experts=8,
     num_experts_per_tok=2, vocab_size=VOCAB, n_positions=128,
     initializer_range=0.1, bias_std=0.05)
+# tests/test_dots3_note.py's toy cut: a selecting layer that keeps 4 rows,
+# window layers behind 5, 3 of 8 experts held.
+DOTS3_CFG = dots3_ref.Dots3NoteConfig(
+    layer_types=(dots3_ref.FULL, dots3_ref.FULL, dots3_ref.SLIDING,
+                 dots3_ref.SLIDING, dots3_ref.SLIDING),
+    hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+    full=dots3_ref.LatentSizes(heads=4, q_rank=32, kv_rank=32, nope=16,
+                               rope=8, value=16, theta=8e7),
+    swa=dots3_ref.LatentSizes(heads=2, q_rank=32, kv_rank=48, nope=24,
+                              rope=8, value=16, theta=5e4),
+    index_n_heads=2, index_head_dim=16, index_topk=4, sliding_window_size=5,
+    router_width=8, experts_first=2, n_routed_experts=3, n_shared_experts=1,
+    num_experts_per_tok=2, first_k_dense_replace=1, vocab_size=VOCAB,
+    n_positions=128, initializer_range=0.2, bias_std=0.05)
 DECODERS = ("gpt2", "hybrid", "glm-experts", "lfm2")
 TOL = dict(rtol=2e-5, atol=2e-5)
 
@@ -85,6 +108,9 @@ def _no_ambient_tracer():
 FAMILIES = {"hybrid": (jamba_glue, jamba_ref, JAMBA_CFG),
             "glm-experts": (glm_glue, glm_ref, GLM_CFG),
             "lfm2": (lfm2_glue, lfm2_ref, LFM2_CFG)}
+# Served in the two-width cases only: the shadow and the oracles above are
+# the four older families'.
+DOTS3 = (dots3_glue, dots3_ref, DOTS3_CFG)
 # What each family's own test file allows between the program's logits and
 # its plain reference's (tests/test_jamba.py, test_glm_moe.py,
 # test_lfm2_moe.py); the GPT-2 block against its own ``[B, S]`` forward.
@@ -99,8 +125,9 @@ def decoders():
   gpt = GPT(GPT_CFG)
   out = {"gpt2": (gpt, gpt.init(jax.random.PRNGKey(0),
                                 jnp.zeros((1, 4), jnp.int32))["params"])}
-  for name, (glue, ref, cfg) in FAMILIES.items():
-    model, shell_of = glue.build_model(cfg, F32)
+  for name, (glue, ref, cfg) in dict(FAMILIES, dots3=DOTS3).items():
+    model, shell_of = glue.build_model(
+        cfg, dict(F32, ring_tile=8) if name == "dots3" else F32)
     out[name] = (model, glue.program_params(
         cfg, ref.seed_key(SEED), shell_of(jnp.zeros((1, 8), jnp.int32))))
   return out
@@ -133,7 +160,7 @@ REQUESTS = 90
 
 
 def _requests():
-  """90 requests over 72 slots.  The first 72 bring prompts of two to six
+  """90 requests over 64 slots.  The first 64 bring prompts of two to six
   chunks, so the first steps hold more prefill than the width takes; the
   rest bring prompts from under a chunk to five of them into slots used a
   second time, so later steps mix chunks with decodes."""
@@ -262,24 +289,78 @@ def test_every_step_commits_what_full_width_commits(decoders, name):
   assert eng._step_fn._cache_size() == 1
 
 
-@pytest.mark.parametrize("name", DECODERS)
-def test_requests_finish_the_same_whatever_the_width(decoders, name,
-                                                     monkeypatch):
-  flat = _engine(decoders, name)
-  got = _drive(flat, _requests())
+def _twin(decoders, name, twin):
+  from easyparallellibrary_tpu.serving.speculative import NgramDrafter
+  return _engine(decoders, name, **{
+      "plain": {}, "guarded": dict(resilience=True),
+      "spec": dict(drafter=NgramDrafter(k=3, ngram_max=3))}[twin])
+
+
+def _two_bursts(eng):
+  """``_requests()``, whose first plans fill the width and whose later
+  ones fit the narrow one, then a second slot's worth of long prompts at
+  once into the drained engine: the plans cross ``NARROW`` downwards and
+  upwards again."""
+  _drive(eng, _requests())
+  rng = np.random.RandomState(5)
+  return _drive(eng, [
+      Request(uid=REQUESTS + i, max_new_tokens=3,
+              prompt=rng.randint(0, VOCAB, (n,)).astype(np.int32))
+      for i, n in enumerate(rng.randint(2 * CHUNK + 1, 5 * CHUNK, SLOTS))])
+
+
+# Against the engine at FULL width (the plan is never trimmed), and, two
+# widths against ONE (``narrow_width`` patched to give the width back: the
+# same plans, the same tokens), each family and each twin.
+WIDTH_CASES = [(name, "plain", "full") for name in DECODERS] + [
+    (name, "plain", "one") for name in DECODERS + ("dots3",)] + [
+        ("gpt2", "spec", "one"), ("gpt2", "guarded", "one")]
+
+
+@pytest.mark.parametrize("name,twin,oracle", WIDTH_CASES)
+def test_requests_finish_the_same_whatever_the_width(decoders, name, twin,
+                                                     oracle, monkeypatch):
+  if oracle == "one":
+    tracer = trace_lib.install(trace_lib.Tracer(enabled=True))
+  flat = _twin(decoders, name, twin)
+  assert (flat.flat_width, flat.flat_narrow) == (WIDTH, NARROW)
+  lives = []
+  real = flat._step_fn
+  flat._step_fn = lambda *a: (lives.append(int(np.asarray(a[4]).sum())),
+                              real(*a))[1]
+  got = _two_bursts(flat) if oracle == "one" else _drive(flat, _requests())
   assert flat.stats.flat_trimmed > 0
-  monkeypatch.setattr(engine_lib, "flat_width",
-                      lambda slots, chunk: slots * chunk)
-  full = _engine(decoders, name)
-  assert full.flat_width == SLOTS * CHUNK
-  assert full.scheduler.width == SLOTS * CHUNK
-  want = _drive(full, _requests())
-  assert full.stats.flat_trimmed == 0
+  assert real._cache_size() == 1
+  if oracle == "full":
+    monkeypatch.setattr(engine_lib, "flat_width",
+                        lambda slots, chunk: slots * chunk)
+    other = _twin(decoders, name, twin)
+    assert other.flat_width == other.scheduler.width == SLOTS * CHUNK
+    want = _drive(other, _requests())
+    assert other.stats.flat_trimmed == 0
+    # the trimmed requests took more steps and no other tokens
+    assert flat._steps >= other._steps
+  else:
+    # ``serving/flat_narrow`` counts exactly the steps whose live positions
+    # fit the narrow width, and the plans crossed it in both directions
+    narrow = [int(live <= NARROW) for live in lives]
+    assert [e["args"]["value"] for e in tracer.events()
+            if e["ph"] == "C" and e["name"] == "serving/flat_narrow"] == narrow
+    moves = set(zip(narrow, narrow[1:]))
+    assert {(0, 1), (1, 0)} <= moves
+    assert flat.stats.flat_narrow_steps == sum(narrow)
+    assert flat.stats.summary()["flat_narrow_step_share"] == pytest.approx(
+        sum(narrow) / len(narrow))
+    monkeypatch.setattr(engine_lib, "narrow_width",
+                        lambda width, num_slots: width)
+    other = _twin(decoders, name, twin)
+    assert other.flat_narrow == other.flat_width == WIDTH
+    want = _two_bursts(other)
+    assert other.stats.flat_narrow_steps == 0
+    assert other._steps == flat._steps
   assert got.keys() == want.keys()
   for uid in want:
     np.testing.assert_array_equal(got[uid], want[uid], err_msg=str(uid))
-  # the trimmed requests took more steps and no other tokens
-  assert flat._steps >= full._steps
 
 
 @pytest.mark.parametrize("name", DECODERS)
@@ -297,10 +378,10 @@ def test_a_step_with_exactly_the_width_live_and_one_with_nothing(decoders,
           head_pos=head_pos, **state), static_argnums=4)
   rng = np.random.RandomState(11)
   tokens = jnp.asarray(rng.randint(0, VOCAB, (SLOTS, CHUNK)), jnp.int32)
-  # 47 whole chunks, a decode, 7 positions, idle slots: 384 exactly, the
+  # 31 whole chunks, a decode, 7 positions, idle slots: 256 exactly, the
   # last fed slot ending on row T - 1
   nv = np.zeros((SLOTS,), np.int32)
-  nv[:47], nv[64], nv[68] = CHUNK, 1, 7
+  nv[:31], nv[48], nv[57] = CHUNK, 1, 7
   assert nv.sum() == WIDTH
   nv = jnp.asarray(nv)
   kv, cur = kv_lib.allocate_kv_cache(model.cfg, SLOTS, CHUNK)
@@ -326,6 +407,97 @@ def test_a_step_with_exactly_the_width_live_and_one_with_nothing(decoders,
   _assert_same_state(after, got_kv, nv)
 
 
+# Layers whose mixer owns a leaf that grows with the context and stands
+# outside the conditionals (models/gpt.py:SplitLayer): the hybrid's one
+# attention layer of four, every latent layer, LFM2's attention layer
+# between two convolutions.  A GPT-2 block's K/V pair stands inside.
+SPLIT = {"gpt2": 0, "hybrid": 1, "glm-experts": 3, "lfm2": 1, "dots3": 5}
+
+
+def _conditionals(jaxpr):
+  """Every ``cond`` equation of ``jaxpr``, those of inner jaxprs too."""
+  for eqn in jaxpr.eqns:
+    if eqn.primitive.name == "cond":
+      yield eqn
+    for sub in jax.core.jaxprs_in_params(eqn.params):
+      yield from _conditionals(sub)
+
+
+@pytest.mark.parametrize("name", DECODERS + ("dots3",))
+def test_the_second_width_stands_round_the_layers_and_nothing_else(
+    decoders, name, monkeypatch):
+  """What the second width may cost in set-up is the position-wise layers,
+  traced and lowered at two row counts (PERF.md, PR 41); everything else is
+  in the program once.  In the step as the engine builds it: one
+  conditional more than the split layers (the runs between their mixers),
+  above what the one-width step has (none where the derivation gives the
+  width back); no cache leaf of a split layer is what a conditional
+  returns, so none is copied for one; the head's matrix product and the
+  sampler's sort stand there once, on ``[slots, ..]`` rows."""
+  from easyparallellibrary_tpu.observability import device as device_lib
+
+  def program():
+    eng = _engine(decoders, name)
+    specs = []
+    real, note = eng._step_fn, eng._note_step_specs
+    eng._note_step_specs = lambda args: (
+        specs.append(device_lib.specs_of(args)), note(args))[1]
+    eng.submit(Request(uid=0, prompt=np.arange(1, 7, dtype=np.int32),
+                       max_new_tokens=3))
+    eng.run()
+    assert real._cache_size() == 1
+    text = real.lower(*specs[0]).as_text()
+    count = lambda pattern: len(re.findall(pattern, text))
+    conds = list(_conditionals(jax.make_jaxpr(real)(*specs[0]).jaxpr))
+    return eng, conds, (
+        count(rf"stablehlo\.dot_general .* -> tensor<{SLOTS}x{VOCAB}xf32>"),
+        count(r"stablehlo\.sort"), count(r"stablehlo\.case"))
+
+  eng, conds, (heads, sorts, cases) = program()
+  assert (eng.flat_width, eng.flat_narrow) == (WIDTH, NARROW)
+  split_leaves = {
+      leaf.shape for path, leaf in jax.tree_util.tree_leaves_with_path(
+          eng._kv) if SPLIT[name] and re.search(
+              r"attn|latent", jax.tree_util.keystr(path))}
+  assert bool(split_leaves) == bool(SPLIT[name])
+  returned = {v.aval.shape for eqn in conds for v in eqn.outvars}
+  assert not split_leaves & returned, split_leaves & returned
+  if name == "gpt2":        # what the check sees where a leaf does stand inside
+    assert {leaf.shape for leaf in jax.tree_util.tree_leaves(eng._kv)
+            } <= returned
+  monkeypatch.setattr(engine_lib, "narrow_width",
+                      lambda width, num_slots: width)
+  eng, _, (heads_one, sorts_one, cases_one) = program()
+  assert eng.flat_narrow == WIDTH
+  assert heads == heads_one == 1
+  if name in ("gpt2", "hybrid"):     # an expert layer sorts its assignments
+    assert sorts == sorts_one == 1
+  assert cases - cases_one == SPLIT[name] + 1
+
+
+@pytest.mark.parametrize("name", DECODERS + ("dots3",))
+def test_a_step_reads_its_parameters_without_evaluating_an_initializer(
+    decoders, name, monkeypatch):
+  """Tracing a family's fused step asks flax's own ``Scope.param`` for
+  nothing: every module that declares parameters reads the ones that exist
+  through ``ops/layers.py:HeldParams`` (tests/test_held_params.py), so no
+  initializer is evaluated abstractly a parameter an access, at either
+  width.  A module that declares one without the mixin is named here."""
+  from flax.core import scope as scope_lib
+  seen, real = [], scope_lib.Scope.param
+
+  def param(self, name, *args, **kwargs):
+    seen.append("/".join(self.path + (name,)))
+    return real(self, name, *args, **kwargs)
+  monkeypatch.setattr(scope_lib.Scope, "param", param)
+  eng = _engine(decoders, name)
+  eng.submit(Request(uid=0, prompt=np.arange(1, 7, dtype=np.int32),
+                     max_new_tokens=2))
+  eng.run()
+  assert eng._step_fn._cache_size() == 1
+  assert not seen, sorted(set(seen))
+
+
 # ------------------------------------------------------------ the scheduler --
 
 
@@ -342,6 +514,25 @@ def test_the_width_follows_slots_and_chunk_alone():
   # ... and full, where the map is a reshape, wherever the half rounds up
   # past every position
   assert (width(4, 8), width(8, 16), width(7, 16)) == (32, 128, 112)
+  # the second width: half of the first up to a multiple of 128, never
+  # under a row a slot up to one; the serving cells' five geometries
+  narrow = lambda slots, chunk: engine_lib.narrow_width(
+      width(slots, chunk), slots)
+  assert [narrow(*g) for g in ((96, 16), (128, 8), (96, 8), (128, 16),
+                               (32, 32))] == [384, 256, 384, 512, 256]
+  assert narrow(SLOTS, CHUNK) == NARROW
+  # where a row a slot, or half the width, rounds up to MORE than half the
+  # width (the expert cell's 384 -> 256 above, 72 x 8 the same) or to the
+  # width itself there is one width, and the step is built without a
+  # conditional
+  for slots, chunk in ((96, 8), (72, 8), (256, 1), (300, 2), (130, 2),
+                       (4, 8), (8, 16)):
+    assert narrow(slots, chunk) == width(slots, chunk)
+  assert narrow(200, 4) == 256 < width(200, 4)
+  from easyparallellibrary_tpu.models.gpt import slot_rows
+  some = jnp.ones((130,), jnp.int32)
+  assert slot_rows(some, some, 130, 2, width=256, narrow=256).narrow is None
+  assert slot_rows(some, some, 130, 2, width=256, narrow=128).narrow == 128
   for slots, chunk in ((96, 16), (300, 2), (17, 16), (1, 1)):
     assert slots <= width(slots, chunk) <= slots * chunk
 
@@ -458,7 +649,8 @@ def test_the_step_says_what_it_held_and_what_the_width_held_back(decoders,
                         if e["ph"] == "C" and e["name"] == n]
   meta = [e["args"] for e in events
           if e["ph"] == "M" and e["name"] == "serving/flat_width"]
-  assert meta == [{"width": WIDTH, "positions": SLOTS * CHUNK}]
+  assert meta == [{"width": WIDTH, "narrow": NARROW,
+                   "positions": SLOTS * CHUNK}]
   assert counters("serving/flat_positions") == lives
   assert max(lives) == WIDTH
   trimmed = counters("serving/flat_trimmed")
@@ -559,9 +751,9 @@ def test_flat_logits_are_the_plain_references(decoders, plain_logits, name):
   got = np.zeros((SLOTS, S, VOCAB), np.float32)
   step = 0
   while (fed < S).any():
-    # a wave of 40 slots, moving by 24 a step; every third slot of it
+    # a wave of 34 slots, moving by 20 a step; every third slot of it
     # feeds five positions where it could feed eight
-    wave = (np.arange(SLOTS) - 24 * step) % SLOTS < 40
+    wave = (np.arange(SLOTS) - 20 * step) % SLOTS < 34
     nv = np.where(wave, np.minimum(
         np.where(np.arange(SLOTS) % 3 == step % 3, 5, CHUNK), S - fed), 0)
     assert 0 < nv.sum() <= WIDTH
@@ -585,7 +777,7 @@ def test_flat_logits_are_the_plain_references(decoders, plain_logits, name):
 
 def _wide_vocab_gpt():
   """A GPT-2 block whose head is most of its work: over every position
-  of 72 x 8 it alone would cost more than the whole flat step."""
+  of 64 x 8 it alone would cost more than the whole flat step."""
   cfg = GPTConfig(vocab_size=4096, num_layers=2, num_heads=4, d_model=32,
                   d_ff=64, max_seq_len=128, dtype=jnp.float32)
   model = GPT(cfg)
@@ -650,7 +842,7 @@ def _served(eng, requests):
 
 
 def test_the_speculating_and_the_guarded_twin_on_the_narrow_batch(decoders):
-  """Both twins at 72 x 8 on 384 rows, a burst that outruns the width: the
+  """Both twins at 64 x 8 on 256 rows, a burst that outruns the width: the
   speculating engine's drafts ride the rows the plan leaves and its greedy
   streams are the plain engine's; the guarded engine convicts the slot
   whose logits a fault poisons, retries it, and serves the same streams."""

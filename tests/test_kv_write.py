@@ -15,6 +15,7 @@ window unwritten.
 
 import dataclasses
 import importlib
+import os
 import re
 
 import jax
@@ -746,6 +747,25 @@ def test_moe_gmm_compiles_for_v5e_at_the_cells_shapes(one_chip, highest,
     assert len(calls) == 1 and "moe_gmm" in calls[0].split("=")[0], calls
 
 
+def _assert_no_leaf_copied(text, kv):
+  """No instruction of the compiled program ``text`` copies a cache leaf
+  of ``kv`` of 16 MiB or more (a window over the context, a recurrence's
+  state; a convolution's few inputs are selected anew every step), in
+  whatever order of its dimensions: such a leaf is written in place and
+  read where it lies."""
+  names = {"bfloat16": "bf16", "float32": "f32"}
+  leaves = {(names[str(leaf.dtype)], leaf.size)
+            for leaf in jax.tree_util.tree_leaves(kv)
+            if leaf.size * leaf.dtype.itemsize >= 1 << 24}
+  assert leaves
+  for dtype, dims in re.findall(r"= (\w+)\[([\d,]+)\]\S* copy(?:-start)?\(",
+                                text):
+    size = 1
+    for d in dims.split(","):
+      size *= int(d)
+    assert (dtype, size) not in leaves, f"{dtype}[{dims}] is copied"
+
+
 def _abstract_step(model, slots, C, one_chip, **engine):
   """The plain fused step as the engine builds it for ``model`` at
   ``slots x C``, with every kernel's Pallas lowering, and abstract
@@ -754,7 +774,8 @@ def _abstract_step(model, slots, C, one_chip, **engine):
   scan's lowering)."""
   import types
   from flax import linen as nn
-  from easyparallellibrary_tpu.serving.engine import flat_width
+  from easyparallellibrary_tpu.serving.engine import (
+      flat_width, narrow_width)
   on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                            sharding=one_chip)
   params = jax.tree_util.tree_map(on_chip, nn.meta.unbox(jax.eval_shape(
@@ -764,7 +785,9 @@ def _abstract_step(model, slots, C, one_chip, **engine):
       on_chip, kv_lib.cache_leaves(model.cfg, slots, C))
   engine = types.SimpleNamespace(**{**dict(
       model=model, num_slots=slots, chunk=C,
-      flat_width=flat_width(slots, C), kv_write_impl="pallas",
+      flat_width=flat_width(slots, C),
+      flat_narrow=narrow_width(flat_width(slots, C), slots),
+      kv_write_impl="pallas",
       slot_attn_impl="pallas", ssm_scan_impl=None, _recurrent=False,
       moe_gmm_impl=None, _experts=False, dsa_index_impl=None,
       _jit_step=lambda step, donate, **kw: jax.jit(
@@ -780,29 +803,42 @@ def _abstract_step(model, slots, C, one_chip, **engine):
       spec((slots,), f32), spec((slots,), i32), spec((slots,), f32))
 
 
-def test_expert_step_compiled_for_v5e_holds_its_three_kernels(one_chip):
+@pytest.mark.parametrize("C", [8, 16], ids=["one_width", "two_widths"])
+def test_expert_step_compiled_for_v5e_holds_its_three_kernels(one_chip, C):
   """The fused step of a two-layer cut (one dense, one expert layer) of
-  models/glm_moe.py at GLM-4.7-Flash's widths, 96 slots x chunk 8,
-  compiled for a described v5e as the engine builds it: one ``kv_write``
-  and one ``slot_attn`` a layer over ONE latent leaf, two ``moe_gmm`` an
-  expert layer, no copy of a ``[96, 4104, 1, 576]`` leaf in either order
-  of its dimensions, no ``[positions, 64, ..]`` dispatch tensor, no
-  ``while`` loop (a scatter or a binary search would be one)."""
+  models/glm_moe.py at GLM-4.7-Flash's widths and 96 slots, compiled for a
+  described v5e as the engine builds it: one ``kv_write`` and one
+  ``slot_attn`` a layer over ONE latent leaf, two ``moe_gmm`` an expert
+  layer; no copy of a ``[96, 4096 + C, 1, 576]`` leaf in either order of
+  its dimensions, no ``[positions, 64, ..]`` dispatch tensor, no ``while``
+  loop (a scatter or a binary search would be one).  At its cell's chunk
+  of 8 the step has ONE width (384 rows, whose half rounds up to 256: more
+  than half, serving/engine.py ``narrow_width``); at a chunk of 16 it has
+  two (768 / 384), the write and the attend outside the conditionals and
+  in the program ONCE (models/gpt.py ``SplitLayer``), the expert layer's
+  ``moe_gmm`` on either side of one.  (Not at 128 slots x 8: there the
+  ONE-width program copies the leaf four times too.)"""
   from easyparallellibrary_tpu.models.glm_moe import GlmMoe, GlmMoeConfig
+  from easyparallellibrary_tpu.serving.engine import (
+      flat_width, narrow_width)
   epl.init()
-  slots, C = 96, 8
+  slots, Lc = 96, 4096 + C
+  widths = 1 + (narrow_width(flat_width(slots, C), slots)
+                < flat_width(slots, C))
+  assert widths == {8: 1, 16: 2}[C]
   cfg = GlmMoeConfig(num_layers=2, vocab_size=32768)
   step, args = _abstract_step(GlmMoe(cfg), slots, C, one_chip,
                               moe_gmm_impl="pallas", _experts=True)
   text = _compiled_text(step, *args)
   calls = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
   assert (calls("kv_write"), calls("slot_attn"), calls("moe_gmm")) == (
-      2, 2, 2), text.count("tpu_custom_call")
+      2, 2, 2 * widths), text.count("tpu_custom_call")
   assert " while(" not in text
+  _assert_no_leaf_copied(text, args[1])
   for line in text.splitlines():
     m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) (\S+?)\(", line)
-    if m and (m.group(2).startswith(f"bf16[{slots},4104,1,576]")
-              or m.group(2).startswith(f"bf16[{slots},1,576,4104]")):
+    if m and (m.group(2).startswith(f"bf16[{slots},{Lc},1,576]")
+              or m.group(2).startswith(f"bf16[{slots},1,576,{Lc}]")):
       assert m.group(3) in ("parameter", "bitcast", "get-tuple-element",
                             "custom-call"), line
   assert not re.search(rf"\[{slots * C},64,\d+", text)
@@ -814,7 +850,8 @@ def test_lfm2_step_compiled_for_v5e_holds_its_three_kernels(one_chip):
   compiled for a described v5e as the engine builds it for a model with
   recurrent state AND routed experts: one ``kv_write`` and one
   ``slot_attn`` over the ``[128, 4112, 512]`` leaf kept in rows (8 K/V
-  heads of 64 under 32 query heads), two ``moe_gmm``, no copy or
+  heads of 64 under 32 query heads), outside the step's conditionals and
+  in the program once; two ``moe_gmm`` on either side of one; no copy or
   transpose of a leaf, no ``while`` loop (the window is advanced by
   selects)."""
   from easyparallellibrary_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
@@ -828,8 +865,9 @@ def test_lfm2_step_compiled_for_v5e_holds_its_three_kernels(one_chip):
   text = _compiled_text(step, *args)
   calls = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
   assert (calls("kv_write"), calls("slot_attn"), calls("moe_gmm")) == (
-      1, 1, 2), text.count("tpu_custom_call")
+      1, 1, 4), text.count("tpu_custom_call")
   assert " while(" not in text
+  _assert_no_leaf_copied(text, args[1])
   for line in text.splitlines():
     m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) (\S+?)\(", line)
     if m and m.group(2).startswith(f"bf16[{slots},4112,"):
@@ -842,12 +880,14 @@ def test_dots3_step_compiled_for_v5e_holds_its_kernels(one_chip):
   layer + 32 held experts of 256) of models/dots3_note.py at
   dots3-note-prev's widths and its cell's geometry, 32 slots x chunk 32 at
   a context of 12,800, compiled for a described v5e as the engine builds
-  it: three ``kv_write`` (the latent leaf, the index leaf in rows, the
-  ring), two each of ``dsa_index``, ``slot_attn_sel`` and ``slot_attn_win``
-  (the slots that feed several positions, then the decoding ones), two
-  ``moe_gmm``; no copy of a cache leaf in either order of its
-  dimensions; no ``[slots, chunk, heads, Lc]`` score tensor; the one
-  ``while`` is the threshold's 32 counting passes."""
+  it, at two widths: three ``kv_write`` (the latent leaf, the index leaf in
+  rows, the ring), two each of ``dsa_index``, ``slot_attn_sel`` and
+  ``slot_attn_win`` (the slots that feed several positions, then the
+  decoding ones), outside the conditionals and in the program ONCE
+  (models/gpt.py ``SplitLayer``), as the one ``while``, the threshold's 32
+  counting passes; two ``moe_gmm`` on either side of a conditional; no copy
+  of a cache leaf in either order of its dimensions; no ``[slots, chunk,
+  heads, Lc]`` score tensor."""
   from easyparallellibrary_tpu.models.dots3_note import (
       FULL, SLIDING, Dots3Note, Dots3NoteConfig)
   epl.init()
@@ -861,8 +901,9 @@ def test_dots3_step_compiled_for_v5e_holds_its_kernels(one_chip):
   calls = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
   assert [calls(n) for n in ("kv_write", "dsa_index", "slot_attn_sel",
                              "slot_attn_win", "moe_gmm", "slot_attn")] == [
-      3, 2, 2, 2, 2, 0], text.count("tpu_custom_call")
+      3, 2, 2, 2, 4, 0], text.count("tpu_custom_call")
   assert text.count(" while(") == 1
+  _assert_no_leaf_copied(text, args[1])
   leaves = [f"bf16[{slots},12832,1,576]", f"bf16[{slots},1,576,12832]",
             f"bf16[{slots},12832,128]", f"bf16[{slots},640,1,1088]",
             f"bf16[{slots},1,1088,640]"]
@@ -875,9 +916,11 @@ def test_dots3_step_compiled_for_v5e_holds_its_kernels(one_chip):
 
 
 def _flat_cuts():
-  """Two-layer cuts of the four decoders at their cells' widths and
-  geometry: ``name -> (model, slots, C, engine attributes, kernel calls a
-  step (kv_write, slot_attn, ssm_scan, moe_gmm), vocabulary)``."""
+  """Two-layer cuts of the four decoders at their cells' widths and, but
+  for the expert decoder's chunk, geometry: ``name -> (model, slots, C, engine attributes, kernel calls a
+  two-width step (kv_write, slot_attn, ssm_scan, moe_gmm): once what a
+  split layer's mixer calls, twice what stands in a conditional,
+  models/gpt.py ``slot_layers``), vocabulary)``."""
   from easyparallellibrary_tpu.models.glm_moe import GlmMoe, GlmMoeConfig
   from easyparallellibrary_tpu.models.jamba import Jamba, JambaConfig
   from easyparallellibrary_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
@@ -885,17 +928,18 @@ def _flat_cuts():
   return {
       "gpt2m": (GPT(GPTConfig(vocab_size=50304, num_layers=2, num_heads=16,
                               d_model=1024, d_ff=4096, max_seq_len=1024)),
-                96, 16, {}, (2, 2, 0, 0), 50304),
+                96, 16, {}, (4, 4, 0, 0), 50304),
       "jamba2": (Jamba(JambaConfig(num_layers=2, attn_layer_period=2,
                                    attn_layer_offset=1, vocab_size=32768)),
                  128, 8, dict(_recurrent=True, ssm_scan_impl="pallas"),
-                 (1, 1, 1, 0), 32768),
+                 (1, 1, 2, 0), 32768),
+      # at a chunk of 16: its cell's 96 x 8 has one width
       "glm47": (GlmMoe(GlmMoeConfig(num_layers=2, vocab_size=32768)),
-                96, 8, experts, (2, 2, 0, 2), 32768),
+                96, 16, experts, (2, 2, 0, 4), 32768),
       "lfm2": (Lfm2Moe(Lfm2MoeConfig(
           layer_types=("conv", "full_attention"), num_dense_layers=1,
           vocab_size=32768)), 128, 16, dict(_recurrent=True, **experts),
-               (1, 1, 0, 2), 32768)}
+               (1, 1, 0, 4), 32768)}
 
 
 @pytest.mark.parametrize("name", ["gpt2m", "jamba2", "glm47", "lfm2"])
@@ -903,12 +947,17 @@ def test_flat_step_for_v5e_multiplies_the_width_and_heads_the_slots(
     one_chip, name):
   """The plain fused step as the engine builds it, lowered and compiled
   for a described v5e: every matrix product of a position-wise layer has
-  ``flat_width(slots, C)`` rows and none ``slots x C``, the head's has
-  ``slots``, and the kernels are called as often a step as they were.  The
+  ``flat_width(slots, C)`` rows or, in the narrow side of the layers'
+  conditional, ``narrow_width`` of them, as many of the one as of the
+  other, and none ``slots x C``; the head's has ``slots`` and stands there
+  once; a split layer's kernels stand there once, outside the conditionals,
+  a whole layer's on either side; no cache leaf is copied.  The
   one exception is named: a Mamba layer's two small projections between
   its convolution and its scan (``x_proj``: 5120 -> 192, ``dt_proj``: 160
-  -> 5120) stay with the ``[slots, C, ..]`` operands the scan takes."""
-  from easyparallellibrary_tpu.serving.engine import flat_width
+  -> 5120) stay with the ``[slots, C, ..]`` operands the scan takes, on
+  either side."""
+  from easyparallellibrary_tpu.serving.engine import (
+      flat_width, narrow_width)
   epl.init()
   model, slots, C, engine, kernel_calls, vocab = _flat_cuts()[name]
   step, args = _abstract_step(model, slots, C, one_chip, **engine)
@@ -926,12 +975,72 @@ def test_flat_step_for_v5e_multiplies_the_width_and_heads_the_slots(
   wide = [(lhs, out) for lhs, out in dots
           if lhs[0] == slots * C or lhs[:2] == [slots, C]]
   if name == "jamba2":
-    assert sorted(out for _, out in wide) == [192, 5120], wide
+    assert sorted(out for _, out in wide) == [192, 192, 5120, 5120], wide
   else:
     assert not wide, wide
   assert sum(lhs[0] == T for lhs, _ in dots) >= 8, dots
+  narrow = narrow_width(T, slots)
+  assert narrow < T
+  assert (sum(lhs[0] == narrow for lhs, _ in dots)
+          == sum(lhs[0] == T for lhs, _ in dots)), dots
   text = _compiled_text(step, *args)
   calls = lambda kernel: len(re.findall(rf"%{kernel}[.\d]* = ", text))
   assert tuple(calls(k) for k in (
       "kv_write", "slot_attn", "ssm_scan", "moe_gmm")) == kernel_calls
   assert " while(" not in text
+  _assert_no_leaf_copied(text, args[1])
+
+
+
+@pytest.mark.parametrize("cell,chunk", [
+    ("gpt2m-chat-steady", None), ("jamba2-3b-reasoning-backlog", None),
+    ("glm47flash-agent-backlog", 16),
+    ("lfm2moe-chat-steady", None), ("dots3note-longdoc-backlog", None)])
+def test_a_serving_cells_step_compiled_for_v5e_copies_no_leaf(one_chip, cell,
+                                                              chunk):
+  """The step of each serving configuration of the benchmark AT ITS FULL
+  DEPTH and geometry, as the engine builds it, compiled for a described
+  v5e: no cache leaf is copied, on either side of a conditional or
+  outside one.  A split layer's leaves are no conditional's to change
+  (models/gpt.py ``SplitLayer``); GPT-2's K/V pairs do stand inside one,
+  all 24 layers of them, where the compiler writes them in place: this is
+  the guard of that (with every mixer inside one conditional it copied
+  leaves of 100 to 540 MB in the four other configurations: PERF.md,
+  PR 41).  The expert cell's own geometry has one width (96 x 8:
+  tests/test_flat_step.py); its decoder is compiled at a chunk of 16,
+  where it has two."""
+  root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+  from easyparallellibrary_tpu.serving.engine import (
+      flat_width, narrow_width)
+  from perfbench.harness import manifest as manifest_lib
+  epl.init()
+  man = manifest_lib.Manifest(root)
+  cell_file = man.cell_file(cell)
+  config_file = man.config_file(man.workload(cell)["config"])
+  engine = {}
+  if cell_file["runner"] == "serve":
+    from perfbench.reference import gpt2
+    from perfbench.runners import epl_gpt
+    model = GPT(epl_gpt.gpt_config(gpt2.GPT2Config.from_file(config_file),
+                                   cell_file["model"]))
+  else:
+    family = cell_file["family"]
+    glue = importlib.import_module(f"perfbench.runners.epl_{family}")
+    model, _ = glue.build_model(glue.ref_config(config_file),
+                                cell_file["model"])
+    engine = dict(_recurrent=kv_lib.has_recurrent_state(model.cfg))
+    if family == "jamba":
+      engine.update(ssm_scan_impl="pallas")
+    else:
+      engine.update(moe_gmm_impl="pallas", _experts=True)
+    if family == "dots3_note":
+      engine.update(dsa_index_impl="pallas")
+  slots = cell_file["engine"]["num_slots"]
+  C = chunk or cell_file["engine"]["prefill_chunk"]
+  T = flat_width(slots, C)
+  assert narrow_width(T, slots) < T
+  step, args = _abstract_step(model, slots, C, one_chip, **engine)
+  text = _compiled_text(step, *args)
+  # more than the sampler's two conditionals: the layers'
+  assert text.count(" conditional(") > 2
+  _assert_no_leaf_copied(text, args[1])

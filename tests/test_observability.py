@@ -533,8 +533,8 @@ def test_events_a_step_do_not_depend_on_live_slots(tiny_gpt, phase_tracer):
   sixteen = _events_of_one_decode_step(tiny_gpt, phase_tracer, 16)
   # plan, device_step + dispatch + fetch, commit, publish: 12 B/E events;
   # active_slots, overlapped_steps, wasted_positions, sampled_slots,
-  # live_kv_rows, flat_positions, flat_trimmed: 7 counters
-  assert two == sixteen == 19
+  # live_kv_rows, flat_positions, flat_trimmed, flat_narrow: 8 counters
+  assert two == sixteen == 20
 
 
 def test_disabled_tracer_stamps_and_keeps_nothing(tiny_gpt, monkeypatch):
