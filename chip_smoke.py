@@ -109,6 +109,8 @@ from easyparallellibrary_tpu.models.dots3_note import (
 from easyparallellibrary_tpu.models.glm_moe import GlmMoe, GlmMoeConfig
 from easyparallellibrary_tpu.models.jamba import MAMBA, Jamba, JambaConfig
 from easyparallellibrary_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
+from easyparallellibrary_tpu.models.smallthinker import (
+    SmallThinker, SmallThinkerConfig)
 from easyparallellibrary_tpu.models.gpt import (
     _dense_causal_attention, generate, gpt_loss, make_gpt_train_step)
 from easyparallellibrary_tpu.observability.device import specs_of
@@ -181,6 +183,9 @@ class Sizes:
   dots3_cfg: Dots3NoteConfig      # full + dense, full + experts, window
   dots3_shapes: tuple             # (slots, Lc, chunk, ring rows) of the
                                   # kernels' checks
+  smallthinker_cfg: SmallThinkerConfig   # one period, the window cut
+  smallthinker_cell: tuple        # (cell's config, slots, chunk): the
+                                  # kernels' checks and the rules' report
 
   @staticmethod
   def real() -> "Sizes":
@@ -245,7 +250,21 @@ class Sizes:
         # The cell's leaves (12,800 + 32 positions, rings of 640 rows) at
         # its chunk, on 8 slots (the references' score tensors for 32
         # would not fit).
-        dots3_shapes=(8, 12832, 32, 640))
+        dots3_shapes=(8, 12832, 32, 640),
+        # SmallThinker-21BA3B's widths, one period (a full layer without
+        # positions, three window layers with rotary), every one of its 64
+        # experts; vocabulary and context cut as above, and the window cut
+        # to 129 (a ring of 256 rows) so that a request of a few hundred
+        # positions wraps it.  The kernels and the rules at the cell's own
+        # geometry: the published two periods in bfloat16, 48 slots x chunk
+        # 32, window 4096 (rings of 4,224 rows), context 16384.
+        smallthinker_cfg=SmallThinkerConfig(
+            vocab_size=32768, window_layout=(0, 1, 1, 1),
+            rope_layout=(0, 1, 1, 1), sliding_window=129, max_seq_len=1024,
+            dtype=jnp.float32, param_dtype=jnp.float32),
+        smallthinker_cell=(SmallThinkerConfig(
+            window_layout=(0, 1, 1, 1) * 2, rope_layout=(0, 1, 1, 1) * 2),
+                           48, 32))
 
   @staticmethod
   def toy() -> "Sizes":
@@ -295,7 +314,19 @@ class Sizes:
             swa_qk_rope_head_dim=8, swa_v_head_dim=16, n_routed_experts=8,
             experts_held=(2, 4), num_experts_per_tok=2, max_seq_len=256,
             dtype=jnp.float32, param_dtype=jnp.float32),
-        dots3_shapes=(4, 264, 8, 128))
+        dots3_shapes=(4, 264, 8, 128),
+        smallthinker_cfg=SmallThinkerConfig(
+            vocab_size=512, d_model=128, num_heads=14, num_kv_heads=2,
+            head_dim=16, moe_d_ff=128, n_routed_experts=8,
+            num_experts_per_tok=3, sliding_window=33,
+            window_layout=(0, 1, 1, 1), rope_layout=(0, 1, 1, 1),
+            max_seq_len=256, dtype=jnp.float32, param_dtype=jnp.float32),
+        smallthinker_cell=(SmallThinkerConfig(
+            vocab_size=512, d_model=128, num_heads=14, num_kv_heads=2,
+            head_dim=128, moe_d_ff=128, n_routed_experts=8,
+            num_experts_per_tok=3, sliding_window=100,
+            window_layout=(0, 1), rope_layout=(0, 1), max_seq_len=256,
+            dtype=jnp.float32), 6, 16))
 
 
 def say(msg: str) -> None:
@@ -1196,8 +1227,16 @@ def serve_expert_cut(sizes: Sizes, model, want_calls: dict, what: str,
         f"matmul {eng.moe_gmm_impl}, cache {eng.cache_layout}")
     if not sizes.rehearsal:
       impls = (eng.kv_write_impl, eng.slot_attn_impl, eng.moe_gmm_impl)
+      # Window layers over K/V pairs resolve a write and an attend of
+      # their own (last, so that the grouped matmul stays third).
+      windowed = tuple(i for i in (eng.kv_win_write_impl,
+                                   eng.kv_win_attn_impl) if i is not None)
+      if windowed:
+        say(f"  {what} window layers: ring write {windowed[0]}, windowed "
+            f"attend {windowed[1]}")
       if gmm_may_decline and eng.moe_gmm_impl == "reference":
         impls, want_calls = impls[:2], dict(want_calls, **{MOE_GMM: 0})
+      impls += windowed
       hlo = spy.inner.lower(*spy.specs).compile().as_text()
       calls = {n: named_calls(hlo, n) for n in want_calls}
       check(all(i == "pallas" for i in impls) and calls == want_calls,
@@ -1507,6 +1546,174 @@ def phase_dots3(sizes: Sizes) -> None:
       f"against reference lowerings {err:.2e}")
 
 
+# ----------------------------------------------------------- smallthinker --
+
+
+def check_kv_ring_write(B, R, W, C, dtype, rehearsal: bool) -> None:
+  """``kv_write`` of a K/V PAIR kept in rows as a ring ``[B, R, W]`` bit for
+  bit against the rows written at their positions modulo ``R`` in every
+  slot the step feeds, an idle slot's ring untouched: windows at the ring's
+  start, across a stripe's edge, across the ring's END (two stripes, the
+  second at the leaf's head) and many turns in."""
+  r = np.random.RandomState(21)
+  leaves = [jnp.asarray(r.randn(B, R, W), dtype) for _ in range(2)]
+  rows = [jnp.asarray(r.randn(B, C, W), dtype) for _ in range(2)]
+  cursors = jnp.asarray(([0, 128 - C // 2, R - C // 2, R - 1, 7 * R + R - 3]
+                         + list(r.randint(0, 20 * R, B)))[:B], jnp.int32)
+  num_valid = jnp.asarray(([C, C, C, 1, C // 2 or 1, 0] + [1, C] * B)[:B],
+                          jnp.int32)
+  args = (*leaves, *rows, cursors, num_valid)
+  write = compile_here(
+      functools.partial(kv_write_pallas, interpret=rehearsal, ring=True),
+      *args, mosaic_calls=1, rehearsal=rehearsal)
+  got = write(*args)
+  want = jax.jit(functools.partial(kv_write_reference, ring=True))(*args[:5])
+  fed = np.asarray(num_valid) > 0
+  for g, w, old in zip(got, want, leaves):
+    check((_bits(g)[fed] == _bits(w)[fed]).all()
+          and (_bits(g)[~fed] == _bits(old)[~fed]).all(),
+          f"ring kv_write of a pair {jnp.dtype(dtype).name} differs from "
+          "the reference in a fed slot, or touched an idle one")
+  say(f"  K/V ring write slots{B} rows{R} width{W} chunk{C} "
+      f"{jnp.dtype(dtype).name}: bit-identical in {int(fed.sum())} fed "
+      f"slots, {int((~fed).sum())} idle untouched, "
+      f"{int((np.asarray(cursors) % R + C > R).sum())} windows across the "
+      "ring's end")
+
+
+def check_kv_window_attend(B, R, C, H, Hkv, hd, window, dtype,
+                           rehearsal: bool) -> None:
+  """``slot_attn_kvwin`` over K and V rings filled position by position
+  (never written rows and the dead rows of this step's write hold NaN)
+  against plain grouped attention over each query's window of the slot's
+  history."""
+  r = np.random.RandomState(22)
+  W = Hkv * hd
+  q = jnp.asarray(r.randn(B, C, H, hd), dtype)
+  cursors = np.asarray(([0, window - 3, R - C // 2, 3 * R + 5]
+                        + list(r.randint(0, 4 * R, B)))[:B], np.int32)
+  num_valid = np.asarray(([C, C, C, 1, 0, C // 2 or 1] + [1, C] * B)[:B],
+                         np.int32)
+  top = int((cursors + C).max())
+  hist = r.randn(2, B, top, W).astype(np.float32)
+  rings = np.full((2, B, R, W), np.nan, np.float32)
+  for b in range(B):
+    for p in range(max(0, cursors[b] + num_valid[b] - R),
+                   cursors[b] + num_valid[b]):
+      rings[:, b, p % R] = hist[:, b, p]
+    for p in range(cursors[b] + num_valid[b], cursors[b] + C):
+      rings[:, b, p % R] = np.nan
+  cur, nv = jnp.asarray(cursors), jnp.asarray(num_valid)
+  ring_k, ring_v = (jnp.asarray(x, dtype) for x in rings)
+  with jax.default_matmul_precision("highest"):
+    attend = compile_here(
+        functools.partial(
+            slot_attn_lib.slot_attention_kv_window_pallas.__wrapped__,
+            interpret=rehearsal, window=window, scale=hd ** -0.5),
+        q, ring_k, ring_v, cur, nv, mosaic_calls=_launches(C),
+        rehearsal=rehearsal)
+    out = np.asarray(attend(q, ring_k, ring_v, cur, nv), np.float32)
+
+  held = np.asarray(jnp.asarray(hist, dtype).astype(jnp.float32))
+  qs = np.asarray(q.astype(jnp.float32))
+  G = H // Hkv
+  worst, peak = 0.0, 1e-30
+  for b in range(B):
+    for i in range(num_valid[b]):
+      t = cursors[b] + i
+      lo = max(0, t - window + 1)
+      for g in range(Hkv):
+        keys = held[0, b, lo:t + 1, g * hd:(g + 1) * hd]
+        vals = held[1, b, lo:t + 1, g * hd:(g + 1) * hd]
+        s_ = qs[b, i, g * G:(g + 1) * G] @ keys.T * hd ** -0.5
+        p = np.exp(s_ - s_.max(-1, keepdims=True))
+        want = (p / p.sum(-1, keepdims=True)) @ vals
+        worst = max(worst, float(np.abs(out[b, i, g * G:(g + 1) * G]
+                                        - want).max()))
+        peak = max(peak, float(np.abs(want).max()))
+  check(np.isfinite(out).all(), "slot_attn_kvwin output not finite")
+  dead = np.arange(C)[None] >= num_valid[:, None]
+  check((out[dead] == 0).all(), "slot_attn_kvwin: dead positions not zeros")
+  err = worst / peak
+  tol = 2e-2 if dtype == jnp.bfloat16 else 5e-4
+  check(err <= tol, f"slot_attn_kvwin {jnp.dtype(dtype).name}: error "
+        f"{err:.3g} of the reference's max, tol {tol}")
+  say(f"  K/V window attend slots{B} ring{R} heads{H}/{Hkv} of {hd} "
+      f"window{window} chunk{C} {jnp.dtype(dtype).name}: {err:.2e} of "
+      "plain attention over each window, unwritten and dead rows unread")
+
+
+def report_rules(cfg, slots: int, chunk: int) -> dict:
+  """Every kernel rule a SmallThinker engine of ``slots x chunk`` asks, what
+  it resolved and, where it declined, why (the shapes it was handed and
+  what its ``*_fits`` says of them)."""
+  from easyparallellibrary_tpu.kernels.kv_write import kv_write_fits
+  from easyparallellibrary_tpu.kernels.moe_gmm import moe_gmm_fits
+  from easyparallellibrary_tpu.serving import kv_cache as kv_lib
+  full = kv_lib.kv_leaf_shape(cfg, slots, chunk)
+  ring = kv_lib.kv_leaf_shape(cfg, slots, chunk, ring=True)
+  H, hd = cfg.num_heads, cfg.head_dim
+  rows = slots * chunk * cfg.num_experts_per_tok
+  E, D, F = cfg.n_routed_experts, cfg.d_model, cfg.moe_d_ff
+  rules = {
+      "kv_write (full layers)": (
+          kv_lib.kv_write_impl(cfg, slots, chunk), full,
+          kv_write_fits(full, cfg.dtype, chunk)),
+      "slot_attn (full layers)": (
+          kv_lib.slot_attn_impl(cfg, slots, chunk), full,
+          slot_attn_lib.slot_attn_fits(full, cfg.dtype, chunk, H, hd)),
+      "kv_write ring=True (window layers)": (
+          kv_lib.kv_win_write_impl(cfg, slots, chunk), ring,
+          kv_write_fits(ring, cfg.dtype, chunk, ring=True)),
+      "slot_attn_kvwin (window layers)": (
+          kv_lib.kv_win_attn_impl(cfg, slots, chunk), ring,
+          slot_attn_lib.tile_attn_fits(ring, cfg.dtype, chunk, H, hd,
+                                       ring=True)),
+      "moe_gmm": (
+          kv_lib.moe_gmm_impl(cfg, slots, chunk),
+          ((rows, D), (E, D, 2 * F), (E, F, D)),
+          all(moe_gmm_fits((rows, k), (E, k, n), cfg.dtype)
+              for k, n in ((D, 2 * F), (F, D)))),
+  }
+  for name, (impl, shape, fits) in rules.items():
+    why = "" if impl == "pallas" else (
+        f": DECLINED, {'the backend is ' + jax.default_backend() if fits else 'the shapes do not fit the kernel'}")
+    say(f"  rule {name}: {impl} for {shape} {jnp.dtype(cfg.dtype).name}"
+        + why)
+  return {name: impl for name, (impl, _, _) in rules.items()}
+
+
+def phase_smallthinker(sizes: Sizes) -> None:
+  cell_cfg, slots, C = sizes.smallthinker_cell
+  resolved = report_rules(cell_cfg, slots, C)
+  if not sizes.rehearsal:
+    check(all(i == "pallas" for i in resolved.values()),
+          f"a rule declined at the cell's shapes: {resolved}")
+  R = cell_cfg.ring_length(C)
+  W = cell_cfg.num_kv_heads * cell_cfg.head_dim
+  for dtype in (jnp.float32, jnp.bfloat16):
+    check_kv_ring_write(slots, R, W, C, dtype, sizes.rehearsal)
+    check_kv_window_attend(slots, R, C, cell_cfg.num_heads,
+                           cell_cfg.num_kv_heads, cell_cfg.head_dim,
+                           cell_cfg.sliding_window, dtype, sizes.rehearsal)
+  cfg = sizes.smallthinker_cfg
+  n_win = sum(cfg.window_layout)
+  n_full = cfg.num_layers - n_win
+  gap, err = serve_expert_cut(
+      sizes, SmallThinker(cfg),
+      {MOE_GMM: 2 * cfg.num_layers, SLOT_ATTN: n_full,
+       slot_attn_lib.SLOT_ATTN_KVWIN: 2 * n_win, "kv_write": cfg.num_layers},
+      "smallthinker", more_impls=("kv_win_write_impl", "kv_win_attn_impl"))
+  say(f"PASS smallthinker: the K/V ring kv_write and slot_attn_kvwin f32 + "
+      "bf16 " + ("INTERPRETED" if sizes.rehearsal else "compiled")
+      + f" at the cell's leaves against their references; {n_full} full "
+      f"layer(s) without positions + {n_win} window layers with rotary, "
+      f"{cfg.n_routed_experts} ReLU experts top-{cfg.num_experts_per_tok} "
+      f"routed from the layer's input, served tokens within {gap:.1e} of "
+      f"the teacher-forced best; step logits kernels against reference "
+      f"lowerings {err:.2e}")
+
+
 # ---------------------------------------------------------------- overlap --
 
 
@@ -1601,7 +1808,7 @@ def main(argv=None) -> int:
   parser.add_argument(
       "--only", default=None,
       help="run this one phase (kernels, train, serve, hybrid, experts, "
-           "lfm2, dots3, overlap); prints no result line")
+           "lfm2, dots3, smallthinker, overlap); prints no result line")
   args = parser.parse_args(argv)
   t_start = time.perf_counter()
   cache_dir = compile_cache.configure()
@@ -1628,6 +1835,7 @@ def main(argv=None) -> int:
                       ("experts", lambda: phase_experts(sizes)),
                       ("lfm2", lambda: phase_lfm2(sizes)),
                       ("dots3", lambda: phase_dots3(sizes)),
+                      ("smallthinker", lambda: phase_smallthinker(sizes)),
                       ("overlap", lambda: phase_overlap(sizes))):
     if args.only not in (None, name):
       continue
@@ -1658,4 +1866,12 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-  sys.exit(main())
+  # The exit code, said where a reader of the output's end cannot miss it
+  # (a failed check raises past a ``tail``): on standard error, so that a
+  # passing run's result line stays the last of standard output.
+  code = 1
+  try:
+    code = main()
+  finally:
+    print(f"chip_smoke.py exit code {code}", file=sys.stderr, flush=True)
+  sys.exit(code)

@@ -66,12 +66,16 @@ A layer whose cache is ONE leaf (models/glm_moe.py: the latent ``[B, Lc,
 v = None``: the same call with one operand instead of two, and ``None``
 back in the second place.
 
-A one-leaf cache kept as a RING (``ring=True``; models/dots3_note.py's
-window layers: ``[B, R, 1, W]``, ``R`` a whole number of 128-position
-tiles) takes position ``p`` at row ``p mod R``: the window starts at
-``cursor mod R`` and a chunk that crosses ``R`` lands in two stripes, the
-second at the leaf's head.  The positions form only (what a latent leaf is
-kept in); the reference writes the same rows by their indices.
+A cache kept as a RING (``ring=True``: ``R`` rows, a whole number of
+128-position tiles) takes position ``p`` at row ``p mod R``: the window
+starts at ``cursor mod R`` and a chunk that crosses ``R`` lands in two
+blocks, the second at the leaf's head.  Both forms: positions (models/
+dots3_note.py's window layers, one latent leaf ``[B, R, 1, W]``: the
+second TILE is the one that holds the window's last row going round) and
+rows (models/smallthinker.py's window layers, a K/V pair ``[B, R, H_kv x
+hd]``, ``R`` in whole stripes: the stripe after the last IS the first, so
+the kernel's arithmetic is the straight leaf's and its index map alone
+goes round).  The reference writes the same rows by their indices.
 """
 
 from __future__ import annotations
@@ -124,10 +128,10 @@ def kv_write_fits(cache_shape, dtype, chunk: int,
   """Whether the kernel can tile a leaf of ``dtype`` for ``chunk``-wide
   windows, in the form its rank asks for: a 32-bit or 16-bit float leaf,
   a window of at most 128 positions, blocks within the VMEM budget, a
-  ring only in positions and in whole 128-position tiles, and
+  ring in whole 128-position tiles, and
 
   * rows ``[B, Lc, W]``: ``W`` whole lane tiles and at least one whole
-    stripe (:func:`stripe_rows`);
+    stripe (:func:`stripe_rows`), a ring whole stripes;
   * positions ``[B, Lc, H, hd]``: at least one whole 128-position tile
     (so a window touches two at most) and an ``hd`` that fills whole
     sublane tiles."""
@@ -136,12 +140,12 @@ def kv_write_fits(cache_shape, dtype, chunk: int,
     return False
   if not 1 <= chunk <= LANES:
     return False
-  if ring and (len(cache_shape) != 4 or cache_shape[1] % LANES):
+  if ring and cache_shape[1] % LANES:
     return False
   if len(cache_shape) == 3:
     _, Lc, W = cache_shape
     stripe = stripe_rows(chunk, dtype)
-    if W % LANES or Lc < stripe:
+    if W % LANES or Lc < stripe or (ring and Lc % stripe):
       return False
     # The staging block and the arithmetic are float32 whatever the leaf.
     return _VMEM_TILES * stripe * W * 4 <= _VMEM_BUDGET
@@ -236,8 +240,12 @@ def _kv_write_rows_kernel(order_ref, live_ref, cur_ref, fed_ref, *refs,
                                out_ref.dtype)
 
 
-def _kv_write_rows(caches, news, cursors, num_valid, interpret: bool):
-  """The rows form over ``[B, Lc, W]`` leaves and ``[B, C, W]`` chunks."""
+def _kv_write_rows(caches, news, cursors, num_valid, interpret: bool,
+                   ring: bool = False):
+  """The rows form over ``[B, Lc, W]`` leaves and ``[B, C, W]`` chunks.  A
+  ring is whole stripes, so the stripe after its last is its first: the
+  index map goes round and the kernel, which works from the window's
+  offset in the stripe it is handed, is the straight leaf's."""
   B, Lc, W = caches[0].shape
   C = news[0].shape[1]
   n = len(caches)
@@ -248,7 +256,8 @@ def _kv_write_rows(caches, news, cursors, num_valid, interpret: bool):
 
   def stripe_idx(i, j, order, live, cur, fed):
     b = order[i]
-    return (b, _window_block(cur[b], j, C, stripe), 0)
+    at = _window_block(cur[b], j, C, stripe)
+    return (b, at % (Lc // stripe) if ring else at, 0)
 
   chunk_spec = pl.BlockSpec((1, C, W),
                             lambda i, j, order, *_: (order[i], 0, 0))
@@ -359,7 +368,7 @@ def kv_write_pallas(cached_k, cached_v, k, v, cursors, num_valid=None,
   if cached_k.ndim == 3:
     news = [x.astype(dtype).reshape(x.shape[:2] + cached_k.shape[2:])
             for x in news]
-    return _kv_write_rows(caches, news, cursors, num_valid, interpret)
+    return _kv_write_rows(caches, news, cursors, num_valid, interpret, ring)
   B, _, H, hd = cached_k.shape
   # Position-minor views: bitcasts on the TPU, whose layout of the leaf
   # is already this.
@@ -413,7 +422,7 @@ def kv_write(cached_k, cached_v, k, v, cursors, num_valid=None,
   returns ``(new_cached_k, new_cached_v)``, the second ``None`` for a
   one-leaf layer (``cached_v = v = None``).  ``num_valid`` (``None`` =
   every slot is fed) lets the rows form skip the slots the step does not
-  feed.  ``ring`` writes a one-leaf cache as a ring (module docstring).
+  feed.  ``ring`` writes the cache as a ring (module docstring).
   ``impl=None`` applies the
   dispatch rule to the shapes at hand, and takes the leaf as spread
   over chips whenever a multi-device mesh has been built (the legacy

@@ -620,10 +620,11 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
   return out.transpose(0, 3, 1, 2, 4).reshape(B, C, H, vd)
 
 
-# ------------------------------------- one leaf, selected or behind a window --
+# ------------------------------------- selected rows, or rows behind a window --
 #
-# Two more forms of the ONE-LEAF attend (models/dots3_note.py), each under
-# a kernel name of its own so that a device trace tells their time apart:
+# Three more forms of the attend, each under a kernel name of its own so
+# that a device trace tells their time apart; two of a ONE-LEAF cache
+# (models/dots3_note.py), one of a K/V PAIR (models/smallthinker.py):
 #
 # * ``slot_attn_sel``: a query sees row ``s <= t`` iff ``s`` is in its
 #   SELECTION.  The selection reaches the kernel as the index scores
@@ -638,8 +639,22 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
 #   ``p mod R = j``, and a query at ``t`` sees it iff ``t - window < p <=
 #   t``.  Blocks wholly outside every window of the tile are neither
 #   fetched nor computed.
+# * ``slot_attn_kvwin``: the same ring and the same lower bound over a K/V
+#   PAIR kept in rows, ``[B, R, H_kv x hd]`` twice (``hd`` whole lane
+#   tiles), under grouped heads: the ``G = H / H_kv`` query heads of a K/V
+#   head are stacked on the sublanes, ``G x positions`` rows against that
+#   head's lanes of the K block (the transposed-right-hand-side product of
+#   the rows form above) and of the V block.  ``G`` need not be a power of
+#   two (7: 28 heads on 4).  Queries are read from, and the output written
+#   as, ``[B, C, H x hd]``, a head its own lane tiles: rows as the
+#   projection leaves them, gathered into slot order, nothing transposed
+#   (in place from the flat batch they would be an offset in rows into a
+#   rank-2 array, which Mosaic cannot prove whole sublane tiles).  A tile
+#   is the whole chunk while that keeps it within :data:`_TILE_ROWS` rows
+#   (the ring is read once a prefilling slot); a decoding slot's one
+#   position stacks its heads ``G`` to a sublane tile a K/V head.
 #
-# Both run on a grid over the step's live (slot, position TILE) pairs
+# All run on a grid over the step's live (slot, position TILE) pairs
 # (:func:`live_tiles`; their number is a value, the program compiles once)
 # and the leaf's blocks: a tile is ``tile_positions`` chunk positions of
 # every head, rows ordered (position, head) as the projection leaves them,
@@ -657,6 +672,7 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
 
 SLOT_ATTN_SEL = "slot_attn_sel"
 SLOT_ATTN_WIN = "slot_attn_win"
+SLOT_ATTN_KVWIN = "slot_attn_kvwin"
 
 # Query rows of one tile the kernels aim at (positions x heads).
 _TILE_ROWS = 1024
@@ -674,6 +690,24 @@ def tile_positions(chunk: int, num_heads: int) -> int:
   if chunk % 8 == 0 and 8 * num_heads <= _TILE_ROWS:
     return 8
   return chunk
+
+
+def pair_tile_positions(chunk: int, num_heads: int) -> int:
+  """Chunk positions of one query tile of the pair form: the whole chunk
+  while the tile stays within :data:`_TILE_ROWS` rows (a prefilling slot
+  then reads its ring once), else 16 (a sublane tile of either dtype)
+  where that divides the chunk."""
+  if chunk * num_heads <= _TILE_ROWS or chunk % 16:
+    return chunk
+  return 16
+
+
+def _pair_rows(tp: int, group: int, dtype) -> int:
+  """Stacked query rows of one K/V head in the pair form: its ``group``
+  heads' ``tp`` positions, or, for one position, its heads up to a whole
+  sublane tile."""
+  tile = sublane_tile(dtype)
+  return group * tp if tp > 1 else -(-group // tile) * tile
 
 
 def live_tiles(num_valid, chunk: int, tile: int):
@@ -705,19 +739,22 @@ def split_decodes(num_valid, chunk: int):
 
 
 def _tile_block(L: int, W: int, dtype, rows: int, vd: int,
-                ring: bool) -> int:
+                ring: bool, pair: bool = False) -> int:
   """Leaf positions per block for a query tile of ``rows`` rows: the
   widest of :data:`_BLOCKS` (a ring: the widest 128-multiple that divides
   it, the ring itself first) that keeps the kernel within
-  :data:`_TILE_VMEM_BUDGET`; 0 if none does."""
+  :data:`_TILE_VMEM_BUDGET`; 0 if none does.  A ``pair`` holds a K and a V
+  block, and its query rows are one head wide (``vd``), stacked once
+  more in scratch."""
   size = jnp.dtype(dtype).itemsize
   if ring:
     blocks = [b for b in range(L, 0, -LANES) if L % b == 0 and b <= 1024]
   else:
     blocks = [b for b in _BLOCKS if b <= L]
+  leaves, q_width = (2, 2 * vd) if pair else (1, W)
   for block in blocks:
-    vmem = (2 * W * block * size                 # the leaf's block
-            + 2 * rows * (W + vd) * size         # q, out
+    vmem = (2 * leaves * W * block * size        # the leaf's block(s)
+            + 2 * rows * (q_width + vd) * size   # q, out
             + rows * (vd + 2 * LANES) * 4        # acc, max, sum
             + 3 * rows * block * 4)              # scores, probabilities
     if vmem <= _TILE_VMEM_BUDGET:
@@ -731,14 +768,31 @@ def tile_attn_fits(cache_shape, dtype, chunk: int, num_heads: int,
   a one-leaf cache ``[B, L, 1, W]`` of ``dtype``: a 16- or 32-bit float,
   a width of whole sublane tiles, a chunk of whole tiles of positions,
   a leaf of at least 128 rows (a ring: whole 128-row tiles), heads in
-  whole sublane tiles, and a block within the budget."""
-  if len(cache_shape) != 4 or cache_shape[2] != 1:
-    return False
-  _, L, _, W = cache_shape
+  whole sublane tiles, and a block within the budget.  A rank-3 shape is
+  one leaf of a K/V PAIR kept in rows, ``[B, L, H_kv x v_width]``
+  (``v_width`` the head size): the pair form, built behind a window only,
+  wants heads of whole lane tiles, query heads in whole groups, and a
+  tile of positions in whole sublane tiles (or the one position of a
+  chunk of 1)."""
   dtype = jnp.dtype(dtype)
   if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
     return False
   tile = sublane_tile(dtype)
+  if len(cache_shape) == 3:
+    _, L, W = cache_shape
+    hd = v_width
+    if not ring or hd % LANES or W % hd or num_heads % (W // hd):
+      return False
+    if L < LANES or L % LANES or not 1 <= chunk <= LANES:
+      return False
+    tp = pair_tile_positions(chunk, num_heads)
+    if tp > 1 and tp % tile:
+      return False
+    rows = (W // hd) * _pair_rows(tp, num_heads // (W // hd), dtype)
+    return _tile_block(L, W, dtype, rows, hd, True, pair=True) > 0
+  if len(cache_shape) != 4 or cache_shape[2] != 1:
+    return False
+  _, L, _, W = cache_shape
   if W % tile or v_width % tile or num_heads % tile:
     return False
   if L < LANES or (ring and L % LANES) or not 1 <= chunk <= LANES:
@@ -811,20 +865,64 @@ def slot_attention_window_reference(q, ring, cursors, num_valid, window: int,
   return jnp.einsum("bhqk,bkd->bqhd", probs.astype(dtype), values)
 
 
+def slot_attention_kv_window_reference(q, ring_k, ring_v, cursors, num_valid,
+                                       window: int, scale: float):
+  """Every query against every row of its slot's K/V rings (either order:
+  ``[B, R, H_kv x hd]`` or ``[B, R, H_kv, hd]``), grouped heads, masked to
+  the positions ``t - window < p <= t`` the rows hold."""
+  B, C, H, hd = q.shape
+  R = ring_k.shape[1]
+  dtype = q.dtype
+  nv = (jnp.full((B,), C, jnp.int32) if num_valid is None
+        else num_valid.astype(jnp.int32))
+  keys = ring_k.reshape(B, R, -1, hd)
+  values = ring_v.reshape(B, R, -1, hd)
+  Hkv = keys.shape[2]
+  held = ring_positions(cursors.astype(jnp.int32) + nv, R)[:, None, :]
+  t = cursors[:, None, None] + jnp.arange(C)[None, :, None]
+  seen = (held >= 0) & (held <= t) & (held > t - window)
+  logits = jnp.einsum("bqhgd,bkhd->bhgqk", q.reshape(B, C, Hkv, H // Hkv, hd),
+                      keys) * jnp.asarray(scale, dtype)
+  logits = jnp.where(seen[:, None, None], logits,
+                     jnp.asarray(-1e9, logits.dtype))
+  probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+  # A row no query of the slot sees may hold anything: keep it out of the
+  # product (``slot_attention_window_reference``).
+  live = jnp.any(seen, axis=1)[..., None, None]
+  values = jnp.where(live, values, jnp.zeros((), dtype))
+  out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(dtype), values)
+  return out.reshape(B, C, H, hd)
+
+
 def _tile_attn_kernel(slot_ref, tile_ref, count_ref, cur_ref, bound_ref,
                       starts_ref, into_ref, pos_ref, q_ref, k_ref, *refs,
                       block: int, num_blocks: int, scale: float,
                       v_width: int, tp: int, heads: int,
-                      window: Optional[int], ring: int):
+                      window: Optional[int], ring: int, pair=None):
   """One (live tile, leaf block) grid step of the selected (``window``
-  None) or the windowed form.  ``q_ref`` ``[tp, heads, W]``, the tile's
+  None) or a windowed form.  ``q_ref`` ``[tp, heads, W]``, the tile's
   rows of the flat batch, taken as ``tp x heads`` rows (position, head);
   ``k_ref`` ``[1, 1, W, block]``, position-minor; ``pos_ref`` each query
   row's position in its tile; ``into_ref`` the output as it was handed in
   (aliased, untouched).  ``refs``: the selected form's score block ``[1,
   tp, block]`` and thresholds ``[1, tp, 1]``, then the output block and the
-  three scratches."""
+  three scratches.
+
+  The PAIR form (``pair = (H_kv, G, hd)``, behind a window): ``q_ref``
+  and the output block ``[1, positions, heads x hd]``,
+  a head its own lanes; ``k_ref`` and ``refs[0]`` the K and V blocks ``[1,
+  block, H_kv x hd]`` as the leaves hold them; a fourth scratch holds the
+  query rows stacked a K/V head, (head of the group, position), filled on
+  the tile's first step: whole ``[tp, hd]`` pieces, or, for one position,
+  a row a head in float32 (a 16-bit row alone is half a sublane word),
+  the group padded to a sublane tile."""
   del count_ref, starts_ref, into_ref
+  if pair is not None:
+    *refs, qs_ref = refs
+    Hkv, G, hd = pair
+    rows_g = qs_ref.shape[0] // Hkv      # stacked rows of one K/V head
+    lanes = lambda i: slice(i * hd, (i + 1) * hd)
+    of_head = lambda g: slice(g * rows_g, (g + 1) * rows_g)
   o_ref, m_ref, l_ref, acc_ref = refs[-4:]
   i = pl.program_id(0)
   kb = pl.program_id(1)
@@ -841,6 +939,12 @@ def _tile_attn_kernel(slot_ref, tile_ref, count_ref, cur_ref, bound_ref,
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
+    if pair is not None:
+      if tp == 1:
+        qs_ref[...] = jnp.zeros_like(qs_ref)
+      for h in range(heads):
+        at = (h // G) * rows_g + (h % G) * tp
+        qs_ref[at:at + tp] = q_ref[0, :, lanes(h)].astype(qs_ref.dtype)[:tp]
 
   col0 = kb * block
   if window is None:
@@ -860,14 +964,27 @@ def _tile_attn_kernel(slot_ref, tile_ref, count_ref, cur_ref, bound_ref,
 
   @pl.when(live)
   def _fold():
-    q, k = q_ref[...].reshape(tp * heads, -1), k_ref[0, 0]
-    precision = None if q.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
-    s = jax.lax.dot_general(
-        q, k, (((1,), (0,)), ((), ())), precision=precision,
-        preferred_element_type=jnp.float32) * scale       # [rows, block]
+    precision = (None if k_ref.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    if pair is None:
+      q, k = q_ref[...].reshape(tp * heads, -1), k_ref[0, 0]
+      s = jax.lax.dot_general(
+          q, k, (((1,), (0,)), ((), ())), precision=precision,
+          preferred_element_type=jnp.float32) * scale     # [rows, block]
+      vcol = col0 + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    else:
+      # A K/V head's stacked rows against its own lanes of the K block,
+      # contracted over the lanes of both (K is not transposed).
+      k = k_ref[0]                                         # [block, W]
+      s = jnp.concatenate([
+          jax.lax.dot_general(
+              qs_ref[of_head(g)].astype(k.dtype), k[:, lanes(g)],
+              (((1,), (1,)), ((), ())), precision=precision,
+              preferred_element_type=jnp.float32)
+          for g in range(Hkv)], axis=0) * scale            # [rows, block]
+      vcol = col0 + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
     t = t_lo + pos_ref[...]                                # [rows, 1]
     col = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    vcol = col0 + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
     if window is None:
       sc_ref, thr_ref = refs[0], refs[1]
       picked = jnp.where(sc_ref[0] >= thr_ref[0], 0.0, NEG_INF)  # [tp, block]
@@ -884,35 +1001,67 @@ def _tile_attn_kernel(slot_ref, tile_ref, count_ref, cur_ref, bound_ref,
       dead = vheld < lo
     # Nothing of a row no query sees may reach the sums through V either
     # (``0 * NaN = NaN``).
-    v = k[:v_width]
+    v = k[:v_width] if pair is None else refs[0][0]
     v = jnp.where(dead, jnp.zeros_like(v), v)
     p, corr = _online_softmax_fold(s, m_ref, l_ref, ...)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (1,)), ((), ())), precision=precision,
-        preferred_element_type=jnp.float32)                # [rows, vd]
+    p = p.astype(v.dtype)
+    if pair is None:
+      pv = jax.lax.dot_general(
+          p, v, (((1,), (1,)), ((), ())), precision=precision,
+          preferred_element_type=jnp.float32)              # [rows, vd]
+    else:
+      pv = jnp.concatenate([
+          jax.lax.dot_general(
+              p[of_head(g)], v[:, lanes(g)], (((1,), (0,)), ((), ())),
+              precision=precision, preferred_element_type=jnp.float32)
+          for g in range(Hkv)], axis=0)                    # [rows, hd]
+    acc_ref[...] = acc_ref[...] * corr + pv
 
   @pl.when(kb == num_blocks - 1)
   def _emit():
     l_col = jnp.maximum(l_ref[...][:, :1], 1e-30)
     real = t_lo + pos_ref[...] < bound
-    o_ref[0] = jnp.where(real, acc_ref[...] / l_col, 0.0).astype(o_ref.dtype)
+    out = jnp.where(real, acc_ref[...] / l_col, 0.0)
+    if pair is None:
+      o_ref[0] = out.astype(o_ref.dtype)
+      return
+    # A head's rows to its own lanes of the output block; one position
+    # fills the block's first row and leaves the others zeros, which is
+    # what a slot that feeds one position holds there.
+    first = jax.lax.broadcasted_iota(
+        jnp.int32, (o_ref.shape[1], hd), 0) == 0
+    for h in range(heads):
+      at = (h // G) * rows_g + (h % G) * tp
+      piece = out[at:at + tp]
+      if tp == 1 and o_ref.shape[1] > 1:
+        piece = jnp.where(first, jnp.broadcast_to(piece, first.shape), 0.0)
+      o_ref[0, :, lanes(h)] = piece.astype(o_ref.dtype)
 
 
 def _tile_attention(q, leaf, cursors, num_valid, scores, threshold,
                     window: Optional[int], interpret: bool,
                     block: Optional[int], v_width: int, scale: float,
-                    starts=None, chunk: Optional[int] = None):
-  """The two forms' shared call: ``window`` None is the selected form
-  (``scores``, ``threshold`` given), else the leaf is a ring.  ``q`` is
-  ``[B, C, H, W]``, or, with ``starts`` and ``chunk``, the step's
-  token-flat batch ``[T, H, W]`` in which slot ``b``'s live positions are
-  the rows from ``starts[b]`` on (models/gpt.py:SlotRows): a tile's query
-  rows are read where they lie, no copy of them into ``[slots, chunk]``
-  order.  Decoding slots take a launch of their own on their one position
-  (:func:`split_decodes`)."""
+                    starts=None, chunk: Optional[int] = None, values=None):
+  """The forms' shared call: ``window`` None is the selected form
+  (``scores``, ``threshold`` given), else the leaf is a ring; ``values``
+  (the V ring beside ``leaf``, the K ring, both kept in rows) is the pair
+  form.  ``q`` is ``[B, C, H, W]``, or (the one-leaf forms), with ``starts``
+  and ``chunk``, the step's token-flat batch ``[T, H, W]`` in which slot
+  ``b``'s live positions are the rows from ``starts[b]`` on
+  (models/gpt.py:SlotRows): a tile's query rows are read where they lie, no
+  copy of them into ``[slots, chunk]`` order.  Decoding slots take a
+  launch of their own on their one position (:func:`split_decodes`)."""
   B = cursors.shape[0]
   H, W = q.shape[-2:]
-  if starts is None:
+  pair = values is not None
+  if pair:
+    # In [slots, chunk] order, a row as the projection leaves it, H x hd
+    # lanes: an offset in rows into a rank-2 flat batch is one Mosaic
+    # cannot prove whole sublane tiles.
+    chunk = q.shape[1]
+    q = q.reshape(B, chunk, H * W)
+    starts = jnp.zeros((B,), jnp.int32)
+  elif starts is None:
     chunk = q.shape[1]
     q = q.reshape(B * chunk, H, W)
     starts = jnp.arange(B, dtype=jnp.int32) * chunk
@@ -930,17 +1079,26 @@ def _tile_attention(q, leaf, cursors, num_valid, scores, threshold,
   launch = functools.partial(
       _tile_launch, q.astype(leaf.dtype), starts.astype(jnp.int32), leaf,
       cur, bound, window=window, interpret=interpret, block=block,
-      v_width=v_width, scale=scale)
+      v_width=v_width, scale=scale, values=values)
   # Every launch writes the tiles it visits into ONE buffer that starts
   # as zeros (aliased in and out): what no tile covers stays zeros, and
   # the decoding slots' launch lands beside the other's with no merge.
   # Both work under every slot's TRUE bound, so the one tile a launch
   # with nothing to do still visits (a grid has at least one step) writes
   # what the other launch writes there.
-  out = jnp.zeros((B, chunk * H, v_width), leaf.dtype)
+  out = jnp.zeros((B, chunk, H * v_width) if pair
+                  else (B, chunk * H, v_width), leaf.dtype)
   split = split_decodes(num_valid, chunk)
   if split is None:
     out = launch(out, chunk, nv, scores, threshold)
+  elif pair:
+    # The one-position launch writes a block of several positions (its
+    # result and zeros: a row alone is no block of a 16-bit array), so it
+    # goes FIRST: where it had nothing to do, the block it still visits
+    # is one the other launch then writes whole, or an idle slot's.
+    many, one = split
+    out = launch(out, 1, one, None, None)
+    out = launch(out, chunk, many, None, None)
   else:
     many, one = split
     first = lambda x: None if x is None else x[:, :1]
@@ -951,25 +1109,37 @@ def _tile_attention(q, leaf, cursors, num_valid, scores, threshold,
 
 def _tile_launch(q, starts, leaf, cur, bound, into, C: int, feeds, scores,
                  threshold, *, window: Optional[int], interpret: bool,
-                 block: Optional[int], v_width: int, scale: float):
+                 block: Optional[int], v_width: int, scale: float,
+                 values=None):
   """One launch over the (slot, tile of ``C``'s positions) pairs that
   cover the first ``feeds[b]`` positions of each slot (int32 ``[B]``, at
   most ``C``); ``q`` ``[T, H, W]``, slot ``b``'s position ``i`` at row
   ``starts[b] + i``, ``cur`` and ``bound`` each slot's cursor and true
   bound.  ``into`` ``[B, chunk x H, v_width]`` is the output, handed in:
   the launch writes the tiles it visits (their positions at or beyond the
-  bound as zeros) and leaves every other row as it was."""
-  H, W = q.shape[1:]
+  bound as zeros) and leaves every other row as it was.  The pair form
+  (``values``): ``q`` and ``into`` ``[B, chunk, heads x hd]``, ``leaf`` and
+  ``values`` ``[B, R, H_kv x hd]``."""
   L = leaf.shape[1]
   dtype = leaf.dtype
   ring = window is not None
-  tp = tile_positions(C, H)
-  rows = tp * H
+  pair = None
+  if values is None:
+    H, W = q.shape[1:]
+    tp = tile_positions(C, H)
+    rows = tp * H
+    pos = (jnp.arange(rows, dtype=jnp.int32) // H)[:, None]
+  else:
+    H, W, hd = q.shape[2] // v_width, leaf.shape[2], v_width
+    pair = (W // hd, H // (W // hd), hd)
+    tp = pair_tile_positions(C, H)
+    rows = pair[0] * _pair_rows(tp, pair[1], dtype)
+    # Stacked rows are (K/V head, head of its group, position).
+    pos = (jnp.arange(rows, dtype=jnp.int32) % tp)[:, None]
   if block is None:
-    block = _tile_block(L, W, dtype, rows, v_width, ring)
+    block = _tile_block(L, W, dtype, rows, v_width, ring, pair is not None)
   nb = pl.cdiv(L, block)
   slot, tile, count = live_tiles(feeds, C, tp)
-  pos = (jnp.arange(rows, dtype=jnp.int32) // H)[:, None]
 
   def leaf_idx(i, kb, slot, tile, count, cur, bound, starts):
     # A step beyond the tile's last live block (a dead one: the kernel
@@ -977,21 +1147,40 @@ def _tile_launch(q, starts, leaf, cur, bound, into, C: int, feeds, scores,
     # it issues no DMA of its own.
     b = slot[i]
     if ring:
+      # A ring not yet gone round holds nothing beyond its newest row.
       held = jnp.maximum(bound[b] - 1, 0) % L // block
-      return b, 0, 0, jnp.where(bound[b] > 0, kb, held)
+      return b, 0, 0, jnp.where(
+          bound[b] > L, kb, jnp.where(bound[b] > 0, jnp.minimum(kb, held),
+                                      held))
     t_hi = jnp.minimum(bound[b], cur[b] + (tile[i] + 1) * tp) - 1
     return b, 0, 0, jnp.minimum(kb, jnp.maximum(t_hi, 0) // block)
 
   # The tile's query rows, read where they lie in the flat batch: an
   # offset in rows, not in blocks (every dimension an element offset).
-  q_spec = pl.BlockSpec(
-      (pl.Element(tp), pl.Element(H), pl.Element(W)),
-      lambda i, kb, slot, tile, count, cur, bound, starts: (
-          starts[slot[i]] + tile[i] * tp, 0, 0))
   in_specs = [pl.BlockSpec(memory_space=pl.ANY),
-              pl.BlockSpec((rows, 1), lambda i, kb, *_: (0, 0)),
-              q_spec, pl.BlockSpec((1, 1, W, block), leaf_idx)]
-  operands = [into, pos, q, jnp.transpose(leaf, (0, 2, 3, 1))]
+              pl.BlockSpec((rows, 1), lambda i, kb, *_: (0, 0))]
+  if pair is None:
+    in_specs += [
+        pl.BlockSpec(
+            (pl.Element(tp), pl.Element(H), pl.Element(W)),
+            lambda i, kb, slot, tile, count, cur, bound, starts: (
+                starts[slot[i]] + tile[i] * tp, 0, 0)),
+        pl.BlockSpec((1, 1, W, block), leaf_idx)]
+    operands = [into, pos, q, jnp.transpose(leaf, (0, 2, 3, 1))]
+    out_block = (1, rows, v_width)
+    scratch = []
+  else:
+    def rows_idx(*a):
+      b, _, _, kb = leaf_idx(*a)
+      return b, kb, 0
+    # One position is the first row of a block of a sublane tile's rows,
+    # read and written (a row alone is no block of a 16-bit array).
+    out_block = (1, tp if tp > 1 else min(into.shape[1], sublane_tile(dtype)),
+                 H * hd)
+    in_specs += [pl.BlockSpec(out_block, lambda i, kb, slot, tile, *_: (
+        slot[i], tile[i], 0))] + [pl.BlockSpec((1, block, W), rows_idx)] * 2
+    operands = [into, pos, q, leaf, values]
+    scratch = [pltpu.VMEM((rows, hd), dtype if tp > 1 else jnp.float32)]
   if not ring:
     def score_idx(i, kb, slot, tile, *rest):
       return slot[i], tile[i], leaf_idx(i, kb, slot, tile, *rest)[3]
@@ -1009,24 +1198,25 @@ def _tile_launch(q, starts, leaf, cur, bound, into, C: int, feeds, scores,
   out = pl.pallas_call(
       functools.partial(
           _tile_attn_kernel, block=block, num_blocks=nb, scale=float(scale),
-          v_width=v_width, tp=tp, heads=H, window=window, ring=L),
+          v_width=v_width, tp=tp, heads=H, window=window, ring=L, pair=pair),
       grid_spec=pltpu.PrefetchScalarGridSpec(
           num_scalar_prefetch=6,
           grid=(count[0], nb),
           in_specs=in_specs,
           out_specs=pl.BlockSpec(
-              (1, rows, v_width),
+              out_block,
               lambda i, kb, slot, tile, *_: (slot[i], tile[i], 0)),
           scratch_shapes=[
               pltpu.VMEM((rows, LANES), jnp.float32),      # running max
               pltpu.VMEM((rows, LANES), jnp.float32),      # running sum
               pltpu.VMEM((rows, v_width), jnp.float32),    # accumulator
-          ]),
+          ] + scratch),
       out_shape=jax.ShapeDtypeStruct(into.shape, dtype),
       # Operands count the six scalar-prefetch arrays: ``into`` follows.
       input_output_aliases={6: 0},
       interpret=interpret,
-      name=SLOT_ATTN_WIN if ring else SLOT_ATTN_SEL,
+      name=(SLOT_ATTN_KVWIN if pair is not None
+            else SLOT_ATTN_WIN if ring else SLOT_ATTN_SEL),
       **kwargs,
   )(slot, tile, count, cur, bound, starts, *operands)
   return out
@@ -1053,6 +1243,16 @@ def slot_attention_window_pallas(q, ring, cursors, num_valid=None,
                                  v_width: int, scale: float):
   return _tile_attention(q, ring, cursors, num_valid, None, None, window,
                          interpret, block, v_width, scale, starts, chunk)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "interpret", "block",
+                                             "scale"))
+def slot_attention_kv_window_pallas(q, ring_k, ring_v, cursors,
+                                    num_valid=None, interpret: bool = False,
+                                    block: Optional[int] = None, *,
+                                    window: int, scale: float):
+  return _tile_attention(q, ring_k, cursors, num_valid, None, None, window,
+                         interpret, block, q.shape[-1], scale, values=ring_v)
 
 
 # --------------------------------------------------------------- dispatch --
@@ -1135,3 +1335,28 @@ def slot_attention_window(q, ring, cursors, num_valid=None, *, impl: str,
       q, ring, cursors, num_valid, interpret=impl == "interpret",
       starts=starts, chunk=chunk, window=window, v_width=v_width,
       scale=scale)
+
+
+def slot_attention_kv_window(q, ring_k, ring_v, cursors, num_valid=None, *,
+                             impl: str, window: int,
+                             scale: Optional[float] = None):
+  """Attend each slot's chunk over a RING of K/V PAIRS (``ring_k``,
+  ``ring_v`` ``[B, R, H_kv x hd]``, position ``p`` at row ``p mod R``,
+  written through ``kv_write(..., ring=True)``) under grouped heads: query
+  ``i`` of slot ``b``, at ``t = cursors[b] + i``, sees the positions ``t -
+  window < p <= t``; query head ``h`` reads K/V head ``h // (H / H_kv)``.
+  ``q`` and ``out`` ``[B, C, H, hd]``.  ``scale`` defaults to ``1 /
+  sqrt(hd)``.  ``impl`` is resolved by the caller
+  (:func:`resolve_tile_attn_impl` on a leaf's rank-3 shape)."""
+  _check_impl(impl)
+  scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else float(scale)
+  if impl == "reference":
+    return slot_attention_kv_window_reference(q, ring_k, ring_v, cursors,
+                                              num_valid, window, scale)
+  # The kernel reads rows of ``H_kv x hd`` lanes: what the rule takes on a
+  # TPU is kept so; a pair kept in positions (interpreted, a toy width)
+  # folds its heads here.
+  in_rows = lambda ring: ring.reshape(ring.shape[0], ring.shape[1], -1)
+  return slot_attention_kv_window_pallas(
+      q, in_rows(ring_k), in_rows(ring_v), cursors, num_valid,
+      interpret=impl == "interpret", window=window, scale=scale)

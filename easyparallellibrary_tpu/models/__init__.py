@@ -7,6 +7,9 @@ from easyparallellibrary_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
 from easyparallellibrary_tpu.models.dots3_note import (
     Dots3Note, Dots3NoteConfig,
 )
+from easyparallellibrary_tpu.models.smallthinker import (
+    SmallThinker, SmallThinkerConfig,
+)
 from easyparallellibrary_tpu.models.bert import (
     Bert, BertConfig, bert_large_config,
 )
@@ -20,6 +23,7 @@ __all__ = [
     "GlmMoe", "GlmMoeConfig",
     "Lfm2Moe", "Lfm2MoeConfig",
     "Dots3Note", "Dots3NoteConfig",
+    "SmallThinker", "SmallThinkerConfig",
     "Bert", "BertConfig", "bert_large_config",
     "ResNet", "ResNetConfig", "resnet18_config", "resnet50_config",
 ]

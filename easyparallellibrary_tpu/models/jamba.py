@@ -125,16 +125,20 @@ def _dense(cfg, features: int, name: str):
                kernel_init=nn.initializers.normal(stddev=0.02), name=name)
 
 
-def gqa_causal_attention(q, k, v, dtype):
+def gqa_causal_attention(q, k, v, dtype, window: Optional[int] = None):
   """Dense causal attention of ``q`` [B, S, H, hd] over ``k``/``v`` [B, S,
   H_kv, hd], each K/V head shared by H / H_kv query heads: the full
-  forward's attention (float32 softmax, as ``_dense_causal_attention``)."""
+  forward's attention (float32 softmax, as ``_dense_causal_attention``).
+  Behind a ``window`` position ``t`` sees ``t - window < s <= t``
+  (models/smallthinker.py)."""
   B, S, H, hd = q.shape
   Hkv = k.shape[2]
   q = q.reshape(B, S, Hkv, H // Hkv, hd)
   scale = 1.0 / jnp.sqrt(hd).astype(dtype)
   logits = jnp.einsum("bqhgd,bkhd->bhgqk", q, k) * scale
   mask = jnp.tril(jnp.ones((S, S), jnp.bool_))
+  if window is not None:
+    mask &= ~jnp.tril(jnp.ones((S, S), jnp.bool_), -window)
   logits = jnp.where(mask, logits, jnp.asarray(-1e9, logits.dtype))
   probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
   out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(dtype), v)

@@ -368,6 +368,19 @@ def noaux_tc_route(x, router_kernel, bias, top_k: int, scale: float,
   return chosen.astype(jnp.int32), weights * scale
 
 
+def softmax_topk_route(x, router_kernel, top_k: int):
+  """A softmax router with no bias (models/smallthinker.py): ``r = x W_r``
+  in float32 whatever ``x``'s dtype; the ``top_k`` largest LOGITS are
+  chosen; their weights are the softmax over the chosen logits alone
+  (equal to the softmax over all of them renormalised over the chosen).
+  ``x`` ``[N, D]`` -> ``(chosen int32 [N, top_k], weights float32 [N,
+  top_k])``."""
+  logits = jnp.matmul(x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+  top, chosen = jax.lax.top_k(logits, top_k)
+  return chosen.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
 def sort_by_expert(chosen, live, num_experts: int, first: int = 0):
   """Sort a step's ``N x top_k`` assignments by expert.  ``chosen`` int32
   ``[N, top_k]``, ``live`` bool ``[N]`` (``None``: every position).  A
@@ -399,11 +412,13 @@ def sort_by_expert(chosen, live, num_experts: int, first: int = 0):
 
 
 def dropless_experts(x, chosen, weights, live, w_gate_up, w_down,
-                     impl: Optional[str] = None, first: int = 0):
+                     impl: Optional[str] = None, first: int = 0,
+                     gate=jax.nn.silu):
   """``sum_i weights[n, i] * Expert_{chosen[n, i]}(x[n])`` for the live
-  positions of ``x`` ``[N, D]``, each expert a SiLU-gated MLP:
-  ``w_gate_up`` ``[E, D, 2 F]`` (gate columns, then up), ``w_down`` ``[E,
-  F, D]``.  The stacks hold the router's experts ``[first, first + E)``;
+  positions of ``x`` ``[N, D]``, each expert a gated MLP, ``W_down
+  (gate(x W_gate) * (x W_up))`` (``gate``: SiLU; models/smallthinker.py's
+  is ReLU): ``w_gate_up`` ``[E, D, 2 F]`` (gate columns, then up),
+  ``w_down`` ``[E, F, D]``.  The stacks hold the router's experts ``[first, first + E)``;
   the sum runs over the chosen experts among them (an absent expert's
   term is another chip's: nothing stands in for it).  Returns ``(y [N,
   D]`` in ``x``'s dtype, zeros at dead positions, ``group_sizes [E])``.
@@ -415,7 +430,7 @@ def dropless_experts(x, chosen, weights, live, w_gate_up, w_down,
   order, sizes = sort_by_expert(chosen, live, E, first)
   rows = x[order // k]                                    # [N k, D]
   h = moe_gmm(rows, w_gate_up, sizes, impl=impl)
-  h = jax.nn.silu(h[:, :F2 // 2]) * h[:, F2 // 2:]
+  h = gate(h[:, :F2 // 2]) * h[:, F2 // 2:]
   out = moe_gmm(h, w_down, sizes, impl=impl)              # [N k, D]
   # Back to position order: the inverse of a permutation is its argsort
   # (a second small sort; a scatter is a serial loop on a TPU), then a
@@ -439,6 +454,17 @@ class DroplessMoE(HeldParams, nn.Module):
   all); a shared expert runs for every position (fixed shapes), and what
   it gives a dead one nothing reads.
 
+  What the model's config decides beyond the sizes, each absent from a
+  config that takes the default: ``cfg.expert_route`` (a function ``(x,
+  router_kernel, top_k) -> (chosen, weights)``, e.g.
+  :func:`softmax_topk_route`; absent: :func:`noaux_tc_route`, whose bias
+  is then in the tree and which reads ``routed_scaling_factor``,
+  ``norm_topk_prob`` and ``route_norm_eps``) and ``cfg.expert_gate`` (the
+  gate's activation; absent: SiLU).  ``router_in`` (``None``: ``x``) is what
+  the ROUTER reads where that is not what the experts read
+  (models/smallthinker.py routes from the layer's input, before its
+  attention).
+
   ``cfg.experts_held = (first, count)`` (absent or ``None``: all) TELLS
   the layer which of the router's experts it holds, one chip's share of
   a layer divided over several: the router keeps its width and its
@@ -456,7 +482,7 @@ class DroplessMoE(HeldParams, nn.Module):
   moe_gmm_impl: Optional[str] = None
 
   @nn.compact
-  def __call__(self, x, live=None):
+  def __call__(self, x, live=None, router_in=None):
     from easyparallellibrary_tpu.models.jamba import (
         GatedMLP, _boxed as boxed)
     cfg = self.cfg
@@ -466,21 +492,28 @@ class DroplessMoE(HeldParams, nn.Module):
     normal = nn.initializers.normal(stddev=0.02)
     router = self.param("router_kernel", boxed(normal, 2),
                         (D, cfg.n_routed_experts), jnp.float32)
-    bias = self.param("e_score_correction_bias",
-                      boxed(nn.initializers.zeros_init(), 1),
-                      (cfg.n_routed_experts,), jnp.float32)
+    route = getattr(cfg, "expert_route", None)
+    if route is None:
+      bias = self.param("e_score_correction_bias",
+                        boxed(nn.initializers.zeros_init(), 1),
+                        (cfg.n_routed_experts,), jnp.float32)
     w_gate_up = self.param("experts_gate_up", boxed(normal, 3),
                            (E, D, 2 * F), cfg.param_dtype)
     w_down = self.param("experts_down", boxed(normal, 3), (E, F, D),
                         cfg.param_dtype)
     flat = x.reshape(-1, D)
     flat_live = None if live is None else live.reshape(-1)
-    chosen, weights = noaux_tc_route(
-        flat, router, bias, k, cfg.routed_scaling_factor,
-        cfg.norm_topk_prob, cfg.route_norm_eps)
+    routed = flat if router_in is None else router_in.reshape(-1, D)
+    if route is None:
+      chosen, weights = noaux_tc_route(
+          routed, router, bias, k, cfg.routed_scaling_factor,
+          cfg.norm_topk_prob, cfg.route_norm_eps)
+    else:
+      chosen, weights = route(routed, router, k)
     y, sizes = dropless_experts(
         flat, chosen, weights, flat_live, jnp.asarray(w_gate_up, cfg.dtype),
-        jnp.asarray(w_down, cfg.dtype), impl=self.moe_gmm_impl, first=first)
+        jnp.asarray(w_down, cfg.dtype), impl=self.moe_gmm_impl, first=first,
+        gate=getattr(cfg, "expert_gate", jax.nn.silu))
     total = jnp.sum(sizes).astype(jnp.float32)
     if held is not None:
       self.sow("stats", "held_assignments", total)

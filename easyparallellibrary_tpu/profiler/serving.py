@@ -180,6 +180,12 @@ class ServingStats:
     self.selected_rows = 0
     self.window_rows = 0
     self.held_assignments = 0
+    # Window layers over K/V pairs beside full ones
+    # (models/smallthinker.py; 0 elsewhere), summed over the steps: rows
+    # under the live slots' bounds, and those rows with each slot's held
+    # to the window's reach.
+    self.context_rows = 0
+    self.kv_window_rows = 0
     self.kv_rows = 0
     self.busy_time_s = 0.0
     self.prefill_tokens = 0
@@ -346,6 +352,13 @@ class ServingStats:
     self.window_rows += int(window_rows)
     self.held_assignments += int(held_assignments)
 
+  def note_kv_window_step(self, context_rows: int, window_rows: int):
+    """What a step of a model with window layers over K/V pairs adds to
+    :meth:`note_step`'s sample: the rows a full layer must read of the
+    step's live slots, and the rows a window layer must."""
+    self.context_rows += int(context_rows)
+    self.kv_window_rows += int(window_rows)
+
   # ----------------------------------------------------------------- step
 
   def note_step(self, active_slots: int, num_slots: int,
@@ -435,7 +448,7 @@ class ServingStats:
       "flat_trimmed_steps", "flat_narrow_steps",
       "routed_positions", "expert_steps", "expert_load_sum",
       "experts_touched_sum", "index_rows", "selected_rows", "window_rows",
-      "held_assignments", "busy_time_s", "prefill_tokens",
+      "held_assignments", "context_rows", "kv_window_rows", "busy_time_s", "prefill_tokens",
       "decode_tokens", "finished_requests", "generated_tokens",
       "drafted_tokens", "accepted_tokens", "shed_requests", "requeues",
       "bad_steps",
@@ -541,7 +554,8 @@ class ServingStats:
         **{f"{name}_per_step": (getattr(self, name) / self.steps
                                 if self.steps else 0.0)
            for name in ("index_rows", "selected_rows", "window_rows",
-                        "held_assignments")},
+                        "held_assignments", "context_rows",
+                        "kv_window_rows")},
         # Speculation (all 0.0 on a non-speculative engine): drafted vs
         # accepted totals, overall acceptance rate, and accepted-per-
         # step percentiles over the steps that drafted.
@@ -662,7 +676,8 @@ def fleet_summary(replica_stats: List["ServingStats"],
       **{f"{name}_per_step": (
           sum(getattr(s, name) for s in stats) / steps if steps else 0.0)
          for name in ("index_rows", "selected_rows", "window_rows",
-                      "held_assignments")},
+                      "held_assignments", "context_rows",
+                      "kv_window_rows")},
       "drafted_tokens": float(drafted),
       "accepted_tokens": float(accepted),
       "acceptance_rate": (accepted / drafted) if drafted else 0.0,

@@ -54,6 +54,15 @@ ROADMAP_WINDOW_LATENT = (
     "layers in one cache'), the latent rows' pool ROADMAP item R5 ('A "
     "latent cache'; docs/serving.md 'Current limits'); serve it through "
     "the contiguous cache with those features off")
+ROADMAP_WINDOW_KV = (
+    "an attention layer behind a window keeps its K/V pair as a ring of "
+    "window - 1 + chunk rows a slot, which overwrites what a cursor moved "
+    "back would need again (a rejected draft's rows, a retried step's, a "
+    "shared prefix's) and has no paged form: a paged pool per layer kind "
+    "that frees the blocks a window has left, and what a ring answers to a "
+    "cursor moved back, are ROADMAP item R6 ('Window and full layers in "
+    "one cache'; docs/serving.md 'Current limits'); serve it through the "
+    "contiguous cache with those features off")
 ROADMAP_PREEMPTION = (
     "priority reorders ADMISSION, and on the paged engine "
     "(serving.paged.enabled) a RUNNING throughput-class slot is "
@@ -130,8 +139,9 @@ def check_servable(cfg, role: str = "the serving engine") -> None:
   ``cfg`` is a :class:`models.gpt.GPTConfig`, a
   :class:`models.jamba.JambaConfig`, a
   :class:`models.glm_moe.GlmMoeConfig`, a
-  :class:`models.lfm2_moe.Lfm2MoeConfig` or a
-  :class:`models.dots3_note.Dots3NoteConfig` (a config without
+  :class:`models.lfm2_moe.Lfm2MoeConfig`, a
+  :class:`models.dots3_note.Dots3NoteConfig` or a
+  :class:`models.smallthinker.SmallThinkerConfig` (a config without
   ``pipeline_stages`` / ``num_experts`` has neither); ``role`` names the
   component doing the rejecting so a draft-model failure reads
   differently from a target-model one.  Refused are the GPT block's
@@ -198,6 +208,21 @@ def check_latent_cache(cfg, feature: str) -> None:
         f"({type(cfg).__name__}) — {'; '.join(why[k][1] for k in kinds)}")
 
 
+def check_kv_window(cfg, feature: str) -> None:
+  """Reject ``feature`` (the paged cache, prefix caching, speculative
+  decoding, the guarded retry, a draft model) for a model some of whose
+  attention layers keep their K/V pair as a ring behind a window
+  (``cfg.layer_kinds()``: models/smallthinker.py's ``window_kv``): each of
+  them moves a cursor back or shares rows by position, and the ring has
+  overwritten them.  ONE message for every such composition."""
+  from easyparallellibrary_tpu.serving.kv_cache import has_kv_window
+  if has_kv_window(cfg):
+    raise ValueError(
+        f"{feature} is not available for a model with attention layers "
+        f"behind a window over K/V pairs ({type(cfg).__name__}, window_kv "
+        f"layers) — {ROADMAP_WINDOW_KV}")
+
+
 def check_draft_compatible(target_cfg, draft_cfg) -> None:
   """Reject draft models whose shapes cannot verify against the target.
 
@@ -211,6 +236,7 @@ def check_draft_compatible(target_cfg, draft_cfg) -> None:
   check_recurrent_state(draft_cfg, "a draft model (its rejected drafts "
                         "roll back)")
   check_latent_cache(draft_cfg, "a draft model")
+  check_kv_window(draft_cfg, "a draft model")
   if draft_cfg.vocab_size != target_cfg.vocab_size:
     raise ValueError(
         f"draft model vocab_size {draft_cfg.vocab_size} != target "

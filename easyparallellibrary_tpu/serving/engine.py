@@ -83,7 +83,8 @@ from easyparallellibrary_tpu.observability.registry import (
     SERVING_NAMESPACE, MetricRegistry)
 from easyparallellibrary_tpu.serving import kv_cache as kv_lib
 from easyparallellibrary_tpu.serving._capabilities import (
-    check_draft_fits_chunk, check_latent_cache, check_recurrent_state,
+    check_draft_fits_chunk, check_kv_window, check_latent_cache,
+    check_recurrent_state,
     check_servable, step_overlap)
 from easyparallellibrary_tpu.serving.resilience import (
     AdmissionController, BadStepPolicy, DEGRADE_LEVELS)
@@ -213,6 +214,18 @@ def _rows_up_to(resident, num_valid, most: int) -> int:
   under = np.clip(most - r, 0, n)
   return int(np.sum(under * (r + 1) + under * (under - 1) // 2
                     + (n - under) * most))
+
+
+def _slot_rows(resident, num_valid, window: int):
+  """``(context_rows, kv_window_rows)`` of a plan: over its live slots, the
+  rows under each slot's bound (``resident + num_valid``: what a full
+  layer must read) and the same with each slot's term held to ``window -
+  1 + num_valid`` (what a layer behind a window must read: the window's
+  reach behind the step's first query, and the step's own rows)."""
+  r, n = resident.astype(np.int64), num_valid.astype(np.int64)
+  bound = np.where(n > 0, r + n, 0)
+  return (int(np.sum(bound)),
+          int(np.sum(np.minimum(bound, np.where(n > 0, window - 1 + n, 0)))))
 
 
 def flat_width(num_slots: int, chunk: int) -> int:
@@ -410,6 +423,7 @@ class ContinuousBatchingEngine:
     if self.paged:
       check_recurrent_state(cfg, "the paged cache (serving.paged)")
       check_latent_cache(cfg, "the paged cache (serving.paged)")
+      check_kv_window(cfg, "the paged cache (serving.paged)")
     eff_batch = max_batch if max_batch is not None else conf.max_batch
     if self.paged:
       self.block_size = (block_size if block_size is not None
@@ -505,6 +519,25 @@ class ContinuousBatchingEngine:
           f"{self._track_prefix}/dsa_index_impl",
           {"impl": self.dsa_index_impl})
       self._sparse = (cfg.index_topk, cfg.sliding_window)
+    # Window layers over K/V PAIRS beside full ones
+    # (models/smallthinker.py): the ring write's and the windowed attend's
+    # lowerings, each resolved once by its own rule beside the full
+    # layers' pair above, and the window the step's row counters hold a
+    # slot's rows to (``_kv_window``); None for a model without such
+    # layers.
+    self.kv_win_write_impl = None if self.paged else (
+        kv_lib.kv_win_write_impl(cfg, self.num_slots, self.chunk, self.mesh))
+    self.kv_win_attn_impl = None if self.paged else (
+        kv_lib.kv_win_attn_impl(cfg, self.num_slots, self.chunk, self.mesh))
+    self._kv_window = None
+    if self.kv_win_write_impl is not None:
+      trace_lib.get_tracer().metadata(
+          f"{self._track_prefix}/kv_win_write_impl",
+          {"impl": self.kv_win_write_impl})
+      trace_lib.get_tracer().metadata(
+          f"{self._track_prefix}/kv_win_attn_impl",
+          {"impl": self.kv_win_attn_impl})
+      self._kv_window = cfg.sliding_window
     # What the contiguous cache holds of each kind of state (K/V,
     # recurrent state, latent rows) and the ORDER its leaves under a
     # cursor are kept in (``kv_order``: rows or positions,
@@ -525,6 +558,7 @@ class ContinuousBatchingEngine:
     if self.prefix_caching:
       check_recurrent_state(cfg, "prefix caching (serving.prefix_cache)")
       check_latent_cache(cfg, "prefix caching (serving.prefix_cache)")
+      check_kv_window(cfg, "prefix caching (serving.prefix_cache)")
     self.drafter = self._resolve_drafter(conf, drafter, speculative,
                                          draft_model, draft_params)
     if self.drafter is not None:
@@ -532,6 +566,7 @@ class ContinuousBatchingEngine:
           cfg, "speculative decoding (serving.speculative: rejected "
           "drafts roll back)")
       check_latent_cache(cfg, "speculative decoding (serving.speculative)")
+      check_kv_window(cfg, "speculative decoding (serving.speculative)")
     # Rows of the token-flat batch the contiguous step's position-wise
     # layers run on (``flat_width``), the plain step's and the speculating
     # one's alike; the scheduler keeps every plan, drafts included, within
@@ -569,6 +604,7 @@ class ContinuousBatchingEngine:
           cfg, "the guarded step (serving.resilience: a retried step "
           "needs the state it started from)")
       check_latent_cache(cfg, "the guarded step (serving.resilience)")
+      check_kv_window(cfg, "the guarded step (serving.resilience)")
     # Whether step k+1 is launched while step k still runs (``step``):
     # "on" for the plain step of the contiguous cache, whose one
     # dependency on step k, the sampled token, is handed on inside the
@@ -793,6 +829,12 @@ class ContinuousBatchingEngine:
                      f"({self.dsa_index_impl} index scores), "
                      f"{lay['window_leaves']} window rings "
                      f"{lay['window_bytes'] / 1e6:.1f} MB")
+      if self._kv_window is not None:
+        layout += (f": {lay['window_leaves']} K/V window rings "
+                   f"{lay['window_bytes'] / 1e6:.1f} MB "
+                   f"({self.kv_win_write_impl} ring write, "
+                   f"{self.kv_win_attn_impl} windowed attend) beside "
+                   f"{lay['kv_leaves']} full K/V leaves")
       if self._experts:
         layout += f", {self.moe_gmm_impl} expert matmul"
     get_logger().info(
@@ -899,6 +941,8 @@ class ContinuousBatchingEngine:
         "ssm_scan_impl": self.ssm_scan_impl,
         "moe_gmm_impl": self.moe_gmm_impl,
         "dsa_index_impl": self.dsa_index_impl,
+        "kv_win_write_impl": self.kv_win_write_impl,
+        "kv_win_attn_impl": self.kv_win_attn_impl,
         "step_overlap": self.step_overlap,
         "wasted_positions": sched.wasted_positions,
         "recompiles": self._compile_sentinel.recompiles,
@@ -1004,6 +1048,7 @@ class ContinuousBatchingEngine:
     recurrent = self._recurrent
     gmm_impl = self.moe_gmm_impl
     index_impl = self.dsa_index_impl
+    win_impls = (self.kv_win_write_impl, self.kv_win_attn_impl)
     experts = self._experts
 
     def step(params, kv, cursors, tokens, num_valid, reset, prev,
@@ -1026,6 +1071,9 @@ class ContinuousBatchingEngine:
         state_args["moe_gmm_impl"] = gmm_impl
       if index_impl is not None:
         state_args["dsa_index_impl"] = index_impl
+      if win_impls[0] is not None:
+        state_args["kv_win_write_impl"] = win_impls[0]
+        state_args["kv_win_attn_impl"] = win_impls[1]
       # Each slot's next-token logits sit at its LAST live chunk
       # position, and the head runs on that row alone; idle slots
       # (num_valid=0) read position 0 — garbage the scheduler never
@@ -1869,6 +1917,11 @@ class ContinuousBatchingEngine:
       selected_rows, window_rows = (
           _rows_up_to(plan.resident, plan.num_valid, k)
           for k in self._sparse)
+    if self._kv_window is not None:
+      # Two sums over the plan's live slots: the rows a full layer must
+      # read and the rows a layer behind the window must.
+      context_rows, kv_window_rows = _slot_rows(
+          plan.resident, plan.num_valid, self._kv_window)
     # Whether the step was launched with its predecessor in flight (0:
     # the pipeline was empty, the first step after idle or a drain), and
     # the positions it ran for requests that had retired by its commit.
@@ -1904,6 +1957,9 @@ class ContinuousBatchingEngine:
         tracer.counter("serving/index_rows", index_rows)
         tracer.counter("serving/selected_rows", selected_rows)
         tracer.counter("serving/window_rows", window_rows)
+      if self._kv_window is not None:
+        tracer.counter("serving/context_rows", context_rows)
+        tracer.counter("serving/kv_window_rows", kv_window_rows)
     if self.stats is not None:
       self.stats.note_step(
           active_slots=plan.active_slots, num_slots=self.num_slots,
@@ -1920,6 +1976,8 @@ class ContinuousBatchingEngine:
       if self._sparse is not None:
         self.stats.note_sparse_step(index_rows, selected_rows, window_rows,
                                     held_assignments)
+      if self._kv_window is not None:
+        self.stats.note_kv_window_step(context_rows, kv_window_rows)
       if self.paged:
         self.stats.note_blocks(self.scheduler.kv_blocks_free,
                                self.scheduler.kv_blocks_used,
@@ -1958,6 +2016,9 @@ class ContinuousBatchingEngine:
         record["index_rows"] = index_rows
         record["selected_rows"] = selected_rows
         record["window_rows"] = window_rows
+      if self._kv_window is not None:
+        record["context_rows"] = context_rows
+        record["kv_window_rows"] = kv_window_rows
       if self.paged:
         # The block-pool gauges (ROADMAP item 1 satellite): pool
         # occupancy, internal fragmentation, and preemption count under

@@ -20,7 +20,7 @@ never attendable because the mask only exposes positions the current
 request's own tokens have written (see slot_cache_attend's docstring;
 tests/test_serving.py asserts the no-leakage property).
 
-Six kinds of per-slot state live in the contiguous cache, chosen per
+Seven kinds of per-slot state live in the contiguous cache, chosen per
 layer from the model's own layer kinds (:func:`cache_leaves`).  Three are
 rows under the slot's cursor: the K/V pair of an attention layer; the
 LATENT leaf of multi-head latent attention (models/glm_moe.py), which is
@@ -28,10 +28,14 @@ ONE tensor a layer whose values are its keys' leading columns
 (``check_latent_cache`` refuses what only a K/V pair is built for); and
 the SPARSE_LATENT pair of a latent attention that selects its rows
 (models/dots3_note.py): that latent leaf and, beside it, the indexer's
-keys ``[num_slots, Lc, index_head_dim]``.  One is rows that do NOT grow
-with the served context: the WINDOW_LATENT leaf of a latent attention
-behind a window, a ring of ``cfg.ring_length(chunk)`` rows (position
-``p`` at row ``p mod R``), whose width and head count are its own.  Two
+keys ``[num_slots, Lc, index_head_dim]``.  Two are rows that do NOT grow
+with the served context, rings of ``cfg.ring_length(chunk)`` rows
+(position ``p`` at row ``p mod R``): the WINDOW_LATENT leaf of a latent
+attention behind a window, whose width and head count are its own, and
+the WINDOW_KV pair of an attention layer behind a window
+(models/smallthinker.py), ``cached_key`` / ``cached_value`` as an
+attention layer's in everything but their length, beside ordinary
+attention leaves in the same model: two kinds of K/V in one cache.  Two
 are recurrent, with no position axis, and no cursor can roll them back
 (serving/_capabilities.py ``check_recurrent_state``): a Mamba layer's
 convolution window and float32 scan state (models/jamba.py), and a CONV
@@ -84,6 +88,7 @@ from easyparallellibrary_tpu.models.dots3_note import (
 from easyparallellibrary_tpu.models.glm_moe import LATENT
 from easyparallellibrary_tpu.models.jamba import ATTENTION, MAMBA
 from easyparallellibrary_tpu.models.lfm2_moe import CONV
+from easyparallellibrary_tpu.models.smallthinker import WINDOW_KV
 
 # Layer kinds whose state is a recurrence's: no position axis, nothing a
 # cursor can roll back.
@@ -149,6 +154,13 @@ def has_latent_cache(cfg) -> bool:
   return bool(latent_kinds(cfg))
 
 
+def has_kv_window(cfg) -> bool:
+  """Whether some layer keeps its K/V pair as a ring behind a window,
+  which overwrites what a cursor moved back would need again
+  (serving/_capabilities.py refuses what would move one)."""
+  return WINDOW_KV in layer_kinds(cfg)
+
+
 def latent_leaf_shapes(cfg, kind: str, num_slots: int,
                        chunk: int) -> Dict[str, Tuple[int, ...]]:
   """The leaves a layer of a latent ``kind`` keeps, by name.  A model
@@ -170,30 +182,38 @@ def latent_leaf_shapes(cfg, kind: str, num_slots: int,
 
 def kv_heads(cfg) -> Tuple[int, int]:
   """``(H_kv, hd)`` of one cache row under a cursor: the model's K/V head
-  count (its query heads when it has no fewer) and the head size; for a
-  model with ONE latent attention one head of ``kv_lora_rank +
-  qk_rope_head_dim`` values (a model whose latent kinds differ has no one
-  answer: :func:`latent_leaf_shapes`)."""
+  count (its query heads when it has no fewer) and the head size, the
+  model's OWN where it says one (``cfg.head_dim``: models/smallthinker.py's
+  28 heads of 128 on a ``d_model`` of 2560), ``d_model / num_heads``
+  elsewhere; for a model with ONE latent attention one head of
+  ``kv_lora_rank + qk_rope_head_dim`` values (a model whose latent kinds
+  differ has no one answer: :func:`latent_leaf_shapes`)."""
   if LATENT in layer_kinds(cfg):
     return 1, cfg.latent_dim
-  if cfg.d_model % cfg.num_heads:
-    raise ValueError(f"d_model {cfg.d_model} must divide into "
-                     f"{cfg.num_heads} heads")
-  return (getattr(cfg, "num_kv_heads", None) or cfg.num_heads,
-          cfg.d_model // cfg.num_heads)
+  hd = getattr(cfg, "head_dim", None)
+  if hd is None:
+    if cfg.d_model % cfg.num_heads:
+      raise ValueError(f"d_model {cfg.d_model} must divide into "
+                       f"{cfg.num_heads} heads")
+    hd = cfg.d_model // cfg.num_heads
+  return getattr(cfg, "num_kv_heads", None) or cfg.num_heads, hd
 
 
-def kv_leaf_shape(cfg, num_slots: int, chunk: int) -> Tuple[int, ...]:
+def kv_leaf_shape(cfg, num_slots: int, chunk: int,
+                  ring: bool = False) -> Tuple[int, ...]:
   """Shape of one leaf of rows under a cursor, in the order it is kept
   (module docstring, order note): a K/V pair whose heads' width ``H_kv x
   hd`` is a whole number of 128-lane tiles, in a 16-bit or 32-bit float,
   is kept in rows, ``[num_slots, Lc, H_kv x hd]``; every other leaf (a
-  narrower pair, the latent leaf) ``[num_slots, Lc, H_kv, hd]``."""
+  narrower pair, the latent leaf) ``[num_slots, Lc, H_kv, hd]``.  ``ring``:
+  a window layer's leaf of the same pair, ``cfg.ring_length(chunk)`` rows
+  in place of ``Lc`` (kind :data:`WINDOW_KV`)."""
   if SPARSE_LATENT in layer_kinds(cfg):
     return latent_leaf_shapes(cfg, SPARSE_LATENT, num_slots,
                               chunk)["cached_latent"]
   Hkv, hd = kv_heads(cfg)
-  lead = (num_slots, cache_length(cfg, chunk))
+  lead = (num_slots, cfg.ring_length(chunk) if ring
+          else cache_length(cfg, chunk))
   if (not has_latent_cache(cfg) and (Hkv * hd) % 128 == 0
       and jnp.dtype(cfg.dtype) in (jnp.dtype(jnp.bfloat16),
                                    jnp.dtype(jnp.float32))):
@@ -226,16 +246,25 @@ def cache_leaves(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
     to select what the attend reads;
   * window latent: ``{"latent": {"cached_latent": [num_slots, R, 1,
     width]}}``, a ring of ``R = cfg.ring_length(chunk)`` rows whatever the
-    served context (:func:`latent_leaf_shapes`).
+    served context (:func:`latent_leaf_shapes`);
+  * window K/V: ``{"attn": {"cached_key", "cached_value"}}`` as an
+    attention layer's, each a ring of ``R`` rows (``[num_slots, R, H_kv x
+    hd]`` or ``[num_slots, R, H_kv, hd]``) whatever the served context.
   """
   kinds = layer_kinds(cfg)
   if ATTENTION in kinds or LATENT in kinds:
     kv = jax.ShapeDtypeStruct(kv_leaf_shape(cfg, num_slots, chunk),
                               cfg.dtype)
+  if WINDOW_KV in kinds:
+    kv_ring = jax.ShapeDtypeStruct(
+        kv_leaf_shape(cfg, num_slots, chunk, ring=True), cfg.dtype)
   out = {}
   for i, kind in enumerate(kinds):
     if kind == ATTENTION:
       out[f"block_{i}"] = {"attn": {"cached_key": kv, "cached_value": kv}}
+    elif kind == WINDOW_KV:
+      out[f"block_{i}"] = {"attn": {"cached_key": kv_ring,
+                                    "cached_value": kv_ring}}
     elif kind == LATENT:
       out[f"block_{i}"] = {"latent": {"cached_latent": kv}}
     elif kind in (SPARSE_LATENT, WINDOW_LATENT):
@@ -370,6 +399,38 @@ def slot_attn_impl(cfg, num_slots: int, chunk: int,
       sharded=sharded, head_dim=kv_heads(cfg)[1])
 
 
+def kv_win_write_impl(cfg, num_slots: int, chunk: int,
+                      mesh: Optional[Mesh] = None) -> Optional[str]:
+  """The lowering of the ring write of a window layer's K/V pair
+  (:data:`WINDOW_KV`): ``kv_write``'s rule on the ring's shape with
+  ``ring=True``, resolved apart from the full layers' write (one may take
+  its kernel where the other declines); ``None`` for a model without such
+  a layer."""
+  if not has_kv_window(cfg):
+    return None
+  from easyparallellibrary_tpu.kernels.kv_write import (
+      resolve_kv_write_impl)
+  return resolve_kv_write_impl(
+      kv_leaf_shape(cfg, num_slots, chunk, ring=True), cfg.dtype, chunk,
+      sharded=mesh is not None and mesh.size > 1, ring=True)
+
+
+def kv_win_attn_impl(cfg, num_slots: int, chunk: int,
+                     mesh: Optional[Mesh] = None) -> Optional[str]:
+  """The lowering of the attend over that ring (``slot_attn_kvwin``, the
+  pair form of the tile-grid attend of kernels/slot_attention.py),
+  resolved like :func:`kv_win_write_impl`; ``None`` for a model without a
+  window layer over K/V pairs."""
+  if not has_kv_window(cfg):
+    return None
+  from easyparallellibrary_tpu.kernels.slot_attention import (
+      resolve_tile_attn_impl)
+  return resolve_tile_attn_impl(
+      kv_leaf_shape(cfg, num_slots, chunk, ring=True), cfg.dtype, chunk,
+      cfg.num_heads, kv_heads(cfg)[1], ring=True,
+      sharded=mesh is not None and mesh.size > 1)
+
+
 def dsa_index_impl(cfg, num_slots: int, chunk: int,
                    mesh: Optional[Mesh] = None) -> Optional[str]:
   """The lowering of the index scores of a layer that selects what it
@@ -467,8 +528,8 @@ def cache_layout(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
   counts a Mamba layer's two leaves and a conv layer's one) and, for a
   model that has them, of latent rows (under a cursor, one leaf a layer),
   of an indexer's keys (``index_*``, under the cursor beside a latent
-  leaf) and of window rings (``window_*``, whose bytes do not depend on
-  the served context); and ``kv_order``, the order the leaves under a
+  leaf) and of window rings (``window_*``, latent rows or K/V pairs, whose
+  bytes do not depend on the served context); and ``kv_order``, the order the leaves under a
   cursor are kept in
   (``"rows"`` or ``"positions"``: module docstring, order note; ``None``
   for a model that keeps none), which says which form of the window write
@@ -476,12 +537,12 @@ def cache_layout(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
   ``serving/cache_layout``)."""
   names = {ATTENTION: "kv", MAMBA: "state", CONV: "state",
            LATENT: "latent", SPARSE_LATENT: "latent",
-           WINDOW_LATENT: "window"}
+           WINDOW_LATENT: "window", WINDOW_KV: "window"}
   kinds = layer_kinds(cfg)
   groups = ["kv", "state"]
   groups += ["latent"] * (LATENT in kinds or SPARSE_LATENT in kinds)
   groups += ["index"] * (SPARSE_LATENT in kinds)
-  groups += ["window"] * (WINDOW_LATENT in kinds)
+  groups += ["window"] * (WINDOW_LATENT in kinds or WINDOW_KV in kinds)
   out = {f"{name}_{what}": 0
          for name in groups for what in ("bytes", "leaves")}
   leaves = cache_leaves(cfg, num_slots, chunk)

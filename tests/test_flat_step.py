@@ -50,6 +50,8 @@ from perfbench.reference import glm4_moe_lite as glm_ref  # noqa: E402
 from perfbench.reference import jamba as jamba_ref  # noqa: E402
 from perfbench.reference import lfm2_moe as lfm2_ref  # noqa: E402
 from perfbench.runners import epl_dots3_note as dots3_glue  # noqa: E402
+from perfbench.reference import smallthinker as st_ref  # noqa: E402
+from perfbench.runners import epl_smallthinker as st_glue  # noqa: E402
 from perfbench.runners import epl_glm4_moe_lite as glm_glue  # noqa: E402
 from perfbench.runners import epl_jamba as jamba_glue  # noqa: E402
 from perfbench.runners import epl_lfm2_moe as lfm2_glue  # noqa: E402
@@ -111,6 +113,16 @@ FAMILIES = {"hybrid": (jamba_glue, jamba_ref, JAMBA_CFG),
 # Served in the two-width cases only: the shadow and the oracles above are
 # the four older families'.
 DOTS3 = (dots3_glue, dots3_ref, DOTS3_CFG)
+# tests/test_smallthinker.py's toy cut, one period: a full layer without
+# positions and three window layers whose K/V pair is a ring (14 heads on 2
+# of 8, window 8), 8 experts top-3 routed from the layer's input.
+ST_CFG = st_ref.SmallThinkerConfig(
+    hidden_size=64, num_attention_heads=14, num_key_value_heads=2, head_dim=8,
+    moe_ffn_hidden_size=32, moe_num_primary_experts=8,
+    moe_num_active_primary_experts=3, sliding_window_size=8,
+    sliding_window_layout=(0, 1, 1, 1), rope_layout=(0, 1, 1, 1),
+    vocab_size=VOCAB, n_positions=128, initializer_range=0.2)
+LATER = {"dots3": DOTS3, "smallthinker": (st_glue, st_ref, ST_CFG)}
 # What each family's own test file allows between the program's logits and
 # its plain reference's (tests/test_jamba.py, test_glm_moe.py,
 # test_lfm2_moe.py); the GPT-2 block against its own ``[B, S]`` forward.
@@ -125,9 +137,9 @@ def decoders():
   gpt = GPT(GPT_CFG)
   out = {"gpt2": (gpt, gpt.init(jax.random.PRNGKey(0),
                                 jnp.zeros((1, 4), jnp.int32))["params"])}
-  for name, (glue, ref, cfg) in dict(FAMILIES, dots3=DOTS3).items():
+  for name, (glue, ref, cfg) in dict(FAMILIES, **LATER).items():
     model, shell_of = glue.build_model(
-        cfg, dict(F32, ring_tile=8) if name == "dots3" else F32)
+        cfg, dict(F32, ring_tile=8) if name in LATER else F32)
     out[name] = (model, glue.program_params(
         cfg, ref.seed_key(SEED), shell_of(jnp.zeros((1, 8), jnp.int32))))
   return out
@@ -313,7 +325,7 @@ def _two_bursts(eng):
 # widths against ONE (``narrow_width`` patched to give the width back: the
 # same plans, the same tokens), each family and each twin.
 WIDTH_CASES = [(name, "plain", "full") for name in DECODERS] + [
-    (name, "plain", "one") for name in DECODERS + ("dots3",)] + [
+    (name, "plain", "one") for name in DECODERS + tuple(LATER)] + [
         ("gpt2", "spec", "one"), ("gpt2", "guarded", "one")]
 
 
@@ -411,7 +423,8 @@ def test_a_step_with_exactly_the_width_live_and_one_with_nothing(decoders,
 # outside the conditionals (models/gpt.py:SplitLayer): the hybrid's one
 # attention layer of four, every latent layer, LFM2's attention layer
 # between two convolutions.  A GPT-2 block's K/V pair stands inside.
-SPLIT = {"gpt2": 0, "hybrid": 1, "glm-experts": 3, "lfm2": 1, "dots3": 5}
+SPLIT = {"gpt2": 0, "hybrid": 1, "glm-experts": 3, "lfm2": 1, "dots3": 5,
+         "smallthinker": 4}
 
 
 def _conditionals(jaxpr):
@@ -423,7 +436,7 @@ def _conditionals(jaxpr):
       yield from _conditionals(sub)
 
 
-@pytest.mark.parametrize("name", DECODERS + ("dots3",))
+@pytest.mark.parametrize("name", DECODERS + tuple(LATER))
 def test_the_second_width_stands_round_the_layers_and_nothing_else(
     decoders, name, monkeypatch):
   """What the second width may cost in set-up is the position-wise layers,
@@ -475,7 +488,7 @@ def test_the_second_width_stands_round_the_layers_and_nothing_else(
   assert cases - cases_one == SPLIT[name] + 1
 
 
-@pytest.mark.parametrize("name", DECODERS + ("dots3",))
+@pytest.mark.parametrize("name", DECODERS + tuple(LATER))
 def test_a_step_reads_its_parameters_without_evaluating_an_initializer(
     decoders, name, monkeypatch):
   """Tracing a family's fused step asks flax's own ``Scope.param`` for

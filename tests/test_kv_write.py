@@ -790,6 +790,7 @@ def _abstract_step(model, slots, C, one_chip, **engine):
       kv_write_impl="pallas",
       slot_attn_impl="pallas", ssm_scan_impl=None, _recurrent=False,
       moe_gmm_impl=None, _experts=False, dsa_index_impl=None,
+      kv_win_write_impl=None, kv_win_attn_impl=None,
       _jit_step=lambda step, donate, **kw: jax.jit(
           step, donate_argnums=(1, 2))), **engine})
   step = ContinuousBatchingEngine._build_step(engine, True)
@@ -915,6 +916,39 @@ def test_dots3_step_compiled_for_v5e_holds_its_kernels(one_chip):
   assert not re.search(rf"\[{slots},{C},64,12832\]", text)
 
 
+def test_smallthinker_step_compiled_for_v5e_holds_its_kernels(one_chip):
+  """The fused step of a two-layer cut (a full layer without positions, a
+  window layer with rotary) of models/smallthinker.py at
+  SmallThinker-21BA3B's widths and its cell's geometry, 48 slots x chunk 32
+  at a context of 16,384, compiled for a described v5e as the engine builds
+  it, at two widths: two ``kv_write`` (the full pair, the ring), one
+  ``slot_attn`` (28 on 4 heads of 128 in rows) and two ``slot_attn_kvwin``
+  (the decoding slots, then the prefilling ones), outside the conditionals
+  and in the program ONCE (models/gpt.py ``SplitLayer``); two ``moe_gmm`` a
+  layer on either side of a conditional; no copy of a cache leaf, the rings
+  ``[48, 4224, 512]`` among them; no ``[slots, chunk, heads, rows]`` score
+  tensor."""
+  from easyparallellibrary_tpu.models.smallthinker import (
+      SmallThinker, SmallThinkerConfig)
+  epl.init()
+  slots, C = 48, 32
+  cfg = SmallThinkerConfig(window_layout=(0, 1), rope_layout=(0, 1))
+  step, args = _abstract_step(
+      SmallThinker(cfg), slots, C, one_chip, moe_gmm_impl="pallas",
+      _experts=True, kv_win_write_impl="pallas", kv_win_attn_impl="pallas")
+  text = _compiled_text(step, *args)
+  calls = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
+  assert [calls(n) for n in ("kv_write", "slot_attn", "slot_attn_kvwin",
+                             "moe_gmm")] == [2, 1, 2, 8], text.count(
+                                 "tpu_custom_call")
+  _assert_no_leaf_copied(text, args[1])
+  kv = args[1]
+  assert kv["block_0"]["attn"]["cached_key"].shape == (slots, 16416, 512)
+  assert kv["block_1"]["attn"]["cached_key"].shape == (slots, 4224, 512)
+  assert not re.search(rf"\[{slots},{C},28,(4224|16416)\]", text)
+  assert not re.search(rf"\[{slots},28,{C},(4224|16416)\]", text)
+
+
 def _flat_cuts():
   """Two-layer cuts of the four decoders at their cells' widths and, but
   for the expert decoder's chunk, geometry: ``name -> (model, slots, C, engine attributes, kernel calls a
@@ -995,7 +1029,8 @@ def test_flat_step_for_v5e_multiplies_the_width_and_heads_the_slots(
 @pytest.mark.parametrize("cell,chunk", [
     ("gpt2m-chat-steady", None), ("jamba2-3b-reasoning-backlog", None),
     ("glm47flash-agent-backlog", 16),
-    ("lfm2moe-chat-steady", None), ("dots3note-longdoc-backlog", None)])
+    ("lfm2moe-chat-steady", None), ("dots3note-longdoc-backlog", None),
+    ("smallthinker-mixedlen-backlog", None)])
 def test_a_serving_cells_step_compiled_for_v5e_copies_no_leaf(one_chip, cell,
                                                               chunk):
   """The step of each serving configuration of the benchmark AT ITS FULL
@@ -1035,6 +1070,8 @@ def test_a_serving_cells_step_compiled_for_v5e_copies_no_leaf(one_chip, cell,
       engine.update(moe_gmm_impl="pallas", _experts=True)
     if family == "dots3_note":
       engine.update(dsa_index_impl="pallas")
+    if family == "smallthinker":
+      engine.update(kv_win_write_impl="pallas", kv_win_attn_impl="pallas")
   slots = cell_file["engine"]["num_slots"]
   C = chunk or cell_file["engine"]["prefill_chunk"]
   T = flat_width(slots, C)
