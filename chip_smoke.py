@@ -200,10 +200,12 @@ class Sizes:
                                     dtype=jnp.float32),
         prompt_lens=(64, 160, 320, 512),
         new_tokens=32,
-        # Resident at the trainer's shape; streaming past
-        # _RESIDENT_MAX_BYTES (two heads keep the dense reference's
+        # Resident at the trainer's shape and at the train cell's shape
+        # a chip (gpt2l-train-zero1-4chip: 8 rows of 20 heads); streaming
+        # past _RESIDENT_MAX_BYTES (two heads keep the dense reference's
         # [S, S] scores inside HBM).
         flash_shapes=((2, 16, 1024, 64, jnp.bfloat16),
+                      (8, 20, 1024, 64, jnp.bfloat16),
                       (1, 2, 16384, 64, jnp.bfloat16)),
         paged_shape=(16, 16, 64, 16, 8),
         # The serving cells' leaves (1024 + one chunk of slack of GPT-2

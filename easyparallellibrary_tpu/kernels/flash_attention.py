@@ -8,15 +8,19 @@ with [block, d] matmuls.
 
 Algorithm: FlashAttention-2 style.  Forward saves (out, logsumexp);
 backward recomputes P blockwise from (q, k, lse) — one kernel produces
-dk/dv (grid over KV blocks), another dq (grid over Q blocks).
+dk/dv (walking KV blocks), another dq (walking Q blocks).
 
 Two implementations per kernel, dispatched by sequence length:
 
-* **resident** (short S): the non-blocked operands (K/V in the forward
-  and dq kernels, Q/dO in the dk/dv kernel) sit whole in VMEM and an
-  inner ``fori_loop`` walks their blocks — minimal grid overhead
-  (measured ~0.3 us/grid-step on v5e, which dominates at many-block
-  sizes), and the causal bounds skip dead blocks entirely.
+* **resident** (short S): a grid step owns a whole head, with q, k, v,
+  dO, o, lse and delta of the head in VMEM, and walks its ``[tq, tk]``
+  tiles in two nested loops.  Under a causal mask a row tile walks the
+  key tiles wholly under the diagonal with no mask arithmetic, then the
+  tile or tiles the diagonal crosses with one compare and one select;
+  tiles beyond it are never touched (:func:`causal_tile_counts`).  The
+  tile is not the grid's block: it is chosen for the chip
+  (:func:`_default_block`).  Heads of up to ``_UNROLL_PAIRS`` pairs
+  (S 1024) are walked by loops unrolled at trace time.
 * **streaming** (long S): a fourth grid dimension streams the inner
   blocks with VMEM scratch accumulators carried across steps, so VMEM
   holds only [block, D] tiles and usage is INDEPENDENT of S (the
@@ -25,11 +29,22 @@ Two implementations per kernel, dispatched by sequence length:
   the last live block, so fully-masked blocks are neither fetched
   (Mosaic elides the DMA when the mapped block index repeats) nor
   computed (``pl.when``), and blocks default wider (1024) to amortize
-  grid-step overhead.
+  the grid's steps.
 
-The crossover (``_RESIDENT_MAX_BYTES``) is conservative: resident wins
-measured 1.7x at S=2048 and ~13% at S=8192/D=64; streaming is the only
-option past the VMEM wall.
+Measured on one TPU v5e, the three kernels alone at the train cell's
+shape a chip (B 8, H 20, S 1024, D 64, bfloat16, causal; microseconds a
+call of ``flash_fwd`` / ``flash_dkv`` / ``flash_dq``, device time of the
+custom calls in a profiler trace; PERF.md section 6, PR 43, has the whole
+sweep).  Before: blocks of 512 as the grid's blocks, the mask on every
+block, 738 / 1017 / 645.  Unrolled tiles of 256: 413 / 602 / 458; of
+512: 425 / 687 / 523; of 128 (64 tiles a head, with ``_UNROLL_TILES``
+lifted): 440 / 596 / 550.  The same tiles under
+``fori_loop``: 256 1095 / 1218 / 1031, 512 833 / 981 / 842: an
+iteration's chain of matmul, lane reduce, exp and matmul is paid a tile
+and nothing overlaps it, which is what made small blocks slow, not the
+grid's steps.  At D 64 every product fills half the MXU, and the backward
+kernels are bound by it.  The crossover (``_RESIDENT_MAX_BYTES``) is the
+VMEM wall: streaming is the only option past it.
 
 Used by models via ``attn_impl="pallas_flash"`` and as the local block of
 ring attention.  Off-TPU the kernels run in Pallas interpreter mode so
@@ -40,12 +55,12 @@ from __future__ import annotations
 
 import functools
 import json
+import math
 import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -103,122 +118,282 @@ def _score_tile(qblk, kblk, q_start, k_start, causal: bool, scale: float):
 _RESIDENT_MAX_BYTES = 1024 * 1024
 
 
+def _scale_folds(scale: float, dtype) -> bool:
+  """Whether the softmax scale can be folded into a ``[tile, D]`` operand
+  of the score product instead of multiplying the ``[tq, tk]`` score
+  tile: exact when the scale is a power of two (D 16, 64, 256) and the
+  operand is a binary float, where the multiply only moves exponents."""
+  return (math.frexp(scale)[0] == 0.5
+          and jnp.issubdtype(dtype, jnp.floating))
+
+
+def _clamp(x, n: int):
+  return min(x, n) if isinstance(x, int) else jnp.minimum(x, n)
+
+
+def _key_tiles(i, tq: int, tk: int, num_k: int, causal: bool):
+  """``(full, live)`` for row tile ``i``: key tiles ``[0, full)`` lie
+  wholly at or under the diagonal and are walked with no mask, tiles
+  ``[full, live)`` are crossed by it and are walked with the mask, tiles
+  from ``live`` on hold no live pair and are skipped.  Plain arithmetic
+  on a Python int (:func:`causal_tile_counts`, an unrolled walk) or on a
+  ``fori_loop``'s index alike.  Without a mask every tile is full."""
+  if not causal:
+    return num_k, num_k
+  return (_clamp((i * tq + 1) // tk, num_k),
+          _clamp(((i + 1) * tq + tk - 1) // tk, num_k))
+
+
+def _row_tiles(j, tq: int, tk: int, num_q: int, causal: bool):
+  """``(lo, full)`` for key tile ``j``, the dK/dV kernel's view of the
+  same triangle: row tiles before ``lo`` are skipped, ``[lo, full)`` are
+  crossed by the diagonal, tiles from ``full`` on lie wholly under it."""
+  if not causal:
+    return 0, 0
+  return (_clamp((j * tk) // tq, num_q),
+          _clamp(((j + 1) * tk + tq - 2) // tq, num_q))
+
+
+def causal_tile_counts(S: int, Skv: int, tq: int, tk: int):
+  """``(unmasked, masked, skipped)``: the ``[tq, tk]`` tiles of a causal
+  ``[S, Skv]`` call that the resident kernels walk with no mask
+  arithmetic, walk with the mask, and never touch.  Shapes decide it, so
+  it is static; the kernels' loop bounds are the same arithmetic
+  (:func:`_key_tiles`).  Computed pairs are ``(unmasked + masked)
+  * tq * tk`` against the triangle's ``sum_q min(q + 1, Skv)``."""
+  num_q, num_k = S // tq, Skv // tk
+  unmasked = masked = 0
+  for i in range(num_q):
+    full, live = _key_tiles(i, tq, tk, num_k, True)
+    unmasked += full
+    masked += live - full
+  return unmasked, masked, num_q * num_k - unmasked - masked
+
+
+# A head of at most this many (query, key) pairs in at most this many tiles
+# is walked by loops unrolled at trace time (every bound a Python int), so
+# that the scheduler overlaps one tile's matmuls with its neighbour's
+# softmax: an iteration's chain of matmul, lane reduce, exp and matmul is
+# ~500 cycles whatever the tile's size, and a ``fori_loop`` pays it a
+# tile.  Longer heads keep ``fori_loop`` on the same bounds: unrolled,
+# their temporaries overflow the kernel's VMEM (v5e: S 2048 in float32 at
+# tiles of 256, S 4096 in bfloat16 at 512 are refused at compile time);
+# the cap on tiles bounds the kernel's code, which the default tile never
+# reaches (16 tiles at S 1024).
+_UNROLL_PAIRS = 1024 * 1024
+_UNROLL_TILES = 16
+
+
+def _tile_start(i, t: int):
+  return i * t if isinstance(i, int) else pl.multiple_of(i * t, t)
+
+
+def _walk(lo, hi, body, carry, unroll: bool):
+  """``fori_loop``, or its trips unrolled at trace time (every bound of
+  an unrolled head is a Python int).  A range that is empty by its Python
+  bounds (the masked walk of a call with no mask) traces nothing."""
+  if isinstance(lo, int) and isinstance(hi, int) and lo >= hi:
+    return carry
+  if unroll:
+    for i in range(lo, hi):
+      carry = body(i, carry)
+    return carry
+  return jax.lax.fori_loop(lo, hi, body, carry)
+
+
+def _rel_pos(rows: int, cols: int, row_axis: int):
+  """``[rows, cols]`` int32 of (row position - key position) within a
+  tile whose query rows run along ``row_axis``.  A pair at tile offsets
+  ``(q0, k0)`` is live iff ``rel >= k0 - q0``: one compare against a
+  scalar on the tiles the diagonal crosses, none elsewhere."""
+  r = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), row_axis)
+  c = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1 - row_axis)
+  return r - c
+
+
+_NT = (((1,), (1,)), ((), ()))      # [m, d] x [n, d] -> [m, n]
+_NN = (((1,), (0,)), ((), ()))      # [m, n] x [n, d] -> [m, d]
+
+
+def _dot(a, b, dims):
+  # Operands stay in the storage dtype (bf16 on the bench path): the MXU
+  # multiplies bf16 natively and accumulates in fp32.
+  return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _head_block(rows: int, cols: int):
+  """A head's whole ``[rows, cols]`` of a ``[B, H, rows, cols]`` array, on
+  the resident kernels' ``(B, H)`` grid."""
+  return pl.BlockSpec((1, 1, rows, cols), lambda b, h: (b, h, 0, 0))
+
+
 def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                         block_k: int, causal: bool, scale: float):
-  bq, d = q_ref.shape[2], q_ref.shape[3]
-  seq = k_ref.shape[2]
-  qi = pl.program_id(2)
-  # Matmul inputs stay in the storage dtype (bf16 on the bench path): the
-  # MXU multiplies bf16 natively with fp32 accumulation
-  # (preferred_element_type), which is ~4x the fp32-matmul rate on v5e.
-  # Upcasting the operands first would force full fp32 matmuls — measured
-  # at a large fraction of the kernel's runtime.  Softmax stays fp32.
-  q = q_ref[0, 0]                                        # [BQ, D]
+                         tq: int, tk: int, causal: bool, scale: float,
+                         unroll: bool):
+  """One head a grid step: q, k, v, o and lse whole in VMEM, the
+  ``[tq, tk]`` tiles of the head walked by two nested loops."""
+  d = q_ref.shape[3]
+  num_q, num_k = q_ref.shape[2] // tq, k_ref.shape[2] // tk
+  fold = _scale_folds(scale, q_ref.dtype)
+  rel = _rel_pos(tq, tk, 0) if causal else None
 
-  num_kv = seq // block_k
-  if causal:
-    # Only KV blocks at or before this Q block's diagonal participate.
-    hi = jnp.minimum(((qi + 1) * bq + block_k - 1) // block_k, num_kv)
-  else:
-    hi = num_kv
+  def row_tile(i, carry):
+    q0 = _tile_start(i, tq)
+    q = q_ref[0, 0, pl.ds(q0, tq), :]                      # [tq, D]
+    if fold:
+      q = (q * scale).astype(q.dtype)
 
-  def body(j, carry):
-    m, l, acc = carry
-    kblk = k_ref[0, 0, pl.ds(j * block_k, block_k), :]
-    vblk = v_ref[0, 0, pl.ds(j * block_k, block_k), :]
-    s = _score_tile(q, kblk, qi * bq, j * block_k, causal, scale)
-    new_m = jnp.maximum(m, jnp.max(s, axis=-1))
-    p = jnp.exp(s - new_m[:, None])
-    corr = jnp.exp(m - new_m)
-    l = l * corr + jnp.sum(p, axis=-1)
-    acc = acc * corr[:, None] + jax.lax.dot_general(
-        p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return new_m, l, acc
+    def key_tile(j, carry, masked):
+      m, l, acc = carry
+      k0 = _tile_start(j, tk)
+      kblk = k_ref[0, 0, pl.ds(k0, tk), :]                 # [tk, D]
+      vblk = v_ref[0, 0, pl.ds(k0, tk), :]
+      s = _dot(q, kblk, _NT)                               # [tq, tk] fp32
+      if not fold:
+        s = s * scale
+      if masked:
+        s = jnp.where(rel >= k0 - q0, s, NEG_INF)
+      new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+      p = jnp.exp(s - new_m)
+      corr = jnp.exp(m - new_m)
+      l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+      acc = acc * corr + _dot(p.astype(vblk.dtype), vblk, _NN)
+      return new_m, l, acc
 
-  m0 = jnp.full((bq,), NEG_INF, jnp.float32)
-  l0 = jnp.zeros((bq,), jnp.float32)
-  acc0 = jnp.zeros((bq, d), jnp.float32)
-  m, l, acc = jax.lax.fori_loop(0, hi, body, (m0, l0, acc0))
+    state = (jnp.full((tq, 1), NEG_INF, jnp.float32),
+             jnp.zeros((tq, 1), jnp.float32),
+             jnp.zeros((tq, d), jnp.float32))
+    full, live = _key_tiles(i, tq, tk, num_k, causal)
+    state = _walk(0, full, functools.partial(key_tile, masked=False), state,
+                  unroll)
+    state = _walk(full, live, functools.partial(key_tile, masked=True),
+                  state, unroll)
+    m, l, acc = state
 
-  l_safe = jnp.maximum(l, 1e-30)
-  o_ref[0, 0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-  lse = (m + jnp.log(l_safe)).astype(jnp.float32)
-  lse_ref[0, 0] = jnp.broadcast_to(lse[None, :], (8, bq))
+    l_safe = jnp.maximum(l, 1e-30)
+    o_ref[0, 0, pl.ds(q0, tq), :] = (acc / l_safe).astype(o_ref.dtype)
+    # TPU tiling wants the last two dims (8, 128)-aligned, so the [tq]
+    # logsumexp row is broadcast across 8 sublanes: lse is [B, H, 8, S].
+    lse = (m + jnp.log(l_safe))[:, 0]
+    lse_ref[0, 0, :, pl.ds(q0, tq)] = jnp.broadcast_to(lse[None, :],
+                                                       (8, tq))
+    return carry
+
+  _walk(0, num_q, row_tile, 0, unroll)
 
 
 def _bwd_dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                             delta_ref, dk_ref, dv_ref, *, block_q: int,
-                             causal: bool, scale: float):
-  bk, d = k_ref.shape[2], k_ref.shape[3]
-  seq = q_ref.shape[2]
-  ki = pl.program_id(2)
-  kblk = k_ref[0, 0]                                      # [BK, D]
-  vblk = v_ref[0, 0]
+                             delta_ref, dk_ref, dv_ref, *, tq: int,
+                             tk: int, causal: bool, scale: float,
+                             unroll: bool):
+  """dK/dV of one head a grid step.  The score tile is built TRANSPOSED,
+  ``[tk, tq]`` = k q^T: both accumulating products (p^T dO, ds^T q) are
+  then plain ``[tk, tq] x [tq, D]`` matmuls with no transpose of a score
+  tile, and lse / delta broadcast along sublanes straight from the
+  ``[8, S]`` rows they are stored in."""
+  d = k_ref.shape[3]
+  num_q, num_k = q_ref.shape[2] // tq, k_ref.shape[2] // tk
+  fold = _scale_folds(scale, k_ref.dtype)
+  rel = _rel_pos(tk, tq, 1) if causal else None
 
-  num_q = seq // block_q
-  lo = (ki * bk) // block_q if causal else 0
+  def key_tile(j, carry):
+    k0 = _tile_start(j, tk)
+    kblk = k_ref[0, 0, pl.ds(k0, tk), :]                   # [tk, D]
+    vblk = v_ref[0, 0, pl.ds(k0, tk), :]
+    if fold:
+      kblk = (kblk * scale).astype(kblk.dtype)
 
-  def body(i, carry):
-    dk, dv = carry
-    qblk = q_ref[0, 0, pl.ds(i * block_q, block_q), :]    # [BQ, D]
-    doblk = do_ref[0, 0, pl.ds(i * block_q, block_q), :]
-    lse = lse_ref[0, 0, 0, pl.ds(i * block_q, block_q)]      # [BQ]
-    delta = delta_ref[0, 0, 0, pl.ds(i * block_q, block_q)]  # [BQ]
-    s = _score_tile(qblk, kblk, i * block_q, ki * bk, causal, scale)
-    p = jnp.exp(s - lse[:, None])                         # [BQ, BK]
-    dv = dv + jax.lax.dot_general(p.astype(doblk.dtype), doblk,
-                                  (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-    dp = jax.lax.dot_general(doblk, vblk, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None])                        # [BQ, BK]
-    dk = dk + jax.lax.dot_general(ds.astype(qblk.dtype), qblk,
-                                  (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-    return dk, dv
+    def row_tile(i, carry, masked):
+      dk, dv = carry
+      q0 = _tile_start(i, tq)
+      qblk = q_ref[0, 0, pl.ds(q0, tq), :]                 # [tq, D]
+      doblk = do_ref[0, 0, pl.ds(q0, tq), :]
+      lse = lse_ref[0, 0, 0:1, pl.ds(q0, tq)]              # [1, tq]
+      delta = delta_ref[0, 0, 0:1, pl.ds(q0, tq)]
+      st = _dot(kblk, qblk, _NT)                           # [tk, tq] fp32
+      if not fold:
+        st = st * scale
+      if masked:
+        st = jnp.where(rel >= k0 - q0, st, NEG_INF)
+      pt = jnp.exp(st - lse)
+      dv = dv + _dot(pt.astype(doblk.dtype), doblk, _NN)
+      dpt = _dot(vblk, doblk, _NT)
+      dst = pt * (dpt - delta)
+      dk = dk + _dot(dst.astype(qblk.dtype), qblk, _NN)
+      return dk, dv
 
-  dk0 = jnp.zeros((bk, d), jnp.float32)
-  dv0 = jnp.zeros((bk, d), jnp.float32)
-  dk, dv = jax.lax.fori_loop(lo, num_q, body, (dk0, dv0))
-  # dk accumulates ds @ q with unscaled q; fold the s-scale in once here.
-  dk_ref[0, 0] = (dk * scale).astype(dk_ref.dtype)
-  dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+    acc = (jnp.zeros((tk, d), jnp.float32), jnp.zeros((tk, d), jnp.float32))
+    lo, full = _row_tiles(j, tq, tk, num_q, causal)
+    acc = _walk(lo, full, functools.partial(row_tile, masked=True), acc,
+                unroll)
+    acc = _walk(full, num_q, functools.partial(row_tile, masked=False), acc,
+                unroll)
+    dk, dv = acc
+    # dk accumulated ds^T q with unscaled q: the s-scale goes in once here.
+    dk_ref[0, 0, pl.ds(k0, tk), :] = (dk * scale).astype(dk_ref.dtype)
+    dv_ref[0, 0, pl.ds(k0, tk), :] = dv.astype(dv_ref.dtype)
+    return carry
+
+  _walk(0, num_k, key_tile, 0, unroll)
 
 
 def _bwd_dq_kernel_resident(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                            delta_ref, dq_ref, *, block_k: int,
-                            causal: bool, scale: float):
-  bq, d = q_ref.shape[2], q_ref.shape[3]
-  seq = k_ref.shape[2]
-  qi = pl.program_id(2)
-  qblk = q_ref[0, 0]
-  doblk = do_ref[0, 0]
-  lse = lse_ref[0, 0, 0]
-  delta = delta_ref[0, 0, 0]
+                            delta_ref, dq_ref, *, tq: int, tk: int,
+                            causal: bool, scale: float, unroll: bool):
+  """dQ of one head a grid step, tiles walked as the forward walks them."""
+  d = q_ref.shape[3]
+  num_q, num_k = q_ref.shape[2] // tq, k_ref.shape[2] // tk
+  fold = _scale_folds(scale, q_ref.dtype)
+  rel = _rel_pos(tq, tk, 0) if causal else None
 
-  num_kv = seq // block_k
-  hi = jnp.minimum(((qi + 1) * bq + block_k - 1) // block_k,
-                   num_kv) if causal else num_kv
+  def row_tile(i, carry):
+    q0 = _tile_start(i, tq)
+    qblk = q_ref[0, 0, pl.ds(q0, tq), :]                   # [tq, D]
+    doblk = do_ref[0, 0, pl.ds(q0, tq), :]
+    lse = lse_ref[0, 0, 0, pl.ds(q0, tq)][:, None]         # [tq, 1]
+    delta = delta_ref[0, 0, 0, pl.ds(q0, tq)][:, None]
+    if fold:
+      qblk = (qblk * scale).astype(qblk.dtype)
 
-  def body(j, dq):
-    kblk = k_ref[0, 0, pl.ds(j * block_k, block_k), :]
-    vblk = v_ref[0, 0, pl.ds(j * block_k, block_k), :]
-    s = _score_tile(qblk, kblk, qi * bq, j * block_k, causal, scale)
-    p = jnp.exp(s - lse[:, None])
-    dp = jax.lax.dot_general(doblk, vblk, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None])
-    return dq + jax.lax.dot_general(ds.astype(kblk.dtype), kblk,
-                                    (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
+    def key_tile(j, dq, masked):
+      k0 = _tile_start(j, tk)
+      kblk = k_ref[0, 0, pl.ds(k0, tk), :]                 # [tk, D]
+      vblk = v_ref[0, 0, pl.ds(k0, tk), :]
+      s = _dot(qblk, kblk, _NT)                            # [tq, tk] fp32
+      if not fold:
+        s = s * scale
+      if masked:
+        s = jnp.where(rel >= k0 - q0, s, NEG_INF)
+      p = jnp.exp(s - lse)
+      dp = _dot(doblk, vblk, _NT)
+      ds = p * (dp - delta)
+      return dq + _dot(ds.astype(kblk.dtype), kblk, _NN)
 
-  dq = jax.lax.fori_loop(0, hi, body, jnp.zeros((bq, d), jnp.float32))
-  dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
+    dq = jnp.zeros((tq, d), jnp.float32)
+    full, live = _key_tiles(i, tq, tk, num_k, causal)
+    dq = _walk(0, full, functools.partial(key_tile, masked=False), dq,
+               unroll)
+    dq = _walk(full, live, functools.partial(key_tile, masked=True), dq,
+               unroll)
+    dq_ref[0, 0, pl.ds(q0, tq), :] = (dq * scale).astype(dq_ref.dtype)
+    return carry
+
+  _walk(0, num_q, row_tile, 0, unroll)
 
 
 def _resident_ok(S: int, Skv: int, D: int, itemsize: int) -> bool:
   return max(S, Skv) * D * itemsize <= _RESIDENT_MAX_BYTES
+
+
+def _walk_of(S: int, Skv: int, D: int, itemsize: int, bq: int,
+             bk: int) -> str:
+  """How the kernels walk a head of this call: ``stream`` past the VMEM
+  wall, else resident, ``unrolled`` or ``looped`` (``_UNROLL_PAIRS``)."""
+  if not _resident_ok(S, Skv, D, itemsize):
+    return "stream"
+  tiles = (S // bq) * (Skv // bk)
+  return ("unrolled" if S * Skv <= _UNROLL_PAIRS and tiles <= _UNROLL_TILES
+          else "looped")
 
 
 def _kv_clamp_idx(bq: int, bk: int, causal: bool):
@@ -245,11 +420,11 @@ def _q_clamp_idx(bq: int, bk: int, causal: bool, row: bool = False):
   return idx
 
 
-def _compiler_params(n_outer: int):
+def _compiler_params(n_outer: int, interpret: bool):
   """Outer grid dims parallel, innermost (streamed/accumulated) dim
   sequential.  Interpret mode ignores TPU compiler params but rejects
   unknown ones on some versions — only pass them on real TPU."""
-  if _interpret():
+  if interpret:
     return None
   return pltpu.CompilerParams(
       dimension_semantics=("parallel",) * n_outer + ("arbitrary",))
@@ -318,27 +493,39 @@ def _fwd(q, k, v, causal: bool, block_q: int, block_k: int):
   bq = min(block_q, S)
   bk = min(block_k, Skv)
   _check_blocks(S, Skv, bq, bk)
-  scale = 1.0 / np.sqrt(D)
+  return _fwd_call(q, k, v, causal=causal, bq=bq, bk=bk,
+                   walk=_walk_of(S, Skv, D, q.dtype.itemsize, bq, bk),
+                   interpret=_interpret())
 
-  if _resident_ok(S, Skv, D, q.dtype.itemsize):
+
+# Jitted so that the layers of a model share ONE trace and one Mosaic
+# lowering of a kernel: the unrolled bodies are long, and traced a layer
+# they added 7 s to the set-up of a 36-layer train step.  What the trace
+# depends on beside its arguments (backend, the regime's limits) is read
+# by the caller and passed in as static arguments.
+@functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "walk",
+                                             "interpret"))
+def _fwd_call(q, k, v, *, causal: bool, bq: int, bk: int, walk: str,
+              interpret: bool):
+  B, H, S, D = q.shape
+  Skv = k.shape[2]
+  scale = 1.0 / math.sqrt(D)
+
+  if walk != "stream":
+    # One head a grid step; (bq, bk) is the tile its loops walk.
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel_resident, block_k=bk, causal=causal,
-                          scale=scale),
-        grid=(B, H, S // bq),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, Skv, D), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, Skv, D), lambda b, h, i: (b, h, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, 8, bq), lambda b, h, i: (b, h, 0, i)),
-        ],
+        functools.partial(_fwd_kernel_resident, tq=bq, tk=bk,
+                          causal=causal, scale=scale,
+                          unroll=walk == "unrolled"),
+        grid=(B, H),
+        in_specs=[_head_block(S, D), _head_block(Skv, D),
+                  _head_block(Skv, D)],
+        out_specs=[_head_block(S, D), _head_block(8, S)],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, 8, S), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret,
         name=FLASH_FWD,
     )(q, k, v)
     return out, lse
@@ -370,8 +557,8 @@ def _fwd(q, k, v, causal: bool, block_q: int, block_k: int):
           pltpu.VMEM((bq, 128), jnp.float32),            # running denom
           pltpu.VMEM((bq, D), jnp.float32),              # output acc
       ],
-      compiler_params=_compiler_params(3),
-      interpret=_interpret(),
+      compiler_params=_compiler_params(3, interpret),
+      interpret=interpret,
       name=FLASH_FWD,
   )(q, k, v)
   return out, lse
@@ -472,49 +659,44 @@ def _bwd_kernels(q, k, v, dout, lse8, delta8, causal, block_q, block_k):
   bq = min(block_q, S)
   bk = min(block_k, Skv)
   _check_blocks(S, Skv, bq, bk)
-  scale = 1.0 / np.sqrt(D)
+  return _bwd_call(q, k, v, dout, lse8, delta8, causal=causal, bq=bq, bk=bk,
+                   walk=_walk_of(S, Skv, D, q.dtype.itemsize, bq, bk),
+                   interpret=_interpret())
 
-  if _resident_ok(S, Skv, D, q.dtype.itemsize):
+
+@functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "walk",
+                                             "interpret"))
+def _bwd_call(q, k, v, dout, lse8, delta8, *, causal: bool, bq: int,
+              bk: int, walk: str, interpret: bool):
+  B, H, S, D = q.shape
+  Skv = k.shape[2]
+  scale = 1.0 / math.sqrt(D)
+
+  if walk != "stream":
+    in_specs = [_head_block(S, D), _head_block(Skv, D), _head_block(Skv, D),
+                _head_block(S, D), _head_block(8, S), _head_block(8, S)]
+    tiles = dict(tq=bq, tk=bk, causal=causal, scale=scale,
+                 unroll=walk == "unrolled")
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel_resident, block_q=bq,
-                          causal=causal, scale=scale),
-        grid=(B, H, Skv // bk),
-        in_specs=[
-            pl.BlockSpec((1, 1, S, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, S, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, 8, S), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, 8, S), lambda b, h, j: (b, h, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j: (b, h, j, 0)),
-        ],
+        functools.partial(_bwd_dkv_kernel_resident, **tiles),
+        grid=(B, H),
+        in_specs=in_specs,
+        out_specs=[_head_block(Skv, D), _head_block(Skv, D)],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Skv, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, Skv, D), q.dtype),
         ],
-        interpret=_interpret(),
+        interpret=interpret,
         name=FLASH_DKV,
     )(q, k, v, dout, lse8, delta8)
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel_resident, block_k=bk,
-                          causal=causal, scale=scale),
-        grid=(B, H, S // bq),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, Skv, D), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, Skv, D), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, 8, bq), lambda b, h, i: (b, h, 0, i)),
-            pl.BlockSpec((1, 1, 8, bq), lambda b, h, i: (b, h, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, D),
-                               lambda b, h, i: (b, h, i, 0)),
+        functools.partial(_bwd_dq_kernel_resident, **tiles),
+        grid=(B, H),
+        in_specs=in_specs,
+        out_specs=_head_block(S, D),
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        interpret=_interpret(),
+        interpret=interpret,
         name=FLASH_DQ,
     )(q, k, v, dout, lse8, delta8)
     return dq, dk, dv
@@ -550,8 +732,8 @@ def _bwd_kernels(q, k, v, dout, lse8, delta8, causal, block_q, block_k):
           pltpu.VMEM((bk, D), jnp.float32),
           pltpu.VMEM((bk, D), jnp.float32),
       ],
-      compiler_params=_compiler_params(3),
-      interpret=_interpret(),
+      compiler_params=_compiler_params(3, interpret),
+      interpret=interpret,
       name=FLASH_DKV,
   )(q, k, v, dout, lse8, delta8)
 
@@ -574,8 +756,8 @@ def _bwd_kernels(q, k, v, dout, lse8, delta8, causal, block_q, block_k):
                              lambda b, h, i, j: (b, h, i, 0)),
       out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
       scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-      compiler_params=_compiler_params(3),
-      interpret=_interpret(),
+      compiler_params=_compiler_params(3, interpret),
+      interpret=interpret,
       name=FLASH_DQ,
   )(q, k, v, dout, lse8, delta8)
   return dq, dk, dv
@@ -674,11 +856,12 @@ def flash_attention_lse(q, k, v, causal: bool = True,
   return out.transpose(0, 2, 1, 3), lse.transpose(0, 2, 1)
 
 
-# Autotuned block widths: {(S, d, itemsize): want}, loaded lazily from
-# flash_block_table.json next to this module when present (to be
-# written by an autotune run on real hardware; format
-# {"device": <device_kind>, "entries": {"S:d:itemsize": want}}).
-# Entries override the 512/1024 heuristic for their exact shape ONLY
+# Autotuned tile widths: {(S, d, itemsize): want}, loaded lazily from
+# flash_block_table.json next to this module when present (format
+# {"device": <device_kind>, "entries": {"S:d:itemsize": want}}).  None is
+# shipped: the v5e sweep's choice at the train cell's shape is what
+# `_heuristic_want` gives every resident shape up to S 1024.
+# Entries override the heuristic for their exact shape ONLY
 # when the file's device kind matches the current backend — widths
 # tuned for one TPU generation must not silently apply to another (or
 # to CPU test runs).  Loading is lazy because it consults
@@ -713,10 +896,14 @@ def set_block_want(S: int, d: int, itemsize: int, want: int) -> None:
 
 
 def _heuristic_want(S: int, d: int, itemsize: int) -> int:
-  """The untuned block-width default: 512 in the resident regime, 1024
-  once the streaming kernels kick in.  Single source of truth — the
-  autotune benchmark compares its candidates against THIS."""
-  return 512 if S * d * itemsize <= _RESIDENT_MAX_BYTES else 1024
+  """The tile a shape the table lacks is walked in.  Resident regime: 256
+  up to S 1024, whose heads are walked unrolled (the v5e sweep at the
+  train cell's shape, module docstring); 512 beyond, where ``fori_loop``
+  pays its chain a tile and the larger tile amortises it; streaming
+  regime: 1024."""
+  if S * d * itemsize > _RESIDENT_MAX_BYTES:
+    return 1024
+  return 256 if S <= 1024 else 512
 
 
 def _default_block(S: int, want: int = 0, *, d: int,
@@ -726,11 +913,10 @@ def _default_block(S: int, want: int = 0, *, d: int,
   0 when NO such block divides S (e.g. S = 515) — callers must either
   raise or fall back to a non-kernel path, never truncate the grid.
 
-  Default `want`: the autotuned table entry for (S, d, itemsize) when
-  one exists, else 512 in the resident regime and 1024 once S·d is long
-  enough that the streaming kernels kick in (wider blocks amortize the
-  ~0.3 us/grid-step overhead that otherwise dominates: measured 1.4x at
-  S=4096-8192 over 512 blocks).  `d` must match the head dim the kernel
+  In the resident regime the block is the TILE a head is walked in, in
+  the streaming regime the grid's block.  Default `want`: the autotuned
+  table entry for (S, d, itemsize) when one exists, else
+  :func:`_heuristic_want`.  `d` must match the head dim the kernel
   will run with so this agrees with `_resident_ok`'s dispatch."""
   if not want:
     want = _ensure_block_table().get((S, d, itemsize))
@@ -782,13 +968,10 @@ def flash_attention(q, k, v, causal: bool = True,
   """Flash attention over [B, S, H, D] inputs (models' layout).
 
   The scale 1/sqrt(D) is applied inside the kernel.  An explicitly
-  passed block size must divide the sequence length; when omitted, the
-  largest power-of-two block <= 512 that divides S is chosen.
-
-  512x512 default: measured 2.8x faster than 128x128 at S=1024 on v5e
-  (fewer grid invocations amortize per-call overhead and the [512, 512]
-  score tile keeps the MXU busy); still comfortably within VMEM (score
-  tile 1 MB fp32 + K/V blocks 128 KB).
+  passed block size must divide the sequence length; when omitted,
+  :func:`_default_block` chooses it (256 up to S 1024: PERF.md section 6,
+  PR 43).  While a head fits VMEM the block is the tile its loops walk,
+  not a block of the grid.
 
   On a multi-device mesh each chip runs the kernel on its own
   batch/head shard (:func:`_mesh_shard_spec`).

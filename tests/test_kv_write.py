@@ -1081,3 +1081,34 @@ def test_a_serving_cells_step_compiled_for_v5e_copies_no_leaf(one_chip, cell,
   # more than the sampler's two conditionals: the layers'
   assert text.count(" conditional(") > 2
   _assert_no_leaf_copied(text, args[1])
+
+
+@pytest.mark.parametrize("B,S,H,D,dtype,causal", [
+    (8, 1024, 20, 64, jnp.bfloat16, True),    # gpt2l-train-zero1-4chip, a chip
+    (2, 1024, 4, 256, jnp.float32, False),    # the widest head still unrolled
+    (2, 2048, 4, 64, jnp.float32, False),     # past _UNROLL_PAIRS: fori_loop
+    (1, 8192, 2, 64, jnp.bfloat16, True),     # where the resident regime ends
+], ids=["train_cell", "unrolled_f32_d256", "looped_f32", "resident_end"])
+def test_flash_kernels_compile_for_v5e_inside_their_vmem(
+    one_chip, monkeypatch, B, S, H, D, dtype, causal):
+  """The three resident flash kernels (kernels/flash_attention.py), a
+  whole head a grid step, compiled by Mosaic for a described v5e at the
+  default tile: interpret mode knows no VMEM, and a head unrolled past
+  ``_UNROLL_PAIRS`` is refused here for its temporaries (the float32 case
+  at S 2048 compiles only because it is walked by ``fori_loop``)."""
+  from easyparallellibrary_tpu.kernels import flash_attention
+  fa = importlib.import_module(
+      "easyparallellibrary_tpu.kernels.flash_attention")
+  monkeypatch.setattr(fa, "_interpret", lambda: False)
+  assert fa._resident_ok(S, S, D, jnp.dtype(dtype).itemsize)
+  x = jax.ShapeDtypeStruct((B, S, H, D), dtype, sharding=one_chip)
+  loss = lambda q, k, v: jnp.sum(
+      flash_attention(q, k, v, causal=causal).astype(jnp.float32) ** 2)
+  text = _compiled_text(jax.jit(jax.value_and_grad(loss, (0, 1, 2))),
+                        x, x, x)
+  # One Mosaic call a kernel; under autodiff alone the instruction's name
+  # wraps the kernel's (``transpose_jvp_flash_dq__``).
+  calls = [re.search(r"flash_(fwd|dkv|dq)", line.split(" = ")[0]).group(0)
+           for line in text.splitlines()
+           if 'custom_call_target="tpu_custom_call"' in line]
+  assert sorted(calls) == ["flash_dkv", "flash_dq", "flash_fwd"], calls

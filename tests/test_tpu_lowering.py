@@ -9,6 +9,7 @@ these take seconds.
 """
 
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -33,8 +34,16 @@ def _flash_loss(q, k, v):
                  .astype(jnp.float32) ** 2)
 
 
+FLASH_NAMES = ("flash_fwd", "flash_dkv", "flash_dq")
+
+
+def _kernel_names(text):
+  return sorted(re.findall(r'kernel_name = "([^"]+)"', text))
+
+
 @pytest.mark.parametrize("B,S,H,D,dtype", [
     (2, 1024, 16, 64, jnp.bfloat16),      # chip_smoke.py's: resident
+    (8, 1024, 20, 64, jnp.bfloat16),      # gpt2l-train-zero1-4chip, a chip
     (1, 16384, 2, 64, jnp.bfloat16),      # past _RESIDENT_MAX_BYTES
     (1, 1024, 16, 64, jnp.float32),
 ])
@@ -44,6 +53,8 @@ def test_flash_fwd_and_grad_lower_for_tpu(monkeypatch, B, S, H, D, dtype):
   text = _lowers_for_tpu(jax.value_and_grad(_flash_loss, (0, 1, 2)),
                          x, x, x)
   assert text.count("tpu_custom_call") == 3     # fwd, dk/dv, dq
+  # The benchmark's readers find the kernels by these names.
+  assert _kernel_names(text) == sorted(FLASH_NAMES)
 
 
 def test_flash_on_a_mesh_lowers_per_shard(monkeypatch):
@@ -61,6 +72,24 @@ def test_flash_on_a_mesh_lowers_per_shard(monkeypatch):
   assert text.count("tpu_custom_call") == 3
   # Each call sees its chip's shard: batch 8/4, heads 16/2.
   assert "tensor<2x8x1024x64xbf16>" in text
+
+
+def test_the_train_cell_lowers_per_shard_under_its_three_names(monkeypatch):
+  """`gpt2l-train-zero1-4chip`: a global batch of 32 x 1024 over
+  ``data:4``, 20 heads of 64 in bfloat16.  Forward and gradient lower
+  with the three custom calls under the names the benchmark reads, each
+  on its chip's ``[8, 20, 1024, 64]``."""
+  monkeypatch.setattr(fa, "_interpret", lambda: False)
+  epl.init(epl.Config({"cluster.mesh_shape": "data:4"}),
+           devices=jax.devices()[:4])
+  mesh = epl.Env.get().cluster.build_mesh()
+  x = jax.ShapeDtypeStruct(
+      (32, 1024, 20, 64), jnp.bfloat16,
+      sharding=NamedSharding(mesh, P("data", None, None, None)))
+  text = _lowers_for_tpu(jax.value_and_grad(_flash_loss, (0, 1, 2)),
+                         x, x, x)
+  assert _kernel_names(text) == sorted(FLASH_NAMES)
+  assert "tensor<8x20x1024x64xbf16>" in text
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
