@@ -470,13 +470,17 @@ def check_kv_write(B, Lc, H, hd, C, dtype, order, rehearsal: bool) -> None:
 
 def edge_cases(r, B, Lc, C, block):
   """``(cursors, num_valid)`` for an attend's check: a leaf's start, on
-  and across a block's edge, the last legal window, then random ones; a
-  partial chunk and an idle slot among them."""
+  and across a block's edge, the last legal window, a bound inside a
+  tail's last granule, then random ones (each a bound somewhere inside a
+  tail piece); a partial chunk and an idle slot among them; the LAST slot
+  full to the leaf's last row (a copy that went beyond it would leave the
+  array)."""
   cursors = np.asarray(
-      ([0, block - C // 2, Lc - C, block] + list(r.randint(0, Lc - C, B)))
-      [:B], np.int32)
+      ([0, block - C // 2, Lc - C, block, 7, min(block + 37, Lc - C)]
+       + list(r.randint(0, Lc - C, B)))[:B], np.int32)
   num_valid = np.asarray(([C, C, C, 1, 0, C // 2 or 1] + [1] * B)[:B],
                          np.int32)
+  cursors[-1], num_valid[-1] = Lc - C, C
   return cursors, num_valid
 
 
@@ -1693,7 +1697,14 @@ def phase_smallthinker(sizes: Sizes) -> None:
           f"a rule declined at the cell's shapes: {resolved}")
   R = cell_cfg.ring_length(C)
   W = cell_cfg.num_kv_heads * cell_cfg.head_dim
+  from easyparallellibrary_tpu.serving import kv_cache as kv_lib
+  Lc = kv_lib.kv_leaf_shape(cell_cfg, slots, C)[1]
   for dtype in (jnp.float32, jnp.bfloat16):
+    # The full layers' attend at the cell's leaf, on 8 slots (the
+    # reference's score tensor for 48 would not fit).
+    check_slot_attn(min(slots, 8), Lc, cell_cfg.num_heads,
+                    cell_cfg.num_kv_heads, cell_cfg.head_dim, C, dtype,
+                    "rows", rehearsal=sizes.rehearsal)
     check_kv_ring_write(slots, R, W, C, dtype, sizes.rehearsal)
     check_kv_window_attend(slots, R, C, cell_cfg.num_heads,
                            cell_cfg.num_kv_heads, cell_cfg.head_dim,

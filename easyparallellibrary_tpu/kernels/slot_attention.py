@@ -9,19 +9,25 @@ at the slot's cursor.  One algorithm, two lowerings:
   Lc]`` score tensor.  A dead slot, and every row beyond a live slot's
   cursor, costs what a full one costs.  Correct everywhere, and what the
   kernel is tested against.
-* **pallas** — one launch a layer named ``slot_attn``, grid over the
-  LIVE slots (``num_valid > 0``; their number is a value, the grid's
-  first dimension is dynamic and the program compiles once) and K/V
-  blocks of :func:`block_positions` rows.  Each slot's cursor and bound
-  (``cursor + num_valid``) are scalar-prefetched: the kernel reads of a
-  slot's K and V only the blocks under its bound, and an idle slot costs
-  neither a DMA nor a grid step — a step beyond the bound maps to the
-  first block of the next live slot (fetched behind the arithmetic of
-  the last live block, held until its turn) or to the block the pipeline
-  already holds, so it issues no DMA of its own, and its arithmetic is
-  skipped.  Scores, the running max, sum and
-  accumulator of the online softmax live in VMEM; no ``[B, H, C, Lc]``
-  tensor exists in HBM.  It reads the leaf AS IT LIES, in the form its
+* **pallas** — one launch a layer named ``slot_attn`` on ONE flat grid
+  over the step's live PIECES in slot order (:func:`live_pieces`; their
+  number is a value, the grid's one dimension is dynamic and the program
+  compiles once).  A slot whose bound (``cursor + num_valid``) is ``n``
+  rows is ``n // block`` whole blocks of :func:`block_positions` rows and
+  then ONE tail piece of the rest rounded up to a granule
+  (:func:`walk_geometry`: a sublane tile of rows, a lane tile of
+  positions); an idle slot has no piece, and no grid step exists for a
+  block beyond a bound.  The leaves stay in HBM: the kernel copies each
+  piece into one of :data:`_DEPTH` VMEM buffers itself, a whole block as
+  one DMA and a tail as the DMAs of static sizes its length decomposes
+  into (``block / 2, block / 4, .., granule``; :func:`walk_geometry` says
+  how a tail that reaches the leaf's end closes), and starts the copies of
+  the pieces ahead (the next live slot's first, after a slot's last)
+  before it folds the one it holds.  A tail is folded as an edge block
+  over the whole buffer: the rows beyond it are whatever the buffer held,
+  masked as everything at or beyond the bound is.  Scores, the running
+  max, sum and accumulator of the online softmax live in VMEM; no ``[B, H,
+  C, Lc]`` tensor exists in HBM.  It reads the leaf AS IT LIES, in the form its
   rank asks for, the one ``kernels/kv_write.py`` writes through:
 
   - **rows** (rank 3, ``[slot, position, H_kv * hd]``): K and V blocks
@@ -52,8 +58,9 @@ What comes out of rows the kernel does not compute: an idle slot's
 rows, positions ``>= num_valid`` of a partial chunk and the rows that
 pad a short chunk are ZEROS.  Nothing at or beyond a slot's bound
 reaches the output: scores there are masked, and V is zeroed there as
-well (the last live block's tail may hold a previous occupant's rows,
-and the leaf's edge block lanes no row at all; ``0 * NaN = NaN``).
+well (a bound's granule may hold a previous occupant's rows beyond it,
+the buffer beyond a tail whatever piece it held before, and the padding
+of a leaf's last lane tile no row at all; ``0 * NaN = NaN``).
 
 Grouped K/V heads (``H_kv < H``, models/jamba.py) ride the query-row
 axis: the ``G = H / H_kv`` query heads of a K/V head are ``G * C`` rows
@@ -104,18 +111,27 @@ SLOT_ATTN = "slot_attn"
 IMPLS = ("pallas", "reference", "interpret")
 
 LANES = 128
-# Positions a K/V block may span, widest first.  A block is the unit of
-# both the skip and the DMA: wide enough to stream near the memory's
-# rate (``kv_write``'s 128-position tiles reach 46% of it), narrow
-# enough that a short request does not pay for a long allocation.
+# Positions a K/V block may span, widest first.
 _BLOCKS = (2048, 1024, 512, 256, 128)
-# One K (or V) block ``[H_kv, hd, block]`` may take this much.
-_BLOCK_BYTES = 512 * 1024
-# VMEM the kernel may ask for: K and V blocks double-buffered, q and the
-# output block double-buffered, the float32 accumulator, max and sum,
-# and three score-sized float32 temporaries.  v5e's scoped default is 16
-# MiB.
-_VMEM_BUDGET = 12 * 1024 * 1024
+# ``slot_attn``'s block: what one copy moves and one fold covers.  The
+# walk visits no block beyond a bound and copies of a tail only its
+# granules, so a wide block costs no byte; it costs the arithmetic of a
+# tail folded at the block's width, and spares grid steps and rescalings
+# of the accumulator.  On the chip (PERF.md section 6, PR 44) 1024 rows
+# were the fastest or within 2% of it on every cell's leaf that 1.5 MiB
+# of K let take them, 2048 slower wherever bounds mostly end under it
+# (the hybrid's, LFM2's), and GPT-2 medium's 2 KiB rows as fast at 512.
+_BLOCK_ROWS = 1024
+_BLOCK_BYTES = 1536 * 1024
+# VMEM the kernel may ask for: K and V pieces in ``_DEPTH`` buffers, q and
+# the output block double-buffered, the float32 accumulator, max and sum,
+# and three score-sized float32 temporaries; and the limit it hands
+# Mosaic (v5e's scoped default is 16 MiB of 128).
+_VMEM_BUDGET = 24 * 1024 * 1024
+_VMEM_LIMIT = 48 * 1024 * 1024
+# Buffers a leaf's pieces rotate through: the walk keeps the copies of the
+# next ``_DEPTH - 1`` pieces in flight behind the fold of the one it holds.
+_DEPTH = 3
 
 
 def _backend_impl() -> str:
@@ -156,18 +172,19 @@ def _unit(hd: int):
 def block_positions(cache_shape, dtype, chunk: int, num_heads: int,
                     head_dim: Optional[int] = None) -> int:
   """Positions per K/V block for a leaf of either order: the widest
-  of :data:`_BLOCKS` whose K block stays within :data:`_BLOCK_BYTES`,
-  does not outgrow the leaf and leaves the kernel within its VMEM
-  budget; 0 if none does."""
+  of :data:`_BLOCKS` within :data:`_BLOCK_ROWS` whose K block stays
+  within :data:`_BLOCK_BYTES`, does not outgrow the leaf and leaves the
+  kernel within its VMEM budget; 0 if none does."""
   Lc, Hkv, hd, rows_form = _geometry(cache_shape, head_dim)
   size = jnp.dtype(dtype).itemsize
   rows = _query_rows(chunk, num_heads // Hkv, dtype)
   W = Hkv * hd
   width, stack = _unit(hd)
   for block in _BLOCKS:
-    if block > Lc or W * block * size > _BLOCK_BYTES:
+    if (block > min(Lc, _BLOCK_ROWS)
+        or W * block * size > _BLOCK_BYTES):
       continue
-    vmem = (4 * W * block * size               # K, V, double-buffered
+    vmem = (2 * _DEPTH * W * block * size      # K, V pieces
             + 4 * rows * W * size)             # q, out, double-buffered
     if rows_form:
       stacked = (W // width) * stack * rows    # every unit's stacked rows
@@ -191,7 +208,7 @@ def slot_attn_fits(cache_shape, dtype, chunk: int, num_heads: int,
   two blocks at most), a block within the budgets, and
 
   * rows ``[B, Lc, H_kv x hd]`` (``head_dim`` given): an ``hd`` that is a
-    whole number of lane tiles or divides one;
+    whole number of lane tiles or divides one, ``Lc`` a multiple of 8;
   * positions ``[B, Lc, H_kv, hd]``: an ``hd`` that fills whole sublane
     tiles."""
   Lc, Hkv, hd, rows_form = _geometry(cache_shape, head_dim)
@@ -203,7 +220,9 @@ def slot_attn_fits(cache_shape, dtype, chunk: int, num_heads: int,
   if Hkv < 1 or num_heads % Hkv:
     return False
   if rows_form:
-    if cache_shape[2] != Hkv * hd or (hd % LANES and LANES % hd):
+    # Rows in whole 8-row tiles, as the array keeps them: what a tail
+    # that reaches the leaf's end closes with (:func:`walk_geometry`).
+    if cache_shape[2] != Hkv * hd or (hd % LANES and LANES % hd) or Lc % 8:
       return False
   elif hd % sublane_tile(dtype):
     return False
@@ -282,6 +301,108 @@ def live_order(alive):
   return order, jnp.maximum(upto[-1:], 1)
 
 
+def walk_geometry(cache_shape, dtype):
+  """``(granule, length)`` of the walk over a leaf.  ``granule``: the
+  positions a piece is a whole number of, a sublane tile of a leaf kept in
+  rows (16 of bfloat16, 8 of float32), a lane tile of one kept in
+  positions; a slot's tail is its bound rounded up to it.  ``length``: the
+  positions of a slot the walk may copy.  A leaf kept in rows holds its
+  ``Lc`` rows and no more (a tail that reaches the leaf's end closes with
+  the few rows of its last granule); one kept in positions is padded to
+  whole lane tiles by the array's own tiling, which a copy may read (as
+  Pallas' pipeline reads a partial edge block) and the masks discard."""
+  Lc = cache_shape[1]
+  if len(cache_shape) == 3:
+    return sublane_tile(dtype), Lc
+  return LANES, -(-Lc // LANES) * LANES
+
+
+def live_pieces(bound, length: int, block: int, granule: int):
+  """``(slot, start, rows, count)`` for ONE flat grid over the step's live
+  pieces in slot order: slot ``b`` with bound ``n > 0`` is the whole
+  blocks ``[k x block, (k + 1) x block)`` under ``n`` rounded up to
+  ``granule`` (held to ``length``, the positions a slot has:
+  :func:`walk_geometry`), then one tail of the rest (``rows`` under
+  ``block``: a multiple of ``granule``, or one that ends at ``length``);
+  an idle slot is nothing.  ``count`` (int32
+  ``[1]``, at least 1: with every slot idle one piece of no rows) is a
+  value; the lists hold ``B x cdiv(length, block)`` entries, those from
+  ``count`` on of no rows.  The twin of :func:`live_order` one level down,
+  and :func:`live_tiles`' on the other axis.  It reads the bounds and the
+  leaf's geometry alone, so the layers of a step share one."""
+  B = bound.shape[0]
+  nb = -(-length // block)
+  covered = jnp.minimum(-(-bound.astype(jnp.int32) // granule) * granule,
+                        length)
+  whole = covered // block
+  tail = covered - whole * block
+  per = whole + (tail > 0)
+  ends = jnp.cumsum(per, dtype=jnp.int32)
+  item = jnp.arange(B * nb, dtype=jnp.int32)
+  slot = jnp.minimum(
+      jnp.sum(ends[None, :] <= item[:, None], axis=1, dtype=jnp.int32), B - 1)
+  k = item - jnp.take(ends - per, slot)
+  w = jnp.take(whole, slot)
+  rows = jnp.where(k < w, block, jnp.where(k == w, jnp.take(tail, slot), 0))
+  rows = jnp.where(item < ends[-1], rows, 0)
+  return (slot, jnp.clip(k, 0, nb - 1) * block, rows,
+          jnp.maximum(ends[-1:], 1))
+
+
+def _piece_copies(rows, block: int, granule: int, length: int):
+  """``(size, wanted, offset)`` of the copies a piece of ``rows`` rows is
+  made of, sizes static: the block, then its halves down to the granule,
+  each wanted where ``rows`` has its bit, at the offset the larger ones
+  before it fill; last, where ``length`` is no whole number of granules,
+  the rows of its last one (a tail that ends at ``length``)."""
+  size = block
+  while size >= granule:
+    yield size, (rows & size) != 0, rows - jax.lax.rem(rows, 2 * size)
+    size //= 2
+  if length % granule:
+    yield (length % granule, (rows & (granule - 1)) != 0,
+           rows - (rows & (granule - 1)))
+
+
+def _walk(slot_ref, start_ref, rows_ref, count_ref, leaves, bufs, sem, *,
+          block: int, granule: int, length: int, minor: bool):
+  """This grid step's piece ``(slot, start, rows, buffer)``, in VMEM when
+  it returns: starts the copies of the piece ``_DEPTH - 1`` steps ahead
+  (on the first step also of those before it), then waits for this
+  step's.  ``leaves`` the K (and V) leaves in HBM, ``bufs`` their ``[_DEPTH,
+  ..block..]`` buffers, ``sem`` DMA semaphores ``[leaves, _DEPTH]``;
+  ``minor``: the position is the leaves' last axis, else their second."""
+  j = pl.program_id(0)
+
+  def copies(p, act):
+    b, start, n = slot_ref[p], start_ref[p], rows_ref[p]
+    at = jax.lax.rem(p, _DEPTH)
+    for size, wanted, off in _piece_copies(n, block, granule, length):
+      @pl.when(wanted)
+      def _():
+        aligned = min(size, granule)
+        src = pl.ds(pl.multiple_of(start + off, aligned), size)
+        dst = pl.ds(pl.multiple_of(off, aligned), size)
+        for i, (leaf, buf) in enumerate(zip(leaves, bufs)):
+          act(pltpu.make_async_copy(
+              leaf.at[b, :, :, src] if minor else leaf.at[b, src],
+              buf.at[at, :, :, dst] if minor else buf.at[at, dst],
+              sem.at[i, at]))
+
+  start_copy = lambda dma: dma.start()
+  for d in range(_DEPTH - 1):
+    @pl.when((j == 0) & (d < count_ref[0]))
+    def _():
+      copies(d, start_copy)
+  ahead = j + _DEPTH - 1
+
+  @pl.when(ahead < count_ref[0])
+  def _():
+    copies(ahead, start_copy)
+  copies(j, lambda dma: dma.wait())
+  return slot_ref[j], start_ref[j], rows_ref[j], jax.lax.rem(j, _DEPTH)
+
+
 def _online_softmax_fold(s, m_ref, l_ref, at):
   """Fold one block's masked scores ``s [.., rows, block]`` into the
   running max and sum held at index ``at`` of their scratches (``...``
@@ -302,13 +423,14 @@ def _online_softmax_fold(s, m_ref, l_ref, at):
   return p, corr
 
 
-def _slot_attn_rows_kernel(order_ref, live_ref, cur_ref, bound_ref, pos_ref,
-                           q_ref, k_ref, v_ref, o_ref, qs_ref, m_ref, l_ref,
-                           acc_ref, *, block: int, num_blocks: int,
+def _slot_attn_rows_kernel(slot_ref, start_ref, rows_ref, count_ref, cur_ref,
+                           bound_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                           k_buf, v_buf, sem, qs_ref, m_ref, l_ref, acc_ref,
+                           *, block: int, granule: int, length: int,
                            scale: float, hd: int):
-  """One (slot, K/V block) grid step of the rows form: K and V blocks
-  ``[block, W]`` as the leaf holds them, ``q`` and the output ``[rows,
-  W]``.
+  """One piece of the rows form: K and V pieces ``[block, W]`` as the
+  leaf holds them (a tail fills the buffer's leading rows), ``q`` and the
+  output ``[rows, W]``.
 
   The lanes are walked a UNIT at a time (:func:`_unit`): a head of whole
   lane tiles, or one lane tile of ``n`` smaller heads.  On a slot's first
@@ -323,20 +445,21 @@ def _slot_attn_rows_kernel(order_ref, live_ref, cur_ref, bound_ref, pos_ref,
   products stand side by side with nothing between them for the MXUs to
   wait on; the three softmax scratches are stacked the same way.
   ``pos_ref`` holds each stacked row's position in the chunk."""
-  del live_ref
   rows, W = q_ref.shape[1:]
   width, stack = _unit(hd)
   units = W // width
   R = stack * rows                     # stacked query rows of one unit
-  b = order_ref[pl.program_id(0)]
-  kb = pl.program_id(1)
+  b, first, held, at = _walk(
+      slot_ref, start_ref, rows_ref, count_ref, (k_hbm, v_hbm),
+      (k_buf, v_buf), sem, block=block, granule=granule, length=length,
+      minor=False)
   cur = cur_ref[b]
   bound = bound_ref[b]
   lanes = lambda u: slice(u * width, (u + 1) * width)
   if stack > 1:
     head = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1) // hd
 
-  @pl.when(kb == 0)
+  @pl.when(first == 0)
   def _init():
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -355,19 +478,20 @@ def _slot_attn_rows_kernel(order_ref, live_ref, cur_ref, bound_ref, pos_ref,
                  else jax.lax.Precision.DEFAULT)
     s = jnp.concatenate([
         jax.lax.dot_general(
-            qs_ref[u * R:(u + 1) * R], k_ref[0, :, lanes(u)],
+            qs_ref[u * R:(u + 1) * R], k_buf[at, :, lanes(u)],
             (((1,), (1,)), ((), ())), precision=precision,
             preferred_element_type=jnp.float32)
         for u in range(units)], axis=0) * scale      # [units x R, block]
-    vs = [v_ref[0, :, lanes(u)] for u in range(units)]
+    vs = [v_buf[at, :, lanes(u)] for u in range(units)]
     if edge:
-      # The block holds rows at or beyond the cursor: query row i sees
+      # The piece holds rows at or beyond the cursor: query row i sees
       # key j iff j <= cursor + i, and nothing at or beyond the bound
-      # (its own chunk's invalid tail, a previous occupant's rows, the
-      # leaf's edge rows) may reach the sums, through K or through V.
-      col = kb * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+      # (its own chunk's invalid tail, a previous occupant's rows, what
+      # the buffer held beyond a tail) may reach the sums, through K or
+      # through V.
+      col = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
       s = jnp.where((col <= cur + pos_ref[...]) & (col < bound), s, NEG_INF)
-      stale = kb * block + jax.lax.broadcasted_iota(
+      stale = first + jax.lax.broadcasted_iota(
           jnp.int32, (block, width), 0) >= bound
       vs = [jnp.where(stale, jnp.zeros_like(v), v) for v in vs]
     p, corr = _online_softmax_fold(s, m_ref, l_ref, ...)
@@ -378,18 +502,18 @@ def _slot_attn_rows_kernel(order_ref, live_ref, cur_ref, bound_ref, pos_ref,
             precision=precision, preferred_element_type=jnp.float32)
         for u in range(units)], axis=0)              # [units x R, width]
 
-  live = kb * block < bound
-  behind = (kb + 1) * block <= cur     # every row of it under the cursor
+  # A whole block with every row under the cursor needs no mask.
+  behind = (held == block) & (first + block <= cur)
 
-  @pl.when(live & behind)
+  @pl.when(behind)
   def _interior():
     fold(edge=False)
 
-  @pl.when(live & jnp.logical_not(behind))
+  @pl.when(jnp.logical_not(behind) & (held > 0))
   def _edge():
     fold(edge=True)
 
-  @pl.when(kb == num_blocks - 1)
+  @pl.when(first + held >= bound)
   def _emit():
     l_col = jnp.maximum(l_ref[...][:, :1], 1e-30)
     out = jnp.where(pos_ref[...] < bound - cur, acc_ref[...] / l_col, 0.0)
@@ -401,38 +525,40 @@ def _slot_attn_rows_kernel(order_ref, live_ref, cur_ref, bound_ref, pos_ref,
       o_ref[0, :, lanes(u)] = merged.astype(o_ref.dtype)
 
 
-def _slot_attn_kernel(order_ref, live_ref, cur_ref, bound_ref, pos_ref,
-                      q_ref, k_ref, *refs, block: int, num_blocks: int,
-                      scale: float, v_width: Optional[int]):
-  """One (slot, K/V block) grid step of the positions form: score the
-  block against every query row of every head, fold it into the online
-  softmax carried in VMEM scratch, emit on the slot's last step.
+def _slot_attn_kernel(slot_ref, start_ref, rows_ref, count_ref, cur_ref,
+                      bound_ref, pos_ref, q_ref, *refs, block: int,
+                      granule: int, length: int, scale: float,
+                      v_width: Optional[int]):
+  """One piece of the positions form: score it against every query row of
+  every head, fold it into the online softmax carried in VMEM scratch,
+  emit on the slot's last piece.
 
   Values keep ``[heads, rows, .]``: one batched matmul over the heads
   for the scores (``[rows, hd] x [hd, block]``, K as the leaf holds it)
-  and one for the V contraction (over the block's positions, the lanes
-  of both operands).  ``order_ref`` names the slot of this grid row
-  (live slots only are visited), ``live_ref`` is the index maps' alone,
-  ``pos_ref`` holds each query row's position in the chunk (rows beyond
-  the chunk carry one no slot reaches).  ``refs``: the V block (absent
-  for a one-leaf layer, whose values are the K block's leading
-  ``v_width`` sublanes), the output block and the three scratches."""
-  v_ref = refs[0] if v_width is None else None
-  o_ref, m_ref, l_ref, acc_ref = refs[-4:]
-  b = order_ref[pl.program_id(0)]
-  kb = pl.program_id(1)
+  and one for the V contraction (over the piece's positions, the lanes
+  of both operands).  ``pos_ref`` holds each query row's position in the
+  chunk (rows beyond the chunk carry one no slot reaches).  ``refs``: the
+  K leaf and the V leaf in HBM (V absent for a one-leaf layer, whose
+  values are the K piece's leading ``v_width`` sublanes), the output
+  block, a buffer a leaf, the semaphores and the three scratches."""
+  leaves = 1 if v_width is not None else 2
+  hbm, o_ref, bufs = refs[:leaves], refs[leaves], refs[leaves + 1:-4]
+  sem, m_ref, l_ref, acc_ref = refs[-4:]
+  b, first, held, at = _walk(
+      slot_ref, start_ref, rows_ref, count_ref, hbm, bufs, sem, block=block,
+      granule=granule, length=length, minor=True)
   cur = cur_ref[b]
   bound = bound_ref[b]
 
-  @pl.when(kb == 0)
+  @pl.when(first == 0)
   def _init():
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
   def fold(edge: bool):
-    q, k = q_ref[0], k_ref[0]
-    v = v_ref[0] if v_width is None else k[:, :v_width]
+    q, k = q_ref[0], bufs[0][at]
+    v = bufs[1][at] if v_width is None else k[:, :v_width]
     # 16-bit operands multiply exactly on the MXU whatever precision the
     # caller's context names (and Mosaic refuses a float32 contraction
     # of them); float32 operands follow the context, as the einsums do.
@@ -441,14 +567,15 @@ def _slot_attn_kernel(order_ref, live_ref, cur_ref, bound_ref, pos_ref,
         q, k, (((2,), (1,)), ((0,), (0,))), precision=precision,
         preferred_element_type=jnp.float32) * scale    # [Hkv, rows, block]
     if edge:
-      # The block holds rows at or beyond the cursor: query row i sees
+      # The piece holds rows at or beyond the cursor: query row i sees
       # key j iff j <= cursor + i, and nothing at or beyond the bound
-      # (its own chunk's invalid tail, a previous occupant's rows, the
-      # leaf's edge lanes) may reach the sums, through K or through V.
-      col = kb * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+      # (its own chunk's invalid tail, a previous occupant's rows, what
+      # the buffer held beyond a tail) may reach the sums, through K or
+      # through V.
+      col = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
       s = jnp.where((col <= cur + pos_ref[...][None])
                     & (col < bound), s, NEG_INF)
-      vcol = kb * block + jax.lax.broadcasted_iota(jnp.int32, v.shape, 2)
+      vcol = first + jax.lax.broadcasted_iota(jnp.int32, v.shape, 2)
       v = jnp.where(vcol < bound, v, jnp.zeros_like(v))
     p, corr = _online_softmax_fold(s, m_ref, l_ref, ...)
     acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
@@ -456,18 +583,18 @@ def _slot_attn_kernel(order_ref, live_ref, cur_ref, bound_ref, pos_ref,
         precision=precision,
         preferred_element_type=jnp.float32)            # [Hkv, rows, hd]
 
-  live = kb * block < bound
-  behind = (kb + 1) * block <= cur     # every row of it under the cursor
+  # A whole block with every row under the cursor needs no mask.
+  behind = (held == block) & (first + block <= cur)
 
-  @pl.when(live & behind)
+  @pl.when(behind)
   def _interior():
     fold(edge=False)
 
-  @pl.when(live & jnp.logical_not(behind))
+  @pl.when(jnp.logical_not(behind) & (held > 0))
   def _edge():
     fold(edge=True)
 
-  @pl.when(kb == num_blocks - 1)
+  @pl.when(first + held >= bound)
   def _emit():
     l_col = jnp.maximum(l_ref[...][:, :, :1], 1e-30)
     real = pos_ref[...][None] < bound - cur          # [1, rows, 1]
@@ -486,7 +613,8 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
   interpreter mode (any backend), ``block`` overrides
   :func:`block_positions` (tests and measurement).  Jitted, so that the
   layers of one step share one trace and one Mosaic lowering of the
-  kernel, as ``kv_write_pallas`` does; XLA inlines the calls."""
+  kernel, as ``kv_write_pallas`` does; XLA inlines the calls and keeps
+  one piece list for the layers of a step."""
   B, C, H, hd = q.shape
   Lc, Hkv, _, rows_form = _geometry(cached_k.shape, hd)
   G = H // Hkv
@@ -495,7 +623,7 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
   vd = hd if cached_v is not None else v_width
   if block is None:
     block = block_positions(cached_k.shape, dtype, C, H, hd)
-  nb = pl.cdiv(Lc, block)
+  granule, length = walk_geometry(cached_k.shape, dtype)
   rows = _query_rows(C, G, dtype)
 
   # The write clamps its window into the leaf (kv_write.py); the read
@@ -505,37 +633,33 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
         else jnp.clip(num_valid.astype(jnp.int32), 0, C))
   alive = nv > 0
   bound = jnp.where(alive, cur + nv, 0)
-  # The grid visits the live slots alone, in slot order (:func:`live_order`).
-  # An idle slot costs no grid step and no DMA; its output block is never
-  # written and is zeroed below.
-  order, live = live_order(alive)
+  # The grid visits the live pieces alone, in slot order
+  # (:func:`live_pieces`).  An idle slot costs no grid step and no DMA;
+  # its output block is never written and is zeroed below.
+  with jax.named_scope("slot_attn_pieces"):
+    pieces = live_pieces(bound, length, block, granule)
   # Each query row's position in its chunk: rows are (group, position),
   # padding rows carry a position no slot reaches.
   pos = jnp.arange(rows, dtype=jnp.int32)
   pos = jnp.where(pos < G * C, pos % C, C)[:, None]
 
-  def kv_idx(i, kb, order, live, cur, bound):
-    # A step beyond its slot's bound points at the first block of the
-    # next live slot, which so streams in behind the arithmetic of this
-    # slot's last block and is held until its turn; the last live slot's
-    # stay on the block the pipeline holds.  Either way such steps issue
-    # no DMA of their own.
-    b = order[i]
-    ahead = order[jnp.minimum(i + 1, B - 1)]
-    reads = kb * block < bound[b]
-    more = i + 1 < live[0]
-    held = jnp.maximum(bound[b] - 1, 0) // block
-    return (jnp.where(reads, b, jnp.where(more, ahead, b)),
-            jnp.where(reads, kb, jnp.where(more, 0, held)))
-
+  leaves = [cached_k] if cached_v is None else [cached_k, cached_v]
+  if interpret and length > Lc:
+    # The padding a leaf kept in positions has on the chip
+    # (:func:`walk_geometry`) the interpreter lacks: it would shift a copy
+    # that reaches into it back inside the array.  So it gets one, of NaN.
+    leaves = [jnp.pad(x, [(0, 0), (0, length - Lc), (0, 0), (0, 0)],
+                      constant_values=jnp.nan) for x in leaves]
   kwargs = {}
   if not interpret:
     kwargs["compiler_params"] = pltpu.CompilerParams(
-        dimension_semantics=("arbitrary", "arbitrary"))
+        dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT)
   scale = 1.0 / math.sqrt(hd) if scale is None else float(scale)
   # Query rows of one K/V head are (group, position): a transpose of
   # ``q`` and of the output for grouped heads alone.
   qr = q.astype(dtype).reshape(B, C, Hkv, G, hd)
+  in_hbm = [pl.BlockSpec(memory_space=pl.ANY)] * len(leaves)
+  sems = pltpu.SemaphoreType.DMA((len(leaves), _DEPTH))
 
   if rows_form:
     W = Hkv * hd
@@ -545,19 +669,17 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
     if rows != G * C:
       qr = jnp.pad(qr, ((0, 0), (0, rows - G * C), (0, 0)))
     row_spec = pl.BlockSpec((1, rows, W),
-                            lambda i, kb, order, *_: (order[i], 0, 0))
-    def rows_idx(*a):
-      b, kb = kv_idx(*a)
-      return b, kb, 0
-
-    kv_spec = pl.BlockSpec((1, block, W), rows_idx)
+                            lambda j, slot, *_: (slot[j], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(live[0], nb),
-        in_specs=[pl.BlockSpec((stacked, 1), lambda i, kb, *_: (0, 0)),
-                  row_spec, kv_spec, kv_spec],
+        num_scalar_prefetch=6,
+        grid=(pieces[3][0],),
+        in_specs=[pl.BlockSpec((stacked, 1), lambda j, *_: (0, 0)),
+                  row_spec] + in_hbm,
         out_specs=row_spec,
         scratch_shapes=[
+            pltpu.VMEM((_DEPTH, block, W), dtype),        # K pieces
+            pltpu.VMEM((_DEPTH, block, W), dtype),        # V pieces
+            sems,
             pltpu.VMEM((stacked, width), dtype),          # stacked q
             pltpu.VMEM((stacked, LANES), jnp.float32),    # running max
             pltpu.VMEM((stacked, LANES), jnp.float32),    # running sum
@@ -566,14 +688,14 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
     )
     out = pl.pallas_call(
         functools.partial(_slot_attn_rows_kernel, block=block,
-                          num_blocks=nb, scale=scale, hd=hd),
+                          granule=granule, length=length, scale=scale,
+                          hd=hd),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, rows, W), dtype),
         interpret=interpret,
         name=SLOT_ATTN,
         **kwargs,
-    )(order, live, cur, bound, jnp.tile(pos, (stacked // rows, 1)), qr,
-      cached_k, cached_v)
+    )(*pieces, cur, bound, jnp.tile(pos, (stacked // rows, 1)), qr, *leaves)
     out = jnp.where(alive[:, None, None], out, 0)
     out = out[:, :G * C].reshape(B, G, C, Hkv, hd)
     return out.transpose(0, 2, 3, 1, 4).reshape(B, C, H, hd)
@@ -586,20 +708,16 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
     qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - G * C), (0, 0)))
 
   row_spec = lambda width: pl.BlockSpec(
-      (1, Hkv, rows, width), lambda i, kb, order, *_: (order[i], 0, 0, 0))
-  def minor_idx(*a):
-    b, kb = kv_idx(*a)
-    return b, 0, 0, kb
-
-  kv_spec = pl.BlockSpec((1, Hkv, hd, block), minor_idx)
-  leaves = [cached_k] if cached_v is None else [cached_k, cached_v]
+      (1, Hkv, rows, width), lambda j, slot, *_: (slot[j], 0, 0, 0))
   grid_spec = pltpu.PrefetchScalarGridSpec(
-      num_scalar_prefetch=4,
-      grid=(live[0], nb),
-      in_specs=[pl.BlockSpec((rows, 1), lambda i, kb, *_: (0, 0)),
-                row_spec(hd)] + [kv_spec] * len(leaves),
+      num_scalar_prefetch=6,
+      grid=(pieces[3][0],),
+      in_specs=[pl.BlockSpec((rows, 1), lambda j, *_: (0, 0)),
+                row_spec(hd)] + in_hbm,
       out_specs=row_spec(vd),
-      scratch_shapes=[
+      scratch_shapes=[pltpu.VMEM((_DEPTH, Hkv, hd, block), dtype)
+                      for _ in leaves] + [                # K (and V) pieces
+          sems,
           pltpu.VMEM((Hkv, rows, LANES), jnp.float32),   # running max
           pltpu.VMEM((Hkv, rows, LANES), jnp.float32),   # running sum
           pltpu.VMEM((Hkv, rows, vd), jnp.float32),      # accumulator
@@ -607,14 +725,14 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
   )
   out = pl.pallas_call(
       functools.partial(
-          _slot_attn_kernel, block=block, num_blocks=nb, scale=scale,
-          v_width=None if cached_v is not None else v_width),
+          _slot_attn_kernel, block=block, granule=granule, length=length,
+          scale=scale, v_width=None if cached_v is not None else v_width),
       grid_spec=grid_spec,
       out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, vd), dtype),
       interpret=interpret,
       name=SLOT_ATTN,
       **kwargs,
-  )(order, live, cur, bound, pos, qr, *map(to_minor, leaves))
+  )(*pieces, cur, bound, pos, qr, *map(to_minor, leaves))
   out = jnp.where(alive[:, None, None, None], out, 0)
   out = out[:, :, :G * C].reshape(B, Hkv, G, C, vd)
   return out.transpose(0, 3, 1, 2, 4).reshape(B, C, H, vd)

@@ -151,8 +151,9 @@ def slot_cache_attend(q, k, v, cached_k, cached_v, cursors, dtype,
   the engine zeroes the bad step's writes before the slot is read again
   — a retried slot's rows above its committed cursor, a quarantined slot
   whole (engine._sanitize_slots) — so the invariant holds without taxing
-  this hot path.  The kernel attend never reads a row at or beyond a slot's bound except inside the bound's own block, where it
-  masks V as well as the scores, so no stale row reaches its output
+  this hot path.  The kernel attend copies of a slot's rows at or beyond its bound only
+  the rest of the bound's own granule, and masks V as well as the scores
+  over whatever its buffer holds beyond the bound, so no stale row reaches its output
   whatever it holds; callers keep the invariant all the same, because
   which attend a step was built with is the rule's to decide.  What the
   kernel does not compute — an idle slot, positions ``>= num_valid`` —

@@ -228,6 +228,16 @@ def _slot_rows(resident, num_valid, window: int):
           int(np.sum(np.minimum(bound, np.where(n > 0, window - 1 + n, 0)))))
 
 
+def _walk_rows(resident, num_valid, granule: int, length: int) -> int:
+  """Rows one layer's ``slot_attn`` walk covers in a step: each live
+  slot's bound ``resident + num_valid`` rounded up to ``granule`` and held
+  to ``length``, the sum of ``kernels/slot_attention.py:live_pieces``'
+  rows, from the plan."""
+  r, n = resident.astype(np.int64), num_valid.astype(np.int64)
+  bound = np.where(n > 0, r + n, 0)
+  return int(np.sum(np.minimum(-(-bound // granule) * granule, length)))
+
+
 def flat_width(num_slots: int, chunk: int) -> int:
   """Rows ``T`` of the token-flat batch the contiguous fused step runs its
   position-wise layers on (models/gpt.py:SlotRows), static for the
@@ -460,6 +470,11 @@ class ContinuousBatchingEngine:
     # row of every slot — resolved once by the twin of the write's rule.
     self.slot_attn_impl = None if self.paged else kv_lib.slot_attn_impl(
         cfg, self.num_slots, self.chunk, self.mesh)
+    # The granule and length of the attend kernel's walk, where the step
+    # was built on it: what ``serving/attn_rows_read`` counts by.
+    self._attn_walk = (
+        kv_lib.slot_attn_walk(cfg, self.num_slots, self.chunk)
+        if self.slot_attn_impl in ("pallas", "interpret") else None)
     # Rows the cache holds for one layer: what ``serving/live_kv_rows``
     # is a share of.
     self._kv_rows = (self.num_blocks * self.block_size if self.paged else
@@ -1895,6 +1910,12 @@ class ContinuousBatchingEngine:
     # cache; and the positions the plan held back for want of a row (0:
     # the width cut nothing this step).  An expert model's layers route
     # the plan's own positions.
+    # Rows one layer's ``slot_attn`` walk covers this step: each live
+    # bound up to the walk's granule.  Over ``live_kv_rows`` it is how
+    # much of what the kernel fetches no query can see.
+    attn_rows_read = (
+        _walk_rows(plan.resident, plan.num_valid, *self._attn_walk)
+        if self._attn_walk is not None else None)
     fed_positions = plan.prefill_tokens + plan.decode_tokens
     flat_positions = fed_positions + (
         0 if step.num_draft is None else int(step.num_draft.sum()))
@@ -1932,6 +1953,8 @@ class ContinuousBatchingEngine:
       tracer.counter("serving/wasted_positions", plan.wasted)
       tracer.counter("serving/sampled_slots", sampled_slots)
       tracer.counter("serving/live_kv_rows", live_kv_rows)
+      if attn_rows_read is not None:
+        tracer.counter("serving/attn_rows_read", attn_rows_read)
       tracer.counter("serving/flat_positions", flat_positions)
       tracer.counter("serving/flat_trimmed", flat_trimmed)
       tracer.counter("serving/flat_narrow", flat_narrow)

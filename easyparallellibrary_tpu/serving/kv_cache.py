@@ -399,6 +399,18 @@ def slot_attn_impl(cfg, num_slots: int, chunk: int,
       sharded=sharded, head_dim=kv_heads(cfg)[1])
 
 
+def slot_attn_walk(cfg, num_slots: int, chunk: int):
+  """``(granule, length)`` of ``slot_attn``'s walk over this model's leaf
+  (kernels/slot_attention.py:walk_geometry): what
+  ``serving/attn_rows_read`` rounds each live bound up to, and holds it
+  to.  ``None`` for a model none of whose layers takes that walk (no rows
+  under a cursor, or every such layer a selected or windowed latent one)."""
+  from easyparallellibrary_tpu.kernels.slot_attention import walk_geometry
+  if not _under_cursor(cfg) or _mixed_latent(cfg):
+    return None
+  return walk_geometry(kv_leaf_shape(cfg, num_slots, chunk), cfg.dtype)
+
+
 def kv_win_write_impl(cfg, num_slots: int, chunk: int,
                       mesh: Optional[Mesh] = None) -> Optional[str]:
   """The lowering of the ring write of a window layer's K/V pair

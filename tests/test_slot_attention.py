@@ -123,15 +123,15 @@ def test_kernel_equals_the_reference_to_rounding(order, C, heads, dtype):
                          ids=["f32", "bf16"])
 @in_both_orders
 def test_the_rules_own_block_and_no_bound_given(order, dtype):
-  """The block the rule picks for the leaf (512 KiB of K: 1024 of 1040
-  rows in bfloat16, so the edge block holds 16), and ``num_valid=None``
-  as ``generate()``'s decode calls it: every position real."""
+  """The block the rule picks for the leaf (1024 of 1040 rows, so a full
+  slot's tail holds 16), and ``num_valid=None`` as ``generate()``'s
+  decode calls it: every position real."""
   C, H, hd = 16, 4, 64
   cur = [0, 500, 1008, 1023, LC - C]
   q, ck, cv = _operands(len(cur), C, H, H, hd, LC, dtype, seed=3)
   assert sa.block_positions(_shape(len(cur), LC, H, hd, order), dtype, C,
                             H, hd) == \
-      {jnp.float32: 512, jnp.bfloat16: 1024}[dtype]
+      {jnp.float32: 1024, jnp.bfloat16: 1024}[dtype]
   got = _check(q, ck, cv, cur, [C] * len(cur), dtype, order=order)
   unbounded = sa.slot_attention_pallas(
       q, jnp.asarray(_leaf(ck, order), dtype),
@@ -175,6 +175,123 @@ def test_nan_beyond_the_bound_does_not_reach_the_output(order, block, dtype):
   _check(q, ck, cv, cur, nv, dtype, block=block, poison=True, order=order)
 
 
+# ---------------------------------------------------------------- the walk
+
+
+def _pieces_by_loop(bound, block, granule, length):
+  """``live_pieces`` as a loop: a slot's whole blocks, then its tail."""
+  out = []
+  for b, n in enumerate(bound):
+    covered = min(-(-n // granule) * granule, length)
+    out += [(b, k * block, block) for k in range(covered // block)]
+    if covered % block:
+      out.append((b, covered // block * block, covered % block))
+  return out
+
+
+@pytest.mark.parametrize("granule,length", [(16, LC), (128, 1152), (16, 1032)],
+                         ids=["rows", "positions", "rows_odd_length"])
+@pytest.mark.parametrize("bound", [
+    [0, 1, 16, 64, 255, 256, 257, 1032],
+    [0, 0, 300, 1025],
+    [300, 1025, 0, 0],
+    [129, 0, 0, 513, 0, 77],
+    [0, 0, 0],
+    [1032] * 4,
+], ids=["each_edge", "idle_first", "idle_last", "idle_between", "all_idle",
+        "all_full"])
+def test_piece_list_is_the_loops(bound, granule, length):
+  """Pieces in slot order, a slot's whole blocks before its tail, the rows
+  covered each bound up to the granule (and no further than the slot's
+  length: a leaf in rows of no whole number of granules), nothing for an
+  idle slot; the count a value (one piece of no rows when every slot
+  idles), the lists' length a shape."""
+  block = 256
+  slot, start, rows, count = map(np.asarray, sa.live_pieces(
+      jnp.asarray(bound, jnp.int32), length, block, granule))
+  want = _pieces_by_loop(bound, block, granule, length)
+  assert count.shape == (1,) and count[0] == max(len(want), 1)
+  assert slot.shape == start.shape == rows.shape == (
+      len(bound) * -(-length // block),)
+  got = list(zip(slot, start, rows))[:len(want)]
+  assert [tuple(map(int, g)) for g in got] == want
+  assert (rows[len(want):] == 0).all() and (start % block == 0).all()
+  covered = np.zeros(len(bound), np.int64)
+  np.add.at(covered, slot, rows)
+  np.testing.assert_array_equal(
+      covered, np.minimum(-(-np.asarray(bound) // granule) * granule, length))
+  # whole blocks first: within a slot the sizes never grow
+  for b in range(len(bound)):
+    mine = rows[:len(want)][slot[:len(want)] == b]
+    assert (np.diff(mine) <= 0).all() and (mine[:-1] == block).all()
+  if not want:
+    assert rows[0] == 0 and start[0] == 0
+
+
+def _mixed_bounds(C, block, granule, Lc):
+  """``(cursors, num_valid)``: bounds of 0 (idle), 1, a granule, one under
+  a block, a block, one over, one inside a tail's last granule, the whole
+  leaf; idle slots first, between and last."""
+  bound = [0, 1, granule, block - 1, 0, block, block + 1,
+           2 * block + granule + 3, Lc, 0]
+  nv = [0 if n == 0 else min(n, C, 1 + i % C) for i, n in enumerate(bound)]
+  nv[-2] = C
+  cur = [n - v for n, v in zip(bound, nv)]
+  return cur, nv
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("form", ["rows_hd64", "rows_hd128", "rows_odd_length",
+                                  "positions", "one_leaf"])
+def test_the_walk_reads_under_each_bound_and_nothing_beyond(form, dtype):
+  """Bounds that end in every part of a block, NaN from each bound on (so
+  in the rest of the bound's granule, which the tail's copy fetches, and in
+  what the buffer holds beyond the tail): the reference's output on the
+  clean leaf, in the rows form at both head sizes (and over a leaf of no
+  whole number of 16-row granules, whose last slot is full: the hybrid
+  cell's 8200), in positions, and over one leaf whose values are its keys'
+  leading columns."""
+  C, block = 8, 256
+  Lc = 1032 if form == "rows_odd_length" else LC
+  if form == "one_leaf":
+    H, hd, vw = 4, 64, 32
+    shape = (10, Lc, 1, hd)
+  else:
+    H, hd = (4, 64) if form in ("rows_hd64", "positions") else (4, 128)
+    vw = None
+    shape = _shape(10, Lc, H, hd, "positions" if form == "positions"
+                   else "rows")
+  granule, length = sa.walk_geometry(shape, dtype)
+  assert (granule, length) == (
+      (128, 1152) if len(shape) == 4 else
+      ({jnp.float32: 8, jnp.bfloat16: 16}[dtype], Lc))
+  cur, nv = _mixed_bounds(C, block, granule, Lc)
+  if form == "one_leaf":
+    r = np.random.RandomState(11)
+    q = jnp.asarray(r.standard_normal((10, C, H, hd)) / np.sqrt(hd), dtype)
+    leaf = r.standard_normal(shape).astype(np.float32)
+    want = sa.slot_attention_reference(
+        q, jnp.asarray(leaf, dtype), None, jnp.asarray(cur, jnp.int32),
+        v_width=vw, scale=0.37)
+    for b in range(10):
+      leaf[b, cur[b] + nv[b] if nv[b] else 0:] = np.nan
+    got = sa.slot_attention_pallas(
+        q, jnp.asarray(leaf, dtype), None, jnp.asarray(cur, jnp.int32),
+        jnp.asarray(nv, jnp.int32), interpret=True, block=block, v_width=vw,
+        scale=0.37)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    real = (np.arange(C)[None] < np.asarray(nv)[:, None])[:, :, None, None]
+    assert np.isfinite(got).all() and (np.where(real, 0, got) == 0).all()
+    np.testing.assert_allclose(np.where(real, got, 0),
+                               np.where(real, want, 0), atol=TOL[dtype],
+                               rtol=TOL[dtype])
+    return
+  q, ck, cv = _operands(10, C, H, H, hd, Lc, dtype, seed=9)
+  _check(q, ck, cv, cur, nv, dtype, block=block, poison=True,
+         order="positions" if form == "positions" else "rows")
+
+
 # ---------------------------------------------------------------- the rule
 
 
@@ -198,10 +315,11 @@ def test_nan_beyond_the_bound_does_not_reach_the_output(order, block, dtype):
     ((4, 272, 384), jnp.float32, 16, 4, False, 128),     # heads not in groups
     ((4, 272, 16384), jnp.float32, 128, 128, False, 128),  # over the VMEM
     ((4, 272, 128), jnp.float32, 16, 2, True, 64),       # leaf spread over chips
+    ((4, 268, 128), jnp.float32, 16, 2, False, 64),      # rows in no 8-row tiles
 ], ids=["toy_leaf", "short_leaf", "wide_chunk", "odd_hd", "f16",
         "odd_groups", "vmem", "sharded", "rows_short_leaf", "rows_wide_chunk",
         "rows_hd_96", "rows_hd_192", "rows_f16", "rows_odd_groups",
-        "rows_vmem", "rows_sharded"])
+        "rows_vmem", "rows_sharded", "rows_odd_tiles"])
 def test_what_the_kernel_declines_takes_the_reference(
     monkeypatch, shape, dtype, chunk, heads, sharded, hd):
   for impl in ("interpret", "pallas"):
@@ -211,10 +329,13 @@ def test_what_the_kernel_declines_takes_the_reference(
 
 
 @pytest.mark.parametrize("shape,chunk,heads,block", [
-    ((96, 1040, 16, 64), 16, 16, 256),     # the GPT-2 medium cells
-    ((128, 8200, 1, 128), 8, 20, 2048),    # the hybrid cell
-    ((8, 1024, 16, 64), 1, 16, 256),       # generate()'s decode
-], ids=["gpt2m_cells", "hybrid_cell", "decode_1"])
+    ((96, 1040, 16, 64), 16, 16, 512),     # the GPT-2 medium cells
+    ((128, 8200, 1, 128), 8, 20, 1024),    # the hybrid cell
+    ((8, 1024, 16, 64), 1, 16, 512),       # generate()'s decode
+    ((48, 16416, 4, 128), 32, 28, 1024),   # SmallThinker's full layers
+    ((128, 4112, 8, 64), 16, 32, 1024),    # LFM2's attention layers
+], ids=["gpt2m_cells", "hybrid_cell", "decode_1", "smallthinker_cell",
+        "lfm2_cell"])
 @in_both_orders
 def test_rule_follows_the_backend_and_sizes_the_block(
     monkeypatch, order, shape, chunk, heads, block):
@@ -322,9 +443,9 @@ def test_one_leaf_equals_the_reference_and_reads_no_nan(H, hd, vw, C, dtype):
 
 
 def test_one_leaf_with_the_rules_own_block():
-  """The block the rule picks for the latent leaf: 256 rows of 576
-  bfloat16 values (288 KiB, within the 512 KiB a K block may take)."""
-  assert sa.block_positions((96, 4104, 1, 576), jnp.bfloat16, 8, 20) == 256
+  """The block the rule picks for the latent leaf: 1024 rows of 576
+  bfloat16 values (1.125 MiB, within the 1.5 MiB a K block may take)."""
+  assert sa.block_positions((96, 4104, 1, 576), jnp.bfloat16, 8, 20) == 1024
   _check_one_leaf(4, 64, 32, 8, jnp.bfloat16, block=None)
 
 

@@ -176,6 +176,68 @@ def test_live_kv_rows_is_counted_every_step(monkeypatch):
 
 
 @in_both_orders
+def test_attn_rows_read_is_the_walks_own_sum(monkeypatch, order):
+  """``serving/attn_rows_read`` beside ``serving/live_kv_rows``, a step's
+  pair: each live bound up to the walk's granule (a lane tile of
+  positions, a sublane tile of float32 rows), which is the piece list's
+  own sum of rows; never under the live rows; absent from a step built on
+  the einsums.  The step compiled once over steps whose live slots and
+  bounds all differ."""
+  epl.init()
+  eng, _, events = _serve(monkeypatch, "interpret", order=order)
+  counter = lambda name: [e["args"]["value"] for e in events
+                          if e["ph"] == "C" and e["name"] == name]
+  read, live = counter("serving/attn_rows_read"), counter(
+      "serving/live_kv_rows")
+  granule = {"positions": 128, "rows": 8}[order]
+  Lc = kv_lib.cache_length(SERVE_IN[order], 8)
+  assert eng._attn_walk == (granule, {"positions": 384, "rows": Lc}[order])
+  assert len(read) == len(live) == eng._steps
+  assert all(r >= l and r % granule == 0 for r, l in zip(read, live))
+  assert len(set(live)) > 5 and eng._step_fn._cache_size() == 1
+  # The first step feeds 8, 3 and 8 positions to three fresh slots.
+  bound = jnp.asarray([8, 3, 8], jnp.int32)
+  pieces = sa.live_pieces(bound, eng._attn_walk[1], 128, granule)
+  assert read[0] == int(np.sum(pieces[2])) == 3 * max(granule, 8)
+  # A scripted plan: an idle slot, a bound on a granule, one past it.
+  from easyparallellibrary_tpu.serving.engine import _walk_rows
+  resident = np.asarray([40, 120, 128, 0], np.int32)
+  feeds = np.asarray([0, 8, 1, 5], np.int32)
+  want = sa.live_pieces(jnp.asarray([0, 128, 129, 5], jnp.int32), 264, 128,
+                        granule)[2]
+  assert _walk_rows(resident, feeds, granule, 264) == int(np.sum(want))
+  # ... and a full slot of a leaf that holds no whole number of granules.
+  assert _walk_rows(np.asarray([8192], np.int32), np.asarray([8], np.int32),
+                    16, 8200) == 8200 == int(np.sum(sa.live_pieces(
+                        jnp.asarray([8200], jnp.int32), 8200, 1024, 16)[2]))
+  _, _, events = _serve(monkeypatch, "reference", order=order)
+  assert not [e for e in events if e["name"] == "serving/attn_rows_read"]
+
+
+def test_the_layers_of_a_step_share_one_piece_list(monkeypatch):
+  """The piece list reads the bounds and the leaf's geometry alone: in the
+  compiled step of a two-layer model it stands once, not once a layer."""
+  from easyparallellibrary_tpu.observability import device as device_lib
+  epl.init()
+  _backend_takes(monkeypatch, "interpret")
+  model, params, prompts = _model("rows")
+  eng = ContinuousBatchingEngine(model, params, num_slots=3, prefill_chunk=8)
+  specs = []
+  real, note = eng._step_fn, eng._note_step_specs
+  eng._note_step_specs = lambda args: (
+      specs.append(device_lib.specs_of(args)), note(args))[1]
+  eng.submit(Request(uid=0, prompt=prompts[1], max_new_tokens=2))
+  eng.run()
+  assert model.cfg.num_layers == 2
+  text = real.lower(*specs[0]).compile().as_text()
+  # A piece list takes three vectors by slot (``jnp.take``): its gathers
+  # stand in the program, every call inlined, three times and not six.
+  takes = [l for l in text.splitlines()
+           if " gather(" in l and "slot_attn_pieces" in l]
+  assert len(takes) == 3, takes
+
+
+@in_both_orders
 def test_engine_on_a_mesh_of_chips_takes_the_reference(monkeypatch, order):
   _backend_takes(monkeypatch, "interpret")
   epl.init(epl.Config({"cluster.mesh_shape": "data:4,model:2"}))
