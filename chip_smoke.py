@@ -105,19 +105,21 @@ from easyparallellibrary_tpu.kernels import dsa_index as dsa_lib
 from easyparallellibrary_tpu.kernels import slot_attention as slot_attn_lib
 from easyparallellibrary_tpu.models import GPT, GPTConfig
 from easyparallellibrary_tpu.models.dots3_note import (
-    FULL, SLIDING, Dots3Note, Dots3NoteConfig)
+    Dots3Note, Dots3NoteConfig)
 from easyparallellibrary_tpu.models.glm_moe import GlmMoe, GlmMoeConfig
-from easyparallellibrary_tpu.models.jamba import MAMBA, Jamba, JambaConfig
+from easyparallellibrary_tpu.models.jamba import Jamba, JambaConfig
+from easyparallellibrary_tpu.models.layer_kinds import FULL, MAMBA, SLIDING
 from easyparallellibrary_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
 from easyparallellibrary_tpu.models.smallthinker import (
     SmallThinker, SmallThinkerConfig)
 from easyparallellibrary_tpu.models.gpt import (
     _dense_causal_attention, generate, gpt_loss, make_gpt_train_step)
+from easyparallellibrary_tpu.models.slot_core import slot_step_logits
 from easyparallellibrary_tpu.observability.device import specs_of
 from easyparallellibrary_tpu.parallel import (
     TrainState, create_sharded_train_state, parallelize)
 from easyparallellibrary_tpu.serving import (
-    ContinuousBatchingEngine, Request)
+    ContinuousBatchingEngine, Request, kv_cache as kv_lib)
 from easyparallellibrary_tpu.testing import chaos
 from easyparallellibrary_tpu.testing.hlo import op_sites
 from easyparallellibrary_tpu.utils import compile_cache
@@ -777,11 +779,10 @@ def serve(model, params, prompts, new_tokens: int, paged: bool,
       f"computation {in_branch[0]} of a conditional, none on the path "
       "every step takes")
   if not paged:
-    say(f"  contiguous engine: attend {eng.slot_attn_impl}, kv write "
-        f"{eng.kv_write_impl}")
+    say(f"  contiguous engine: {kv_lib.resolved(eng.lowerings)}")
   if not rehearsal:
     impls = ((eng._paged_impl,) if paged else
-             (eng.kv_write_impl, eng.slot_attn_impl))
+             tuple(kv_lib.resolved(eng.lowerings).values()))
     check(all(impl == "pallas" for impl in impls),
           f"{'paged attend' if paged else 'cache write and attend'} "
           f"resolved to {impls}, not the kernel")
@@ -1058,30 +1059,27 @@ def phase_hybrid(sizes: Sizes) -> None:
   check(spy._cache_size() == 1,
         f"hybrid fused step compiled {spy._cache_size()} times")
   n_mamba = cfg.layer_kinds().count(MAMBA)
-  say(f"  hybrid engine: {len(prompts)} requests, attend "
-      f"{eng.slot_attn_impl}, kv write "
-      f"{eng.kv_write_impl}, ssm scan {eng.ssm_scan_impl}, cache "
+  resolved = kv_lib.resolved(eng.lowerings)
+  say(f"  hybrid engine: {len(prompts)} requests, {resolved}, cache "
       f"{eng.cache_layout}")
   if not sizes.rehearsal:
-    check(eng.kv_write_impl == "pallas" and eng.ssm_scan_impl == "pallas",
-          f"hybrid engine resolved kv write {eng.kv_write_impl!r}, ssm "
-          f"scan {eng.ssm_scan_impl!r}: not the kernels")
+    check(set(resolved) == {"kv_write_impl", "slot_attn_impl",
+                            "ssm_scan_impl"}
+          and set(resolved.values()) == {"pallas"},
+          f"hybrid engine resolved {resolved}: not the three kernels")
     hlo = spy.inner.lower(*spy.specs).compile().as_text()
     # One scan a Mamba layer; one write and, as the rule takes grouped
     # heads, one attend an attention layer.
     scans, attends = named_calls(hlo, SSM_SCAN), named_calls(hlo, SLOT_ATTN)
     n_attn = cfg.num_layers - n_mamba
-    check(eng.slot_attn_impl == "pallas" and scans == n_mamba
-          and attends == n_attn
+    check(scans == n_mamba and attends == n_attn
           and hlo.count(MOSAIC_CALL) == n_mamba + 2 * n_attn,
-          f"attend {eng.slot_attn_impl!r}; {scans} ssm_scan, {attends} "
+          f"{scans} ssm_scan, {attends} "
           f"slot_attn and {hlo.count(MOSAIC_CALL)} Mosaic calls in the "
           f"hybrid step, expected {n_mamba}, {n_attn} and "
           f"{n_mamba + 2 * n_attn}")
   # One fused call, the kernel against the reference lowering, on the
   # same inputs: prefill chunks, decodes and idle slots side by side.
-  from easyparallellibrary_tpu.models.gpt import slot_step_logits
-  from easyparallellibrary_tpu.serving import kv_cache as kv_lib
   N, C = 8, eng.chunk
   r = np.random.RandomState(4)
   tokens = jnp.asarray(r.randint(0, cfg.vocab_size, (N, C)), jnp.int32)
@@ -1197,19 +1195,17 @@ def check_latent_leaf(B, Lc, H, hd, rank, C, dtype, rehearsal: bool) -> None:
 
 
 def serve_expert_cut(sizes: Sizes, model, want_calls: dict, what: str,
-                     more_impls: tuple = (), gmm_may_decline: bool = False):
+                     gmm_may_decline: bool = False):
   """A cut of an expert decoder through the engine: every request runs to
   its length on ONE compile, every kernel resolved and counted
   (``want_calls``: custom calls by name in the compiled step), the served
   tokens against the teacher-forced full forward (the experts by
   ``ragged_dot``), and one fused call's logits, kernels against reference
-  lowerings (``more_impls``: further lowerings the model's step takes by
-  name; ``gmm_may_decline``: the grouped matmul's rule may take the
+  lowerings, every entry of the engine's record of them
+  (``gmm_may_decline``: the grouped matmul's rule may take the
   reference, as it does for float32 experts of a hidden size of 5120,
   whose tiles pass its VMEM budget: then no ``moe_gmm`` call is expected).
   Returns ``(gap, err)`` of the last two."""
-  from easyparallellibrary_tpu.models.gpt import slot_step_logits
-  from easyparallellibrary_tpu.serving import kv_cache as kv_lib
   cfg = model.cfg
   params = jax.jit(lambda: model.init(
       jax.random.PRNGKey(2), jnp.zeros((1, 8), jnp.int32))["params"])()
@@ -1228,25 +1224,22 @@ def serve_expert_cut(sizes: Sizes, model, want_calls: dict, what: str,
             f"{what} request {uid} did not run to its length")
     check(spy._cache_size() == 1,
           f"{what}'s fused step compiled {spy._cache_size()} times")
-    say(f"  {what} engine: {len(prompts)} requests, attend "
-        f"{eng.slot_attn_impl}, kv write {eng.kv_write_impl}, expert "
-        f"matmul {eng.moe_gmm_impl}, cache {eng.cache_layout}")
+    resolved = kv_lib.resolved(eng.lowerings)
+    say(f"  {what} engine: {len(prompts)} requests, {resolved}, cache "
+        f"{eng.cache_layout}")
+    # Where the grouped matmul's rule declined the experts' shapes, no
+    # ``moe_gmm`` call is expected and the kernel side of the comparison
+    # below runs it as the engine did.
+    declined = gmm_may_decline and resolved["moe_gmm_impl"] == "reference"
     if not sizes.rehearsal:
-      impls = (eng.kv_write_impl, eng.slot_attn_impl, eng.moe_gmm_impl)
-      # Window layers over K/V pairs resolve a write and an attend of
-      # their own (last, so that the grouped matmul stays third).
-      windowed = tuple(i for i in (eng.kv_win_write_impl,
-                                   eng.kv_win_attn_impl) if i is not None)
-      if windowed:
-        say(f"  {what} window layers: ring write {windowed[0]}, windowed "
-            f"attend {windowed[1]}")
-      if gmm_may_decline and eng.moe_gmm_impl == "reference":
-        impls, want_calls = impls[:2], dict(want_calls, **{MOE_GMM: 0})
-      impls += windowed
+      if declined:
+        want_calls = dict(want_calls, **{MOE_GMM: 0})
       hlo = spy.inner.lower(*spy.specs).compile().as_text()
       calls = {n: named_calls(hlo, n) for n in want_calls}
-      check(all(i == "pallas" for i in impls) and calls == want_calls,
-            f"{what} engine resolved {impls}; custom calls {calls}, "
+      check(all(impl == "pallas" for name, impl in resolved.items()
+                if not (declined and name == "moe_gmm_impl"))
+            and calls == want_calls,
+            f"{what} engine resolved {resolved}; custom calls {calls}, "
             f"expected {want_calls}")
     # The served tokens against the teacher-forced full forward: attention
     # over the whole sequence, the experts by ragged_dot.
@@ -1273,16 +1266,13 @@ def serve_expert_cut(sizes: Sizes, model, want_calls: dict, what: str,
     num_valid = jnp.asarray([C, 1, 0, C // 2, 1, C, 0, 1], jnp.int32)
     state_args = {"reset": jnp.zeros((N,), jnp.bool_)} if recurrent else {}
     kernel_impl = "interpret" if sizes.rehearsal else "pallas"
-    # Where the grouped matmul's rule declined the experts' shapes, the
-    # kernel side runs it as the engine did.
-    declined = gmm_may_decline and eng.moe_gmm_impl == "reference"
     got = {}
     for impl in (kernel_impl, "reference"):
       kv, cursors = kv_lib.allocate_kv_cache(cfg, N, C)
       step = jax.jit(functools.partial(
-          slot_step_logits, model, kv_write_impl=impl, slot_attn_impl=impl,
-          moe_gmm_impl="reference" if declined else impl,
-          **{name: impl for name in more_impls}))
+          slot_step_logits, model, **dict(
+              dict.fromkeys(resolved, impl),
+              **({"moe_gmm_impl": "reference"} if declined else {}))))
       for _ in range(2):       # the second call reads what the first wrote
         lg, kv = step(params, kv, tokens, cursors, num_valid=num_valid,
                       **state_args)
@@ -1541,7 +1531,7 @@ def phase_dots3(sizes: Sizes) -> None:
       {MOE_GMM: 2 * n_moe, slot_attn_lib.SLOT_ATTN_SEL: 2 * n_full,
        slot_attn_lib.SLOT_ATTN_WIN: 2 * n_win, dsa_lib.DSA_INDEX: 2 * n_full,
        "kv_write": 2 * n_full + n_win}, "dots3",
-      more_impls=("dsa_index_impl",), gmm_may_decline=True)
+      gmm_may_decline=True)
   say(f"PASS dots3: the ring kv_write, dsa_index, kth_largest, "
       "slot_attn_sel and slot_attn_win f32 + bf16 "
       + ("INTERPRETED" if sizes.rehearsal else "compiled")
@@ -1650,43 +1640,29 @@ def check_kv_window_attend(B, R, C, H, Hkv, hd, window, dtype,
 
 
 def report_rules(cfg, slots: int, chunk: int) -> dict:
-  """Every kernel rule a SmallThinker engine of ``slots x chunk`` asks, what
-  it resolved and, where it declined, why (the shapes it was handed and
-  what its ``*_fits`` says of them)."""
-  from easyparallellibrary_tpu.kernels.kv_write import kv_write_fits
-  from easyparallellibrary_tpu.kernels.moe_gmm import moe_gmm_fits
-  from easyparallellibrary_tpu.serving import kv_cache as kv_lib
-  full = kv_lib.kv_leaf_shape(cfg, slots, chunk)
-  ring = kv_lib.kv_leaf_shape(cfg, slots, chunk, ring=True)
-  H, hd = cfg.num_heads, cfg.head_dim
-  rows = slots * chunk * cfg.num_experts_per_tok
-  E, D, F = cfg.n_routed_experts, cfg.d_model, cfg.moe_d_ff
-  rules = {
-      "kv_write (full layers)": (
-          kv_lib.kv_write_impl(cfg, slots, chunk), full,
-          kv_write_fits(full, cfg.dtype, chunk)),
-      "slot_attn (full layers)": (
-          kv_lib.slot_attn_impl(cfg, slots, chunk), full,
-          slot_attn_lib.slot_attn_fits(full, cfg.dtype, chunk, H, hd)),
-      "kv_write ring=True (window layers)": (
-          kv_lib.kv_win_write_impl(cfg, slots, chunk), ring,
-          kv_write_fits(ring, cfg.dtype, chunk, ring=True)),
-      "slot_attn_kvwin (window layers)": (
-          kv_lib.kv_win_attn_impl(cfg, slots, chunk), ring,
-          slot_attn_lib.tile_attn_fits(ring, cfg.dtype, chunk, H, hd,
-                                       ring=True)),
-      "moe_gmm": (
-          kv_lib.moe_gmm_impl(cfg, slots, chunk),
-          ((rows, D), (E, D, 2 * F), (E, F, D)),
-          all(moe_gmm_fits((rows, k), (E, k, n), cfg.dtype)
-              for k, n in ((D, 2 * F), (F, D)))),
-  }
-  for name, (impl, shape, fits) in rules.items():
-    why = "" if impl == "pallas" else (
-        f": DECLINED, {'the backend is ' + jax.default_backend() if fits else 'the shapes do not fit the kernel'}")
-    say(f"  rule {name}: {impl} for {shape} {jnp.dtype(cfg.dtype).name}"
-        + why)
-  return {name: impl for name, (impl, _, _) in rules.items()}
+  """What every kernel rule resolved for an engine of ``slots x chunk`` over
+  ``cfg`` (``kv_cache.step_lowerings``: whatever model it is handed), the
+  leaves and expert stacks the rules saw and, where one declined, why."""
+  leaves = {}
+  for i, kind in enumerate(kv_lib.layer_kinds(cfg)):
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+        kv_lib.cache_leaves(cfg, slots, chunk)[f"block_{i}"]):
+      leaves[f"{kind} {path[-1].key}"] = tuple(leaf.shape)
+  if getattr(cfg, "n_routed_experts", 0):
+    E = (getattr(cfg, "experts_held", None) or (0, cfg.n_routed_experts))[1]
+    leaves["expert rows and stacks"] = (
+        (slots * chunk * cfg.num_experts_per_tok, cfg.d_model),
+        (E, cfg.d_model, 2 * cfg.moe_d_ff), (E, cfg.moe_d_ff, cfg.d_model))
+  for what, shape in leaves.items():
+    say(f"  {what}: {shape} {jnp.dtype(cfg.dtype).name}")
+  resolved = kv_lib.resolved(kv_lib.step_lowerings(cfg, slots, chunk))
+  # One chip, no mesh: a rule declines for its backend or for the shapes.
+  why = (f": DECLINED, the backend is {jax.default_backend()}"
+         if jax.default_backend() != "tpu"
+         else ": DECLINED, the shapes do not fit the kernel")
+  for name, impl in resolved.items():
+    say(f"  rule {name}: {impl}" + ("" if impl == "pallas" else why))
+  return resolved
 
 
 def phase_smallthinker(sizes: Sizes) -> None:
@@ -1697,7 +1673,6 @@ def phase_smallthinker(sizes: Sizes) -> None:
           f"a rule declined at the cell's shapes: {resolved}")
   R = cell_cfg.ring_length(C)
   W = cell_cfg.num_kv_heads * cell_cfg.head_dim
-  from easyparallellibrary_tpu.serving import kv_cache as kv_lib
   Lc = kv_lib.kv_leaf_shape(cell_cfg, slots, C)[1]
   for dtype in (jnp.float32, jnp.bfloat16):
     # The full layers' attend at the cell's leaf, on 8 slots (the
@@ -1716,7 +1691,7 @@ def phase_smallthinker(sizes: Sizes) -> None:
       sizes, SmallThinker(cfg),
       {MOE_GMM: 2 * cfg.num_layers, SLOT_ATTN: n_full,
        slot_attn_lib.SLOT_ATTN_KVWIN: 2 * n_win, "kv_write": cfg.num_layers},
-      "smallthinker", more_impls=("kv_win_write_impl", "kv_win_attn_impl"))
+      "smallthinker")
   say(f"PASS smallthinker: the K/V ring kv_write and slot_attn_kvwin f32 + "
       "bf16 " + ("INTERPRETED" if sizes.rehearsal else "compiled")
       + f" at the cell's leaves against their references; {n_full} full "

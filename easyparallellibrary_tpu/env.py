@@ -54,6 +54,14 @@ class Env:
     self.reset(config)
     return self
 
+  def mesh_built(self) -> bool:
+    """Whether a multi-device mesh has been built: what a kernel's entry
+    called with ``impl=None`` takes its operands as spread over (the
+    legacy ``generate()`` decode, a decoder called bare); the serving
+    engine resolves each lowering from its own mesh and passes it."""
+    mesh = self.cluster.built_mesh if self.cluster is not None else None
+    return mesh is not None and mesh.size > 1
+
   # -- collections ---------------------------------------------------------
 
   def add_to_collection(self, value, key: str):

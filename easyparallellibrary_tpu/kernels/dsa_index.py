@@ -52,6 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from easyparallellibrary_tpu.env import Env
 from easyparallellibrary_tpu.kernels.slot_attention import (
     LANES, live_tiles, split_decodes, sublane_tile)
 
@@ -263,13 +264,19 @@ def _dsa_index_launch(q, w, keys, cursors, num_valid, interpret: bool,
 # --------------------------------------------------------------- dispatch --
 
 
-def dsa_index(q, w, keys, cursors, num_valid=None, *, impl: str):
+def dsa_index(q, w, keys, cursors, num_valid=None, *,
+              impl: Optional[str] = None):
   """Index scores of each slot's chunk against its index leaf (module
   docstring): ``q`` ``[B, C, Hi, d]``, ``w`` ``[B, C, Hi]``, ``keys`` ``[B,
-  Lc, d]`` AFTER this step's write -> float32 ``[B, C, Lc]``.  ``impl`` is
-  resolved by the caller (:func:`resolve_dsa_index_impl`)."""
+  Lc, d]`` AFTER this step's write -> float32 ``[B, C, Lc]``.  ``impl=None``
+  applies :func:`resolve_dsa_index_impl` to the operands at hand (the
+  serving engine resolves it once and passes it)."""
+  if impl is None:
+    impl = resolve_dsa_index_impl(keys.shape, keys.dtype, q.shape[1],
+                                  q.shape[2],
+                                  sharded=Env.get().mesh_built())
   if impl not in IMPLS:
-    raise ValueError(f"impl must be one of {IMPLS}; got {impl!r}")
+    raise ValueError(f"impl must be one of {IMPLS} or None; got {impl!r}")
   if impl == "reference":
     return dsa_index_reference(q, w, keys, cursors, num_valid)
   return dsa_index_pallas(q, w, keys, cursors, num_valid,

@@ -2,7 +2,7 @@
 
 The serving engine's fused step appends a ``chunk``-wide K/V window to
 every layer's contiguous cache at each slot's own cursor
-(``models.gpt.slot_cache_attend``; the layout note in
+(``models.slot_core.slot_cache_attend``; the layout note in
 ``serving/kv_cache.py`` is the contract: the full ``chunk``-wide window
 lands at ``cursor``, never clamped, never shifted).  One algorithm, two
 lowerings, behind one dispatcher:
@@ -54,8 +54,8 @@ configuration field, no environment variable, no setter.  A third impl,
 ``interpret``, runs the kernel in Pallas interpreter mode: the parity
 tests' CPU vehicle, reached by naming it or by patching
 :func:`_backend_impl`.  The engine resolves
-the lowering ONCE when it builds its step and records it
-(``engine.kv_write_impl``, trace metadata ``serving/kv_write_impl``).
+the lowering ONCE when it builds its step and records it (trace metadata
+``serving/kv_write_impl``, ``engine.lowerings["kv_write_impl"]``).
 
 Shapes: ``cached_k/cached_v`` ``[B, Lc, H * hd]`` (rows) or ``[B, Lc, H,
 hd]`` (positions); ``k/v`` ``[B, C, H, hd]`` or ``[B, C, H * hd]``;
@@ -423,17 +423,11 @@ def kv_write(cached_k, cached_v, k, v, cursors, num_valid=None,
   one-leaf layer (``cached_v = v = None``).  ``num_valid`` (``None`` =
   every slot is fed) lets the rows form skip the slots the step does not
   feed.  ``ring`` writes the cache as a ring (module docstring).
-  ``impl=None`` applies the
-  dispatch rule to the shapes at hand, and takes the leaf as spread
-  over chips whenever a multi-device mesh has been built (the legacy
-  ``generate()`` decode); the serving engine resolves the impl from its
-  own mesh and passes it."""
+  ``impl=None`` applies the dispatch rule to the shapes at hand
+  (and to ``Env.mesh_built``)."""
   if impl is None:
-    cluster = Env.get().cluster
-    mesh = cluster.built_mesh if cluster is not None else None
-    impl = resolve_kv_write_impl(
-        cached_k.shape, cached_k.dtype, k.shape[1],
-        sharded=mesh is not None and mesh.size > 1, ring=ring)
+    impl = resolve_kv_write_impl(cached_k.shape, cached_k.dtype, k.shape[1],
+                                 sharded=Env.get().mesh_built(), ring=ring)
   if impl not in IMPLS:
     raise ValueError(f"impl must be one of {IMPLS} or None; got {impl!r}")
   if impl == "reference":

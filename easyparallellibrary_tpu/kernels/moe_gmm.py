@@ -41,7 +41,7 @@ backend and what it is handed — no configuration field, environment
 variable or setter; ``interpret`` runs the kernel in Pallas interpreter
 mode (the CPU parity tests, by name or by patching
 :func:`_backend_impl`).  The engine resolves it once when it builds its
-step and records it (``engine.moe_gmm_impl``, trace metadata
+step and records it (``engine.lowerings["moe_gmm_impl"]``, trace metadata
 ``serving/moe_gmm_impl``).
 """
 
@@ -259,11 +259,8 @@ def moe_gmm(lhs, rhs, group_sizes, impl: Optional[str] = None):
   over chips whenever a multi-device mesh has been built; the serving
   engine resolves the impl from its own mesh and passes it."""
   if impl is None:
-    cluster = Env.get().cluster
-    mesh = cluster.built_mesh if cluster is not None else None
-    impl = resolve_moe_gmm_impl(
-        lhs.shape, rhs.shape, lhs.dtype,
-        sharded=mesh is not None and mesh.size > 1)
+    impl = resolve_moe_gmm_impl(lhs.shape, rhs.shape, lhs.dtype,
+                                sharded=Env.get().mesh_built())
   if impl not in IMPLS:
     raise ValueError(f"impl must be one of {IMPLS} or None; got {impl!r}")
   if impl == "reference":

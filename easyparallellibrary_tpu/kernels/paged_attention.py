@@ -8,8 +8,8 @@ implementations of that gather-attend, behind one dispatcher:
 
 * **reference** — pure jnp (``jnp.take`` over the block dimension,
   dense masked softmax), numerically a MIRROR of
-  ``models.gpt.slot_cache_attend``: same einsum structure, same ``-1e9``
-  mask, same fp32 softmax, same dtype flow.  This is the CPU /
+  ``models.slot_core.slot_cache_attend``: same einsum structure, same
+  ``-1e9`` mask, same fp32 softmax, same dtype flow.  This is the CPU /
   correctness path — the engine's greedy bit-exactness contract vs
   ``generate(use_cache=True)`` is carried by this implementation, and
   the TPU kernel is tested against it (tests/test_serving_paged.py).

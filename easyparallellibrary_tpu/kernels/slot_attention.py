@@ -1,6 +1,6 @@
 """Slot-cache attention over the live rows only — Pallas TPU kernel.
 
-The attend half of ``models.gpt.slot_cache_attend``: every slot's ``C``
+The attend half of ``models.slot_core.slot_cache_attend``: every slot's ``C``
 new query positions against that slot's own contiguous K/V cache, causal
 at the slot's cursor.  One algorithm, two lowerings:
 
@@ -73,8 +73,8 @@ the reference everywhere else.  It reads the backend and what it is
 handed — no configuration field, environment variable or setter;
 ``interpret`` runs the kernel in Pallas interpreter mode (the CPU parity
 tests, by name or by patching :func:`_backend_impl`).  The engine
-resolves it once when it builds its step and records it
-(``engine.slot_attn_impl``, trace metadata ``serving/slot_attn_impl``).
+resolves it once when it builds its step and records it (trace metadata
+``serving/slot_attn_impl``, ``engine.lowerings["slot_attn_impl"]``).
 
 A layer whose cache is ONE leaf (models/glm_moe.py: the latent ``[B, Lc,
 1, 576]`` of absorbed multi-head latent attention) passes ``cached_v =
@@ -1166,8 +1166,8 @@ def _tile_attention(q, leaf, cursors, num_valid, scores, threshold,
   form.  ``q`` is ``[B, C, H, W]``, or (the one-leaf forms), with ``starts``
   and ``chunk``, the step's token-flat batch ``[T, H, W]`` in which slot
   ``b``'s live positions are the rows from ``starts[b]`` on
-  (models/gpt.py:SlotRows): a tile's query rows are read where they lie, no
-  copy of them into ``[slots, chunk]`` order.  Decoding slots take a
+  (models/slot_core.py:SlotRows): a tile's query rows are read where they
+  lie, no copy of them into ``[slots, chunk]`` order.  Decoding slots take a
   launch of their own on their one position (:func:`split_decodes`)."""
   B = cursors.shape[0]
   H, W = q.shape[-2:]
@@ -1376,6 +1376,11 @@ def slot_attention_kv_window_pallas(q, ring_k, ring_v, cursors,
 # --------------------------------------------------------------- dispatch --
 
 
+def _check_impl(impl: str) -> None:
+  if impl not in IMPLS:
+    raise ValueError(f"impl must be one of {IMPLS} or None; got {impl!r}")
+
+
 def slot_attention(q, cached_k, cached_v, cursors, num_valid=None,
                    impl: Optional[str] = None,
                    v_width: Optional[int] = None,
@@ -1384,18 +1389,12 @@ def slot_attention(q, cached_k, cached_v, cursors, num_valid=None,
   returns ``out [B, C, H, hd]`` (``[B, C, H, v_width]`` for a one-leaf
   layer: ``cached_v=None``, the values the keys' leading ``v_width``
   columns).  ``impl=None`` applies the dispatch rule
-  to the shapes at hand, and takes the leaf as spread over chips
-  whenever a multi-device mesh has been built (the legacy ``generate()``
-  decode); the serving engine resolves the impl from its own mesh and
-  passes it."""
+  to the shapes at hand (and to ``Env.mesh_built``)."""
   if impl is None:
-    cluster = Env.get().cluster
-    mesh = cluster.built_mesh if cluster is not None else None
     impl = resolve_slot_attn_impl(
         cached_k.shape, cached_k.dtype, q.shape[1], q.shape[2],
-        sharded=mesh is not None and mesh.size > 1, head_dim=q.shape[3])
-  if impl not in IMPLS:
-    raise ValueError(f"impl must be one of {IMPLS} or None; got {impl!r}")
+        sharded=Env.get().mesh_built(), head_dim=q.shape[3])
+  _check_impl(impl)
   if (cached_v is None) != (v_width is not None):
     raise ValueError("a one-leaf attend passes cached_v=None AND v_width; "
                      "a K/V pair passes neither")
@@ -1407,24 +1406,23 @@ def slot_attention(q, cached_k, cached_v, cursors, num_valid=None,
                                v_width=v_width, scale=scale)
 
 
-def _check_impl(impl: str) -> None:
-  if impl not in IMPLS:
-    raise ValueError(f"impl must be one of {IMPLS}; got {impl!r}")
-
-
 def slot_attention_selected(q, latent, scores, threshold, cursors,
-                            num_valid=None, *, impl: str, v_width: int,
-                            scale: float, starts=None):
+                            num_valid=None, *, impl: Optional[str] = None,
+                            v_width: int, scale: float, starts=None):
   """Attend each slot's chunk over the SELECTED rows of its one-leaf
   cache ``[B, Lc, 1, W]``: query ``i`` of slot ``b`` sees row ``s <=
   cursors[b] + i`` iff ``scores[b, i, s] >= threshold[b, i]`` (the index
   scores of kernels/dsa_index.py and the query's k-th largest).  The
   values are the rows' leading ``v_width`` columns; ``out [B, C, H,
-  v_width]``.  ``impl`` is resolved by the caller
-  (:func:`resolve_tile_attn_impl`).  The kernel also takes ``q`` as the
-  step's flat batch ``[T, H, W]`` with ``starts`` (int32 ``[B]``: the
-  flat row of each slot's first position), which spares the copy into
-  ``[B, C]`` order; the reference takes ``[B, C, H, W]`` only."""
+  v_width]``.  ``impl=None`` applies :func:`resolve_tile_attn_impl` to the
+  operands at hand, as :func:`slot_attention` does.  The kernel also takes
+  ``q`` as the step's flat batch ``[T, H, W]`` with ``starts`` (int32
+  ``[B]``: the flat row of each slot's first position), which spares the
+  copy into ``[B, C]`` order; the reference takes ``[B, C, H, W]`` only."""
+  if impl is None:
+    impl = resolve_tile_attn_impl(latent.shape, latent.dtype,
+                                  scores.shape[1], q.shape[-2], v_width,
+                                  sharded=Env.get().mesh_built())
   _check_impl(impl)
   if impl == "reference":
     return slot_attention_selected_reference(q, latent, scores, threshold,
@@ -1436,15 +1434,20 @@ def slot_attention_selected(q, latent, scores, threshold, cursors,
       scale=scale)
 
 
-def slot_attention_window(q, ring, cursors, num_valid=None, *, impl: str,
-                          window: int, v_width: int, scale: float,
-                          starts=None, chunk: Optional[int] = None):
+def slot_attention_window(q, ring, cursors, num_valid=None, *,
+                          impl: Optional[str] = None, window: int,
+                          v_width: int, scale: float, starts=None,
+                          chunk: Optional[int] = None):
   """Attend each slot's chunk over a RING leaf ``[B, R, 1, W]`` (position
   ``p`` at row ``p mod R``, written through ``kv_write(..., ring=True)``):
   query ``i`` of slot ``b``, at ``t = cursors[b] + i``, sees the positions
   ``t - window < p <= t``.  ``out [B, C, H, v_width]``.  ``starts`` and
   ``chunk``: ``q`` as the flat batch, as :func:`slot_attention_selected`
-  takes it."""
+  takes it, and ``impl=None`` as it resolves it."""
+  if impl is None:
+    impl = resolve_tile_attn_impl(
+        ring.shape, ring.dtype, q.shape[1] if starts is None else chunk,
+        q.shape[-2], v_width, ring=True, sharded=Env.get().mesh_built())
   _check_impl(impl)
   if impl == "reference":
     return slot_attention_window_reference(q, ring, cursors, num_valid,
@@ -1456,7 +1459,7 @@ def slot_attention_window(q, ring, cursors, num_valid=None, *, impl: str,
 
 
 def slot_attention_kv_window(q, ring_k, ring_v, cursors, num_valid=None, *,
-                             impl: str, window: int,
+                             impl: Optional[str] = None, window: int,
                              scale: Optional[float] = None):
   """Attend each slot's chunk over a RING of K/V PAIRS (``ring_k``,
   ``ring_v`` ``[B, R, H_kv x hd]``, position ``p`` at row ``p mod R``,
@@ -1464,8 +1467,12 @@ def slot_attention_kv_window(q, ring_k, ring_v, cursors, num_valid=None, *,
   ``i`` of slot ``b``, at ``t = cursors[b] + i``, sees the positions ``t -
   window < p <= t``; query head ``h`` reads K/V head ``h // (H / H_kv)``.
   ``q`` and ``out`` ``[B, C, H, hd]``.  ``scale`` defaults to ``1 /
-  sqrt(hd)``.  ``impl`` is resolved by the caller
-  (:func:`resolve_tile_attn_impl` on a leaf's rank-3 shape)."""
+  sqrt(hd)``.  ``impl=None`` applies :func:`resolve_tile_attn_impl` to the
+  ring's shape as it lies (rank 3, kept in rows, is what it takes)."""
+  if impl is None:
+    impl = resolve_tile_attn_impl(ring_k.shape, ring_k.dtype, q.shape[1],
+                                  q.shape[2], q.shape[3], ring=True,
+                                  sharded=Env.get().mesh_built())
   _check_impl(impl)
   scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else float(scale)
   if impl == "reference":

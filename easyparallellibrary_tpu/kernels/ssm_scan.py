@@ -45,7 +45,7 @@ what it is handed and the backend — no configuration field, environment
 variable or setter; ``interpret`` runs the kernel in Pallas interpreter
 mode (the CPU parity tests, by name or by patching
 :func:`_backend_impl`).  The engine resolves it once when it builds its
-step and records it (``engine.ssm_scan_impl``, trace metadata
+step and records it (``engine.lowerings["ssm_scan_impl"]``, trace metadata
 ``serving/ssm_scan_impl``).
 """
 
@@ -248,10 +248,8 @@ def ssm_scan(state, u, delta, Bm, Cm, z, A, D, num_valid=None, reset=None,
   if reset is None:
     reset = jnp.zeros((B,), bool)
   if impl is None:
-    cluster = Env.get().cluster
-    mesh = cluster.built_mesh if cluster is not None else None
-    impl = resolve_ssm_scan_impl(
-        state.shape, u.dtype, C, sharded=mesh is not None and mesh.size > 1)
+    impl = resolve_ssm_scan_impl(state.shape, u.dtype, C,
+                                 sharded=Env.get().mesh_built())
   if impl not in IMPLS:
     raise ValueError(f"impl must be one of {IMPLS} or None; got {impl!r}")
   if impl == "reference":

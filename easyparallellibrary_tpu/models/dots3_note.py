@@ -5,7 +5,7 @@ a headwise output gate, and one chip's share of the routed experts.
 ``model_type: dots3_note`` (dots3-note-prev, 46 layers, 288B-A17B with its
 towers): pre-RMSNorm residual layers; ``layer_types[l]`` says which of two
 multi-head latent attentions layer ``l`` mixes by, both
-models/glm_moe.py's :class:`LatentAttention` at sizes of their own
+models/blocks.py's :class:`LatentAttention` at sizes of their own
 (:meth:`Dots3NoteConfig.latent_dims`):
 
 * a FULL layer (``full_attention``): 128 heads of 128 | 64 rotary | 128
@@ -66,26 +66,15 @@ from typing import Any, Optional, Tuple
 import jax.numpy as jnp
 from flax import linen as nn
 
-from easyparallellibrary_tpu.models.glm_moe import (
-    IndexerDims, LatentAttention, LatentDims)
-from easyparallellibrary_tpu.models.gpt import (
-    SplitLayer, child_of, flat_ids, slot_layers)
-from easyparallellibrary_tpu.models.jamba import GatedMLP, RMSNorm, _dense
+from easyparallellibrary_tpu.models.blocks import (
+    GatedMLP, IndexerDims, LatentAttention, LatentDims, RMSNorm, dense,
+    ring_length)
+from easyparallellibrary_tpu.models.layer_kinds import (
+    FULL, SLIDING, SPARSE_LATENT, WINDOW_LATENT)
 from easyparallellibrary_tpu.models.moe import DroplessMoE
+from easyparallellibrary_tpu.models.slot_core import (
+    SplitLayer, child_of, flat_ids, slot_layers)
 from easyparallellibrary_tpu.ops import Embedding
-
-# What a layer keeps per slot (serving/kv_cache.py reads
-# ``cfg.layer_kinds()``).
-SPARSE_LATENT, WINDOW_LATENT = "sparse_latent", "window_latent"
-FULL, SLIDING = "full_attention", "sliding_attention"
-
-
-def ring_length(window: int, chunk: int, tile: int = 128) -> int:
-  """Rows of a window layer's ring for ``chunk``-wide steps: the window's
-  reach behind a step's first query (``window - 1``) plus the chunk the
-  step writes, up to whole ``tile``-row tiles (the attend's blocks and
-  the write's tiles): 640 at window 513, chunk 32."""
-  return -(-(window - 1 + chunk) // tile) * tile
 
 
 @dataclasses.dataclass(frozen=True)
@@ -185,11 +174,11 @@ class Dots3NoteBlock(nn.Module):
                rows=None, part=None, carry=None):
     cfg = self.cfg
     norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
-    # In three parts where the step asks (models/gpt.py:SplitLayer).
+    # In three parts where the step asks (models/slot_core.py:SplitLayer).
     latent = LatentAttention(
-        cfg, decode=self.decode, kv_write_impl=self.kv_write_impl,
+        cfg, cfg.latent_dims(self.layer_type), decode=self.decode,
+        kv_write_impl=self.kv_write_impl,
         slot_attn_impl=self.slot_attn_impl,
-        dims=cfg.latent_dims(self.layer_type),
         dsa_index_impl=self.dsa_index_impl, name="latent")
     if part == "mix":
       return latent(carry, positions, slot_cursors, num_valid, rows, part)
@@ -231,11 +220,6 @@ class Dots3Note(nn.Module):
                        "engine); pass decode=True")
     B, S = ids.shape
     if decode:
-      if dsa_index_impl is None or slot_attn_impl is None:
-        # A direct caller: resolve what the engine would have.
-        from easyparallellibrary_tpu.serving import kv_cache as kv_lib
-        slot_attn_impl = slot_attn_impl or kv_lib.slot_attn_impl(cfg, B, S)
-        dsa_index_impl = dsa_index_impl or kv_lib.dsa_index_impl(cfg, B, S)
       rows, ids = flat_ids(ids, slot_cursors, num_valid, rows)
       positions = rows.positions
     else:
@@ -263,4 +247,4 @@ class Dots3Note(nn.Module):
     x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm_f")(x)
     if return_hidden:
       return x
-    return _dense(cfg, cfg.vocab_size, "lm_head")(x)
+    return dense(cfg, cfg.vocab_size, "lm_head")(x)

@@ -12,12 +12,12 @@ equations in plain float32 and the tests compare the two.
 
 Serving only.  The model exposes the surface the continuous-batching
 engine steps through (``model.cfg``, ``model.apply(..., decode=True,
-slot_cursors=..., mutable=["cache"])``, ``models.gpt.slot_step_logits``)
+slot_cursors=..., mutable=["cache"])``, ``models.slot_core.slot_step_logits``)
 and keeps TWO kinds of per-slot state in the ``cache`` collection, which
 ``serving/kv_cache.py`` allocates from :meth:`JambaConfig.layer_kinds`:
 
 * attention layers: ``cached_key`` / ``cached_value`` under a cursor,
-  through ``models.gpt.slot_cache_attend``: ``[slots, Lc, H_kv x hd]``,
+  through ``models.slot_core.slot_cache_attend``: ``[slots, Lc, H_kv x hd]``,
   kept in rows, where that width fills whole lane tiles (the published
   one head of 128 does), ``[slots, Lc, H_kv, hd]`` elsewhere
   (serving/kv_cache.py, order note);
@@ -48,15 +48,15 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
-from easyparallellibrary_tpu.models.gpt import (
-    SplitLayer, _missing_slot_cache, child_of, flat_ids, slot_cache_attend,
+from easyparallellibrary_tpu.models.blocks import (
+    GatedMLP, RMSNorm, advance_window, boxed, dense, gqa_causal_attention,
+    uniform)
+from easyparallellibrary_tpu.models.layer_kinds import ATTENTION, MAMBA
+from easyparallellibrary_tpu.models.slot_core import (
+    SplitLayer, child_of, flat_ids, missing_slot_cache, slot_cache_attend,
     slot_layers)
-from easyparallellibrary_tpu.ops import Dense, Embedding
+from easyparallellibrary_tpu.ops import Embedding
 from easyparallellibrary_tpu.ops.layers import HeldParams
-
-# What a layer keeps per slot: the cache manager's vocabulary
-# (serving/kv_cache.py reads ``cfg.layer_kinds()``).
-ATTENTION, MAMBA = "attention", "mamba"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,57 +94,6 @@ class JambaConfig:
         else MAMBA for i in range(self.num_layers))
 
 
-def _boxed(init, ndim: int):
-  return nn.with_partitioning(init, (None,) * ndim)
-
-
-class RMSNorm(HeldParams, nn.Module):
-  """``x * rsqrt(mean(x^2) + eps) * g`` in float32; the gain is a float32
-  parameter whatever the weights' dtype.  ``rescale`` is a constant the
-  result is multiplied by before it is rounded (models/dots3_note.py: the
-  rescaled latents of its attention); 1 leaves the arithmetic as it is."""
-  eps: float
-  dtype: Any
-  rescale: float = 1.0
-
-  @nn.compact
-  def __call__(self, x):
-    g = self.param("scale", _boxed(nn.initializers.ones_init(), 1),
-                   (x.shape[-1],), jnp.float32)
-    x = x.astype(jnp.float32)
-    y = x * jax.lax.rsqrt(
-        jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * g
-    if self.rescale != 1.0:
-      y = y * self.rescale
-    return y.astype(self.dtype)
-
-
-def _dense(cfg, features: int, name: str):
-  return Dense(features, use_bias=False, parallel="none", dtype=cfg.dtype,
-               param_dtype=cfg.param_dtype,
-               kernel_init=nn.initializers.normal(stddev=0.02), name=name)
-
-
-def gqa_causal_attention(q, k, v, dtype, window: Optional[int] = None):
-  """Dense causal attention of ``q`` [B, S, H, hd] over ``k``/``v`` [B, S,
-  H_kv, hd], each K/V head shared by H / H_kv query heads: the full
-  forward's attention (float32 softmax, as ``_dense_causal_attention``).
-  Behind a ``window`` position ``t`` sees ``t - window < s <= t``
-  (models/smallthinker.py)."""
-  B, S, H, hd = q.shape
-  Hkv = k.shape[2]
-  q = q.reshape(B, S, Hkv, H // Hkv, hd)
-  scale = 1.0 / jnp.sqrt(hd).astype(dtype)
-  logits = jnp.einsum("bqhgd,bkhd->bhgqk", q, k) * scale
-  mask = jnp.tril(jnp.ones((S, S), jnp.bool_))
-  if window is not None:
-    mask &= ~jnp.tril(jnp.ones((S, S), jnp.bool_), -window)
-  logits = jnp.where(mask, logits, jnp.asarray(-1e9, logits.dtype))
-  probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-  out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(dtype), v)
-  return out.reshape(B, S, H, hd)
-
-
 class AttentionMixer(nn.Module):
   cfg: JambaConfig
   decode: bool = False
@@ -156,26 +105,26 @@ class AttentionMixer(nn.Module):
                part=None):
     cfg = self.cfg
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    out_proj = lambda: _dense(cfg, cfg.d_model, "o")
+    out_proj = lambda: dense(cfg, cfg.d_model, "o")
     # In slot mode the whole call is its three parts in turn
-    # (models/gpt.py:SplitLayer), ``h`` from the second on the carry.
+    # (models/slot_core.py:SplitLayer), ``h`` from the second on the carry.
     if part in (None, "pre"):
       B, S, _ = h.shape
-      q = _dense(cfg, H * hd, "q")(h).reshape(B, S, H, hd)
-      k = _dense(cfg, Hkv * hd, "k")(h).reshape(B, S, Hkv, hd)
-      v = _dense(cfg, Hkv * hd, "v")(h).reshape(B, S, Hkv, hd)
+      q = dense(cfg, H * hd, "q")(h).reshape(B, S, H, hd)
+      k = dense(cfg, Hkv * hd, "k")(h).reshape(B, S, Hkv, hd)
+      v = dense(cfg, Hkv * hd, "v")(h).reshape(B, S, Hkv, hd)
       if not self.decode:
         return out_proj()(gqa_causal_attention(q, k, v, cfg.dtype).reshape(
             B, S, H * hd))
       # ``h`` is the step's token-flat batch [T, 1, D]
-      # (models/gpt.py:SlotRows); the window write and the attend take
+      # (models/slot_core.py:SlotRows); the window write and the attend take
       # their operands as [slots, C, ...].
       h = (), tuple(rows.to_slots(t[:, 0]) for t in (q, k, v))
       if part == "pre":
         return h
     if part in (None, "mix"):
-      ck = self.variable("cache", "cached_key", _missing_slot_cache)
-      cv = self.variable("cache", "cached_value", _missing_slot_cache)
+      ck = self.variable("cache", "cached_key", missing_slot_cache)
+      cv = self.variable("cache", "cached_value", missing_slot_cache)
       out, ck.value, cv.value = slot_cache_attend(
           *h[1], ck.value, cv.value, slot_cursors, cfg.dtype,
           write_impl=self.kv_write_impl, attn_impl=self.slot_attn_impl,
@@ -184,20 +133,6 @@ class AttentionMixer(nn.Module):
       if part == "mix":
         return h
     return out_proj()(rows.to_flat(h[1]).reshape(-1, 1, H * hd))
-
-
-def advance_window(full, num_valid, keep: int):
-  """The convolution's carried inputs after a chunk: rows ``[num_valid,
-  num_valid + keep)`` of ``full`` [B, keep + C, Di] (the old window
-  followed by the chunk's inputs), per slot.  A select and a sum over the
-  few rows, not a gather: exact, and no serial loop over the slots on a
-  TPU.  ``num_valid = 0`` returns the old window bit for bit."""
-  if num_valid is None:
-    return full[:, full.shape[1] - keep:]
-  rows = num_valid[:, None] + jnp.arange(keep)[None]          # [B, keep]
-  pick = rows[:, :, None] == jnp.arange(full.shape[1])[None, None]
-  return jnp.sum(jnp.where(pick[..., None], full[:, None],
-                           jnp.zeros((), full.dtype)), axis=2)
 
 
 def _dt_bias_init(key, shape, dtype=jnp.float32):
@@ -213,13 +148,6 @@ def _a_log_init(key, shape, dtype=jnp.float32):
   """``log(1..N)`` down the state axis of ``[N, Di]``."""
   n = jnp.arange(1, shape[0] + 1, dtype=jnp.float32)
   return jnp.broadcast_to(jnp.log(n)[:, None], shape).astype(dtype)
-
-
-def _uniform(bound: float):
-  def init(key, shape, dtype=jnp.float32):
-    return jax.random.uniform(key, shape, jnp.float32, -bound,
-                              bound).astype(dtype)
-  return init
 
 
 class MambaMixer(HeldParams, nn.Module):
@@ -239,22 +167,22 @@ class MambaMixer(HeldParams, nn.Module):
     Di, N, K, R = (cfg.d_inner, cfg.mamba_d_state, cfg.mamba_d_conv,
                    cfg.mamba_dt_rank)
     f32 = jnp.float32
-    uz = _dense(cfg, 2 * Di, "in_proj")(h)
+    uz = dense(cfg, 2 * Di, "in_proj")(h)
     if self.decode:
       # ``h`` is the step's token-flat batch [T, 1, D]
-      # (models/gpt.py:SlotRows): the convolution over a slot's window
+      # (models/slot_core.py:SlotRows): the convolution over a slot's window
       # and the scan over its state run as [slots, C, ...], between the
       # two projections.
       uz = rows.to_slots(uz[:, 0])
     B, C, _ = uz.shape
     u, z = uz[..., :Di], uz[..., Di:]
-    conv_w = self.param("conv_w", _boxed(_uniform(K ** -0.5), 2), (K, Di),
+    conv_w = self.param("conv_w", boxed(uniform(K ** -0.5), 2), (K, Di),
                         cfg.param_dtype)
-    conv_b = self.param("conv_b", _boxed(_uniform(K ** -0.5), 1), (Di,),
+    conv_b = self.param("conv_b", boxed(uniform(K ** -0.5), 1), (Di,),
                         cfg.param_dtype)
     if self.decode:
-      conv_var = self.variable("cache", "conv_state", _missing_slot_cache)
-      ssm_var = self.variable("cache", "ssm_state", _missing_slot_cache)
+      conv_var = self.variable("cache", "conv_state", missing_slot_cache)
+      ssm_var = self.variable("cache", "ssm_state", missing_slot_cache)
       window, state = conv_var.value, ssm_var.value
       if reset is not None:
         window = jnp.where(reset[:, None, None],
@@ -270,19 +198,19 @@ class MambaMixer(HeldParams, nn.Module):
     if self.decode:
       conv_var.value = advance_window(full, num_valid, K - 1)
 
-    dbc = _dense(cfg, R + 2 * N, "x_proj")(u)
+    dbc = dense(cfg, R + 2 * N, "x_proj")(u)
     norm = lambda name: RMSNorm(cfg.rms_norm_eps, f32, name=name)
     dt = norm("dt_norm")(dbc[..., :R])
     Bm = norm("b_norm")(dbc[..., R:R + N])
     Cm = norm("c_norm")(dbc[..., R + N:])
-    dt_w = self.param("dt_proj", _boxed(_uniform(R ** -0.5), 2), (R, Di),
+    dt_w = self.param("dt_proj", boxed(uniform(R ** -0.5), 2), (R, Di),
                       cfg.param_dtype)
-    dt_b = self.param("dt_bias", _boxed(_dt_bias_init, 1), (Di,), f32)
+    dt_b = self.param("dt_bias", boxed(_dt_bias_init, 1), (Di,), f32)
     delta = jax.nn.softplus(
         jnp.matmul(dt.astype(cfg.dtype), jnp.asarray(dt_w, cfg.dtype),
                    preferred_element_type=f32) + dt_b)
-    a_log = self.param("A_log", _boxed(_a_log_init, 2), (N, Di), f32)
-    d_skip = self.param("D", _boxed(nn.initializers.ones_init(), 1), (Di,),
+    a_log = self.param("A_log", boxed(_a_log_init, 2), (N, Di), f32)
+    d_skip = self.param("D", boxed(nn.initializers.ones_init(), 1), (Di,),
                         f32)
     y, state = ssm_scan(state, u, delta, Bm, Cm, z, -jnp.exp(a_log), d_skip,
                         num_valid=num_valid, reset=reset,
@@ -290,23 +218,7 @@ class MambaMixer(HeldParams, nn.Module):
     if self.decode:
       ssm_var.value = state
       y = rows.to_flat(y)[:, None]
-    return _dense(cfg, cfg.d_model, "out_proj")(y)
-
-
-class GatedMLP(nn.Module):
-  """``down(silu(gate(h)) * up(h))``; ``cfg`` gives ``d_model`` and the
-  dtypes, ``d_ff`` the width where a model has more than one
-  (models/glm_moe.py: its dense layer and its shared expert)."""
-  cfg: Any
-  d_ff: Optional[int] = None
-
-  @nn.compact
-  def __call__(self, h):
-    cfg = self.cfg
-    d_ff = self.d_ff or cfg.d_ff
-    gate = _dense(cfg, d_ff, "gate")(h)
-    up = _dense(cfg, d_ff, "up")(h)
-    return _dense(cfg, cfg.d_model, "down")(jax.nn.silu(gate) * up)
+    return dense(cfg, cfg.d_model, "out_proj")(y)
 
 
 class JambaBlock(nn.Module):
@@ -323,7 +235,7 @@ class JambaBlock(nn.Module):
     cfg = self.cfg
     norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
     if self.kind == ATTENTION:
-      # In three parts where the step asks (models/gpt.py:SplitLayer).
+      # In three parts where the step asks (models/slot_core.py:SplitLayer).
       mixer = AttentionMixer(cfg, decode=self.decode,
                              kv_write_impl=self.kv_write_impl,
                              slot_attn_impl=self.slot_attn_impl, name="attn")
@@ -349,7 +261,7 @@ class Jamba(nn.Module):
   ``[slots]`` says how many of the chunk's positions each slot's
   recurrent state takes (``None``: all), ``reset`` bool ``[slots]`` which
   slots start from zero state.  In slot mode the position-wise layers run
-  on the token-flat batch ``rows`` describes (models/gpt.py:SlotRows;
+  on the token-flat batch ``rows`` describes (models/slot_core.py:SlotRows;
   every position of every slot when none is handed in) and the logits
   are those of the rows it names."""
 

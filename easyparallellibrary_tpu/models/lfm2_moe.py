@@ -30,7 +30,7 @@ Serving: slot mode (``decode=True`` with ``slot_cursors``) keeps per slot,
 through ``serving/kv_cache.py`` and :meth:`Lfm2MoeConfig.layer_kinds`,
 
 * attention layers: ``cached_key`` / ``cached_value`` under a cursor,
-  through ``models.gpt.slot_cache_attend`` (``[slots, Lc, H_kv x hd]``,
+  through ``models.slot_core.slot_cache_attend`` (``[slots, Lc, H_kv x hd]``,
   kept in rows, at the published 8 heads of 64);
 * conv layers: ``conv_state`` ``[slots, L - 1, d_model]``, the layer's
   WHOLE state (kind :data:`CONV`).  Like the hybrid's recurrence it has no
@@ -55,20 +55,16 @@ from typing import Any, Optional
 import jax.numpy as jnp
 from flax import linen as nn
 
-from easyparallellibrary_tpu.models.glm_moe import rotary
-from easyparallellibrary_tpu.models.gpt import (
-    SplitLayer, _missing_slot_cache, child_of, flat_ids, slot_cache_attend,
-    slot_layers)
-from easyparallellibrary_tpu.models.jamba import (
-    ATTENTION, GatedMLP, RMSNorm, _boxed, _dense, _uniform, advance_window,
-    gqa_causal_attention)
+from easyparallellibrary_tpu.models.blocks import (
+    GatedMLP, RMSNorm, advance_window, boxed, dense, gqa_causal_attention,
+    rotary, uniform)
+from easyparallellibrary_tpu.models.layer_kinds import ATTENTION, CONV
 from easyparallellibrary_tpu.models.moe import DroplessMoE
+from easyparallellibrary_tpu.models.slot_core import (
+    SplitLayer, child_of, flat_ids, missing_slot_cache, slot_cache_attend,
+    slot_layers)
 from easyparallellibrary_tpu.ops import Embedding
 from easyparallellibrary_tpu.ops.layers import HeldParams
-
-# What a conv layer keeps per slot (serving/kv_cache.py reads
-# ``cfg.layer_kinds()``): the convolution's last inputs, nothing else.
-CONV = "conv"
 
 # ``layer_types`` of the published LFM2-8B-A1B: 18 conv, 6 attention.
 PUBLISHED_LAYER_TYPES = (
@@ -132,17 +128,17 @@ class ShortConv(HeldParams, nn.Module):
     cfg = self.cfg
     D = h.shape[-1]
     L = cfg.conv_L_cache
-    bcu = _dense(cfg, 3 * D, "in_proj")(h)
+    bcu = dense(cfg, 3 * D, "in_proj")(h)
     gate_b, gate_c, u = bcu[..., :D], bcu[..., D:2 * D], bcu[..., 2 * D:]
     z = gate_b * u
-    conv_w = self.param("conv_w", _boxed(_uniform(L ** -0.5), 2), (L, D),
+    conv_w = self.param("conv_w", boxed(uniform(L ** -0.5), 2), (L, D),
                         cfg.param_dtype)
     if self.decode:
       # ``h`` is the step's token-flat batch [T, 1, D]
-      # (models/gpt.py:SlotRows): the convolution over a slot's window
+      # (models/slot_core.py:SlotRows): the convolution over a slot's window
       # runs as [slots, C, D], everything around it stays flat.
       z = rows.to_slots(z[:, 0])
-      state = self.variable("cache", "conv_state", _missing_slot_cache)
+      state = self.variable("cache", "conv_state", missing_slot_cache)
       window = state.value
       if reset is not None:
         window = jnp.where(reset[:, None, None],
@@ -157,7 +153,7 @@ class ShortConv(HeldParams, nn.Module):
     if self.decode:
       state.value = advance_window(full, num_valid, L - 1)
       conv = rows.to_flat(conv)[:, None]
-    return _dense(cfg, D, "out_proj")(gate_c * conv)
+    return dense(cfg, D, "out_proj")(gate_c * conv)
 
 
 class NormedAttention(nn.Module):
@@ -173,29 +169,29 @@ class NormedAttention(nn.Module):
                rows=None, part=None):
     cfg = self.cfg
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    out_proj = lambda: _dense(cfg, cfg.d_model, "o")
+    out_proj = lambda: dense(cfg, cfg.d_model, "o")
     # In slot mode the whole call is its three parts in turn
-    # (models/gpt.py:SplitLayer), ``h`` from the second on the carry.
+    # (models/slot_core.py:SplitLayer), ``h`` from the second on the carry.
     if part in (None, "pre"):
       B, S, _ = h.shape
       norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype, name=name)
-      q = _dense(cfg, H * hd, "q")(h).reshape(B, S, H, hd)
-      k = _dense(cfg, Hkv * hd, "k")(h).reshape(B, S, Hkv, hd)
-      v = _dense(cfg, Hkv * hd, "v")(h).reshape(B, S, Hkv, hd)
+      q = dense(cfg, H * hd, "q")(h).reshape(B, S, H, hd)
+      k = dense(cfg, Hkv * hd, "k")(h).reshape(B, S, Hkv, hd)
+      v = dense(cfg, Hkv * hd, "v")(h).reshape(B, S, Hkv, hd)
       q = rotary(norm("q_norm")(q), positions, cfg.rope_theta)
       k = rotary(norm("k_norm")(k), positions, cfg.rope_theta)
       if not self.decode:
         return out_proj()(gqa_causal_attention(q, k, v, cfg.dtype).reshape(
             B, S, H * hd))
       # ``h`` is the step's token-flat batch [T, 1, D]
-      # (models/gpt.py:SlotRows); the window write and the attend take
+      # (models/slot_core.py:SlotRows); the window write and the attend take
       # their operands as [slots, C, ...].
       h = (), tuple(rows.to_slots(t[:, 0]) for t in (q, k, v))
       if part == "pre":
         return h
     if part in (None, "mix"):
-      ck = self.variable("cache", "cached_key", _missing_slot_cache)
-      cv = self.variable("cache", "cached_value", _missing_slot_cache)
+      ck = self.variable("cache", "cached_key", missing_slot_cache)
+      cv = self.variable("cache", "cached_value", missing_slot_cache)
       out, ck.value, cv.value = slot_cache_attend(
           *h[1], ck.value, cv.value, slot_cursors, cfg.dtype,
           write_impl=self.kv_write_impl, attn_impl=self.slot_attn_impl,
@@ -221,7 +217,7 @@ class Lfm2MoeBlock(nn.Module):
     cfg = self.cfg
     norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype, name=name)
     if self.kind == ATTENTION:
-      # In three parts where the step asks (models/gpt.py:SplitLayer).
+      # In three parts where the step asks (models/slot_core.py:SplitLayer).
       attn = NormedAttention(
           cfg, decode=self.decode, kv_write_impl=self.kv_write_impl,
           slot_attn_impl=self.slot_attn_impl, name="attn")
@@ -256,7 +252,7 @@ class Lfm2Moe(nn.Module):
   experts are handed and how far a convolution window advances —
   ``reset`` bool ``[slots]`` which slots start from an empty window.  In
   slot mode the position-wise layers run on the token-flat batch ``rows``
-  describes (models/gpt.py:SlotRows; every position of every slot when
+  describes (models/slot_core.py:SlotRows; every position of every slot when
   none is handed in) and the logits are those of the rows it names."""
 
   cfg: Lfm2MoeConfig

@@ -45,6 +45,7 @@ from flax import linen as nn
 from jax.sharding import PartitionSpec as P
 
 from easyparallellibrary_tpu import constants
+from easyparallellibrary_tpu.models.blocks import GatedMLP, boxed
 from easyparallellibrary_tpu.ops.layers import HeldParams
 
 
@@ -483,8 +484,6 @@ class DroplessMoE(HeldParams, nn.Module):
 
   @nn.compact
   def __call__(self, x, live=None, router_in=None):
-    from easyparallellibrary_tpu.models.jamba import (
-        GatedMLP, _boxed as boxed)
     cfg = self.cfg
     k, F, D = cfg.num_experts_per_tok, cfg.moe_d_ff, cfg.d_model
     held = getattr(cfg, "experts_held", None)

@@ -33,7 +33,7 @@ Slot mode (``decode=True``, the serving engine) keeps, per layer KIND
 
 * ``attention`` (a full layer): ``cached_key`` / ``cached_value`` under the
   slot's cursor, ``[slots, Lc, H_kv x hd]`` kept in rows, through
-  ``models.gpt.slot_cache_attend`` (``kv_write``, ``slot_attn``);
+  ``models.slot_core.slot_cache_attend`` (``kv_write``, ``slot_attn``);
 * ``window_kv`` (a window layer): the same pair as a RING of ``R``
   rows whatever the served context, position ``p`` at row ``p mod R``,
   ``R`` = window - 1 + chunk up to the attend's 128-row tile
@@ -59,19 +59,14 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from easyparallellibrary_tpu.models.dots3_note import ring_length
-from easyparallellibrary_tpu.models.glm_moe import rotary
-from easyparallellibrary_tpu.models.gpt import (
-    SplitLayer, _missing_slot_cache, child_of, flat_ids, slot_cache_attend,
-    slot_layers)
-from easyparallellibrary_tpu.models.jamba import (
-    ATTENTION, RMSNorm, _dense, gqa_causal_attention)
+from easyparallellibrary_tpu.models.blocks import (
+    RMSNorm, dense, gqa_causal_attention, ring_length, rotary)
+from easyparallellibrary_tpu.models.layer_kinds import ATTENTION, WINDOW_KV
 from easyparallellibrary_tpu.models.moe import DroplessMoE, softmax_topk_route
+from easyparallellibrary_tpu.models.slot_core import (
+    SplitLayer, child_of, flat_ids, missing_slot_cache, slot_cache_attend,
+    slot_layers)
 from easyparallellibrary_tpu.ops import Embedding
-
-# What a window layer keeps per slot (serving/kv_cache.py reads
-# ``cfg.layer_kinds()``): its K/V pair as a ring.
-WINDOW_KV = "window_kv"
 
 # ``sliding_window_layout`` and ``rope_layout`` of the published model.
 PUBLISHED_LAYOUT = (0, 1, 1, 1) * 13
@@ -140,14 +135,14 @@ class GroupedAttention(nn.Module):
                rows=None, part=None):
     cfg = self.cfg
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    out_proj = lambda: _dense(cfg, cfg.d_model, "o")
+    out_proj = lambda: dense(cfg, cfg.d_model, "o")
     # In slot mode the whole call is its three parts in turn
-    # (models/gpt.py:SplitLayer), ``h`` from the second on the carry.
+    # (models/slot_core.py:SplitLayer), ``h`` from the second on the carry.
     if part in (None, "pre"):
       B, S, _ = h.shape
-      q = _dense(cfg, H * hd, "q")(h).reshape(B, S, H, hd)
-      k = _dense(cfg, Hkv * hd, "k")(h).reshape(B, S, Hkv, hd)
-      v = _dense(cfg, Hkv * hd, "v")(h).reshape(B, S, Hkv, hd)
+      q = dense(cfg, H * hd, "q")(h).reshape(B, S, H, hd)
+      k = dense(cfg, Hkv * hd, "k")(h).reshape(B, S, Hkv, hd)
+      v = dense(cfg, Hkv * hd, "v")(h).reshape(B, S, Hkv, hd)
       if self.rope:
         q = rotary(q, positions, cfg.rope_theta)
         k = rotary(k, positions, cfg.rope_theta)
@@ -155,15 +150,15 @@ class GroupedAttention(nn.Module):
         return out_proj()(gqa_causal_attention(
             q, k, v, cfg.dtype, self.window).reshape(B, S, H * hd))
       # ``h`` is the step's token-flat batch [T, 1, D]
-      # (models/gpt.py:SlotRows); the window write and the attend take
+      # (models/slot_core.py:SlotRows); the window write and the attend take
       # their operands as [slots, C, ...].
       h = (), tuple(rows.to_slots(t[:, 0]) for t in (q, k, v))
       if part == "pre":
         return h
     if part in (None, "mix"):
       q, k, v = h[1]
-      ck = self.variable("cache", "cached_key", _missing_slot_cache)
-      cv = self.variable("cache", "cached_value", _missing_slot_cache)
+      ck = self.variable("cache", "cached_key", missing_slot_cache)
+      cv = self.variable("cache", "cached_value", missing_slot_cache)
       if self.window is None:
         out, ck.value, cv.value = slot_cache_attend(
             q, k, v, ck.value, cv.value, slot_cursors, cfg.dtype,
@@ -200,7 +195,7 @@ class SmallThinkerBlock(nn.Module):
                rows=None, part=None, carry=None):
     cfg = self.cfg
     norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype, name=name)
-    # In three parts where the step asks (models/gpt.py:SplitLayer).
+    # In three parts where the step asks (models/slot_core.py:SplitLayer).
     attn = GroupedAttention(
         cfg, self.window, self.rope, decode=self.decode,
         write_impl=self.write_impl, attn_impl=self.attn_impl, name="attn")
@@ -246,13 +241,6 @@ class SmallThinker(nn.Module):
                        "engine); pass decode=True")
     B, S = ids.shape
     if decode:
-      if kv_win_write_impl is None or kv_win_attn_impl is None:
-        # A direct caller: resolve what the engine would have.
-        from easyparallellibrary_tpu.serving import kv_cache as kv_lib
-        kv_win_write_impl = (kv_win_write_impl
-                             or kv_lib.kv_win_write_impl(cfg, B, S))
-        kv_win_attn_impl = (kv_win_attn_impl
-                            or kv_lib.kv_win_attn_impl(cfg, B, S))
       rows, ids = flat_ids(ids, slot_cursors, num_valid, rows)
       positions = rows.positions
     else:
@@ -282,4 +270,4 @@ class SmallThinker(nn.Module):
     x = RMSNorm(cfg.norm_eps, cfg.dtype, name="norm_f")(x)
     if return_hidden:
       return x
-    return _dense(cfg, cfg.vocab_size, "lm_head")(x)
+    return dense(cfg, cfg.vocab_size, "lm_head")(x)

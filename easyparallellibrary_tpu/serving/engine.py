@@ -8,14 +8,14 @@ jitted step — compiled once, shapes never change — fuses
 
 into a single model call against the slot KV cache (kv_cache.py),
 per-slot cursors selecting each slot's absolute positions and causal
-window (models/gpt.py ``slot_cache_attend``).  The plan is a
+window (models/slot_core.py ``slot_cache_attend``).  The plan is a
 ``[num_slots, chunk]`` block; the call does its position-wise work on
 the block's LIVE positions, packed in slot order into a token-flat
 batch of ``flat_width(num_slots, chunk)`` rows, moves to the
 ``[num_slots, chunk, ...]`` layout only around the operations that own
 per-slot state (the window write, the attend, a recurrence), and runs
-the head on the one row a slot samples from (models/gpt.py ``SlotRows``;
-docs/serving.md "The flat batch").
+the head on the one row a slot samples from (models/slot_core.py
+``SlotRows``; docs/serving.md "The flat batch").
 Requests therefore join and leave the batch every iteration with zero
 recompilation — iteration-level batching as in Orca (OSDI'22) — and the
 cache + cursor buffers are donated, so the engine's steady-state device
@@ -76,6 +76,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from easyparallellibrary_tpu.env import Env
+from easyparallellibrary_tpu.models.slot_core import (
+    paged_step_logits, slot_step_logits)
 from easyparallellibrary_tpu.observability import device as device_lib
 from easyparallellibrary_tpu.observability import slo as slo_lib
 from easyparallellibrary_tpu.observability import trace as trace_lib
@@ -240,7 +242,7 @@ def _walk_rows(resident, num_valid, granule: int, length: int) -> int:
 
 def flat_width(num_slots: int, chunk: int) -> int:
   """Rows ``T`` of the token-flat batch the contiguous fused step runs its
-  position-wise layers on (models/gpt.py:SlotRows), static for the
+  position-wise layers on (models/slot_core.py:SlotRows), static for the
   engine's life: one compiled program a twin.  A step computes ``T`` rows
   whatever is live, so ``T`` is sized for what a step holds, not for every
   position of every slot: HALF of ``num_slots x chunk``, up to a multiple
@@ -459,59 +461,45 @@ class ContinuousBatchingEngine:
     else:
       self.block_size = self.num_blocks = self.token_budget = 0
       self._paged_impl = None
-    # The contiguous cache's window write (kernels/kv_write.py): which
-    # lowering the fused step is built with, resolved ONCE here from
-    # the backend, the leaf's shape and dtype and the mesh (None on a
-    # paged engine, whose pools take a scatter of flat rows).
-    self.kv_write_impl = None if self.paged else kv_lib.kv_write_impl(
+    # What the fused step is lowered to (serving/kv_cache.py
+    # ``step_lowerings``): each kernel rule's answer under its name,
+    # resolved ONCE here from the backend, the leaves' shapes and dtypes
+    # and the mesh; ``None`` where the model has no layer a rule is about,
+    # and throughout on a paged engine, whose pools take a scatter of flat
+    # rows and the paged attend above.  A run says what it timed: a rule
+    # that declined shows "reference".  Metadata, so no ring eviction
+    # loses it.
+    self.lowerings = kv_lib.step_lowerings(
         cfg, self.num_slots, self.chunk, self.mesh)
-    # The attend over that cache (kernels/slot_attention.py): the
-    # kernel that reads each slot's live rows, or the einsums over every
-    # row of every slot — resolved once by the twin of the write's rule.
-    self.slot_attn_impl = None if self.paged else kv_lib.slot_attn_impl(
-        cfg, self.num_slots, self.chunk, self.mesh)
+    if self.paged:
+      self.lowerings = dict.fromkeys(self.lowerings)
+    for name, impl in kv_lib.resolved(self.lowerings).items():
+      trace_lib.get_tracer().metadata(
+          f"{self._track_prefix}/{name}", {"impl": impl})
     # The granule and length of the attend kernel's walk, where the step
     # was built on it: what ``serving/attn_rows_read`` counts by.
     self._attn_walk = (
         kv_lib.slot_attn_walk(cfg, self.num_slots, self.chunk)
-        if self.slot_attn_impl in ("pallas", "interpret") else None)
+        if self.lowerings["slot_attn_impl"] in ("pallas", "interpret")
+        else None)
     # Rows the cache holds for one layer: what ``serving/live_kv_rows``
     # is a share of.
     self._kv_rows = (self.num_blocks * self.block_size if self.paged else
                      self.num_slots * kv_lib.cache_length(cfg, self.chunk))
-    if self.kv_write_impl is not None:
-      # A run says which write and which attend it timed: a step that
-      # fell back shows "reference".  Metadata, so no ring eviction
-      # loses it.
-      trace_lib.get_tracer().metadata(
-          f"{self._track_prefix}/kv_write_impl",
-          {"impl": self.kv_write_impl})
-      trace_lib.get_tracer().metadata(
-          f"{self._track_prefix}/slot_attn_impl",
-          {"impl": self.slot_attn_impl})
-    # Recurrent state beside K/V (models/jamba.py's Mamba layers,
-    # models/lfm2_moe.py's conv layers): what the step must tell the
-    # model (``reset``), and for a Mamba layer the scan's lowering
-    # (kernels/ssm_scan.py), resolved once by the same kind of rule.
-    # None / absent for a model without such a layer.
+    # What gates the step's arguments and its counters (their names are
+    # PERF.md section 3's): recurrent state beside K/V, which the step
+    # must tell which slots start a request (``reset``); routed experts,
+    # whose load the step hands back; attends that read less than every
+    # row under a slot's bound, and what their counters count rows up to
+    # (``_sparse``: a selecting layer's selection and the latent window;
+    # ``_kv_window``: the window of K/V rings beside full layers).
     self._recurrent = kv_lib.has_recurrent_state(cfg)
-    self.ssm_scan_impl = kv_lib.ssm_scan_impl(
-        cfg, self.num_slots, self.chunk, self.mesh)
-    if self.ssm_scan_impl is not None:
-      trace_lib.get_tracer().metadata(
-          f"{self._track_prefix}/ssm_scan_impl",
-          {"impl": self.ssm_scan_impl})
-    # Dropless experts inside the fused step (models/glm_moe.py,
-    # models/lfm2_moe.py): the
-    # grouped matmul's lowering (kernels/moe_gmm.py), resolved once by its
-    # rule; None for a model without routed experts.
-    self.moe_gmm_impl = kv_lib.moe_gmm_impl(
-        cfg, self.num_slots, self.chunk, self.mesh)
-    self._experts = self.moe_gmm_impl is not None
-    if self._experts:
-      trace_lib.get_tracer().metadata(
-          f"{self._track_prefix}/moe_gmm_impl",
-          {"impl": self.moe_gmm_impl})
+    self._experts = self.lowerings["moe_gmm_impl"] is not None
+    self._sparse = ((cfg.index_topk, cfg.sliding_window)
+                    if self.lowerings["dsa_index_impl"] is not None else None)
+    self._kv_window = (cfg.sliding_window if
+                       self.lowerings["kv_win_write_impl"] is not None
+                       else None)
     # A share of the routed experts (``cfg.experts_held``: one chip of
     # several a layer is divided over): which of the router's experts the
     # layers hold.  None: all.
@@ -521,38 +509,6 @@ class ContinuousBatchingEngine:
           f"{self._track_prefix}/experts_held",
           {"first": self.experts_held[0], "count": self.experts_held[1],
            "published": cfg.n_routed_experts})
-    # Attends that read less than every row under a slot's bound
-    # (models/dots3_note.py): the lowering of a selecting layer's index
-    # scores (kernels/dsa_index.py), resolved once by its rule, and what
-    # the step's counters count rows up to (``_sparse``: the selection's
-    # size and the window); None for a model without such layers.
-    self.dsa_index_impl = None if self.paged else kv_lib.dsa_index_impl(
-        cfg, self.num_slots, self.chunk, self.mesh)
-    self._sparse = None
-    if self.dsa_index_impl is not None:
-      trace_lib.get_tracer().metadata(
-          f"{self._track_prefix}/dsa_index_impl",
-          {"impl": self.dsa_index_impl})
-      self._sparse = (cfg.index_topk, cfg.sliding_window)
-    # Window layers over K/V PAIRS beside full ones
-    # (models/smallthinker.py): the ring write's and the windowed attend's
-    # lowerings, each resolved once by its own rule beside the full
-    # layers' pair above, and the window the step's row counters hold a
-    # slot's rows to (``_kv_window``); None for a model without such
-    # layers.
-    self.kv_win_write_impl = None if self.paged else (
-        kv_lib.kv_win_write_impl(cfg, self.num_slots, self.chunk, self.mesh))
-    self.kv_win_attn_impl = None if self.paged else (
-        kv_lib.kv_win_attn_impl(cfg, self.num_slots, self.chunk, self.mesh))
-    self._kv_window = None
-    if self.kv_win_write_impl is not None:
-      trace_lib.get_tracer().metadata(
-          f"{self._track_prefix}/kv_win_write_impl",
-          {"impl": self.kv_win_write_impl})
-      trace_lib.get_tracer().metadata(
-          f"{self._track_prefix}/kv_win_attn_impl",
-          {"impl": self.kv_win_attn_impl})
-      self._kv_window = cfg.sliding_window
     # What the contiguous cache holds of each kind of state (K/V,
     # recurrent state, latent rows) and the ORDER its leaves under a
     # cursor are kept in (``kv_order``: rows or positions,
@@ -822,36 +778,21 @@ class ContinuousBatchingEngine:
                 f"{kv_lib.paged_cache_bytes(cfg, self.num_blocks, self.block_size) / 1e6:.1f} MB")
     else:
       lay = self.cache_layout
+      total = kv_lib.cache_bytes(cfg, self.num_slots, self.chunk)
+      # The kinds of state the cache holds any of (kv, state, latent, ...).
+      held = [key.removesuffix("_leaves") for key in lay
+              if key.endswith("_leaves") and lay[key]]
       layout = (f"flat width {self.flat_width}"
                 + (f" / {self.flat_narrow}"
                    if self.flat_narrow < self.flat_width else "")
-                + ", "
-                f"contiguous slots kept in {lay['kv_order']}, "
-                f"{self.slot_attn_impl} attend, "
-                f"{self.kv_write_impl} kv write, "
-                f"{kv_lib.cache_bytes(cfg, self.num_slots, self.chunk) / 1e6:.1f} MB")
-      if self._recurrent:
-        layout += (f": {lay['kv_leaves']} K/V leaves "
-                   f"{lay['kv_bytes'] / 1e6:.1f} MB + {lay['state_leaves']} "
-                   f"recurrent-state leaves {lay['state_bytes'] / 1e6:.1f} "
-                   f"MB")
-        if self.ssm_scan_impl is not None:
-          layout += f", {self.ssm_scan_impl} ssm scan"
-      elif "latent_leaves" in lay:
-        layout += f": {lay['latent_leaves']} latent leaves"
-        if "index_leaves" in lay:
-          layout += (f", {lay['index_leaves']} index leaves "
-                     f"({self.dsa_index_impl} index scores), "
-                     f"{lay['window_leaves']} window rings "
-                     f"{lay['window_bytes'] / 1e6:.1f} MB")
-      if self._kv_window is not None:
-        layout += (f": {lay['window_leaves']} K/V window rings "
-                   f"{lay['window_bytes'] / 1e6:.1f} MB "
-                   f"({self.kv_win_write_impl} ring write, "
-                   f"{self.kv_win_attn_impl} windowed attend) beside "
-                   f"{lay['kv_leaves']} full K/V leaves")
-      if self._experts:
-        layout += f", {self.moe_gmm_impl} expert matmul"
+                + f", contiguous slots kept in {lay['kv_order']}, "
+                f"{total / 1e6:.1f} MB ("
+                + ", ".join(f"{lay[f'{kind}_leaves']} {kind} leaves "
+                            f"{lay[f'{kind}_bytes'] / 1e6:.1f} MB"
+                            for kind in held)
+                + "); "
+                + ", ".join(f"{name} {impl}" for name, impl in
+                            kv_lib.resolved(self.lowerings).items()))
     get_logger().info(
         "serving engine: %d slots x chunk %d (%s, %s), step overlap %s, "
         "prefill budget %s, max batch %d, speculation %s, resilience %s",
@@ -950,14 +891,8 @@ class ContinuousBatchingEngine:
         "num_active": sched.num_active,
         "num_slots": self.num_slots,
         "paged": self.paged,
-        "kv_write_impl": self.kv_write_impl,
-        "slot_attn_impl": self.slot_attn_impl,
+        **self.lowerings,
         "kv_order": (self.cache_layout or {}).get("kv_order"),
-        "ssm_scan_impl": self.ssm_scan_impl,
-        "moe_gmm_impl": self.moe_gmm_impl,
-        "dsa_index_impl": self.dsa_index_impl,
-        "kv_win_write_impl": self.kv_win_write_impl,
-        "kv_win_attn_impl": self.kv_win_attn_impl,
         "step_overlap": self.step_overlap,
         "wasted_positions": sched.wasted_positions,
         "recompiles": self._compile_sentinel.recompiles,
@@ -1053,17 +988,11 @@ class ContinuousBatchingEngine:
     return jax.jit(step, **jit_kwargs)
 
   def _build_step(self, donate: bool, guard: bool = False):
-    from easyparallellibrary_tpu.models.gpt import slot_step_logits
     model = self.model
     C = self.chunk
     width, narrow = self.flat_width, self.flat_narrow
-    write_impl = self.kv_write_impl
-    attn_impl = self.slot_attn_impl
-    scan_impl = self.ssm_scan_impl
+    lowerings = kv_lib.resolved(self.lowerings)
     recurrent = self._recurrent
-    gmm_impl = self.moe_gmm_impl
-    index_impl = self.dsa_index_impl
-    win_impls = (self.kv_win_write_impl, self.kv_win_attn_impl)
     experts = self._experts
 
     def step(params, kv, cursors, tokens, num_valid, reset, prev,
@@ -1080,24 +1009,15 @@ class ContinuousBatchingEngine:
       # routed experts and positions of its own at once
       # (models/lfm2_moe.py): each argument goes to the models that ask.
       state_args = dict(reset=reset) if recurrent else {}
-      if scan_impl is not None:
-        state_args["ssm_scan_impl"] = scan_impl
-      if experts:
-        state_args["moe_gmm_impl"] = gmm_impl
-      if index_impl is not None:
-        state_args["dsa_index_impl"] = index_impl
-      if win_impls[0] is not None:
-        state_args["kv_win_write_impl"] = win_impls[0]
-        state_args["kv_win_attn_impl"] = win_impls[1]
       # Each slot's next-token logits sit at its LAST live chunk
       # position, and the head runs on that row alone; idle slots
       # (num_valid=0) read position 0 — garbage the scheduler never
       # consumes.
       last, kv, *sown = slot_step_logits(
-          model, params, kv, tokens, cursors, kv_write_impl=write_impl,
-          slot_attn_impl=attn_impl, num_valid=num_valid, stats=experts,
-          width=width, narrow=narrow,
-          head_pos=jnp.clip(num_valid - 1, 0, C - 1), **state_args)
+          model, params, kv, tokens, cursors, num_valid=num_valid,
+          stats=experts, width=width, narrow=narrow,
+          head_pos=jnp.clip(num_valid - 1, 0, C - 1), **lowerings,
+          **state_args)
       step_keys = jax.vmap(jax.random.fold_in)(keys, tok_index)
       nxt = sample_token_slots(last.astype(jnp.float32), step_keys,
                                temperature, top_k, top_p)
@@ -1128,15 +1048,13 @@ class ContinuousBatchingEngine:
     in ``k_max = drafter.k``; per-slot draft length is data
     (``num_draft``), so joins/leaves/short proposals never recompile.
     """
-    from easyparallellibrary_tpu.models.gpt import slot_step_logits
     from easyparallellibrary_tpu.serving.speculative.verify import (
         verify_tokens)
     model = self.model
     C = self.chunk
     K = self.drafter.k
     width, narrow = self.flat_width, self.flat_narrow
-    write_impl = self.kv_write_impl
-    attn_impl = self.slot_attn_impl
+    lowerings = kv_lib.resolved(self.lowerings)
 
     def step(params, kv, cursors, tokens, num_valid, num_draft, reset,
              keys, tok_index, temperature, top_k, top_p):
@@ -1150,10 +1068,8 @@ class ContinuousBatchingEngine:
       pos = jnp.clip(base[:, None] - 1 + jnp.arange(K + 1)[None],
                      0, C - 1)
       tgt, kv = slot_step_logits(model, params, kv, tokens, cursors,
-                                 kv_write_impl=write_impl,
-                                 slot_attn_impl=attn_impl,
                                  num_valid=num_valid, width=width,
-                                 narrow=narrow, head_pos=pos)
+                                 narrow=narrow, head_pos=pos, **lowerings)
       tgt = tgt.astype(jnp.float32)
       dpos = jnp.clip(base[:, None] + jnp.arange(K)[None], 0, C - 1)
       drafts = jnp.take_along_axis(tokens, dpos, axis=1)
@@ -1188,7 +1104,6 @@ class ContinuousBatchingEngine:
     validity are data — joins, leaves and pool reshuffles never
     recompile.  No device cursors: positions are host-planned, so the
     only persistent device state is the donated pool pair."""
-    from easyparallellibrary_tpu.models.gpt import paged_step_logits
     model = self.model
     T = self.token_budget
     impl = self._paged_impl
@@ -1224,7 +1139,6 @@ class ContinuousBatchingEngine:
     bookkeeping, and rejected-draft K/V beyond it is masked garbage
     overwritten on the next feed, exactly like chunked-prefill
     garbage."""
-    from easyparallellibrary_tpu.models.gpt import paged_step_logits
     from easyparallellibrary_tpu.serving.speculative.verify import (
         verify_tokens)
     model = self.model

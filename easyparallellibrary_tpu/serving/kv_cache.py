@@ -13,7 +13,7 @@ block list behind an on-device block table, and a host-side
 :class:`BlockAllocator` (free list + refcounts) turns retired requests'
 worst-case tail reservations into extra concurrent requests.  A slot is the unit of admission: a request owns
 exactly one slot from admission to retirement, its write offset tracked
-by a per-slot cursor (the cursor *vector* models/gpt.py's
+by a per-slot cursor (the cursor *vector* models/slot_core.py's
 ``slot_cache_attend`` consumes).  Eviction is free-list bookkeeping on
 the host — no device work: stale K/V left by the previous occupant is
 never attendable because the mask only exposes positions the current
@@ -83,12 +83,9 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from easyparallellibrary_tpu import constants
-from easyparallellibrary_tpu.models.dots3_note import (
-    FULL, SLIDING, SPARSE_LATENT, WINDOW_LATENT)
-from easyparallellibrary_tpu.models.glm_moe import LATENT
-from easyparallellibrary_tpu.models.jamba import ATTENTION, MAMBA
-from easyparallellibrary_tpu.models.lfm2_moe import CONV
-from easyparallellibrary_tpu.models.smallthinker import WINDOW_KV
+from easyparallellibrary_tpu.models.layer_kinds import (
+    ATTENTION, CONV, FULL, LATENT, MAMBA, SLIDING, SPARSE_LATENT, WINDOW_KV,
+    WINDOW_LATENT)
 
 # Layer kinds whose state is a recurrence's: no position axis, nothing a
 # cursor can roll back.
@@ -497,6 +494,32 @@ def moe_gmm_impl(cfg, num_slots: int, chunk: int,
   return _one_impl(resolve_moe_gmm_impl((rows, k), (E, k, n), cfg.dtype,
                                         sharded=sharded)
                    for k, n in ((D, 2 * F), (F, D)))
+
+
+# The rules above in the order every record of a step's lowerings is kept
+# in.  A rule's name is at once the decoders' keyword, the trace metadata's
+# suffix (``serving/<name>``) and the diagnostic bundle's key.
+_RULES = (kv_write_impl, slot_attn_impl, kv_win_write_impl, kv_win_attn_impl,
+          dsa_index_impl, ssm_scan_impl, moe_gmm_impl)
+
+
+def step_lowerings(cfg, num_slots: int, chunk: int,
+                   mesh: Optional[Mesh] = None) -> Dict[str, Optional[str]]:
+  """What a fused step over the cache :func:`allocate_kv_cache` builds for
+  the same arguments is lowered to: each rule's answer under the rule's
+  name, in :data:`_RULES`' order; ``None`` where the model has no layer the
+  rule is about.  THE record whoever builds such a step resolves once (the
+  engine, a draft model's rollout) and hands ``slot_step_logits`` the
+  entries of that are not ``None``."""
+  return {rule.__name__: rule(cfg, num_slots, chunk, mesh)
+          for rule in _RULES}
+
+
+def resolved(lowerings: Dict[str, Optional[str]]) -> Dict[str, str]:
+  """The entries of a :func:`step_lowerings` record some rule resolved:
+  the keywords ``slot_step_logits`` is handed (a model takes those of its
+  own kinds of layer and no others) and the metadata a run records."""
+  return {name: impl for name, impl in lowerings.items() if impl is not None}
 
 
 def allocate_kv_cache(cfg, num_slots: int, chunk: int,

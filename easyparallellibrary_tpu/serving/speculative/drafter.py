@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from easyparallellibrary_tpu.models.slot_core import (
+    paged_step_logits, slot_step_logits)
 from easyparallellibrary_tpu.serving._capabilities import (
     check_draft_compatible)
 
@@ -242,21 +244,18 @@ class DraftModelDrafter(Drafter):
     else:
       self._kv, self._cursors = kv_lib.allocate_kv_cache(
           self.model.cfg, engine.num_slots, engine.chunk, mesh)
-      geometry = (self.model.cfg, engine.num_slots, engine.chunk, mesh)
       self._fn = self._build_draft_fn(
-          engine.chunk, engine.flat_width, kv_lib.kv_write_impl(*geometry),
-          kv_lib.slot_attn_impl(*geometry))
+          engine.chunk, engine.flat_width,
+          kv_lib.resolved(kv_lib.step_lowerings(
+              self.model.cfg, engine.num_slots, engine.chunk, mesh)))
 
-  def _build_draft_fn(self, chunk: int, width: int, kv_write_impl: str,
-                      slot_attn_impl: str):
-    from easyparallellibrary_tpu.models.gpt import slot_step_logits
+  def _build_draft_fn(self, chunk: int, width: int, lowerings):
     model, K, C = self.model, self.k, chunk
-    # One resolved lowering of the cache write and one of the attend for
-    # the chunk-wide and the one-token calls alike (what fits a chunk
-    # fits one token).
-    score = functools.partial(slot_step_logits,
-                              kv_write_impl=kv_write_impl,
-                              slot_attn_impl=slot_attn_impl)
+    # One resolved lowering of the cache write and one of the attend (a
+    # draft model keeps K/V pairs and nothing else: the record's other
+    # entries are None) for the chunk-wide and the one-token calls alike
+    # (what fits a chunk fits one token).
+    score = functools.partial(slot_step_logits, **lowerings)
 
     def draft(params, kv, cursors, tokens, num_valid, reset):
       cursors = jnp.where(reset, 0, cursors)
@@ -295,7 +294,6 @@ class DraftModelDrafter(Drafter):
     ``paged_step_logits``, so overshoot (a slot near its budget) costs
     acceptance, never correctness.  No cursors anywhere: rollback is
     implicit in next step's host-planned positions."""
-    from easyparallellibrary_tpu.models.gpt import paged_step_logits
     model, K = self.model, self.k
     N = engine.num_slots
     T = engine.token_budget

@@ -40,9 +40,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import easyparallellibrary_tpu as epl  # noqa: E402
 from easyparallellibrary_tpu.models import moe as moe_lib  # noqa: E402
-from easyparallellibrary_tpu.models.dots3_note import (  # noqa: E402
-    SPARSE_LATENT, WINDOW_LATENT, ring_length)
-from easyparallellibrary_tpu.models.gpt import slot_step_logits  # noqa: E402
+from easyparallellibrary_tpu.models.blocks import ring_length  # noqa: E402
+from easyparallellibrary_tpu.models.layer_kinds import (  # noqa: E402
+    SPARSE_LATENT, WINDOW_LATENT)
+from easyparallellibrary_tpu.models.slot_core import slot_step_logits  # noqa: E402
 from easyparallellibrary_tpu.observability import trace as trace_lib  # noqa: E402
 from easyparallellibrary_tpu.profiler.serving import ServingStats  # noqa: E402
 from easyparallellibrary_tpu.serving import (  # noqa: E402
@@ -640,8 +641,9 @@ def _teacher_forced(ref_cfg, rp, out):
 def test_engine_on_mixed_prompts_equals_per_request_reference_decoding(both):
   model, params, rp = both
   eng, out = _serve(model, params)
-  assert (eng.kv_write_impl, eng.slot_attn_impl, eng.dsa_index_impl,
-          eng.moe_gmm_impl) == ("reference",) * 4
+  assert kv_lib.resolved(eng.lowerings) == dict.fromkeys(
+      ("kv_write_impl", "slot_attn_impl", "dsa_index_impl", "moe_gmm_impl"),
+      "reference")
   _teacher_forced(REF_CFG, rp, out)
 
 
@@ -653,8 +655,9 @@ def test_engine_commits_the_same_under_the_interpreted_kernels(monkeypatch,
   _backend_takes(monkeypatch, "interpret")
   eng, out = _serve(model, params, chunk=16)
   # (The toy experts are narrower than the grouped matmul's tiles.)
-  assert (eng.kv_write_impl, eng.slot_attn_impl,
-          eng.dsa_index_impl) == ("interpret",) * 3
+  assert kv_lib.resolved(eng.lowerings) == dict(dict.fromkeys(
+      ("kv_write_impl", "slot_attn_impl", "dsa_index_impl"), "interpret"),
+      moe_gmm_impl="reference")
   for uid, toks in plain.items():
     np.testing.assert_array_equal(np.asarray(out[uid]), np.asarray(toks))
   _teacher_forced(WIDE_CFG, rp, out)

@@ -1,4 +1,4 @@
-"""The token-flat fused step (models/gpt.py ``SlotRows``,
+"""The token-flat fused step (models/slot_core.py ``SlotRows``,
 serving/engine.py ``flat_width``): the position-wise layers run on ``T``
 rows, the live positions of the step's slots one after another, and the
 head on the one row a slot samples from.
@@ -38,7 +38,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import easyparallellibrary_tpu as epl  # noqa: E402
 from easyparallellibrary_tpu.models import GPT, GPTConfig  # noqa: E402
-from easyparallellibrary_tpu.models.gpt import slot_step_logits  # noqa: E402
+from easyparallellibrary_tpu.models.slot_core import slot_step_logits  # noqa: E402
 from easyparallellibrary_tpu.observability import trace as trace_lib  # noqa: E402
 from easyparallellibrary_tpu.profiler.serving import ServingStats  # noqa: E402
 from easyparallellibrary_tpu.serving import (  # noqa: E402
@@ -218,11 +218,7 @@ class _Shadow(chaos._StepFnWrapper):
   def __init__(self, eng):
     super().__init__(eng)
     model, C = eng.model, eng.chunk
-    extra = {}
-    if eng.ssm_scan_impl is not None:
-      extra["ssm_scan_impl"] = eng.ssm_scan_impl
-    if eng._experts:
-      extra["moe_gmm_impl"] = eng.moe_gmm_impl
+    lowerings = kv_lib.resolved(eng.lowerings)
 
     @jax.jit
     def full(params, kv, cursors, tokens, num_valid, reset, prev, from_prev):
@@ -230,10 +226,8 @@ class _Shadow(chaos._StepFnWrapper):
       cursors = jnp.where(reset, 0, cursors)
       state = dict(reset=reset) if eng._recurrent else {}
       logits, kv = slot_step_logits(
-          model, params, kv, tokens, cursors,
-          kv_write_impl=eng.kv_write_impl,
-          slot_attn_impl=eng.slot_attn_impl, num_valid=num_valid, **state,
-          **extra)
+          model, params, kv, tokens, cursors, num_valid=num_valid, **state,
+          **lowerings)
       last = jnp.take_along_axis(
           logits, jnp.clip(num_valid - 1, 0, C - 1)[:, None, None],
           axis=1)[:, 0]
@@ -420,7 +414,7 @@ def test_a_step_with_exactly_the_width_live_and_one_with_nothing(decoders,
 
 
 # Layers whose mixer owns a leaf that grows with the context and stands
-# outside the conditionals (models/gpt.py:SplitLayer): the hybrid's one
+# outside the conditionals (models/slot_core.py:SplitLayer): the hybrid's one
 # attention layer of four, every latent layer, LFM2's attention layer
 # between two convolutions.  A GPT-2 block's K/V pair stands inside.
 SPLIT = {"gpt2": 0, "hybrid": 1, "glm-experts": 3, "lfm2": 1, "dots3": 5,
@@ -542,7 +536,7 @@ def test_the_width_follows_slots_and_chunk_alone():
                        (4, 8), (8, 16)):
     assert narrow(slots, chunk) == width(slots, chunk)
   assert narrow(200, 4) == 256 < width(200, 4)
-  from easyparallellibrary_tpu.models.gpt import slot_rows
+  from easyparallellibrary_tpu.models.slot_core import slot_rows
   some = jnp.ones((130,), jnp.int32)
   assert slot_rows(some, some, 130, 2, width=256, narrow=256).narrow is None
   assert slot_rows(some, some, 130, 2, width=256, narrow=128).narrow == 128

@@ -34,9 +34,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import easyparallellibrary_tpu as epl  # noqa: E402
 from easyparallellibrary_tpu.models import GPTConfig  # noqa: E402
 from easyparallellibrary_tpu.models import moe as moe_lib  # noqa: E402
-from easyparallellibrary_tpu.models.glm_moe import (  # noqa: E402
-    LATENT, GlmMoeConfig, rotary)
-from easyparallellibrary_tpu.models.gpt import slot_step_logits  # noqa: E402
+from easyparallellibrary_tpu.models.blocks import rotary  # noqa: E402
+from easyparallellibrary_tpu.models.glm_moe import GlmMoeConfig  # noqa: E402
+from easyparallellibrary_tpu.models.layer_kinds import LATENT  # noqa: E402
+from easyparallellibrary_tpu.models.slot_core import slot_step_logits  # noqa: E402
 from easyparallellibrary_tpu.observability import trace as trace_lib  # noqa: E402
 from easyparallellibrary_tpu.profiler.serving import ServingStats  # noqa: E402
 from easyparallellibrary_tpu.serving import (  # noqa: E402
@@ -363,8 +364,8 @@ def test_engine_on_mixed_prompts_equals_per_request_reference_decoding(both):
   the reference's own next token for that request alone."""
   model, params, rp = both
   eng, out = _serve(model, params)
-  assert (eng.kv_write_impl, eng.slot_attn_impl, eng.moe_gmm_impl) == (
-      "reference",) * 3
+  assert kv_lib.resolved(eng.lowerings) == dict.fromkeys(
+      ("kv_write_impl", "slot_attn_impl", "moe_gmm_impl"), "reference")
   for req in _requests():
     stream = np.asarray(out[req.uid])
     n = len(req.prompt)
@@ -384,8 +385,9 @@ def test_engine_commits_the_same_under_the_interpreted_kernels(monkeypatch,
   # The write and the attend take the toy leaf; the grouped matmul's rule
   # declines a contraction of 64 (not whole lane tiles) and keeps
   # ``ragged_dot``: the kernel inside a step is the test above's.
-  assert (eng.kv_write_impl, eng.slot_attn_impl, eng.moe_gmm_impl) == (
-      "interpret", "interpret", "reference")
+  assert kv_lib.resolved(eng.lowerings) == {
+      "kv_write_impl": "interpret", "slot_attn_impl": "interpret",
+      "moe_gmm_impl": "reference"}
   for uid in want:
     np.testing.assert_array_equal(got[uid], want[uid])
 

@@ -28,10 +28,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import easyparallellibrary_tpu as epl  # noqa: E402
 from easyparallellibrary_tpu.models import GPT, GPTConfig  # noqa: E402
-from easyparallellibrary_tpu.models.gpt import (  # noqa: E402
+from easyparallellibrary_tpu.models.blocks import (  # noqa: E402
+    advance_window, gqa_causal_attention)
+from easyparallellibrary_tpu.models.layer_kinds import ATTENTION, MAMBA  # noqa: E402
+from easyparallellibrary_tpu.models.slot_core import (  # noqa: E402
     slot_cache_attend, slot_step_logits)
-from easyparallellibrary_tpu.models.jamba import (  # noqa: E402
-    ATTENTION, MAMBA, Jamba, advance_window, gqa_causal_attention)
 from easyparallellibrary_tpu.observability import trace as trace_lib  # noqa: E402
 from easyparallellibrary_tpu.serving import (  # noqa: E402
     ContinuousBatchingEngine, Request, kv_cache as kv_lib)
@@ -359,7 +360,9 @@ def test_engine_streams_equal_greedy_decoding_by_the_reference(
   with jax.default_matmul_precision("highest"):
     eng = ContinuousBatchingEngine(model, params, num_slots=3,
                                    prefill_chunk=4)
-    assert eng.ssm_scan_impl == impl and eng.kv_write_impl == "reference"
+    assert kv_lib.resolved(eng.lowerings) == {
+        "kv_write_impl": "reference", "slot_attn_impl": "reference",
+        "ssm_scan_impl": impl}
     for uid, prompt, n in reqs:
       assert eng.submit(Request(uid=uid, prompt=prompt, max_new_tokens=n))
     out = eng.run()
@@ -482,7 +485,7 @@ def test_a_gpt_engine_is_what_it_was():
     names = {ev["name"] for ev in tracer.events()}
   finally:
     trace_lib.install(None)
-  assert eng.ssm_scan_impl is None
+  assert eng.lowerings["ssm_scan_impl"] is None
   assert eng.cache_layout == {
       "kv_bytes": 4 * 2 * 52 * 32 * 4, "kv_leaves": 4, "state_bytes": 0,
       "state_leaves": 0, "kv_order": "positions"}

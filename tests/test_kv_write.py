@@ -26,7 +26,8 @@ from jax.sharding import SingleDeviceSharding
 
 import easyparallellibrary_tpu as epl
 from easyparallellibrary_tpu.models import GPT, GPTConfig
-from easyparallellibrary_tpu.models.gpt import generate, slot_cache_attend
+from easyparallellibrary_tpu.models.gpt import generate
+from easyparallellibrary_tpu.models.slot_core import slot_cache_attend
 from easyparallellibrary_tpu.observability import trace as trace_lib
 from easyparallellibrary_tpu.serving import (
     ContinuousBatchingEngine, Request, kv_cache as kv_lib)
@@ -233,8 +234,8 @@ def test_engine_commits_the_same_under_either_lowering(monkeypatch, order,
                                             order)
   assert eng_k.cache_layout["kv_order"] == order
   # Which write each run timed is on record, not inferred.
-  assert eng_k.kv_write_impl == "interpret"
-  assert eng_r.kv_write_impl == "reference"
+  assert eng_k.lowerings["kv_write_impl"] == "interpret"
+  assert eng_r.lowerings["kv_write_impl"] == "reference"
   assert [f["args"] for f in facts_k] == [{"impl": "interpret"}]
   assert [f["args"] for f in facts_r] == [{"impl": "reference"}]
   assert sorted(out_k) == sorted(out_r) == list(range(len(prompts)))
@@ -770,8 +771,8 @@ def _abstract_step(model, slots, C, one_chip, **engine):
   """The plain fused step as the engine builds it for ``model`` at
   ``slots x C``, with every kernel's Pallas lowering, and abstract
   arguments for it that sit on the described chip.  ``engine``: what else
-  ``_build_step`` reads of an engine (recurrent state, experts, the
-  scan's lowering)."""
+  ``_build_step`` reads of an engine (recurrent state, experts) and, as
+  ``<name>_impl``, the other entries of its record of lowerings."""
   import types
   from flax import linen as nn
   from easyparallellibrary_tpu.serving.engine import (
@@ -783,14 +784,15 @@ def _abstract_step(model, slots, C, one_chip, **engine):
                          jnp.zeros((1, 8), jnp.int32))["params"])))
   kv = jax.tree_util.tree_map(
       on_chip, kv_lib.cache_leaves(model.cfg, slots, C))
+  lowerings = dict(
+      kv_write_impl="pallas", slot_attn_impl="pallas",
+      **{name: engine.pop(name) for name in list(engine)
+         if name.endswith("_impl")})
   engine = types.SimpleNamespace(**{**dict(
       model=model, num_slots=slots, chunk=C,
       flat_width=flat_width(slots, C),
       flat_narrow=narrow_width(flat_width(slots, C), slots),
-      kv_write_impl="pallas",
-      slot_attn_impl="pallas", ssm_scan_impl=None, _recurrent=False,
-      moe_gmm_impl=None, _experts=False, dsa_index_impl=None,
-      kv_win_write_impl=None, kv_win_attn_impl=None,
+      lowerings=lowerings, _recurrent=False, _experts=False,
       _jit_step=lambda step, donate, **kw: jax.jit(
           step, donate_argnums=(1, 2))), **engine})
   step = ContinuousBatchingEngine._build_step(engine, True)
@@ -816,7 +818,7 @@ def test_expert_step_compiled_for_v5e_holds_its_three_kernels(one_chip, C):
   of 8 the step has ONE width (384 rows, whose half rounds up to 256: more
   than half, serving/engine.py ``narrow_width``); at a chunk of 16 it has
   two (768 / 384), the write and the attend outside the conditionals and
-  in the program ONCE (models/gpt.py ``SplitLayer``), the expert layer's
+  in the program ONCE (models/slot_core.py ``SplitLayer``), the expert layer's
   ``moe_gmm`` on either side of one.  (Not at 128 slots x 8: there the
   ONE-width program copies the leaf four times too.)"""
   from easyparallellibrary_tpu.models.glm_moe import GlmMoe, GlmMoeConfig
@@ -885,12 +887,12 @@ def test_dots3_step_compiled_for_v5e_holds_its_kernels(one_chip):
   rows, the ring), two each of ``dsa_index``, ``slot_attn_sel`` and
   ``slot_attn_win`` (the slots that feed several positions, then the
   decoding ones), outside the conditionals and in the program ONCE
-  (models/gpt.py ``SplitLayer``), as the one ``while``, the threshold's 32
+  (models/slot_core.py ``SplitLayer``), as the one ``while``, the threshold's 32
   counting passes; two ``moe_gmm`` on either side of a conditional; no copy
   of a cache leaf in either order of its dimensions; no ``[slots, chunk,
   heads, Lc]`` score tensor."""
-  from easyparallellibrary_tpu.models.dots3_note import (
-      FULL, SLIDING, Dots3Note, Dots3NoteConfig)
+  from easyparallellibrary_tpu.models.dots3_note import Dots3Note, Dots3NoteConfig
+  from easyparallellibrary_tpu.models.layer_kinds import FULL, SLIDING
   epl.init()
   slots, C = 32, 32
   cfg = Dots3NoteConfig(vocab_size=19008, layer_types=(FULL, SLIDING),
@@ -924,7 +926,7 @@ def test_smallthinker_step_compiled_for_v5e_holds_its_kernels(one_chip):
   it, at two widths: two ``kv_write`` (the full pair, the ring), one
   ``slot_attn`` (28 on 4 heads of 128 in rows) and two ``slot_attn_kvwin``
   (the decoding slots, then the prefilling ones), outside the conditionals
-  and in the program ONCE (models/gpt.py ``SplitLayer``); two ``moe_gmm`` a
+  and in the program ONCE (models/slot_core.py ``SplitLayer``); two ``moe_gmm`` a
   layer on either side of a conditional; no copy of a cache leaf, the rings
   ``[48, 4224, 512]`` among them; no ``[slots, chunk, heads, rows]`` score
   tensor."""
@@ -954,7 +956,7 @@ def _flat_cuts():
   for the expert decoder's chunk, geometry: ``name -> (model, slots, C, engine attributes, kernel calls a
   two-width step (kv_write, slot_attn, ssm_scan, moe_gmm): once what a
   split layer's mixer calls, twice what stands in a conditional,
-  models/gpt.py ``slot_layers``), vocabulary)``."""
+  models/slot_core.py ``slot_layers``), vocabulary)``."""
   from easyparallellibrary_tpu.models.glm_moe import GlmMoe, GlmMoeConfig
   from easyparallellibrary_tpu.models.jamba import Jamba, JambaConfig
   from easyparallellibrary_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
@@ -1037,7 +1039,7 @@ def test_a_serving_cells_step_compiled_for_v5e_copies_no_leaf(one_chip, cell,
   DEPTH and geometry, as the engine builds it, compiled for a described
   v5e: no cache leaf is copied, on either side of a conditional or
   outside one.  A split layer's leaves are no conditional's to change
-  (models/gpt.py ``SplitLayer``); GPT-2's K/V pairs do stand inside one,
+  (models/slot_core.py ``SplitLayer``); GPT-2's K/V pairs do stand inside one,
   all 24 layers of them, where the compiler writes them in place: this is
   the guard of that (with every mixer inside one conditional it copied
   leaves of 100 to 540 MB in the four other configurations: PERF.md,

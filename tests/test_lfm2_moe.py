@@ -44,10 +44,9 @@ from easyparallellibrary_tpu.kernels.slot_attention import (  # noqa: E402
 from easyparallellibrary_tpu.models import GPTConfig  # noqa: E402
 from easyparallellibrary_tpu.models import moe as moe_lib  # noqa: E402
 from easyparallellibrary_tpu.models.glm_moe import GlmMoeConfig  # noqa: E402
-from easyparallellibrary_tpu.models.gpt import slot_step_logits  # noqa: E402
-from easyparallellibrary_tpu.models.jamba import ATTENTION  # noqa: E402
-from easyparallellibrary_tpu.models.lfm2_moe import (  # noqa: E402
-    CONV, Lfm2MoeConfig)
+from easyparallellibrary_tpu.models.layer_kinds import ATTENTION, CONV  # noqa: E402
+from easyparallellibrary_tpu.models.lfm2_moe import Lfm2MoeConfig  # noqa: E402
+from easyparallellibrary_tpu.models.slot_core import slot_step_logits  # noqa: E402
 from easyparallellibrary_tpu.observability import trace as trace_lib  # noqa: E402
 from easyparallellibrary_tpu.profiler.serving import ServingStats  # noqa: E402
 from easyparallellibrary_tpu.serving import (  # noqa: E402
@@ -410,7 +409,7 @@ def test_no_shared_expert_means_no_shared_in_the_tree_and_glm_is_unchanged():
   assert set(p_glm["shared"]) == {"gate", "up", "down"}
   routed_params = {k: p_glm[k] for k in routed_keys}
   routed = bare.apply({"params": routed_params}, x)
-  from easyparallellibrary_tpu.models.jamba import GatedMLP
+  from easyparallellibrary_tpu.models.blocks import GatedMLP
   shared = GatedMLP(_MoeCfg(1), d_ff=32).apply(
       {"params": p_glm["shared"]}, x)
   np.testing.assert_array_equal(
@@ -478,8 +477,9 @@ def test_engine_on_mixed_prompts_equals_per_request_reference_decoding(both):
   alone."""
   model, params, rp = both
   eng, out = _serve(model, params)
-  assert (eng.kv_write_impl, eng.slot_attn_impl, eng.moe_gmm_impl,
-          eng.ssm_scan_impl) == ("reference",) * 3 + (None,)
+  assert kv_lib.resolved(eng.lowerings) == dict.fromkeys(
+      ("kv_write_impl", "slot_attn_impl", "moe_gmm_impl"), "reference")
+  assert eng.lowerings["ssm_scan_impl"] is None
   reqs = _requests()
   padded = np.zeros((len(reqs), S), np.int32)
   for req in reqs:

@@ -20,7 +20,8 @@ import pytest
 
 import easyparallellibrary_tpu as epl
 from easyparallellibrary_tpu.models import GPT, GPTConfig
-from easyparallellibrary_tpu.models.gpt import generate, slot_step_logits
+from easyparallellibrary_tpu.models.gpt import generate
+from easyparallellibrary_tpu.models.slot_core import slot_step_logits
 from easyparallellibrary_tpu.profiler import ServingStats, percentile
 from easyparallellibrary_tpu.serving import (
     ContinuousBatchingEngine, DraftModelDrafter, NgramDrafter, Request,

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from easyparallellibrary_tpu.models.gpt import slot_cache_attend
+from easyparallellibrary_tpu.models.slot_core import slot_cache_attend
 
 sa = importlib.import_module(
     "easyparallellibrary_tpu.kernels.slot_attention")

@@ -102,8 +102,9 @@ def test_engine_commits_the_same_greedy_tokens_under_either_attend(
   eng_r, out_r, _ = _serve(monkeypatch, "reference", DRAFTERS[drafter],
                            order)
   # Which attend each run timed is on record, not inferred.
-  assert eng_k.slot_attn_impl == eng_k.kv_write_impl == "interpret"
-  assert eng_r.slot_attn_impl == eng_r.kv_write_impl == "reference"
+  for eng, impl in ((eng_k, "interpret"), (eng_r, "reference")):
+    assert kv_lib.resolved(eng.lowerings) == {
+        "kv_write_impl": impl, "slot_attn_impl": impl}
   facts = [e["args"] for e in events
            if e["ph"] == "M" and e["name"] == "serving/slot_attn_impl"]
   assert facts == [{"impl": "interpret"}]

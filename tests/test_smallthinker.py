@@ -42,8 +42,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import easyparallellibrary_tpu as epl  # noqa: E402
 from easyparallellibrary_tpu.models import moe as moe_lib  # noqa: E402
 from easyparallellibrary_tpu.models import smallthinker as st_lib  # noqa: E402
-from easyparallellibrary_tpu.models.gpt import slot_step_logits  # noqa: E402
-from easyparallellibrary_tpu.models.jamba import ATTENTION  # noqa: E402
+from easyparallellibrary_tpu.models.layer_kinds import ATTENTION, WINDOW_KV  # noqa: E402
+from easyparallellibrary_tpu.models.slot_core import slot_step_logits  # noqa: E402
 from easyparallellibrary_tpu.observability import trace as trace_lib  # noqa: E402
 from easyparallellibrary_tpu.profiler.serving import ServingStats  # noqa: E402
 from easyparallellibrary_tpu.serving import (  # noqa: E402
@@ -59,7 +59,6 @@ kvw, sa, gmm = (
     importlib.import_module(f"easyparallellibrary_tpu.kernels.{m}")
     for m in ("kv_write", "slot_attention", "moe_gmm"))
 KERNELS = [kvw, sa, gmm]
-WINDOW_KV = st_lib.WINDOW_KV
 
 LAYOUT = (0, 1, 1, 1) * 2
 REF_CFG = ref.SmallThinkerConfig(
@@ -565,8 +564,9 @@ def test_engine_on_mixed_prompts_equals_per_request_reference_decoding(both):
   (contexts of up to 46 behind rings of 12 rows)."""
   model, params, rp = both
   eng, out = _serve(model, params)
-  assert (eng.kv_write_impl, eng.slot_attn_impl, eng.kv_win_write_impl,
-          eng.kv_win_attn_impl, eng.moe_gmm_impl) == ("reference",) * 5
+  assert kv_lib.resolved(eng.lowerings) == dict.fromkeys(
+      ("kv_write_impl", "slot_attn_impl", "kv_win_write_impl",
+       "kv_win_attn_impl", "moe_gmm_impl"), "reference")
   assert eng.step_overlap == "on"
   _teacher_forced(REF_CFG, rp, out)
 
@@ -579,8 +579,9 @@ def test_engine_commits_the_same_under_the_interpreted_kernels(monkeypatch,
   _backend_takes(monkeypatch, "interpret")
   eng, out = _serve(model, params, chunk=16)
   # (The toy experts are narrower than the grouped matmul's tiles.)
-  assert (eng.kv_write_impl, eng.slot_attn_impl, eng.kv_win_write_impl,
-          eng.kv_win_attn_impl) == ("interpret",) * 4
+  assert kv_lib.resolved(eng.lowerings) == dict(dict.fromkeys(
+      ("kv_write_impl", "slot_attn_impl", "kv_win_write_impl",
+       "kv_win_attn_impl"), "interpret"), moe_gmm_impl="reference")
   assert eng.cache_layout["kv_order"] == "rows"
   for uid, toks in plain.items():
     np.testing.assert_array_equal(np.asarray(out[uid]), np.asarray(toks))
