@@ -1702,6 +1702,163 @@ def phase_smallthinker(sizes: Sizes) -> None:
       f"lowerings {err:.2e}")
 
 
+# ------------------------------------------------------------------ glm5 --
+
+
+def glm5_cell_cfg(**overrides) -> GlmMoeConfig:
+  """GLM-5's cut as its cell serves it (perfbench/configs/glm-5.json): every
+  layer a selecting latent attention, 64 of 256 experts held on the host."""
+  return GlmMoeConfig(**{**dict(
+      vocab_size=19360, num_layers=6, d_model=6144, d_ff=12288,
+      moe_d_ff=2048, num_heads=64, q_lora_rank=2048, kv_lora_rank=512,
+      qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+      index_topk=2048, index_n_heads=32, index_head_dim=128,
+      n_routed_experts=256, experts_held=(0, 64), num_experts_per_tok=8,
+      first_k_dense=1, routed_scaling_factor=2.5, max_seq_len=10752),
+                          **overrides})
+
+
+def check_exchange(cfg, chips: int, positions: int, uneven: bool,
+                   rehearsal: bool) -> None:
+  """One expert layer's routed sum with the held experts DIVIDED over
+  ``chips`` chips (``models/moe.py:exchanged_experts`` under a
+  ``shard_map``, the grouped matmul in the lowering the backend takes)
+  against the one-chip layer that holds them all (``dropless_experts``
+  with the same lowering of the grouped matmul, which ``check_moe_gmm``
+  holds to ``ragged_dot`` apart: XLA's own lowering of it multiplies every
+  row by every expert, 5e16 operations at these shapes), position for
+  position.  ``uneven``: most
+  assignments on the first chip's experts and none on the last's, so that
+  the exchange takes several rounds."""
+  from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+  from easyparallellibrary_tpu.models import moe as moe_lib
+  from easyparallellibrary_tpu.utils.compat import shard_map
+  first, held = cfg.experts_held
+  D, F, k = cfg.d_model, cfg.moe_d_ff, cfg.num_experts_per_tok
+  per = held // chips
+  N = chips * positions
+  r = np.random.RandomState(47)
+  mesh = Mesh(np.array(jax.devices()[:chips]), ("expert",))
+  split, whole = (NamedSharding(mesh, P("expert")),
+                  NamedSharding(mesh, P()))
+  draw = lambda shape, scale: jax.jit(
+      lambda key: (scale * jax.random.normal(key, shape, jnp.float32)
+                   ).astype(cfg.dtype), out_shardings=split)
+  w_gate_up = draw((held, D, 2 * F), D ** -0.5)(jax.random.PRNGKey(1))
+  w_down = draw((held, F, D), F ** -0.5)(jax.random.PRNGKey(2))
+  x = jax.device_put(jnp.asarray(r.randn(N, D), cfg.dtype), split)
+  if uneven:
+    # 3 of 4 choices among the first chip's experts, the rest among the
+    # second's and third's and the absent ones; none on the last chip's.
+    pool = np.concatenate([np.repeat(first + np.arange(per), 12),
+                           first + per + np.arange(2 * per),
+                           (first + held + np.arange(per)) %
+                           cfg.n_routed_experts])
+    chosen = np.stack([r.choice(np.unique(pool), k, replace=False,
+                                p=np.bincount(pool, minlength=
+                                              cfg.n_routed_experts)[
+                                                  np.unique(pool)]
+                                / len(pool)) for _ in range(N)])
+  else:
+    chosen = np.stack([r.choice(cfg.n_routed_experts, k, replace=False)
+                       for _ in range(N)])
+  chosen = jax.device_put(jnp.asarray(chosen, jnp.int32), split)
+  weights = jax.device_put(jnp.asarray(r.rand(N, k), jnp.float32), split)
+  live = jax.device_put(jnp.asarray(r.rand(N) < 0.9), split)
+  # The cell's rows a pair and round; a toy's would never be exceeded.
+  rows = 8 if rehearsal else moe_lib.exchange_rows(
+      positions, k, per, cfg.n_routed_experts)
+  impl = "interpret" if rehearsal else "pallas"
+  if rehearsal and D % 128:
+    impl = "reference"
+
+  def local(x, chosen, weights, live, a, b):
+    y, sizes, sent, left, rounds = moe_lib.exchanged_experts(
+        x, chosen, weights, live, a, b, axis="expert", rows=rows, impl=impl,
+        first=first)
+    return y, sizes, sent[None], left[None], rounds[None]
+  mapped = jax.jit(shard_map(
+      local, mesh, in_specs=(P("expert"),) * 6,
+      out_specs=(P("expert"),) * 5))
+  y, sizes, sent, left, rounds = mapped(x, chosen, weights, live, w_gate_up,
+                                        w_down)
+  one = jax.devices()[0]
+  on_one = lambda a: jax.device_put(a, one)
+  want, want_sizes = jax.jit(functools.partial(
+      moe_lib.dropless_experts, impl=impl, first=first))(
+          *map(on_one, (x, chosen, weights, live, w_gate_up, w_down)))
+  check(np.array_equal(np.asarray(sizes), np.asarray(want_sizes)),
+        "the exchange's experts were sent other rows than the one-chip "
+        f"layer's: {np.asarray(sizes)} against {np.asarray(want_sizes)}")
+  check(int(np.asarray(sent).sum()) == int(np.asarray(want_sizes).sum()),
+        "an assignment to a held expert was dropped")
+  err = rel_err(np.asarray(y, np.float32), np.asarray(want, np.float32))
+  tol = 2e-2 if cfg.dtype == jnp.bfloat16 else 1e-4
+  check(err <= tol, f"exchanged layer: error {err:.3g} of the one-chip "
+        f"layer's max, tol {tol}")
+  took = int(np.asarray(rounds).max())
+  check(took > 1 if uneven else took == 1 or rehearsal,
+        f"the exchange took {took} round(s), uneven={uneven}")
+  by_chip = np.asarray(want_sizes).reshape(chips, per).sum(1)
+  say(f"  exchange {chips} chips x {positions} positions, {per} experts a "
+      f"chip, {rows} rows a pair and round, {'uneven' if uneven else 'even'}"
+      f" routing: rows received a chip {by_chip.tolist()}, "
+      f"{int(np.asarray(left).sum())} of {int(np.asarray(sent).sum())} "
+      f"held assignments left their chip, {took} round(s), {err:.2e} of "
+      "the one-chip layer's max, none dropped")
+
+
+def phase_glm5(sizes: Sizes) -> None:
+  """The GLM-5 cell's kernels and its exchange before the long runs: the
+  four rules on ONE chip's share of the cell's leaves, the index scores,
+  the selected attend and the grouped matmul at those shapes, and (four
+  chips) one exchanged expert layer at the cell's widths against the
+  one-chip layer that holds the same 64 experts, under even and under
+  uneven routing."""
+  count = len(jax.devices())
+  if sizes.rehearsal:
+    cfg = glm5_cell_cfg(
+        vocab_size=512, num_layers=3, d_model=128, d_ff=256, moe_d_ff=128,
+        num_heads=4, q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, index_topk=16, index_n_heads=2,
+        index_head_dim=128, n_routed_experts=16, experts_held=(4, 8),
+        num_experts_per_tok=2, max_seq_len=232, dtype=jnp.float32,
+        param_dtype=jnp.float32)
+    slots, C, check_slots, positions = 4, 8, 4, 16
+  else:
+    cfg = glm5_cell_cfg()
+    slots, C, check_slots, positions = 32, 32, 8, 512
+  resolved = report_rules(cfg, slots, C)
+  if not sizes.rehearsal:
+    check(all(i == "pallas" for i in resolved.values()),
+          f"a rule declined at the cell's shapes a chip: {resolved}")
+  dims = cfg.latent_dims()
+  Lc = kv_lib.cache_length(cfg, C)
+  dtype = cfg.dtype
+  picked = check_dsa_index(check_slots, Lc, C, dims.indexer.num_heads,
+                           dims.indexer.head_dim, cfg.index_topk, dtype,
+                           sizes.rehearsal)
+  check_selected_attend(check_slots, Lc, C, dims.num_heads, dims.latent_dim,
+                        dims.kv_lora_rank, picked, dtype, sizes.rehearsal)
+  if not sizes.rehearsal:
+    per = cfg.experts_held[1] // 4
+    rows = 4 * 384
+    for K, N in ((cfg.d_model, 2 * cfg.moe_d_ff), (cfg.moe_d_ff,
+                                                   cfg.d_model)):
+      check_moe_gmm(rows, K, N, per, dtype, sizes.rehearsal)
+  if count < 4:
+    say(f"  exchange: skipped, the machine has {count} device(s)")
+  else:
+    for uneven in (False, True):
+      check_exchange(cfg, 4, positions, uneven, sizes.rehearsal)
+  say("PASS glm5: the rules on a chip's share of the cell's leaves, "
+      "dsa_index, kth_largest, slot_attn_sel and moe_gmm "
+      + ("INTERPRETED" if sizes.rehearsal else "compiled")
+      + " at them" + ("" if count < 4 else
+                      "; one exchanged expert layer over four chips equal to "
+                      "the one-chip layer under even and uneven routing"))
+
+
 # ---------------------------------------------------------------- overlap --
 
 
@@ -1796,7 +1953,8 @@ def main(argv=None) -> int:
   parser.add_argument(
       "--only", default=None,
       help="run this one phase (kernels, train, serve, hybrid, experts, "
-           "lfm2, dots3, smallthinker, overlap); prints no result line")
+           "lfm2, dots3, smallthinker, glm5, overlap); prints no result "
+           "line")
   args = parser.parse_args(argv)
   t_start = time.perf_counter()
   cache_dir = compile_cache.configure()
@@ -1824,6 +1982,7 @@ def main(argv=None) -> int:
                       ("lfm2", lambda: phase_lfm2(sizes)),
                       ("dots3", lambda: phase_dots3(sizes)),
                       ("smallthinker", lambda: phase_smallthinker(sizes)),
+                      ("glm5", lambda: phase_glm5(sizes)),
                       ("overlap", lambda: phase_overlap(sizes))):
     if args.only not in (None, name):
       continue

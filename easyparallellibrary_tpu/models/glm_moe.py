@@ -35,6 +35,16 @@ which models/dots3_note.py builds at other sizes):
   in their one-leaf form).  Equal to the expanded form up to rounding:
   matrix products re-associated.
 
+``model_type: glm_moe_dsa`` (GLM-5) is the same block with DeepSeek-V3.2's
+indexer in EVERY layer (``index_topk`` > 0: ``LatentDims(indexer=...)``,
+layer kind ``sparse_latent``, an index leaf beside the latent one; a query
+attends the ``index_topk`` rows of largest index score, all of them while
+its position is below that) and, where the config says so
+(``experts_held``), a share of the routed experts.  ``expert_axis`` names
+the mesh axis a serving engine divided that share over: the expert layers
+then exchange rows between the chips (models/moe.py ``exchanged_experts``).
+``perfbench/reference/glm_moe_dsa.py`` holds those equations.
+
 A latent row, like a K/V row, is addressed under a cursor, so a partly
 valid chunk is harmless; but nothing here can restore a cache the paged
 layout, prefix caching or speculation would need (no paged pool of latent
@@ -48,14 +58,15 @@ float32.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import jax.numpy as jnp
 from flax import linen as nn
 
 from easyparallellibrary_tpu.models.blocks import (
-    GatedMLP, LatentAttention, LatentDims, RMSNorm, dense)
-from easyparallellibrary_tpu.models.layer_kinds import LATENT
+    GatedMLP, IndexerDims, LatentAttention, LatentDims, RMSNorm, dense)
+from easyparallellibrary_tpu.models.layer_kinds import (
+    FULL, LATENT, SPARSE_LATENT)
 from easyparallellibrary_tpu.models.moe import DroplessMoE
 from easyparallellibrary_tpu.models.slot_core import (
     SplitLayer, child_of, flat_ids, slot_layers)
@@ -75,7 +86,13 @@ class GlmMoeConfig:
   qk_nope_head_dim: int = 192
   qk_rope_head_dim: int = 64
   v_head_dim: int = 256
-  n_routed_experts: int = 64
+  # ``index_topk`` > 0 (``model_type: glm_moe_dsa``, GLM-5): every layer
+  # selects the rows it attends through DeepSeek-V3.2's indexer.
+  index_topk: int = 0
+  index_n_heads: int = 32
+  index_head_dim: int = 128
+  n_routed_experts: int = 64         # the router's width
+  experts_held: Optional[Tuple[int, int]] = None   # (first, count); all
   n_shared_experts: int = 1
   num_experts_per_tok: int = 4
   first_k_dense: int = 1
@@ -98,9 +115,22 @@ class GlmMoeConfig:
   def qk_head_dim(self) -> int:
     return self.qk_nope_head_dim + self.qk_rope_head_dim
 
+  # No layer attends behind a window (what ``serving/window_rows``
+  # counts rows up to: none).
+  sliding_window = 0
+
   def layer_kinds(self) -> tuple:
-    """Every layer keeps one latent leaf."""
-    return (LATENT,) * self.num_layers
+    """Every layer keeps one latent leaf, and with an indexer its index
+    leaf beside it."""
+    kind = SPARSE_LATENT if self.index_topk else LATENT
+    return (kind,) * self.num_layers
+
+  def latent_dims(self, layer_type: str = FULL) -> LatentDims:
+    """The one latent attention of the model (the cache asks a selecting
+    layer's by its layer type)."""
+    if layer_type != FULL:
+      raise ValueError(f"layer type {layer_type!r}: every layer is {FULL}")
+    return glm_latent_dims(self)
 
 
 def glm_latent_dims(cfg) -> LatentDims:
@@ -109,7 +139,10 @@ def glm_latent_dims(cfg) -> LatentDims:
       num_heads=cfg.num_heads, q_lora_rank=cfg.q_lora_rank,
       kv_lora_rank=cfg.kv_lora_rank, qk_nope_head_dim=cfg.qk_nope_head_dim,
       qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
-      rope_theta=cfg.rope_theta)
+      rope_theta=cfg.rope_theta,
+      indexer=None if not cfg.index_topk else IndexerDims(
+          cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk,
+          cfg.qk_rope_head_dim))
 
 
 class GlmMoeBlock(nn.Module):
@@ -119,6 +152,8 @@ class GlmMoeBlock(nn.Module):
   kv_write_impl: Optional[str] = None
   slot_attn_impl: Optional[str] = None
   moe_gmm_impl: Optional[str] = None
+  dsa_index_impl: Optional[str] = None
+  expert_axis: Optional[str] = None
 
   @nn.compact
   def __call__(self, x, positions, slot_cursors=None, num_valid=None,
@@ -129,7 +164,8 @@ class GlmMoeBlock(nn.Module):
     latent = LatentAttention(
         cfg, glm_latent_dims(cfg), decode=self.decode,
         kv_write_impl=self.kv_write_impl,
-        slot_attn_impl=self.slot_attn_impl, name="latent")
+        slot_attn_impl=self.slot_attn_impl,
+        dsa_index_impl=self.dsa_index_impl, name="latent")
     if part == "mix":
       return latent(carry, positions, slot_cursors, num_valid, rows, part)
     mixed = latent(carry if part == "post" else norm("norm_in")(x),
@@ -144,7 +180,7 @@ class GlmMoeBlock(nn.Module):
     # an idle slot's positions and the flat batch's padding rows reach no
     # expert.
     return x + DroplessMoE(cfg, moe_gmm_impl=self.moe_gmm_impl,
-                           name="moe")(
+                           expert_axis=self.expert_axis, name="moe")(
                                h, None if rows is None else rows.live)
 
 
@@ -158,14 +194,17 @@ class GlmMoe(nn.Module):
   handed.  In slot mode the position-wise layers run on the token-flat
   batch ``rows`` describes (models/slot_core.py:SlotRows; every position of
   every slot when none is handed in) and the logits are those of the
-  rows it names."""
+  rows it names.  ``expert_axis`` names the mesh axis the held experts
+  are divided over when the call stands inside a ``shard_map`` over it
+  (a serving engine on such a mesh; models/moe.py ``exchanged_experts``)."""
 
   cfg: GlmMoeConfig
 
   @nn.compact
   def __call__(self, ids, decode: bool = False, return_hidden: bool = False,
                slot_cursors=None, num_valid=None, kv_write_impl=None,
-               slot_attn_impl=None, moe_gmm_impl=None, rows=None):
+               slot_attn_impl=None, moe_gmm_impl=None, dsa_index_impl=None,
+               expert_axis=None, rows=None):
     cfg = self.cfg
     if decode and slot_cursors is None:
       raise ValueError(
@@ -188,7 +227,8 @@ class GlmMoe(nn.Module):
       block = child_of(lambda parent: GlmMoeBlock(
           cfg, dense=i < cfg.first_k_dense, decode=decode,
           kv_write_impl=kv_write_impl, slot_attn_impl=slot_attn_impl,
-          moe_gmm_impl=moe_gmm_impl, name=f"block_{i}", parent=parent))
+          moe_gmm_impl=moe_gmm_impl, dsa_index_impl=dsa_index_impl,
+          expert_axis=expert_axis, name=f"block_{i}", parent=parent))
       # In slot mode a layer takes each row's position from the map of
       # the rows it is handed (``slot_layers``); its latent leaf stays
       # outside a two-width step's conditionals.

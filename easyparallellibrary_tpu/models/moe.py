@@ -31,7 +31,10 @@ then down) multiply each expert's rows by its own matrices; the results
 are weighted and gathered back.  Every shape is fixed by ``positions x
 top_k``, so the fused step compiles once; a dead position (beyond a
 slot's ``num_valid``, an idle slot) is assigned to NO expert: its rows
-sort behind the last group and are not multiplied.
+sort behind the last group and are not multiplied.  On a serving engine
+divided over the ``expert`` axis (``DroplessMoE(expert_axis=)``) the same
+layer exchanges rows between the chips that hold its experts, still
+without capacity (:func:`exchanged_experts`).
 """
 
 from __future__ import annotations
@@ -443,6 +446,118 @@ def dropless_experts(x, chosen, weights, live, w_gate_up, w_down,
   return y.astype(x.dtype), sizes
 
 
+def exchange_rows(positions: int, top_k: int, experts_here: int,
+                  router_width: int) -> int:
+  """Rows a chip SENDS each chip of the axis in one round of
+  :func:`exchanged_experts`: half again what ``positions`` live positions
+  send it on average (``top_k x experts_here / router_width`` of their
+  assignments), up to a multiple of the grouped matmul's 128-row tile.  A
+  size, not a capacity: what exceeds it goes in a further round."""
+  mean = positions * top_k * experts_here / router_width
+  return max(128, -(-int(1.5 * mean) // 128) * 128)
+
+
+def exchanged_experts(x, chosen, weights, live, w_gate_up, w_down,
+                      axis: str, rows: int, impl: Optional[str] = None,
+                      first: int = 0, gate=jax.nn.silu):
+  """:func:`dropless_experts` with the held experts DIVIDED over the mesh
+  axis ``axis`` (called inside a ``shard_map`` over it): chip ``j`` of
+  ``n`` holds the router's experts ``[first + j E_l, first + (j + 1)
+  E_l)`` (its ``w_gate_up`` ``[E_l, D, 2 F]``, ``w_down`` ``[E_l, F, D]``)
+  and its OWN positions ``x`` ``[N, D]`` with their choices.  An
+  assignment's row goes to the chip that holds its expert, every chip runs
+  the grouped matmuls over the rows it received from all ``n``, and each
+  term goes back to its position's chip, which weighs and sums its
+  positions' terms.  Assignments to experts outside the ``n E_l`` held on
+  the axis are another host's (nothing stands in for them).
+
+  The dataflow, with static shapes and no capacity: a chip sorts its
+  assignments by expert (so by destination), and a ROUND moves ``rows`` of
+  them to each chip (``all_to_all`` of ``[n, rows, D]``, under the named
+  scope ``moe_dispatch``), which brings what it received into expert order
+  (the counts every chip sends every chip are gathered first, so the
+  order is arithmetic on them and one small sort), multiplies, and
+  returns the products by the same road (``moe_combine``).  Rounds repeat
+  while any pair of chips has rows left (a ``while_loop`` whose trip
+  count every chip derives from the same gathered counts): one round
+  unless a chip is sent more than ``rows`` by one other, and under ANY
+  imbalance every assignment to a held expert is computed.
+
+  Returns ``(y [N, D], sizes [E_l]`` the rows this chip's experts were
+  sent, ``sent`` how many of this chip's assignments fell on held
+  experts, ``out`` how many of those left the chip, ``rounds)``."""
+  from easyparallellibrary_tpu.kernels.moe_gmm import moe_gmm
+  n = jax.lax.psum(1, axis)
+  me = jax.lax.axis_index(axis)
+  N, k = chosen.shape
+  E_l, _, F2 = w_gate_up.shape
+  D = x.shape[-1]
+  C, i32 = rows, jnp.int32
+  order, sizes = sort_by_expert(chosen, live, n * E_l, first)
+  inv = jnp.argsort(order).astype(i32)          # sorted row of assignment
+  per_dst = sizes.reshape(n, E_l)
+  seg_count = jnp.sum(per_dst, axis=1)                          # [n]
+  seg_start = jnp.cumsum(seg_count) - seg_count
+  # What every chip sends every chip, by expert: [source, dest, E_l].
+  counts = jax.lax.all_gather(per_dst, axis)
+  mine = counts[:, me]                                          # [n, E_l]
+  src_ends = jnp.cumsum(mine, axis=1)
+  src_total = src_ends[:, -1]
+  rounds = jnp.max(-(-jnp.sum(counts, axis=2) // C))
+  slot = jnp.arange(C, dtype=i32)
+  # Each flat assignment's destination and place in that chip's segment.
+  rel = chosen.reshape(-1) - first
+  held = (rel >= 0) & (rel < n * E_l)
+  if live is not None:
+    held &= jnp.repeat(live, k)
+  dst = jnp.clip(rel // E_l, 0, n - 1)
+  place = inv - jnp.take(seg_start, dst)
+  w_flat = jnp.where(held, weights.reshape(-1), 0.0)
+
+  def one_round(carry):
+    r, y = carry
+    lo = r * C
+    # The rows sent: sorted rows [seg_start + lo, + C) of each segment.
+    at = jnp.clip(seg_start[:, None] + lo + slot[None], 0, N * k - 1)
+    send = jnp.take(x, jnp.take(order, at.reshape(-1)) // k, axis=0)
+    with jax.named_scope("moe_dispatch"):
+      got = jax.lax.all_to_all(send.reshape(n, C, D), axis, 0, 0)
+    # Row ``c`` of source ``s`` is row ``lo + c`` of what ``s`` sends
+    # here: its expert is how many of that source's experts end at or
+    # before it; beyond the source's total it is no row (expert E_l).
+    nth = lo + slot
+    expert = jnp.sum(src_ends[:, None, :] <= nth[None, :, None], axis=2,
+                     dtype=i32)
+    expert = jnp.where(nth[None] < src_total[:, None], expert, E_l)
+    order2 = jnp.argsort(expert.reshape(-1), stable=True).astype(i32)
+    sizes2 = jnp.sum(
+        jnp.clip(src_ends, lo, lo + C)
+        - jnp.clip(src_ends - mine, lo, lo + C), axis=0).astype(i32)
+    rows2 = jnp.take(got.reshape(n * C, D), order2, axis=0)
+    h = moe_gmm(rows2, w_gate_up, sizes2, impl=impl)
+    h = gate(h[:, :F2 // 2]) * h[:, F2 // 2:]
+    out = moe_gmm(h, w_down, sizes2, impl=impl)                 # [n C, D]
+    back = jnp.take(out, jnp.argsort(order2), axis=0)
+    with jax.named_scope("moe_combine"):
+      back = jax.lax.all_to_all(back.reshape(n, C, D), axis, 0, 0)
+    # Each assignment's term, where this round carried it.
+    off = place - lo
+    here = held & (off >= 0) & (off < C)
+    term = jnp.take(back.reshape(n * C, D),
+                    jnp.clip(dst * C + off, 0, n * C - 1), axis=0)
+    w = jnp.where(here, w_flat, 0.0)
+    y = y + jnp.sum(term.reshape(N, k, D).astype(jnp.float32)
+                    * w.reshape(N, k, 1), axis=1)
+    return r + 1, y
+
+  _, y = jax.lax.while_loop(lambda c: c[0] < rounds, one_round,
+                            (jnp.zeros((), i32), jnp.zeros((N, D),
+                                                           jnp.float32)))
+  sent = jnp.sum(seg_count)
+  return (y.astype(x.dtype), jnp.sum(mine, axis=0).astype(i32), sent,
+          sent - jnp.take(seg_count, me), rounds)
+
+
 class DroplessMoE(HeldParams, nn.Module):
   """Routed experts without capacity beside shared ones, if any:
   ``Shared(x) + sum_i w_i Expert_i(x)``, the routed sum alone where
@@ -474,6 +589,19 @@ class DroplessMoE(HeldParams, nn.Module):
   and the result is ``Shared(x)`` plus the held experts' terms of the sum.
   No code stands in for the absent chips.
 
+  ``expert_axis`` (a field, ``None``: one chip) names the mesh axis the
+  HELD experts are divided over when the layer stands inside a
+  ``shard_map`` over it (a serving engine on such a mesh): the stacks are
+  then a chip's run of them, ``[count / chips, ..]``, and the routed sum
+  is :func:`exchanged_experts`': rows go to the chips that hold their
+  experts and the terms come back, no capacity, a round sized by
+  :func:`exchange_rows`.  It then also sows
+  ``exchange_rows_out`` / ``exchange_rows_in`` (this chip's assignments
+  that left it, and those that arrived from others) and
+  ``exchange_rounds``; ``held_assignments`` counts this chip's positions'
+  assignments to the host's experts, ``expert_load`` and
+  ``experts_touched`` what this chip's experts were sent.
+
   Sows into the ``stats`` collection ``expert_load``, the busiest
   expert's assignments over the mean (1.0 = even; 0 when nothing is
   live), and ``experts_touched``, how many experts have at least one live
@@ -481,6 +609,7 @@ class DroplessMoE(HeldParams, nn.Module):
 
   cfg: Any
   moe_gmm_impl: Optional[str] = None
+  expert_axis: Optional[str] = None
 
   @nn.compact
   def __call__(self, x, live=None, router_in=None):
@@ -488,6 +617,14 @@ class DroplessMoE(HeldParams, nn.Module):
     k, F, D = cfg.num_experts_per_tok, cfg.moe_d_ff, cfg.d_model
     held = getattr(cfg, "experts_held", None)
     first, E = held if held is not None else (0, cfg.n_routed_experts)
+    axis = self.expert_axis
+    if axis is not None:
+      # Inside a ``shard_map`` over ``axis`` the stacks are a chip's share.
+      chips = jax.lax.psum(1, axis)
+      if E % chips:
+        raise ValueError(f"{E} held experts do not divide over the "
+                         f"{chips} chips of axis {axis!r}")
+      E //= chips
     normal = nn.initializers.normal(stddev=0.02)
     router = self.param("router_kernel", boxed(normal, 2),
                         (D, cfg.n_routed_experts), jnp.float32)
@@ -509,13 +646,28 @@ class DroplessMoE(HeldParams, nn.Module):
           cfg.norm_topk_prob, cfg.route_norm_eps)
     else:
       chosen, weights = route(routed, router, k)
-    y, sizes = dropless_experts(
-        flat, chosen, weights, flat_live, jnp.asarray(w_gate_up, cfg.dtype),
-        jnp.asarray(w_down, cfg.dtype), impl=self.moe_gmm_impl, first=first,
-        gate=getattr(cfg, "expert_gate", jax.nn.silu))
-    total = jnp.sum(sizes).astype(jnp.float32)
-    if held is not None:
-      self.sow("stats", "held_assignments", total)
+    stacks = (jnp.asarray(w_gate_up, cfg.dtype),
+              jnp.asarray(w_down, cfg.dtype))
+    gate = getattr(cfg, "expert_gate", jax.nn.silu)
+    if axis is None:
+      y, sizes = dropless_experts(
+          flat, chosen, weights, flat_live, *stacks, impl=self.moe_gmm_impl,
+          first=first, gate=gate)
+      total = jnp.sum(sizes).astype(jnp.float32)
+      if held is not None:
+        self.sow("stats", "held_assignments", total)
+    else:
+      y, sizes, sent, left, rounds = exchanged_experts(
+          flat, chosen, weights, flat_live, *stacks, axis=axis,
+          rows=exchange_rows(flat.shape[0], k, E, cfg.n_routed_experts),
+          impl=self.moe_gmm_impl, first=first, gate=gate)
+      # ``sizes``: the rows this chip's experts were SENT, by all chips.
+      total = jnp.sum(sizes).astype(jnp.float32)
+      self.sow("stats", "held_assignments", sent.astype(jnp.float32))
+      self.sow("stats", "exchange_rows_out", left.astype(jnp.float32))
+      self.sow("stats", "exchange_rows_in", total - (sent - left).astype(
+          jnp.float32))
+      self.sow("stats", "exchange_rounds", rounds.astype(jnp.float32))
     self.sow("stats", "expert_load",
              jnp.max(sizes).astype(jnp.float32) * E
              / jnp.maximum(total, 1.0))

@@ -470,8 +470,10 @@ def slot_step_logits(model, params, kv, tokens, cursors,
   takes token ``i``'s position from the same ``cursors[b] + i``.
   ``state_args`` go to a model that asks for more (models/jamba.py:
   ``reset``, ``ssm_scan_impl``; models/glm_moe.py: ``moe_gmm_impl``;
-  models/lfm2_moe.py: ``reset`` AND ``moe_gmm_impl``); a GPT takes
-  none.  ``stats`` also returns what the model sowed into its ``stats``
+  models/lfm2_moe.py: ``reset`` AND ``moe_gmm_impl``; ``expert_axis``,
+  the mesh axis a divided engine's step is mapped over, to a model whose
+  expert layers exchange rows over it); a GPT takes none.  ``stats`` also
+  returns what the model sowed into its ``stats``
   collection (an expert layer's load).
 
   The position-wise layers run on a token-flat batch (:class:`SlotRows`)
@@ -492,6 +494,12 @@ def slot_step_logits(model, params, kv, tokens, cursors,
   """
   rows = slot_rows(cursors, num_valid, *tokens.shape, width=width,
                    head_pos=head_pos, narrow=narrow)
+  axis = state_args.get("expert_axis")
+  if axis is not None and rows.fits is not None:
+    # Inside a ``shard_map`` over ``axis`` the layers exchange rows between
+    # the chips, so every chip must take the same side of the width's
+    # conditionals: the narrow one only where every chip's positions fit.
+    rows.fits = jax.lax.pmin(rows.fits.astype(jnp.int32), axis) > 0
   logits, mut = model.apply(
       {"params": params, "cache": kv}, tokens, decode=True,
       slot_cursors=cursors, num_valid=num_valid, rows=rows,

@@ -186,6 +186,16 @@ class ServingStats:
     # to the window's reach.
     self.context_rows = 0
     self.kv_window_rows = 0
+    # An engine divided over a mesh axis (0 elsewhere), summed over the
+    # steps: the assignments that left their position's chip and that
+    # arrived at another's experts (all expert layers, all chips), the
+    # fullest and the emptiest chip's live positions, the steps whose
+    # exchange took more than one round.
+    self.exchange_rows_out = 0
+    self.exchange_rows_in = 0
+    self.chip_live_max = 0
+    self.chip_live_min = 0
+    self.exchange_extra_rounds = 0
     self.kv_rows = 0
     self.busy_time_s = 0.0
     self.prefill_tokens = 0
@@ -352,6 +362,17 @@ class ServingStats:
     self.window_rows += int(window_rows)
     self.held_assignments += int(held_assignments)
 
+  def note_divided_step(self, chip_live_max: int, chip_live_min: int,
+                        exchange_rows_out: float, exchange_rows_in: float,
+                        exchange_rounds: float):
+    """What a step of an engine divided over a mesh axis adds to
+    :meth:`note_step`'s sample."""
+    self.chip_live_max += int(chip_live_max)
+    self.chip_live_min += int(chip_live_min)
+    self.exchange_rows_out += int(exchange_rows_out)
+    self.exchange_rows_in += int(exchange_rows_in)
+    self.exchange_extra_rounds += int(exchange_rounds > 1)
+
   def note_kv_window_step(self, context_rows: int, window_rows: int):
     """What a step of a model with window layers over K/V pairs adds to
     :meth:`note_step`'s sample: the rows a full layer must read of the
@@ -448,7 +469,10 @@ class ServingStats:
       "flat_trimmed_steps", "flat_narrow_steps",
       "routed_positions", "expert_steps", "expert_load_sum",
       "experts_touched_sum", "index_rows", "selected_rows", "window_rows",
-      "held_assignments", "context_rows", "kv_window_rows", "busy_time_s", "prefill_tokens",
+      "held_assignments", "context_rows", "kv_window_rows",
+      "exchange_rows_out", "exchange_rows_in", "chip_live_max",
+      "chip_live_min", "exchange_extra_rounds", "busy_time_s",
+      "prefill_tokens",
       "decode_tokens", "finished_requests", "generated_tokens",
       "drafted_tokens", "accepted_tokens", "shed_requests", "requeues",
       "bad_steps",
@@ -555,7 +579,10 @@ class ServingStats:
                                 if self.steps else 0.0)
            for name in ("index_rows", "selected_rows", "window_rows",
                         "held_assignments", "context_rows",
-                        "kv_window_rows")},
+                        "kv_window_rows", "exchange_rows_out",
+                        "exchange_rows_in", "chip_live_max",
+                        "chip_live_min")},
+        "exchange_extra_rounds": float(self.exchange_extra_rounds),
         # Speculation (all 0.0 on a non-speculative engine): drafted vs
         # accepted totals, overall acceptance rate, and accepted-per-
         # step percentiles over the steps that drafted.
@@ -677,7 +704,11 @@ def fleet_summary(replica_stats: List["ServingStats"],
           sum(getattr(s, name) for s in stats) / steps if steps else 0.0)
          for name in ("index_rows", "selected_rows", "window_rows",
                       "held_assignments", "context_rows",
-                      "kv_window_rows")},
+                      "kv_window_rows", "exchange_rows_out",
+                      "exchange_rows_in", "chip_live_max",
+                      "chip_live_min")},
+      "exchange_extra_rounds": float(
+          sum(s.exchange_extra_rounds for s in stats)),
       "drafted_tokens": float(drafted),
       "accepted_tokens": float(accepted),
       "acceptance_rate": (accepted / drafted) if drafted else 0.0,

@@ -63,6 +63,16 @@ ROADMAP_WINDOW_KV = (
     "cursor moved back, are ROADMAP item R6 ('Window and full layers in "
     "one cache'; docs/serving.md 'Current limits'); serve it through the "
     "contiguous cache with those features off")
+ROADMAP_DIVIDED = (
+    "an engine on a mesh whose 'expert' axis is larger than one DIVIDES "
+    "its slots and its held experts over that axis: the plain fused step "
+    "of the contiguous cache runs a chip's slots on each chip inside one "
+    "shard_map and exchanges expert rows between them (models/moe.py "
+    "exchanged_experts).  Nothing else is built across chips: experts "
+    "over more chips than hold the slots, a pipeline's further stages, a "
+    "K/V pair divided by heads inside the shard_map, the paged, "
+    "speculative and guarded twins on such a mesh are ROADMAP item R9 "
+    "('Multi-chip serving'; docs/serving.md 'The divided engine')")
 ROADMAP_PREEMPTION = (
     "priority reorders ADMISSION, and on the paged engine "
     "(serving.paged.enabled) a RUNNING throughput-class slot is "
@@ -221,6 +231,47 @@ def check_kv_window(cfg, feature: str) -> None:
         f"{feature} is not available for a model with attention layers "
         f"behind a window over K/V pairs ({type(cfg).__name__}, window_kv "
         f"layers) — {ROADMAP_WINDOW_KV}")
+
+
+def check_divided(model, mesh, num_slots: int, *, paged: bool,
+                  prefix_cache: bool, speculative: bool,
+                  resilient: bool) -> None:
+  """Reject what an engine whose slots are divided over the mesh's
+  ``expert`` axis (serving/kv_cache.py ``slot_axis``) cannot be: ONE
+  message for every such composition.  It serves a model with held
+  experts whose ``__call__`` takes ``expert_axis`` (models/glm_moe.py),
+  through the plain step of the contiguous cache, on a mesh whose other
+  axes are of size one, with a whole number of slots and of held experts
+  a chip."""
+  import inspect
+  from easyparallellibrary_tpu.serving.kv_cache import slot_axis
+  axis = slot_axis(mesh)
+  if axis is None:
+    return
+  name, chips = axis
+  cfg = model.cfg
+  other = {a: n for a, n in zip(mesh.axis_names, mesh.devices.shape)
+           if a != name and n > 1}
+  held = getattr(cfg, "experts_held", None)
+  experts = held[1] if held is not None else getattr(
+      cfg, "n_routed_experts", 0)
+  wrong = [what for what, bad in (
+      (f"mesh axes {other} beside {name!r}", bool(other)),
+      (f"a model whose call takes no expert_axis "
+       f"({type(model).__name__})",
+       "expert_axis" not in inspect.signature(
+           type(model).__call__).parameters),
+      (f"{experts} held experts over {chips} chips",
+       not experts or experts % chips != 0),
+      (f"{num_slots} slots over {chips} chips", num_slots % chips != 0),
+      ("the paged cache (serving.paged)", paged),
+      ("prefix caching (serving.prefix_cache)", prefix_cache),
+      ("speculative decoding (serving.speculative)", speculative),
+      ("the guarded step (serving.resilience)", resilient)) if bad]
+  if wrong:
+    raise ValueError(
+        f"an engine divided over mesh axis {name!r} ({chips} chips) is "
+        f"not available with {' and '.join(wrong)} — {ROADMAP_DIVIDED}")
 
 
 def check_draft_compatible(target_cfg, draft_cfg) -> None:

@@ -85,7 +85,8 @@ from easyparallellibrary_tpu.observability.registry import (
     SERVING_NAMESPACE, MetricRegistry)
 from easyparallellibrary_tpu.serving import kv_cache as kv_lib
 from easyparallellibrary_tpu.serving._capabilities import (
-    check_draft_fits_chunk, check_kv_window, check_latent_cache,
+    check_divided, check_draft_fits_chunk, check_kv_window,
+    check_latent_cache,
     check_recurrent_state,
     check_servable, step_overlap)
 from easyparallellibrary_tpu.serving.resilience import (
@@ -187,23 +188,37 @@ def sample_token_slots(logits, keys, temperature, top_k, top_p):
   return jax.lax.cond(jnp.any(temperature > 0), sample, lambda: greedy)
 
 
-def _expert_stats(sown):
+def _expert_stats(sown, axis=None):
   """What a step hands back of its expert layers' sown ``stats``
   (models/moe.py ``DroplessMoE``), float32 ``[2]`` (``[3]`` where the
   layers hold a share of their experts): the busiest expert's
   load over the mean, worst layer, and the fewest experts a layer
-  touched.  Reduced on the device, fetched with the tokens."""
+  touched.  Reduced on the device, fetched with the tokens.  Inside a
+  divided step's ``shard_map`` (``axis``) each layer's numbers are first
+  brought together over the chips (the load's largest, the touched and
+  the held summed), and three more follow, ``[6]``: the assignments that
+  left their position's chip, those that arrived (summed over layers and
+  chips), and the most rounds an exchange took."""
   named = lambda name: [
       leaf for path, leaf in jax.tree_util.tree_leaves_with_path(sown)
       if any(getattr(k, "key", None) == name for k in path)]
-  out = [jnp.max(jnp.stack(named("expert_load"))),
-         jnp.min(jnp.stack(named("experts_touched")))]
+  if axis is None:
+    most = total = lambda v: v
+  else:
+    most = lambda v: jax.lax.pmax(v, axis)
+    total = lambda v: jax.lax.psum(v, axis)
+  out = [jnp.max(most(jnp.stack(named("expert_load")))),
+         jnp.min(total(jnp.stack(named("experts_touched"))))]
   held = named("held_assignments")
   if held:
     # Layers that hold a share of their experts (``cfg.experts_held``):
     # the live assignments that fell on held ones, summed over the
     # layers, third in the same array.
-    out.append(jnp.sum(jnp.stack(held)))
+    out.append(total(jnp.sum(jnp.stack(held))))
+  if axis is not None:
+    out += [total(jnp.sum(jnp.stack(named("exchange_rows_out")))),
+            total(jnp.sum(jnp.stack(named("exchange_rows_in")))),
+            jnp.max(most(jnp.stack(named("exchange_rounds"))))]
   return jnp.stack(out)
 
 
@@ -409,6 +424,16 @@ class ContinuousBatchingEngine:
     self.model = model
     self.params = params
     self.mesh = _resolve_mesh(mesh)
+    # ``(axis, chips)`` where the mesh DIVIDES the slots and the held
+    # experts over its ``expert`` axis (serving/kv_cache.py ``slot_axis``;
+    # docs/serving.md "The divided engine"): slot ``s`` lives on chip ``s
+    # // slots_a_chip``, the fused step runs a chip's slots on each chip
+    # inside one ``shard_map``.  None: one program over all slots.
+    self.slot_axis = kv_lib.slot_axis(self.mesh)
+    if self.slot_axis is not None:
+      # Placed once, as the divided step takes them (a tree that already
+      # lies so is left where it is).
+      self.params = jax.device_put(params, self._param_shardings())
     # The checkpoint version these params came from (blue/green rollout,
     # serving/rollout.py): scopes the prefix cache's keys and makes the
     # scheduler refuse cross-version restore replays.  0 = pre-rollout
@@ -505,10 +530,15 @@ class ContinuousBatchingEngine:
     # layers hold.  None: all.
     self.experts_held = getattr(cfg, "experts_held", None)
     if self.experts_held is not None:
+      held = {"first": self.experts_held[0], "count": self.experts_held[1],
+              "published": cfg.n_routed_experts}
+      if self.slot_axis is not None:
+        # Chip ``j`` of the axis holds ``a_chip`` experts from ``first + j
+        # x a_chip``.
+        held.update(chips=self.slot_axis[1],
+                    a_chip=self.experts_held[1] // self.slot_axis[1])
       trace_lib.get_tracer().metadata(
-          f"{self._track_prefix}/experts_held",
-          {"first": self.experts_held[0], "count": self.experts_held[1],
-           "published": cfg.n_routed_experts})
+          f"{self._track_prefix}/experts_held", held)
     # What the contiguous cache holds of each kind of state (K/V,
     # recurrent state, latent rows) and the ORDER its leaves under a
     # cursor are kept in (``kv_order``: rows or positions,
@@ -542,20 +572,35 @@ class ContinuousBatchingEngine:
     # layers run on (``flat_width``), the plain step's and the speculating
     # one's alike; the scheduler keeps every plan, drafts included, within
     # it.  0 on a paged engine (``token_budget`` is its width).
-    self.flat_width = 0 if self.paged else flat_width(self.num_slots,
+    check_divided(model, self.mesh, self.num_slots, paged=self.paged,
+                  prefix_cache=self.prefix_caching,
+                  speculative=self.drafter is not None,
+                  resilient=(resilience if resilience is not None
+                             else conf.resilience.enabled))
+    # On a mesh that divides the slots both widths are ONE CHIP's: each
+    # runs the flat batch of its own slots, and the scheduler holds each
+    # chip's live positions to it.
+    chips = 1 if self.slot_axis is None else self.slot_axis[1]
+    self.slots_a_chip = self.num_slots // chips
+    self.flat_width = 0 if self.paged else flat_width(self.slots_a_chip,
                                                       self.chunk)
     # The second width of the same program (``narrow_width``; equal to
     # ``flat_width`` where there is none).
-    self.flat_narrow = narrow_width(self.flat_width, self.num_slots)
+    self.flat_narrow = narrow_width(self.flat_width, self.slots_a_chip)
     if not self.paged:
       trace_lib.get_tracer().metadata(
           f"{self._track_prefix}/flat_width",
           {"width": self.flat_width, "narrow": self.flat_narrow,
-           "positions": self.num_slots * self.chunk})
+           "positions": self.slots_a_chip * self.chunk})
+    if self.slot_axis is not None:
+      trace_lib.get_tracer().metadata(
+          f"{self._track_prefix}/slot_axis",
+          {"axis": self.slot_axis[0], "chips": chips,
+           "slots_a_chip": self.slots_a_chip})
     self.scheduler = FCFSScheduler(
         num_slots=self.num_slots, prefill_chunk=self.chunk,
         max_seq_len=cfg.max_seq_len, prefill_token_budget=budget,
-        width=self.flat_width,
+        width=self.flat_width, slot_groups=chips,
         max_batch=eff_batch,
         stop_token=stop_token if stop_token is not None
         else conf.stop_token,
@@ -797,7 +842,10 @@ class ContinuousBatchingEngine:
         "serving engine: %d slots x chunk %d (%s, %s), step overlap %s, "
         "prefill budget %s, max batch %d, speculation %s, resilience %s",
         self.num_slots, self.chunk, layout,
-        "mesh-sharded" if self.mesh is not None else "single-program",
+        "single-program" if self.mesh is None else "mesh-sharded"
+        if self.slot_axis is None else
+        "divided over %s:%d, %d slots and a flat batch a chip" % (
+            *self.slot_axis, self.slots_a_chip),
         self.step_overlap,
         budget or "uncapped", self.scheduler.max_batch,
         f"{type(self.drafter).__name__}(k={self.drafter.k})"
@@ -987,6 +1035,44 @@ class ContinuousBatchingEngine:
       jit_kwargs["out_shardings"] = (rep,) * n_rep_out + state_out
     return jax.jit(step, **jit_kwargs)
 
+  def _param_shardings(self):
+    """Divided engine: where every parameter lies, the routed experts'
+    stacks split over the axis along their leading, experts dimension
+    (chip ``j`` holds the ``j``-th run of them), all else whole on every
+    chip."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    split = NamedSharding(self.mesh, P(self.slot_axis[0]))
+    whole = NamedSharding(self.mesh, P())
+    stacks = ("experts_gate_up", "experts_down")
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: split if any(
+            getattr(k, "key", None) in stacks for k in path) else whole,
+        self.params)
+
+  def _chip_live(self, plan):
+    """Divided engine: the plan's live positions a chip, int ``[chips]``."""
+    return plan.num_valid.reshape(self.slot_axis[1], -1).sum(axis=1)
+
+  def _jit_divided(self, step, donate: bool):
+    """The plain step of an engine divided over the slots: ``step`` as one
+    chip runs it on its own slots, under a ``shard_map`` over the axis.
+    Per-slot arguments and results and the cache's leaves are split along
+    their leading dimension, the parameters as :meth:`_param_shardings`
+    says; the expert layers' numbers come back whole (the body reduced
+    them over the chips)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from easyparallellibrary_tpu.utils.compat import shard_map
+    kv_sh, slot_sh = kv_lib.kv_cache_shardings(self.model.cfg, self.mesh)
+    whole = NamedSharding(self.mesh, P())
+    ins = (self._param_shardings(), kv_sh) + (slot_sh,) * 11
+    outs = (slot_sh, whole, kv_sh, slot_sh)
+    specs = lambda tree: jax.tree_util.tree_map(lambda sh: sh.spec, tree)
+    return jax.jit(
+        shard_map(step, self.mesh, in_specs=specs(ins),
+                  out_specs=specs(outs), check=False),
+        donate_argnums=(1, 2) if donate else (),
+        in_shardings=ins, out_shardings=outs)
+
   def _build_step(self, donate: bool, guard: bool = False):
     model = self.model
     C = self.chunk
@@ -994,6 +1080,10 @@ class ContinuousBatchingEngine:
     lowerings = kv_lib.resolved(self.lowerings)
     recurrent = self._recurrent
     experts = self._experts
+    # Divided over the slots the body below is ONE CHIP's: its slots'
+    # rows of every argument, its share of the expert stacks, and the
+    # model told which axis to exchange expert rows over.
+    expert_axis = None if self.slot_axis is None else self.slot_axis[0]
 
     def step(params, kv, cursors, tokens, num_valid, reset, prev,
              from_prev, keys, tok_index, temperature, top_k, top_p):
@@ -1009,6 +1099,8 @@ class ContinuousBatchingEngine:
       # routed experts and positions of its own at once
       # (models/lfm2_moe.py): each argument goes to the models that ask.
       state_args = dict(reset=reset) if recurrent else {}
+      if expert_axis is not None:
+        state_args["expert_axis"] = expert_axis
       # Each slot's next-token logits sit at its LAST live chunk
       # position, and the head runs on that row alone; idle slots
       # (num_valid=0) read position 0 — garbage the scheduler never
@@ -1025,7 +1117,7 @@ class ContinuousBatchingEngine:
       # busiest expert's load over the mean, worst layer
       # (``serving/expert_load_max``), and the fewest experts a layer
       # touched (``serving/experts_touched_min``).
-      nxt = (nxt, _expert_stats(sown)) if experts else (nxt,)
+      nxt = (nxt, _expert_stats(sown, expert_axis)) if experts else (nxt,)
       if not guard:
         return *nxt, kv, cursors + num_valid
       # In-jit finiteness verdict on exactly the rows commit consumes
@@ -1037,8 +1129,10 @@ class ContinuousBatchingEngine:
       return *nxt, slot_ok, kv, jnp.where(slot_ok, cursors + num_valid,
                                           cursors)
 
-    return self._jit_step(step, donate, n_rep_in=10,
-                          n_rep_out=1 + int(experts) + int(guard))
+    if self.slot_axis is None:
+      return self._jit_step(step, donate, n_rep_in=10,
+                            n_rep_out=1 + int(experts) + int(guard))
+    return self._jit_divided(step, donate)
 
   def _build_spec_step(self, donate: bool, guard: bool = False):
     """The speculative twin of :meth:`_build_step`: the SAME single
@@ -1537,8 +1631,12 @@ class ContinuousBatchingEngine:
       return (self.params, self._kv, plan.tokens, plan.slot_ids,
               plan.positions, plan.valid, plan.block_tables, last_idx,
               *drafts, plan.num_valid > 0, *sampling)
-    live = plan.prefill_tokens + plan.decode_tokens + (
-        0 if num_draft is None else int(num_draft.sum()))
+    if self.slot_axis is None:
+      live = plan.prefill_tokens + plan.decode_tokens + (
+          0 if num_draft is None else int(num_draft.sum()))
+    else:
+      # Divided over the slots, the width is a chip's: the fullest one's.
+      live = int(self._chip_live(plan).max())
     if live > self.flat_width:
       # The scheduler's ceiling keeps every plan within the width; a
       # plan beyond it would lose its last positions without a sign.
@@ -1842,6 +1940,14 @@ class ContinuousBatchingEngine:
     expert_load_max, experts_touched_min, *held = (
         map(float, expert_load) if expert_load is not None else (0.0, 0.0))
     held_assignments = held[0] if held else 0.0
+    if self.slot_axis is not None:
+      # The fullest and the emptiest chip's live positions (the step is
+      # as slow as the fullest), and what the expert layers exchanged.
+      chip_live = self._chip_live(plan)
+      chip_live_max, chip_live_min = int(chip_live.max()), int(chip_live.min())
+      exchange_rows_out, exchange_rows_in, exchange_rounds = held[1:4]
+      # Narrow only where every chip's own live positions fit.
+      flat_narrow = int(chip_live_max <= self.flat_narrow < self.flat_width)
     if self._sparse is not None:
       # Three sums over the plan: the index rows one selecting layer's
       # queries score (every row under the slot's bound), the rows its
@@ -1890,6 +1996,11 @@ class ContinuousBatchingEngine:
         # expert layers: of ``routed_positions x num_experts_per_tok`` a
         # layer.
         tracer.counter("serving/held_assignments", held_assignments)
+      if self.slot_axis is not None:
+        tracer.counter("serving/exchange_rows_out", exchange_rows_out)
+        tracer.counter("serving/exchange_rows_in", exchange_rows_in)
+        tracer.counter("serving/chip_live_max", chip_live_max)
+        tracer.counter("serving/chip_live_min", chip_live_min)
       if self._sparse is not None:
         tracer.counter("serving/index_rows", index_rows)
         tracer.counter("serving/selected_rows", selected_rows)
@@ -1915,6 +2026,10 @@ class ContinuousBatchingEngine:
                                     held_assignments)
       if self._kv_window is not None:
         self.stats.note_kv_window_step(context_rows, kv_window_rows)
+      if self.slot_axis is not None:
+        self.stats.note_divided_step(
+            chip_live_max, chip_live_min, exchange_rows_out,
+            exchange_rows_in, exchange_rounds)
       if self.paged:
         self.stats.note_blocks(self.scheduler.kv_blocks_free,
                                self.scheduler.kv_blocks_used,
@@ -1949,6 +2064,11 @@ class ContinuousBatchingEngine:
         record["experts_touched_min"] = experts_touched_min
       if self.experts_held is not None:
         record["held_assignments"] = held_assignments
+      if self.slot_axis is not None:
+        record["exchange_rows_out"] = exchange_rows_out
+        record["exchange_rows_in"] = exchange_rows_in
+        record["chip_live_max"] = chip_live_max
+        record["chip_live_min"] = chip_live_min
       if self._sparse is not None:
         record["index_rows"] = index_rows
         record["selected_rows"] = selected_rows

@@ -292,9 +292,28 @@ def kv_spec(rank: int = 4) -> P:
   return P(*((None, None, constants.MODEL_AXIS) + (None,) * (rank - 3)))
 
 
+def slot_axis(mesh: Optional[Mesh]) -> Optional[Tuple[str, int]]:
+  """``(name, size)`` of the mesh axis an engine's slots (and a model's
+  held experts) are DIVIDED over: the ``expert`` axis where it is larger
+  than one; ``None`` on every other mesh and without one.  Slot ``s`` of
+  ``num_slots`` lives on chip ``s // (num_slots / size)``; the fused step
+  runs inside a ``shard_map`` over the axis (serving/engine.py), so each
+  chip holds whole leaves of its own slots and every kernel rule is
+  resolved for what a chip holds (:func:`step_lowerings`)."""
+  if mesh is None:
+    return None
+  size = dict(zip(mesh.axis_names, mesh.devices.shape)).get(
+      constants.EXPERT_AXIS, 1)
+  return (constants.EXPERT_AXIS, size) if size > 1 else None
+
+
 def kv_cache_shardings(cfg, mesh: Optional[Mesh]):
   """(kv_shardings_pytree, cursor_sharding) matching
   :func:`allocate_kv_cache`'s structure, or (None, None) without a mesh.
+
+  On a mesh that divides the slots (:func:`slot_axis`) every leaf and the
+  cursors are split over that axis along their leading, slots dimension
+  and nothing else applies.
 
   K/V heads shard over ``model`` only when the cache's head count
   actually divides the axis; otherwise the leaf is replicated (a 1-sized
@@ -305,6 +324,11 @@ def kv_cache_shardings(cfg, mesh: Optional[Mesh]):
   """
   if mesh is None:
     return None, None
+  divided = slot_axis(mesh)
+  if divided is not None:
+    by_slot = NamedSharding(mesh, P(divided[0]))
+    return jax.tree_util.tree_map(lambda leaf: by_slot,
+                                  cache_leaves(cfg, 1, 1)), by_slot
   sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
   tp = sizes.get(constants.MODEL_AXIS, 1)
   split = (tp > 1 and ATTENTION in layer_kinds(cfg)
@@ -508,9 +532,16 @@ def step_lowerings(cfg, num_slots: int, chunk: int,
   """What a fused step over the cache :func:`allocate_kv_cache` builds for
   the same arguments is lowered to: each rule's answer under the rule's
   name, in :data:`_RULES`' order; ``None`` where the model has no layer the
-  rule is about.  THE record whoever builds such a step resolves once (the
+  rule is about; on a mesh that divides the slots (:func:`slot_axis`) each
+  rule is applied to one chip's share of them.  THE record whoever builds
+  such a step resolves once (the
   engine, a draft model's rollout) and hands ``slot_step_logits`` the
   entries of that are not ``None``."""
+  divided = slot_axis(mesh)
+  if divided is not None:
+    # Inside the step's ``shard_map`` a kernel is handed what ONE chip
+    # holds, whole: its share of the slots, and no partitioner in the way.
+    num_slots, mesh = num_slots // divided[1], None
   return {rule.__name__: rule(cfg, num_slots, chunk, mesh)
           for rule in _RULES}
 

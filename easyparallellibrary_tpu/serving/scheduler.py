@@ -488,7 +488,8 @@ class FCFSScheduler:
                prefix_cache: bool = False,
                prefix_session_ttl_s: float = 0.0,
                prefix_max_cached_blocks: int = 0,
-               checkpoint_version: int = 0, width: int = 0):
+               checkpoint_version: int = 0, width: int = 0,
+               slot_groups: int = 1):
     from easyparallellibrary_tpu.serving.kv_cache import (
         BlockAllocator, SlotAllocator)
     from easyparallellibrary_tpu.serving.prefix_cache import PrefixCache
@@ -573,10 +574,17 @@ class FCFSScheduler:
     # the ceiling is cut short, the ones behind it wait, and all of them
     # go on next step.  ``StepPlan.flat_trimmed`` counts what a plan held
     # back for it.
-    if 0 < width < num_slots:
+    # ``slot_groups`` (an engine divided over a mesh axis: its chips):
+    # the slots fall into that many equal runs, each with a flat batch of
+    # ``width`` rows of its own, and the ceiling holds a run at a time.
+    if slot_groups < 1 or num_slots % slot_groups:
+      raise ValueError(f"{num_slots} slots do not divide into "
+                       f"{slot_groups} groups")
+    if 0 < width < num_slots // slot_groups:
       raise ValueError(f"width {width} must hold one row a slot: "
-                       f"{num_slots} slots")
+                       f"{num_slots // slot_groups} slots")
     self.width = width
+    self.slot_groups = slot_groups
     # Temporary degradation override (engine resilience): when > 0 the
     # effective per-step budget is min(budget or inf, override).
     self.budget_override = 0
@@ -1657,6 +1665,10 @@ class FCFSScheduler:
         resident=np.zeros((N,), np.int32))
     budget = self._effective_budget()
     room = self._prefill_room()
+    # Prefill positions granted so far in each run of slots (one run: the
+    # plan's own count).
+    per_group = N // self.slot_groups
+    granted = [0] * self.slot_groups
     spec_k = self.effective_spec_k        # hoisted: loop-invariant
     for slot in self._admit_order:
       state = self.active.get(slot)
@@ -1684,8 +1696,9 @@ class FCFSScheduler:
         grant = min(C, len(state.prefix) - pos)
         if budget > 0:
           grant = min(grant, max(budget - plan.prefill_tokens, 0))
-        if room is not None and grant > room - plan.prefill_tokens:
-          fits = max(room - plan.prefill_tokens, 0)
+        group = slot // per_group
+        if room is not None and grant > room[group] - granted[group]:
+          fits = max(room[group] - granted[group], 0)
           plan.flat_trimmed += grant - fits
           grant = fits
         if grant == 0:
@@ -1694,6 +1707,7 @@ class FCFSScheduler:
         plan.num_valid[slot] = grant
         plan.prefilling[slot] = True
         plan.prefill_tokens += grant
+        granted[group] += grant
       else:
         if state.samples_ahead:
           plan.from_prev[slot] = True   # the sample is still on the device
@@ -1719,23 +1733,28 @@ class FCFSScheduler:
     self._plans.append(plan)
     return plan
 
-  def _prefill_room(self) -> Optional[int]:
+  def _prefill_room(self) -> Optional[List[int]]:
     """Positions the next plan may hand to prefill under the flat batch's
-    width: the width less one row a decoding slot, which is never held
-    back.  None where no plan of the active slots could reach the width
-    (the usual case: nothing is counted)."""
-    if not self.width or len(self.active) * self.chunk <= self.width:
+    width, a run of slots (``slot_groups``; one entry where there is one):
+    the width less one row a decoding slot of the run, which is never
+    held back.  None where no plan of the active slots could reach the
+    width (the usual case: nothing is counted)."""
+    per_group = self.num_slots // self.slot_groups
+    if not self.width or min(len(self.active),
+                             per_group) * self.chunk <= self.width:
       return None
-    decoding = sum(
-        1 for s in self.active.values()
-        if s.planned_pos >= len(s.prefix)
-        and s.planned_generated < s.req.max_new_tokens)
-    return self.width - decoding
+    decoding = [0] * self.slot_groups
+    for slot, s in self.active.items():
+      decoding[slot // per_group] += (
+          s.planned_pos >= len(s.prefix)
+          and s.planned_generated < s.req.max_new_tokens)
+    return [self.width - n for n in decoding]
 
   def _fit_drafts(self, plan: StepPlan) -> None:
     """Speculative drafts ride the rows the plan's own positions leave of
     the width, in admission order; a draft that does not fit is not
     proposed."""
+    # (speculation is refused where the slots fall into several runs)
     spare = self.width - plan.prefill_tokens - plan.decode_tokens
     if int(plan.draft_cap.sum()) <= spare:
       return

@@ -792,7 +792,7 @@ def _abstract_step(model, slots, C, one_chip, **engine):
       model=model, num_slots=slots, chunk=C,
       flat_width=flat_width(slots, C),
       flat_narrow=narrow_width(flat_width(slots, C), slots),
-      lowerings=lowerings, _recurrent=False, _experts=False,
+      lowerings=lowerings, _recurrent=False, _experts=False, slot_axis=None,
       _jit_step=lambda step, donate, **kw: jax.jit(
           step, donate_argnums=(1, 2))), **engine})
   step = ContinuousBatchingEngine._build_step(engine, True)
