@@ -1342,6 +1342,38 @@ def _bits(x):
   return np.asarray(x).view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
 
 
+def check_flat_attend(kernel, name, q, num_valid, out, operands, tol,
+                      rehearsal: bool) -> None:
+  """A one-leaf tile form from the step's FLAT batch: ``q``'s live positions
+  packed in slot order into ``T`` rows, the last live slot ending three rows
+  before the batch's end (its last tile is read from ``T - 8`` on and
+  worked a shift further down).  The result must lie at the rows the
+  queries lie at, equal what the ``[slots, chunk]`` call gave there
+  (``out``), and be zeros in the rows no position lives in."""
+  B, C = q.shape[:2]
+  live = np.arange(C)[None] < num_valid[:, None]
+  total = int(num_valid.sum())
+  flat = np.zeros((total + 3,) + q.shape[2:], np.float32)
+  flat[:total] = np.asarray(q.astype(jnp.float32))[live]
+  flat = jnp.asarray(flat, q.dtype)
+  starts = jnp.asarray(np.cumsum(num_valid) - num_valid, jnp.int32)
+  attend = compile_here(
+      lambda flat, starts, *operands: kernel(flat, *operands, starts=starts,
+                                             chunk=C),
+      flat, starts, *operands, mosaic_calls=_launches(C), rehearsal=rehearsal)
+  got = np.asarray(attend(flat, starts, *operands), np.float32)
+  check(got.shape == (total + 3,) + out.shape[2:],
+        f"{name} from the flat batch: output {got.shape}")
+  err = rel_err(got[:total], out[live])
+  check(err <= tol, f"{name} from the flat batch: {err:.3g} of the "
+        f"[slots, chunk] call's max, tol {tol}")
+  check((got[total:] == 0).all(),
+        f"{name} from the flat batch: rows beyond the live ones not zeros")
+  say(f"  {name} from the flat batch ({total} live of {total + 3} rows): "
+      f"{err:.2e} of the [slots, chunk] call's max at the queries' rows, "
+      "zeros beyond")
+
+
 def check_ring_write(B, R, W, C, dtype, rehearsal: bool) -> None:
   """``kv_write`` of a ring ``[B, R, 1, W]`` bit for bit against the rows
   written at their positions modulo ``R``: windows at the ring's start,
@@ -1442,6 +1474,13 @@ def check_selected_attend(B, Lc, C, H, W, rank, picked, dtype,
   tol = 2e-2 if dtype == jnp.bfloat16 else 5e-4
   check(err <= tol, f"slot_attn_sel {jnp.dtype(dtype).name}: error "
         f"{err:.3g} of the reference's max, tol {tol}")
+  with jax.default_matmul_precision("highest"):
+    check_flat_attend(
+        functools.partial(
+            slot_attn_lib.slot_attention_selected_pallas.__wrapped__,
+            interpret=rehearsal, v_width=rank, scale=scale),
+        "slot_attn_sel", q, num_valid, out, (dirty, scores, thr, cur, nv),
+        tol, rehearsal)
   say(f"  selected attend slots{B} Lc{Lc} heads{H}/1 width{W} values{rank} "
       f"chunk{C} {jnp.dtype(dtype).name}: {err:.2e} of the reference's "
       "max, NaN beyond the bounds unread")
@@ -1500,6 +1539,13 @@ def check_window_attend(B, R, C, H, W, rank, window, dtype,
   tol = 2e-2 if dtype == jnp.bfloat16 else 5e-4
   check(err <= tol, f"slot_attn_win {jnp.dtype(dtype).name}: error "
         f"{err:.3g} of the reference's max, tol {tol}")
+  with jax.default_matmul_precision("highest"):
+    check_flat_attend(
+        functools.partial(
+            slot_attn_lib.slot_attention_window_pallas.__wrapped__,
+            interpret=rehearsal, window=window, v_width=rank, scale=scale),
+        "slot_attn_win", q, num_valid, out, (jnp.asarray(ring, dtype), cur, nv),
+        tol, rehearsal)
   say(f"  window attend slots{B} ring{R} heads{H}/1 width{W} values{rank} "
       f"window{window} chunk{C} {jnp.dtype(dtype).name}: {err:.2e} of "
       "plain attention over each window, unwritten and dead rows unread")

@@ -787,6 +787,43 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
 # bound: no select, slice or concatenation of ``[slots, chunk]``-sized
 # tensors follows a launch, and the tile a launch with nothing to do still
 # visits holds what the other launch writes there.
+#
+# Which of a step's mixers read and write its token-flat batch ``[T, ..]``
+# themselves (models/slot_core.py:SlotRows; ``starts[b]`` the row of slot
+# ``b``'s first live position), and which are handed ``[slots, chunk, ..]``
+# arrays gathered from it (``SlotRows.to_slots``) and gathered back
+# (``to_flat``):
+#
+# * THE ONE-LEAF TILE FORMS, ``slot_attn_sel`` and ``slot_attn_win``, work
+#   on the flat batch where it lies (:func:`tile_attn_out` says when: the
+#   kernel resolved, the batch narrower than ``slots x chunk``).  ``q`` is
+#   ``[T, H, W]`` and a tile's rows are the block at row ``starts[b] + tile
+#   x tp``, an offset in elements, held to ``T - tp`` (a tile that starts
+#   nearer the batch's end is read from there and worked that many rows
+#   further down, :func:`_tile_shift`: the flat batch is never padded).  The
+#   output is ``[T, H, v_width]``, rows as ``q``'s, left in HBM: a tile's
+#   result is cast into one VMEM tile and its LIVE positions alone are
+#   copied out by the kernel, a count of 1 to ``tp`` as DMAs of 8, 4, 2 and
+#   1 positions (:func:`_tile_out_copies`), waited for when the next tile
+#   is about to emit.  So a slot's partial last tile writes nothing of the
+#   next slot's rows, the two launches write disjoint rows (or the same
+#   values) in either order, and the rows beyond the step's live positions
+#   keep the zeros the buffer starts as.  Called with ``[B, C, H, W]``
+#   (every position of every slot: ``starts[b] = b x C``) the same code
+#   gives ``[B, C, H, v_width]``, dead positions zeros.  The layer that
+#   calls them (models/blocks.py:LatentAttention) then holds no ``[slots,
+#   chunk, ..]`` array of queries or of attended rows; its latent and index
+#   rows still go ``to_slots`` for ``kv_write`` and ``dsa_index``, and the
+#   index scores ``to_flat`` for the thresholds.
+# * THE PAIR FORM, ``slot_attn_kvwin``, takes ``q`` gathered ``to_slots`` and
+#   writes ``[B, C, H x hd]`` blocks a tile, gathered back ``to_flat``: its
+#   rows are ``H x hd`` lanes wide, rank 2, and Mosaic cannot take an
+#   offset in rows into them.
+# * ``slot_attn`` (the first grid, above), ``kv_write``, ``dsa_index``,
+#   ``ssm_scan`` and the convolution's window: ``[slots, chunk, ..]`` in,
+#   ``[slots, chunk, ..]`` out, two gathers a mixer.  The one-leaf forms'
+#   output is the form they would take (ROADMAP S2(c)): the array left in
+#   HBM, the live rows copied by the kernel in static sizes.
 
 SLOT_ATTN_SEL = "slot_attn_sel"
 SLOT_ATTN_WIN = "slot_attn_win"
@@ -919,6 +956,18 @@ def tile_attn_fits(cache_shape, dtype, chunk: int, num_heads: int,
   return _tile_block(L, W, dtype, tp * num_heads, v_width, ring) > 0
 
 
+def tile_attn_out(impl: Optional[str], narrower: bool) -> str:
+  """Where the one-leaf forms read their queries and write their result in
+  a step whose attend was lowered to ``impl``, on a flat batch that is
+  ``narrower`` than ``slots x chunk`` or is not: ``"flat"``, the step's
+  token-flat batch itself, where the kernel runs and the batch's rows are
+  not the chunk positions in order already; ``"slots"``, arrays in
+  ``[slots, chunk]`` order gathered from and back into it (at full width by
+  a reshape).  What the mixer asks (models/blocks.py:LatentAttention) and
+  what the engine's record says (serving/kv_cache.py:tile_attn_out)."""
+  return "flat" if impl in ("pallas", "interpret") and narrower else "slots"
+
+
 def resolve_tile_attn_impl(cache_shape, dtype, chunk: int, num_heads: int,
                            v_width: int, ring: bool = False,
                            sharded: bool = False) -> str:
@@ -1012,19 +1061,62 @@ def slot_attention_kv_window_reference(q, ring_k, ring_v, cursors, num_valid,
   return out.reshape(B, C, H, hd)
 
 
+def _tile_shift(start, tp: int, flat_rows: int):
+  """Rows a tile that starts at flat row ``start`` lies further down in
+  the block it is read as: blocks start at ``flat_rows - tp`` at the
+  latest."""
+  return jnp.maximum(start - (flat_rows - tp), 0)
+
+
+def _tile_out_copies(slot_ref, tile_ref, cur_ref, bound_ref, starts_ref,
+                     o_buf, o_hbm, sem, i, act, *, tp: int, flat_rows: int):
+  """``act`` on each copy that takes the live positions of the ``i``-th
+  tile of the grid from ``o_buf`` ``[tp, heads, v_width]`` to their rows of
+  the flat output ``o_hbm``: a count of 1 to ``tp`` positions as the copies
+  of static sizes its bits name (.., 4, 2, 1 positions of every head, a
+  semaphore each), the walk's :func:`_piece_copies` one level up.  A tile
+  of no live position (the one a launch with nothing to do visits at an
+  idle slot) is no copy."""
+  b = slot_ref[i]
+  first = tile_ref[i] * tp
+  start = starts_ref[b] + first
+  shift = _tile_shift(start, tp, flat_rows)
+  n = jnp.clip(bound_ref[b] - cur_ref[b] - first, 0, tp - shift)
+  for k in range(tp.bit_length()):
+    size = 1 << k
+    off = n - jax.lax.rem(n, 2 * size)
+
+    @pl.when((n & size) != 0)
+    def _():
+      act(pltpu.make_async_copy(
+          o_buf.at[pl.ds(shift + off, size)],
+          o_hbm.at[pl.ds(start + off, size)], sem.at[k]))
+
+
 def _tile_attn_kernel(slot_ref, tile_ref, count_ref, cur_ref, bound_ref,
                       starts_ref, into_ref, pos_ref, q_ref, k_ref, *refs,
                       block: int, num_blocks: int, scale: float,
                       v_width: int, tp: int, heads: int,
-                      window: Optional[int], ring: int, pair=None):
+                      window: Optional[int], ring: int, pair=None,
+                      flat_rows: Optional[int] = None):
   """One (live tile, leaf block) grid step of the selected (``window``
   None) or a windowed form.  ``q_ref`` ``[tp, heads, W]``, the tile's
   rows of the flat batch, taken as ``tp x heads`` rows (position, head);
   ``k_ref`` ``[1, 1, W, block]``, position-minor; ``pos_ref`` each query
   row's position in its tile; ``into_ref`` the output as it was handed in
   (aliased, untouched).  ``refs``: the selected form's score block ``[1,
-  tp, block]`` and thresholds ``[1, tp, 1]``, then the output block and the
-  three scratches.
+  tp, block]`` and thresholds ``[1, tp, 1]``, then the output, the three
+  scratches and the output's VMEM tile and its DMA semaphores.
+
+  The one-leaf forms' output is the flat batch ``[flat_rows, heads,
+  v_width]`` itself, left in HBM: a tile's LIVE positions are copied out of
+  VMEM to the rows they are read from, in the static sizes their number
+  decomposes into (:func:`_tile_out_copies`), so a slot's partial last
+  tile writes nothing of the next slot's rows.  A tile that starts within
+  ``tp`` rows of the batch's end is read from ``flat_rows - tp`` on (a
+  block beyond the array is no block): its rows lie ``shift`` rows further
+  down in ``q_ref``, and the tile is worked as if it began ``shift``
+  positions earlier, its first ``shift`` rows (another tile's queries) dead.
 
   The PAIR form (``pair = (H_kv, G, hd)``, behind a window): ``q_ref``
   and the output block ``[1, positions, heads x hd]``,
@@ -1034,18 +1126,26 @@ def _tile_attn_kernel(slot_ref, tile_ref, count_ref, cur_ref, bound_ref,
   the tile's first step: whole ``[tp, hd]`` pieces, or, for one position,
   a row a head in float32 (a 16-bit row alone is half a sublane word),
   the group padded to a sublane tile."""
-  del count_ref, starts_ref, into_ref
+  del into_ref
+  i = pl.program_id(0)
+  kb = pl.program_id(1)
+  b = slot_ref[i]
+  first = tile_ref[i] * tp                  # the tile's first chunk position
+  shift = 0
   if pair is not None:
     *refs, qs_ref = refs
     Hkv, G, hd = pair
     rows_g = qs_ref.shape[0] // Hkv      # stacked rows of one K/V head
     lanes = lambda i: slice(i * hd, (i + 1) * hd)
     of_head = lambda g: slice(g * rows_g, (g + 1) * rows_g)
+  else:
+    *refs, o_buf, o_sem = refs
+    shift = _tile_shift(starts_ref[b] + first, tp, flat_rows)
+    first = first - shift
+    out_copies = functools.partial(
+        _tile_out_copies, slot_ref, tile_ref, cur_ref, bound_ref, starts_ref,
+        o_buf, refs[-4], o_sem, tp=tp, flat_rows=flat_rows)
   o_ref, m_ref, l_ref, acc_ref = refs[-4:]
-  i = pl.program_id(0)
-  kb = pl.program_id(1)
-  b = slot_ref[i]
-  first = tile_ref[i] * tp                  # the tile's first chunk position
   cur = cur_ref[b]
   bound = bound_ref[b]
   # Positions of the tile's first and last live query.
@@ -1106,6 +1206,9 @@ def _tile_attn_kernel(slot_ref, tile_ref, count_ref, cur_ref, bound_ref,
     if window is None:
       sc_ref, thr_ref = refs[0], refs[1]
       picked = jnp.where(sc_ref[0] >= thr_ref[0], 0.0, NEG_INF)  # [tp, block]
+      if tp > 1:
+        # position p's scores to the row its query lies in, p + shift
+        picked = pltpu.roll(picked, shift, 0)
       s = s + jnp.concatenate(
           [jnp.broadcast_to(picked[p:p + 1], (heads, block))
            for p in range(tp)], axis=0)
@@ -1141,7 +1244,16 @@ def _tile_attn_kernel(slot_ref, tile_ref, count_ref, cur_ref, bound_ref,
     real = t_lo + pos_ref[...] < bound
     out = jnp.where(real, acc_ref[...] / l_col, 0.0)
     if pair is None:
-      o_ref[0] = out.astype(o_ref.dtype)
+      # The tile before this one has had this tile's folds to land in.
+      @pl.when(i > 0)
+      def _():
+        out_copies(i - 1, lambda dma: dma.wait())
+      o_buf[...] = out.astype(o_buf.dtype).reshape(o_buf.shape)
+      out_copies(i, lambda dma: dma.start())
+
+      @pl.when(i == count_ref[0] - 1)
+      def _():
+        out_copies(i, lambda dma: dma.wait())
       return
     # A head's rows to its own lanes of the output block; one position
     # fills the block's first row and leaves the others zeros, which is
@@ -1167,11 +1279,14 @@ def _tile_attention(q, leaf, cursors, num_valid, scores, threshold,
   and ``chunk``, the step's token-flat batch ``[T, H, W]`` in which slot
   ``b``'s live positions are the rows from ``starts[b]`` on
   (models/slot_core.py:SlotRows): a tile's query rows are read where they
-  lie, no copy of them into ``[slots, chunk]`` order.  Decoding slots take a
-  launch of their own on their one position (:func:`split_decodes`)."""
+  lie and its result is written to the same rows of ``[T, H, v_width]``,
+  which is then what comes back; no ``[slots, chunk]``-ordered copy of
+  either exists.  Decoding slots take a launch of their own on their one
+  position (:func:`split_decodes`)."""
   B = cursors.shape[0]
   H, W = q.shape[-2:]
   pair = values is not None
+  flat = starts is not None
   if pair:
     # In [slots, chunk] order, a row as the projection leaves it, H x hd
     # lanes: an offset in rows into a rank-2 flat batch is one Mosaic
@@ -1179,13 +1294,16 @@ def _tile_attention(q, leaf, cursors, num_valid, scores, threshold,
     chunk = q.shape[1]
     q = q.reshape(B, chunk, H * W)
     starts = jnp.zeros((B,), jnp.int32)
-  elif starts is None:
+  elif not flat:
+    # Every position of every slot is a flat batch too, slot ``b``'s rows
+    # from ``b x chunk`` on.
     chunk = q.shape[1]
     q = q.reshape(B * chunk, H, W)
     starts = jnp.arange(B, dtype=jnp.int32) * chunk
-  else:
-    # A slot's last tile may start within a tile of the batch's end.
-    q = jnp.pad(q, ((0, tile_positions(chunk, H)), (0, 0), (0, 0)))
+  elif q.shape[0] < tile_positions(chunk, H):
+    raise ValueError(
+        f"a flat batch of {q.shape[0]} rows is less than one tile of "
+        f"{tile_positions(chunk, H)} positions")
   L = leaf.shape[1]
   nv = (jnp.full((B,), chunk, jnp.int32) if num_valid is None
         else jnp.clip(num_valid.astype(jnp.int32), 0, chunk))
@@ -1198,14 +1316,14 @@ def _tile_attention(q, leaf, cursors, num_valid, scores, threshold,
       _tile_launch, q.astype(leaf.dtype), starts.astype(jnp.int32), leaf,
       cur, bound, window=window, interpret=interpret, block=block,
       v_width=v_width, scale=scale, values=values)
-  # Every launch writes the tiles it visits into ONE buffer that starts
-  # as zeros (aliased in and out): what no tile covers stays zeros, and
-  # the decoding slots' launch lands beside the other's with no merge.
-  # Both work under every slot's TRUE bound, so the one tile a launch
-  # with nothing to do still visits (a grid has at least one step) writes
-  # what the other launch writes there.
+  # Every launch writes what it computes into ONE buffer that starts as
+  # zeros (aliased in and out): what no tile covers stays zeros, and the
+  # decoding slots' launch lands beside the other's with no merge.  Both
+  # work under every slot's TRUE bound, so the one tile a launch with
+  # nothing to do still visits (a grid has at least one step) writes what
+  # the other launch writes there.
   out = jnp.zeros((B, chunk, H * v_width) if pair
-                  else (B, chunk * H, v_width), leaf.dtype)
+                  else (q.shape[0], H, v_width), leaf.dtype)
   split = split_decodes(num_valid, chunk)
   if split is None:
     out = launch(out, chunk, nv, scores, threshold)
@@ -1218,11 +1336,12 @@ def _tile_attention(q, leaf, cursors, num_valid, scores, threshold,
     out = launch(out, 1, one, None, None)
     out = launch(out, chunk, many, None, None)
   else:
+    # The one-leaf forms write live positions alone: in either order.
     many, one = split
     first = lambda x: None if x is None else x[:, :1]
     out = launch(out, chunk, many, scores, threshold)
     out = launch(out, 1, one, first(scores), first(threshold))
-  return out.reshape(B, chunk, H, v_width)
+  return out if flat else out.reshape(B, chunk, H, v_width)
 
 
 def _tile_launch(q, starts, leaf, cur, bound, into, C: int, feeds, scores,
@@ -1233,22 +1352,23 @@ def _tile_launch(q, starts, leaf, cur, bound, into, C: int, feeds, scores,
   cover the first ``feeds[b]`` positions of each slot (int32 ``[B]``, at
   most ``C``); ``q`` ``[T, H, W]``, slot ``b``'s position ``i`` at row
   ``starts[b] + i``, ``cur`` and ``bound`` each slot's cursor and true
-  bound.  ``into`` ``[B, chunk x H, v_width]`` is the output, handed in:
-  the launch writes the tiles it visits (their positions at or beyond the
-  bound as zeros) and leaves every other row as it was.  The pair form
+  bound.  ``into`` ``[T, H, v_width]`` is the output, handed in and left in
+  HBM: the launch copies each tile's positions under the bound to the rows
+  their queries lie in and leaves every other row as it was.  The pair form
   (``values``): ``q`` and ``into`` ``[B, chunk, heads x hd]``, ``leaf`` and
-  ``values`` ``[B, R, H_kv x hd]``."""
+  ``values`` ``[B, R, H_kv x hd]``, the output a block a tile (its
+  positions at or beyond the bound zeros)."""
   L = leaf.shape[1]
   dtype = leaf.dtype
   ring = window is not None
   pair = None
   if values is None:
-    H, W = q.shape[1:]
+    T, H, W = q.shape
     tp = tile_positions(C, H)
     rows = tp * H
     pos = (jnp.arange(rows, dtype=jnp.int32) // H)[:, None]
   else:
-    H, W, hd = q.shape[2] // v_width, leaf.shape[2], v_width
+    T, H, W, hd = None, q.shape[2] // v_width, leaf.shape[2], v_width
     pair = (W // hd, H // (W // hd), hd)
     tp = pair_tile_positions(C, H)
     rows = pair[0] * _pair_rows(tp, pair[1], dtype)
@@ -1273,20 +1393,24 @@ def _tile_launch(q, starts, leaf, cur, bound, into, C: int, feeds, scores,
     t_hi = jnp.minimum(bound[b], cur[b] + (tile[i] + 1) * tp) - 1
     return b, 0, 0, jnp.minimum(kb, jnp.maximum(t_hi, 0) // block)
 
-  # The tile's query rows, read where they lie in the flat batch: an
-  # offset in rows, not in blocks (every dimension an element offset).
   in_specs = [pl.BlockSpec(memory_space=pl.ANY),
               pl.BlockSpec((rows, 1), lambda i, kb, *_: (0, 0))]
   if pair is None:
+    # The tile's query rows, read where they lie in the flat batch: an
+    # offset in rows, not in blocks (every dimension an element offset),
+    # held inside the batch (the kernel knows the shift:
+    # :func:`_tile_shift`).
     in_specs += [
         pl.BlockSpec(
             (pl.Element(tp), pl.Element(H), pl.Element(W)),
             lambda i, kb, slot, tile, count, cur, bound, starts: (
-                starts[slot[i]] + tile[i] * tp, 0, 0)),
+                jnp.minimum(starts[slot[i]] + tile[i] * tp, T - tp), 0, 0)),
         pl.BlockSpec((1, 1, W, block), leaf_idx)]
     operands = [into, pos, q, jnp.transpose(leaf, (0, 2, 3, 1))]
-    out_block = (1, rows, v_width)
-    scratch = []
+    out_spec = pl.BlockSpec(memory_space=pl.ANY)
+    # The output's tile and a semaphore a size of copy out of it.
+    scratch = [pltpu.VMEM((tp, H, v_width), dtype),
+               pltpu.SemaphoreType.DMA((tp.bit_length(),))]
   else:
     def rows_idx(*a):
       b, _, _, kb = leaf_idx(*a)
@@ -1295,9 +1419,11 @@ def _tile_launch(q, starts, leaf, cur, bound, into, C: int, feeds, scores,
     # read and written (a row alone is no block of a 16-bit array).
     out_block = (1, tp if tp > 1 else min(into.shape[1], sublane_tile(dtype)),
                  H * hd)
-    in_specs += [pl.BlockSpec(out_block, lambda i, kb, slot, tile, *_: (
-        slot[i], tile[i], 0))] + [pl.BlockSpec((1, block, W), rows_idx)] * 2
+    tile_idx = lambda i, kb, slot, tile, *_: (slot[i], tile[i], 0)
+    in_specs += [pl.BlockSpec(out_block, tile_idx)] + [
+        pl.BlockSpec((1, block, W), rows_idx)] * 2
     operands = [into, pos, q, leaf, values]
+    out_spec = pl.BlockSpec(out_block, tile_idx)
     scratch = [pltpu.VMEM((rows, hd), dtype if tp > 1 else jnp.float32)]
   if not ring:
     def score_idx(i, kb, slot, tile, *rest):
@@ -1316,14 +1442,13 @@ def _tile_launch(q, starts, leaf, cur, bound, into, C: int, feeds, scores,
   out = pl.pallas_call(
       functools.partial(
           _tile_attn_kernel, block=block, num_blocks=nb, scale=float(scale),
-          v_width=v_width, tp=tp, heads=H, window=window, ring=L, pair=pair),
+          v_width=v_width, tp=tp, heads=H, window=window, ring=L, pair=pair,
+          flat_rows=T),
       grid_spec=pltpu.PrefetchScalarGridSpec(
           num_scalar_prefetch=6,
           grid=(count[0], nb),
           in_specs=in_specs,
-          out_specs=pl.BlockSpec(
-              out_block,
-              lambda i, kb, slot, tile, *_: (slot[i], tile[i], 0)),
+          out_specs=out_spec,
           scratch_shapes=[
               pltpu.VMEM((rows, LANES), jnp.float32),      # running max
               pltpu.VMEM((rows, LANES), jnp.float32),      # running sum
@@ -1417,8 +1542,10 @@ def slot_attention_selected(q, latent, scores, threshold, cursors,
   v_width]``.  ``impl=None`` applies :func:`resolve_tile_attn_impl` to the
   operands at hand, as :func:`slot_attention` does.  The kernel also takes
   ``q`` as the step's flat batch ``[T, H, W]`` with ``starts`` (int32
-  ``[B]``: the flat row of each slot's first position), which spares the
-  copy into ``[B, C]`` order; the reference takes ``[B, C, H, W]`` only."""
+  ``[B]``: the flat row of each slot's first position) and then returns
+  ``[T, H, v_width]``, each live position's result at the row its query
+  lies in and zeros elsewhere: no copy into ``[B, C]`` order on the way in
+  or out.  The reference takes ``[B, C, H, W]`` only."""
   if impl is None:
     impl = resolve_tile_attn_impl(latent.shape, latent.dtype,
                                   scores.shape[1], q.shape[-2], v_width,
@@ -1442,8 +1569,9 @@ def slot_attention_window(q, ring, cursors, num_valid=None, *,
   ``p`` at row ``p mod R``, written through ``kv_write(..., ring=True)``):
   query ``i`` of slot ``b``, at ``t = cursors[b] + i``, sees the positions
   ``t - window < p <= t``.  ``out [B, C, H, v_width]``.  ``starts`` and
-  ``chunk``: ``q`` as the flat batch, as :func:`slot_attention_selected`
-  takes it, and ``impl=None`` as it resolves it."""
+  ``chunk``: ``q`` as the flat batch and the result as its rows ``[T, H,
+  v_width]``, as :func:`slot_attention_selected` takes and gives them, and
+  ``impl=None`` as it resolves it."""
   if impl is None:
     impl = resolve_tile_attn_impl(
         ring.shape, ring.dtype, q.shape[1] if starts is None else chunk,

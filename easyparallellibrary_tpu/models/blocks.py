@@ -287,12 +287,13 @@ class LatentAttention(HeldParams, nn.Module):
       index = () if ix is None else tuple(
           rows.to_slots(t[:, 0]) for t in (k_ix, q_ix, w_ix))
       # The selected and the windowed kernels read a tile's query rows
-      # where they lie in the flat batch; every other attend takes them
-      # in [slots, C] order, which is also what a lowering nobody resolved
-      # yet (``None``) is handed: every lowering takes it.
-      if (dims.window is not None or ix is not None) and (
-          self.slot_attn_impl not in (None, "reference")
-          and rows.dst is not None):
+      # where they lie in the flat batch, and write its result there;
+      # every other attend takes them in [slots, C] order, which is also
+      # what a lowering nobody resolved yet (``None``) is handed: every
+      # lowering takes it.
+      from easyparallellibrary_tpu.kernels.slot_attention import tile_attn_out
+      if (dims.window is not None or ix is not None) and tile_attn_out(
+          self.slot_attn_impl, rows.dst is not None) == "flat":
         h = (gate, q_abs), (rows.to_slots(new[:, 0]), None, *index)
       else:
         h = (gate,), (rows.to_slots(new[:, 0]), rows.to_slots(q_abs),
@@ -303,10 +304,11 @@ class LatentAttention(HeldParams, nn.Module):
       h = self._mix(*h, dims, slot_cursors, num_valid, rows)
       if part == "mix":
         return h
-    (gate,), o_lat = h
-    # the attend's latent rows [slots, C, H, r] -> [rows, 1, H, dv]
-    return gated_out(jnp.einsum("bshr,rhd->bshd",
-                                rows.to_flat(o_lat)[:, None],
+    (gate, *flat_o), o_lat = h
+    # the attend's latent rows, flat [rows, H, r] as a kernel that works on
+    # the flat batch left them or [slots, C, H, r], -> [rows, 1, H, dv]
+    o_lat = flat_o[0] if flat_o else rows.to_flat(o_lat)
+    return gated_out(jnp.einsum("bshr,rhd->bshd", o_lat[:, None],
                                 w_kvb()[..., dn:]), gate)
 
   def _mix(self, rowwise, whole, dims, slot_cursors, num_valid, rows):
@@ -315,7 +317,9 @@ class LatentAttention(HeldParams, nn.Module):
     window write, the index scores and their thresholds, the attend.
     ``rowwise`` ``(gate [T, 1, H] or None[, q_abs [T, H, r + dr]])``,
     ``whole`` ``(new, q_abs or None[, k_ix, q_ix, w_ix])``.  Returns
-    ``((gate,), o_lat [slots, C, H, r])``."""
+    ``((gate,), o_lat [slots, C, H, r])``, or, where the queries came
+    row-wise, ``((gate, o_lat [T, H, r]), None)``: the attend read and wrote
+    the flat batch where it lies, and its result is row-wise too."""
     from easyparallellibrary_tpu.kernels.kv_write import kv_write
     from easyparallellibrary_tpu.kernels.slot_attention import (
         slot_attention, slot_attention_selected, slot_attention_window)
@@ -358,7 +362,8 @@ class LatentAttention(HeldParams, nn.Module):
       o_lat = slot_attention(q_abs, latent.value, None, slot_cursors,
                              num_valid, impl=self.slot_attn_impl, v_width=r,
                              scale=scale)
-    return (gate,), o_lat.astype(self.cfg.dtype)
+    o_lat = o_lat.astype(self.cfg.dtype)
+    return ((gate, o_lat), None) if flat_q else ((gate,), o_lat)
 
   def _dense_attend(self, q_nope, q_rope, c, k_r, w_kvb, index, dims):
     """The full forward's attend over its own sequence: ``[B, S, H, dv]``."""

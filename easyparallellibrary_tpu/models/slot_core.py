@@ -211,7 +211,15 @@ class SlotRows:
   gathers of whole rows (a scatter is a serial loop on a TPU): a dead
   chunk position reads some other row's values, which nothing reads
   after it, exactly as it held garbage before; a padding row is
-  gathered by no position.
+  gathered by no position.  Who still moves rows so: ``kv_write`` (new K/V,
+  latent and index rows ``to_slots``), ``slot_attn`` and ``slot_attn_kvwin``
+  (queries in, result back), ``dsa_index`` (index queries and weights in,
+  the scores ``to_flat`` for their thresholds), ``ssm_scan`` and the
+  convolution's window.  Who does not: the selected and the windowed latent
+  attends (``slot_attn_sel``, ``slot_attn_win``), which where their kernel
+  runs read their queries from the flat batch at ``dst``'s first row a slot
+  and write their result to the same rows (kernels/slot_attention.py, the
+  tile forms; the layer's carry then holds both row-wise).
 
   ``src`` int32 ``[T]`` — the ``slot * C + i`` each flat row reads;
   ``dst`` int32 ``[slots * C]`` — the flat row each chunk position reads

@@ -486,6 +486,20 @@ class ContinuousBatchingEngine:
     else:
       self.block_size = self.num_blocks = self.token_budget = 0
       self._paged_impl = None
+    # Rows of the token-flat batch the contiguous step's position-wise
+    # layers run on (``flat_width``), the plain step's and the speculating
+    # one's alike; the scheduler keeps every plan, drafts included, within
+    # it.  0 on a paged engine (``token_budget`` is its width).  On a mesh
+    # that divides the slots both widths are ONE CHIP's: each runs the flat
+    # batch of its own slots, and the scheduler holds each chip's live
+    # positions to it.
+    chips = 1 if self.slot_axis is None else self.slot_axis[1]
+    self.slots_a_chip = self.num_slots // chips
+    self.flat_width = 0 if self.paged else flat_width(self.slots_a_chip,
+                                                      self.chunk)
+    # The second width of the same program (``narrow_width``; equal to
+    # ``flat_width`` where there is none).
+    self.flat_narrow = narrow_width(self.flat_width, self.slots_a_chip)
     # What the fused step is lowered to (serving/kv_cache.py
     # ``step_lowerings``): each kernel rule's answer under its name,
     # resolved ONCE here from the backend, the leaves' shapes and dtypes
@@ -495,10 +509,10 @@ class ContinuousBatchingEngine:
     # that declined shows "reference".  Metadata, so no ring eviction
     # loses it.
     self.lowerings = kv_lib.step_lowerings(
-        cfg, self.num_slots, self.chunk, self.mesh)
+        cfg, self.num_slots, self.chunk, self.mesh, self.flat_width or None)
     if self.paged:
       self.lowerings = dict.fromkeys(self.lowerings)
-    for name, impl in kv_lib.resolved(self.lowerings).items():
+    for name, impl in kv_lib.recorded(self.lowerings).items():
       trace_lib.get_tracer().metadata(
           f"{self._track_prefix}/{name}", {"impl": impl})
     # The granule and length of the attend kernel's walk, where the step
@@ -568,25 +582,11 @@ class ContinuousBatchingEngine:
           "drafts roll back)")
       check_latent_cache(cfg, "speculative decoding (serving.speculative)")
       check_kv_window(cfg, "speculative decoding (serving.speculative)")
-    # Rows of the token-flat batch the contiguous step's position-wise
-    # layers run on (``flat_width``), the plain step's and the speculating
-    # one's alike; the scheduler keeps every plan, drafts included, within
-    # it.  0 on a paged engine (``token_budget`` is its width).
     check_divided(model, self.mesh, self.num_slots, paged=self.paged,
                   prefix_cache=self.prefix_caching,
                   speculative=self.drafter is not None,
                   resilient=(resilience if resilience is not None
                              else conf.resilience.enabled))
-    # On a mesh that divides the slots both widths are ONE CHIP's: each
-    # runs the flat batch of its own slots, and the scheduler holds each
-    # chip's live positions to it.
-    chips = 1 if self.slot_axis is None else self.slot_axis[1]
-    self.slots_a_chip = self.num_slots // chips
-    self.flat_width = 0 if self.paged else flat_width(self.slots_a_chip,
-                                                      self.chunk)
-    # The second width of the same program (``narrow_width``; equal to
-    # ``flat_width`` where there is none).
-    self.flat_narrow = narrow_width(self.flat_width, self.slots_a_chip)
     if not self.paged:
       trace_lib.get_tracer().metadata(
           f"{self._track_prefix}/flat_width",
@@ -837,7 +837,7 @@ class ContinuousBatchingEngine:
                             for kind in held)
                 + "); "
                 + ", ".join(f"{name} {impl}" for name, impl in
-                            kv_lib.resolved(self.lowerings).items()))
+                            kv_lib.recorded(self.lowerings).items()))
     get_logger().info(
         "serving engine: %d slots x chunk %d (%s, %s), step overlap %s, "
         "prefill budget %s, max batch %d, speculation %s, resilience %s",

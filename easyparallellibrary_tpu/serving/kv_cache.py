@@ -520,6 +520,23 @@ def moe_gmm_impl(cfg, num_slots: int, chunk: int,
                    for k, n in ((D, 2 * F), (F, D)))
 
 
+def tile_attn_out(cfg, impl: Optional[str], narrower: bool) -> Optional[str]:
+  """Where the attends of the layers that select their rows or sit behind
+  a latent window read their queries and write their result, given what
+  :func:`slot_attn_impl` resolved and whether the flat batch is
+  ``narrower`` than ``num_slots x chunk``
+  (kernels/slot_attention.py:tile_attn_out, which the mixer asks too):
+  ``"flat"``, the step's token-flat batch where it lies; ``"slots"``,
+  arrays in ``[slots, chunk]`` order gathered from it and back; ``None``
+  for a model without such a layer.  No lowering a caller could name: what
+  the step does, derived from what the other rules resolved."""
+  kinds = layer_kinds(cfg)
+  if SPARSE_LATENT not in kinds and WINDOW_LATENT not in kinds:
+    return None
+  from easyparallellibrary_tpu.kernels import slot_attention
+  return slot_attention.tile_attn_out(impl, narrower)
+
+
 # The rules above in the order every record of a step's lowerings is kept
 # in.  A rule's name is at once the decoders' keyword, the trace metadata's
 # suffix (``serving/<name>``) and the diagnostic bundle's key.
@@ -528,7 +545,8 @@ _RULES = (kv_write_impl, slot_attn_impl, kv_win_write_impl, kv_win_attn_impl,
 
 
 def step_lowerings(cfg, num_slots: int, chunk: int,
-                   mesh: Optional[Mesh] = None) -> Dict[str, Optional[str]]:
+                   mesh: Optional[Mesh] = None,
+                   width: Optional[int] = None) -> Dict[str, Optional[str]]:
   """What a fused step over the cache :func:`allocate_kv_cache` builds for
   the same arguments is lowered to: each rule's answer under the rule's
   name, in :data:`_RULES`' order; ``None`` where the model has no layer the
@@ -536,21 +554,36 @@ def step_lowerings(cfg, num_slots: int, chunk: int,
   rule is applied to one chip's share of them.  THE record whoever builds
   such a step resolves once (the
   engine, a draft model's rollout) and hands ``slot_step_logits`` the
-  entries of that are not ``None``."""
+  entries of that are not ``None`` (:func:`resolved`).  Last, under
+  ``tile_attn_out``, what follows from them and from the flat batch's
+  ``width`` (a chip's rows; ``None``: every position of every slot) with
+  no rule of its own: :func:`tile_attn_out`."""
   divided = slot_axis(mesh)
   if divided is not None:
     # Inside the step's ``shard_map`` a kernel is handed what ONE chip
     # holds, whole: its share of the slots, and no partitioner in the way.
     num_slots, mesh = num_slots // divided[1], None
-  return {rule.__name__: rule(cfg, num_slots, chunk, mesh)
-          for rule in _RULES}
+  record = {rule.__name__: rule(cfg, num_slots, chunk, mesh)
+            for rule in _RULES}
+  record["tile_attn_out"] = tile_attn_out(
+      cfg, record["slot_attn_impl"],
+      width is not None and width < num_slots * chunk)
+  return record
 
 
 def resolved(lowerings: Dict[str, Optional[str]]) -> Dict[str, str]:
-  """The entries of a :func:`step_lowerings` record some rule resolved:
+  """The lowerings of a :func:`step_lowerings` record some rule resolved:
   the keywords ``slot_step_logits`` is handed (a model takes those of its
-  own kinds of layer and no others) and the metadata a run records."""
-  return {name: impl for name, impl in lowerings.items() if impl is not None}
+  own kinds of layer and no others)."""
+  return {rule.__name__: lowerings[rule.__name__] for rule in _RULES
+          if lowerings.get(rule.__name__) is not None}
+
+
+def recorded(lowerings: Dict[str, Optional[str]]) -> Dict[str, str]:
+  """Every entry of the record that says something, :func:`resolved`'s
+  and what follows from them: the metadata a run records and the line an
+  engine logs."""
+  return {name: said for name, said in lowerings.items() if said is not None}
 
 
 def allocate_kv_cache(cfg, num_slots: int, chunk: int,

@@ -265,17 +265,55 @@ def test_interpreted_kernels_equal_the_reference_lowerings(wide):
                              atol=LOGIT_TOL, rtol=0)
 
 
+def _step_shapes(closed):
+  """Every array shape of a traced program, the bodies of its conditionals,
+  loops, jitted calls and kernels' wrappers included."""
+  shapes = set()
+
+  def walk(jaxpr):
+    for eqn in jaxpr.eqns:
+      shapes.update(tuple(v.aval.shape) for v in (*eqn.invars, *eqn.outvars)
+                    if hasattr(v.aval, "shape"))
+      if eqn.primitive.name == "pallas_call":
+        continue                                   # a kernel's own blocks
+      for sub in jax.core.jaxprs_in_params(eqn.params):
+        walk(sub)
+  walk(closed.jaxpr)
+  return shapes
+
+
+def _slot_ordered(B, C, model):
+  """The ``[slots, chunk]``-ordered arrays of queries and of attended
+  latent rows a step of ``model`` could hold, a layer type each."""
+  shapes = set()
+  for kind in (ref.FULL, ref.SLIDING):
+    dims = model.cfg.latent_dims(kind)
+    H, r, W = dims.num_heads, dims.kv_lora_rank, dims.latent_dim
+    shapes |= {(B, C, H, W), (B, C, H, r), (B, C * H, r), (B * C, H, r)}
+  return shapes
+
+
 def test_kernels_read_the_flat_batch_where_it_lies(wide):
   """Under a flat batch narrower than ``slots x chunk`` the selected and
   the windowed kernels take their query rows from the flat batch itself
-  (slot ``b``'s from row ``starts[b]`` on, at no tile's edge): two steps
-  of a whole chunk, a partial one, an idle slot and a decode, interpreted
-  kernels against the reference lowerings at the same width and at full
-  width."""
+  (slot ``b``'s from row ``starts[b]`` on, at no tile's edge) and write
+  their result to the same rows: two steps of a whole chunk, a partial one,
+  an idle slot and a decode, interpreted kernels against the reference
+  lowerings at the same width and at full width.  The traced step holds no
+  ``[slots, chunk]``-ordered array of queries or of attended rows, which the
+  reference lowering at the same width does."""
   model, params, _ = wide
   B, C = 4, 16
   tokens = jax.random.randint(jax.random.PRNGKey(3), (2, B, C), 0, 256)
   num_valid = jnp.asarray([[C, 3, 0, 1], [1, C, 5, 0]], jnp.int32)
+  kv, cur = kv_lib.allocate_kv_cache(model.cfg, B, C)
+  trace = lambda impl: _step_shapes(jax.make_jaxpr(
+      lambda kv: slot_step_logits(
+          model, params, kv, tokens[0], cur, num_valid=num_valid[0], width=40,
+          kv_write_impl=impl, slot_attn_impl=impl, dsa_index_impl=impl,
+          moe_gmm_impl="reference"))(kv))
+  assert not trace("interpret") & _slot_ordered(B, C, model)
+  assert len(trace("reference") & _slot_ordered(B, C, model)) >= 4
   got = {}
   with jax.default_matmul_precision("highest"):
     for name, impl, width in (("flat", "interpret", 40),
@@ -475,16 +513,43 @@ def test_a_launch_with_nothing_to_do_overwrites_nothing(form, nv):
   """A tiled chunk is served by two launches into one buffer (the slots
   that feed several positions, the decoding slots); a launch whose kind of
   slot is absent still visits one tile, which must leave what the other
-  launch wrote there as it is."""
+  launch wrote there as it is.  In ``[slots, chunk]`` order and from the
+  flat batch, whose result lies where its queries do and is zeros in the
+  rows no position lives in."""
+  case = _attend_case(form, nv, [0, 125, 40, 200])
+  B, C, want, nv = case["B"], case["C"], case["want"], np.asarray(nv)
+  got = case["pallas"](case["q"])
+  for b in range(B):
+    n = int(nv[b])
+    np.testing.assert_allclose(np.asarray(got[b, :n]), np.asarray(want[b, :n]),
+                               rtol=1e-5, atol=1e-5)
+    assert np.all(np.asarray(got[b, n:]) == 0)
+  # (a flat batch holds a tile of positions at least: 8 rows)
+  flat, starts, live = _packed(case["q"], nv, rows=sum(nv) + 9)
+  got = np.asarray(case["pallas"](flat, starts=starts, chunk=C))
+  assert got.shape == (sum(nv) + 9,) + want.shape[2:]
+  np.testing.assert_allclose(got[:sum(nv)], np.asarray(want)[live],
+                             rtol=1e-5, atol=1e-5)
+  assert np.all(got[sum(nv):] == 0)
+
+
+def _attend_case(form, nv, cursors, C=16):
+  """Operands of one attend of either one-leaf form over ``len(nv)`` slots
+  (float32, 8 heads of 48 on values of 32, leaves of 256 rows), the
+  reference's result in ``[slots, chunk]`` order, the jitted kernel
+  (interpreted) and the operands :func:`sa._tile_launch` takes after
+  ``(q, starts, leaf, cur, bound, into, C, feeds)``."""
   rng = np.random.default_rng(8)
-  B, C, H, W, r, L = 4, 16, 8, 48, 32, 256
+  B, H, W, r, L = len(nv), 8, 48, 32, 256
   q = jnp.asarray(rng.normal(size=(B, C, H, W)), jnp.float32)
   leaf = jnp.asarray(rng.normal(size=(B, L, 1, W)), jnp.float32)
-  cur, nv = jnp.asarray([0, 125, 40, 200], jnp.int32), jnp.asarray(nv)
+  cur, nv = jnp.asarray(cursors, jnp.int32), jnp.asarray(nv, jnp.int32)
   if form == "win":
-    args, kw = (q, leaf, cur, nv), dict(window=133, v_width=r, scale=0.2)
-    want = sa.slot_attention_window(*args, impl="reference", **kw)
-    got = sa.slot_attention_window_pallas(*args, interpret=True, **kw)
+    kw = dict(window=133, v_width=r, scale=0.2)
+    want = sa.slot_attention_window(q, leaf, cur, nv, impl="reference", **kw)
+    pallas = lambda q, **flat: sa.slot_attention_window_pallas(
+        q, leaf, cur, nv, interpret=True, **flat, **kw)
+    scores = thr = None
   else:
     t = np.asarray(cur)[:, None] + np.arange(C)[None]
     scores = jnp.where(jnp.arange(L)[None, None] <= t[..., None],
@@ -493,14 +558,117 @@ def test_a_launch_with_nothing_to_do_overwrites_nothing(form, nv):
     thr = di.kth_largest(scores.reshape(B * C, L),
                          jnp.clip(jnp.asarray(t).reshape(-1) + 1, 1, 16)
                          ).reshape(B, C)
-    args, kw = (q, leaf, scores, thr, cur, nv), dict(v_width=r, scale=0.2)
-    want = sa.slot_attention_selected(*args, impl="reference", **kw)
-    got = sa.slot_attention_selected_pallas(*args, interpret=True, **kw)
-  for b in range(B):
-    n = int(nv[b])
-    np.testing.assert_allclose(np.asarray(got[b, :n]), np.asarray(want[b, :n]),
+    kw = dict(v_width=r, scale=0.2)
+    want = sa.slot_attention_selected(q, leaf, scores, thr, cur, nv,
+                                      impl="reference", **kw)
+    pallas = lambda q, **flat: sa.slot_attention_selected_pallas(
+        q, leaf, scores, thr, cur, nv, interpret=True, **flat, **kw)
+  launch = lambda q, starts, into, C_, feeds: sa._tile_launch(
+      q, starts, leaf, cur, jnp.where(nv > 0, cur + nv, 0), into, C_, feeds,
+      *((None, None) if scores is None else
+        (scores[:, :C_], thr[:, :C_])),
+      window=kw.get("window"), interpret=True, block=None, v_width=r,
+      scale=0.2)
+  return dict(B=B, C=C, q=q, want=want, pallas=pallas, launch=launch)
+
+
+def _packed(q, nv, rows):
+  """``q`` ``[B, C, ..]`` as a flat batch of ``rows`` rows, each slot's
+  first ``nv[b]`` positions in slot order and zeros after them; the flat
+  row of each slot's first position; the ``[B, C]`` mask that picks the
+  live positions in that order."""
+  nv = np.asarray(nv)
+  live = np.arange(q.shape[1])[None] < nv[:, None]
+  flat = np.zeros((rows,) + q.shape[2:], np.float32)
+  flat[:nv.sum()] = np.asarray(q)[live]
+  return (jnp.asarray(flat), jnp.asarray(np.cumsum(nv) - nv, jnp.int32), live)
+
+
+SENTINEL = -7.0
+
+
+@pytest.mark.parametrize("form", ["sel", "win"])
+def test_a_tile_writes_its_live_positions_and_no_other_row(form):
+  """The flat output handed to a launch full of a sentinel: slots that feed
+  1, 7, 8, 9, a whole chunk of 16 and 0 positions beside each other and a
+  last slot of 5 whose one tile starts within a tile of the batch's last
+  row (it is read from ``T - 8`` on and worked ``shift`` rows down).  The
+  launch over the slots that feed several positions writes their live rows
+  and leaves the decoding slot's row, the rows after a partial last tile
+  (the NEXT slot's) and the batch's padding rows as they were; the decoding
+  slots' launch then fills that one row."""
+  nv = np.asarray([1, 7, 8, 9, 16, 0, 5])
+  case = _attend_case(form, nv, [200, 0, 125, 40, 3, 77, 230])
+  T = nv.sum() + 2
+  flat, starts, live = _packed(case["q"], nv, rows=T)
+  assert int(starts[-1]) > T - 8 and int(starts[-1]) + 5 < T
+  want = np.asarray(case["want"])[live]
+  many, one = sa.split_decodes(jnp.asarray(nv), case["C"])
+  into = jnp.full((T, 8, 32), SENTINEL, jnp.float32)
+  got = np.asarray(case["launch"](flat, starts, into, case["C"], many))
+  assert np.all(got[0] == SENTINEL)            # the decoding slot's row
+  assert np.all(got[nv.sum():] == SENTINEL)    # the padding rows
+  np.testing.assert_allclose(got[1:nv.sum()], want[1:], rtol=1e-5, atol=1e-5)
+  got = np.asarray(case["launch"](flat, starts, jnp.asarray(got), 1, one))
+  np.testing.assert_allclose(got[:nv.sum()], want, rtol=1e-5, atol=1e-5)
+  assert np.all(got[nv.sum():] == SENTINEL)
+
+
+@pytest.mark.parametrize("form", ["sel", "win"])
+@pytest.mark.parametrize("nv", [[1, 9], [9, 1], [1, 9, 1], [16, 1, 0, 3]],
+                         ids=["decode-first", "decode-last", "between",
+                              "idle-between"])
+def test_the_two_launches_land_beside_each_other(form, nv):
+  """The decoding slots' launch and the other's write disjoint rows of the
+  flat output (or, where one has nothing to do and visits a tile all the
+  same, what the other writes there), whichever slot comes first in the
+  batch and whichever launch runs first: nothing a launch wrote is
+  written over, every other row keeps the sentinel."""
+  nv = np.asarray(nv)
+  case = _attend_case(form, nv, [200, 0, 125, 40][:len(nv)])
+  T = nv.sum() + 1
+  flat, starts, live = _packed(case["q"], nv, rows=T)
+  many, one = sa.split_decodes(jnp.asarray(nv), case["C"])
+  launch = jax.jit(case["launch"], static_argnums=3)
+  launches = [(case["C"], many), (1, one)]
+  for order in (launches, launches[::-1]):
+    out = jnp.full((T, 8, 32), SENTINEL, jnp.float32)
+    for C_, feeds in order:
+      out = launch(flat, starts, out, C_, feeds)
+    np.testing.assert_allclose(np.asarray(out)[:nv.sum()],
+                               np.asarray(case["want"])[live],
                                rtol=1e-5, atol=1e-5)
-    assert np.all(np.asarray(got[b, n:]) == 0)
+    assert np.all(np.asarray(out)[nv.sum():] == SENTINEL)
+
+
+def test_the_pair_form_takes_and_gives_what_it_did():
+  """``slot_attn_kvwin`` keeps its ``[slots, chunk, heads x hd]`` operands
+  and output (an offset in rows into a rank-2 flat batch is one Mosaic
+  cannot take): two launches, the decoding slots' first, each handed the
+  output buffer, the stacked rows' positions, ``q`` and the two rings, and
+  blocks of the output a tile."""
+  B, C, H, Hkv, hd, R = 4, 16, 8, 2, 128, 256
+  q = jnp.zeros((B, C, H, hd), jnp.float32)
+  ring = jnp.zeros((B, R, Hkv * hd), jnp.float32)
+  closed = jax.make_jaxpr(lambda *a: sa.slot_attention_kv_window_pallas(
+      *a, interpret=True, window=133, scale=0.1))(
+          q, ring, ring, jnp.zeros((B,), jnp.int32),
+          jnp.full((B,), C, jnp.int32))
+  (inner,) = [e for e in closed.jaxpr.eqns if e.primitive.name in (
+      "pjit", "jit")]
+  calls = [e for e in inner.params["jaxpr"].jaxpr.eqns
+           if e.primitive.name == "pallas_call"]
+  rows = lambda tp: Hkv * sa._pair_rows(tp, H // Hkv, jnp.float32)
+  assert [sa.pair_tile_positions(c, H) for c in (1, C)] == [1, C]
+  for call, tp in zip(calls, (1, C), strict=True):
+    assert call.params["name"] == sa.SLOT_ATTN_KVWIN
+    assert [tuple(v.aval.shape) for v in call.invars[-5:]] == [
+        (B, C, H * hd), (rows(tp), 1), (B, C, H * hd), (B, R, Hkv * hd),
+        (B, R, Hkv * hd)]
+    assert [tuple(v.aval.shape) for v in call.outvars] == [(B, C, H * hd)]
+    out_block = call.params["grid_mapping"].block_mappings[-1].block_shape
+    assert tuple(int(getattr(d, "block_size", d)) for d in out_block) == (
+        1, 8 if tp == 1 else C, H * hd)
 
 
 def test_the_cells_leaves_fit_the_kernels(monkeypatch):
@@ -661,6 +829,34 @@ def test_engine_commits_the_same_under_the_interpreted_kernels(monkeypatch,
   for uid, toks in plain.items():
     np.testing.assert_array_equal(np.asarray(out[uid]), np.asarray(toks))
   _teacher_forced(WIDE_CFG, rp, out)
+
+
+def test_narrow_and_wide_steps_commit_the_reference_lowerings_tokens(
+    monkeypatch, wide):
+  """One engine whose flat batch is narrower than its ``slots x chunk``
+  positions and has a second width (40 and 16 rows of 4 x 16, named here:
+  the rule gives so few positions their full width): the attends read and
+  write the flat batch where it lies (``tile_attn_out`` ``flat``) on steps
+  that take the narrow side of the layers' conditionals and on steps that
+  take the wide one, and the engine commits what it commits under the
+  reference lowerings, which move rows to ``[slots, chunk]`` and back."""
+  model, params, rp = wide
+  monkeypatch.setattr(engine_lib, "flat_width", lambda slots, chunk: 40)
+  monkeypatch.setattr(engine_lib, "narrow_width", lambda width, slots: 16)
+  outs = {}
+  for impl, form in (("reference", "slots"), ("interpret", "flat")):
+    _backend_takes(monkeypatch, impl)
+    stats = ServingStats()
+    eng, outs[impl] = _serve(model, params, slots=4, chunk=16, stats=stats)
+    assert (eng.flat_width, eng.flat_narrow) == (40, 16)
+    assert eng.lowerings["slot_attn_impl"] == impl
+    assert eng.lowerings["tile_attn_out"] == form
+    assert 0 < stats.flat_narrow_steps < stats.steps
+    assert eng._step_fn._cache_size() == 1
+  for uid, toks in outs["reference"].items():
+    np.testing.assert_array_equal(np.asarray(outs["interpret"][uid]),
+                                  np.asarray(toks))
+  _teacher_forced(WIDE_CFG, rp, outs["interpret"])
 
 
 def test_the_window_leaves_do_not_grow_with_the_served_context(both):

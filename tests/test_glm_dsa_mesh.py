@@ -19,6 +19,8 @@ score or the 2nd expert.  The divided layer against the one-chip layer
 sums the same float32 terms, a round's at a time: ``1e-5`` absolute.
 """
 
+import dataclasses
+import importlib
 import os
 import sys
 
@@ -36,7 +38,8 @@ from easyparallellibrary_tpu.models.layer_kinds import SPARSE_LATENT  # noqa: E4
 from easyparallellibrary_tpu.models.slot_core import slot_step_logits  # noqa: E402
 from easyparallellibrary_tpu.profiler.serving import ServingStats  # noqa: E402
 from easyparallellibrary_tpu.serving import (  # noqa: E402
-    ContinuousBatchingEngine, Request, kv_cache as kv_lib)
+    ContinuousBatchingEngine, Request, engine as engine_lib,
+    kv_cache as kv_lib)
 from easyparallellibrary_tpu.serving._capabilities import ROADMAP_DIVIDED  # noqa: E402
 from easyparallellibrary_tpu.serving.scheduler import FCFSScheduler  # noqa: E402
 from easyparallellibrary_tpu.utils.compat import shard_map  # noqa: E402
@@ -338,6 +341,42 @@ def test_divided_engine_emits_the_one_chip_engines_tokens(both, slots, chunk,
   if slots == 128:
     assert eng.flat_narrow < eng.flat_width
     assert 0 < stats.flat_narrow_steps < stats.steps
+
+
+def test_divided_engine_attends_the_flat_batch_where_it_lies(monkeypatch):
+  """The selected attend's flat form under the divided step's ``shard_map``:
+  an engine on two chips, four slots x chunk 16 a chip on a flat batch of
+  40 rows with a second width of 16 (named here: the rule gives so few
+  positions their full width), at widths the interpreted kernels take (8
+  heads, an index key of one lane tile).  Each chip's attends read and
+  write its own flat batch where it lies (``tile_attn_out`` ``flat``), on
+  narrow steps and on wide ones, and the host commits what it commits
+  under the reference lowerings, which move rows to ``[slots, chunk]`` and
+  back."""
+  epl.init()
+  cfg = dataclasses.replace(REF_CFG, heads=8, index_head_dim=128)
+  model, shell_of = glue.build_model(cfg, F32)
+  params = glue.program_params(
+      cfg, ref.seed_key(2 ** 31 + 47), shell_of(jnp.zeros((1, 8), jnp.int32)))
+  monkeypatch.setattr(engine_lib, "flat_width", lambda slots, chunk: 40)
+  monkeypatch.setattr(engine_lib, "narrow_width", lambda width, slots: 16)
+  reqs = _requests(10, hi=50)
+  got = {}
+  for impl, form in (("reference", "slots"), ("interpret", "flat")):
+    for mod in ("kv_write", "slot_attention", "dsa_index", "moe_gmm"):
+      monkeypatch.setattr(
+          importlib.import_module(f"easyparallellibrary_tpu.kernels.{mod}"),
+          "_backend_impl", lambda: impl)
+    stats = ServingStats()
+    with jax.default_matmul_precision("highest"):
+      got[impl], eng = _serve(model, params, reqs, 8, 16, _mesh(2), stats)
+    assert eng.slot_axis == ("expert", 2)
+    assert (eng.flat_width, eng.flat_narrow) == (40, 16)
+    assert eng.lowerings["slot_attn_impl"] == impl
+    assert eng.lowerings["tile_attn_out"] == form
+    assert 0 < stats.flat_narrow_steps < stats.steps
+  for uid, toks in got["reference"].items():
+    np.testing.assert_array_equal(got["interpret"][uid], toks, str(uid))
 
 
 def test_divided_engine_records_what_it_is(both):

@@ -916,6 +916,16 @@ def test_dots3_step_compiled_for_v5e_holds_its_kernels(one_chip):
       assert m.group(3) in ("parameter", "bitcast", "get-tuple-element",
                             "custom-call"), line
   assert not re.search(rf"\[{slots},{C},64,12832\]", text)
+  # The two attends read and write the flat batch of 512 rows where it lies
+  # (PR 48): their result is ``[T, H, r]``, no ``[slots, chunk x H, r]``
+  # buffer in either split of its rows exists, and the queries are not
+  # padded by a tile of rows.
+  T = 512
+  for H, r, W in ((128, 512, 576), (64, 1024, 1088)):
+    assert f"bf16[{T},{H},{r}]" in text
+    assert not re.search(
+        rf"bf16\[{slots},{C * H},{r}\]|bf16\[{slots},{C},{H},{r}\]"
+        rf"|bf16\[{slots * C // 8},8,{H},{r}\]|bf16\[{T + 8},{H},{W}\]", text)
 
 
 def test_smallthinker_step_compiled_for_v5e_holds_its_kernels(one_chip):

@@ -1,9 +1,13 @@
 """One record of what a fused step was lowered to
 (``kv_cache.step_lowerings``): the engine holds it as ONE mapping, records
 its resolved entries as trace metadata under their own names and hands it
-whole to the diagnostic bundle, for every served family alike."""
+whole to the diagnostic bundle, for every served family alike.  Its last
+entry, ``tile_attn_out``, is no lowering but what follows from them: where
+the attends of a model that selects its rows or sits behind a latent window
+read their queries and write their result."""
 
 import importlib
+import logging
 import os
 import sys
 
@@ -19,9 +23,10 @@ from easyparallellibrary_tpu.observability import trace as trace_lib  # noqa: E4
 from easyparallellibrary_tpu.serving import (  # noqa: E402
     ContinuousBatchingEngine, kv_cache as kv_lib)
 
-NAMES = ["kv_write_impl", "slot_attn_impl", "kv_win_write_impl",
-         "kv_win_attn_impl", "dsa_index_impl", "ssm_scan_impl",
-         "moe_gmm_impl"]
+LOWERINGS = ["kv_write_impl", "slot_attn_impl", "kv_win_write_impl",
+             "kv_win_attn_impl", "dsa_index_impl", "ssm_scan_impl",
+             "moe_gmm_impl"]
+NAMES = LOWERINGS + ["tile_attn_out"]
 # A family's tiny configuration is its own test file's (``REF_CFG``
 # through the benchmark's glue; GPT's is built here) and what its rules
 # resolve: the K/V pair's two and those of its own kinds of layer.
@@ -36,10 +41,16 @@ FAMILIES = {
     "lfm2_moe": ("test_lfm2_moe", "epl_lfm2_moe", KV | {"moe_gmm_impl"}),
     "dots3_note": ("test_dots3_note", "epl_dots3_note",
                    KV | {"dsa_index_impl", "moe_gmm_impl"}),
+    "glm_moe_dsa": ("test_glm_dsa_mesh", "epl_glm_moe_dsa",
+                    KV | {"dsa_index_impl", "moe_gmm_impl"}),
     "smallthinker": ("test_smallthinker", "epl_smallthinker",
                      KV | {"kv_win_write_impl", "kv_win_attn_impl",
                            "moe_gmm_impl"}),
 }
+# The two whose layers select their rows or sit behind a latent window: at
+# the full width a toy engine has (and under the reference lowerings) their
+# attends take ``[slots, chunk]``-ordered operands.
+TILE_FORMS = {"dots3_note", "glm_moe_dsa"}
 
 
 def _build(family):
@@ -60,22 +71,40 @@ def _build(family):
 
 
 @pytest.mark.parametrize("family", list(FAMILIES))
-def test_the_engine_keeps_one_record_and_says_it_three_ways(family):
+def test_the_engine_keeps_one_record_and_says_it_three_ways(family, caplog):
   model, params = _build(family)
   want = FAMILIES[family][2]
   paged = family == "gpt-paged"
+  from easyparallellibrary_tpu.utils.logging import get_logger
+  logger = get_logger()
+  propagated = logger.propagate
   tracer = trace_lib.install(trace_lib.Tracer(enabled=True))
   try:
-    eng = ContinuousBatchingEngine(
-        model, params, num_slots=2, prefill_chunk=4,
-        **(dict(paged=True, block_size=8) if paged else {}))
+    logger.propagate = True  # the repo logger is handler-only by default
+    with caplog.at_level(logging.INFO, logger=logger.name):
+      eng = ContinuousBatchingEngine(
+          model, params, num_slots=2, prefill_chunk=4,
+          **(dict(paged=True, block_size=8) if paged else {}))
     events = tracer.events()
   finally:
     trace_lib.install(None)
+    logger.propagate = propagated
   assert list(eng.lowerings) == NAMES
   assert eng.lowerings == (dict.fromkeys(NAMES) if paged else
                            kv_lib.step_lowerings(model.cfg, 2, 4))
   assert kv_lib.resolved(eng.lowerings) == dict.fromkeys(want, "reference")
+  out = "slots" if family in TILE_FORMS else None
+  assert eng.lowerings["tile_attn_out"] == out
+  said = dict(dict.fromkeys(want, "reference"),
+              **({} if out is None else {"tile_attn_out": out}))
+  assert kv_lib.recorded(eng.lowerings) == said
+  # The start-up line names every entry that says something, in the
+  # record's order.
+  (line,) = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("serving engine:")]
+  if not paged:
+    assert ", ".join(f"{name} {said[name]}" for name in NAMES
+                     if name in said) in line
   # The trace: one metadata event a resolved lowering, under its own name,
   # beside the ones that are no lowerings.
   meta = {ev["name"]: ev["args"] for ev in events
@@ -83,10 +112,45 @@ def test_the_engine_keeps_one_record_and_says_it_three_ways(family):
   others = {"serving/step_overlap"} | (set() if paged else {
       "serving/cache_layout", "serving/flat_width"}) | (
           {"serving/experts_held"} if eng.experts_held is not None else set())
-  assert set(meta) == {f"serving/{name}" for name in want} | others
-  assert all(meta[f"serving/{name}"] == {"impl": "reference"}
-             for name in want)
-  # The diagnostic bundle: the same seven keys with the same values.
+  assert set(meta) == {f"serving/{name}" for name in said} | others
+  assert all(meta[f"serving/{name}"] == {"impl": impl}
+             for name, impl in said.items())
+  # The diagnostic bundle: the same eight keys with the same values.
   bundle = eng._capture_context()["serving"]
   assert {name: bundle[name] for name in NAMES} == eng.lowerings
   eng.close()
+
+
+@pytest.mark.parametrize("family", sorted(TILE_FORMS))
+@pytest.mark.parametrize("backend,width,out", [
+    ("pallas", 512, "flat"), ("interpret", 512, "flat"),
+    # every position of every slot: the map is a reshape
+    ("pallas", 1024, "slots"), ("pallas", None, "slots"),
+    # the reference lowering takes [slots, chunk] order at any width
+    ("reference", 512, "slots")])
+def test_the_flat_form_follows_the_kernel_and_the_width(monkeypatch, family,
+                                                        backend, width, out):
+  """``tile_attn_out`` at a serving cell's geometry (32 slots x chunk 32 a
+  chip, 512 rows): ``flat`` exactly where the tile kernels were resolved
+  and the flat batch is narrower than ``slots x chunk``; what the mixer
+  asks (kernels/slot_attention.py:tile_attn_out) is what the record says."""
+  from easyparallellibrary_tpu.kernels import slot_attention
+  for mod in ("kv_write", "slot_attention", "dsa_index", "moe_gmm"):
+    monkeypatch.setattr(
+        importlib.import_module(f"easyparallellibrary_tpu.kernels.{mod}"),
+        "_backend_impl", lambda: backend)
+  # The decoder as its serving cell builds it, at the published widths.
+  from perfbench.harness import manifest as manifest_lib
+  man = manifest_lib.Manifest(os.path.join(os.path.dirname(__file__), ".."))
+  cell = {"dots3_note": "dots3note-longdoc-backlog",
+          "glm_moe_dsa": "glm5-agentctx-backlog-4chip"}[family]
+  glue = importlib.import_module(f"perfbench.runners.epl_{family}")
+  epl.init()
+  cfg = glue.build_model(
+      glue.ref_config(man.config_file(man.workload(cell)["config"])),
+      man.cell_file(cell)["model"])[0].cfg
+  record = kv_lib.step_lowerings(cfg, 32, 32, width=width)
+  assert record["slot_attn_impl"] == backend
+  assert record["tile_attn_out"] == out == slot_attention.tile_attn_out(
+      backend, width is not None and width < 32 * 32)
+  assert "tile_attn_out" not in kv_lib.resolved(record)
