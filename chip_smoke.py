@@ -80,6 +80,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import functools
+import importlib
 import json
 import re
 import sys
@@ -102,6 +103,9 @@ from easyparallellibrary_tpu.kernels.slot_attention import (
 from easyparallellibrary_tpu.kernels.ssm_scan import (
     SSM_SCAN, ssm_scan_pallas, ssm_scan_reference)
 from easyparallellibrary_tpu.kernels import dsa_index as dsa_lib
+# the package's ``flash_attention`` is the function: the module by its name
+fa = importlib.import_module(
+    "easyparallellibrary_tpu.kernels.flash_attention")
 from easyparallellibrary_tpu.kernels import slot_attention as slot_attn_lib
 from easyparallellibrary_tpu.models import GPT, GPTConfig
 from easyparallellibrary_tpu.models.dots3_note import (
@@ -283,7 +287,7 @@ class Sizes:
         rehearsal=True, train_cfg=train, batch_candidates=(4,),
         serve_cfg=serve, cut_cfg=serve, prompt_lens=(8, 20, 40, 64),
         new_tokens=8,
-        flash_shapes=((1, 2, 128, 32, jnp.float32),
+        flash_shapes=((1, 4, 128, 32, jnp.float32),   # rows: four heads a tile
                       (1, 1, 256, 32, jnp.float32)),
         paged_shape=(6, 4, 32, 8, 4),
         kv_shapes=((4, 136, 4, 4, 32, 8), (4, 136, 4, 1, 32, 8)),
@@ -362,19 +366,36 @@ def compile_here(fn, *args, mosaic_calls: int, rehearsal: bool):
 
 
 def check_flash(B, H, S, D, dtype, rehearsal: bool) -> None:
+  """The flash kernels against the dense float32 reference, through every
+  form this shape can take: the entry as the rule lays it out
+  (``flash_layout``: rows where the heads fill lane tiles and a head is
+  resident), and where that is ``rows`` also the fused projection's one
+  ``[B, S, 3 x H x D]`` operand (the train cell's call) and the head-major
+  kernels behind their transposes (ring attention's primitives)."""
   r = np.random.RandomState(S)
   q, k, v, dout = (jnp.asarray(r.randn(B, S, H, D), dtype)
                    for _ in range(4))
+  itemsize = jnp.dtype(dtype).itemsize
+  layout = fa.flash_layout(S, H, D, itemsize)
+  bq = fa._default_block(S, d=D, itemsize=itemsize)
 
   def fwd_bwd(attend, q, k, v, dout):
     out, vjp = jax.vjp(attend, q, k, v)
     return (out,) + vjp(dout.astype(out.dtype))
 
-  kernel = compile_here(
-      functools.partial(fwd_bwd, functools.partial(flash_attention,
-                                                   causal=True)),
-      q, k, v, dout, mosaic_calls=3, rehearsal=rehearsal)
-  got = kernel(q, k, v, dout)
+  def from_qkv(q, k, v):
+    cut = lambda x: x.reshape(B, S, H * D)
+    qkv = jnp.concatenate([cut(q), cut(k), cut(v)], axis=-1)
+    return fa.flash_attention_qkv(qkv, H, causal=True).reshape(B, S, H, D)
+
+  def head_major(q, k, v):
+    t = lambda x: x.transpose(0, 2, 1, 3)
+    return t(fa._flash(t(q), t(k), t(v), True, bq, bq))
+
+  forms = {layout: functools.partial(flash_attention, causal=True)}
+  if fa.flash_layout(S, H, D, itemsize, fused=True) == "rows":
+    forms["rows of one qkv"] = from_qkv
+  forms.setdefault("heads", head_major)
   # The reference sees the same (storage-dtype) values in float32, so
   # the difference is the kernel's own error, not the reference's.
   f32 = [x.astype(jnp.float32) for x in (q, k, v, dout)]
@@ -383,17 +404,22 @@ def check_flash(B, H, S, D, dtype, rehearsal: bool) -> None:
         fwd_bwd, lambda q, k, v: _dense_causal_attention(
             q, k, v, jnp.float32)))(*f32)
   tol = 2e-2 if dtype == jnp.bfloat16 else 5e-4
-  errs = {}
-  for name, g, w in zip(("out", "dq", "dk", "dv"), got, ref):
-    check(bool(jnp.isfinite(g.astype(jnp.float32)).all()),
-          f"flash {name} not finite at {(B, H, S, D)}")
-    errs[name] = rel_err(g, w)
-    check(errs[name] <= tol,
-          f"flash {name} at {(B, H, S, D)} {jnp.dtype(dtype).name}: "
-          f"error {errs[name]:.3g} of the reference's max, tol {tol}")
-  say(f"  flash B{B} H{H} S{S} D{D} {jnp.dtype(dtype).name}: "
-      + " ".join(f"{n} {e:.2e}" for n, e in errs.items())
-      + f" (tol {tol})")
+  for form, attend in forms.items():
+    kernel = compile_here(functools.partial(fwd_bwd, attend), q, k, v, dout,
+                          mosaic_calls=3, rehearsal=rehearsal)
+    got = kernel(q, k, v, dout)
+    errs = {}
+    for name, g, w in zip(("out", "dq", "dk", "dv"), got, ref):
+      check(bool(jnp.isfinite(g.astype(jnp.float32)).all()),
+            f"flash {name} in {form} not finite at {(B, H, S, D)}")
+      errs[name] = rel_err(g, w)
+      check(errs[name] <= tol,
+            f"flash {name} in {form} at {(B, H, S, D)} "
+            f"{jnp.dtype(dtype).name}: error {errs[name]:.3g} of the "
+            f"reference's max, tol {tol}")
+    say(f"  flash B{B} H{H} S{S} D{D} {jnp.dtype(dtype).name} in {form}: "
+        + " ".join(f"{n} {e:.2e}" for n, e in errs.items())
+        + f" (tol {tol})")
 
 
 def check_paged(T, H, hd, bs, MB, dtype, rehearsal: bool) -> None:
@@ -977,13 +1003,23 @@ def phase_four_chips(sizes: Sizes) -> None:
         say(f"  peak HBM GiB on chip {d.id}: "
             f"{peak_hbm(d, tree_bytes(state) // 4)}")
       B, dp, mp = batch["ids"].shape[0], (2 if tp else 4), (2 if tp else 1)
-      want = (f"bf16[{B // dp},{cfg.num_heads // mp},"
-              f"{cfg.max_seq_len},{cfg.d_model // cfg.num_heads}]")
+      b, h, S = B // dp, cfg.num_heads // mp, cfg.max_seq_len
+      hd = cfg.d_model // cfg.num_heads
+      layout = fa.flash_layout(S, h, hd, jnp.dtype(cfg.dtype).itemsize,
+                               fused=mp == 1)
+      if layout == "rows":
+        # a chip's heads side by side; with every head on the chip q, k
+        # and v are the fused projection's one array
+        want = {f"bf16[{b},{S},{h * hd}]"}
+        if mp == 1:
+          want.add(f"bf16[{b},{S},{3 * h * hd}]")
+      else:
+        want = {f"bf16[{b},{h},{S},{hd}]"}
       operands = flash_call_operands(hlo)
-      say(f"  flash custom-call operands: {operands}")
-      check(all(op == want for ops in operands for op in ops
+      say(f"  flash custom-call operands in {layout}: {operands}")
+      check(all(op in want for ops in operands for op in ops
                 if op.startswith("bf16")),
-            f"flash operands are not the per-chip shard {want}")
+            f"flash operands are not the per-chip shard {sorted(want)}")
       gathers = sorted(set(re.findall(
           r"= (\S+?)\{[^ ]* all-gather(?:-start)?\(", hlo)))
       say(f"  all-gather results in the step: {gathers or 'none'}")

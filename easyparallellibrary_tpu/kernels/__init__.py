@@ -1,4 +1,5 @@
-from easyparallellibrary_tpu.kernels.flash_attention import flash_attention
+from easyparallellibrary_tpu.kernels.flash_attention import (
+    flash_attention, flash_attention_qkv)
 from easyparallellibrary_tpu.kernels.dsa_index import (
     dsa_index_pallas, dsa_index_reference)
 from easyparallellibrary_tpu.kernels.kv_write import (
@@ -16,7 +17,7 @@ from easyparallellibrary_tpu.kernels.paged_attention import (
 
 __all__ = [
     "dsa_index_pallas", "dsa_index_reference",
-    "flash_attention",
+    "flash_attention", "flash_attention_qkv",
     "kv_write_pallas", "kv_write_reference",
     "moe_gmm_pallas", "moe_gmm_reference",
     "paged_attention", "paged_attention_pallas",

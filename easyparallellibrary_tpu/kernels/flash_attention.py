@@ -14,7 +14,19 @@ Two implementations per kernel, dispatched by sequence length:
 
 * **resident** (short S): a grid step owns a whole head, with q, k, v,
   dO, o, lse and delta of the head in VMEM, and walks its ``[tq, tk]``
-  tiles in two nested loops.  Under a causal mask a row tile walks the
+  tiles in two nested loops.  Where heads of ``D`` lanes pack whole
+  128-lane tiles (:func:`flash_layout`: ``D`` 128, 64 or 32 and a head
+  count that divides) the operands stay in ROWS, ``[B, S, H x D]``, the
+  layout the ``qkv`` and ``proj`` matmuls write and read, and a grid
+  step owns the ``[S, 128]`` of the heads that share a tile (a pair at
+  ``D`` 64), walking them one after the other: each head's products
+  contract over the whole tile with the other heads' lanes of one
+  operand zeroed, so every load, store and matmul operand is a full
+  tile and nothing is shuffled between lanes.  ``[B, S, H, D]`` is the
+  same bytes, so nothing is transposed; q, k and v may be the three
+  column blocks of ONE fused projection's output
+  (:func:`flash_attention_qkv`).  Elsewhere the operands are head-major,
+  ``[B, H, S, D]``, behind a transpose either way.  Under a causal mask a row tile walks the
   key tiles wholly under the diagonal with no mask arithmetic, then the
   tile or tiles the diagonal crosses with one compare and one select;
   tiles beyond it are never touched (:func:`causal_tile_counts`).  The
@@ -46,8 +58,14 @@ grid's steps.  At D 64 every product fills half the MXU, and the backward
 kernels are bound by it.  The crossover (``_RESIDENT_MAX_BYTES``) is the
 VMEM wall: streaming is the only option past it.
 
+Rows against head-major at that shape (PERF.md section 6, PR 49): XLA
+keeps an array whose minor dimension is 64 position-minor (a 64-wide
+minor dimension pads its 128 lanes) and a Mosaic call demands row-major,
+so every head-major operand and result of the three kernels cost a
+relayout copy, 13 a layer of the four-chip train step, again under remat.
+
 Used by models via ``attn_impl="pallas_flash"`` and as the local block of
-ring attention.  Off-TPU the kernels run in Pallas interpreter mode so
+ring attention (head-major primitives ``_fwd`` / ``_bwd_kernels``).  Off-TPU the kernels run in Pallas interpreter mode so
 tests exercise identical code paths on CPU.
 """
 
@@ -68,8 +86,10 @@ from jax.sharding import PartitionSpec as P
 
 from easyparallellibrary_tpu import constants
 from easyparallellibrary_tpu.env import Env
+from easyparallellibrary_tpu.observability import trace as trace_lib
 from easyparallellibrary_tpu.utils.compat import (
     ambient_manual_axes, shard_map)
+from easyparallellibrary_tpu.utils.sharding import constrain
 
 NEG_INF = -1e30
 
@@ -227,155 +247,236 @@ def _head_block(rows: int, cols: int):
   return pl.BlockSpec((1, 1, rows, cols), lambda b, h: (b, h, 0, 0))
 
 
-def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+# The lanes of a vector tile: an array kept in ROWS, ``[B, S, H x D]`` as
+# the projections write and read it, is taken by the resident kernels in
+# blocks of ``[S, _LANES]``, the ``_LANES // D`` heads that share a tile.
+_LANES = 128
+# At most this many heads a tile: a grid step walks its heads one after the
+# other, unrolled, and eight heads of 16 lanes at S 1024 overflow VMEM
+# (18.2 MB of 16, refused at compile time for a described v5e).
+_TILE_HEADS = 4
+
+
+def _tile_block(rows: int, first: int = 0):
+  """The whole ``[rows, 128]`` of lane tile ``first + p`` of a ``[B, rows,
+  n x 128]`` array kept in rows, on the ``(B, lane tiles of a head group)``
+  grid.  ``first`` picks a column block of a wider array (q, k and v of one
+  fused projection's output)."""
+  return pl.BlockSpec((1, rows, _LANES), lambda b, p: (b, 0, first + p))
+
+
+def _group_block(heads: int, cols: int):
+  """The ``[heads, 8, cols]`` of lse / delta that belong to a lane tile's
+  heads: those stay ``[B, H, 8, S]`` under either layout."""
+  return pl.BlockSpec((1, heads, 8, cols), lambda b, p: (b, p, 0, 0))
+
+
+def _rows_at(ref, start, size: int):
+  """Index of rows ``[start, start + size)``, every lane, of a grid step's
+  block: one head's ``[1, 1, S, D]`` of a head-major array or one lane
+  tile's ``[1, S, 128]`` of an array kept in rows."""
+  return (0,) * (len(ref.shape) - 2) + (pl.ds(start, size), slice(None))
+
+
+def _head_lanes(lanes: int, d: int):
+  """``[1, lanes]`` int32, the head within the block that each lane
+  belongs to; None where the block is one head's."""
+  if lanes == d:
+    return None
+  lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+  return jax.lax.shift_right_logical(lane, d.bit_length() - 1)
+
+
+def _of_head(x, head_of, h: int, factor: float = 1.0):
+  """``x`` times ``factor`` in head ``h``'s lanes and zero in the other
+  heads' of the tile, in ``x``'s dtype: the operand of a product that
+  contracts over all the tile's lanes and sees one head.  The zeros add
+  nothing to a float32 sum, so a head's scores are what its own ``D``
+  lanes give; at ``D`` 64 a product already fills half the MXU's depth,
+  so the zeroed half costs no push.  A block of one head (``head_of``
+  None) is only scaled."""
+  if head_of is None:
+    return x if factor == 1.0 else (x * factor).astype(x.dtype)
+  keep = jnp.where(head_of == h, jnp.float32(factor), jnp.float32(0.0))
+  return (x.astype(jnp.float32) * keep).astype(x.dtype)
+
+
+def _into_head(whole, part, head_of, h: int):
+  """``whole`` with head ``h``'s lanes taken from ``part``: a product
+  whose right operand is the tile (``P V``, ``dS K``) fills every lane,
+  and only the head's own are its result."""
+  if head_of is None or whole is None:
+    return part
+  return jnp.where(head_of == h, part, whole)
+
+
+def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, d: int,
                          tq: int, tk: int, causal: bool, scale: float,
                          unroll: bool):
-  """One head a grid step: q, k, v, o and lse whole in VMEM, the
-  ``[tq, tk]`` tiles of the head walked by two nested loops."""
-  d = q_ref.shape[3]
-  num_q, num_k = q_ref.shape[2] // tq, k_ref.shape[2] // tk
+  """One block a grid step, a head's ``[S, D]`` or the ``[S, 128]`` of
+  the heads that share a lane tile: q, k, v, o and lse whole in VMEM, the
+  ``[tq, tk]`` tiles of each head walked by two nested loops."""
+  lanes = q_ref.shape[-1]
+  num_q, num_k = q_ref.shape[-2] // tq, k_ref.shape[-2] // tk
   fold = _scale_folds(scale, q_ref.dtype)
   rel = _rel_pos(tq, tk, 0) if causal else None
+  head_of = _head_lanes(lanes, d)
 
   def row_tile(i, carry):
     q0 = _tile_start(i, tq)
-    q = q_ref[0, 0, pl.ds(q0, tq), :]                      # [tq, D]
-    if fold:
-      q = (q * scale).astype(q.dtype)
+    q_rows = q_ref[_rows_at(q_ref, q0, tq)]                # [tq, lanes]
+    out, sums = None, []
+    for h in range(lanes // d):
+      q = _of_head(q_rows, head_of, h, scale if fold else 1.0)
 
-    def key_tile(j, carry, masked):
-      m, l, acc = carry
-      k0 = _tile_start(j, tk)
-      kblk = k_ref[0, 0, pl.ds(k0, tk), :]                 # [tk, D]
-      vblk = v_ref[0, 0, pl.ds(k0, tk), :]
-      s = _dot(q, kblk, _NT)                               # [tq, tk] fp32
-      if not fold:
-        s = s * scale
-      if masked:
-        s = jnp.where(rel >= k0 - q0, s, NEG_INF)
-      new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-      p = jnp.exp(s - new_m)
-      corr = jnp.exp(m - new_m)
-      l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-      acc = acc * corr + _dot(p.astype(vblk.dtype), vblk, _NN)
-      return new_m, l, acc
+      def key_tile(j, carry, masked):
+        m, l, acc = carry
+        k0 = _tile_start(j, tk)
+        kblk = k_ref[_rows_at(k_ref, k0, tk)]              # [tk, lanes]
+        vblk = v_ref[_rows_at(v_ref, k0, tk)]
+        s = _dot(q, kblk, _NT)                             # [tq, tk] fp32
+        if not fold:
+          s = s * scale
+        if masked:
+          s = jnp.where(rel >= k0 - q0, s, NEG_INF)
+        new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - new_m)
+        corr = jnp.exp(m - new_m)
+        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * corr + _dot(p.astype(vblk.dtype), vblk, _NN)
+        return new_m, l, acc
 
-    state = (jnp.full((tq, 1), NEG_INF, jnp.float32),
-             jnp.zeros((tq, 1), jnp.float32),
-             jnp.zeros((tq, d), jnp.float32))
-    full, live = _key_tiles(i, tq, tk, num_k, causal)
-    state = _walk(0, full, functools.partial(key_tile, masked=False), state,
-                  unroll)
-    state = _walk(full, live, functools.partial(key_tile, masked=True),
-                  state, unroll)
-    m, l, acc = state
+      state = (jnp.full((tq, 1), NEG_INF, jnp.float32),
+               jnp.zeros((tq, 1), jnp.float32),
+               jnp.zeros((tq, lanes), jnp.float32))
+      full, live = _key_tiles(i, tq, tk, num_k, causal)
+      state = _walk(0, full, functools.partial(key_tile, masked=False),
+                    state, unroll)
+      state = _walk(full, live, functools.partial(key_tile, masked=True),
+                    state, unroll)
+      m, l, acc = state
 
-    l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0, 0, pl.ds(q0, tq), :] = (acc / l_safe).astype(o_ref.dtype)
+      l_safe = jnp.maximum(l, 1e-30)
+      out = _into_head(out, acc / l_safe, head_of, h)
+      sums.append((m, l_safe))
+    o_ref[_rows_at(o_ref, q0, tq)] = out.astype(o_ref.dtype)
     # TPU tiling wants the last two dims (8, 128)-aligned, so the [tq]
     # logsumexp row is broadcast across 8 sublanes: lse is [B, H, 8, S].
-    lse = (m + jnp.log(l_safe))[:, 0]
-    lse_ref[0, 0, :, pl.ds(q0, tq)] = jnp.broadcast_to(lse[None, :],
-                                                       (8, tq))
+    for h, (m, l_safe) in enumerate(sums):
+      lse = (m + jnp.log(l_safe))[:, 0]
+      lse_ref[0, h, :, pl.ds(q0, tq)] = jnp.broadcast_to(lse[None, :],
+                                                         (8, tq))
     return carry
 
   _walk(0, num_q, row_tile, 0, unroll)
 
 
 def _bwd_dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                             delta_ref, dk_ref, dv_ref, *, tq: int,
+                             delta_ref, dk_ref, dv_ref, *, d: int, tq: int,
                              tk: int, causal: bool, scale: float,
                              unroll: bool):
-  """dK/dV of one head a grid step.  The score tile is built TRANSPOSED,
+  """dK/dV of one block a grid step.  The score tile is built TRANSPOSED,
   ``[tk, tq]`` = k q^T: both accumulating products (p^T dO, ds^T q) are
-  then plain ``[tk, tq] x [tq, D]`` matmuls with no transpose of a score
-  tile, and lse / delta broadcast along sublanes straight from the
+  then plain ``[tk, tq] x [tq, lanes]`` matmuls with no transpose of a
+  score tile, and lse / delta broadcast along sublanes straight from the
   ``[8, S]`` rows they are stored in."""
-  d = k_ref.shape[3]
-  num_q, num_k = q_ref.shape[2] // tq, k_ref.shape[2] // tk
+  lanes = k_ref.shape[-1]
+  num_q, num_k = q_ref.shape[-2] // tq, k_ref.shape[-2] // tk
   fold = _scale_folds(scale, k_ref.dtype)
   rel = _rel_pos(tk, tq, 1) if causal else None
+  head_of = _head_lanes(lanes, d)
 
   def key_tile(j, carry):
     k0 = _tile_start(j, tk)
-    kblk = k_ref[0, 0, pl.ds(k0, tk), :]                   # [tk, D]
-    vblk = v_ref[0, 0, pl.ds(k0, tk), :]
-    if fold:
-      kblk = (kblk * scale).astype(kblk.dtype)
+    k_rows = k_ref[_rows_at(k_ref, k0, tk)]                # [tk, lanes]
+    v_rows = v_ref[_rows_at(v_ref, k0, tk)]
+    dk_out = dv_out = None
+    for h in range(lanes // d):
+      kblk = _of_head(k_rows, head_of, h, scale if fold else 1.0)
+      vblk = _of_head(v_rows, head_of, h)
 
-    def row_tile(i, carry, masked):
-      dk, dv = carry
-      q0 = _tile_start(i, tq)
-      qblk = q_ref[0, 0, pl.ds(q0, tq), :]                 # [tq, D]
-      doblk = do_ref[0, 0, pl.ds(q0, tq), :]
-      lse = lse_ref[0, 0, 0:1, pl.ds(q0, tq)]              # [1, tq]
-      delta = delta_ref[0, 0, 0:1, pl.ds(q0, tq)]
-      st = _dot(kblk, qblk, _NT)                           # [tk, tq] fp32
-      if not fold:
-        st = st * scale
-      if masked:
-        st = jnp.where(rel >= k0 - q0, st, NEG_INF)
-      pt = jnp.exp(st - lse)
-      dv = dv + _dot(pt.astype(doblk.dtype), doblk, _NN)
-      dpt = _dot(vblk, doblk, _NT)
-      dst = pt * (dpt - delta)
-      dk = dk + _dot(dst.astype(qblk.dtype), qblk, _NN)
-      return dk, dv
+      def row_tile(i, carry, masked):
+        dk, dv = carry
+        q0 = _tile_start(i, tq)
+        qblk = q_ref[_rows_at(q_ref, q0, tq)]              # [tq, lanes]
+        doblk = do_ref[_rows_at(do_ref, q0, tq)]
+        lse = lse_ref[0, h, 0:1, pl.ds(q0, tq)]            # [1, tq]
+        delta = delta_ref[0, h, 0:1, pl.ds(q0, tq)]
+        st = _dot(kblk, qblk, _NT)                         # [tk, tq] fp32
+        if not fold:
+          st = st * scale
+        if masked:
+          st = jnp.where(rel >= k0 - q0, st, NEG_INF)
+        pt = jnp.exp(st - lse)
+        dv = dv + _dot(pt.astype(doblk.dtype), doblk, _NN)
+        dpt = _dot(vblk, doblk, _NT)
+        dst = pt * (dpt - delta)
+        dk = dk + _dot(dst.astype(qblk.dtype), qblk, _NN)
+        return dk, dv
 
-    acc = (jnp.zeros((tk, d), jnp.float32), jnp.zeros((tk, d), jnp.float32))
-    lo, full = _row_tiles(j, tq, tk, num_q, causal)
-    acc = _walk(lo, full, functools.partial(row_tile, masked=True), acc,
-                unroll)
-    acc = _walk(full, num_q, functools.partial(row_tile, masked=False), acc,
-                unroll)
-    dk, dv = acc
-    # dk accumulated ds^T q with unscaled q: the s-scale goes in once here.
-    dk_ref[0, 0, pl.ds(k0, tk), :] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0, 0, pl.ds(k0, tk), :] = dv.astype(dv_ref.dtype)
+      acc = (jnp.zeros((tk, lanes), jnp.float32),
+             jnp.zeros((tk, lanes), jnp.float32))
+      lo, full = _row_tiles(j, tq, tk, num_q, causal)
+      acc = _walk(lo, full, functools.partial(row_tile, masked=True), acc,
+                  unroll)
+      acc = _walk(full, num_q, functools.partial(row_tile, masked=False),
+                  acc, unroll)
+      dk, dv = acc
+      # dk accumulated ds^T q with unscaled q: the s-scale goes in once
+      # here.
+      dk_out = _into_head(dk_out, dk * scale, head_of, h)
+      dv_out = _into_head(dv_out, dv, head_of, h)
+    dk_ref[_rows_at(dk_ref, k0, tk)] = dk_out.astype(dk_ref.dtype)
+    dv_ref[_rows_at(dv_ref, k0, tk)] = dv_out.astype(dv_ref.dtype)
     return carry
 
   _walk(0, num_k, key_tile, 0, unroll)
 
 
 def _bwd_dq_kernel_resident(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                            delta_ref, dq_ref, *, tq: int, tk: int,
+                            delta_ref, dq_ref, *, d: int, tq: int, tk: int,
                             causal: bool, scale: float, unroll: bool):
-  """dQ of one head a grid step, tiles walked as the forward walks them."""
-  d = q_ref.shape[3]
-  num_q, num_k = q_ref.shape[2] // tq, k_ref.shape[2] // tk
+  """dQ of one block a grid step, tiles walked as the forward walks
+  them."""
+  lanes = q_ref.shape[-1]
+  num_q, num_k = q_ref.shape[-2] // tq, k_ref.shape[-2] // tk
   fold = _scale_folds(scale, q_ref.dtype)
   rel = _rel_pos(tq, tk, 0) if causal else None
+  head_of = _head_lanes(lanes, d)
 
   def row_tile(i, carry):
     q0 = _tile_start(i, tq)
-    qblk = q_ref[0, 0, pl.ds(q0, tq), :]                   # [tq, D]
-    doblk = do_ref[0, 0, pl.ds(q0, tq), :]
-    lse = lse_ref[0, 0, 0, pl.ds(q0, tq)][:, None]         # [tq, 1]
-    delta = delta_ref[0, 0, 0, pl.ds(q0, tq)][:, None]
-    if fold:
-      qblk = (qblk * scale).astype(qblk.dtype)
+    q_rows = q_ref[_rows_at(q_ref, q0, tq)]                # [tq, lanes]
+    do_rows = do_ref[_rows_at(do_ref, q0, tq)]
+    dq_out = None
+    for h in range(lanes // d):
+      lse = lse_ref[0, h, 0, pl.ds(q0, tq)][:, None]       # [tq, 1]
+      delta = delta_ref[0, h, 0, pl.ds(q0, tq)][:, None]
+      qblk = _of_head(q_rows, head_of, h, scale if fold else 1.0)
+      doblk = _of_head(do_rows, head_of, h)
 
-    def key_tile(j, dq, masked):
-      k0 = _tile_start(j, tk)
-      kblk = k_ref[0, 0, pl.ds(k0, tk), :]                 # [tk, D]
-      vblk = v_ref[0, 0, pl.ds(k0, tk), :]
-      s = _dot(qblk, kblk, _NT)                            # [tq, tk] fp32
-      if not fold:
-        s = s * scale
-      if masked:
-        s = jnp.where(rel >= k0 - q0, s, NEG_INF)
-      p = jnp.exp(s - lse)
-      dp = _dot(doblk, vblk, _NT)
-      ds = p * (dp - delta)
-      return dq + _dot(ds.astype(kblk.dtype), kblk, _NN)
+      def key_tile(j, dq, masked):
+        k0 = _tile_start(j, tk)
+        kblk = k_ref[_rows_at(k_ref, k0, tk)]              # [tk, lanes]
+        vblk = v_ref[_rows_at(v_ref, k0, tk)]
+        s = _dot(qblk, kblk, _NT)                          # [tq, tk] fp32
+        if not fold:
+          s = s * scale
+        if masked:
+          s = jnp.where(rel >= k0 - q0, s, NEG_INF)
+        p = jnp.exp(s - lse)
+        dp = _dot(doblk, vblk, _NT)
+        ds = p * (dp - delta)
+        return dq + _dot(ds.astype(kblk.dtype), kblk, _NN)
 
-    dq = jnp.zeros((tq, d), jnp.float32)
-    full, live = _key_tiles(i, tq, tk, num_k, causal)
-    dq = _walk(0, full, functools.partial(key_tile, masked=False), dq,
-               unroll)
-    dq = _walk(full, live, functools.partial(key_tile, masked=True), dq,
-               unroll)
-    dq_ref[0, 0, pl.ds(q0, tq), :] = (dq * scale).astype(dq_ref.dtype)
+      dq = jnp.zeros((tq, lanes), jnp.float32)
+      full, live = _key_tiles(i, tq, tk, num_k, causal)
+      dq = _walk(0, full, functools.partial(key_tile, masked=False), dq,
+                 unroll)
+      dq = _walk(full, live, functools.partial(key_tile, masked=True), dq,
+                 unroll)
+      dq_out = _into_head(dq_out, dq * scale, head_of, h)
+    dq_ref[_rows_at(dq_ref, q0, tq)] = dq_out.astype(dq_ref.dtype)
     return carry
 
   _walk(0, num_q, row_tile, 0, unroll)
@@ -498,38 +599,73 @@ def _fwd(q, k, v, causal: bool, block_q: int, block_k: int):
                    interpret=_interpret())
 
 
+def _fwd_rows(q, k, v, d: int, causal: bool, bq: int, bk: int,
+              fused: bool = False):
+  """``_fwd`` over operands kept in rows, ``[B, S, H x d]``
+  (:func:`flash_layout` says where): out in rows, lse ``[B, H, 8, S]``.
+  ``fused``: q, k and v are ONE array, ``[B, S, 3 x H x d]``, the three
+  column blocks of a fused projection's output."""
+  S = q.shape[1]
+  return _fwd_call(q, k, v, causal=causal, bq=bq, bk=bk,
+                   walk=_walk_of(S, S, d, q.dtype.itemsize, bq, bk),
+                   interpret=_interpret(), d=d, fused=fused)
+
+
+def _qkv_tiles(width: int, fused: bool):
+  """``(lane tiles of H x d, first tile of q, of k, of v)`` in operands
+  kept in rows: each its own array, or the three column blocks of one."""
+  n = width // (3 * _LANES) if fused else width // _LANES
+  return (n, 0, n, 2 * n) if fused else (n, 0, 0, 0)
+
+
 # Jitted so that the layers of a model share ONE trace and one Mosaic
 # lowering of a kernel: the unrolled bodies are long, and traced a layer
 # they added 7 s to the set-up of a 36-layer train step.  What the trace
-# depends on beside its arguments (backend, the regime's limits) is read
-# by the caller and passed in as static arguments.
+# depends on beside its arguments (backend, the regime's limits, the head
+# size of operands kept in rows) is read by the caller and passed in as
+# static arguments.
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "walk",
-                                             "interpret"))
+                                             "interpret", "d", "fused"))
 def _fwd_call(q, k, v, *, causal: bool, bq: int, bk: int, walk: str,
-              interpret: bool):
-  B, H, S, D = q.shape
-  Skv = k.shape[2]
-  scale = 1.0 / math.sqrt(D)
-
+              interpret: bool, d: Optional[int] = None,
+              fused: bool = False):
   if walk != "stream":
-    # One head a grid step; (bq, bk) is the tile its loops walk.
+    # A block a grid step; (bq, bk) is the tile its loops walk.
+    if d is not None:
+      # Operands in rows: a lane tile's heads a grid step.
+      B, S, _ = q.shape
+      n, *first = _qkv_tiles(q.shape[2], fused)
+      grid, out_dims = (B, n), (B, S, n * _LANES)
+      in_specs = [_tile_block(S, f) for f in first]
+      out_specs = [_tile_block(S), _group_block(_LANES // d, S)]
+      lse_dims = (B, n * _LANES // d, 8, S)
+    else:
+      # Head-major: one head a grid step.
+      B, H, S, d = q.shape
+      Skv = k.shape[2]
+      grid, out_dims, lse_dims = (B, H), (B, H, S, d), (B, H, 8, S)
+      in_specs = [_head_block(S, d), _head_block(Skv, d),
+                  _head_block(Skv, d)]
+      out_specs = [_head_block(S, d), _head_block(8, S)]
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel_resident, tq=bq, tk=bk,
-                          causal=causal, scale=scale,
+        functools.partial(_fwd_kernel_resident, d=d, tq=bq, tk=bk,
+                          causal=causal, scale=1.0 / math.sqrt(d),
                           unroll=walk == "unrolled"),
-        grid=(B, H),
-        in_specs=[_head_block(S, D), _head_block(Skv, D),
-                  _head_block(Skv, D)],
-        out_specs=[_head_block(S, D), _head_block(8, S)],
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, 8, S), jnp.float32),
+            jax.ShapeDtypeStruct(out_dims, q.dtype),
+            jax.ShapeDtypeStruct(lse_dims, jnp.float32),
         ],
         interpret=interpret,
         name=FLASH_FWD,
     )(q, k, v)
     return out, lse
 
+  B, H, S, D = q.shape
+  Skv = k.shape[2]
+  scale = 1.0 / math.sqrt(D)
   num_kv = Skv // bk
   grid = (B, H, S // bq, num_kv)
 
@@ -665,26 +801,39 @@ def _bwd_kernels(q, k, v, dout, lse8, delta8, causal, block_q, block_k):
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "walk",
-                                             "interpret"))
+                                             "interpret", "d", "fused"))
 def _bwd_call(q, k, v, dout, lse8, delta8, *, causal: bool, bq: int,
-              bk: int, walk: str, interpret: bool):
-  B, H, S, D = q.shape
-  Skv = k.shape[2]
-  scale = 1.0 / math.sqrt(D)
-
+              bk: int, walk: str, interpret: bool, d: Optional[int] = None,
+              fused: bool = False):
   if walk != "stream":
-    in_specs = [_head_block(S, D), _head_block(Skv, D), _head_block(Skv, D),
-                _head_block(S, D), _head_block(8, S), _head_block(8, S)]
-    tiles = dict(tq=bq, tk=bk, causal=causal, scale=scale,
-                 unroll=walk == "unrolled")
+    if d is not None:
+      # q, k, v, dO in and dQ, dK, dV out in rows; lse8 / delta8 as ever.
+      B, S, _ = q.shape
+      n, *first = _qkv_tiles(q.shape[2], fused)
+      rows, row8 = _tile_block(S), _group_block(_LANES // d, S)
+      grid = (B, n)
+      in_specs = [_tile_block(S, f) for f in first] + [rows, row8, row8]
+      dq_spec = dk_spec = rows
+      dq_dims = dk_dims = (B, S, n * _LANES)
+    else:
+      B, H, S, d = q.shape
+      Skv = k.shape[2]
+      grid = (B, H)
+      in_specs = [_head_block(S, d), _head_block(Skv, d),
+                  _head_block(Skv, d), _head_block(S, d),
+                  _head_block(8, S), _head_block(8, S)]
+      dq_spec, dk_spec = _head_block(S, d), _head_block(Skv, d)
+      dq_dims, dk_dims = (B, H, S, d), (B, H, Skv, d)
+    tiles = dict(d=d, tq=bq, tk=bk, causal=causal,
+                 scale=1.0 / math.sqrt(d), unroll=walk == "unrolled")
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel_resident, **tiles),
-        grid=(B, H),
+        grid=grid,
         in_specs=in_specs,
-        out_specs=[_head_block(Skv, D), _head_block(Skv, D)],
+        out_specs=[dk_spec, dk_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Skv, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Skv, D), q.dtype),
+            jax.ShapeDtypeStruct(dk_dims, q.dtype),
+            jax.ShapeDtypeStruct(dk_dims, q.dtype),
         ],
         interpret=interpret,
         name=FLASH_DKV,
@@ -692,15 +841,18 @@ def _bwd_call(q, k, v, dout, lse8, delta8, *, causal: bool, bq: int,
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel_resident, **tiles),
-        grid=(B, H),
+        grid=grid,
         in_specs=in_specs,
-        out_specs=_head_block(S, D),
-        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+        out_specs=dq_spec,
+        out_shape=jax.ShapeDtypeStruct(dq_dims, q.dtype),
         interpret=interpret,
         name=FLASH_DQ,
     )(q, k, v, dout, lse8, delta8)
     return dq, dk, dv
 
+  B, H, S, D = q.shape
+  Skv = k.shape[2]
+  scale = 1.0 / math.sqrt(D)
   num_q, num_kv = S // bq, Skv // bk
 
   # dk/dv: grid streams Q blocks innermost, accumulating into VMEM
@@ -804,6 +956,72 @@ def _flash_bwd(causal, block_q, block_k, residuals, dout):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _fwd_rows_saved(q, k, v, d, causal, bq, bk, fused):
+  """``_fwd_rows`` with its results tagged for a names-aware remat policy,
+  under the same names as ``_flash_fwd``'s: one policy saves either."""
+  out, lse = _fwd_rows(q, k, v, d, causal, bq, bk, fused)
+  return (checkpoint_name(out, "flash_out"),
+          checkpoint_name(lse, "flash_lse"))
+
+
+def _rows_bwd(q, k, v, out, lse, dout, d, causal, bq, bk, fused):
+  B, S, HD = out.shape
+  # delta = rowsum(dO * O) a head, head-major like lse.  As a product with
+  # the heads' 0/1 lane map and not a sum over a [.., H, d] view: XLA keeps
+  # such a view position-minor and copied the float32 [B, S, H x d] whole
+  # to get there.
+  lane_of = (jnp.arange(HD)[None, :] // d
+             == jnp.arange(HD // d)[:, None]).astype(jnp.float32)
+  delta = jnp.einsum(
+      "bsk,hk->bhs", dout.astype(jnp.float32) * out.astype(jnp.float32),
+      lane_of, precision=jax.lax.Precision.HIGHEST)        # [B, H, S]
+  return _bwd_call(q, k, v, dout, lse, _tile8(delta), causal=causal, bq=bq,
+                   bk=bk, walk=_walk_of(S, S, d, q.dtype.itemsize, bq, bk),
+                   interpret=_interpret(), d=d, fused=fused)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_rows(q, k, v, d, causal, bq, bk):
+  """:func:`_flash` over operands kept in rows, ``[B, S, H x d]``."""
+  return _fwd_rows(q, k, v, d, causal, bq, bk)[0]
+
+
+def _flash_rows_fwd(q, k, v, d, causal, bq, bk):
+  out, lse = _fwd_rows_saved(q, k, v, d, causal, bq, bk, False)
+  return out, (q, k, v, out, lse)
+
+
+def _flash_rows_bwd(d, causal, bq, bk, residuals, dout):
+  return _rows_bwd(*residuals, dout, d, causal, bq, bk, False)
+
+
+_flash_rows.defvjp(_flash_rows_fwd, _flash_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _flash_qkv(qkv, d, causal, bq, bk):
+  """:func:`_flash_rows` with q, k and v read where a fused projection
+  wrote them, the three column blocks of ``[B, S, 3 x H x d]``: a Mosaic
+  call takes no slice fused into its operand, so three operands would be
+  three copies."""
+  return _fwd_rows(qkv, qkv, qkv, d, causal, bq, bk, True)[0]
+
+
+def _flash_qkv_fwd(qkv, d, causal, bq, bk):
+  out, lse = _fwd_rows_saved(qkv, qkv, qkv, d, causal, bq, bk, True)
+  return out, (qkv, out, lse)
+
+
+def _flash_qkv_bwd(d, causal, bq, bk, residuals, dout):
+  qkv, out, lse = residuals
+  return (jnp.concatenate(
+      _rows_bwd(qkv, qkv, qkv, out, lse, dout, d, causal, bq, bk, True),
+      axis=-1),)
+
+
+_flash_qkv.defvjp(_flash_qkv_fwd, _flash_qkv_bwd)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash_lse(q, k, v, causal, block_q, block_k):
   out, lse8 = _fwd(q, k, v, causal, block_q, block_k)
@@ -843,12 +1061,7 @@ def flash_attention_lse(q, k, v, causal: bool = True,
   this wrapper is the layout-friendly public entry point for external
   composition, e.g. KV-chunked decoding."""
   B, S, H, D = q.shape
-  bq = (min(block_q, S) if block_q else
-        _default_block(S, d=D, itemsize=q.dtype.itemsize))
-  bk = (min(block_k, S) if block_k else
-        _default_block(S, d=D, itemsize=q.dtype.itemsize))
-  if not bq or not bk or S % bq or S % bk:
-    raise ValueError(f"block sizes ({bq}, {bk}) must divide seq len {S}")
+  bq, bk = _blocks(S, D, q.dtype.itemsize, block_q, block_k)
   qt = q.transpose(0, 2, 1, 3)
   kt = k.transpose(0, 2, 1, 3)
   vt = v.transpose(0, 2, 1, 3)
@@ -962,6 +1175,52 @@ def _mesh_shard_spec(B: int, H: int):
   return mesh, P(b_axis, None, h_axis, None)
 
 
+def flash_layout(S: int, H: int, D: int, itemsize: int,
+                 fused: bool = False) -> str:
+  """The layout the kernels take a call's operands in, from its shapes
+  alone (``H``: the heads ONE chip holds; ``fused``: q, k and v arrive as
+  one projection's ``[B, S, 3 x H x D]``, not as ``[B, S, H, D]``
+  arrays): ``"rows"``, ``[B, S, H x D]`` as the projections write and
+  read them, where heads of ``D`` lanes pack whole 128-lane tiles (``D``
+  128, 64, 32; ``H`` a multiple of ``128 // D``) and a head is resident
+  in VMEM; ``"heads"``, ``[B, H, S, D]`` behind a transpose either way,
+  elsewhere (the streaming kernels, an odd head count, ``D`` 80 or 16).
+  A ``[.., S, 64]`` array pads its lanes to 128 in HBM and XLA keeps it
+  position-minor, so every head-major operand of a Mosaic call cost a
+  relayout copy: 13 a layer of the four-chip train step (PERF.md section
+  6, PR 49).  Heads of 128 lanes pad nothing: head arrays of that width
+  stay head-major (XLA lays a free-standing one out that way at no cost,
+  and relaying it into rows read +48% on the chip), and rows are taken
+  only from the fused projection, where no head array exists at all."""
+  group = _LANES // D if D and _LANES % D == 0 else 0
+  if (0 < group <= _TILE_HEADS and H % group == 0 and (fused or group > 1)
+      and _resident_ok(S, S, D, itemsize)):
+    return "rows"
+  return "heads"
+
+
+def _blocks(S: int, D: int, itemsize: int, block_q: Optional[int] = None,
+            block_k: Optional[int] = None):
+  bq = (min(block_q, S) if block_q else
+        _default_block(S, d=D, itemsize=itemsize))
+  bk = (min(block_k, S) if block_k else
+        _default_block(S, d=D, itemsize=itemsize))
+  if not bq or not bk or S % bq or S % bk:
+    raise ValueError(f"block sizes ({bq}, {bk}) must divide seq len {S}")
+  return bq, bk
+
+
+def _chip_layout(sharded, S: int, H: int, D: int, itemsize: int,
+                 fused: bool = False):
+  """``(layout, heads a chip)`` of a call, recorded for a traced run
+  (trace metadata ``train/flash_layout``: once a compile)."""
+  if sharded is not None and sharded[1][2] is not None:
+    H //= sharded[0].shape[sharded[1][2]]
+  layout = flash_layout(S, H, D, itemsize, fused)
+  trace_lib.get_tracer().metadata("train/flash_layout", {"layout": layout})
+  return layout, H
+
+
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None):
@@ -974,25 +1233,65 @@ def flash_attention(q, k, v, causal: bool = True,
   not a block of the grid.
 
   On a multi-device mesh each chip runs the kernel on its own
-  batch/head shard (:func:`_mesh_shard_spec`).
+  batch/head shard (:func:`_mesh_shard_spec`).  Where
+  :func:`flash_layout` says ``rows`` for a chip's heads, the kernels read
+  q, k, v (and o, dO) and write o (and dQ, dK, dV) as ``[B, S, H x D]``,
+  which ``[B, S, H, D]`` is without moving a byte: nothing is transposed.
   """
   B, S, H, D = q.shape
-  bq = (min(block_q, S) if block_q else
-        _default_block(S, d=D, itemsize=q.dtype.itemsize))
-  bk = (min(block_k, S) if block_k else
-        _default_block(S, d=D, itemsize=q.dtype.itemsize))
-  if not bq or not bk or S % bq or S % bk:
-    raise ValueError(f"block sizes ({bq}, {bk}) must divide seq len {S}")
+  bq, bk = _blocks(S, D, q.dtype.itemsize, block_q, block_k)
+  sharded = _mesh_shard_spec(B, H)
+  layout, _ = _chip_layout(sharded, S, H, D, q.dtype.itemsize)
 
   def per_shard(q, k, v):
-    # Kernels use [B, H, S, D] layout.
+    if layout == "rows":
+      b, _, h, _ = q.shape
+      out = _flash_rows(q.reshape(b, S, h * D), k.reshape(b, S, h * D),
+                        v.reshape(b, S, h * D), D, causal, bq, bk)
+      return out.reshape(b, S, h, D)
+    # The head-major kernels use [B, H, S, D].
     out = _flash(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                  v.transpose(0, 2, 1, 3), causal, bq, bk)
     return out.transpose(0, 2, 1, 3)
 
-  sharded = _mesh_shard_spec(B, H)
   if sharded is None:
     return per_shard(q, k, v)
   mesh, spec = sharded
   return shard_map(per_shard, mesh, in_specs=(spec, spec, spec),
                    out_specs=spec)(q, k, v)
+
+
+def flash_attention_qkv(qkv, num_heads: int, causal: bool = True):
+  """Self-attention straight from a fused projection: ``qkv`` ``[B, S,
+  3 x H x D]`` (the column blocks q, k, v, each its heads side by side)
+  -> ``[B, S, H x D]``, what the output projection reads.
+
+  Where a chip holds every head and :func:`flash_layout` says ``rows``,
+  the kernels read the three column blocks of the ONE array where the
+  projection wrote them and no ``[B, S, H, D]`` or ``[B, H, S, D]`` array
+  exists on the way: XLA keeps an array whose minor dimension is 64
+  position-minor and relays it out for every Mosaic call.  Elsewhere
+  (heads divided over the ``model`` axis, a layout of ``heads``) the
+  three are cut as the models always cut them and go through
+  :func:`flash_attention`."""
+  B, S, W = qkv.shape
+  H, D = num_heads, W // (3 * num_heads)
+  sharded = _mesh_shard_spec(B, H)
+  layout, chip_heads = _chip_layout(sharded, S, H, D, qkv.dtype.itemsize,
+                                    fused=True)
+  if layout != "rows" or chip_heads != H:
+    # Heads ride the model axis (a column-parallel projection already
+    # produced the sharded feature dim; this re-expresses it on heads).
+    qkv = constrain(qkv.reshape(B, S, 3, H, D),
+                    P(constants.DATA_AXIS, None, None, constants.MODEL_AXIS,
+                      None))
+    return flash_attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
+                           causal=causal).reshape(B, S, H * D)
+
+  bq, bk = _blocks(S, D, qkv.dtype.itemsize)
+  per_shard = lambda qkv: _flash_qkv(qkv, D, causal, bq, bk)
+  if sharded is None:
+    return per_shard(qkv)
+  mesh, spec = sharded
+  spec = P(spec[0], None, None)
+  return shard_map(per_shard, mesh, in_specs=(spec,), out_specs=spec)(qkv)

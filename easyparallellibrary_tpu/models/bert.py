@@ -83,33 +83,36 @@ class EncoderBlock(nn.Module):
     y = LayerNorm(dtype=cfg.dtype, name="ln1")(x)
     qkv = Dense(3 * D, parallel=col, dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype, name="qkv")(y)
-    qkv = qkv.reshape(B, S, 3, H, D // H)
-    qkv = _constrain(qkv, P(constants.DATA_AXIS, None, None,
-                            constants.MODEL_AXIS, None))
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
     if cfg.attn_impl == "pallas_flash":
-      # Bidirectional flash (causal=False) — same kernel as GPT's path;
-      # removes the [B, H, S, S] score temps at BERT's S=512 default.
+      # Bidirectional flash (causal=False) — same kernel as GPT's path,
+      # reading q, k and v where the projection wrote them; removes the
+      # [B, H, S, S] score temps at BERT's S=512 default.
       from easyparallellibrary_tpu.kernels.flash_attention import (
-          flash_attention)
-      attn = flash_attention(q, k, v, causal=False).reshape(B, S, D)
-    elif cfg.attn_impl == "ring":
-      # Bidirectional ring — the encoder family's long-context path
-      # (sequence sharded over the seq axis; composes with the smap
-      # pipeline engines exactly like GPT's).
-      from easyparallellibrary_tpu.sequence.ring_attention import (
-          ring_attention)
-      attn = ring_attention(q, k, v, causal=False).reshape(B, S, D)
-    elif cfg.attn_impl == "ulysses":
-      from easyparallellibrary_tpu.sequence.ulysses import (
-          ulysses_attention)
-      attn = ulysses_attention(q, k, v, causal=False).reshape(B, S, D)
-    elif cfg.attn_impl == "xla":
-      scale = 1.0 / jnp.sqrt(D // H).astype(cfg.dtype)
-      logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-      probs = jax.nn.softmax(logits.astype(jnp.float32),
-                             -1).astype(cfg.dtype)
-      attn = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(B, S, D)
+          flash_attention_qkv)
+      attn = flash_attention_qkv(qkv, H, causal=False)
+    elif cfg.attn_impl in ("ring", "ulysses", "xla"):
+      qkv = qkv.reshape(B, S, 3, H, D // H)
+      qkv = _constrain(qkv, P(constants.DATA_AXIS, None, None,
+                              constants.MODEL_AXIS, None))
+      q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+      if cfg.attn_impl == "ring":
+        # Bidirectional ring — the encoder family's long-context path
+        # (sequence sharded over the seq axis; composes with the smap
+        # pipeline engines exactly like GPT's).
+        from easyparallellibrary_tpu.sequence.ring_attention import (
+            ring_attention)
+        attn = ring_attention(q, k, v, causal=False)
+      elif cfg.attn_impl == "ulysses":
+        from easyparallellibrary_tpu.sequence.ulysses import (
+            ulysses_attention)
+        attn = ulysses_attention(q, k, v, causal=False)
+      else:
+        scale = 1.0 / jnp.sqrt(D // H).astype(cfg.dtype)
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+        probs = jax.nn.softmax(logits.astype(jnp.float32),
+                               -1).astype(cfg.dtype)
+        attn = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+      attn = attn.reshape(B, S, D)
     else:
       # A typo'd impl silently falling back to dense attention would
       # mislabel any benchmark run on top of it (same guard as GPT).

@@ -156,6 +156,11 @@ class CausalSelfAttention(nn.Module):
                   rows.slots, rows.chunk, H, head_dim),
               P(constants.DATA_AXIS, None, constants.MODEL_AXIS, None))
           for i in range(3))
+    elif (cfg.attn_impl == "pallas_flash" and paged_info is None
+          and not self.decode):
+      # The flash kernels read q, k and v where the projection wrote them
+      # (kernels/flash_attention.py:flash_attention_qkv): nothing to cut.
+      q = k = v = None
     else:
       qkv = qkv.reshape(B, S, 3, H, head_dim)
       # Heads ride the model axis (column-parallel QKV already produced
@@ -187,8 +192,8 @@ class CausalSelfAttention(nn.Module):
       out = ulysses_attention(q, k, v, causal=True)
     elif cfg.attn_impl == "pallas_flash":
       from easyparallellibrary_tpu.kernels.flash_attention import (
-          flash_attention)
-      out = flash_attention(q, k, v, causal=True)
+          flash_attention_qkv)
+      out = flash_attention_qkv(qkv, H, causal=True)
     elif cfg.attn_impl == "xla":
       out = _dense_causal_attention(q, k, v, cfg.dtype)
     else:

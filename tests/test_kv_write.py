@@ -422,14 +422,20 @@ def test_sanitize_zeroes_the_same_rows_in_both_orders():
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def four_chips():
+  """The devices of a described four-chip v5e host."""
   from jax.experimental import topologies
   try:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
   except Exception as e:  # no libtpu here, or another process holds it
     pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-  return SingleDeviceSharding(topo.devices[0])
+  return list(topo.devices)
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+  return SingleDeviceSharding(four_chips[0])
 
 
 def _compiled_text(jitted, *args) -> str:
@@ -1124,3 +1130,104 @@ def test_flash_kernels_compile_for_v5e_inside_their_vmem(
            for line in text.splitlines()
            if 'custom_call_target="tpu_custom_call"' in line]
   assert sorted(calls) == ["flash_dkv", "flash_dq", "flash_fwd"], calls
+
+
+def test_flash_rows_compile_for_v5e_from_the_one_projection(one_chip,
+                                                            monkeypatch):
+  """The train cell's call a chip, ``flash_attention_qkv`` on ``[8, 1024,
+  3 x 1280]`` bfloat16: Mosaic takes the three column blocks of the one
+  operand (blocks of whole 128-lane tiles, two heads each), and the
+  program around the three kernels copies and transposes nothing."""
+  fa = importlib.import_module(
+      "easyparallellibrary_tpu.kernels.flash_attention")
+  monkeypatch.setattr(fa, "_interpret", lambda: False)
+  qkv = jax.ShapeDtypeStruct((8, 1024, 3840), jnp.bfloat16,
+                             sharding=one_chip)
+  loss = lambda qkv: jnp.sum(fa.flash_attention_qkv(
+      qkv, 20, causal=True).astype(jnp.float32) ** 2)
+  text = _compiled_text(jax.jit(jax.value_and_grad(loss)), qkv)
+  calls = [line for line in text.splitlines()
+           if 'custom_call_target="tpu_custom_call"' in line]
+  assert sorted(re.search(r"flash_(fwd|dkv|dq)", c.split(" = ")[0]).group(0)
+                for c in calls) == ["flash_dkv", "flash_dq", "flash_fwd"]
+  for call in calls:
+    operands = call.split("operand_layout_constraints={")[1]
+    assert operands.count("bf16[8,1024,3840]{2,1,0}") == 3, call
+  assert " copy(" not in text and " transpose(" not in text
+
+
+def _head_shaped(line, dims):
+  """Whether an HLO instruction's result holds ``dims`` in any order and
+  layout (dimensions of 1 aside)."""
+  m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]", line)
+  return bool(m) and sorted(
+      int(d) for d in m.group(1).split(",") if d not in ("", "1")) == dims
+
+
+def test_train_step_compiled_for_four_v5e_relays_out_no_head_array(
+    four_chips, monkeypatch, layers=2):
+  """`gpt2l-train-zero1-4chip`'s step at GPT-2 large's widths (1280 wide,
+  20 heads of 64, S 1024, 8 rows a chip, remat with ``dots_flash``,
+  ZeRO-1 over ``data:4``), cut to a few layers, as XLA plans it for a
+  described ``v5e:2x2``.  At PR 48 the plan of the full depth held 473
+  ``copy`` instructions of a ``bf16[8,20,1024,64]``-shaped array, 13.1 a
+  layer, 47 ms of a 425 ms step (PERF.md section 6, PR 49): XLA keeps an
+  array with a 64-wide minor dimension position-minor, a Mosaic call wants
+  it row-major, and every q, k, v, o, dO, dQ, dK, dV paid the relayout,
+  again under remat.  Now no array of that shape exists: no ``copy`` and
+  no ``transpose`` of one, no copy of the projection's ``[8, 1024, 3840]``
+  or of an ``[8, 1024, 1280]`` operand in an attention block either, and
+  one ``flash_fwd``, one ``flash_dkv``, one ``flash_dq`` a layer."""
+  import optax
+  from easyparallellibrary_tpu.models.gpt import make_gpt_train_step
+  from easyparallellibrary_tpu.parallel import TrainState, parallelize
+  from easyparallellibrary_tpu.parallel.api import (
+      batch_sharding, state_shardings)
+  from easyparallellibrary_tpu.runtime import zero as zero_lib
+  fa = importlib.import_module(
+      "easyparallellibrary_tpu.kernels.flash_attention")
+  monkeypatch.setattr(fa, "_interpret", lambda: False)
+  epl.init(epl.Config({"zero.level": "v1"}), devices=four_chips)
+  with epl.replicate(1):
+    model = GPT(GPTConfig(
+        vocab_size=50304, num_layers=layers, num_heads=20, d_model=1280,
+        d_ff=5120, max_seq_len=1024, dtype=jnp.bfloat16,
+        param_dtype=jnp.float32, remat=True, attn_impl="pallas_flash",
+        remat_policy="dots_flash", loss_chunk=256))
+  mesh = epl.current_plan().build_mesh()
+  assert dict(zip(mesh.axis_names, mesh.devices.shape))["data"] == 4
+  tx = optax.adamw(3e-4, weight_decay=0.01)
+
+  def init_fn(key):
+    params = model.init(key, jnp.zeros((1, 8), jnp.int32))["params"]
+    return TrainState.create(apply_fn=model.apply, params=params, tx=tx)
+
+  key = jax.random.PRNGKey(0)
+  abstract = jax.eval_shape(init_fn, key)
+  shardings = zero_lib.shard_opt_state(
+      abstract, state_shardings(abstract, mesh), mesh, "v1")
+  # the parameters are boxed: pair the leaves, not the trees
+  leaves, treedef = jax.tree_util.tree_flatten(abstract)
+  state = jax.tree_util.tree_unflatten(treedef, [
+      jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s)
+      for a, s in zip(leaves, jax.tree_util.tree_leaves(shardings))])
+  batch = {"ids": jax.ShapeDtypeStruct((32, 1025), jnp.int32,
+                                       sharding=batch_sharding(mesh))}
+  step = parallelize(make_gpt_train_step(model), mesh, shardings)
+  text = _compiled_text(step.jitted, state, batch, key)
+
+  moved = [line for line in text.splitlines()
+           if re.search(r" (copy|transpose)\(", line)]
+  assert moved, "the plan's weight copies at least"
+  heads = [line for line in moved if _head_shaped(line, [8, 20, 64, 1024])]
+  assert not heads, heads[:3]
+  activations = [line for line in moved
+                 if re.match(r"\s*%\S+ = (bf16|f32)\[8,1024,(1280|3840)\]"
+                             r"\S* copy\(", line) and "/attn/" in line]
+  assert not activations, activations[:3]
+  assert not re.findall(r"pad_add_fusion", text)
+  calls = [re.search(r"flash_(fwd|dkv|dq)", line.split(" = ")[0])
+           for line in text.splitlines()
+           if 'custom_call_target="tpu_custom_call"' in line]
+  assert sorted(m.group(0) for m in calls) == sorted(
+      ["flash_fwd", "flash_dkv", "flash_dq"] * layers)

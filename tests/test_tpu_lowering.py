@@ -70,8 +70,27 @@ def test_flash_on_a_mesh_lowers_per_shard(monkeypatch):
   text = _lowers_for_tpu(jax.value_and_grad(_flash_loss, (0, 1, 2)),
                          x, x, x)
   assert text.count("tpu_custom_call") == 3
-  # Each call sees its chip's shard: batch 8/4, heads 16/2.
-  assert "tensor<2x8x1024x64xbf16>" in text
+  # Each call sees its chip's shard, in rows: batch 8/4, heads 16/2 of 64
+  # side by side; nothing is head-major.
+  assert "tensor<2x1024x512xbf16>" in text
+  assert "tensor<2x8x1024x64xbf16>" not in text
+
+
+def test_heads_a_chip_that_do_not_fill_lane_tiles_stay_head_major(
+    monkeypatch):
+  """The rule reads the heads ONE chip holds: 6 heads of 64 over
+  ``model:2`` are 3 a chip, no whole pairs, so each call keeps its chip's
+  ``[B, H, S, D]`` shard behind the transposes."""
+  monkeypatch.setattr(fa, "_interpret", lambda: False)
+  epl.init(epl.Config({"cluster.mesh_shape": "data:4,model:2"}))
+  mesh = epl.Env.get().cluster.build_mesh()
+  x = jax.ShapeDtypeStruct(
+      (8, 1024, 6, 64), jnp.bfloat16,
+      sharding=NamedSharding(mesh, P("data", None, "model", None)))
+  text = _lowers_for_tpu(jax.value_and_grad(_flash_loss, (0, 1, 2)),
+                         x, x, x)
+  assert _kernel_names(text) == sorted(FLASH_NAMES)
+  assert "tensor<2x3x1024x64xbf16>" in text
 
 
 def test_the_train_cell_lowers_per_shard_under_its_three_names(monkeypatch):
@@ -89,7 +108,30 @@ def test_the_train_cell_lowers_per_shard_under_its_three_names(monkeypatch):
   text = _lowers_for_tpu(jax.value_and_grad(_flash_loss, (0, 1, 2)),
                          x, x, x)
   assert _kernel_names(text) == sorted(FLASH_NAMES)
-  assert "tensor<8x20x1024x64xbf16>" in text
+  assert "tensor<8x1024x1280xbf16>" in text
+  assert "tensor<8x20x1024x64xbf16>" not in text
+
+
+def test_the_train_cell_reads_q_k_v_from_the_one_projection(monkeypatch):
+  """As ``models/gpt.py`` calls it: the fused projection's ``[32, 1024,
+  3 x 1280]`` goes in whole, each chip's kernels take their ``[8, 1024,
+  3840]`` three times (the column blocks are ``in_specs``, not slices) and
+  write ``[8, 1024, 1280]``; no array of rank 4 with a 64-wide minor
+  dimension is left for XLA to relay out (lse and delta, ``[8, 20, 8,
+  1024]`` float32, are the only head-major ones)."""
+  monkeypatch.setattr(fa, "_interpret", lambda: False)
+  epl.init(epl.Config({"cluster.mesh_shape": "data:4"}),
+           devices=jax.devices()[:4])
+  mesh = epl.Env.get().cluster.build_mesh()
+  qkv = jax.ShapeDtypeStruct(
+      (32, 1024, 3840), jnp.bfloat16,
+      sharding=NamedSharding(mesh, P("data", None, None)))
+  loss = lambda qkv: jnp.sum(fa.flash_attention_qkv(
+      qkv, 20, causal=True).astype(jnp.float32) ** 2)
+  text = _lowers_for_tpu(jax.value_and_grad(loss), qkv)
+  assert _kernel_names(text) == sorted(FLASH_NAMES)
+  assert "tensor<8x1024x3840xbf16>" in text
+  assert not re.findall(r"tensor<[\dx]*x64xbf16>", text)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
