@@ -55,6 +55,16 @@ a user calls, and checks what comes out by the repo's own references:
                   three-layer cut (full + dense, full + experts, window +
                   experts, 8 of 256 experts held) through the engine as
                   for ``experts``
+  gigachat        (PR 50) the decoder of gated delta-rule linear attention
+                  beside a gated latent attention (models/gigachat.py,
+                  GigaChat3.5-432B-A28B's widths): every kernel rule at its
+                  cell's shapes (128 slots x chunk 32) with what it
+                  resolved, ``gdn_scan`` at its cell's heads (32 key | 64
+                  value heads of 128, chunk 32; f32, bf16) against the
+                  reference scan, both forms and an idle slot bit for bit;
+                  a three-layer cut (linear + dense, full + experts, linear
+                  + experts, 4 of 256 experts held) through the engine as
+                  for ``experts``
   overlap         (PR 33) GPT-2 medium and the LFM2 cut, 32 requests each
                   through the engine's overlapped loop (step k+1 launched
                   before step k's tokens are fetched) and through the
@@ -103,6 +113,7 @@ from easyparallellibrary_tpu.kernels.slot_attention import (
 from easyparallellibrary_tpu.kernels.ssm_scan import (
     SSM_SCAN, ssm_scan_pallas, ssm_scan_reference)
 from easyparallellibrary_tpu.kernels import dsa_index as dsa_lib
+gdn_lib = importlib.import_module("easyparallellibrary_tpu.kernels.gdn_scan")
 # the package's ``flash_attention`` is the function: the module by its name
 fa = importlib.import_module(
     "easyparallellibrary_tpu.kernels.flash_attention")
@@ -110,6 +121,7 @@ from easyparallellibrary_tpu.kernels import slot_attention as slot_attn_lib
 from easyparallellibrary_tpu.models import GPT, GPTConfig
 from easyparallellibrary_tpu.models.dots3_note import (
     Dots3Note, Dots3NoteConfig)
+from easyparallellibrary_tpu.models.gigachat import GigaChat, GigaChatConfig
 from easyparallellibrary_tpu.models.glm_moe import GlmMoe, GlmMoeConfig
 from easyparallellibrary_tpu.models.jamba import Jamba, JambaConfig
 from easyparallellibrary_tpu.models.layer_kinds import FULL, MAMBA, SLIDING
@@ -192,6 +204,8 @@ class Sizes:
   smallthinker_cfg: SmallThinkerConfig   # one period, the window cut
   smallthinker_cell: tuple        # (cell's config, slots, chunk): the
                                   # kernels' checks and the rules' report
+  gigachat_cfg: GigaChatConfig    # linear + dense, full + experts, linear
+  gigachat_cell: tuple            # (cell's config, slots, chunk)
 
   @staticmethod
   def real() -> "Sizes":
@@ -272,7 +286,20 @@ class Sizes:
             dtype=jnp.float32, param_dtype=jnp.float32),
         smallthinker_cell=(SmallThinkerConfig(
             window_layout=(0, 1, 1, 1) * 2, rope_layout=(0, 1, 1, 1) * 2),
-                           48, 32))
+                           48, 32),
+        # GigaChat3.5's widths: a dense linear layer, a full expert layer,
+        # a linear expert layer, 4 of its 256 experts held (float32 weights
+        # of 16 would not fit beside the check); vocabulary and context cut
+        # as above.  The rules and the kernel at the cell's own geometry:
+        # the cut of perfbench/configs/gigachat3.5-432b-a28b.json in
+        # bfloat16, 128 slots x chunk 32, context 4096.
+        gigachat_cfg=GigaChatConfig(
+            vocab_size=16032, num_layers=3, full_attention_layers=(1,),
+            first_k_dense=1, experts_held=(0, 4), max_seq_len=1024,
+            dtype=jnp.float32, param_dtype=jnp.float32),
+        gigachat_cell=(GigaChatConfig(
+            vocab_size=16032, num_layers=5, full_attention_layers=(1,),
+            first_k_dense=1, experts_held=(0, 16)), 128, 32))
 
   @staticmethod
   def toy() -> "Sizes":
@@ -334,7 +361,24 @@ class Sizes:
             head_dim=128, moe_d_ff=128, n_routed_experts=8,
             num_experts_per_tok=3, sliding_window=100,
             window_layout=(0, 1), rope_layout=(0, 1), max_seq_len=256,
-            dtype=jnp.float32), 6, 16))
+            dtype=jnp.float32), 6, 16),
+        gigachat_cfg=GigaChatConfig(
+            vocab_size=512, num_layers=3, full_attention_layers=(1,),
+            d_model=128, d_ff=256, moe_d_ff=128, num_heads=2,
+            q_lora_rank=64, kv_lora_rank=64, qk_nope_head_dim=32,
+            qk_rope_head_dim=16, v_head_dim=32, rope_original_max=64,
+            linear_num_key_heads=1, linear_num_value_heads=2,
+            n_routed_experts=8, experts_held=(2, 4), num_experts_per_tok=2,
+            first_k_dense=1, max_seq_len=256, dtype=jnp.float32,
+            param_dtype=jnp.float32),
+        gigachat_cell=(GigaChatConfig(
+            vocab_size=512, num_layers=2, full_attention_layers=(1,),
+            d_model=128, d_ff=256, moe_d_ff=128, num_heads=2,
+            q_lora_rank=64, kv_lora_rank=64, qk_nope_head_dim=32,
+            qk_rope_head_dim=16, v_head_dim=32, linear_num_key_heads=1,
+            linear_num_value_heads=2, n_routed_experts=8,
+            experts_held=(2, 4), num_experts_per_tok=2, first_k_dense=1,
+            max_seq_len=256, dtype=jnp.float32), 6, 16))
 
 
 def say(msg: str) -> None:
@@ -1784,6 +1828,85 @@ def phase_smallthinker(sizes: Sizes) -> None:
       f"lowerings {err:.2e}")
 
 
+# -------------------------------------------------------------- gigachat --
+
+
+def check_gdn_scan(B, Hk, Hv, d, C, dtype, rehearsal: bool) -> None:
+  """The gated delta rule's kernel (the convolution over the slot's window
+  inside it) against ``lax.scan``: an idle slot, a decoding one (one
+  position's form), whole and partly valid chunks (the chunk's form), some
+  from ``reset``.  State and outputs to float32
+  rounding (a 16-bit output to its own rounding); an idle slot's state bit
+  for bit; zeros beyond a slot's live positions."""
+  r = np.random.RandomState(5)
+  f32 = jnp.float32
+  state = jnp.asarray(r.randn(B, Hv, d, d), f32)
+  W = 2 * Hk * d + Hv * d
+  x = jnp.asarray(r.randn(B, C, W), dtype)
+  window = jnp.asarray(r.randn(B, 3, W), dtype)
+  taps = jnp.asarray(0.5 * r.randn(4, W), f32)
+  g = -jax.nn.softplus(jnp.asarray(r.randn(B, C, Hv) - 2.0, f32))
+  beta = jax.nn.sigmoid(jnp.asarray(r.randn(B, C, Hv), f32))
+  num_valid = jnp.asarray(([0, 1, C, C // 2 + 1, 1, 2]
+                           + list(r.randint(0, C + 1, B)))[:B], jnp.int32)
+  reset = jnp.asarray(([False, False, True, False, True, False]
+                       + list(r.rand(B) < 0.3))[:B])
+  args = (state, window, x, taps, g, beta, num_valid, reset)
+  kernel = compile_here(
+      functools.partial(gdn_lib.gdn_scan_pallas, interpret=rehearsal),
+      *args, mosaic_calls=1, rehearsal=rehearsal)
+  out, new, new_window = kernel(*args)
+  ref_out, ref_new, ref_window = jax.jit(gdn_lib.gdn_scan_reference)(*args)
+  check((np.asarray(new_window, np.float32)
+         == np.asarray(ref_window, np.float32)).all(),
+        "gdn_scan's two lowerings advance the window differently")
+  e_state, e_out = rel_err(new, ref_new), rel_err(out, ref_out)
+  tol_out = 2e-5 if jnp.dtype(dtype).itemsize == 4 else 1e-2
+  check(e_state <= 2e-5 and e_out <= tol_out,
+        f"gdn_scan {jnp.dtype(dtype).name}: state error {e_state:.3g}, "
+        f"output error {e_out:.3g} against the reference")
+  check((np.asarray(new)[0].view(np.uint32)
+         == np.asarray(state)[0].view(np.uint32)).all(),
+        "gdn_scan moved the state of an idle slot")
+  live = np.arange(C)[None] < np.asarray(num_valid)[:, None]
+  check(not np.asarray(out, np.float32)[~live].any(),
+        "gdn_scan wrote beyond a slot's live positions")
+  say(f"  gdn_scan slots{B} heads{Hk}|{Hv} of {d} chunk{C} "
+      f"{jnp.dtype(dtype).name}: state error {e_state:.2e}, output error "
+      f"{e_out:.2e}")
+
+
+def phase_gigachat(sizes: Sizes) -> None:
+  cell_cfg, slots, C = sizes.gigachat_cell
+  resolved = report_rules(cell_cfg, slots, C)
+  if not sizes.rehearsal:
+    check(all(i == "pallas" for i in resolved.values()),
+          f"a rule declined at the cell's shapes: {resolved}")
+  d = cell_cfg.linear_key_head_dim
+  for dtype in (jnp.float32, jnp.bfloat16):
+    # The cell's heads and chunk on 8 slots (the reference scans 32
+    # positions of 64 matrix states a slot).
+    check_gdn_scan(8, cell_cfg.linear_num_key_heads,
+                   cell_cfg.linear_num_value_heads, d, C, dtype,
+                   sizes.rehearsal)
+  cfg = sizes.gigachat_cfg
+  n_full = len(cfg.full_attention_layers)
+  n_linear = cfg.num_layers - n_full
+  n_moe = cfg.num_layers - cfg.first_k_dense
+  gap, err = serve_expert_cut(
+      sizes, GigaChat(cfg),
+      {gdn_lib.GDN_SCAN: n_linear, MOE_GMM: 2 * n_moe, SLOT_ATTN: n_full,
+       "kv_write": n_full}, "gigachat", gmm_may_decline=True)
+  say(f"PASS gigachat: gdn_scan f32 + bf16 "
+      + ("INTERPRETED" if sizes.rehearsal else "compiled")
+      + f" at the cell's heads against the reference scan; {n_linear} "
+      f"gated delta-rule layers + {n_full} gated latent attention, "
+      f"{cfg.experts_held[1]} of {cfg.n_routed_experts} experts held, "
+      f"served tokens within {gap:.1e} of the teacher-forced best (the "
+      f"chunked rule over the sequence); step logits kernels against "
+      f"reference lowerings {err:.2e}")
+
+
 # ------------------------------------------------------------------ glm5 --
 
 
@@ -2035,8 +2158,8 @@ def main(argv=None) -> int:
   parser.add_argument(
       "--only", default=None,
       help="run this one phase (kernels, train, serve, hybrid, experts, "
-           "lfm2, dots3, smallthinker, glm5, overlap); prints no result "
-           "line")
+           "lfm2, dots3, smallthinker, gigachat, glm5, overlap); prints no "
+           "result line")
   args = parser.parse_args(argv)
   t_start = time.perf_counter()
   cache_dir = compile_cache.configure()
@@ -2064,6 +2187,7 @@ def main(argv=None) -> int:
                       ("lfm2", lambda: phase_lfm2(sizes)),
                       ("dots3", lambda: phase_dots3(sizes)),
                       ("smallthinker", lambda: phase_smallthinker(sizes)),
+                      ("gigachat", lambda: phase_gigachat(sizes)),
                       ("glm5", lambda: phase_glm5(sizes)),
                       ("overlap", lambda: phase_overlap(sizes))):
     if args.only not in (None, name):

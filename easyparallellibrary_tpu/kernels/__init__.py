@@ -2,6 +2,8 @@ from easyparallellibrary_tpu.kernels.flash_attention import (
     flash_attention, flash_attention_qkv)
 from easyparallellibrary_tpu.kernels.dsa_index import (
     dsa_index_pallas, dsa_index_reference)
+from easyparallellibrary_tpu.kernels.gdn_scan import (
+    gdn_scan_pallas, gdn_scan_reference)
 from easyparallellibrary_tpu.kernels.kv_write import (
     kv_write_pallas, kv_write_reference)
 from easyparallellibrary_tpu.kernels.moe_gmm import (
@@ -18,6 +20,7 @@ from easyparallellibrary_tpu.kernels.paged_attention import (
 __all__ = [
     "dsa_index_pallas", "dsa_index_reference",
     "flash_attention", "flash_attention_qkv",
+    "gdn_scan_pallas", "gdn_scan_reference",
     "kv_write_pallas", "kv_write_reference",
     "moe_gmm_pallas", "moe_gmm_reference",
     "paged_attention", "paged_attention_pallas",

@@ -10,6 +10,9 @@ from easyparallellibrary_tpu.models.dots3_note import (
 from easyparallellibrary_tpu.models.smallthinker import (
     SmallThinker, SmallThinkerConfig,
 )
+from easyparallellibrary_tpu.models.gigachat import (
+    GigaChat, GigaChatConfig,
+)
 from easyparallellibrary_tpu.models.bert import (
     Bert, BertConfig, bert_large_config,
 )
@@ -24,6 +27,7 @@ __all__ = [
     "Lfm2Moe", "Lfm2MoeConfig",
     "Dots3Note", "Dots3NoteConfig",
     "SmallThinker", "SmallThinkerConfig",
+    "GigaChat", "GigaChatConfig",
     "Bert", "BertConfig", "bert_large_config",
     "ResNet", "ResNetConfig", "resnet18_config", "resnet50_config",
 ]

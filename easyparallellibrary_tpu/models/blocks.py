@@ -1,7 +1,7 @@
 """The blocks more than one decoder is built from, below every decoder
 file (each imports this module; it imports none of them and nothing of
 ``serving/``): the two norms, the SiLU-gated MLP, the parameter helpers,
-rotary positions, the full forward's grouped attention, a convolution
+rotary positions (plain and YaRN's), the full forward's grouped attention, a convolution
 window's advance, a window ring's length, and multi-head latent attention
 with its sizes (:class:`LatentAttention`, :class:`LatentDims`,
 :class:`IndexerDims`).  Class names are flax scope and parameter-path
@@ -15,6 +15,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from easyparallellibrary_tpu.models.slot_core import missing_slot_cache
@@ -79,31 +80,97 @@ class LayerNorm(HeldParams, nn.Module):
     return y.astype(self.dtype)
 
 
+def clamp_gate_up(gate, up, limit: Optional[float]):
+  """A gated MLP's two pre-activations held to ``limit`` before their
+  product (``swiglu_limit``, models/gigachat.py): the gate at most
+  ``limit``, the up projection within ``+-limit``; ``None`` leaves both."""
+  if limit is None:
+    return gate, up
+  return jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+
+
 class GatedMLP(nn.Module):
   """``down(silu(gate(h)) * up(h))``; ``cfg`` gives ``d_model`` and the
   dtypes, ``d_ff`` the width where a model has more than one
-  (models/glm_moe.py: its dense layer and its shared expert)."""
+  (models/glm_moe.py: its dense layer and its shared expert), ``limit``
+  what the two pre-activations are held to (:func:`clamp_gate_up`)."""
   cfg: Any
   d_ff: Optional[int] = None
+  limit: Optional[float] = None
 
   @nn.compact
   def __call__(self, h):
     cfg = self.cfg
     d_ff = self.d_ff or cfg.d_ff
-    gate = dense(cfg, d_ff, "gate")(h)
-    up = dense(cfg, d_ff, "up")(h)
+    gate, up = clamp_gate_up(dense(cfg, d_ff, "gate")(h),
+                             dense(cfg, d_ff, "up")(h), self.limit)
     return dense(cfg, cfg.d_model, "down")(jax.nn.silu(gate) * up)
 
 
-def rotary(x, positions, theta: float):
+@dataclasses.dataclass(frozen=True)
+class YarnDims:
+  """YaRN's rescaling of rotary positions (Peng et al. 2023, as
+  DeepSeek-V3's modelling code has it, a config's ``rope_scaling`` of
+  ``type: yarn``): the slow pairs' frequencies divided by ``factor``, the
+  fast ones kept, a linear ramp between the pairs that turn
+  ``beta_fast`` and ``beta_slow`` times over ``original_max_position``.
+  ``scale_softmax`` (``use_mla_scaling_factor``): the attention's softmax
+  scale times ``mscale(factor, mscale_all_dim)`` squared."""
+  factor: float
+  original_max_position: int
+  beta_fast: float = 32.0
+  beta_slow: float = 1.0
+  mscale: float = 1.0
+  mscale_all_dim: float = 0.0
+  scale_softmax: bool = True
+
+  @staticmethod
+  def get_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * float(np.log(factor)) + 1.0
+
+  @property
+  def amplitude(self) -> float:
+    """What cos and sin are multiplied by."""
+    return (self.get_mscale(self.factor, self.mscale)
+            / self.get_mscale(self.factor, self.mscale_all_dim))
+
+  @property
+  def softmax_factor(self) -> float:
+    if not (self.scale_softmax and self.mscale_all_dim):
+      return 1.0
+    return self.get_mscale(self.factor, self.mscale_all_dim) ** 2
+
+  def frequencies(self, d: int, theta: float) -> np.ndarray:
+    """The ``d / 2`` pair frequencies, float32."""
+    pairs = np.arange(d // 2, dtype=np.float64)
+    extra = theta ** (-2.0 * pairs / d)
+    turn = lambda rotations: (d * np.log(
+        self.original_max_position / (rotations * 2 * np.pi))
+                              / (2 * np.log(theta)))
+    low = max(np.floor(turn(self.beta_fast)), 0)
+    high = min(np.ceil(turn(self.beta_slow)), d - 1)
+    if low == high:
+      high += 0.001
+    keep = 1.0 - np.clip((pairs - low) / (high - low), 0.0, 1.0)
+    return (extra / self.factor * (1.0 - keep) + extra * keep).astype(
+        np.float32)
+
+
+def rotary(x, positions, theta: float, yarn: Optional[YarnDims] = None):
   """Rotate-half rotary embedding over ALL of ``x``'s last axis: ``x``
   ``[B, S, H, d]``, ``positions`` int ``[B, S]``; pair ``i`` is ``(x[i],
-  x[i + d/2])`` turned by ``position * theta^(-2i/d)``.  float32 inside."""
+  x[i + d/2])`` turned by ``position * theta^(-2i/d)``, or by
+  ``yarn``'s frequency table (:class:`YarnDims`).  float32 inside."""
   d = x.shape[-1]
-  freq = jnp.exp(jnp.arange(d // 2, dtype=jnp.float32)
-                 * (-2.0 * jnp.log(theta) / d))
+  if yarn is None:
+    freq = jnp.exp(jnp.arange(d // 2, dtype=jnp.float32)
+                   * (-2.0 * jnp.log(theta) / d))
+  else:
+    freq = jnp.asarray(yarn.frequencies(d, theta))
   ang = positions.astype(jnp.float32)[:, :, None, None] * freq
   cos, sin = jnp.cos(ang), jnp.sin(ang)
+  if yarn is not None and yarn.amplitude != 1.0:
+    cos, sin = cos * yarn.amplitude, sin * yarn.amplitude
   x32 = x.astype(jnp.float32)
   a, b = x32[..., :d // 2], x32[..., d // 2:]
   return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
@@ -179,7 +246,11 @@ class LatentDims:
   (models/glm_moe.py ``glm_latent_dims``); a model whose layers differ
   (models/dots3_note.py) hands each layer its own.  ``q_rescale`` /
   ``kv_rescale`` multiply the two normed latents; ``gate`` adds a sigmoid
-  gate, one value a head, on the heads' outputs; ``window`` limits a query
+  gate on the heads' outputs, from a projection of the layer's input:
+  ``True`` one value a head (models/dots3_note.py), ``"elementwise"`` one a
+  value of every head (models/gigachat.py); ``yarn`` rescales the rotary
+  positions and, where it says so, the softmax scale; ``window`` limits a
+  query
   at ``t`` to the positions ``t - window < s <= t`` (slot mode then keeps
   the latent leaf as a ring); ``indexer`` limits it to the rows an indexer
   selects."""
@@ -192,13 +263,15 @@ class LatentDims:
   rope_theta: float
   q_rescale: float = 1.0
   kv_rescale: float = 1.0
-  gate: bool = False
+  gate: Any = False
   window: Optional[int] = None
   indexer: Optional[IndexerDims] = None
+  yarn: Optional[YarnDims] = None
 
   @property
   def scale(self) -> float:
-    return float(self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+    scale = float(self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+    return scale if self.yarn is None else scale * self.yarn.softmax_factor
 
   @property
   def latent_dim(self) -> int:
@@ -237,8 +310,14 @@ class LatentAttention(HeldParams, nn.Module):
                 r, H, dn + dv))
       return held[0]
 
+    elementwise = dims.gate == "elementwise"
+
     def gated_out(out, gate):
-      if gate is not None:
+      if elementwise:
+        # carried as the projection left it; one sigmoid a value here
+        gate = jax.nn.sigmoid(gate.astype(jnp.float32)).reshape(out.shape)
+        out = (out.astype(jnp.float32) * gate).astype(cfg.dtype)
+      elif gate is not None:
         out = (out.astype(jnp.float32) * gate[..., None]).astype(cfg.dtype)
       return dense(cfg, cfg.d_model, "o")(
           out.reshape(*out.shape[:2], H * dv))
@@ -253,10 +332,11 @@ class LatentAttention(HeldParams, nn.Module):
           dense(cfg, dims.q_lora_rank, "q_a")(h))
       q = dense(cfg, H * (dn + dr), "q_b")(c_q).reshape(B, S, H, dn + dr)
       q_nope = q[..., :dn]
-      q_rope = rotary(q[..., dn:], positions, dims.rope_theta)
+      q_rope = rotary(q[..., dn:], positions, dims.rope_theta, dims.yarn)
       kv = dense(cfg, r + dr, "kv_a")(h)
       c = norm("kv_norm", dims.kv_rescale)(kv[..., :r])
-      k_r = rotary(kv[..., None, r:], positions, dims.rope_theta)  # [B,S,1,dr]
+      k_r = rotary(kv[..., None, r:], positions, dims.rope_theta,
+                   dims.yarn)                                # [B,S,1,dr]
       if ix is not None:
         # The indexer: index queries from the query latent, ONE index key a
         # position from the layer's input, a weight an index head.
@@ -269,9 +349,13 @@ class LatentAttention(HeldParams, nn.Module):
                 dense(cfg, ix.head_dim, "index_k")(h))[:, :, None],
             positions, dims.rope_theta, ix.rope_dim)[:, :, 0]
         w_ix = dense(cfg, ix.num_heads, "index_w")(h).astype(jnp.float32)
-      # One value a head, from the layer's input, on the heads' outputs.
-      gate = None if not dims.gate else jax.nn.sigmoid(
-          dense(cfg, H, "gate")(h).astype(jnp.float32))
+      # From the layer's input, on the heads' outputs: one value a head,
+      # or one a value of every head.
+      if elementwise:
+        gate = dense(cfg, H * dv, "gate")(h)
+      else:
+        gate = None if not dims.gate else jax.nn.sigmoid(
+            dense(cfg, H, "gate")(h).astype(jnp.float32))
       if not self.decode:
         return gated_out(self._dense_attend(
             q_nope, q_rope, c, k_r, w_kvb(),

@@ -16,6 +16,9 @@ ATTENTION, MAMBA, CONV = "attention", "mamba", "conv"
 LATENT, SPARSE_LATENT, WINDOW_LATENT = "latent", "sparse_latent", "window_latent"
 # The K/V pair as a ring behind a window (models/smallthinker.py).
 WINDOW_KV = "window_kv"
+# A gated delta-rule linear attention's convolution window and its state, a
+# float32 matrix a value head (models/gigachat.py).
+GATED_DELTA = "gated_delta"
 
 # ``layer_types`` of a published config whose layers differ in their latent
 # attention (models/dots3_note.py): what ``cfg.latent_dims`` is asked by.

@@ -415,13 +415,20 @@ def sort_by_expert(chosen, live, num_experts: int, first: int = 0):
   return order, jnp.diff(ends, prepend=0)
 
 
+def _up(up, h):
+  """An expert's up projection through what the model does to it before
+  the product (``cfg.expert_up``; ``None``: nothing)."""
+  return h if up is None else up(h)
+
+
 def dropless_experts(x, chosen, weights, live, w_gate_up, w_down,
                      impl: Optional[str] = None, first: int = 0,
-                     gate=jax.nn.silu):
+                     gate=jax.nn.silu, up=None):
   """``sum_i weights[n, i] * Expert_{chosen[n, i]}(x[n])`` for the live
   positions of ``x`` ``[N, D]``, each expert a gated MLP, ``W_down
-  (gate(x W_gate) * (x W_up))`` (``gate``: SiLU; models/smallthinker.py's
-  is ReLU): ``w_gate_up`` ``[E, D, 2 F]`` (gate columns, then up),
+  (gate(x W_gate) * up(x W_up))`` (``gate``: SiLU; models/smallthinker.py's
+  is ReLU; ``up``: nothing; models/gigachat.py clamps both): ``w_gate_up``
+  ``[E, D, 2 F]`` (gate columns, then up),
   ``w_down`` ``[E, F, D]``.  The stacks hold the router's experts ``[first, first + E)``;
   the sum runs over the chosen experts among them (an absent expert's
   term is another chip's: nothing stands in for it).  Returns ``(y [N,
@@ -434,7 +441,7 @@ def dropless_experts(x, chosen, weights, live, w_gate_up, w_down,
   order, sizes = sort_by_expert(chosen, live, E, first)
   rows = x[order // k]                                    # [N k, D]
   h = moe_gmm(rows, w_gate_up, sizes, impl=impl)
-  h = gate(h[:, :F2 // 2]) * h[:, F2 // 2:]
+  h = gate(h[:, :F2 // 2]) * _up(up, h[:, F2 // 2:])
   out = moe_gmm(h, w_down, sizes, impl=impl)              # [N k, D]
   # Back to position order: the inverse of a permutation is its argsort
   # (a second small sort; a scatter is a serial loop on a TPU), then a
@@ -459,7 +466,7 @@ def exchange_rows(positions: int, top_k: int, experts_here: int,
 
 def exchanged_experts(x, chosen, weights, live, w_gate_up, w_down,
                       axis: str, rows: int, impl: Optional[str] = None,
-                      first: int = 0, gate=jax.nn.silu):
+                      first: int = 0, gate=jax.nn.silu, up=None):
   """:func:`dropless_experts` with the held experts DIVIDED over the mesh
   axis ``axis`` (called inside a ``shard_map`` over it): chip ``j`` of
   ``n`` holds the router's experts ``[first + j E_l, first + (j + 1)
@@ -535,7 +542,7 @@ def exchanged_experts(x, chosen, weights, live, w_gate_up, w_down,
         - jnp.clip(src_ends - mine, lo, lo + C), axis=0).astype(i32)
     rows2 = jnp.take(got.reshape(n * C, D), order2, axis=0)
     h = moe_gmm(rows2, w_gate_up, sizes2, impl=impl)
-    h = gate(h[:, :F2 // 2]) * h[:, F2 // 2:]
+    h = gate(h[:, :F2 // 2]) * _up(up, h[:, F2 // 2:])
     out = moe_gmm(h, w_down, sizes2, impl=impl)                 # [n C, D]
     back = jnp.take(out, jnp.argsort(order2), axis=0)
     with jax.named_scope("moe_combine"):
@@ -575,8 +582,12 @@ class DroplessMoE(HeldParams, nn.Module):
   router_kernel, top_k) -> (chosen, weights)``, e.g.
   :func:`softmax_topk_route`; absent: :func:`noaux_tc_route`, whose bias
   is then in the tree and which reads ``routed_scaling_factor``,
-  ``norm_topk_prob`` and ``route_norm_eps``) and ``cfg.expert_gate`` (the
-  gate's activation; absent: SiLU).  ``router_in`` (``None``: ``x``) is what
+  ``norm_topk_prob`` and ``route_norm_eps``), ``cfg.expert_gate`` (the
+  gate's activation; absent: SiLU), ``cfg.expert_up`` (what the up
+  projection passes through before the product; absent: nothing) and
+  ``cfg.swiglu_limit`` (the shared expert's clamp of both,
+  models/blocks.py ``clamp_gate_up``; absent: none).  ``router_in``
+  (``None``: ``x``) is what
   the ROUTER reads where that is not what the experts read
   (models/smallthinker.py routes from the layer's input, before its
   attention).
@@ -649,10 +660,11 @@ class DroplessMoE(HeldParams, nn.Module):
     stacks = (jnp.asarray(w_gate_up, cfg.dtype),
               jnp.asarray(w_down, cfg.dtype))
     gate = getattr(cfg, "expert_gate", jax.nn.silu)
+    up = getattr(cfg, "expert_up", None)
     if axis is None:
       y, sizes = dropless_experts(
           flat, chosen, weights, flat_live, *stacks, impl=self.moe_gmm_impl,
-          first=first, gate=gate)
+          first=first, gate=gate, up=up)
       total = jnp.sum(sizes).astype(jnp.float32)
       if held is not None:
         self.sow("stats", "held_assignments", total)
@@ -660,7 +672,7 @@ class DroplessMoE(HeldParams, nn.Module):
       y, sizes, sent, left, rounds = exchanged_experts(
           flat, chosen, weights, flat_live, *stacks, axis=axis,
           rows=exchange_rows(flat.shape[0], k, E, cfg.n_routed_experts),
-          impl=self.moe_gmm_impl, first=first, gate=gate)
+          impl=self.moe_gmm_impl, first=first, gate=gate, up=up)
       # ``sizes``: the rows this chip's experts were SENT, by all chips.
       total = jnp.sum(sizes).astype(jnp.float32)
       self.sow("stats", "held_assignments", sent.astype(jnp.float32))
@@ -675,5 +687,7 @@ class DroplessMoE(HeldParams, nn.Module):
              jnp.sum(sizes > 0).astype(jnp.float32))
     if not cfg.n_shared_experts:
       return y.reshape(x.shape)
-    shared = GatedMLP(cfg, d_ff=cfg.n_shared_experts * F, name="shared")(x)
+    shared = GatedMLP(cfg, d_ff=cfg.n_shared_experts * F,
+                      limit=getattr(cfg, "swiglu_limit", None),
+                      name="shared")(x)
     return shared + y.reshape(x.shape)

@@ -478,7 +478,8 @@ def slot_step_logits(model, params, kv, tokens, cursors,
   takes token ``i``'s position from the same ``cursors[b] + i``.
   ``state_args`` go to a model that asks for more (models/jamba.py:
   ``reset``, ``ssm_scan_impl``; models/glm_moe.py: ``moe_gmm_impl``;
-  models/lfm2_moe.py: ``reset`` AND ``moe_gmm_impl``; ``expert_axis``,
+  models/lfm2_moe.py: ``reset`` AND ``moe_gmm_impl``; models/gigachat.py:
+  ``reset``, ``gdn_scan_impl`` and ``moe_gmm_impl``; ``expert_axis``,
   the mesh axis a divided engine's step is mapped over, to a model whose
   expert layers exchange rows over it); a GPT takes none.  ``stats`` also
   returns what the model sowed into its ``stats``

@@ -178,7 +178,8 @@ def check_recurrent_state(cfg, feature: str) -> None:
   """Reject ``feature`` (the paged cache, prefix caching, speculative
   decoding, the guarded retry, a draft model) for a model some of whose
   layers keep recurrent state (``cfg.layer_kinds()``: models/jamba.py's
-  ``mamba``, models/lfm2_moe.py's ``conv``): each of them takes a request back to an earlier position by moving a
+  ``mamba``, models/lfm2_moe.py's ``conv``, models/gigachat.py's
+  ``gated_delta``): each of them takes a request back to an earlier position by moving a
   cursor or dropping blocks, and stale recurrent state is masked by
   nothing.  ONE message for every such composition.  Preemption by
   replay (scheduler.requeue_slot) is not among them: a replay starts

@@ -533,6 +533,10 @@ class ContinuousBatchingEngine:
     # (``_sparse``: a selecting layer's selection and the latent window;
     # ``_kv_window``: the window of K/V rings beside full layers).
     self._recurrent = kv_lib.has_recurrent_state(cfg)
+    # A matrix state whose update has two forms (kernels/gdn_scan.py): how
+    # many slots advance and how many positions run the chunk's form are
+    # counted.
+    self._delta = self.lowerings["gdn_scan_impl"] is not None
     self._experts = self.lowerings["moe_gmm_impl"] is not None
     self._sparse = ((cfg.index_topk, cfg.sliding_window)
                     if self.lowerings["dsa_index_impl"] is not None else None)
@@ -1982,6 +1986,14 @@ class ContinuousBatchingEngine:
         # Slots whose recurrent state this step zeroed: requests that
         # started (or restarted, after a requeue) here.
         tracer.counter("serving/state_resets", int(plan.reset.sum()))
+      if self._delta:
+        # Slots whose matrix state advanced this step, and the live
+        # positions of those that fed more than one: the chunk's form of
+        # the delta rule (a slot that fed one ran one position's).
+        tracer.counter("serving/state_slots",
+                       int((plan.num_valid > 0).sum()))
+        tracer.counter("serving/state_chunk_positions", int(
+            plan.num_valid[plan.num_valid > 1].sum()))
       if self._experts:
         # Live positions the step handed its expert layers (each goes to
         # ``num_experts_per_tok`` experts), and how unevenly they fell.

@@ -20,7 +20,7 @@ never attendable because the mask only exposes positions the current
 request's own tokens have written (see slot_cache_attend's docstring;
 tests/test_serving.py asserts the no-leakage property).
 
-Seven kinds of per-slot state live in the contiguous cache, chosen per
+Eight kinds of per-slot state live in the contiguous cache, chosen per
 layer from the model's own layer kinds (:func:`cache_leaves`).  Three are
 rows under the slot's cursor: the K/V pair of an attention layer; the
 LATENT leaf of multi-head latent attention (models/glm_moe.py), which is
@@ -35,12 +35,15 @@ attention behind a window, whose width and head count are its own, and
 the WINDOW_KV pair of an attention layer behind a window
 (models/smallthinker.py), ``cached_key`` / ``cached_value`` as an
 attention layer's in everything but their length, beside ordinary
-attention leaves in the same model: two kinds of K/V in one cache.  Two
+attention leaves in the same model: two kinds of K/V in one cache.  Three
 are recurrent, with no position axis, and no cursor can roll them back
 (serving/_capabilities.py ``check_recurrent_state``): a Mamba layer's
-convolution window and float32 scan state (models/jamba.py), and a CONV
+convolution window and float32 scan state (models/jamba.py), a CONV
 layer's window alone, the whole state of a gated short convolution
-(models/lfm2_moe.py).
+(models/lfm2_moe.py), and a GATED_DELTA layer's convolution window and its
+state, a float32 matrix a value head (models/gigachat.py: 4.19 MB a slot
+and layer whatever the context, beside the LATENT leaf of the model's one
+full attention in four: recurrent and latent leaves in one cache).
 
 Placement: the cache is materialized directly into its sharded layout on
 the mesh (same jit-with-out-shardings trick as
@@ -84,12 +87,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from easyparallellibrary_tpu import constants
 from easyparallellibrary_tpu.models.layer_kinds import (
-    ATTENTION, CONV, FULL, LATENT, MAMBA, SLIDING, SPARSE_LATENT, WINDOW_KV,
-    WINDOW_LATENT)
+    ATTENTION, CONV, FULL, GATED_DELTA, LATENT, MAMBA, SLIDING,
+    SPARSE_LATENT, WINDOW_KV, WINDOW_LATENT)
 
 # Layer kinds whose state is a recurrence's: no position axis, nothing a
 # cursor can roll back.
-RECURRENT = (MAMBA, CONV)
+RECURRENT = (MAMBA, CONV, GATED_DELTA)
 # Layer kinds that keep latent rows in place of a K/V pair: one leaf under
 # the cursor; that leaf and the indexer's keys; one ring.
 LATENT_KINDS = (LATENT, SPARSE_LATENT, WINDOW_LATENT)
@@ -126,8 +129,9 @@ def layer_kinds(cfg) -> Tuple[str, ...]:
 
 def recurrent_kinds(cfg) -> Tuple[str, ...]:
   """The kinds of recurrent state the model's layers keep (a Mamba layer's
-  window and scan state, a conv layer's window), in :data:`RECURRENT`'s
-  order: what a refusal names."""
+  window and scan state, a conv layer's window, a gated delta layer's
+  window and matrix state), in :data:`RECURRENT`'s order: what a refusal
+  names."""
   kinds = layer_kinds(cfg)
   return tuple(kind for kind in RECURRENT if kind in kinds)
 
@@ -177,6 +181,13 @@ def latent_leaf_shapes(cfg, kind: str, num_slots: int,
                             dims.latent_dim)}
 
 
+def delta_state_shape(cfg, num_slots: int) -> Tuple[int, int, int, int]:
+  """A gated delta layer's state: ``[num_slots, Hv, dk, dv]``, value-head
+  major, so that a head's matrix is one run of tiles."""
+  return (num_slots, cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+          cfg.linear_value_head_dim)
+
+
 def kv_heads(cfg) -> Tuple[int, int]:
   """``(H_kv, hd)`` of one cache row under a cursor: the model's K/V head
   count (its query heads when it has no fewer) and the head size, the
@@ -202,7 +213,9 @@ def kv_leaf_shape(cfg, num_slots: int, chunk: int,
   (module docstring, order note): a K/V pair whose heads' width ``H_kv x
   hd`` is a whole number of 128-lane tiles, in a 16-bit or 32-bit float,
   is kept in rows, ``[num_slots, Lc, H_kv x hd]``; every other leaf (a
-  narrower pair, the latent leaf) ``[num_slots, Lc, H_kv, hd]``.  ``ring``:
+  narrower pair, the latent leaf) ``[num_slots, Lc, H_kv, hd]``, and where
+  the slots fill whole lane tiles with ``Lc`` up to whole lane tiles of rows
+  (below).  ``ring``:
   a window layer's leaf of the same pair, ``cfg.ring_length(chunk)`` rows
   in place of ``Lc`` (kind :data:`WINDOW_KV`)."""
   if SPARSE_LATENT in layer_kinds(cfg):
@@ -215,6 +228,17 @@ def kv_leaf_shape(cfg, num_slots: int, chunk: int,
       and jnp.dtype(cfg.dtype) in (jnp.dtype(jnp.bfloat16),
                                    jnp.dtype(jnp.float32))):
     return lead + (Hkv * hd,)
+  if not ring and num_slots % 128 == 0:
+    # A leaf kept in positions (a ring's length is the window's business).
+    # The TPU keeps as an array's minor dimension the one that pads least.
+    # Slots that fill whole lane tiles would win over a length that does
+    # not (4096 + 32 rows pad to 4224), the leaf would lie SLOT-minor, and
+    # every step would copy it to the position-minor order the write and
+    # the attend read, and back (0.6 GB twice a step at 128 slots of 4128
+    # rows: seen in the step compiled for a described v5e).  So such a
+    # leaf is allocated up to whole lane tiles of rows: nothing reads the
+    # rows past ``cache_length``, and no padding is left to prefer.
+    lead = (num_slots, -(-lead[1] // 128) * 128)
   return lead + (Hkv, hd)
 
 
@@ -234,6 +258,11 @@ def cache_leaves(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
   * conv: ``{"conv": {"conv_state": [num_slots, conv_L_cache - 1,
     d_model]}}`` in the compute dtype (the last products the gated short
     convolution carries), the layer's whole state;
+  * gated delta: ``{"linear": {"conv_state": [num_slots,
+    linear_conv_kernel_dim - 1, 2 Hk dk + Hv dv]`` in the compute dtype
+    (the last inputs of the convolution over queries, keys and values),
+    ``"delta_state": [num_slots, Hv, dk, dv]`` float32}}`` (a matrix a
+    value head, value-head major), no position axis;
   * latent: ``{"latent": {"cached_latent": [num_slots, Lc, 1,
     kv_lora_rank + qk_rope_head_dim]}}`` in the compute dtype, read under
     the slot's cursor as keys and, its leading ``kv_lora_rank`` columns,
@@ -278,6 +307,13 @@ def cache_leaves(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
       out[f"block_{i}"] = {"conv": {
           "conv_state": jax.ShapeDtypeStruct(
               (num_slots, cfg.conv_L_cache - 1, cfg.d_model), cfg.dtype)}}
+    elif kind == GATED_DELTA:
+      out[f"block_{i}"] = {"linear": {
+          "conv_state": jax.ShapeDtypeStruct(
+              (num_slots, cfg.linear_conv_kernel_dim - 1,
+               cfg.linear_conv_dim), cfg.dtype),
+          "delta_state": jax.ShapeDtypeStruct(
+              delta_state_shape(cfg, num_slots), jnp.float32)}}
     else:
       raise ValueError(f"layer {i}: no cache for layer kind {kind!r}")
   return out
@@ -497,6 +533,22 @@ def ssm_scan_impl(cfg, num_slots: int, chunk: int,
       sharded=mesh is not None and mesh.size > 1)
 
 
+def gdn_scan_impl(cfg, num_slots: int, chunk: int,
+                  mesh: Optional[Mesh] = None) -> Optional[str]:
+  """The lowering of the fused step's gated delta rule over the matrix
+  state :func:`allocate_kv_cache` builds — the dispatch rule of
+  kernels/gdn_scan.py applied to its ``delta_state`` leaf and the
+  convolution's width, resolved once like :func:`kv_write_impl`; ``None``
+  for a model without such a layer."""
+  if GATED_DELTA not in layer_kinds(cfg):
+    return None
+  from easyparallellibrary_tpu.kernels.gdn_scan import (
+      resolve_gdn_scan_impl)
+  return resolve_gdn_scan_impl(
+      delta_state_shape(cfg, num_slots), cfg.linear_conv_dim, cfg.dtype,
+      chunk, sharded=mesh is not None and mesh.size > 1)
+
+
 def moe_gmm_impl(cfg, num_slots: int, chunk: int,
                  mesh: Optional[Mesh] = None) -> Optional[str]:
   """The lowering of the fused step's grouped matmuls over a dropless
@@ -541,7 +593,7 @@ def tile_attn_out(cfg, impl: Optional[str], narrower: bool) -> Optional[str]:
 # in.  A rule's name is at once the decoders' keyword, the trace metadata's
 # suffix (``serving/<name>``) and the diagnostic bundle's key.
 _RULES = (kv_write_impl, slot_attn_impl, kv_win_write_impl, kv_win_attn_impl,
-          dsa_index_impl, ssm_scan_impl, moe_gmm_impl)
+          dsa_index_impl, ssm_scan_impl, gdn_scan_impl, moe_gmm_impl)
 
 
 def step_lowerings(cfg, num_slots: int, chunk: int,
@@ -624,7 +676,8 @@ def allocate_kv_cache(cfg, num_slots: int, chunk: int,
 def cache_layout(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
   """What the slot cache holds, by kind of state: bytes and leaves of
   K/V (under a cursor), of recurrent state (no position axis: ``state_*``
-  counts a Mamba layer's two leaves and a conv layer's one) and, for a
+  counts a Mamba layer's two leaves, a conv layer's one and a gated delta
+  layer's two, whatever else the model keeps beside them) and, for a
   model that has them, of latent rows (under a cursor, one leaf a layer),
   of an indexer's keys (``index_*``, under the cursor beside a latent
   leaf) and of window rings (``window_*``, latent rows or K/V pairs, whose
@@ -635,7 +688,7 @@ def cache_layout(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
   and of the attend a step runs.  The engine records it (trace metadata
   ``serving/cache_layout``)."""
   names = {ATTENTION: "kv", MAMBA: "state", CONV: "state",
-           LATENT: "latent", SPARSE_LATENT: "latent",
+           GATED_DELTA: "state", LATENT: "latent", SPARSE_LATENT: "latent",
            WINDOW_LATENT: "window", WINDOW_KV: "window"}
   kinds = layer_kinds(cfg)
   groups = ["kv", "state"]
@@ -660,8 +713,8 @@ def cache_layout(cfg, num_slots: int, chunk: int) -> Dict[str, Any]:
 
 def cache_bytes(cfg, num_slots: int, chunk: int) -> int:
   """Total cache footprint in bytes (every leaf of every layer: K and V,
-  convolution and scan state) — the number the admission knobs trade
-  against HBM."""
+  latent and index rows, rings, convolution windows, scan and delta state)
+  — the number the admission knobs trade against HBM."""
   layout = cache_layout(cfg, num_slots, chunk)
   return sum(v for k, v in layout.items() if k.endswith("_bytes"))
 

@@ -967,6 +967,47 @@ def test_smallthinker_step_compiled_for_v5e_holds_its_kernels(one_chip):
   assert not re.search(rf"\[{slots},28,{C},(4224|16416)\]", text)
 
 
+def test_gigachat_step_compiled_for_v5e_holds_its_kernels(one_chip):
+  """The fused step of a two-layer cut (a linear layer + dense, a full layer
+  + 16 held experts of 256) of models/gigachat.py at GigaChat3.5's widths
+  and its cell's geometry, 128 slots x chunk 32 at a context of 4096,
+  compiled for a described v5e as the engine builds it, at two widths: one
+  ``gdn_scan`` whose state operand aliases its output, one ``kv_write`` and
+  one ``slot_attn`` over the latent leaf, each outside the conditionals and
+  in the program ONCE (models/slot_core.py ``SplitLayer``); two ``moe_gmm``
+  on either side of a conditional; no copy of the 537 MB matrix state nor
+  of the latent leaf, which at 128 slots is allocated 4224 rows long so
+  that the chip keeps it position-minor (serving/kv_cache.py
+  ``kv_leaf_shape``); no ``while`` (the reference scan is one)."""
+  from easyparallellibrary_tpu.models.gigachat import (
+      GigaChat, GigaChatConfig)
+  epl.init()
+  slots, C = 128, 32
+  cfg = GigaChatConfig(vocab_size=16032, num_layers=2,
+                       full_attention_layers=(1,), first_k_dense=1,
+                       experts_held=(0, 16), max_seq_len=4096)
+  step, args = _abstract_step(GigaChat(cfg), slots, C, one_chip,
+                              moe_gmm_impl="pallas", _experts=True,
+                              gdn_scan_impl="pallas", _recurrent=True)
+  text = _compiled_text(step, *args)
+  calls = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
+  assert [calls(n) for n in ("gdn_scan", "kv_write", "slot_attn",
+                             "moe_gmm")] == [1, 1, 1, 4], text.count(
+                                 "tpu_custom_call")
+  assert " while(" not in text
+  _assert_no_leaf_copied(text, args[1])
+  kv = args[1]
+  assert kv["block_0"]["linear"]["delta_state"].shape == (slots, 64, 128,
+                                                          128)
+  assert kv["block_0"]["linear"]["conv_state"].shape == (slots, 3, 16384)
+  assert kv["block_1"]["latent"]["cached_latent"].shape == (slots, 4224, 1,
+                                                            576)
+  scan = [l for l in text.splitlines() if re.match(r"\s*%gdn_scan", l)][0]
+  # operand 2 (after the two scalar-prefetch vectors) is the state,
+  # output 1 the new state
+  assert "{1}: (2, {})" in scan, scan
+
+
 def _flat_cuts():
   """Two-layer cuts of the four decoders at their cells' widths and, but
   for the expert decoder's chunk, geometry: ``name -> (model, slots, C, engine attributes, kernel calls a
@@ -1048,7 +1089,8 @@ def test_flat_step_for_v5e_multiplies_the_width_and_heads_the_slots(
     ("gpt2m-chat-steady", None), ("jamba2-3b-reasoning-backlog", None),
     ("glm47flash-agent-backlog", 16),
     ("lfm2moe-chat-steady", None), ("dots3note-longdoc-backlog", None),
-    ("smallthinker-mixedlen-backlog", None)])
+    ("smallthinker-mixedlen-backlog", None),
+    ("gigachat35-decode-backlog", None)])
 def test_a_serving_cells_step_compiled_for_v5e_copies_no_leaf(one_chip, cell,
                                                               chunk):
   """The step of each serving configuration of the benchmark AT ITS FULL
@@ -1090,6 +1132,8 @@ def test_a_serving_cells_step_compiled_for_v5e_copies_no_leaf(one_chip, cell,
       engine.update(dsa_index_impl="pallas")
     if family == "smallthinker":
       engine.update(kv_win_write_impl="pallas", kv_win_attn_impl="pallas")
+    if family == "gigachat3_5":
+      engine.update(gdn_scan_impl="pallas")
   slots = cell_file["engine"]["num_slots"]
   C = chunk or cell_file["engine"]["prefill_chunk"]
   T = flat_width(slots, C)

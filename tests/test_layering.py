@@ -13,7 +13,7 @@ import pytest
 PKG = "easyparallellibrary_tpu"
 ROOT = pathlib.Path(__file__).resolve().parents[1] / PKG
 DECODERS = ("gpt", "jamba", "glm_moe", "lfm2_moe", "dots3_note",
-            "smallthinker")
+            "smallthinker", "gigachat")
 BELOW_THE_DECODERS = ("slot_core", "blocks", "layer_kinds")
 # The slot-mode core's names (models/slot_core.py).
 CORE = {"slot_cache_attend", "PagedInfo", "paged_cache_attend",

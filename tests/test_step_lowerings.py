@@ -25,7 +25,7 @@ from easyparallellibrary_tpu.serving import (  # noqa: E402
 
 LOWERINGS = ["kv_write_impl", "slot_attn_impl", "kv_win_write_impl",
              "kv_win_attn_impl", "dsa_index_impl", "ssm_scan_impl",
-             "moe_gmm_impl"]
+             "gdn_scan_impl", "moe_gmm_impl"]
 NAMES = LOWERINGS + ["tile_attn_out"]
 # A family's tiny configuration is its own test file's (``REF_CFG``
 # through the benchmark's glue; GPT's is built here) and what its rules
@@ -46,6 +46,8 @@ FAMILIES = {
     "smallthinker": ("test_smallthinker", "epl_smallthinker",
                      KV | {"kv_win_write_impl", "kv_win_attn_impl",
                            "moe_gmm_impl"}),
+    "gigachat3_5": ("test_gigachat", "epl_gigachat3_5",
+                    KV | {"gdn_scan_impl", "moe_gmm_impl"}),
 }
 # The two whose layers select their rows or sit behind a latent window: at
 # the full width a toy engine has (and under the reference lowerings) their
