@@ -1422,6 +1422,18 @@ def _bits(x):
   return np.asarray(x).view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
 
 
+def pack_flat(q, num_valid, rows: int):
+  """``q`` ``[B, C, ..]`` as a step's flat batch of ``rows`` rows: each
+  slot's first ``num_valid[b]`` positions in slot order, zeros after them;
+  the flat row of each slot's first position; the ``[B, C]`` mask of the
+  live positions."""
+  live = np.arange(q.shape[1])[None] < num_valid[:, None]
+  flat = np.zeros((rows,) + q.shape[2:], np.float32)
+  flat[:int(num_valid.sum())] = np.asarray(q.astype(jnp.float32))[live]
+  return (jnp.asarray(flat, q.dtype),
+          jnp.asarray(np.cumsum(num_valid) - num_valid, jnp.int32), live)
+
+
 def check_flat_attend(kernel, name, q, num_valid, out, operands, tol,
                       rehearsal: bool) -> None:
   """A one-leaf tile form from the step's FLAT batch: ``q``'s live positions
@@ -1430,13 +1442,9 @@ def check_flat_attend(kernel, name, q, num_valid, out, operands, tol,
   worked a shift further down).  The result must lie at the rows the
   queries lie at, equal what the ``[slots, chunk]`` call gave there
   (``out``), and be zeros in the rows no position lives in."""
-  B, C = q.shape[:2]
-  live = np.arange(C)[None] < num_valid[:, None]
+  C = q.shape[1]
   total = int(num_valid.sum())
-  flat = np.zeros((total + 3,) + q.shape[2:], np.float32)
-  flat[:total] = np.asarray(q.astype(jnp.float32))[live]
-  flat = jnp.asarray(flat, q.dtype)
-  starts = jnp.asarray(np.cumsum(num_valid) - num_valid, jnp.int32)
+  flat, starts, live = pack_flat(q, num_valid, total + 3)
   attend = compile_here(
       lambda flat, starts, *operands: kernel(flat, *operands, starts=starts,
                                              chunk=C),
@@ -1781,13 +1789,19 @@ def report_rules(cfg, slots: int, chunk: int) -> dict:
         (E, cfg.d_model, 2 * cfg.moe_d_ff), (E, cfg.moe_d_ff, cfg.d_model))
   for what, shape in leaves.items():
     say(f"  {what}: {shape} {jnp.dtype(cfg.dtype).name}")
-  resolved = kv_lib.resolved(kv_lib.step_lowerings(cfg, slots, chunk))
+  from easyparallellibrary_tpu.serving.engine import flat_width
+  record = kv_lib.step_lowerings(cfg, slots, chunk,
+                                 width=flat_width(slots, chunk))
+  resolved = kv_lib.resolved(record)
   # One chip, no mesh: a rule declines for its backend or for the shapes.
   why = (f": DECLINED, the backend is {jax.default_backend()}"
          if jax.default_backend() != "tpu"
          else ": DECLINED, the shapes do not fit the kernel")
   for name, impl in resolved.items():
     say(f"  rule {name}: {impl}" + ("" if impl == "pallas" else why))
+  if record["tile_attn_out"] is not None:
+    say(f"  the tile-grid attends read and write: {record['tile_attn_out']}"
+        f" (a flat batch of {flat_width(slots, chunk)} rows)")
   return resolved
 
 
@@ -1875,6 +1889,111 @@ def check_gdn_scan(B, Hk, Hv, d, C, dtype, rehearsal: bool) -> None:
       f"{jnp.dtype(dtype).name}: state error {e_state:.2e}, output error "
       f"{e_out:.2e}")
 
+def kernel_us(call, args, name: str, reps: int = 4) -> float:
+  """Device time of the custom calls named ``name`` in one run of the
+  compiled ``call(*args)``, in us, from a profiler trace of ``reps`` runs
+  (the kernel alone: a wall clock around the call reads its wrapper's
+  relayouts and fills too)."""
+  import glob
+  import tempfile
+  jax.block_until_ready(call(*args))
+  with tempfile.TemporaryDirectory() as where:
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(where, profiler_options=opts)
+    for _ in range(reps):
+      out = call(*args)
+    jax.block_until_ready(out)
+    jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{where}/plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(path)
+    ns = sum(ev.duration_ns for plane in data.planes
+             if plane.name == "/device:TPU:0" for line in plane.lines
+             if line.name == "XLA Ops" for ev in line.events
+             if re.match(rf"%{name}(\.\d+)* = .* custom-call\(", ev.name))
+  return ns / reps / 1e3
+
+
+def check_plain_tile_attend(B, L, H, W, r, C, dtype, rehearsal: bool,
+                            timed: bool = False) -> None:
+  """The PLAIN one-leaf attend on the tile grid (``slot_attn`` from the
+  step's flat batch: kernels/slot_attention.py ``plain_tile_form``) against
+  the einsums over every row, at a serving step's mix: one slot in eight
+  prefilling (whole chunks, a partial one), the others decoding one
+  position, two idle, bounds up to the leaf's last row, NaN in every row
+  at or beyond a slot's bound.  ``timed``: the kernel alone at blocks of
+  512 / 1024 / 2048 beside the first grid's on the same operands gathered
+  into ``[slots, chunk]`` order."""
+  rs = np.random.RandomState(11)
+  num_valid = np.where(np.arange(B) % 8 == 3, C, 1).astype(np.int32)
+  num_valid[[1, B - 2]] = 0
+  num_valid[2] = C // 2 + 1
+  cursors = rs.randint(0, L - C, B).astype(np.int32)
+  # a decode from a leaf's start, a partial chunk, a whole chunk that ends
+  # at the leaf's last row, a cursor beyond it (clamped, as the write's)
+  cursors[[0, 2, 3, B - 1]] = [0, 5, L - C, L - 1]
+  leaf = rs.randn(B, L, 1, W).astype(np.float32)
+  q = jnp.asarray(rs.randn(B, C, H, W) / np.sqrt(W), dtype)
+  scale = 0.3
+  dirty = leaf.copy()
+  clamped = np.clip(cursors, 0, L - C)
+  for b in range(B):
+    dirty[b, clamped[b] + num_valid[b] if num_valid[b] else 0:] = np.nan
+  total = int(num_valid.sum())
+  T = -(-max(total + 3, 8) // 8) * 8
+  flat, starts, live = pack_flat(q, num_valid, T)
+  operands = (jnp.asarray(dirty, dtype), jnp.asarray(cursors),
+              jnp.asarray(num_valid))
+
+  def tiled(block=None):
+    return compile_here(
+        lambda flat, starts, *ops: slot_attn_lib.slot_attention_tiled_pallas(
+            flat, *ops, interpret=rehearsal, block=block, starts=starts,
+            chunk=C, v_width=r, scale=scale),
+        flat, starts, *operands, mosaic_calls=_launches(C),
+        rehearsal=rehearsal)
+
+  # Both sides at the highest precision, as ``check_slot_attn``: float32
+  # operands then multiply as float32 in the kernel too.
+  with jax.default_matmul_precision("highest"):
+    got = np.asarray(tiled()(flat, starts, *operands), np.float32)
+    # The reference eight slots at a time: its score tensor for 128 slots
+    # of 64 heads x 32 positions x 4224 rows would not fit.
+    ref = jax.jit(lambda q, leaf, cur: slot_attention_reference(
+        q, leaf, None, cur, v_width=r, scale=scale))
+    clean = jnp.asarray(leaf, dtype).astype(jnp.float32)
+    want = np.concatenate([
+        np.asarray(ref(q[b:b + 8].astype(jnp.float32), clean[b:b + 8],
+                       jnp.asarray(clamped[b:b + 8])))
+        for b in range(0, B, 8)])
+  check(np.isfinite(got).all(), "the plain tile attend's output not finite")
+  err = rel_err(got[:total], want[live])
+  tol = 2e-2 if dtype == jnp.bfloat16 else 5e-4
+  check(err <= tol, f"plain tile attend {jnp.dtype(dtype).name}: {err:.3g} "
+        f"of the reference's max, tol {tol}")
+  check((got[total:] == 0).all(),
+        "plain tile attend: rows no live position owns are not zeros")
+  block = slot_attn_lib._tile_block(
+      L, W, dtype, slot_attn_lib.tile_positions(C, H) * H, r, False)
+  say(f"  slot_attn on the tile grid slots{B} L{L} H{H} W{W} values{r} "
+      f"chunk{C} block{block} {jnp.dtype(dtype).name} ({total} live of {T} "
+      f"flat rows, {int((num_valid == 1).sum())} decoding slots): {err:.2e} "
+      "of the reference's max, NaN beyond the bounds unread, zeros beyond "
+      "the live rows")
+  if not timed or rehearsal:
+    return
+  first = compile_here(
+      lambda q, *ops: slot_attention_pallas.__wrapped__(
+          q, ops[0], None, *ops[1:], v_width=r, scale=scale),
+      q, *operands, mosaic_calls=1, rehearsal=rehearsal)
+  say(f"  the kernels alone, us a call (device trace, {SLOT_ATTN}): first "
+      f"grid on [slots, chunk] operands "
+      f"{kernel_us(first, (q,) + operands, SLOT_ATTN):.0f}; tile grid "
+      + ", ".join(
+          f"block {b} "
+          f"{kernel_us(tiled(b), (flat, starts) + operands, SLOT_ATTN):.0f}"
+          for b in (512, 1024, 2048)))
+
 
 def phase_gigachat(sizes: Sizes) -> None:
   cell_cfg, slots, C = sizes.gigachat_cell
@@ -1882,6 +2001,10 @@ def phase_gigachat(sizes: Sizes) -> None:
   if not sizes.rehearsal:
     check(all(i == "pallas" for i in resolved.values()),
           f"a rule declined at the cell's shapes: {resolved}")
+    from easyparallellibrary_tpu.serving.engine import flat_width
+    check(kv_lib.step_lowerings(cell_cfg, slots, C, width=flat_width(
+        slots, C))["tile_attn_out"] == "flat",
+          "the cell's plain latent leaf did not take the tile grid")
   d = cell_cfg.linear_key_head_dim
   for dtype in (jnp.float32, jnp.bfloat16):
     # The cell's heads and chunk on 8 slots (the reference scans 32
@@ -1889,6 +2012,14 @@ def phase_gigachat(sizes: Sizes) -> None:
     check_gdn_scan(8, cell_cfg.linear_num_key_heads,
                    cell_cfg.linear_num_value_heads, d, C, dtype,
                    sizes.rehearsal)
+    # The latent layer's attend as the cell's step runs it (PR 51): the
+    # plain leaf on the tile grid, from the flat batch (a toy cut's two
+    # heads are no sublane tile: eight there).
+    L = kv_lib.kv_leaf_shape(cell_cfg, slots, C)[1]
+    check_plain_tile_attend(
+        slots, L, 8 if sizes.rehearsal else cell_cfg.num_heads,
+        cell_cfg.latent_dim, cell_cfg.kv_lora_rank, C, dtype,
+        sizes.rehearsal, timed=dtype == jnp.bfloat16)
   cfg = sizes.gigachat_cfg
   n_full = len(cfg.full_attention_layers)
   n_linear = cfg.num_layers - n_full

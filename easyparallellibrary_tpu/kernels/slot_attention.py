@@ -85,6 +85,20 @@ from HBM once a block, not once as keys and once as values, and the
 output is ``[B, C, H, v_width]``.  ``scale`` replaces ``1 / sqrt(hd)``
 where the queries' width is not the head size the softmax is scaled by.
 
+That grid's query block is a slot's WHOLE chunk of every head: ``C x H``
+rows a live slot, whatever the slot feeds.  At 20 heads x chunk 8
+(GLM-4.7-Flash) that is 160 rows; at 64 heads x chunk 32 (GigaChat3.5) it
+is 2,048, of which a decoding slot needs 64.  So a plain one-leaf attend
+whose chunk is more than one tile of positions runs on the TILE grid
+below (:func:`plain_tile_form`: from the leaf's shape, the heads, the
+chunk and the flat batch's width; no key, no model's name): the same
+rows under the same mask and the same arithmetic, computed for the live
+(slot, tile of 8 positions) pairs and, in a launch of their own, for the
+one position of each decoding slot, queries read from and results
+written to the step's token-flat batch where it lies.  Both launches
+keep the name ``slot_attn``: it is one attend, and the benchmark sums
+the calls of that name.
+
 Shapes: ``q`` ``[B, C, H, hd]``; ``cached_k/cached_v`` ``[B, Lc, H_kv x
 hd]`` (rows) or ``[B, Lc, H_kv, hd]`` (positions) AFTER this step's
 window write; ``cursors``, ``num_valid`` int32 ``[B]``.
@@ -742,7 +756,10 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
 #
 # Three more forms of the attend, each under a kernel name of its own so
 # that a device trace tells their time apart; two of a ONE-LEAF cache
-# (models/dots3_note.py), one of a K/V PAIR (models/smallthinker.py):
+# (models/dots3_note.py), one of a K/V PAIR (models/smallthinker.py); and
+# the PLAIN one-leaf attend itself (every row ``s <= t`` under the bound,
+# the selected form less its selection and its two score operands), under
+# the first grid's name, where :func:`plain_tile_form` holds:
 #
 # * ``slot_attn_sel``: a query sees row ``s <= t`` iff ``s`` is in its
 #   SELECTION.  The selection reaches the kernel as the index scores
@@ -794,9 +811,11 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
 # arrays gathered from it (``SlotRows.to_slots``) and gathered back
 # (``to_flat``):
 #
-# * THE ONE-LEAF TILE FORMS, ``slot_attn_sel`` and ``slot_attn_win``, work
+# * THE ONE-LEAF TILE FORMS, ``slot_attn_sel``, ``slot_attn_win`` and the
+#   plain leaf's ``slot_attn`` where it takes this grid, work
 #   on the flat batch where it lies (:func:`tile_attn_out` says when: the
-#   kernel resolved, the batch narrower than ``slots x chunk``).  ``q`` is
+#   kernel resolved, the batch narrower than ``slots x chunk``; a plain
+#   leaf besides only where :func:`plain_tile_form` holds).  ``q`` is
 #   ``[T, H, W]`` and a tile's rows are the block at row ``starts[b] + tile
 #   x tp``, an offset in elements, held to ``T - tp`` (a tile that starts
 #   nearer the batch's end is read from there and worked that many rows
@@ -819,7 +838,9 @@ def slot_attention_pallas(q, cached_k, cached_v, cursors, num_valid=None,
 #   writes ``[B, C, H x hd]`` blocks a tile, gathered back ``to_flat``: its
 #   rows are ``H x hd`` lanes wide, rank 2, and Mosaic cannot take an
 #   offset in rows into them.
-# * ``slot_attn`` (the first grid, above), ``kv_write``, ``dsa_index``,
+# * ``slot_attn`` ON THE FIRST GRID (above: every K/V pair, and a plain
+#   leaf whose chunk is one tile of positions or whose heads the tile
+#   kernel declines), ``kv_write``, ``dsa_index``,
 #   ``ssm_scan`` and the convolution's window: ``[slots, chunk, ..]`` in,
 #   ``[slots, chunk, ..]`` out, two gathers a mixer.  The one-leaf forms'
 #   output is the form they would take (ROADMAP S2(c)): the array left in
@@ -881,12 +902,17 @@ def live_tiles(num_valid, chunk: int, tile: int):
           jnp.maximum(ends[-1:], 1))
 
 
+def decodes_apart(chunk: int) -> bool:
+  """Whether a chunk is wide enough to be tiled (a multiple of 8 above 8),
+  so that the slots that feed one position take a launch of their own."""
+  return chunk % 8 == 0 and chunk > 8
+
+
 def split_decodes(num_valid, chunk: int):
   """``(num_valid of the slots that feed more than one position, 0 or 1
-  for those that feed exactly one)`` where a chunk is wide enough to be
-  tiled (a multiple of 8 above 8), ``None`` where one launch serves
-  all."""
-  if chunk % 8 or chunk == 8 or num_valid is None:
+  for those that feed exactly one)`` where :func:`decodes_apart` holds,
+  ``None`` where one launch serves all."""
+  if not decodes_apart(chunk) or num_valid is None:
     return None
   nv = num_valid.astype(jnp.int32)
   one = nv == 1
@@ -966,6 +992,24 @@ def tile_attn_out(impl: Optional[str], narrower: bool) -> str:
   a reshape).  What the mixer asks (models/blocks.py:LatentAttention) and
   what the engine's record says (serving/kv_cache.py:tile_attn_out)."""
   return "flat" if impl in ("pallas", "interpret") and narrower else "slots"
+
+
+def plain_tile_form(impl: Optional[str], narrower: bool, cache_shape, dtype,
+                    chunk: int, num_heads: int, v_width: int) -> bool:
+  """Whether a PLAIN one-leaf attend (no window, no selection: every row
+  ``s <= t`` under the slot's bound) runs on the tile grid over the flat
+  batch, as the selected and the windowed forms do, and not on the first
+  grid over ``[slots, chunk]`` operands: where those forms work on the flat
+  batch at all (:func:`tile_attn_out`), the tile kernel can tile the leaf
+  (:func:`tile_attn_fits`) AND a slot's chunk is more than one tile of
+  positions, since only then does a tile skip anything (a chunk of 32 x 64
+  heads is 2,048 query rows a slot on the first grid, of which a decoding
+  slot needs 64).  Shapes and the resolved lowering, nothing else; what the
+  mixer asks (models/blocks.py:LatentAttention) and what the engine's
+  record follows (serving/kv_cache.py:tile_attn_out)."""
+  return (tile_attn_out(impl, narrower) == "flat"
+          and tile_positions(chunk, num_heads) < chunk
+          and tile_attn_fits(cache_shape, dtype, chunk, num_heads, v_width))
 
 
 def resolve_tile_attn_impl(cache_shape, dtype, chunk: int, num_heads: int,
@@ -1098,10 +1142,12 @@ def _tile_attn_kernel(slot_ref, tile_ref, count_ref, cur_ref, bound_ref,
                       block: int, num_blocks: int, scale: float,
                       v_width: int, tp: int, heads: int,
                       window: Optional[int], ring: int, pair=None,
-                      flat_rows: Optional[int] = None):
-  """One (live tile, leaf block) grid step of the selected (``window``
-  None) or a windowed form.  ``q_ref`` ``[tp, heads, W]``, the tile's
-  rows of the flat batch, taken as ``tp x heads`` rows (position, head);
+                      flat_rows: Optional[int] = None, selected: bool = True):
+  """One (live tile, leaf block) grid step of the plain (``window`` None,
+  not ``selected``: every row ``s <= t`` under the bound), the selected
+  (``window`` None) or a windowed form.  ``q_ref`` ``[tp, heads, W]``, the
+  tile's rows of the flat batch, taken as ``tp x heads`` rows (position,
+  head);
   ``k_ref`` ``[1, 1, W, block]``, position-minor; ``pos_ref`` each query
   row's position in its tile; ``into_ref`` the output as it was handed in
   (aliased, untouched).  ``refs``: the selected form's score block ``[1,
@@ -1204,14 +1250,15 @@ def _tile_attn_kernel(slot_ref, tile_ref, count_ref, cur_ref, bound_ref,
     t = t_lo + pos_ref[...]                                # [rows, 1]
     col = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     if window is None:
-      sc_ref, thr_ref = refs[0], refs[1]
-      picked = jnp.where(sc_ref[0] >= thr_ref[0], 0.0, NEG_INF)  # [tp, block]
-      if tp > 1:
-        # position p's scores to the row its query lies in, p + shift
-        picked = pltpu.roll(picked, shift, 0)
-      s = s + jnp.concatenate(
-          [jnp.broadcast_to(picked[p:p + 1], (heads, block))
-           for p in range(tp)], axis=0)
+      if selected:
+        sc_ref, thr_ref = refs[0], refs[1]
+        picked = jnp.where(sc_ref[0] >= thr_ref[0], 0.0, NEG_INF)  # [tp, block]
+        if tp > 1:
+          # position p's scores to the row its query lies in, p + shift
+          picked = pltpu.roll(picked, shift, 0)
+        s = s + jnp.concatenate(
+            [jnp.broadcast_to(picked[p:p + 1], (heads, block))
+             for p in range(tp)], axis=0)
       s = jnp.where((col <= t) & (col < bound), s, NEG_INF)
       dead = vcol >= bound
     else:
@@ -1273,9 +1320,10 @@ def _tile_attention(q, leaf, cursors, num_valid, scores, threshold,
                     block: Optional[int], v_width: int, scale: float,
                     starts=None, chunk: Optional[int] = None, values=None):
   """The forms' shared call: ``window`` None is the selected form
-  (``scores``, ``threshold`` given), else the leaf is a ring; ``values``
-  (the V ring beside ``leaf``, the K ring, both kept in rows) is the pair
-  form.  ``q`` is ``[B, C, H, W]``, or (the one-leaf forms), with ``starts``
+  (``scores``, ``threshold`` given) or, without them, the plain one (every
+  row ``s <= t``, under the first grid's name); else the leaf is a ring;
+  ``values`` (the V ring beside ``leaf``, the K ring, both kept in rows) is
+  the pair form.  ``q`` is ``[B, C, H, W]``, or (the one-leaf forms), with ``starts``
   and ``chunk``, the step's token-flat batch ``[T, H, W]`` in which slot
   ``b``'s live positions are the rows from ``starts[b]`` on
   (models/slot_core.py:SlotRows): a tile's query rows are read where they
@@ -1425,7 +1473,8 @@ def _tile_launch(q, starts, leaf, cur, bound, into, C: int, feeds, scores,
     operands = [into, pos, q, leaf, values]
     out_spec = pl.BlockSpec(out_block, tile_idx)
     scratch = [pltpu.VMEM((rows, hd), dtype if tp > 1 else jnp.float32)]
-  if not ring:
+  selected = scores is not None
+  if selected:
     def score_idx(i, kb, slot, tile, *rest):
       return slot[i], tile[i], leaf_idx(i, kb, slot, tile, *rest)[3]
     in_specs += [pl.BlockSpec((1, tp, block), score_idx),
@@ -1443,7 +1492,7 @@ def _tile_launch(q, starts, leaf, cur, bound, into, C: int, feeds, scores,
       functools.partial(
           _tile_attn_kernel, block=block, num_blocks=nb, scale=float(scale),
           v_width=v_width, tp=tp, heads=H, window=window, ring=L, pair=pair,
-          flat_rows=T),
+          flat_rows=T, selected=selected),
       grid_spec=pltpu.PrefetchScalarGridSpec(
           num_scalar_prefetch=6,
           grid=(count[0], nb),
@@ -1458,8 +1507,8 @@ def _tile_launch(q, starts, leaf, cur, bound, into, C: int, feeds, scores,
       # Operands count the six scalar-prefetch arrays: ``into`` follows.
       input_output_aliases={6: 0},
       interpret=interpret,
-      name=(SLOT_ATTN_KVWIN if pair is not None
-            else SLOT_ATTN_WIN if ring else SLOT_ATTN_SEL),
+      name=(SLOT_ATTN_KVWIN if pair is not None else SLOT_ATTN_WIN if ring
+            else SLOT_ATTN_SEL if selected else SLOT_ATTN),
       **kwargs,
   )(slot, tile, count, cur, bound, starts, *operands)
   return out
@@ -1475,6 +1524,17 @@ def slot_attention_selected_pallas(q, latent, scores, threshold, cursors,
   return _tile_attention(q, latent, cursors, num_valid, scores, threshold,
                          None, interpret, block, v_width, scale, starts,
                          chunk)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "block",
+                                             "v_width", "scale", "chunk"))
+def slot_attention_tiled_pallas(q, latent, cursors, num_valid=None,
+                                interpret: bool = False,
+                                block: Optional[int] = None, starts=None,
+                                chunk: Optional[int] = None, *,
+                                v_width: int, scale: float):
+  return _tile_attention(q, latent, cursors, num_valid, None, None, None,
+                         interpret, block, v_width, scale, starts, chunk)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret", "block",
@@ -1509,20 +1569,35 @@ def _check_impl(impl: str) -> None:
 def slot_attention(q, cached_k, cached_v, cursors, num_valid=None,
                    impl: Optional[str] = None,
                    v_width: Optional[int] = None,
-                   scale: Optional[float] = None):
+                   scale: Optional[float] = None, starts=None,
+                   chunk: Optional[int] = None):
   """Attend each slot's chunk over its own cache (module docstring);
   returns ``out [B, C, H, hd]`` (``[B, C, H, v_width]`` for a one-leaf
   layer: ``cached_v=None``, the values the keys' leading ``v_width``
   columns).  ``impl=None`` applies the dispatch rule
-  to the shapes at hand (and to ``Env.mesh_built``)."""
+  to the shapes at hand (and to ``Env.mesh_built``).  A one-leaf layer
+  whose caller found :func:`plain_tile_form` to hold hands ``q`` as the
+  step's flat batch ``[T, H, hd]`` with ``starts`` and ``chunk``, as
+  :func:`slot_attention_selected` takes it, and gets ``[T, H, v_width]``
+  back: the same rows under the same mask, on the tile grid (a kernel's
+  lowering only: the reference takes ``[B, C, H, hd]``)."""
+  flat = starts is not None
   if impl is None:
     impl = resolve_slot_attn_impl(
-        cached_k.shape, cached_k.dtype, q.shape[1], q.shape[2],
-        sharded=Env.get().mesh_built(), head_dim=q.shape[3])
+        cached_k.shape, cached_k.dtype, chunk if flat else q.shape[1],
+        q.shape[-2], sharded=Env.get().mesh_built(), head_dim=q.shape[-1])
   _check_impl(impl)
   if (cached_v is None) != (v_width is not None):
     raise ValueError("a one-leaf attend passes cached_v=None AND v_width; "
                      "a K/V pair passes neither")
+  if flat:
+    if cached_v is not None or impl == "reference":
+      raise ValueError("the flat batch (starts=) is read by the tile kernel "
+                       "of a one-leaf cache alone (plain_tile_form)")
+    return slot_attention_tiled_pallas(
+        q, cached_k, cursors, num_valid, interpret=impl == "interpret",
+        starts=starts, chunk=chunk, v_width=v_width,
+        scale=1.0 / math.sqrt(q.shape[-1]) if scale is None else float(scale))
   if impl == "reference":
     return slot_attention_reference(q, cached_k, cached_v, cursors,
                                     v_width=v_width, scale=scale)

@@ -370,14 +370,23 @@ class LatentAttention(HeldParams, nn.Module):
           -1)[:, 0]                                          # [T,H,r+dr]
       index = () if ix is None else tuple(
           rows.to_slots(t[:, 0]) for t in (k_ix, q_ix, w_ix))
-      # The selected and the windowed kernels read a tile's query rows
-      # where they lie in the flat batch, and write its result there;
-      # every other attend takes them in [slots, C] order, which is also
-      # what a lowering nobody resolved yet (``None``) is handed: every
-      # lowering takes it.
-      from easyparallellibrary_tpu.kernels.slot_attention import tile_attn_out
-      if (dims.window is not None or ix is not None) and tile_attn_out(
-          self.slot_attn_impl, rows.dst is not None) == "flat":
+      # The tile kernels read a tile's query rows where they lie in the
+      # flat batch, and write its result there: the selected and the
+      # windowed attends wherever they run on a narrower batch, a plain
+      # leaf's where tiling its chunk skips something besides
+      # (``plain_tile_form``, from the leaf's shape, the heads and the
+      # chunk).  The first grid takes them in [slots, C] order, which is
+      # also what a lowering nobody resolved yet (``None``) is handed:
+      # every lowering takes it.
+      from easyparallellibrary_tpu.kernels.slot_attention import (
+          plain_tile_form, tile_attn_out)
+      narrower = rows.dst is not None
+      flat = tile_attn_out(self.slot_attn_impl, narrower) == "flat"
+      if flat and dims.window is None and ix is None:
+        leaf = self.get_variable("cache", "cached_latent")
+        flat = plain_tile_form(self.slot_attn_impl, narrower, leaf.shape,
+                               leaf.dtype, rows.chunk, H, r)
+      if flat:
         h = (gate, q_abs), (rows.to_slots(new[:, 0]), None, *index)
       else:
         h = (gate,), (rows.to_slots(new[:, 0]), rows.to_slots(q_abs),
@@ -445,7 +454,7 @@ class LatentAttention(HeldParams, nn.Module):
     else:
       o_lat = slot_attention(q_abs, latent.value, None, slot_cursors,
                              num_valid, impl=self.slot_attn_impl, v_width=r,
-                             scale=scale)
+                             scale=scale, starts=starts, chunk=rows.chunk)
     o_lat = o_lat.astype(self.cfg.dtype)
     return ((gate, o_lat), None) if flat_q else ((gate,), o_lat)
 

@@ -216,7 +216,9 @@ class SlotRows:
   (queries in, result back), ``dsa_index`` (index queries and weights in,
   the scores ``to_flat`` for their thresholds), ``ssm_scan`` and the
   convolution's window.  Who does not: the selected and the windowed latent
-  attends (``slot_attn_sel``, ``slot_attn_win``), which where their kernel
+  attends (``slot_attn_sel``, ``slot_attn_win``) and a plain latent leaf's
+  ``slot_attn`` where it takes their grid (``plain_tile_form``), which
+  where their kernel
   runs read their queries from the flat batch at ``dst``'s first row a slot
   and write their result to the same rows (kernels/slot_attention.py, the
   tile forms; the layer's carry then holds both row-wise).

@@ -255,6 +255,14 @@ def _walk_rows(resident, num_valid, granule: int, length: int) -> int:
   return int(np.sum(np.minimum(-(-bound // granule) * granule, length)))
 
 
+def _tile_positions(num_valid, tile: int, decode: int) -> int:
+  """Chunk positions one layer's tile-grid attend works on in a step: a
+  slot that feeds several positions its live tiles of ``tile``, one that
+  feeds one ``decode`` (serving/kv_cache.py:attn_tile), from the plan."""
+  n = num_valid.astype(np.int64)
+  return int(np.sum(np.where(n == 1, decode, -(-n // tile) * tile)))
+
+
 def flat_width(num_slots: int, chunk: int) -> int:
   """Rows ``T`` of the token-flat batch the contiguous fused step runs its
   position-wise layers on (models/slot_core.py:SlotRows), static for the
@@ -518,9 +526,14 @@ class ContinuousBatchingEngine:
     # The granule and length of the attend kernel's walk, where the step
     # was built on it: what ``serving/attn_rows_read`` counts by.
     self._attn_walk = (
-        kv_lib.slot_attn_walk(cfg, self.num_slots, self.chunk)
+        kv_lib.slot_attn_walk(cfg, self.num_slots, self.chunk,
+                              self.lowerings["tile_attn_out"])
         if self.lowerings["slot_attn_impl"] in ("pallas", "interpret")
         else None)
+    # The tile and the decoding slot's cost of the one-leaf attends that
+    # run on the tile grid instead: what ``serving/attn_tile_positions``
+    # counts by.
+    self._attn_tile = kv_lib.attn_tile(cfg, self.lowerings, self.chunk)
     # Rows the cache holds for one layer: what ``serving/live_kv_rows``
     # is a share of.
     self._kv_rows = (self.num_blocks * self.block_size if self.paged else
@@ -1932,6 +1945,12 @@ class ContinuousBatchingEngine:
     attn_rows_read = (
         _walk_rows(plan.resident, plan.num_valid, *self._attn_walk)
         if self._attn_walk is not None else None)
+    # Chunk positions one layer's tile-grid attend works on this step:
+    # over ``flat_positions`` it is how much of that work no live query
+    # asked for (a partial last tile's dead positions).
+    attn_tile_positions = (
+        _tile_positions(plan.num_valid, *self._attn_tile)
+        if self._attn_tile is not None else None)
     fed_positions = plan.prefill_tokens + plan.decode_tokens
     flat_positions = fed_positions + (
         0 if step.num_draft is None else int(step.num_draft.sum()))
@@ -1980,6 +1999,8 @@ class ContinuousBatchingEngine:
       if attn_rows_read is not None:
         tracer.counter("serving/attn_rows_read", attn_rows_read)
       tracer.counter("serving/flat_positions", flat_positions)
+      if attn_tile_positions is not None:
+        tracer.counter("serving/attn_tile_positions", attn_tile_positions)
       tracer.counter("serving/flat_trimmed", flat_trimmed)
       tracer.counter("serving/flat_narrow", flat_narrow)
       if self._recurrent:

@@ -456,14 +456,17 @@ def slot_attn_impl(cfg, num_slots: int, chunk: int,
       sharded=sharded, head_dim=kv_heads(cfg)[1])
 
 
-def slot_attn_walk(cfg, num_slots: int, chunk: int):
+def slot_attn_walk(cfg, num_slots: int, chunk: int,
+                   tile_out: Optional[str] = None):
   """``(granule, length)`` of ``slot_attn``'s walk over this model's leaf
   (kernels/slot_attention.py:walk_geometry): what
   ``serving/attn_rows_read`` rounds each live bound up to, and holds it
-  to.  ``None`` for a model none of whose layers takes that walk (no rows
-  under a cursor, or every such layer a selected or windowed latent one)."""
+  to.  ``None`` for a model none of whose layers takes that walk: no rows
+  under a cursor, every such layer a selected or windowed latent one, or
+  (``tile_out``, the step's :func:`tile_attn_out`, ``"flat"``) a plain
+  latent leaf that the tile grid serves."""
   from easyparallellibrary_tpu.kernels.slot_attention import walk_geometry
-  if not _under_cursor(cfg) or _mixed_latent(cfg):
+  if not _under_cursor(cfg) or _mixed_latent(cfg) or tile_out == "flat":
     return None
   return walk_geometry(kv_leaf_shape(cfg, num_slots, chunk), cfg.dtype)
 
@@ -572,21 +575,57 @@ def moe_gmm_impl(cfg, num_slots: int, chunk: int,
                    for k, n in ((D, 2 * F), (F, D)))
 
 
-def tile_attn_out(cfg, impl: Optional[str], narrower: bool) -> Optional[str]:
-  """Where the attends of the layers that select their rows or sit behind
-  a latent window read their queries and write their result, given what
-  :func:`slot_attn_impl` resolved and whether the flat batch is
+def tile_attn_out(cfg, impl: Optional[str], narrower: bool, num_slots: int,
+                  chunk: int) -> Optional[str]:
+  """Where the attends that run on the tile grid of
+  kernels/slot_attention.py read their queries and write their result,
+  given what :func:`slot_attn_impl` resolved and whether the flat batch is
   ``narrower`` than ``num_slots x chunk``
   (kernels/slot_attention.py:tile_attn_out, which the mixer asks too):
   ``"flat"``, the step's token-flat batch where it lies; ``"slots"``,
   arrays in ``[slots, chunk]`` order gathered from it and back; ``None``
-  for a model without such a layer.  No lowering a caller could name: what
-  the step does, derived from what the other rules resolved."""
-  kinds = layer_kinds(cfg)
-  if SPARSE_LATENT not in kinds and WINDOW_LATENT not in kinds:
-    return None
+  for a model with no such attend.  The layers that select their rows or
+  sit behind a latent window always run there; a PLAIN latent leaf
+  (:data:`LATENT`) does where ``plain_tile_form`` holds for the leaf
+  :func:`kv_leaf_shape` gives ``num_slots`` and ``chunk`` (then ``"flat"``:
+  the tile kernel can tile it, a chunk is more than one tile of positions
+  and the batch is narrower), and keeps the first grid, ``None`` here,
+  everywhere else.  No lowering a caller could name: what the step does,
+  derived from what the other rules resolved."""
   from easyparallellibrary_tpu.kernels import slot_attention
-  return slot_attention.tile_attn_out(impl, narrower)
+  kinds = layer_kinds(cfg)
+  if SPARSE_LATENT in kinds or WINDOW_LATENT in kinds:
+    return slot_attention.tile_attn_out(impl, narrower)
+  if LATENT in kinds:
+    dims = cfg.latent_dims()
+    if slot_attention.plain_tile_form(
+        impl, narrower, kv_leaf_shape(cfg, num_slots, chunk), cfg.dtype,
+        chunk, dims.num_heads, dims.kv_lora_rank):
+      return "flat"
+  return None
+
+
+def attn_tile(cfg, lowerings: Dict[str, Optional[str]],
+              chunk: int) -> Optional[Tuple[int, int]]:
+  """``(tile, decode)`` of the step's one-leaf attends where they run on
+  the tile grid (the kernel resolved, and the layers select their rows, sit
+  behind a latent window or are a plain leaf the grid serves): the chunk
+  positions a live tile of a slot that feeds several is worked as, and
+  those a slot that feeds one costs (1 where a chunk of tiles has the
+  decoding slots' launch, a whole tile elsewhere).  What
+  ``serving/attn_tile_positions`` counts by; ``None`` where no layer runs
+  there."""
+  from easyparallellibrary_tpu.kernels import slot_attention
+  if lowerings.get("slot_attn_impl") not in ("pallas", "interpret"):
+    return None
+  if _mixed_latent(cfg):
+    heads = cfg.latent_dims(_LAYER_TYPE[latent_kinds(cfg)[0]]).num_heads
+  elif lowerings.get("tile_attn_out") == "flat":
+    heads = cfg.latent_dims().num_heads
+  else:
+    return None
+  tile = slot_attention.tile_positions(chunk, heads)
+  return tile, 1 if slot_attention.decodes_apart(chunk) else tile
 
 
 # The rules above in the order every record of a step's lowerings is kept
@@ -619,7 +658,7 @@ def step_lowerings(cfg, num_slots: int, chunk: int,
             for rule in _RULES}
   record["tile_attn_out"] = tile_attn_out(
       cfg, record["slot_attn_impl"],
-      width is not None and width < num_slots * chunk)
+      width is not None and width < num_slots * chunk, num_slots, chunk)
   return record
 
 

@@ -847,12 +847,26 @@ def test_narrow_and_wide_steps_commit_the_reference_lowerings_tokens(
   for impl, form in (("reference", "slots"), ("interpret", "flat")):
     _backend_takes(monkeypatch, impl)
     stats = ServingStats()
-    eng, outs[impl] = _serve(model, params, slots=4, chunk=16, stats=stats)
+    tracer = trace_lib.install(trace_lib.Tracer(enabled=True))
+    try:
+      eng, outs[impl] = _serve(model, params, slots=4, chunk=16, stats=stats)
+      counters = {name: [ev["args"]["value"] for ev in tracer.events()
+                         if ev["ph"] == "C" and ev["name"] == name]
+                  for name in ("serving/attn_tile_positions",
+                               "serving/flat_positions")}
+    finally:
+      trace_lib.install(None)
     assert (eng.flat_width, eng.flat_narrow) == (40, 16)
     assert eng.lowerings["slot_attn_impl"] == impl
     assert eng.lowerings["tile_attn_out"] == form
     assert 0 < stats.flat_narrow_steps < stats.steps
     assert eng._step_fn._cache_size() == 1
+    # Where the tile kernels run, the chunk positions they work on a step
+    # (live tiles of 8, decoding slots 1) beside the live ones.
+    tiles, live = counters.values()
+    assert len(tiles) == (len(live) if impl == "interpret" else 0)
+    assert all(t >= n for t, n in zip(tiles, live))
+    assert sum(tiles) > sum(live) or impl == "reference"
   for uid, toks in outs["reference"].items():
     np.testing.assert_array_equal(np.asarray(outs["interpret"][uid]),
                                   np.asarray(toks))

@@ -587,3 +587,145 @@ def test_the_engine_says_what_it_holds_and_counts_the_forms(both):
   # step of a request feeds one position.
   assert sum(counters("serving/state_chunk_positions")) == 3 * 6
   assert sum(counters("serving/state_slots")) == 3 * (2 + 2)
+
+
+# ----------------------------------- the latent layer on the tile grid (PR 51) --
+
+# Eight heads: whole sublane tiles of float32, which the tile kernel wants.
+WIDE_CFG = dataclasses.replace(REF_CFG, heads=8)
+
+
+@pytest.fixture(scope="module")
+def wide():
+  epl.init()
+  key = ref.seed_key(2 ** 31 + 7)
+  model, shell_of = glue.build_model(WIDE_CFG, F32)
+  return model, glue.program_params(
+      WIDE_CFG, key, shell_of(jnp.zeros((1, 8), jnp.int32)))
+
+
+def _attend_takes(monkeypatch, impl):
+  """The attend's and the window write's backend lowering (the delta rule's
+  kernel declines these heads either way)."""
+  for name in ("slot_attention", "kv_write"):
+    monkeypatch.setattr(importlib.import_module(
+        f"easyparallellibrary_tpu.kernels.{name}"), "_backend_impl",
+        lambda: impl)
+
+
+def _mixed_requests():
+  rng = np.random.default_rng(5)
+  return [Request(uid=f"r{j}", prompt=rng.integers(0, 256, n).astype(np.int32),
+                  max_new_tokens=m)
+          for j, (n, m) in enumerate([(3, 6), (19, 9), (30, 5), (11, 12),
+                                      (26, 7)])]
+
+
+def test_narrow_and_wide_steps_commit_the_same_on_the_tile_grid(monkeypatch,
+                                                                wide):
+  """One engine whose flat batch is narrower than its ``slots x chunk``
+  positions and has a second width (40 and 16 rows of 4 x 16, named here:
+  the rule gives so few positions their full width).  Under the interpreted
+  kernels the plain latent leaf takes the tile grid (``tile_attn_out``
+  ``flat``: queries read from the flat batch, the result written to its
+  rows, on steps that take either side of the layers' conditionals) and no
+  ``serving/attn_rows_read`` is counted, the first grid's walk being gone;
+  ``serving/attn_tile_positions`` is the plan's live tiles of 8 and its
+  decoding slots; the engine commits what it commits under the reference
+  lowerings.  One compile each."""
+  from easyparallellibrary_tpu.profiler.serving import ServingStats
+  from easyparallellibrary_tpu.serving import engine as engine_lib
+  model, params = wide
+  monkeypatch.setattr(engine_lib, "flat_width", lambda slots, chunk: 40)
+  monkeypatch.setattr(engine_lib, "narrow_width", lambda width, slots: 16)
+  outs, counted = {}, {}
+  for impl, form in (("reference", None), ("interpret", "flat")):
+    _attend_takes(monkeypatch, impl)
+    stats = ServingStats()
+    tracer = trace_lib.install(trace_lib.Tracer(enabled=True))
+    try:
+      eng = ContinuousBatchingEngine(model, params, num_slots=4,
+                                     prefill_chunk=16, stats=stats)
+      plans = []
+      plan_step = eng.scheduler.plan_step
+      def planned(*a, plan_step=plan_step, plans=plans, **kw):
+        plan = plan_step(*a, **kw)
+        if plan is not None:
+          plans.append(np.array(plan.num_valid))
+        return plan
+      eng.scheduler.plan_step = planned
+      for r in _mixed_requests():
+        assert eng.submit(r)
+      with jax.default_matmul_precision("highest"):
+        outs[impl] = eng.run()
+      events = tracer.events()
+    finally:
+      trace_lib.install(None)
+    assert (eng.flat_width, eng.flat_narrow) == (40, 16)
+    assert eng.lowerings["slot_attn_impl"] == impl
+    assert eng.lowerings["tile_attn_out"] == form
+    assert 0 < stats.flat_narrow_steps < stats.steps
+    assert eng._step_fn._cache_size() == 1
+    counted[impl] = {
+        name: [ev["args"]["value"] for ev in events
+               if ev["ph"] == "C" and ev["name"] == f"serving/{name}"]
+        for name in ("attn_tile_positions", "attn_rows_read",
+                     "flat_positions")}
+    counted[impl]["plans"] = plans
+  for uid, toks in outs["reference"].items():
+    np.testing.assert_array_equal(np.asarray(outs["interpret"][uid]),
+                                  np.asarray(toks))
+  assert not counted["reference"]["attn_tile_positions"]
+  got = counted["interpret"]
+  assert not got["attn_rows_read"]
+  assert len(got["attn_tile_positions"]) == len(got["flat_positions"]) > 0
+  # Held to the plans: a slot that feeds one position counts 1, one that
+  # feeds more its live tiles of 8 positions.
+  want = sorted(int(np.sum(np.where(nv == 1, 1, -(-nv // 8) * 8)))
+                for nv in got["plans"] if nv.sum())
+  assert sorted(got["attn_tile_positions"]) == want
+  assert all(t >= f for t, f in zip(got["attn_tile_positions"],
+                                    got["flat_positions"]))
+  assert sum(got["attn_tile_positions"]) > sum(got["flat_positions"])
+
+
+def test_the_step_holds_two_launches_on_the_flat_batch(monkeypatch, wide):
+  """The fused step's program under the interpreted kernels at a narrower
+  width: two ``slot_attn`` launches (many, one) whose queries are the flat
+  batch ``[T, H, r + dr]`` and whose output its rows ``[T, H, r]``; at
+  full width (and for GLM-4.7-Flash's 20 heads at any) the one launch of
+  the first grid on ``[slots, Hkv, rows, ..]`` operands."""
+  from easyparallellibrary_tpu.models.slot_core import slot_step_logits
+  model, params = wide
+  sa = importlib.import_module("easyparallellibrary_tpu.kernels.slot_attention")
+  B, C, T = 4, 16, 40
+  kv, cursors = kv_lib.allocate_kv_cache(model.cfg, B, C)
+  tokens = jnp.zeros((B, C), jnp.int32)
+  nv = jnp.asarray([16, 1, 0, 3], jnp.int32)
+
+  def launches(width):
+    closed = jax.make_jaxpr(lambda p, kv: slot_step_logits(
+        model, p, kv, tokens, cursors, num_valid=nv,
+        reset=jnp.zeros((B,), jnp.bool_), width=width,
+        kv_write_impl="interpret", slot_attn_impl="interpret",
+        gdn_scan_impl="reference", moe_gmm_impl="reference"))(params, kv)
+    found = []
+    def walk(jaxpr):
+      for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call" and e.params["name"] == (
+            sa.SLOT_ATTN):
+          found.append([tuple(v.aval.shape) for v in e.invars])
+        for sub in jax.core.jaxprs_in_params(e.params):
+          walk(sub)
+    walk(closed.jaxpr)
+    return found
+
+  H, W, r = 8, 40, 32
+  flat = launches(T)
+  assert len(flat) == 2
+  for shapes in flat:
+    assert (T, H, W) in shapes and (T, H, r) in shapes
+    assert not any(len(s) > 2 and (s[:2] == (B, C) or s[0] == B * C)
+                   for s in shapes)
+  (first,) = launches(None)
+  assert (B, 1, sa._query_rows(C, H, jnp.float32), W) in first

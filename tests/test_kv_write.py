@@ -973,12 +973,17 @@ def test_gigachat_step_compiled_for_v5e_holds_its_kernels(one_chip):
   and its cell's geometry, 128 slots x chunk 32 at a context of 4096,
   compiled for a described v5e as the engine builds it, at two widths: one
   ``gdn_scan`` whose state operand aliases its output, one ``kv_write`` and
-  one ``slot_attn`` over the latent leaf, each outside the conditionals and
-  in the program ONCE (models/slot_core.py ``SplitLayer``); two ``moe_gmm``
+  TWO ``slot_attn`` over the latent leaf (PR 51: the plain leaf on the tile
+  grid, the slots that feed several positions and then the decoding ones),
+  each outside the conditionals and in the program ONCE
+  (models/slot_core.py ``SplitLayer``); two ``moe_gmm``
   on either side of a conditional; no copy of the 537 MB matrix state nor
   of the latent leaf, which at 128 slots is allocated 4224 rows long so
   that the chip keeps it position-minor (serving/kv_cache.py
-  ``kv_leaf_shape``); no ``while`` (the reference scan is one)."""
+  ``kv_leaf_shape``); no ``while`` (the reference scan is one); the attend
+  reads ``[T, 64, 576]`` and writes ``[T, 64, 512]`` where the flat batch
+  lies, and no ``[128, 32, 64, ..]`` array of queries or of attended rows
+  exists in any split of its dimensions."""
   from easyparallellibrary_tpu.models.gigachat import (
       GigaChat, GigaChatConfig)
   epl.init()
@@ -992,10 +997,23 @@ def test_gigachat_step_compiled_for_v5e_holds_its_kernels(one_chip):
   text = _compiled_text(step, *args)
   calls = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
   assert [calls(n) for n in ("gdn_scan", "kv_write", "slot_attn",
-                             "moe_gmm")] == [1, 1, 1, 4], text.count(
+                             "moe_gmm")] == [1, 1, 2, 4], text.count(
                                  "tpu_custom_call")
   assert " while(" not in text
   _assert_no_leaf_copied(text, args[1])
+  # Both launches stand in the entry computation: outside the conditionals.
+  entry = text[text.index("\nENTRY "):]
+  assert len(re.findall(r"%slot_attn[.\d]* = ", entry)) == 2
+  T, H = 2048, 64
+  for width in (576, 512):
+    assert f"bf16[{T},{H},{width}]" in text
+    assert not re.search(
+        rf"bf16\[{slots},{C},{H},{width}\]|bf16\[{slots},{C * H},{width}\]"
+        rf"|bf16\[{slots},1,{C * H},{width}\]"
+        rf"|bf16\[{slots},1,{H},{C},{width}\]"
+        rf"|bf16\[{slots * C // 8},8,{H},{width}\]"
+        rf"|bf16\[{slots * C},{H * width}\]|bf16\[{T + 8},{H},{width}\]",
+        text)
   kv = args[1]
   assert kv["block_0"]["linear"]["delta_state"].shape == (slots, 64, 128,
                                                           128)

@@ -469,3 +469,176 @@ def test_rule_takes_the_latent_leaf_on_a_tpu(monkeypatch, backend, sharded,
   for dtype in (jnp.bfloat16, jnp.float32):
     assert sa.resolve_slot_attn_impl((96, 4104, 1, 576), dtype, 8, 20,
                                      sharded=sharded) == want
+
+
+# ------------------------------------- a plain one-leaf attend on the tile grid --
+#
+# Where ``plain_tile_form`` holds, a latent leaf with no window and no
+# selection is served by the tile grid the selected and the windowed forms
+# run on: queries read from the step's flat batch where they lie, the
+# result written to the same rows, live tiles alone, the decoding slots in
+# a launch of their own, both under the first grid's name.
+
+
+def _flat_case(nv, cursors, dtype, C=16, pad=9, seed=8):
+  """Operands of one plain attend from a flat batch: ``len(nv)`` slots of 8
+  heads of 48 on values of 32 over leaves of 264 rows, each slot's first
+  ``nv[b]`` positions packed in slot order into ``sum(nv) + pad`` rows;
+  NaN in every leaf row at or beyond a slot's bound.  Returns ``(flat,
+  starts, dirty leaf, cursors, nv, want at the live rows)``."""
+  rng = np.random.default_rng(seed)
+  B, H, W, r, L = len(nv), 8, 48, 32, 264
+  nv, cur = np.asarray(nv, np.int32), np.asarray(cursors, np.int32)
+  q = jnp.asarray(rng.normal(size=(B, C, H, W)), dtype)
+  leaf = rng.normal(size=(B, L, 1, W)).astype(np.float32)
+  want = np.asarray(sa.slot_attention_reference(
+      q, jnp.asarray(leaf, dtype), None, jnp.asarray(cur), v_width=r,
+      scale=0.2), np.float32)
+  for b in range(B):
+    leaf[b, cur[b] + nv[b] if nv[b] else 0:] = np.nan
+  live = np.arange(C)[None] < nv[:, None]
+  flat = np.zeros((nv.sum() + pad, H, W), np.float32)
+  flat[:nv.sum()] = np.asarray(q, np.float32)[live]
+  return (jnp.asarray(flat, dtype), jnp.asarray(np.cumsum(nv) - nv, jnp.int32),
+          jnp.asarray(leaf, dtype), jnp.asarray(cur), jnp.asarray(nv),
+          want[live])
+
+
+PLAIN_CASES = {
+    # whole and partial chunks, an idle slot, decodes beside them
+    "mixed": ([16, 3, 0, 1, 9, 1], [0, 125, 240, 200, 3, 248], 9),
+    # the decoding slots' launch alone has work
+    "only-decodes": ([1, 1, 0, 1], [0, 125, 40, 248], 9),
+    # the other launch alone has work
+    "no-decode": ([16, 3, 0, 5], [0, 125, 40, 200], 9),
+    # neither has: each still visits one tile and must write nothing
+    "idle": ([0, 0, 0, 0], [0, 125, 40, 200], 9),
+    "last-slot-alone": ([0, 0, 0, 16], [0, 125, 40, 248], 9),
+    # the last slot's one tile starts within 8 rows of the batch's end: it
+    # is read from T - 8 on and worked a shift further down (_tile_shift)
+    "tile-near-the-end": ([1, 7, 8, 9, 16, 0, 5],
+                          [200, 0, 125, 40, 3, 77, 230], 2),
+    # a decode in the batch's last row
+    "decode-last": ([9, 1], [200, 0], 0),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", list(PLAIN_CASES))
+def test_plain_tile_form_equals_the_reference_flat_in_and_out(case, dtype):
+  """``slot_attn`` on the tile grid (interpreted) against the einsums over
+  every row: each live position's result at the row its query lies in,
+  zeros at the rows no live position owns, nothing at or beyond a bound
+  read (NaN planted there)."""
+  nv, cursors, pad = PLAIN_CASES[case]
+  flat, starts, leaf, cur, nv, want = _flat_case(nv, cursors, dtype, pad=pad)
+  total = int(nv.sum())
+  if case == "tile-near-the-end":
+    T = flat.shape[0]
+    assert int(starts[-1]) > T - 8 and int(starts[-1]) + 5 < T
+  got = sa.slot_attention(flat, leaf, None, cur, nv, impl="interpret",
+                          v_width=32, scale=0.2, starts=starts, chunk=16)
+  assert got.shape == flat.shape[:2] + (32,) and got.dtype == dtype
+  got = np.asarray(got, np.float32)
+  assert np.isfinite(got).all()
+  np.testing.assert_allclose(got[:total], want, atol=TOL[dtype],
+                             rtol=TOL[dtype])
+  assert (got[total:] == 0).all()
+
+
+@pytest.mark.parametrize("block", [128, None], ids=["three-blocks", "ruled"])
+def test_plain_tile_form_is_the_first_grid_in_slot_order(block):
+  """Called with ``[B, C, H, W]`` (every position of every slot) the same
+  launches give what the first grid gives: dead positions zeros."""
+  flat, starts, leaf, cur, nv, _ = _flat_case(
+      [16, 3, 0, 1, 9, 1], [0, 125, 240, 200, 3, 248], jnp.float32)
+  rng = np.random.default_rng(1)
+  q = jnp.asarray(rng.normal(size=(6, 16, 8, 48)), jnp.float32)
+  first = sa.slot_attention_pallas(q, leaf, None, cur, nv, interpret=True,
+                                   block=128, v_width=32, scale=0.2)
+  tiled = sa.slot_attention_tiled_pallas(q, leaf, cur, nv, interpret=True,
+                                         block=block, v_width=32, scale=0.2)
+  assert tiled.shape == first.shape
+  np.testing.assert_allclose(np.asarray(tiled), np.asarray(first), atol=2e-6,
+                             rtol=2e-6)
+
+
+def test_the_plain_launches_keep_the_first_grids_name_and_flat_operands():
+  """Two launches (the slots that feed several positions, then the decoding
+  ones) named ``slot_attn``, each handed the aliased ``[T, H, v]`` output,
+  the rows' positions, ``q`` ``[T, H, W]`` and the leaf position-minor: no
+  score operand, no ``[slots, chunk]``-ordered array."""
+  T, B, C, H, W, r, L = 40, 4, 16, 8, 48, 32, 264
+  closed = jax.make_jaxpr(lambda *a: sa.slot_attention_tiled_pallas(
+      *a, interpret=True, starts=jnp.arange(B, dtype=jnp.int32) * 9, chunk=C,
+      v_width=r, scale=0.2))(
+          jnp.zeros((T, H, W)), jnp.zeros((B, L, 1, W)),
+          jnp.zeros((B,), jnp.int32), jnp.full((B,), 9, jnp.int32))
+  (inner,) = [e for e in closed.jaxpr.eqns if e.primitive.name in (
+      "pjit", "jit")]
+  calls = [e for e in inner.params["jaxpr"].jaxpr.eqns
+           if e.primitive.name == "pallas_call"]
+  for call, tp in zip(calls, (8, 1), strict=True):
+    assert call.params["name"] == sa.SLOT_ATTN
+    assert [tuple(v.aval.shape) for v in call.invars[-4:]] == [
+        (T, H, r), (tp * H, 1), (T, H, W), (B, 1, W, L)]
+    assert [tuple(v.aval.shape) for v in call.outvars] == [(T, H, r)]
+
+
+def test_the_flat_batch_is_the_tile_kernels_alone():
+  flat, starts, leaf, cur, nv, _ = _flat_case([9, 1], [200, 0], jnp.float32)
+  with pytest.raises(ValueError, match="plain_tile_form"):
+    sa.slot_attention(flat, leaf, None, cur, nv, impl="reference",
+                      v_width=32, scale=0.2, starts=starts, chunk=16)
+  with pytest.raises(ValueError, match="plain_tile_form"):
+    sa.slot_attention(flat, leaf, leaf, cur, nv, impl="interpret",
+                      starts=starts, chunk=16)
+
+
+# The serving cells' attends, by shape: (leaf, heads, values' width or head
+# size, chunk, flat width) -> whether a PLAIN leaf there takes the tile grid.
+CELL_SHAPES = {
+    # GigaChat3.5: 64 heads x chunk 32 are 2,048 query rows a slot on the
+    # first grid; four tiles of 8 positions on the tile grid
+    "gigachat35": ((128, 4224, 1, 576), 64, 512, 32, 2048, True),
+    "gigachat35-narrow-side": ((128, 4224, 1, 576), 64, 512, 32, 1024, True),
+    # GLM-4.7-Flash: 20 heads are no whole sublane tiles of bfloat16, and a
+    # chunk of 8 is one tile
+    "glm47flash": ((96, 4104, 1, 576), 20, 512, 8, 384, False),
+    "glm47flash-chunk-16": ((96, 4112, 1, 576), 20, 512, 16, 768, False),
+    "sixteen-heads-chunk-8": ((96, 4104, 1, 576), 16, 512, 8, 384, False),
+    # at full width the flat batch IS [slots, chunk] order
+    "gigachat35-full-width": ((128, 4224, 1, 576), 64, 512, 32, 4096, False),
+    # dots3's and GLM-5's latent leaves select their rows (not plain: the
+    # mixer never asks), but their shapes would tile
+    "dots3-full-layer": ((32, 12832, 1, 576), 128, 512, 32, 512, True),
+    "glm5-a-chip": ((32, 10784, 1, 576), 64, 512, 32, 512, True),
+    # the K/V cells' leaves, kept in rows: a pair is no one-leaf cache
+    "gpt2m": ((96, 1040, 1024), 16, 64, 16, 768, False),
+    "jamba2": ((128, 8200, 128), 20, 128, 8, 512, False),
+    "lfm2": ((128, 4112, 512), 32, 64, 16, 1024, False),
+    "smallthinker-full-layer": ((48, 16416, 512), 28, 128, 32, 768, False),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELL_SHAPES))
+def test_the_plain_rule_at_the_cells_shapes(cell):
+  """``plain_tile_form`` is shape arithmetic: the tile kernel fits the
+  leaf, a chunk is more than one tile of positions, the batch is narrower
+  than ``slots x chunk``, the kernel was resolved."""
+  shape, H, vw, C, T, takes = CELL_SHAPES[cell]
+  narrower = T < shape[0] * C
+  assert sa.plain_tile_form("pallas", narrower, shape, jnp.bfloat16, C, H,
+                            vw) == takes
+  assert sa.plain_tile_form("interpret", narrower, shape, jnp.bfloat16, C, H,
+                            vw) == takes
+  # no kernel, no tile form; nor for a lowering nobody resolved
+  for impl in ("reference", None):
+    assert not sa.plain_tile_form(impl, narrower, shape, jnp.bfloat16, C, H,
+                                  vw)
+  if takes:
+    assert sa.tile_positions(C, H) == 8 and sa.decodes_apart(C)
+  if cell.startswith("gigachat35"):
+    # a block of 2048 rows within the 24 MiB the tile kernels may ask for
+    assert sa._tile_block(4224, 576, jnp.bfloat16, 8 * 64, 512, False) == 2048

@@ -156,3 +156,64 @@ def test_the_flat_form_follows_the_kernel_and_the_width(monkeypatch, family,
   assert record["tile_attn_out"] == out == slot_attention.tile_attn_out(
       backend, width is not None and width < 32 * 32)
   assert "tile_attn_out" not in kv_lib.resolved(record)
+
+
+# Every serving cell of the benchmark at its own geometry (a chip's share of
+# GLM-5's 128 slots): where its one-leaf attends read and write, and the
+# tile ``serving/attn_tile_positions`` counts by.  GigaChat3.5's plain leaf
+# takes the tile grid (64 heads x chunk 32: 2,048 query rows a slot on the
+# first grid); GLM-4.7-Flash's keeps the first grid (20 heads are no whole
+# sublane tiles, a chunk of 8 is one tile); the K/V cells have no such leaf.
+CELLS = {
+    "gpt2m-chat-steady": (96, None, None),
+    "gpt2m-offline-backlog": (96, None, None),
+    "jamba2-3b-reasoning-backlog": (128, None, None),
+    "glm47flash-agent-backlog": (96, None, None),
+    "lfm2moe-chat-steady": (128, None, None),
+    "dots3note-longdoc-backlog": (32, "flat", (8, 1)),
+    "smallthinker-mixedlen-backlog": (48, None, None),
+    "glm5-agentctx-backlog-4chip": (32, "flat", (8, 1)),
+    "gigachat35-decode-backlog": (128, "flat", (8, 1)),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_a_cells_one_leaf_attends_follow_its_shapes(monkeypatch, cell):
+  from easyparallellibrary_tpu.serving.engine import flat_width
+  from perfbench.harness import manifest as manifest_lib
+  for mod in ("kv_write", "slot_attention", "dsa_index", "moe_gmm",
+              "ssm_scan", "gdn_scan"):
+    monkeypatch.setattr(
+        importlib.import_module(f"easyparallellibrary_tpu.kernels.{mod}"),
+        "_backend_impl", lambda: "pallas")
+  man = manifest_lib.Manifest(os.path.join(os.path.dirname(__file__), ".."))
+  cell_file = man.cell_file(cell)
+  config_file = man.config_file(man.workload(cell)["config"])
+  epl.init()
+  if cell_file["runner"] == "serve":
+    from perfbench.reference import gpt2
+    from perfbench.runners import epl_gpt
+    cfg = epl_gpt.gpt_config(gpt2.GPT2Config.from_file(config_file),
+                             cell_file["model"])
+  else:
+    glue = importlib.import_module(
+        f"perfbench.runners.epl_{cell_file['family']}")
+    cfg = glue.build_model(glue.ref_config(config_file),
+                           cell_file["model"])[0].cfg
+  slots, out, tile = CELLS[cell]
+  chunk = cell_file["engine"]["prefill_chunk"]
+  assert slots * man.workload(cell)["chips"] == cell_file["engine"][
+      "num_slots"]
+  T = flat_width(slots, chunk)
+  record = kv_lib.step_lowerings(cfg, slots, chunk, width=T)
+  assert record["slot_attn_impl"] == "pallas"
+  assert record["tile_attn_out"] == out
+  assert kv_lib.attn_tile(cfg, record, chunk) == tile
+  # the first grid's walk is counted where a leaf still takes it
+  walk = kv_lib.slot_attn_walk(cfg, slots, chunk, record["tile_attn_out"])
+  assert (walk is None) == (out == "flat")
+  # at full width, and under the reference lowerings, no plain leaf does
+  for full in (kv_lib.step_lowerings(cfg, slots, chunk),
+               kv_lib.step_lowerings(cfg, slots, chunk, width=slots * chunk)):
+    assert full["tile_attn_out"] == (
+        None if cell.startswith("gigachat") or out is None else "slots")
